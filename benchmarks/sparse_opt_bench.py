@@ -25,9 +25,6 @@ import time
 sys.path.insert(0, os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..")))
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -97,4 +94,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -18,9 +18,6 @@ import json
 
 import jax
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 from hetu_tpu.galvatron import (LayerProfile, GalvatronSearch)
 
 
@@ -71,4 +68,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -22,9 +22,6 @@ import argparse
 
 import jax
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 from hetu_tpu.galvatron import (GalvatronSearch, LayerProfile,
                                 TransformerHPLayer, make_lm_hybrid_model)
 
@@ -85,4 +82,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

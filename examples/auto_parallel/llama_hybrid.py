@@ -21,9 +21,6 @@ import argparse
 
 import jax
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 import jax.numpy as jnp
 
 from hetu_tpu.galvatron import (GalvatronSearch, LayerProfile, LlamaHPLayer,
@@ -89,4 +86,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

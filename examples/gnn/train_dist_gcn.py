@@ -26,15 +26,12 @@ import sys
 sys.path.insert(0, os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..")))
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from hetu_tpu.platform import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hetu_tpu.gnn import partition_graph
@@ -190,4 +187,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -16,9 +16,6 @@ import sys
 sys.path.insert(0, os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..")))
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 import argparse
 
 import numpy as np
@@ -97,4 +94,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -21,9 +21,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..")))
 
-from hetu_tpu.platform import force_platform_from_env
-force_platform_from_env()
-
 
 def main():
     import numpy as np
@@ -61,4 +58,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -28,7 +28,6 @@ sys.path.insert(0, os.path.abspath(os.path.join(
 def main():
     import numpy as np
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from hetu_tpu.parallel import make_mesh, PipelineParallel

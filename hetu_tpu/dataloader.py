@@ -188,8 +188,8 @@ class Dataloader:
         # device_prefetch: the producer thread uploads each batch with
         # jax.device_put as soon as it's sliced, so the host->device copy
         # overlaps the previous step instead of landing on the critical
-        # path (on a remote-tunnel chip a per-step synchronous upload
-        # costs a full link round trip; on TPU-VM it's PCIe time).
+        # path (a synchronous per-step upload is PCIe time the chip
+        # spends idle).
         # ``sharding``: the committed layout for the batch (a
         # jax.sharding.Sharding) — under a dp/tp mesh the upload lands
         # sharded exactly as the compiled step's in_shardings expect,

@@ -1,4 +1,4 @@
-"""Build + load the native Galvatron DP core (g++ → libgalvatron_dp.so).
+"""Build + load the native Galvatron DP core (g++ → libgalvatron_dp).
 
 Reference ships tools/Hetu-Galvatron/csrc/dp_core.cpp as a pybind11 module;
 pybind11 is absent here so the core exposes a C ABI consumed via ctypes,
@@ -33,7 +33,7 @@ def _declare(lib):
 
 
 _native = NativeLib(os.path.join(_HERE, "csrc", "dp_core.cpp"),
-                    os.path.join(_HERE, "csrc", "libgalvatron_dp.so"),
+                    "libgalvatron_dp",
                     declare=_declare)
 
 

@@ -182,19 +182,11 @@ class TransformerHPLayer:
         tp = int(np.prod([mesh.shape[a] for a in sh.tp_axes] or [1]))
         dp = int(np.prod([mesh.shape[a] for a in sh.dp_axes] or [1]))
         if (t >= 128 and hd <= 512 and nh % tp == 0 and b % dp == 0):
-            from ..ops.pallas.flash_attention import flash_attention
-            from ..platform import shard_map
-            spec = P(sh._axes(sh.dp_axes) if sh.dp_axes else None,
-                     sh._axes(sh.tp_axes) if sh.tp_axes else None,
-                     None, None)
-
-            def body(q, k, v):
-                o = flash_attention(q, k, v, causal=True)
-                assert o is not None  # guaranteed by the shape pre-check
-                return o
-
-            return shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                             out_specs=spec, check_vma=False)(q, k, v)
+            from ..ops.pallas.flash_attention import \
+                sharded_flash_attention
+            return sharded_flash_attention(
+                mesh, q, k, v, batch_axes=sh.dp_axes,
+                head_axes=sh.tp_axes, causal=True)
         a = (q @ k.transpose(0, 1, 3, 2)) / np.sqrt(hd)
         mask = jnp.tril(jnp.ones((t, t), bool))
         a = jnp.where(mask, a, -1e9)

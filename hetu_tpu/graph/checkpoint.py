@@ -10,6 +10,7 @@ checkpoints must work (a 100B-param state never materializes on one host).
 
 from __future__ import annotations
 
+import logging
 import os
 import pickle
 import re
@@ -284,7 +285,9 @@ def restore_resharded(path, target_shardings):
 
     ckptr = ocp.StandardCheckpointer()
     try:
-        meta = ckptr.metadata(str(path))
+        # StepMetadata.item_metadata.tree: the saved state as a plain
+        # nested dict whose leaves carry .shape and .dtype
+        meta = ckptr.metadata(str(path)).item_metadata.tree
     except Exception as e:
         raise CheckpointError(
             f"{path}: unreadable checkpoint metadata "
@@ -317,8 +320,12 @@ def restore_resharded(path, target_shardings):
         tmpl = tree_map_with_path(
             lambda kp, m: _template(kp, m, True), meta)
         state = ckptr.restore(str(path), tmpl)
-    except Exception:
+    except Exception as e:
         # host-gather fallback: read every leaf replicated, then place
+        logging.getLogger(__name__).warning(
+            "%s: restore into the target shardings failed (%s: %s); "
+            "reading every leaf to the host and placing it from there",
+            path, type(e).__name__, e)
         tmpl = tree_map_with_path(
             lambda kp, m: _template(kp, m, False), meta)
         try:

@@ -122,20 +122,9 @@ class DistConfig:
 
 def initialize_from_env():
     """Call inside a launched worker: wires jax.distributed from the env
-    set by `DistConfig.process_env` (no-op when single-process).
-
-    ``HETU_PLATFORM`` (e.g. 'cpu') forces the jax platform first, tearing
-    down any backend a sitecustomize pre-initialized — required because
-    jax.distributed.initialize must run before backend bring-up."""
+    set by `DistConfig.process_env` (no-op when single-process).  The
+    platform is jax's own choice (``JAX_PLATFORMS``)."""
     import jax
-    platform = os.environ.get("HETU_PLATFORM")
-    if platform:
-        try:
-            from jax.extend import backend as _backend
-            _backend.clear_backends()
-        except Exception:
-            pass
-        jax.config.update("jax_platforms", platform)
     coord = os.environ.get("HETU_COORDINATOR")
     n = int(os.environ.get("HETU_NUM_PROCESSES", "1"))
     if coord and n > 1:
@@ -175,9 +164,24 @@ def launch_local(worker_fn, num_workers, ps_tables=None):
 
 def launch(config: DistConfig, script, args=(), dry_run=False):
     """Bring up the cluster: emit (and unless dry_run, execute) one command
-    per worker host.  Returns the [(host, cmd)] plan."""
+    per worker process.  Returns the [(host, cmd)] plan.
+
+    One process for each host's chips: the TPU runtime hands all of a
+    host's chips to the first process that asks, and a second process on
+    that host fails or hangs.  So a host with more than one worker is
+    refused unless the launch environment pins ``JAX_PLATFORMS=cpu`` (a
+    CPU rehearsal); on chips, one worker drives every local chip through
+    a mesh."""
     plan = config.worker_commands(script, args)
     if not dry_run:
+        crowded = {h: n for h, n in config.workers.items() if n > 1}
+        if crowded and not os.environ.get(
+                "JAX_PLATFORMS", "").startswith("cpu"):
+            raise ValueError(
+                f"one process per host: {crowded} asks for several "
+                "workers on one host, and a host's chips belong to one "
+                "process.  Use workers: 1 (one process drives all local "
+                "chips), or set JAX_PLATFORMS=cpu for a CPU rehearsal.")
         procs = [subprocess.Popen(cmd, shell=True) for _, cmd in plan]
         for p in procs:
             p.wait()

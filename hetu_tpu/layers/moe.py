@@ -203,7 +203,8 @@ class _MoEOp(Op):
                                                     self.k, C)
             # pallas_call does not partition under GSPMD: inside ANY
             # meshed program (ep-sharded or just dp) the gather lowers
-            # via XLA instead
+            # via XLA instead; row_gather records which form ran
+            # (pallas/dispatch.py)
             pallas_ok = ctx.mesh is None
             expert_in = sparse_dispatch(tokens, choices,
                                         self.num_experts, C,

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..platform import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..graph.node import Op

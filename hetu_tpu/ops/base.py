@@ -33,11 +33,13 @@ def _peek_id():
     return _n._node_counter[0] + 1
 
 
-def simple_op(impl, op_kind):
+def simple_op(impl, op_kind, node_cls=SimpleOp):
     """Returns a graph-node constructor for a pure function.
 
     ``impl(*input_arrays, **attrs)`` must be jax-traceable; non-Op positional
-    arguments are forbidden (constants go through attrs).
+    arguments are forbidden (constants go through attrs).  ``node_cls`` is
+    a ``SimpleOp`` subclass for the rare op whose ``_compute`` also reads
+    the trace context.
     """
 
     def ctor(*inputs, name=None, **attrs):
@@ -46,7 +48,7 @@ def simple_op(impl, op_kind):
                 raise TypeError(
                     f"{op_kind}: expected graph nodes as inputs, got "
                     f"{type(i).__name__}; pass constants as keyword attrs")
-        return SimpleOp(impl, op_kind, *inputs, name=name, **attrs)
+        return node_cls(impl, op_kind, *inputs, name=name, **attrs)
 
     ctor.__name__ = op_kind
     return ctor

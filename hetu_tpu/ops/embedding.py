@@ -99,7 +99,8 @@ class _PackedLookupOp(_Op):
     ops/pallas/sparse_densify.py — the TPU-native storage for narrow
     embedding dims whose vjp needs no XLA scatter).  The Pallas write
     kernel engages only off-mesh on TPU; the jnp fallback is
-    numerically identical (CPU tests, sharded programs)."""
+    numerically identical (CPU tests, sharded programs), and
+    ``pack_write`` records which of the two ran (pallas/dispatch.py)."""
 
     def _compute(self, input_vals, ctx):
         from .pallas.sparse_densify import packed_lookup

@@ -12,8 +12,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .base import simple_op
+from .base import SimpleOp, simple_op
 
 
 def _softmax_cross_entropy(y, y_, dim=-1):
@@ -29,16 +30,43 @@ softmax_cross_entropy_op = simple_op(_softmax_cross_entropy,
                                      "softmax_cross_entropy")
 
 
-def _softmax_cross_entropy_sparse(y, labels, dim=-1, ignored_index=-1):
-    if dim in (-1, y.ndim - 1):
-        # fused Pallas path: streams the vocab once with online logsumexp;
-        # also sidesteps an XLA pathology for lane-unaligned vocab sizes
-        # (GPT-2's 50257: 3.3x slower than 50304 through the jnp form)
-        from .pallas.softmax_ce import fused_softmax_ce_sparse
-        out = fused_softmax_ce_sparse(y, labels,
-                                      ignored_index=ignored_index)
-        if out is not None:
-            return out
+def _ce_kernel_plan(y, dim, mesh):
+    """``(reason, row_axes)`` for the fused Pallas softmax-CE: ``reason``
+    is None when the kernel runs (per shard over ``row_axes`` when that is
+    non-empty), else why the jnp form runs."""
+    from .pallas import dispatch
+    from .pallas.softmax_ce import unsupported
+    if dim not in (-1, y.ndim - 1):
+        return "class_dim_not_last", ()
+    rows = int(np.prod(y.shape[:-1]))
+    # under a mesh: per shard, rows over 'dp'.  Any other axis is refused:
+    # a 'tp' axis may leave the vocabulary sharded (tied vocab-parallel
+    # heads), which a per-row kernel cannot read without gathering it,
+    # while the jnp form partitions under GSPMD
+    why, axes = dispatch.shard_axes(mesh, {"dp": rows})
+    if why is not None:
+        return why, ()
+    if axes["dp"]:
+        rows //= mesh.shape["dp"]
+    return unsupported(jax.ShapeDtypeStruct((rows, y.shape[-1]),
+                                            y.dtype)), axes["dp"]
+
+
+def _softmax_cross_entropy_sparse(y, labels, dim=-1, ignored_index=-1,
+                                  mesh=None):
+    # fused Pallas path: streams the vocab once with online logsumexp;
+    # also sidesteps an XLA pathology for lane-unaligned vocab sizes
+    # (GPT-2's 50257: 3.3x slower than 50304 through the jnp form)
+    from .pallas import dispatch, softmax_ce
+    why, row_axes = _ce_kernel_plan(y, dim, mesh)
+    if dispatch.record("softmax_ce", why):
+        if row_axes:
+            v = y.shape[-1]
+            return softmax_ce.sharded_softmax_ce_sparse(
+                mesh, y.reshape(-1, v), labels.reshape(-1),
+                ignored_index, row_axes).reshape(y.shape[:-1])
+        return softmax_ce.fused_softmax_ce_sparse(
+            y, labels, ignored_index=ignored_index)
     y = y.astype(jnp.float32)  # stable under bf16 compute policies
     lse = jax.scipy.special.logsumexp(y, axis=dim)
     labels = labels.astype(jnp.int32)
@@ -49,8 +77,17 @@ def _softmax_cross_entropy_sparse(y, labels, dim=-1, ignored_index=-1):
     return jnp.where(labels == ignored_index, 0.0, loss)
 
 
+class _SoftmaxCESparseOp(SimpleOp):
+    """The one loss op that needs the trace context: its kernel runs per
+    shard when the executor has a mesh."""
+
+    def _compute(self, input_vals, ctx):
+        return self.impl(*input_vals, mesh=ctx.mesh, **self.attrs)
+
+
 softmax_cross_entropy_sparse_op = simple_op(
-    _softmax_cross_entropy_sparse, "softmax_cross_entropy_sparse")
+    _softmax_cross_entropy_sparse, "softmax_cross_entropy_sparse",
+    node_cls=_SoftmaxCESparseOp)
 
 
 def _cross_entropy(y, y_, dim=-1, eps=1e-12):

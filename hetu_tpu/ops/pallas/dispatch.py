@@ -1,0 +1,88 @@
+"""Kernel selection shared by the Pallas ops.
+
+Three decisions live here so they are made once and the same way for
+every kernel file: which platform the program is being compiled for,
+whether a ``pallas_call`` runs in interpret mode, and the record of each
+kernel-versus-``jnp`` choice an op makes while it is traced.
+
+A choice is counted in the telemetry registry
+(``hetu_kernel_choice_total{kernel, impl, reason}``) and, when the
+``jnp`` form is taken on a TPU, logged as a warning: a step that ran on
+the chip without its Mosaic kernels must say so.  Selection happens at
+trace time, so the cost is a few calls per compilation and nothing per
+step.
+"""
+
+from __future__ import annotations
+
+import logging
+
+import jax
+
+from ... import telemetry
+
+_log = logging.getLogger(__name__)
+
+
+def platform():
+    """The platform jitted programs compile for in this process."""
+    return jax.default_backend()
+
+
+def interpret():
+    """Interpret mode for ``pallas_call``: only on ``cpu``, which has no
+    Mosaic backend, so that the kernels stay testable there."""
+    return platform() == "cpu"
+
+
+def mosaic():
+    """True when ``pallas_call`` lowers to a Mosaic custom call."""
+    return platform() == "tpu"
+
+
+def shard_axes(mesh, dims):
+    """Which mesh axes split which operand dim of a per-shard kernel.
+
+    ``pallas_call`` does not partition under GSPMD, so under a mesh a
+    kernel runs per shard through ``shard_map``.  ``dims`` maps a mesh
+    axis name to the size of the operand dim it may split (``{"dp":
+    batch, "tp": heads}``).  Returns ``(reason, axes)``: ``axes[name]`` is
+    ``(name,)`` where that axis splits its dim and ``()`` where it has
+    size 1 or the mesh lacks it; ``reason`` is None, or names the first
+    axis of size > 1 that is not in ``dims`` or does not divide its dim —
+    an axis the kernel has no local meaning for."""
+    axes = {name: () for name in dims}
+    for axis, size in (mesh.shape.items() if mesh is not None else ()):
+        if size == 1:
+            continue
+        if axis not in dims or dims[axis] % size:
+            return f"mesh_axis:{axis}={size}", {name: () for name in dims}
+        axes[axis] = (axis,)
+    return None, axes
+
+
+def record(kernel, reason=None):
+    """Count one trace-time selection for ``kernel``.
+
+    ``reason`` is None when the Pallas kernel is taken, else a short
+    string saying why the ``jnp`` form runs instead.  Returns True for
+    the kernel, so a call site reads ``if record("x", why): kernel``."""
+    impl = "pallas" if reason is None else "jnp"
+    telemetry.get_registry().counter(
+        "hetu_kernel_choice_total",
+        "Trace-time selections between a Pallas kernel and its jnp form",
+        labels=("kernel", "impl", "reason"),
+    ).labels(kernel=kernel, impl=impl, reason=reason or "").inc()
+    if reason is not None and mosaic():
+        _log.warning("%s: jnp form on tpu (%s)", kernel, reason)
+    return reason is None
+
+
+def choices():
+    """``{(kernel, impl, reason): count}`` recorded so far (empty while
+    telemetry is disabled, since the registry then counts nothing)."""
+    metric = telemetry.get_registry().snapshot().get(
+        "hetu_kernel_choice_total", {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["impl"],
+             s["labels"]["reason"]): int(s["value"])
+            for s in metric["samples"] if s["value"]}

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .dispatch import interpret
+
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
@@ -45,27 +47,26 @@ _BLOCK_K = 512
 _NEG_INF = -1e30
 
 
-def _interpret():
-    # CPU has no Mosaic backend; interpret mode keeps the kernels testable
-    # on the virtual-device mesh (tests/conftest.py)
-    return jax.default_backend() == "cpu"
-
-
-def _supported(q, k, v, mask):
+def unsupported(q, k, v, mask=None, dropout_keep=1.0):
+    """Why the kernel cannot take these operands, or None when it can.
+    Callers that fall back to the jnp composition record this string
+    (ops/pallas/dispatch.py)."""
     if q.ndim != 4 or k.shape != q.shape or v.shape != q.shape:
-        return False
+        return "not_self_attention_4d"
     b, h, s, d = q.shape
     # head dim is always the FULL last block dim, so Mosaic only needs it
     # 8-aligned (the wrapper pads to that); > 512 would blow VMEM tiles
     if d > 512:
-        return False
+        return "head_dim>512"
     # below one lane-tile of rows the O(S^2) composition is cheaper than
     # padding up to a kernel block
     if s < 128:
-        return False
+        return "seq<128"
     if mask is not None and tuple(mask.shape) != (b, 1, 1, s):
-        return False
-    return True
+        return "mask_not_b11s"
+    if dropout_keep < 1.0 and interpret():
+        return "dropout_prng_needs_mosaic"
+    return None
 
 
 def _pad_plan(s):
@@ -265,7 +266,8 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
                       empty_lse_neg=empty_lse_neg)
     o, lse = pl.pallas_call(
         kern,
-        interpret=_interpret(),
+        name="hetu_flash_fwd",
+        interpret=interpret(),
         grid=(b * h, sq // block_q),
         in_specs=in_specs,
         out_specs=[
@@ -446,7 +448,8 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
                          scale=scale, causal=causal, block_k=block_k,
                          q_len=sq, k_len=sk, keep_prob=keep_prob)
     dq = pl.pallas_call(
-        dq_kern, interpret=_interpret(), grid=(b * h, sq // block_q),
+        dq_kern, name="hetu_flash_bwd_dq", interpret=interpret(),
+        grid=(b * h, sq // block_q),
         in_specs=dq_specs + extra_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
@@ -461,7 +464,8 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
                           block_q=block_q, q_len=sq, k_len=sk,
                           keep_prob=keep_prob)
     dk, dv = pl.pallas_call(
-        dkv_kern, interpret=_interpret(), grid=(b * h, sk // block_k),
+        dkv_kern, name="hetu_flash_bwd_dkv", interpret=interpret(),
+        grid=(b * h, sk // block_k),
         in_specs=dkv_specs + extra_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0)),
@@ -590,10 +594,8 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     ``dropout_keep`` < 1 applies attention-prob dropout in-kernel (TPU
     PRNG); ``seed`` must then be an int32/uint32 scalar array.
     """
-    if not _supported(q, k, v, mask):
+    if unsupported(q, k, v, mask, dropout_keep) is not None:
         return None
-    if dropout_keep < 1.0 and _interpret():
-        return None  # TPU PRNG primitives only under Mosaic
     if dropout_keep < 1.0 and seed is None:
         raise ValueError(
             "flash_attention: dropout_keep < 1 requires seed= (an int32 "
@@ -629,3 +631,43 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     if d_pad != d or s_pad != s:
         out = out[:, :, :s, :d]
     return out
+
+
+def sharded_flash_attention(mesh, q, k, v, mask=None, *, batch_axes=(),
+                            head_axes=(), seed=None, **kw):
+    """:func:`flash_attention` inside a GSPMD mesh program.
+
+    ``pallas_call`` does not partition, and attention is local to one
+    (batch row, head), so the kernel runs under ``shard_map`` on each
+    device's own slice: q/k/v ``[B, H, S, D]`` split their batch dim over
+    ``batch_axes`` and their head dim over ``head_axes`` (mesh axis names;
+    both dims must divide), the ``[B, 1, 1, S]`` mask follows the batch,
+    and the dropout seed is offset by the shard's index so that shards do
+    not repeat one mask.  Same return contract as ``flash_attention``."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    batch_axes, head_axes = tuple(batch_axes), tuple(head_axes)
+    spec = P(batch_axes or None, head_axes or None, None, None)
+    mspec = P(batch_axes or None, None, None, None)
+    operands, specs = [q, k, v], [spec, spec, spec]
+    if mask is not None:
+        operands.append(mask)
+        specs.append(mspec)
+    if seed is not None:
+        operands.append(seed)
+        specs.append(P())
+
+    def local(q, k, v, *rest):
+        rest = list(rest)
+        m = rest.pop(0) if mask is not None else None
+        sd = rest.pop(0) if seed is not None else None
+        if sd is not None and batch_axes + head_axes:
+            shard = jax.lax.axis_index(batch_axes + head_axes)
+            sd = sd + shard.astype(sd.dtype) * jnp.asarray(
+                -1640531535, sd.dtype)        # 0x9E3779B1, wraps in int32
+        return flash_attention(q, k, v, mask=m, seed=sd, **kw)
+
+    # pallas out_shapes carry no varying-axes annotations
+    return shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                     out_specs=spec, check_vma=False)(*operands)

@@ -35,14 +35,22 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import dispatch
 
-def _supported(src_shape, dtype):
-    if jax.default_backend() != "tpu":
-        return False
+
+def _unsupported(src_shape, dtype, use_pallas):
+    """Why the row-gather kernel does not run, or None when it does (the
+    DMA form needs Mosaic, so there is no interpret-mode twin)."""
+    if not use_pallas:
+        return "caller:use_pallas=False"
+    if not dispatch.mosaic():
+        return f"platform:{dispatch.platform()}"
     n, h = src_shape
     if h % 128 != 0 or h > 16384:
-        return False
-    return dtype in (jnp.float32, jnp.bfloat16, np.float32)
+        return "hidden_not_128_aligned_le_16384"
+    if dtype not in (jnp.float32, jnp.bfloat16, np.float32):
+        return f"dtype:{jnp.dtype(dtype).name}"
+    return None
 
 
 _BLK = 8  # output rows per grid step = the TPU sublane quantum
@@ -99,7 +107,8 @@ def row_gather(src, idx, use_pallas=True):
 def _row_gather_fwd_impl(src, idx, use_pallas=True):
     n, h = src.shape
     m = idx.shape[0]
-    if not use_pallas or not _supported(src.shape, src.dtype):
+    if not dispatch.record("moe_row_gather",
+                           _unsupported(src.shape, src.dtype, use_pallas)):
         # jnp.take wraps NEGATIVE indices numpy-style; remap them to an
         # out-of-bounds sentinel so they fill with zeros like the kernel
         safe = jnp.where(idx >= 0, idx, n)
@@ -123,6 +132,7 @@ def _row_gather_fwd_impl(src, idx, use_pallas=True):
     import functools as _ft
     out = pl.pallas_call(
         _ft.partial(_make_kernel(), n),
+        name="hetu_moe_row_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, h), src.dtype),
     )(idx_p, src[:, None, :])

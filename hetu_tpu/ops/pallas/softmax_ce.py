@@ -31,13 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .dispatch import interpret
+
 _BN = 256     # rows per program
 _BV = 2048    # vocab lanes per chunk
 _NEG = -1e30
-
-
-def _interpret():
-    return jax.default_backend() == "cpu"
 
 
 def _fwd_kernel(x_ref, lab_ref, loss_ref, lse_ref, m_sc, l_sc, xt_sc, *,
@@ -108,7 +106,8 @@ def _fwd(logits, labels, ignored):
                              ignored=ignored)
     loss, lse = pl.pallas_call(
         kern,
-        interpret=_interpret(),
+        name="hetu_softmax_ce_fwd",
+        interpret=interpret(),
         grid=(npad // _BN, nv),
         in_specs=[
             pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
@@ -139,7 +138,8 @@ def _bwd(logits, labels, lse, g, ignored):
     kern = functools.partial(_bwd_kernel, v=v, bv=_BV, ignored=ignored)
     dx = pl.pallas_call(
         kern,
-        interpret=_interpret(),
+        name="hetu_softmax_ce_bwd",
+        interpret=interpret(),
         grid=(npad // _BN, nv),
         in_specs=[
             pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
@@ -171,14 +171,49 @@ def _ce_bwd(ignored, res, g):
 _ce.defvjp(_ce_fwd, _ce_bwd)
 
 
+def unsupported(y):
+    """Why the kernel does not take logits of this shape, or None when it
+    does: below 1024 classes or 8 rows the jnp form is one small fusion
+    and a kernel launch buys nothing."""
+    if y.ndim < 2:
+        return "rank<2"
+    if y.shape[-1] < 1024:
+        return "vocab<1024"
+    if int(np.prod(y.shape[:-1])) < 8:
+        return "rows<8"
+    return None
+
+
 def fused_softmax_ce_sparse(y, labels, ignored_index=-1):
     """Per-row CE losses (f32), any vocab size; returns None when the
     shape isn't worth the kernel so callers fall back to jnp."""
-    if y.ndim < 2:
+    if unsupported(y) is not None:
         return None
     v = y.shape[-1]
     n = int(np.prod(y.shape[:-1]))
-    if v < 1024 or n < 8:
-        return None
     out = _ce(y.reshape(n, v), labels.reshape(n), int(ignored_index))
     return out.reshape(y.shape[:-1])
+
+
+def sharded_softmax_ce_sparse(mesh, y, labels, ignored_index=-1,
+                              row_axes=("dp",)):
+    """:func:`fused_softmax_ce_sparse` inside a GSPMD mesh program.
+
+    ``pallas_call`` does not partition, and the loss is local to a row,
+    so each device runs the kernel on its own rows under ``shard_map``:
+    the leading dim of ``y [N, V]`` / ``labels [N]`` splits over
+    ``row_axes`` (it must divide) and the vocabulary stays whole on every
+    device.  Logits whose vocabulary is itself sharded do not fit this
+    layout; their callers keep the jnp form, which GSPMD partitions."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    rows = tuple(row_axes)
+
+    def local(y, labels):
+        return fused_softmax_ce_sparse(y, labels, ignored_index)
+
+    # pallas out_shapes carry no varying-axes annotations
+    return shard_map(local, mesh=mesh,
+                     in_specs=(P(rows, None), P(rows)),
+                     out_specs=P(rows), check_vma=False)(y, labels)

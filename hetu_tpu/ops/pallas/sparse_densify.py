@@ -37,7 +37,7 @@ pallas_call does not partition under GSPMD, so callers inside a
 sharded program have two options: pass ``use_pallas=False`` (the jnp
 fallback is numerically identical), or call
 :func:`sharded_packed_lookup`, which wraps the lookup in the
-``platform.shard_map`` shim — the id batch splits over a mesh axis,
+``jax.shard_map`` — the id batch splits over a mesh axis,
 the packed table rides replicated into every shard, and each device
 runs the SAME kernel on its local slice.
 """
@@ -50,6 +50,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from . import dispatch
 
 _BLK = 64      # row-writes in flight per grid step
 
@@ -68,9 +70,16 @@ def packed_rows(num_rows, dim):
     return (num_rows + q - 1) // q
 
 
-def _kernel_supported(dtype):
-    return (jax.default_backend() == "tpu"
-            and dtype in (jnp.float32, np.float32))
+def _unsupported(dtype, use_pallas):
+    """Why the row-write kernel does not run, or None when it does (the
+    DMA form needs Mosaic, so there is no interpret-mode twin)."""
+    if not use_pallas:
+        return "caller:use_pallas=False"
+    if not dispatch.mosaic():
+        return f"platform:{dispatch.platform()}"
+    if dtype not in (jnp.float32, np.float32):
+        return f"dtype:{jnp.dtype(dtype).name}"
+    return None
 
 
 def _make_kernel():
@@ -126,7 +135,8 @@ def pack_write(pack_ids, lines, p_rows, use_pallas=True):
     pack_ids = pack_ids.reshape(-1).astype(jnp.int32)
     m = pack_ids.shape[0]
     lines = lines.reshape(m, 128)
-    if not use_pallas or not _kernel_supported(lines.dtype):
+    if not dispatch.record("packed_embedding_write",
+                           _unsupported(lines.dtype, use_pallas)):
         safe = jnp.where(pack_ids >= 0, pack_ids, p_rows)
         z = jnp.zeros((p_rows + 1, 128), lines.dtype)
         return z.at[safe].add(lines)[:p_rows]
@@ -151,6 +161,7 @@ def pack_write(pack_ids, lines, p_rows, use_pallas=True):
     )
     out = pl.pallas_call(
         _make_kernel(),
+        name="hetu_packed_embedding_write",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((p_rows, 1, 128), lines.dtype),
         # alias the zero fill straight into the output: XLA's broadcast
@@ -221,8 +232,8 @@ def sharded_packed_lookup(mesh, table, ids, dim, axis="model",
                           use_pallas=True):
     """:func:`packed_lookup` inside a GSPMD mesh program.
 
-    ``pallas_call`` does not partition, so the lookup runs under the
-    platform ``shard_map`` shim: the packed ``[p_rows, 128]`` table is
+    ``pallas_call`` does not partition, so the lookup runs under
+    ``shard_map``: the packed ``[p_rows, 128]`` table is
     replicated into every shard, the id batch's LEADING dim splits over
     mesh axis ``axis`` (it must divide the axis size), and each device
     runs the identical kernel — or the bitwise-equal jnp fallback off
@@ -230,8 +241,8 @@ def sharded_packed_lookup(mesh, table, ids, dim, axis="model",
     same way as ``ids``.  This is the inference/scoring path (the
     embedding server's lookups); training gradients keep flowing
     through the unsharded ``packed_lookup`` vjp."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from ...platform import shard_map
 
     n_shards = int(mesh.shape[axis])
     if ids.shape[0] % n_shards:

@@ -23,8 +23,7 @@ the mantissa budget of every other row in a KV page.
 Every function is generic over the array namespace: pass numpy arrays
 for host/wire paths (the PS server quantizes replies without touching
 jax) and jax arrays for in-graph paths (KV gather/scatter, TP gathers).
-``int8`` works everywhere; ``fp8`` (e4m3) needs dtype support from the
-platform — gate with :func:`fp8_supported` / ``platform.fp8_dtype()``.
+``fp8`` is e4m3 (``float8_e4m3fn``).
 """
 
 from __future__ import annotations
@@ -41,22 +40,12 @@ QMAX = {"int8": 127.0, "fp8": 448.0}
 _FLOAT_CODES = ("fp8",)
 
 
-def fp8_supported():
-    """True when this environment can represent fp8 e4m3 codes."""
-    return _fp8_np_dtype() is not None
-
-
 def _fp8_np_dtype():
-    """The numpy-compatible float8_e4m3fn dtype, or None.  jax >= 0.4
-    re-exports the ml_dtypes definition, so one lookup covers both the
-    numpy and the jax.numpy paths."""
-    try:
-        import ml_dtypes
-        return np.dtype(ml_dtypes.float8_e4m3fn)
-    except (ImportError, AttributeError):
-        from .. import platform
-        dt = platform.fp8_dtype()
-        return None if dt is None else np.dtype(dt)
+    """numpy's float8_e4m3fn: the ml_dtypes definition jax depends on and
+    re-exports as ``jnp.float8_e4m3fn``, so one lookup covers the numpy
+    (wire) and the jax.numpy (in-graph) paths without importing jax."""
+    import ml_dtypes
+    return np.dtype(ml_dtypes.float8_e4m3fn)
 
 
 def code_dtype(dtype):
@@ -64,12 +53,7 @@ def code_dtype(dtype):
     if dtype == "int8":
         return np.dtype(np.int8)
     if dtype == "fp8":
-        dt = _fp8_np_dtype()
-        if dt is None:
-            raise ValueError(
-                "fp8 codes are unavailable: neither ml_dtypes nor this "
-                "jax build defines float8_e4m3fn (use kv_dtype='int8')")
-        return dt
+        return _fp8_np_dtype()
     raise ValueError(f"unknown quantization dtype {dtype!r}; "
                      f"expected one of {sorted(QMAX)}")
 

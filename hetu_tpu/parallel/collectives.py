@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..platform import shard_map
+from jax import shard_map
 
 
 # -- primitive wrappers (valid inside shard_map/pmapped code) --------------
@@ -125,11 +125,8 @@ def axis_index(axis_name):
 def varying(x, axes):
     """Mark an array as device-varying over mesh axes (scan carries that
     start replicated but become shard-dependent need this under shard_map's
-    varying-manual-axes checks; no-op where lax.pcast is unavailable)."""
-    try:
-        return lax.pcast(x, tuple(axes), to="varying")
-    except (AttributeError, TypeError):
-        return x
+    varying-manual-axes checks)."""
+    return lax.pcast(x, tuple(axes), to="varying")
 
 
 def _quantize(x, scale, qmax, itype):

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..platform import shard_map
+from jax import shard_map
 
 from .collectives import varying
 

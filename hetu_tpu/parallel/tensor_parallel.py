@@ -23,19 +23,13 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ..platform import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-
-def _axis_size(axis):
-    return lax.psum(1, axis)
 
 
 def vocab_range(vocab_size, axis):
     """This shard's [start, end) slice of the vocabulary."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     per = vocab_size // size
     start = lax.axis_index(axis) * per
     return start, start + per

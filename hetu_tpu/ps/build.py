@@ -1,4 +1,5 @@
-"""Build + load the native PS core (g++ → libhetu_ps.so, loaded via ctypes).
+"""Build + load the native PS core (g++ → libhetu_ps, loaded via ctypes;
+native_build.py says where the library file goes).
 
 The reference ships its store as prebuilt C++ (libps.so loaded by ctypes at
 executor.py:100-137); here the library is compiled on first use from the
@@ -62,7 +63,7 @@ def _declare(lib):
 
 
 _native = NativeLib(os.path.join(_HERE, "native", "hetu_ps.cpp"),
-                    os.path.join(_HERE, "native", "libhetu_ps.so"),
+                    "libhetu_ps",
                     declare=_declare, extra_flags=["-pthread"])
 
 

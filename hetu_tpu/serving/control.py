@@ -516,15 +516,8 @@ class FleetController:
     def _device_hbm_limit(self):
         if self.hbm_limit_bytes is not None:
             return self.hbm_limit_bytes
-        limit = 16 * 1024 ** 3   # v5e/v5p-class HBM default
-        try:
-            import jax
-            stats = jax.devices()[0].memory_stats()
-        except Exception:   # backend without memory_stats (CPU) — the
-            stats = None    # nominal default above stands
-        if stats and stats.get("bytes_limit"):
-            limit = stats["bytes_limit"]
-        return int(limit)
+        from ..platform import device_memory_limit
+        return device_memory_limit()
 
     def _sense_capacity(self):
         """Fold the telemetry plane's memory/compute evidence into the

@@ -100,6 +100,7 @@ Usage::
 
 from __future__ import annotations
 
+import contextlib
 import time
 import warnings
 
@@ -466,6 +467,13 @@ class InferenceEngine:
         self._verify_fn = None
         self._draft_fn = None
         self._spec_traces = {}
+        # cold dispatches: the first call of each program variant on this
+        # engine may trace and compile, for tens of seconds at published
+        # widths on a chip.  A supervisor (fleet.py) reads these two to
+        # tell a compiling replica from a wedged one.
+        self._warm = set()
+        self.cold_dispatch = None   # tag of the cold call in flight
+        self.cold_until = 0.0       # perf_counter() when the last ended
         self._build()
         if self._spec_k:
             self._build_spec()
@@ -771,6 +779,21 @@ class InferenceEngine:
         if self._draft is not None:
             out.update(self._draft.trace_counts)
         return out
+
+    @contextlib.contextmanager
+    def _dispatching(self, tag):
+        """Around one call of a jitted program: marks the engine cold
+        while the first call of variant ``tag`` is in flight."""
+        if tag in self._warm:
+            yield
+            return
+        self.cold_dispatch = tag
+        try:
+            yield
+            self._warm.add(tag)
+        finally:
+            self.cold_until = time.perf_counter()
+            self.cold_dispatch = None
 
     def _dev_put(self, host_array):
         """Upload a host-built operand.  Mesh engines place it
@@ -1375,7 +1398,8 @@ class InferenceEngine:
             if self.cache.cow_guard:
                 self.cache.assert_writable(slot, start, clen)
         try:
-            with self._tr.span("serve_prefill"):
+            with self._tr.span("serve_prefill"), \
+                    self._dispatching(f"prefill[{bb}x{cb}]"):
                 k, v, toks, oks = self._prefill_fn(
                     self.params, self.cache.k, self.cache.v,
                     self._dev_put(prompts), self._dev_put(p_lens),
@@ -1482,7 +1506,8 @@ class InferenceEngine:
                            engine=self.instance, slot=slot,
                            prompt_len=int(req.prompt.size))
             try:
-                with self._tr.span("serve_prefill"):
+                with self._tr.span("serve_prefill"), \
+                        self._dispatching("prefill"):
                     k, v, tok, ok = self._prefill_fn(
                         self.params, self.cache.k, self.cache.v,
                         jnp.asarray(padded), req.prompt.size, slot,
@@ -1583,7 +1608,8 @@ class InferenceEngine:
                     if self.cache.cow_guard:
                         self.cache.assert_writable(s, pos, 1)
             try:
-                with self._tr.span("serve_decode"):
+                with self._tr.span("serve_decode"), \
+                        self._dispatching("step"):
                     # _last_tokens is mutated in place per emitted token,
                     # so upload a SNAPSHOT: on the CPU backend
                     # jnp.asarray may alias the host buffer / defer the
@@ -1776,14 +1802,16 @@ class InferenceEngine:
                     else:
                         cat = list(req.tokens[dp - p:])
                     work.append((s, cat))
-                props = self._draft.propose(work, temps, topks, seeds)
+                with self._dispatching("draft"):
+                    props = self._draft.propose(work, temps, topks, seeds)
             elif need_draft:
-                props = np.asarray(self._draft_fn(
-                    self.params, self.cache.k, self.cache.v,
-                    self._dev_put(self._last_tokens.copy()),
-                    self.cache.device_positions(),
-                    self.cache.device_block_tables(),
-                    temps, topks, seeds))
+                with self._dispatching("draft"):
+                    props = np.asarray(self._draft_fn(
+                        self.params, self.cache.k, self.cache.v,
+                        self._dev_put(self._last_tokens.copy()),
+                        self.cache.device_positions(),
+                        self.cache.device_block_tables(),
+                        temps, topks, seeds))
         except Exception as e:
             if not self.watchdog:
                 raise
@@ -1810,7 +1838,8 @@ class InferenceEngine:
             if self.cache.cow_guard:
                 self.cache.assert_writable(s, pos, window)
         try:
-            with self._tr.span("serve_decode"):
+            with self._tr.span("serve_decode"), \
+                    self._dispatching("verify"):
                 k, v, picks, oks = self._verify_fn(
                     self.params, self.cache.k, self.cache.v,
                     self._dev_put(toks), self.cache.device_positions(),

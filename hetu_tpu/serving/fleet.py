@@ -1346,6 +1346,23 @@ class EngineFleet:
             tpot = max(known) if known else 0.0
         return max(self.wedge_floor, self.wedge_safety * tpot)
 
+    def _compiling(self, rep):
+        """True while the replica's engine is inside, or less than one
+        wedge bound past, the first call of a program variant: that
+        call may trace and compile, which takes tens of seconds at
+        published widths on a chip and is not a wedge.  Only the derived
+        bound waits for it; an explicit ``wedge_timeout=`` stays absolute.
+        The price: a hang inside a first call is left to the caller's own
+        timeout."""
+        eng = rep.engine
+        if self.wedge_timeout is not None or eng is None:
+            return False
+        if getattr(eng, "cold_dispatch", None) is not None:
+            return True
+        return (time.perf_counter()
+                - getattr(eng, "cold_until", float("-inf"))
+                ) < self.effective_wedge_timeout(rep)
+
     def _supervise_once(self):
         """One supervision pass: wedge detection (threaded only),
         breaker-gated restarts, failover dispatch, deferred cancels."""
@@ -1357,7 +1374,8 @@ class EngineFleet:
                     and rep.engine is not None
                     and not rep.engine.scheduler.idle
                     and rep.health.heartbeat_age(now)
-                    > self.effective_wedge_timeout(rep)):
+                    > self.effective_wedge_timeout(rep)
+                    and not self._compiling(rep)):
                 self._on_wedge(rep, rep.health.heartbeat_age(now))
             if (rep.health.state == QUARANTINED and self.auto_restart
                     and rep.breaker.allow(now)):
@@ -1505,7 +1523,8 @@ class EngineFleet:
                 dur = self._clock() - t0
                 if busy and dur > self.effective_wedge_timeout(rep) \
                         and rep.health.state in (HEALTHY, DEGRADED) \
-                        and rep.engine is not None:
+                        and rep.engine is not None \
+                        and not self._compiling(rep):
                     self._on_pump_stall(rep, dur)
             self._supervise_once()
         return self
@@ -1535,7 +1554,8 @@ class EngineFleet:
                 rep, gen, deadline, bound = armed
                 if (time.perf_counter() >= deadline
                         and rep.generation == gen
-                        and self._watch_armed is armed):
+                        and self._watch_armed is armed
+                        and not self._compiling(rep)):
                     self._watch_armed = None
                     try:
                         self._on_dispatch_wedge(rep, gen, bound)

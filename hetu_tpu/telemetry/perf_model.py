@@ -5,10 +5,10 @@ capture layer supplies (XLA cost-model flops/bytes, measured steps/s),
 so every derived signal here is unit-testable without a device:
 
 * :func:`chip_peaks` — per-chip peak flop rate and HBM bandwidth, from
-  the device kind (published TPU specs; bf16 dense-matmul peaks), with
-  ``HETU_PEAK_FLOPS`` / ``HETU_PEAK_HBM_BW`` env overrides for chips
-  the table doesn't know (and for pinning CPU-quick rounds to a stable
-  denominator).
+  the device kind (published TPU specs with their source; bf16
+  dense-matmul peaks); an unknown device raises.  ``HETU_PEAK_FLOPS`` /
+  ``HETU_PEAK_HBM_BW`` env overrides pin CPU-quick rounds to a stable
+  denominator.
 * :func:`mfu` — model flops utilization: achieved flops/s over peak.
 * :func:`roofline` — arithmetic intensity vs the ridge point, i.e.
   whether the program sits on the compute or the memory roof.
@@ -26,43 +26,42 @@ import os
 
 __all__ = ["CHIP_PEAKS", "chip_peaks", "mfu", "roofline", "derive"]
 
-#: device_kind substring -> (peak flops/s, HBM bytes/s).  Flop peaks are
-#: the published bf16 MXU numbers; substrings are matched in order, so
-#: "v5p" must precede "v5" etc.  The trailing "cpu" entry is nominal.
+#: (device_kind substring, peak flops/s, HBM bytes/s, source).  Flop peaks
+#: are bf16 dense-matmul numbers; substrings are matched in order against
+#: the lower-cased ``jax.devices()[0].device_kind``, so "v5p" precedes
+#: "v5 lite" etc.  The trailing "cpu" entry is nominal.
 CHIP_PEAKS = (
-    ("v6e", (918e12, 1640e9)),          # Trillium
-    ("v5p", (459e12, 2765e9)),
-    ("v5e", (197e12, 819e9)),           # aka v5 lite
-    ("v4", (275e12, 1228e9)),
-    ("v3", (123e12, 900e9)),
-    ("v2", (45e12, 700e9)),
-    ("cpu", (2e11, 5e10)),              # nominal host-order numbers
+    # a v6e reports device_kind "TPU v6 lite"
+    ("v6 lite", 918e12, 1640e9, "Google Cloud TPU docs, 'TPU v6e'"),
+    ("v5p", 459e12, 2765e9, "Google Cloud TPU docs, 'TPU v5p'"),
+    # a v5e reports device_kind "TPU v5 lite"
+    ("v5 lite", 197e12, 819e9, "Google Cloud TPU docs, 'TPU v5e'"),
+    ("v4", 275e12, 1228e9, "Google Cloud TPU docs, 'TPU v4'"),
+    ("v3", 123e12, 900e9, "Google Cloud TPU docs, 'TPU v3'"),
+    ("v2", 45e12, 700e9, "Google Cloud TPU docs, 'TPU v2'"),
+    ("cpu", 2e11, 5e10, "nominal_cpu"),  # host-order numbers, not a spec
 )
-
-_DEFAULT_PEAKS = (2e14, 8e11)           # unknown accelerator: v4-order
 
 
 def chip_peaks(device_kind=None):
     """``{"device_kind", "peak_flops", "peak_hbm_bytes_per_s",
     "peak_source"}`` for the current (or named) chip.
 
-    ``device_kind=None`` sniffs ``jax.devices()[0].device_kind`` — lazy
-    import, so the module stays importable without jax.  Env overrides
-    ``HETU_PEAK_FLOPS`` / ``HETU_PEAK_HBM_BW`` win over the table.
+    ``device_kind=None`` reads ``jax.devices()[0].device_kind`` — lazy
+    import, so the module stays importable without jax.  A device the
+    table does not know raises: a utilization against a guessed peak is
+    worse than none.  Env overrides ``HETU_PEAK_FLOPS`` /
+    ``HETU_PEAK_HBM_BW`` win over the table (and stand in for it on an
+    unknown device when both are set).
     """
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
+        import jax
+        device_kind = jax.devices()[0].device_kind
     kind_l = str(device_kind).lower()
-    flops, bw = _DEFAULT_PEAKS
-    source = "default_unknown_chip"
-    for sub, (f, b) in CHIP_PEAKS:
+    flops = bw = source = None
+    for sub, f, b, src in CHIP_PEAKS:
         if sub in kind_l:
-            flops, bw = f, b
-            source = "nominal_cpu" if sub == "cpu" else "table"
+            flops, bw, source = f, b, src
             break
     env_f = os.environ.get("HETU_PEAK_FLOPS")
     env_b = os.environ.get("HETU_PEAK_HBM_BW")
@@ -71,6 +70,11 @@ def chip_peaks(device_kind=None):
     if env_b:
         bw = float(env_b)
         source = source if env_f else "env"
+    if flops is None or bw is None:
+        raise ValueError(
+            f"no peak rates on record for device_kind {device_kind!r}; "
+            "add a row with its source to telemetry.perf_model.CHIP_PEAKS "
+            "(or set HETU_PEAK_FLOPS and HETU_PEAK_HBM_BW)")
     return {"device_kind": str(device_kind),
             "peak_flops": float(flops),
             "peak_hbm_bytes_per_s": float(bw),

@@ -4,7 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from hetu_tpu.platform import shard_map
+from jax import shard_map
 import pytest
 
 import hetu_tpu as ht

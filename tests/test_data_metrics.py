@@ -295,6 +295,29 @@ def test_mp_dataloader_transform_and_autofeed():
                     reason="single-core host: no parallelism for worker "
                            "processes to exploit (observed 1.33x from GIL "
                            "avoidance alone on 1 core)")
+def _backend_probe(batch):
+    """1.0 everywhere if this worker process has initialised a jax
+    backend, else 0.0."""
+    from jax._src import xla_bridge
+    return np.full_like(batch, float(xla_bridge.backends_are_initialized()))
+
+
+def test_mp_dataloader_worker_never_initialises_a_backend():
+    """A spawned worker re-imports hetu_tpu to find its entry point.  On a
+    TPU host the parent holds the chips, so a worker that initialised a
+    backend would fail or hang: importing must stay off the devices."""
+    from hetu_tpu.dataloader import Dataloader
+
+    data = np.ones((16, 4), np.float32)
+    dl = Dataloader(data, 4, transform=_backend_probe, num_workers=1)
+    try:
+        dl.start()
+        batch = np.asarray(dl.next_batch())
+    finally:
+        dl.stop()
+    assert batch.shape == (4, 4) and not batch.any()
+
+
 def test_mp_dataloader_speeds_up_gil_bound_transform():
     """VERDICT #8 done-criterion: on a preprocessing-bound pipeline the
     process engine beats the thread engine (which serializes the python

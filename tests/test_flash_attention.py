@@ -282,3 +282,86 @@ def test_fused_softmax_ce_matches_jnp(rng, N, V):
     want = jax.grad(r_loss)(logits)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
+
+
+# -- the kernels per shard under a mesh --------------------------------------
+# pallas_call does not partition under GSPMD (on a TPU the lowering refuses:
+# "Mosaic kernels cannot be automatically partitioned"), so under a mesh
+# the ops run the kernels through these shard_map wrappers.
+
+def _mesh(axes):
+    from hetu_tpu.parallel import make_mesh
+    return make_mesh(axes)
+
+
+def test_sharded_flash_attention_matches_unsharded(rng):
+    from hetu_tpu.ops.pallas.flash_attention import sharded_flash_attention
+    mesh = _mesh({"dp": 2, "tp": 2})
+    q, k, v = _qkv(rng, B=2, H=2, S=128, D=32)
+    mask = jnp.where(jnp.asarray(rng.random((2, 1, 1, 128))) < 0.25,
+                     -1e9, 0.0).astype(jnp.float32)
+
+    def sharded(q, k, v):
+        return sharded_flash_attention(mesh, q, k, v, mask,
+                                       batch_axes=("dp",),
+                                       head_axes=("tp",))
+
+    want = flash_attention(q, k, v, mask=mask)
+    np.testing.assert_allclose(np.asarray(jax.jit(sharded)(q, k, v)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(sharded(*a) ** 2),
+                           argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.grad(lambda *a: jnp.sum(flash_attention(*a, mask=mask) ** 2),
+                   argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_sharded_softmax_ce_matches_unsharded(rng):
+    from hetu_tpu.ops.pallas.softmax_ce import (fused_softmax_ce_sparse,
+                                                sharded_softmax_ce_sparse)
+    mesh = _mesh({"dp": 4})
+    logits = jnp.asarray(rng.standard_normal((64, 1500)), jnp.float32)
+    labels = rng.integers(0, 1500, 64)
+    labels[::5] = -1
+    labels = jnp.asarray(labels, jnp.int32)
+
+    def sharded(lg):
+        return sharded_softmax_ce_sparse(mesh, lg, labels)
+
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(sharded)(logits)),
+        np.asarray(fused_softmax_ce_sparse(logits, labels)),
+        rtol=1e-6, atol=1e-6)
+    got = jax.jit(jax.grad(lambda lg: jnp.sum(sharded(lg) ** 2)))(logits)
+    want = jax.grad(lambda lg: jnp.sum(
+        fused_softmax_ce_sparse(lg, labels) ** 2))(logits)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_kernel_plans_follow_the_mesh(monkeypatch):
+    """What the ops decide under a mesh, as on a TPU: batch over dp and
+    heads over tp for attention, rows over dp for the loss, and the jnp
+    form, with its reason, where the layout does not fit."""
+    from hetu_tpu.ops import attention, losses
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((8, 4, 512, 64), jnp.bfloat16)
+    plan = lambda mesh: attention._flash_plan(q, q, q, None, 0.9, mesh)
+    assert plan(None) == (None, (), ())
+    assert plan(_mesh({"dp": 2, "tp": 2})) == (None, ("dp",), ("tp",))
+    assert plan(_mesh({"dp": 4})) == (None, ("dp",), ())
+    assert plan(_mesh({"dp": 1, "pp": 2}))[0] == "mesh_axis:pp=2"
+    assert plan(_mesh({"tp": 8}))[0] == "mesh_axis:tp=8"   # 4 heads
+    short = jax.ShapeDtypeStruct((8, 4, 128, 64), jnp.bfloat16)
+    assert attention._flash_plan(short, short, short, None, 1.0,
+                                 None)[0] == "seq<256"
+    y = jax.ShapeDtypeStruct((4096, 30522), jnp.bfloat16)
+    assert losses._ce_kernel_plan(y, -1, None) == (None, ())
+    assert losses._ce_kernel_plan(y, -1, _mesh({"dp": 4})) == (None,
+                                                               ("dp",))
+    assert losses._ce_kernel_plan(
+        y, -1, _mesh({"dp": 2, "tp": 2}))[0] == "mesh_axis:tp=2"
+    assert losses._ce_kernel_plan(y, 0, None)[0] == "class_dim_not_last"

@@ -412,6 +412,46 @@ def test_wedged_replica_quarantined_by_supervisor_threaded(served,
     fleet.stop()
 
 
+def test_first_call_of_a_program_is_not_a_wedge_under_the_derived_bound(
+        served, oracle):
+    """The first call of each program variant may compile, for tens of
+    seconds at published widths on a chip.  The derived wedge bound (no
+    ``wedge_timeout=``) waits for it; the same stall on a warm program
+    is still a wedge."""
+    prompts, base = oracle
+    fl = telemetry.get_flight()
+    was = fl.enabled
+    fl.enabled = True
+    try:
+        with _quiet():
+            # wedge_safety=1: the derived bound stays at the floor even
+            # after the stalled first request has inflated the TPOT EWMA
+            fleet = _fleet(served, n=1, wedge_floor=0.2, wedge_safety=1.0,
+                           breaker_base=0.01)
+            rep = fleet._replicas[0]
+            n0 = fl.incident_count("engine_wedge")
+            # stall the engine's FIRST decode call, as a compilation would
+            faults.wedge_engine(rep.engine, 0.6)
+            req = fleet.submit(prompts[0], 10)
+            for _ in range(40):
+                if req.finished:
+                    break
+                fleet.pump()
+            assert req.finished and req.engines == ["e0"]
+            assert fl.incident_count("engine_wedge") == n0
+            np.testing.assert_array_equal(req.result(), base[0])
+            # the decode program is warm now: the same stall is a wedge
+            time.sleep(0.25)          # past the grace after a cold call
+            faults.wedge_engine(rep.engine, 0.6)
+            req2 = fleet.submit(prompts[1], 10)
+            fleet.pump(2)
+            assert fl.incident_count("engine_wedge") == n0 + 1
+            fleet.wait([req2])
+    finally:
+        fl.enabled = was
+    fleet.stop()
+
+
 # -- latency bucket overrides ------------------------------------------------
 
 def test_latency_buckets_threaded_through_engine_and_fleet(served):

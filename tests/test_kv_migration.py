@@ -48,9 +48,7 @@ V = 64
 EKW = dict(n_slots=4, max_len=32, max_prompt_len=8, name="mig",
            paged=True, page_len=4)
 
-FP8 = pytest.param("fp8", marks=pytest.mark.skipif(
-    not quant.fp8_supported(),
-    reason="no float8_e4m3fn in this jax/ml_dtypes build"))
+FP8 = "fp8"
 
 
 @contextlib.contextmanager

@@ -77,6 +77,17 @@ def test_launch_dry_run():
     assert len(plan) == 1 and "job.py" in plan[0][1]
 
 
+def test_launch_refuses_several_workers_on_one_host(monkeypatch):
+    """A host's chips belong to one process: two workers on one host are
+    refused unless the launch environment pins the CPU platform."""
+    c = DistConfig(settings={"nodes": [
+        {"host": "localhost", "workers": 2, "chief": True}]})
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(ValueError, match="one process per host"):
+        launch(c, "job.py")
+    assert len(launch(c, "job.py", dry_run=True)) == 2   # the plan prints
+
+
 def test_launch_local_workers_share_state():
     from hetu_tpu.ps import PReduceScheduler
     sched = PReduceScheduler(4)

@@ -24,7 +24,6 @@ HETU_ROOT = os.path.join(os.path.dirname(__file__), "..", "hetu_tpu")
 # is genuinely uninteresting — NOT data-path error handling.
 ALLOWED = {
     # optional env bootstrap / telemetry
-    "launcher.py::initialize_from_env",     # optional coordinator probe
     "profiler.py::save",                    # best-effort trace dump
     "logger.py::__init__",                  # wandb backend optional
     "parallel/search.py::maybe_record",     # profile cache write optional
@@ -41,7 +40,6 @@ ALLOWED = {
     "ps/rpc.py::_heartbeat",                # probe loop; alive() reports
     "ps/rpc.py::close",
     # device/platform probes with safe fallbacks
-    "graph/executor.py::_should_donate",    # memory_stats optional
     "graph/executor.py::_dispatch",         # copy_to_host_async optional
     # best-effort file cleanup around ATOMIC writes (the replace/rename
     # is the correctness step; removing a leftover .tmp cannot fail it)

@@ -8,7 +8,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from hetu_tpu.platform import shard_map
+from jax import shard_map
 
 from hetu_tpu.ps import (PReduceScheduler, PartialReduce, partner_mask,
                          masked_mean_allreduce)

@@ -4,8 +4,7 @@ quantized serving plane built on it (ISSUE 16).
 Contracts pinned here:
 * ROUND-TRIP ERROR IS BOUNDED — quantize_blocks/dequantize_blocks err
   by at most ``roundtrip_bound(dtype, absmax)`` per element, for every
-  block size, for int8 everywhere and fp8 where the platform shim
-  (``platform.fp8_dtype``) reports support, on both the numpy (wire)
+  block size, for int8 and fp8, on both the numpy (wire)
   and jax (in-graph) namespaces;
 * zero blocks emit scale 0 and round-trip to EXACT zeros — freshly
   allocated quantized KV pages stay bitwise-zero through gather;
@@ -33,7 +32,6 @@ import pytest
 import jax.numpy as jnp
 
 import hetu_tpu as ht
-from hetu_tpu import platform
 from hetu_tpu.models import LlamaConfig, LlamaForCausalLM
 from hetu_tpu.ops import quant
 from hetu_tpu.serving import InferenceEngine, PagedKVCache
@@ -42,9 +40,7 @@ from hetu_tpu.serving.kv_cache import (QuantizedKVPool, gather_pages,
 
 V = 64
 
-FP8 = pytest.param("fp8", marks=pytest.mark.skipif(
-    not quant.fp8_supported(),
-    reason="no float8_e4m3fn in this jax/ml_dtypes build"))
+FP8 = "fp8"
 
 
 @pytest.fixture
@@ -125,18 +121,7 @@ def test_unknown_dtype_rejected():
 
 def test_code_bytes_per_element():
     assert quant.code_bytes_per_element("int8") == 1
-    if quant.fp8_supported():
-        assert quant.code_bytes_per_element("fp8") == 1
-    else:
-        with pytest.raises(ValueError, match="unavailable"):
-            quant.code_dtype("fp8")
-
-
-def test_fp8_platform_shim_consistent():
-    """quant.fp8_supported() and platform.fp8_dtype() agree — the shim
-    is the one switch every fp8 gate keys off."""
-    assert quant.fp8_supported() == (platform.fp8_dtype() is not None
-                                     or quant._fp8_np_dtype() is not None)
+    assert quant.code_bytes_per_element("fp8") == 1
 
 
 def test_int8_negation_roundtrips(rng):
@@ -251,15 +236,10 @@ def test_cow_guard_trips_on_quantized_shared_page():
     pool.assert_writable(dst, 2, 1)      # fork made it writable
 
 
-def test_fp8_pool_requires_platform_support():
-    if quant.fp8_supported():
-        pool = PagedKVCache(2, layers=2, kv_heads=2, page_len=4,
-                            head_dim=4, max_len=16, kv_dtype="fp8")
-        assert pool.k.codes.dtype == quant.code_dtype("fp8")
-    else:
-        with pytest.raises(ValueError, match="unavailable"):
-            PagedKVCache(2, layers=2, kv_heads=2, page_len=4,
-                         head_dim=4, max_len=16, kv_dtype="fp8")
+def test_fp8_pool_codes_are_e4m3():
+    pool = PagedKVCache(2, layers=2, kv_heads=2, page_len=4,
+                        head_dim=4, max_len=16, kv_dtype="fp8")
+    assert pool.k.codes.dtype == quant.code_dtype("fp8")
 
 
 # -- quantized serving: opt-in + divergence gate -----------------------------

@@ -10,7 +10,7 @@ import warnings
 import numpy as np
 import jax
 import jax.numpy as jnp
-from hetu_tpu.platform import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import hetu_tpu as ht
