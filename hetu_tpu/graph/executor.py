@@ -110,8 +110,16 @@ class SubExecutor:
             "steps)", labels=("subgraph",)).labels(subgraph=name)
         self._m_step_time = reg.histogram(
             "hetu_executor_step_seconds",
-            "Wall time of one run() call (feed prep + dispatch + guard "
-            "check; device completion is asynchronous)",
+            "Wall time of one run() call, the duration of its root span "
+            "(feed prep + dispatch + guard check, and the fetch when the "
+            "caller asks for numpy values; without it device completion "
+            "is asynchronous)",
+            labels=("subgraph",)).labels(subgraph=name)
+        self._m_h2d_bytes = reg.counter(
+            "hetu_executor_h2d_bytes_total",
+            "Bytes of feeds that arrived as host arrays and were "
+            "uploaded by the executor (size after the cast to the "
+            "placeholder's dtype)",
             labels=("subgraph",)).labels(subgraph=name)
         self._m_multi = reg.counter(
             "hetu_executor_run_steps_calls_total",
@@ -437,21 +445,32 @@ class SubExecutor:
             # dtype passes straight through (no host round-trip); host
             # batches get the one cast the slow path would do
             v = p.auto_feed(self.name)
-            if not isinstance(v, jax.Array) or (
-                    want is not None and v.dtype != want):
+            if not isinstance(v, jax.Array):
+                v = jnp.asarray(v, dtype=want)
+                self._m_h2d_bytes.inc(v.nbytes)
+            elif want is not None and v.dtype != want:
                 v = jnp.asarray(v, dtype=want)
             feeds[p.name] = v
         return feeds
 
+    def _root_span(self):
+        """The ``run`` root of one call: every phase span below is its
+        child and carries its key, ``<subgraph>:<global step>``; in a
+        ``jax.profiler`` capture it is a step marker."""
+        step = self.executor._global_step
+        return self._tr.span("run", key=f"{self.name}:{step}", step=step)
+
     def run(self, feed_dict=None, convert_to_numpy_ret_vals=False):
-        if not _telemetry.enabled():
+        if not self._tr.enabled:
             return self._run_impl(feed_dict, convert_to_numpy_ret_vals)
-        t0 = time.perf_counter()
+        root = self._root_span()
         try:
-            return self._run_impl(feed_dict, convert_to_numpy_ret_vals)
+            with root:
+                return self._run_impl(feed_dict,
+                                      convert_to_numpy_ret_vals)
         finally:
             self._m_steps.inc()
-            self._m_step_time.observe(time.perf_counter() - t0)
+            self._m_step_time.observe(root.dur)
 
     def _run_impl(self, feed_dict, convert_to_numpy_ret_vals):
         if self._jitted is None:
@@ -538,6 +557,7 @@ class SubExecutor:
         # cast feeds to declared dtypes (reference DataloaderOp feeds float32)
         all_device = True
         dtypes = {}
+        uploaded = 0
         for p in self.placeholders:
             v = feeds[p.name]
             want = np.dtype(p.dtype) if p.dtype is not None else None
@@ -546,12 +566,14 @@ class SubExecutor:
                 if p.name not in auto_names:
                     all_device = False
                 feeds[p.name] = jnp.asarray(v, dtype=p.dtype)
+                uploaded += feeds[p.name].nbytes
             elif want is not None and v.dtype != want:
                 # wrong-dtype DEVICE array: cast (device-side) instead of
                 # silently retracing a second program variant
                 if p.name not in auto_names:
                     all_device = False
                 feeds[p.name] = v.astype(want)
+        self._m_h2d_bytes.inc(uploaded)
         self._arm_fast(feed_dict, feeds, names, dtypes, auto_names,
                        all_device)
         return feeds, ps_ids
@@ -661,9 +683,13 @@ class SubExecutor:
             # may restore executor state or raise GuardTripped (abort)
             with self._tr.span("guard_check"):
                 guard.on_step(ex, guard_out[0], guard_out[1])
-        if convert_to_numpy_ret_vals:
-            vals = [None if v is None else np.asarray(v) for v in vals]
-        return vals
+        return self._fetch(vals) if convert_to_numpy_ret_vals else vals
+
+    def _fetch(self, vals):
+        """``fetch`` phase: the step's one synchronisation point — the
+        wait for the device and the copy of the results to the host."""
+        with self._tr.span("fetch"):
+            return [None if v is None else np.asarray(v) for v in vals]
 
     def run_steps(self, feed_dict, n, convert_to_numpy_ret_vals=False):
         """Run ``n`` consecutive training steps on the SAME feeds in ONE
@@ -683,6 +709,11 @@ class SubExecutor:
         body."""
         if n < 1:
             raise ValueError(f"run_steps needs n >= 1, got {n}")
+        with self._root_span():
+            return self._run_steps_impl(feed_dict, n,
+                                        convert_to_numpy_ret_vals)
+
+    def _run_steps_impl(self, feed_dict, n, convert_to_numpy_ret_vals):
         if self._jitted is None:
             with self._tr.span("compile"):
                 self._build()
@@ -720,6 +751,8 @@ class SubExecutor:
                         want is not None and v.dtype != want):
                     all_device = False
                     feeds[p.name] = jnp.asarray(v, dtype=p.dtype)
+                    if not isinstance(v, jax.Array):
+                        self._m_h2d_bytes.inc(feeds[p.name].nbytes)
             self._arm_fast(feed_dict or {}, feeds, names, dtypes, set(),
                            all_device)
         if self._multi_jitted is None:
@@ -839,9 +872,7 @@ class SubExecutor:
         self._runs += n
         if self._monitor_vars:
             self.check_monitors()
-        if convert_to_numpy_ret_vals:
-            vals = [None if v is None else np.asarray(v) for v in vals]
-        return vals
+        return self._fetch(vals) if convert_to_numpy_ret_vals else vals
 
     def check_monitors(self):
         """Warn on any tripped monitor counter (MLM overflow etc.)."""

@@ -230,9 +230,10 @@ def _sync_loss_gauges(reg=None, tr=None, rt=None, fl=None):
               ).set(fl.dropped)
 
 
-# span names recorded INSIDE SubExecutor.run()'s wall time; everything
-# else host-side (data_wait, prefetch_h2d) happens between run() calls
-_RUN_PHASES = ("h2d", "dispatch", "numerics", "guard_check")
+# children of SubExecutor.run()'s root span ``run``, whose duration is
+# the step histogram's wall time; everything else host-side (data_wait,
+# prefetch_h2d) happens between run() calls
+_RUN_PHASES = ("h2d", "dispatch", "numerics", "guard_check", "fetch")
 _LOOP_PHASES = ("data_wait", "prefetch_h2d")
 
 
@@ -242,10 +243,11 @@ def step_phase_report(registry=None, tracer=None):
 
     Returns ``{"steps", "wall_s_per_step", "phases": {...}}`` where the
     phases are ``data_wait`` / ``prefetch_h2d`` (between run() calls),
-    ``h2d`` / ``dispatch`` / ``guard_check`` (inside run()), and
-    ``device_and_wait`` — the residual of the run() wall time not
-    attributable to host work, i.e. time spent inside the jitted call
-    (device compute and runtime queue back-pressure).  The phases sum to
+    ``h2d`` / ``dispatch`` / ``guard_check`` / ``fetch`` (inside run();
+    ``fetch`` is the wait for the device and the copy of the results
+    when the caller asked for numpy values), and ``device_and_wait`` —
+    the remainder of the run() wall time no phase span covers (state
+    rebinding, monitor polls, PS pushes).  The phases sum to
     ``wall_s_per_step`` by construction, so the breakdown IS the
     decomposition of the wall step time (host_gap's numerator).
     ``{"steps": 0}`` when no instrumented step has run."""
@@ -312,14 +314,14 @@ def report(registry=None, tracer=None):
             "goodput": _goodput.report_block()}
 
 
-def chrome_trace(jax_trace_dir=None, **kw):
+def chrome_trace(jax_trace_dir=None):
     """The merged Chrome-trace view: the SpanTracer's host phase lanes
-    (optionally merged + step-aligned with a ``jax.profiler.trace``
-    capture, see :meth:`SpanTracer.chrome_trace`) PLUS the per-rid
-    request lifecycle lanes — one pid per engine, one tid per rid — on
-    the tracer's clock base, so one Perfetto load shows device ops,
-    host phases, and request lifecycles together."""
-    doc = _tracer.chrome_trace(jax_trace_dir=jax_trace_dir, **kw)
+    (optionally merged with a ``jax.profiler.trace`` capture, see
+    :meth:`SpanTracer.chrome_trace`) PLUS the per-rid request lifecycle
+    lanes — one pid per engine, one tid per rid — on the tracer's clock
+    base, so one Perfetto load shows device ops, host phases, and
+    request lifecycles together."""
+    doc = _tracer.chrome_trace(jax_trace_dir=jax_trace_dir)
     doc["traceEvents"].extend(
         _request_trace.chrome_rows(epoch=_tracer._epoch))
     return doc

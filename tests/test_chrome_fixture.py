@@ -1,12 +1,12 @@
 """chrome_trace against a REAL ``jax.profiler.trace`` capture.
 
-ROADMAP carry-over: ``chrome_trace(align_steps=True)`` was verified
-against a synthetic capture only.  ``tests/data/real_jax_capture.trace
-.json.gz`` is an actual (CPU) ``jax.profiler.trace`` artifact — real
-metadata lanes (``/host:CPU`` process, TFRT + python threads), real
-``PjitFunction(step)`` executions, real ``$file.py:123`` host-python
-frames — checked in so the merge/align/aggregate paths are pinned to
-the format jax actually writes, not to what the synthetic test assumed.
+``tests/data/real_jax_capture.trace.json.gz`` is an actual (CPU)
+``jax.profiler.trace`` artifact — real metadata lanes (``/host:CPU``
+process, TFRT + python threads), real ``PjitFunction(step)`` executions,
+real ``$file.py:123`` host-python frames — checked in so the
+merge/aggregate paths are pinned to the format jax actually writes.  One
+test takes a capture of its own: the program's spans are events OF the
+capture (``hetu:<name>``), so nothing has to be aligned afterwards.
 
 Also covers the PR 9 merge surface: ``telemetry.chrome_trace()`` lays
 per-rid request lanes next to the capture's device lanes and the
@@ -66,41 +66,65 @@ def test_fixture_is_a_real_capture():
     assert any(str(e.get("name", "")).startswith("$") for e in evs)
 
 
-def test_align_steps_against_real_capture(tmp_path):
-    cap = _install(tmp_path)
-    tr = SpanTracer(capacity=64, enabled=True)
-    # three host steps, each h2d -> dispatch, on the tracer's own clock
-    for k in range(3):
-        t = k * 0.010
-        tr._record("h2d", t, 0.001)
-        tr._record("dispatch", t + 0.002, 0.005)
-    doc = tr.chrome_trace(jax_trace_dir=cap, align_steps=True,
-                          device_step_regex=STEP_RE)
-    evs = _events(doc)
-    dev = sorted((e for e in evs if e.get("ph") == "X"
-                  and re.search(STEP_RE, str(e.get("name", "")))),
-                 key=lambda e: e["ts"])
-    host = [e for e in evs if e.get("ph") == "X"
-            and e.get("name") in ("h2d", "dispatch")]
-    assert len(dev) >= 3 and len(host) == 6
-    # every host span is annotated with its step and shifted onto the
-    # capture's clock base (tens of seconds of uptime, not ~0)
-    for e in host:
-        assert "aligned_step" in e["args"]
-        assert e["ts"] > 1e6
-    dispatches = [e for e in host if e["name"] == "dispatch"]
-    for k, e in enumerate(dispatches):
-        assert e["args"]["aligned_step"] == k
-        assert e["ts"] == pytest.approx(dev[k]["ts"])
-    # a span recorded before its step's anchor rides the PREVIOUS
-    # anchor's offset (documented looseness: offsets switch at the
-    # anchor span, and h2d leads its dispatch by 2ms in a 10ms step)
-    h2ds = [e for e in host if e["name"] == "h2d"]
-    assert h2ds[0]["ts"] == pytest.approx(dispatches[0]["ts"] - 2e3)
-    for k in (1, 2):
-        assert h2ds[k]["args"]["aligned_step"] == k - 1
-        assert h2ds[k]["ts"] == pytest.approx(
-            dispatches[k - 1]["ts"] + 8e3)
+def test_program_spans_are_in_a_real_capture(tmp_path):
+    """The spans need no aligning: while ``jax.profiler`` traces, every
+    enabled span IS an event of the capture, ``hetu:<name>``, on the
+    profiler's clock.  Three executor steps give three ``hetu:run`` step
+    markers (``_r``, ``step_num`` = the global step) on the thread that
+    called ``run``, each holding its ``hetu:h2d`` / ``hetu:dispatch`` /
+    ``hetu:fetch``, beside the executions of the jitted step."""
+    import glob
+
+    import jax
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    import hetu_tpu as ht
+    from hetu_tpu.layers import Linear
+
+    with ht.name_scope():
+        x = ht.placeholder_op("cap_x", (8, 4))
+        loss = ht.reduce_mean_op(Linear(4, 3)(x))
+    ex = ht.Executor({"train": [loss, ht.SGDOptimizer(0.1).minimize(loss)]})
+    feed = {x: np.ones((8, 4), np.float32)}
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    tracer.enabled = True
+    try:
+        ex.run("train", feed_dict=feed)          # compiled outside
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(3):
+                ex.run("train", feed_dict=feed,
+                       convert_to_numpy_ret_vals=True)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        tracer.enabled = False
+        ring = tracer.spans()
+        tracer.clear()
+    [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats)) for e in line.events
+              if e.name.startswith("hetu:")]
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines]
+    [events] = [ev for ev in lines if ev]        # one host thread has them
+    roots = sorted(e for e in events if e[0] == "hetu:run")
+    assert [e[3]["step_num"] for e in roots] == [1, 2, 3]
+    assert all(e[3]["_r"] == 1 for e in roots)
+    for name in ("hetu:h2d", "hetu:dispatch", "hetu:fetch"):
+        kids = sorted(e for e in events if e[0] == name)
+        assert len(kids) == 3
+        for (_, lo, hi, _), (_, k_lo, k_hi, _) in zip(roots, kids):
+            assert lo <= k_lo and k_hi <= hi     # the profiler's clock
+    assert {e[0] for e in events} == {"hetu:run", "hetu:h2d",
+                                      "hetu:dispatch", "hetu:fetch"}
+    # the ring saw the same three steps on the host clock
+    assert [r[4] for r in ring if r[0] == "run"] == [
+        "train:0", "train:1", "train:2", "train:3"]
 
 
 def test_unaligned_merge_keeps_separate_clock_bases(tmp_path):
