@@ -130,6 +130,12 @@ class SubExecutor:
             "Step-program (re)traces — >1 per subgraph after warmup "
             "means a shape/dtype change recompiled the step",
             labels=("subgraph",)).labels(subgraph=name)
+        self._m_donates = reg.gauge(
+            "hetu_executor_donates_state",
+            "1 when the subgraph's step program was built to take params "
+            "and optimiser state as donated arguments (updated in place, "
+            "no fresh output buffers a step), 0 when it leaves them alive",
+            labels=("subgraph",)).labels(subgraph=name)
         self._tr = _telemetry.get_tracer()
 
     def ps_synchronize(self):
@@ -149,41 +155,46 @@ class SubExecutor:
             raise first_error
 
     def _should_donate(self):
-        """Donate params/opt-state only under real memory pressure.
+        """Whether the step program takes params and optimiser state as
+        donated arguments (argnums 0, 1 beside the step counter's 4).
 
-        Donation halves peak parameter memory, but on current TPU XLA it
-        also makes the compiler stage the param-update fusions in scoped
-        memory (S(1)) and COPY every updated parameter back to its HBM
-        buffer — measured 1.42 -> 2.18 ms/step on the W&D bench shapes
-        (+13% HBM bytes), and the same pattern taxes every stage.  When
-        the state comfortably fits HBM the copies buy nothing, so: donate
-        iff params+opt bytes exceed a quarter of device memory (both
-        copies plus activations still fit below ~50%), or the user forces
-        it with ``Executor(..., donate_params=True/False)``.
+        Rule: a training subgraph donates; an evaluation subgraph never
+        does (its caller goes on reading the weights it was given).
+        ``Executor(..., donate_params=True/False)`` overrides the rule for
+        training subgraphs.
+
+        A step that returns its state in fresh buffers holds the host in
+        PJRT's output allocation before every launch, 20-35 us a MB, with
+        the device idle; donation lets the program update the state in
+        place.  Its price is on the device: XLA stages the update fusions
+        in scoped memory and copies each updated parameter back.  Measured
+        on a v5e, donation off -> on (PERF.md, PR 25):
+
+        * BERT-base, AdamW, bf16 over f32 masters, batch 64 x 512, loss
+          fetched every step (620 leaves, 1.32 GB): one chip 134.7 k ->
+          153.2 k tokens/s (+13.7%), step program 207.6 -> 208.8 ms;
+          DataParallel(4) 482.7 k -> 562.6 k (+16.6%), step program
+          219.1 -> 221.0 ms, XLA's peak a chip 15.31 -> 15.68 GB.
+        * W&D, Adam, batch 128, 337,000 rows x 16 packed (34 leaves,
+          68 MB): 296 -> 833 steps/s with no fetch (the host runs
+          ahead), 264 -> 623 with the loss fetched every step; device
+          time a step 326 -> 369 us in both.
+
+        No measured model loses by wall clock, and state that fills more
+        of HBM or a lazy-sparse table (whose scatter needs the alias to
+        stay rowwise) only gains more, so the rule has no threshold.
         """
+        if not self.training:
+            return False
         cfg = self.executor.config.get("donate_params", "auto")
-        if cfg != "auto":
-            return bool(cfg)
-        ex = self.executor
-        # lazy-sparse (scatter) param updates NEED aliasing: a functional
-        # .at[ids].set over a non-donated table forces XLA to copy the
-        # whole [V, H] buffer first, turning the rowwise update back into
-        # a full-table pass (measured 2.8 ms vs 1.0 ms on the W&D lazy
-        # path).  The S(1) copy-back tax donation carries only hits the
-        # DENSE params, which are small whenever someone bothered with a
-        # sparse table.
-        if any(getattr(op, "sparse", None) for op in self.opt_ops):
-            return True
-        state_bytes = sum(
-            getattr(v, "nbytes", 0)
-            for v in jax.tree_util.tree_leaves((ex.params, ex.opt_state)))
-        from ..platform import device_memory_limit
-        limit = device_memory_limit()
-        # compare against ONE device's HBM: replicated state (plain DP)
-        # costs its full global size on EVERY chip, and for sharded state
-        # the global total over-counts per-device pressure — which only
-        # errs toward donating, the memory-safe direction.
-        return state_bytes > 0.25 * limit
+        return cfg == "auto" or bool(cfg)
+
+    def _donate_argnums(self):
+        """``donate_argnums`` of a step program about to be built, and
+        the gauge that says which it was."""
+        donates = self._should_donate()
+        self._m_donates.set(int(donates))
+        return (0, 1, 4) if donates else (4,)
 
     def _build(self):
         placeholders = self.placeholders
@@ -378,8 +389,7 @@ class SubExecutor:
             return vals, new_params, new_opt_state, step + 1
 
         self._step_fn = step_fn   # run_steps builds its scan over this
-        donate = ((0, 1, 4) if self.training and self._should_donate()
-                  else (4,))
+        donate = self._donate_argnums()
         # single-step program variants: on a sampled cadence the
         # steady-state program carries NO stats (the stats reductions
         # would otherwise pin the pre-update params live across the
@@ -757,8 +767,7 @@ class SubExecutor:
                            all_device)
         if self._multi_jitted is None:
             step_fn = self._step_fn
-            donate = ((0, 1, 4) if self.training
-                      and self._should_donate() else (4,))
+            donate = self._donate_argnums()
             # guard state at build time matches _build's: attach/detach
             # invalidate both compiled programs together
             guarded = ex.config.get("step_guard") is not None

@@ -583,3 +583,138 @@ def test_profile_returns_consistent_pair():
     dt, aggs = out
     assert isinstance(dt, float) and dt > 0
     assert aggs is None
+
+
+# -- donation of the training state (SubExecutor._should_donate) -----------
+
+def _donation_problem(tag, **executor_kw):
+    """A small dense model with a ``train`` and a ``validate`` subgraph."""
+    X, Y = _toy_problem(11)
+    x = ht.placeholder_op(f"dn_x_{tag}", X.shape)
+    y_ = ht.placeholder_op(f"dn_y_{tag}", Y.shape)
+    w = ht.Variable(f"dn_w_{tag}", shape=(10, 1),
+                    initializer=ht.init.xavier_normal())
+    b = ht.Variable(f"dn_b_{tag}", shape=(1,), initializer=ht.init.zeros())
+    loss = ht.reduce_mean_op(ht.reduce_sum_op(
+        ht.pow_op(ht.matmul_op(x, w) + b - y_, exponent=2.0), axes=1))
+    train_op = ht.AdamOptimizer(learning_rate=0.05).minimize(loss)
+    ex = ht.Executor({"train": [loss, train_op], "validate": [loss]},
+                     seed=3, **executor_kw)
+    return ex, {x: X, y_: Y}
+
+
+def _state_leaves(ex):
+    import jax
+    return jax.tree_util.tree_leaves((ex.params, ex.opt_state))
+
+
+def _donates_gauge(subgraph):
+    from hetu_tpu import telemetry
+    samples = telemetry.get_registry().snapshot()[
+        "hetu_executor_donates_state"]["samples"]
+    return {s["labels"]["subgraph"]: s["value"]
+            for s in samples}.get(subgraph)
+
+
+@pytest.fixture
+def tel():
+    from hetu_tpu import telemetry
+    telemetry.get_registry().reset()
+    telemetry.enable()
+    yield telemetry
+    telemetry.disable()
+
+
+@pytest.mark.parametrize("entry,subgraph,config,donated", [
+    ("run", "train", {}, True),
+    ("run_steps", "train", {}, True),
+    ("run", "validate", {}, False),
+    ("run", "train", {"donate_params": False}, False),
+    ("run_steps", "train", {"donate_params": False}, False),
+    ("run", "validate", {"donate_params": True}, False),
+])
+def test_training_state_donation_rule(tel, entry, subgraph, config, donated):
+    """Under ``auto`` a training subgraph hands its state to the step
+    program and an evaluation subgraph leaves it alive;
+    ``donate_params`` overrides the rule for training subgraphs only."""
+    ex, feed = _donation_problem(f"{entry}_{subgraph}_{len(config)}",
+                                 **config)
+    before = _state_leaves(ex)
+    assert before and not any(v.is_deleted() for v in before)
+    if entry == "run":
+        ex.run(subgraph, feed_dict=feed)
+    else:
+        ex.run_steps(subgraph, feed, 3)
+    assert [v.is_deleted() for v in before] == [donated] * len(before)
+    assert _donates_gauge(subgraph) == int(donated)
+    # the executor's own bindings are the step's outputs, never the
+    # donated inputs
+    assert not any(v.is_deleted() for v in _state_leaves(ex))
+    out = ex.run("validate", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    assert np.isfinite(out[0])
+
+
+def test_data_parallel_donates_every_leaf_without_warning(tel):
+    """On a mesh every donated leaf must alias an output (the
+    ``out_shardings`` pin in ``_build``): a leaf XLA cannot reuse makes
+    jax warn "Some donated buffers were not usable" at compile time."""
+    import warnings
+    from hetu_tpu.parallel import DataParallel
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        ex, feed = _donation_problem("dp", dist_strategy=DataParallel(
+            ndev=8))
+        ex.run("train", feed_dict=feed)   # _commit_state places the state
+        placed = _state_leaves(ex)
+        ex.run("train", feed_dict=feed)
+        ex.run_steps("train", feed, 2)
+    assert not [str(w.message) for w in caught
+                if "donated buffers" in str(w.message)]
+    assert placed and all(v.is_deleted() for v in placed)
+    assert _donates_gauge("train") == 1
+
+
+def test_state_dict_survives_later_donation():
+    """``state_dict`` hands out host arrays; the device buffers they were
+    read from are donated by the next step.  On the CPU ``np.asarray`` of
+    a device array can be a zero-copy view, so the saved values must still
+    be that step's after more steps have run, and loading them must bring
+    the loss of that step back."""
+    ex, feed = _donation_problem("sd")
+    for _ in range(3):
+        ex.run("train", feed_dict=feed)
+    saved = ex.state_dict()
+    frozen = {k: np.array(v, copy=True) for k, v in saved["params"].items()}
+    loss_then = ex.run("validate", feed_dict=feed,
+                       convert_to_numpy_ret_vals=True)[0]
+    after = [ex.run("train", feed_dict=feed,
+                    convert_to_numpy_ret_vals=True)[0] for _ in range(3)]
+    for k, v in frozen.items():
+        np.testing.assert_array_equal(v, saved["params"][k])
+        assert not np.array_equal(v, np.asarray(ex.params[k]))
+    ex.load_state_dict(saved)
+    assert ex.run("validate", feed_dict=feed,
+                  convert_to_numpy_ret_vals=True)[0] == loss_then
+    again = [ex.run("train", feed_dict=feed,
+                    convert_to_numpy_ret_vals=True)[0] for _ in range(3)]
+    np.testing.assert_array_equal(after, again)
+
+
+def test_guard_skip_keeps_donated_state_bitwise():
+    """The in-graph ``skip`` select reads the old state inside the program
+    that was given it as donated arguments: a NaN batch must leave params
+    and optimiser state bit for bit."""
+    from hetu_tpu.resilience import StepGuard
+    guard = StepGuard(policy="skip", defer=False)
+    ex, feed = _donation_problem("gs", step_guard=guard)
+    for _ in range(3):
+        ex.run("train", feed_dict=feed)
+    held = _state_leaves(ex)
+    before = [np.array(v, copy=True) for v in held]
+    bad = {k: np.array(v, copy=True) for k, v in feed.items()}
+    next(iter(bad.values()))[0, 0] = np.nan
+    ex.run("train", feed_dict=bad)
+    assert all(v.is_deleted() for v in held)    # donation was on
+    assert guard.stats["skipped"] == 1
+    for want, got in zip(before, _state_leaves(ex)):
+        np.testing.assert_array_equal(want, np.asarray(got))
