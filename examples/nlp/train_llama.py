@@ -8,6 +8,10 @@ Covers the graph-API training path with optional parallelism flags:
   --hf-import    load a transformers Llama checkpoint by path
 
 Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
+       python examples/nlp/train_llama.py --model olmoe-1b-7b --layers 1 \
+           --seq-len 4096 --batch-size 2     (OLMoE's block as published:
+           QK-norm, top-8 of 64 dropless experts, balance and z losses; one
+           layer and its 206 M-parameter embedding and head fill a v5e)
 """
 
 import os
@@ -22,6 +26,7 @@ import numpy as np
 import jax.numpy as jnp
 
 import hetu_tpu as ht
+from hetu_tpu.layers.moe import record_moe_load
 from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              load_hf_llama_weights)
 
@@ -75,7 +80,10 @@ def main():
     elif args.tp > 1 or args.dp > 1:
         from hetu_tpu.parallel import MegatronLM
         kwargs.update(dist_strategy=MegatronLM(dp=args.dp, tp=args.tp))
-    ex = ht.Executor({"train": [loss, opt.minimize(loss)]}, **kwargs)
+    # an MoE model's per-expert load rides the loss's fetch: [2, E] a layer
+    loads = model.moe_loads() if c.num_experts and not args.pp else []
+    ex = ht.Executor({"train": [loss, opt.minimize(loss)] + loads},
+                     **kwargs)
 
     if args.hf_import:
         import transformers
@@ -89,6 +97,8 @@ def main():
         feed = {ids: tok[:, :-1], labels: tok[:, 1:]}
         out = ex.run("train", feed_dict=feed,
                      convert_to_numpy_ret_vals=True)
+        for i, load in enumerate(out[2:]):
+            record_moe_load(f"layer{i}", load)
         if step % 5 == 0 or step == args.steps - 1:
             print(f"step {step:4d}  loss {out[0]:.4f}")
 

@@ -34,6 +34,22 @@ from .trace import TraceContext, evaluate
 from .. import telemetry as _telemetry
 
 
+def _changed_state_only(step_fn):
+    """``step_fn`` returning, of params and optimiser state, only the
+    entries it replaced (found by identity while tracing: an untouched
+    entry is the argument itself)."""
+    def changed(params, opt_state, feeds, base_key, step):
+        vals, new_params, new_opt_state, step = step_fn(
+            params, opt_state, feeds, base_key, step)
+        return (vals,
+                {k: v for k, v in new_params.items()
+                 if v is not params.get(k)},
+                {k: v for k, v in new_opt_state.items()
+                 if v is not opt_state.get(k)},
+                step)
+    return changed
+
+
 class SubExecutor:
     """One named subgraph compiled into a single jitted step function."""
 
@@ -406,6 +422,20 @@ class SubExecutor:
                 stats_fn = functools.partial(step_fn, _stats="full")
         in_shardings = self.executor._input_shardings(self)
         self._jitted_stats = None
+        # A program that does not take its state donated would return
+        # every leaf it only read as a fresh copy (jit does not forward an
+        # input to an output): a second whole state on the device for the
+        # length of the call, which a state that fills half of HBM cannot
+        # afford (OLMoE's validate program: 7.5 GB of state, PR 26).  It
+        # returns the leaves it changed and ``_dispatch`` merges them.
+        # Under a mesh the whole state still comes back: out_shardings are
+        # fixed before the trace says which leaves change.
+        self._returns_changed_only = (donate == (4,)
+                                      and in_shardings is None)
+        if self._returns_changed_only:
+            single = _changed_state_only(single)
+            if stats_fn is not None:
+                stats_fn = _changed_state_only(stats_fn)
         if in_shardings is not None:
             self.executor._commit_state(in_shardings)
             # pin updated params/opt-state to their INPUT shardings: with
@@ -632,6 +662,9 @@ class SubExecutor:
             vals, new_params, new_opt_state, ex._step_arr = fn(
                 ex.params, ex.opt_state, feeds, ex._base_key,
                 ex._step_arr)
+        if self._returns_changed_only:
+            new_params = {**ex.params, **new_params}
+            new_opt_state = {**ex.opt_state, **new_opt_state}
         ex.params = new_params
         ex.opt_state = new_opt_state
         # guard sentinel scalars ride as the two trailing hidden outputs

@@ -11,13 +11,14 @@ tools/Hetu-Galvatron/galvatron/models/llama, models/baichuan): ``rope_theta``
 applies rotary embeddings to q/k before the attention product; ``alibi``
 adds the per-head linear bias instead; ``num_kv_heads`` < num_heads gives
 grouped-query attention (K/V projected to the smaller head count and
-broadcast back at the attention einsum).
+broadcast back at the attention einsum); ``qk_norm`` applies an RMSNorm to
+the projected queries and keys (OLMoE).
 """
 
 from __future__ import annotations
 
 from .base import BaseLayer, fresh_name
-from .common import Linear
+from .common import Linear, RMSNorm
 from ..ops import array_reshape_op, transpose_op, head_split_linear_op
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.rotary import rotary_embedding_op, repeat_kv_op, alibi_bias_op
@@ -27,7 +28,8 @@ class MultiHeadAttention(BaseLayer):
     def __init__(self, hidden_size, num_heads, sequence_length=None,
                  dropout_rate=0.0, causal_mask=False, num_kv_heads=None,
                  rope_theta=None, alibi=False, bias=True,
-                 fused_head_projection=False, name=None):
+                 fused_head_projection=False, qk_norm=False,
+                 qk_norm_eps=1e-5, name=None):
         assert hidden_size % num_heads == 0
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
@@ -51,6 +53,16 @@ class MultiHeadAttention(BaseLayer):
                              name=f"{name}_v")
         self.out_proj = Linear(hidden_size, hidden_size, bias=bias,
                                name=f"{name}_out")
+        # QK-norm (OLMoE, OLMo 2): an RMSNorm over the whole projected
+        # width of q and of k, before the head split and the rotation
+        self.q_norm = self.k_norm = None
+        if qk_norm:
+            assert not fused_head_projection, (
+                "qk_norm normalises the projection before the head split")
+            self.q_norm = RMSNorm(hidden_size, eps=qk_norm_eps,
+                                  name=f"{name}_q_norm")
+            self.k_norm = RMSNorm(kv_dim, eps=qk_norm_eps,
+                                  name=f"{name}_k_norm")
 
     def _split_heads(self, x, seq_len, n_heads):
         # [B, S, H] (or [B*S, H]) -> [B, heads, S, d]
@@ -58,7 +70,7 @@ class MultiHeadAttention(BaseLayer):
             x, output_shape=(-1, seq_len, n_heads, self.head_dim))
         return transpose_op(x, perm=(0, 2, 1, 3))
 
-    def _project_heads(self, x, proj, seq_len, n_heads):
+    def _project_heads(self, x, proj, seq_len, n_heads, norm=None):
         """Projection + head split.  Inference-only graphs use the fused
         einsum (head_split_linear_op: the head transpose rides the
         matmul epilogue — ~0.25 ms/layer saved at GPT-2.7B fwd shapes);
@@ -70,7 +82,9 @@ class MultiHeadAttention(BaseLayer):
                 x, proj.weight,
                 *([] if proj.bias is None else [proj.bias]),
                 seq_len=seq_len, n_heads=n_heads, head_dim=self.head_dim)
-        return self._split_heads(proj(x), seq_len, n_heads)
+        x = proj(x)
+        return self._split_heads(x if norm is None else norm(x), seq_len,
+                                 n_heads)
 
     def __call__(self, query, key, value, attention_mask=None, seq_len=None,
                  kv_seq_len=None):
@@ -92,9 +106,9 @@ class MultiHeadAttention(BaseLayer):
                 "non-rotary, non-alibi cross-attention")
         kv_seq_len = kv_seq_len or seq_len
         q = self._project_heads(query, self.q_proj, seq_len,
-                                self.num_heads)
+                                self.num_heads, self.q_norm)
         k = self._project_heads(key, self.k_proj, kv_seq_len,
-                                self.num_kv_heads)
+                                self.num_kv_heads, self.k_norm)
         v = self._project_heads(value, self.v_proj, kv_seq_len,
                                 self.num_kv_heads)
         if self.rope_theta is not None:

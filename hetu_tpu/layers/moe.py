@@ -38,20 +38,37 @@ def _orthogonal_rows(rng, rows, cols, gain=0.1):
 
 
 class TopKGate(BaseLayer):
-    """GShard top-1/top-2 gate weights (reference TopGate.py).  Routing
-    hyper-parameters (k, capacity) live on the MoELayer, the single source
-    of truth."""
+    """Top-k softmax gate weights for any ``k <= E`` (reference TopGate.py
+    is the GShard top-1/top-2 case).  ``renorm`` rescales a token's kept
+    gates to sum to 1 (GShard top-2, Mixtral); False uses the softmax
+    weights as they are (OLMoE).  Routing hyper-parameters (k, capacity)
+    live on the MoELayer, the single source of truth."""
 
-    def __init__(self, hidden_size, num_experts, name=None):
+    def __init__(self, hidden_size, num_experts, renorm=True, name=None):
         name = fresh_name(name or "gate")
+        self.renorm = renorm
         self.wg = VariableOp(f"{name}_w", (hidden_size, num_experts),
                              init.xavier_uniform())
 
     def gating(self, tokens, wg, ids, k, capacity):
-        return top_k_gating(tokens @ wg, k, capacity)
+        return top_k_gating(tokens @ wg, k, capacity,
+                            second_renorm=self.renorm)
 
     def gating_choices(self, tokens, wg, ids, k, capacity):
-        return top_k_gating_choices(tokens @ wg, k, capacity)
+        return top_k_gating_choices(tokens @ wg, k, capacity,
+                                    second_renorm=self.renorm)
+
+    def route(self, tokens, wg, k):
+        """Dropless routing: ``(logits, idx, gate, probs)``, the logits and
+        everything after them in f32 at full matmul precision, so that which
+        experts a token takes does not depend on the compute type."""
+        import jax
+        import jax.numpy as jnp
+        from ..ops.moe import top_k_route
+        logits = jnp.matmul(tokens.astype(jnp.float32),
+                            wg.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        return (logits,) + top_k_route(logits, k, renorm=self.renorm)
 
     def aux(self, tokens, wg, ids, k):
         return top_k_balance_aux(tokens @ wg)
@@ -141,7 +158,7 @@ class _MoEOp(Op):
 
     def __init__(self, x, gate, w1, b1, w2, b2, num_experts, capacity_factor,
                  k, ep_axis=None, ids=None, sparse=True, w3=None,
-                 name=None):
+                 load_var=None, name=None):
         # swiglu experts are biasless: b1/b2 are None and stay out of the
         # graph entirely (no dead optimizer state / checkpoint entries)
         inputs = [x, w1, w2] if b1 is None else [x, w1, b1, w2, b2]
@@ -152,6 +169,10 @@ class _MoEOp(Op):
             inputs.append(gate.wg)
         if ids is not None:
             inputs.append(ids)
+        if load_var is not None:
+            # last input, read by nobody: it puts the variable into every
+            # program that runs this op, so its update has a state to go to
+            inputs.append(load_var)
         super().__init__(*inputs, name=name or "moe")
         self.gate = gate
         self.num_experts = num_experts
@@ -161,6 +182,15 @@ class _MoEOp(Op):
         self.sparse = sparse
         self.has_w3 = w3 is not None
         self.has_ids = ids is not None
+        self.load_var = load_var
+        if capacity_factor is None:
+            assert w3 is not None and hasattr(gate, "route"), (
+                "dropless routing (capacity_factor=None) runs swiglu "
+                "experts behind a TopKGate")
+
+    @property
+    def dropless(self):
+        return self.capacity_factor is None
 
     def _unpack(self, input_vals):
         """Input layout shared with MoEAuxLossOp (same inputs list)."""
@@ -180,16 +210,43 @@ class _MoEOp(Op):
         return max(int(np.ceil(self.capacity_factor * T * self.k
                                / self.num_experts)), 1)
 
+    def routing(self, x, wg, ctx):
+        """The dropless routing of ``x``, traced once per trace: the loss
+        terms (``MoEAuxLossOp``, ``MoEZLossOp``) read what the layer itself
+        routed by.  Keyed by the identity of ``x``, so a node evaluated in
+        another trace (a remat body, a second program) routes afresh."""
+        import jax
+        memo = ctx.__dict__.setdefault("_moe_routing", {})
+        if self.id not in memo or memo[self.id][0] is not x:
+            with jax.named_scope("hetu_moe_route"):
+                memo[self.id] = (x, self.gate.route(
+                    x.reshape(-1, x.shape[-1]), wg, self.k))
+        return memo[self.id][1]
+
+    def _record_load(self, ctx, routed, kept):
+        """Hand the per-expert pair counts of this step to the executor's
+        state (``MoELayer.load()`` fetches them beside the loss)."""
+        import jax.numpy as jnp
+        if self.load_var is not None:
+            ctx.record_update(self.load_var, jnp.stack(
+                [routed, kept]).astype(jnp.float32))
+
     def _compute(self, input_vals, ctx):
         import jax
         import jax.numpy as jnp
-        from ..ops.moe import sparse_dispatch, sparse_combine
+        from ..ops.moe import sparse_dispatch, sparse_combine, dropless_moe
         x, w1, b1, w2, b2, w3, wg, ids = self._unpack(input_vals)
 
         orig_shape = x.shape
         h = x.shape[-1]
         tokens = x.reshape(-1, h)
         T = tokens.shape[0]
+        if self.dropless:
+            _, idx, gate, _ = self.routing(x, wg, ctx)
+            y, load = dropless_moe(tokens, idx, gate, w1, w3, w2,
+                                   mesh=ctx.mesh)
+            self._record_load(ctx, load, load)
+            return y.reshape(orig_shape)
         C = self._capacity(T)
 
         # scatter-style dispatch (reference LayoutTransform.cu) when the
@@ -209,10 +266,22 @@ class _MoEOp(Op):
             expert_in = sparse_dispatch(tokens, choices,
                                         self.num_experts, C,
                                         use_pallas=pallas_ok)
+            if self.load_var is not None:
+                hot = [jax.nn.one_hot(i, self.num_experts,
+                                      dtype=jnp.float32)
+                       for i, _, _ in choices]
+                self._record_load(
+                    ctx, sum(o.sum(0) for o in hot),
+                    sum((o * (p < C)[:, None]).sum(0)
+                        for o, (_, _, p) in zip(hot, choices)))
         else:
             dispatch, combine, aux = self.gate.gating(tokens, wg, ids,
                                                       self.k, C)
             expert_in = jnp.einsum("tec,th->ech", dispatch, tokens)
+            # a gate without a choices form does not say what it routed
+            # beyond capacity: only the kept pairs are known
+            kept = jnp.sum(dispatch, axis=(0, 2))
+            self._record_load(ctx, kept, kept)
         if self.ep_axis is not None and ctx.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             expert_in = jax.lax.with_sharding_constraint(
@@ -252,6 +321,13 @@ class MoEAuxLossOp(Op):
         # recompute (in the same jitted program, CSE merges it anyway)
         import jax.numpy as jnp
         x, _, _, _, _, _, wg, ids = self.moe._unpack(input_vals)
+        if self.moe.dropless:
+            # balance loss over top-k counts, from the routing the layer
+            # itself ran by (ops/moe.py load_balancing_loss)
+            from ..ops.moe import load_balancing_loss, expert_load
+            _, idx, _, probs = self.moe.routing(x, wg, ctx)
+            return load_balancing_loss(
+                probs, expert_load(idx, self.moe.num_experts))
         if not getattr(self.moe.gate, "has_aux", True):
             # hash/balance gates have identically-zero aux: skip the
             # dispatch recompute entirely
@@ -270,18 +346,74 @@ class MoEAuxLossOp(Op):
         return jnp.asarray(aux, x.dtype)
 
 
+class MoEZLossOp(Op):
+    """Router z-loss ``mean_t logsumexp(logits_t)^2`` of a dropless MoE op,
+    in f32 (ST-MoE; OLMoE trains with 0.001 of it a layer)."""
+
+    def __init__(self, moe_op):
+        assert moe_op.dropless, "the z-loss reads the dropless routing"
+        super().__init__(*moe_op.inputs, name=f"{moe_op.name}_zloss")
+        self.moe = moe_op
+
+    def _compute(self, input_vals, ctx):
+        from ..ops.moe import router_z_loss
+        x, _, _, _, _, _, wg, _ = self.moe._unpack(input_vals)
+        return router_z_loss(self.moe.routing(x, wg, ctx)[0])
+
+
+class MoEChosenOp(Op):
+    """``[T, k]`` int32: the experts each token of a dropless MoE op takes,
+    largest weight first (for checks against a reference's routing)."""
+
+    def __init__(self, moe_op):
+        assert moe_op.dropless
+        super().__init__(*moe_op.inputs, name=f"{moe_op.name}_chosen")
+        self.moe = moe_op
+
+    def _compute(self, input_vals, ctx):
+        x, _, _, _, _, _, wg, _ = self.moe._unpack(input_vals)
+        return self.moe.routing(x, wg, ctx)[1]
+
+
+class MoELoadOp(Op):
+    """``[2, E]`` f32: the (token, choice) pairs routed to each expert and
+    those of them the layer computed (all, on the dropless path), as the
+    MoE op of THIS step recorded them.  The MoE op runs inside the
+    gradient's vjp, whose interior the step's other fetches cannot see; it
+    hands the counts out as a state update and this node reads that update,
+    so fetching it beside the loss adds 512 bytes to the step's one
+    device-to-host copy and no second pass.  In a program where the MoE op
+    has not run by the time this node is evaluated it gives the state's
+    value, the last step's counts."""
+
+    def __init__(self, load_var):
+        super().__init__(load_var, name=f"{load_var.name}_read")
+        self.var = load_var
+
+    def _compute(self, input_vals, ctx):
+        return ctx.updates.get(self.var, input_vals[0])
+
+
 class MoELayer(BaseLayer):
-    """Expert-parallel FFN block (drop-in for TransformerFFN)."""
+    """Expert-parallel FFN block (drop-in for TransformerFFN).
+
+    ``capacity_factor=None`` is the dropless path (ops/moe.py
+    ``dropless_moe``): every (token, choice) pair is computed, by grouped
+    products over the pairs sorted by expert; it needs the ``top`` gate and
+    swiglu experts.  ``renorm_topk`` is the gate's ``renorm``.
+    ``track_load`` adds a ``[2, E]`` state variable of per-expert pair
+    counts (routed, kept) that ``load()`` fetches."""
 
     def __init__(self, hidden_size, intermediate_size, num_experts, k=2,
                  capacity_factor=1.25, gate="top", ep_axis=None,
                  num_groups=None, sparse=True, expert_act="gelu",
-                 name=None):
+                 renorm_topk=True, track_load=False, name=None):
         name = fresh_name(name or "moe")
         if isinstance(gate, BaseLayer):
             self.gate = gate                      # caller-built gate
         elif gate == "top":
-            self.gate = TopKGate(hidden_size, num_experts, name=name)
+            self.gate = TopKGate(hidden_size, num_experts,
+                                 renorm=renorm_topk, name=name)
         elif gate == "hash":
             self.gate = HashGate(num_experts)
         elif gate == "ktop1":
@@ -321,6 +453,9 @@ class MoELayer(BaseLayer):
         # exactness oracle); sparse routing needs a gate with a choices
         # form and is the default memory-safe path
         self.sparse = sparse
+        self.load_var = VariableOp(
+            f"{name}_load", (2, num_experts), init.zeros(),
+            trainable=False) if track_load else None
         if ep_axis is not None:
             ep_vars = [v for v in (self.w1, self.b1, self.w2, self.b2,
                                    self.w3) if v is not None]
@@ -337,9 +472,53 @@ class MoELayer(BaseLayer):
                               self.b2, self.num_experts,
                               self.capacity_factor, self.k,
                               ep_axis=self.ep_axis, ids=ids,
-                              sparse=self.sparse, w3=self.w3)
+                              sparse=self.sparse, w3=self.w3,
+                              load_var=self.load_var)
         return self.last_op
 
     def aux_loss(self):
         assert self.last_op is not None
         return MoEAuxLossOp(self.last_op)
+
+    def z_loss(self):
+        assert self.last_op is not None
+        return MoEZLossOp(self.last_op)
+
+    def chosen(self):
+        assert self.last_op is not None
+        return MoEChosenOp(self.last_op)
+
+    def load(self):
+        assert self.load_var is not None, "MoELayer(track_load=True)"
+        return MoELoadOp(self.load_var)
+
+
+def record_moe_load(layer, load):
+    """Count one step's per-expert load of MoE layer ``layer`` (a label) in
+    the telemetry registry.  ``load`` is the fetched value of
+    ``MoELayer.load()``, ``[2, E]``: pairs routed and pairs computed.
+
+    * ``hetu_moe_pairs_routed_total{layer}``: (token, choice) pairs routed;
+    * ``hetu_moe_pairs_dropped_total{layer}``: those of them no expert
+      computed (capacity overflow; the dropless path keeps it at 0);
+    * ``hetu_moe_expert_load_max_over_mean{layer}``: the fullest expert's
+      pairs over the mean, this step (1.0 is perfectly even).
+
+    The registry counts nothing while telemetry is disabled."""
+    from .. import telemetry
+    reg = telemetry.get_registry()
+    routed, kept = np.asarray(load, np.float64)
+    total = routed.sum()
+    if total <= 0:          # the state's initial zeros: no step has run
+        return
+    reg.counter("hetu_moe_pairs_routed_total",
+                "(token, choice) pairs the router sent to an expert",
+                labels=("layer",)).labels(layer=layer).inc(total)
+    reg.counter("hetu_moe_pairs_dropped_total",
+                "Routed pairs no expert computed (capacity overflow)",
+                labels=("layer",)).labels(layer=layer).inc(
+                    total - kept.sum())
+    reg.gauge("hetu_moe_expert_load_max_over_mean",
+              "Pairs at the fullest expert over the mean, last step",
+              labels=("layer",)).labels(layer=layer).set(
+                  routed.max() * routed.size / total)

@@ -14,6 +14,8 @@ construction for the graph pipeline executor exactly like GPTModel.
 from __future__ import annotations
 
 from contextlib import nullcontext
+from functools import reduce
+from operator import add
 
 import numpy as np
 
@@ -33,7 +35,8 @@ class LlamaConfig:
                  seq_len=2048, rope_theta=10000.0, rms_eps=1e-5,
                  position_embedding="rope", tie_embeddings=False,
                  num_experts=None, moe_k=2, moe_capacity_factor=2.0,
-                 moe_aux_coeff=0.01, ep_axis=None):
+                 moe_aux_coeff=0.01, ep_axis=None, qk_norm=False,
+                 moe_renorm_topk=True, moe_z_coeff=0.0):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -62,6 +65,16 @@ class LlamaConfig:
         self.moe_capacity_factor = moe_capacity_factor
         self.moe_aux_coeff = moe_aux_coeff
         self.ep_axis = ep_axis
+        # the three things OLMoE's block has beyond Mixtral's: an RMSNorm
+        # on the projected queries and keys, top-k softmax weights used as
+        # they are (norm_topk_prob false), and a router z-loss.
+        # moe_capacity_factor=None is dropless routing (layers/moe.py):
+        # no pair dropped, the balance loss over top-k counts
+        self.qk_norm = qk_norm
+        self.moe_renorm_topk = moe_renorm_topk
+        self.moe_z_coeff = moe_z_coeff
+        assert not moe_z_coeff or moe_capacity_factor is None, (
+            "the router z-loss is read from the dropless routing")
 
 
 # published shapes (match the reference's meta_configs/hf_configs)
@@ -81,11 +94,25 @@ LLAMA_CONFIGS = {
     "mistral-7b": dict(hidden_size=4096, num_layers=32, num_heads=32,
                        num_kv_heads=8, intermediate_size=14336,
                        vocab_size=32000),
-    # moe_capacity_factor = E/k: the no-drop point Mixtral parity needs
+    # moe_capacity_factor = E/k: the no-drop point Mixtral parity needs,
+    # at E/k times the expert work (the buffer is E x C rows for T k
+    # pairs); moe_capacity_factor=None, the dropless path of
+    # layers/moe.py, runs the same routing on the T k pairs alone
     "mixtral-8x7b": dict(hidden_size=4096, num_layers=32, num_heads=32,
                          num_kv_heads=8, intermediate_size=14336,
                          vocab_size=32000, num_experts=8, moe_k=2,
                          moe_capacity_factor=4.0),
+    # allenai/OLMoE-1B-7B-0125 config.json: 64 experts of width 1,024,
+    # 8 a token with the softmax weights not renormalised, QK-norm,
+    # trained dropless with 0.01 of the balance loss and 0.001 of the
+    # router z-loss a layer (arXiv:2409.02060)
+    "olmoe-1b-7b": dict(vocab_size=50304, hidden_size=2048, num_layers=16,
+                        num_heads=16, num_kv_heads=16,
+                        intermediate_size=1024, rope_theta=10000.0,
+                        rms_eps=1e-5, qk_norm=True, num_experts=64,
+                        moe_k=8, moe_renorm_topk=False,
+                        moe_capacity_factor=None, moe_aux_coeff=0.01,
+                        moe_z_coeff=0.001),
     # reference models/baichuan: 7B is rope, 13B is alibi
     "baichuan-7b": dict(vocab_size=64000, hidden_size=4096, num_layers=32,
                         num_heads=32, intermediate_size=11008),
@@ -124,6 +151,7 @@ class LlamaDecoderLayer(BaseLayer):
             rope_theta=(c.rope_theta
                         if c.position_embedding == "rope" else None),
             alibi=c.position_embedding == "alibi", bias=False,
+            qk_norm=c.qk_norm, qk_norm_eps=c.rms_eps,
             name=f"{name}_attn")
         if c.num_experts:
             from ..layers.moe import MoELayer
@@ -131,7 +159,8 @@ class LlamaDecoderLayer(BaseLayer):
                                 num_experts=c.num_experts, k=c.moe_k,
                                 capacity_factor=c.moe_capacity_factor,
                                 expert_act="swiglu", ep_axis=c.ep_axis,
-                                name=f"{name}_moe")
+                                renorm_topk=c.moe_renorm_topk,
+                                track_load=True, name=f"{name}_moe")
         else:
             self.mlp = LlamaMLP(c.hidden_size, c.intermediate_size,
                                 name=f"{name}_mlp")
@@ -207,15 +236,33 @@ class LlamaForCausalLM:
     def loss(self, input_ids, labels):
         """labels: [B, S] next-token ids with -1 at ignored positions
         (caller shifts, matching GPTLMHeadModel's convention)."""
+        return self.loss_terms(input_ids, labels)[0]
+
+    def loss_terms(self, input_ids, labels):
+        """``(loss, {"ce": ..., "lbl": ..., "z": ...})``: the training loss
+        and the nodes it is the weighted sum of: the mean cross-entropy
+        over labelled positions and, for an MoE model, the balance loss and
+        the router z-loss, each summed over layers (the z term only where
+        ``moe_z_coeff`` is set)."""
+        c = self.config
         logits = self(input_ids)
         flat = array_reshape_op(labels, output_shape=(-1,))
         ce = softmax_cross_entropy_sparse_op(logits, flat, ignored_index=-1)
-        loss = MaskedMeanOp(ce, flat)
-        if self.config.num_experts:
-            for layer in self.model.layers:
-                loss = loss + self.config.moe_aux_coeff \
-                    * layer.mlp.aux_loss()
-        return loss
+        terms = {"ce": MaskedMeanOp(ce, flat)}
+        loss = terms["ce"]
+        if c.num_experts:
+            mlps = [layer.mlp for layer in self.model.layers]
+            terms["lbl"] = reduce(add, [m.aux_loss() for m in mlps])
+            loss = loss + c.moe_aux_coeff * terms["lbl"]
+            if c.moe_z_coeff:
+                terms["z"] = reduce(add, [m.z_loss() for m in mlps])
+                loss = loss + c.moe_z_coeff * terms["z"]
+        return loss, terms
+
+    def moe_loads(self):
+        """One ``[2, E]`` node a layer of (pairs routed, pairs computed) by
+        expert, to fetch beside the loss (layers/moe.py ``MoELoadOp``)."""
+        return [layer.mlp.load() for layer in self.model.layers]
 
 
 def BaichuanForCausalLM(config, name="baichuan", pipeline_stages=None):

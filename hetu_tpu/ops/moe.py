@@ -24,7 +24,8 @@ from .base import simple_op
 
 def top_k_gating(logits, k, capacity, *, second_renorm=True,
                  noise_rng=None, noise_eps=0.0):
-    """GShard top-k gating (k∈{1,2}).
+    """GShard top-k gating (any ``1 <= k <= E``; ``second_renorm``
+    rescales the kept gates of a token to sum to 1 when ``k >= 2``).
 
     logits: [T, E] raw gate outputs.  Returns (dispatch [T, E, C] float,
     combine [T, E, C] float, aux_loss scalar).  Tokens beyond per-expert
@@ -46,33 +47,33 @@ def top_k_gating_choices(logits, k, capacity, *, second_renorm=True,
     routing choice plus the aux loss, never materializing the [T, E, C]
     dispatch/combine tensors (the sparse dispatch path feeds these to
     ops/pallas/moe_dispatch.row_gather)."""
-    if k not in (1, 2):
-        raise ValueError(f"top_k_gating supports k in (1, 2), got k={k}")
     T, E = logits.shape
+    if not 1 <= k <= E:
+        raise ValueError(f"top_k_gating needs 1 <= k <= {E} experts, got "
+                         f"k={k}")
     probs = jax.nn.softmax(logits, axis=-1)
     if noise_rng is not None and noise_eps > 0:
         logits = logits + noise_eps * jax.random.normal(noise_rng,
                                                         logits.shape)
-    idx1 = jnp.argmax(logits, axis=-1)                       # [T]
-    mask1 = jax.nn.one_hot(idx1, E, dtype=probs.dtype)       # [T, E]
-    gate1 = jnp.sum(probs * mask1, axis=-1)
+    masks_gates = []
+    remaining = logits
+    for _ in range(k):
+        mask = jax.nn.one_hot(jnp.argmax(remaining, axis=-1), E,
+                              dtype=probs.dtype)
+        masks_gates.append((mask, jnp.sum(probs * mask, axis=-1)))
+        remaining = jnp.where(mask > 0, -jnp.inf, remaining)
 
-    # load-balancing aux loss (GShard eq.4): E * mean(me * ce)
+    # load-balancing aux loss (GShard eq.4): E * mean(me * ce), counting
+    # each token's first choice
     me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(mask1, axis=0)
+    ce = jnp.mean(masks_gates[0][0], axis=0)
     aux = E * jnp.sum(me * ce)
 
-    masks_gates = [(mask1, gate1)]
-    if k == 2:
-        logits2 = jnp.where(mask1 > 0, -jnp.inf, logits)
-        mask2 = jax.nn.one_hot(jnp.argmax(logits2, axis=-1), E,
-                               dtype=probs.dtype)
-        masks_gates.append((mask2, jnp.sum(probs * mask2, axis=-1)))
     choices = _choices_with_positions(masks_gates)
     # zero dropped gates BEFORE renorm so kept mass renormalizes to 1
     choices = [(i, g * (p < capacity), p) for (i, g, p) in choices]
-    if k == 2 and second_renorm:
-        total = choices[0][1] + choices[1][1]
+    if k >= 2 and second_renorm:
+        total = sum(g for _, g, _ in choices)
         denom = total + 1e-9
         choices = [(i, g / denom * (total > 0), p)
                    for (i, g, p) in choices]
@@ -359,3 +360,201 @@ def sam_group_sum(x, group_idx, num_groups):
     """SamGroupSum.cu: segment-sum of gate scores per group."""
     return jax.ops.segment_sum(x, group_idx.astype(jnp.int32),
                                num_segments=num_groups)
+
+
+# -- dropless routing (OLMoE / Mixtral as published) --------------------------
+#
+# No capacity: every (token, choice) pair is computed.  The pairs are sorted
+# by expert and the expert FFNs run as grouped products over the ragged
+# groups, so the work is the 8T rows the routing asks for and not the E x C
+# rows of a capacity buffer (at the no-drop capacity factor E/k that is E/k
+# times the expert work).
+
+def top_k_route(logits, k, renorm=False):
+    """``(idx [T, k] int32, gate [T, k] f32, probs [T, E] f32)``: the ``k``
+    largest softmax probabilities of each token, largest first, ties to the
+    lower expert index; ``renorm`` rescales them to sum to 1 (Mixtral), the
+    default uses them as they are (OLMoE's ``norm_topk_prob: false``).
+    Everything in f32 whatever the logits' type, so that routing does not
+    depend on the compute type."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, idx = jax.lax.top_k(probs, k)
+    # the gates by a one-hot product, not top_k's values: its backward
+    # pass is then a product too and not a scatter-add into [T, E]
+    gate = jnp.sum(jax.nn.one_hot(idx, probs.shape[-1], dtype=probs.dtype)
+                   * probs[:, None, :], axis=-1)
+    if renorm:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gate, probs
+
+
+def expert_load(idx, num_experts):
+    """``[E]`` int32: the (token, choice) pairs routed to each expert."""
+    return jnp.sum(jax.nn.one_hot(idx.reshape(-1), num_experts,
+                                  dtype=jnp.int32), axis=0)
+
+
+def load_balancing_loss(probs, load):
+    """``E * sum_i (n_i / T) * mean_t p_t,i`` with ``n_i`` the (token,
+    choice) pairs at expert ``i``: the form of HF
+    ``load_balancing_loss_func`` (Switch eq. 4 over top-k counts).  The
+    counts carry no gradient."""
+    T, E = probs.shape
+    frac = jax.lax.stop_gradient(load.astype(jnp.float32)) / T
+    return E * jnp.sum(frac * jnp.mean(probs, axis=0))
+
+
+def router_z_loss(logits):
+    """``mean_t logsumexp_i(logits_t,i)^2`` (ST-MoE, Zoph et al. 2022)."""
+    z = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(z * z)
+
+
+def grouped_layout(idx, num_experts, tile=None):
+    """Where each (token, choice) pair sits once the pairs are sorted by
+    expert.  Pair ``p = t * k + c``.  Returns a dict:
+
+    * ``load`` ``[E]``: pairs at each expert (the group sizes);
+    * ``slot_of_pair`` ``[T k]``: the row of pair ``p``;
+    * ``pair_of_slot`` ``[M]``: the pair in row ``s``, ``-1`` for a row of
+      padding;
+    * with ``tile``: ``tile_expert`` ``[M / tile]`` and ``n_used`` ``[1]``.
+
+    Without ``tile`` the rows are the pairs in expert order, ``M = T k``
+    (what ``jax.lax.ragged_dot`` takes).  With ``tile`` each expert's rows
+    start on a multiple of ``tile`` and every expert owns at least one row
+    tile, so a row tile belongs to one expert (``tile_expert``);
+    ``M = T k + E tile`` is the static bound, the tiles from ``n_used`` on
+    hold nothing and are assigned to the last expert.  Only sorts, prefix
+    sums and gathers: no scatter."""
+    E = num_experts
+    flat = idx.reshape(-1)
+    P = flat.shape[0]
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32)      # position of pair p
+    load = expert_load(idx, E)
+    start = jnp.cumsum(load) - load
+    if tile is None:
+        return {"load": load, "slot_of_pair": rank, "pair_of_slot": order,
+                "rows": P}
+    tiles = jnp.maximum(-(-load // tile), 1)
+    tile_end = jnp.cumsum(tiles)
+    pstart = (tile_end - tiles) * tile
+    slot_of_pair = pstart[flat] + rank - start[flat]
+    M = P + E * tile
+    n_tiles = M // tile
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right", method="compare_all"),
+        E - 1).astype(jnp.int32)
+    e_of_slot = jnp.repeat(tile_expert, tile)
+    r = jnp.arange(M, dtype=jnp.int32) - pstart[e_of_slot]
+    valid = (r >= 0) & (r < load[e_of_slot])
+    pair_of_slot = jnp.where(
+        valid, order[jnp.clip(start[e_of_slot] + r, 0, P - 1)], -1)
+    return {"load": load, "slot_of_pair": slot_of_pair.astype(jnp.int32),
+            "pair_of_slot": pair_of_slot.astype(jnp.int32),
+            "tile_expert": tile_expert,
+            "n_used": tile_end[-1:].astype(jnp.int32), "rows": M}
+
+
+@jax.custom_vjp
+def _rows_out(tokens, pair_of_slot, slot_of_pair):
+    """``xs[s] = tokens[pair_of_slot[s] // k]`` (zeros where ``-1``).  The
+    backward pass is a gather too: ``d tokens[t]`` is the sum of the ``k``
+    rows its pairs sit in, never a scatter-add."""
+    k = slot_of_pair.shape[0] // tokens.shape[0]
+    tok = jnp.where(pair_of_slot >= 0, pair_of_slot // k, tokens.shape[0])
+    return jnp.take(tokens, tok, axis=0, mode="fill", fill_value=0)
+
+
+def _rows_out_fwd(tokens, pair_of_slot, slot_of_pair):
+    return (_rows_out(tokens, pair_of_slot, slot_of_pair),
+            (slot_of_pair, tokens.shape[0]))
+
+
+def _rows_out_bwd(res, d_xs):
+    slot_of_pair, T = res
+    d = jnp.take(d_xs, slot_of_pair, axis=0)
+    d = d.reshape(T, -1, d.shape[-1]).astype(jnp.float32).sum(1)
+    return d.astype(d_xs.dtype), None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(out, pair_of_slot, slot_of_pair):
+    """``pairs[p] = out[slot_of_pair[p]]``; backward ``d out[s] =
+    d pairs[pair_of_slot[s]]`` (zeros in rows of padding)."""
+    return jnp.take(out, slot_of_pair, axis=0)
+
+
+def _rows_back_fwd(out, pair_of_slot, slot_of_pair):
+    return _rows_back(out, pair_of_slot, slot_of_pair), (pair_of_slot,)
+
+
+def _rows_back_bwd(res, d_pairs):
+    (pair_of_slot,) = res
+    src = jnp.where(pair_of_slot >= 0, pair_of_slot, d_pairs.shape[0])
+    return (jnp.take(d_pairs, src, axis=0, mode="fill", fill_value=0),
+            None, None)
+
+
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+
+#: rows of one tile of the Pallas grouped products: with 64 experts the
+#: padding is E x tile / 2 rows on average, 12.5% of OLMoE's 65,536 pairs
+GMM_TILE = 256
+
+
+def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
+                 impl=None):
+    """``(impl, tile)`` of the grouped products: ``"pallas"`` (the
+    ``hetu_moe_gmm_*`` kernels over tile-aligned groups) or ``"ragged"``
+    (``jax.lax.ragged_dot`` over the pairs in expert order), recorded in
+    ``dispatch.choices()`` under ``moe_gmm``.  ``impl`` forces one."""
+    from .pallas import dispatch, moe_gmm
+    tile = GMM_TILE if pairs // num_experts >= GMM_TILE else 8
+    if impl == "ragged":
+        why = "caller:impl=ragged"
+    elif mesh is not None:
+        why = "mesh"            # pallas_call does not partition under GSPMD
+    elif impl is None and not dispatch.mosaic():
+        why = f"platform:{dispatch.platform()}"
+    else:
+        why = moe_gmm.unsupported(pairs, hidden, inter, tile, dtype)
+    return ("pallas", tile) if dispatch.record("moe_gmm", why) \
+        else ("ragged", None)
+
+
+def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
+                 impl=None):
+    """``y[t] = sum_c gate[t, c] * W_down,e( silu(W_gate,e x_t) * W_up,e
+    x_t )`` with ``e = idx[t, c]``; no pair is dropped.  ``tokens [T, H]``,
+    ``idx, gate [T, k]``, weights ``[E, H, F]``, ``[E, H, F]``,
+    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``."""
+    T, H = tokens.shape
+    k = idx.shape[1]
+    E, _, F = w_gate.shape
+    how, tile = grouped_impl(T * k, E, H, F, tokens.dtype, mesh, impl)
+    with jax.named_scope("hetu_moe_dispatch"):
+        lay = grouped_layout(idx, E, tile)
+        xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
+    with jax.named_scope("hetu_moe_experts"):
+        if how == "pallas":
+            from .pallas.moe_gmm import grouped_matmul
+
+            def product(a, w):
+                return grouped_matmul(a, w, lay["tile_expert"],
+                                      lay["n_used"], tile, E)
+        else:
+            def product(a, w):
+                return jax.lax.ragged_dot(a, w, lay["load"])
+        act = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
+        out = product(act, w_down)
+    with jax.named_scope("hetu_moe_combine"):
+        pairs = _rows_back(out, lay["pair_of_slot"], lay["slot_of_pair"])
+        y = jnp.sum(pairs.reshape(T, k, H).astype(jnp.float32)
+                    * gate[:, :, None], axis=1).astype(tokens.dtype)
+    return y, lay["load"]

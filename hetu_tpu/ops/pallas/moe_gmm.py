@@ -1,0 +1,212 @@
+"""Grouped matrix products over tile-aligned expert groups (dropless MoE).
+
+The dropless MoE path (ops/moe.py ``dropless_moe``) sorts the (token,
+choice) pairs by expert and lays each expert's rows out from a row-tile
+boundary on (``ops/moe.py grouped_layout``): every row tile of ``tm`` rows
+then belongs to one expert, named by ``tile_expert[tile]``, and the rows
+past an expert's last pair are zeros.  So the kernels need no row mask and
+no group offsets, only the scalar-prefetched ``tile_expert`` to pick the
+weight block (the idea of MegaBlocks' block-sparse products, Gale et al.
+2022, with the padding paid in the layout instead of in a mask):
+
+* ``gmm(x [M, K], w [E, K, N]) -> [M, N]``: row tile ``i`` times
+  ``w[tile_expert[i]]``; with ``transpose_rhs`` ``w`` is ``[E, N, K]``
+  (the backward product for ``dx``).  Consecutive tiles of one expert keep
+  the weight block's index, so Pallas fetches each expert's weights once.
+* ``tgmm(x [M, K], dy [M, N]) -> [E, K, N]``: ``dw[e]`` is the sum over
+  expert ``e``'s tiles of ``x_tile^T dy_tile`` (rows innermost in the grid,
+  an f32 accumulator carried across one expert's tiles).  Every expert owns
+  at least one tile, so every ``dw[e]`` is written.
+
+Tiles from ``n_used`` on (the static row bound is never reached) are
+skipped: ``gmm`` writes zeros there, ``tgmm`` adds nothing.  The layout
+assigns them to the last expert, so ``tile_expert`` stays sorted.
+
+Named ``hetu_moe_gmm_fwd`` / ``hetu_moe_gmm_dx`` / ``hetu_moe_gmm_dw`` in
+the device trace.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from . import dispatch
+
+#: scoped VMEM the kernels may use: a [256, 2048] row tile, a [2048, 1024]
+#: weight block and the f32 accumulator, double-buffered, are about 14 MiB;
+#: Mosaic's default scoped limit is 16 MiB of the v5e's 128
+VMEM_LIMIT = 64 * 2 ** 20
+
+
+def _fit(dim, want):
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``want`` (``dim`` itself when it is smaller than 128 or ``want``)."""
+    if dim <= want:
+        return dim
+    t = want - want % 128
+    while t >= 128:
+        if dim % t == 0:
+            return t
+        t -= 128
+    return dim
+
+
+def unsupported(m, k, n, tm, dtype):
+    """Why the grouped-product kernels do not run, or None when they do."""
+    if not (dispatch.mosaic() or dispatch.interpret()):
+        return f"platform:{dispatch.platform()}"
+    if m % tm:
+        return f"rows_not_tile_aligned:{m}%{tm}"
+    if dispatch.mosaic():
+        if k % 128 or n % 128 or tm % 8:
+            return "dims_not_128_aligned"
+        if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
+                                    jnp.dtype(jnp.float32)):
+            return f"dtype:{jnp.dtype(dtype).name}"
+    return None
+
+
+def _params(semantics):
+    from jax.experimental.pallas import tpu as pltpu
+    if dispatch.interpret():
+        return None
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _live(i, n_used):
+    """Row tile ``i``, or the last live one for a skipped tile: its block
+    index then repeats and Pallas fetches nothing new."""
+    return jnp.minimum(i, n_used[0] - 1)
+
+
+def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
+        tk=2048, tn=1024, name="hetu_moe_gmm_fwd"):
+    """``out[tile i] = x[tile i] @ w[tile_expert[i]]`` (``w[...]^T`` with
+    ``transpose_rhs``) for the first ``n_used`` row tiles, zeros after."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    m, k = x.shape
+    n = w.shape[1] if transpose_rhs else w.shape[2]
+    tk, tn = _fit(k, tk), _fit(n, tn)
+    tiles_k = k // tk
+    contract = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+
+    def kernel(te, nu, x_ref, w_ref, o_ref, acc):
+        i, kk = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(kk == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        @pl.when(i < nu[0])
+        def _():
+            acc[...] += jax.lax.dot_general(
+                x_ref[...], w_ref[...], contract,
+                preferred_element_type=jnp.float32)
+
+        @pl.when(kk == tiles_k - 1)
+        def _():
+            o_ref[...] = acc[...].astype(o_ref.dtype)
+
+    if transpose_rhs:
+        w_spec = pl.BlockSpec(
+            (None, tn, tk),
+            lambda j, i, kk, te, nu: (te[_live(i, nu)], j, kk))
+    else:
+        w_spec = pl.BlockSpec(
+            (None, tk, tn),
+            lambda j, i, kk, te, nu: (te[_live(i, nu)], kk, j))
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, m // tm, tiles_k),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda j, i, kk, te, nu: (_live(i, nu), kk)),
+                w_spec],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, kk, te, nu: (i, j)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=_params(("parallel", "arbitrary", "arbitrary")),
+        interpret=dispatch.interpret(),
+    )(tile_expert, n_used, x, w)
+
+
+def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm, tk=2048, tn=512,
+         name="hetu_moe_gmm_dw"):
+    """``dw[e] = sum over expert e's row tiles of x_tile^T @ dy_tile``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    m, k = x.shape
+    n = dy.shape[1]
+    tk, tn = _fit(k, tk), _fit(n, tn)
+    tiles_m = m // tm
+
+    def kernel(te, nu, x_ref, dy_ref, o_ref, acc):
+        i = pl.program_id(2)
+        e = te[i]
+        first = jnp.logical_or(i == 0, te[jnp.maximum(i - 1, 0)] != e)
+        last = jnp.logical_or(i == tiles_m - 1,
+                              te[jnp.minimum(i + 1, tiles_m - 1)] != e)
+
+        @pl.when(first)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        @pl.when(i < nu[0])
+        def _():
+            acc[...] += jax.lax.dot_general(
+                x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            o_ref[...] = acc[...].astype(o_ref.dtype)
+
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // tk, n // tn, tiles_m),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda a, b, i, te, nu: (_live(i, nu), a)),
+                pl.BlockSpec((tm, tn),
+                             lambda a, b, i, te, nu: (_live(i, nu), b))],
+            out_specs=pl.BlockSpec((None, tk, tn),
+                                   lambda a, b, i, te, nu: (te[i], a, b)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((num_experts, k, n), x.dtype),
+        compiler_params=_params(("parallel", "parallel", "arbitrary")),
+        interpret=dispatch.interpret(),
+    )(tile_expert, n_used, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_matmul(x, w, tile_expert, n_used, tm, num_experts):
+    """``x [M, K] @ w[expert of each row tile] -> [M, N]`` with the Pallas
+    kernels above forward and backward."""
+    return gmm(x, w, tile_expert, n_used, tm=tm)
+
+
+def _fwd(x, w, tile_expert, n_used, tm, num_experts):
+    return (gmm(x, w, tile_expert, n_used, tm=tm),
+            (x, w, tile_expert, n_used))
+
+
+def _bwd(tm, num_experts, res, dy):
+    x, w, tile_expert, n_used = res
+    dx = gmm(dy, w, tile_expert, n_used, tm=tm, transpose_rhs=True,
+             name="hetu_moe_gmm_dx")
+    dw = tgmm(x, dy, tile_expert, n_used, num_experts, tm=tm)
+    return dx, dw, None, None
+
+
+grouped_matmul.defvjp(_fwd, _bwd)
