@@ -58,9 +58,9 @@ class Program:
     expert load) and, for the correctness check, a ``validate`` subgraph of
     the same loss, its terms and the same load."""
 
-    #: the Mosaic kernels of a train step that the program names itself
-    KERNELS = ("hetu_flash_fwd", "hetu_flash_bwd_dq", "hetu_flash_bwd_dkv",
-               "hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd",
+    #: the Mosaic kernels of a train step that are held by name; flash
+    #: attention is held by its passes and its work (``loops.trace_checks``)
+    KERNELS = ("hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd",
                "hetu_moe_gmm_fwd", "hetu_moe_gmm_dx", "hetu_moe_gmm_dw")
 
     def __init__(self, config, mix, seed, say):
@@ -174,13 +174,17 @@ class Program:
                 if dispatch.mosaic() else ())
 
     def expected_kernel_shapes(self):
-        """First operand of the flash kernels, the rows of the loss kernel
+        """Flash attention's work (batch, heads, positions, head size;
+        layers a step; the type computed in), the rows of the loss kernel
         and the sizes of the grouped products."""
         c = self.config
-        rows = self.batch * c["num_attention_heads"]
-        hd = c["hidden_size"] // c["num_attention_heads"]
-        return {"flash_key": f"bf16_{rows}_{self.seq}_{hd}",
-                "flash_rows": rows, "head_dim": hd,
+        heads = c["num_attention_heads"]
+        hd = c["hidden_size"] // heads
+        return {"flash_dims": (self.batch, heads, self.seq, hd),
+                "flash_elements": self.batch * heads * self.seq * hd,
+                "flash_rows": self.batch * heads, "head_dim": hd,
+                "attention_layers": c["num_hidden_layers"],
+                "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq,
                 "moe_pairs": self.tokens_per_step * c["num_experts_per_tok"]}
 

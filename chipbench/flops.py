@@ -23,18 +23,18 @@ def bert_train_flops_per_token(c, seq, masked_fraction):
     return 3.0 * (c["num_hidden_layers"] * layer + head)
 
 
-#: flash attention by passes, each with the kernels that carry it out and
-#: the matrix products the algorithm needs for it (Dao et al. 2022):
-#: forward QK^T and PV; backward S once more (P is never stored), dP, dV,
-#: dK and dQ.  The program's backward is two kernels that each form S and
-#: dP again (3 + 4 = 7 products run); the second S and dP are this
-#: implementation's recomputation and are not credited, so a backward fused
-#: into one kernel would read higher, not lower.
+#: flash attention by passes, each with the part of a device event's name
+#: that marks the pass (however many kernels carry it out, under whatever
+#: suffix) and the matrix products the algorithm needs for it (Dao et al.
+#: 2022): forward QK^T and PV; backward S once more (P is never stored), dP,
+#: dV, dK and dQ.  A backward cut into two kernels that each form S and dP
+#: again runs 3 + 4 = 7 products; the second S and dP are that cut's
+#: recomputation and are not credited, so a backward fused into one kernel
+#: reads higher, not lower.
 FLASH_PASSES = {
-    "forward": {"kernels": ("hetu_flash_fwd",), "products": 2,
+    "forward": {"events": "hetu_flash_fwd", "products": 2,
                 "tensors": 4},       # q, k, v read; o written
-    "backward": {"kernels": ("hetu_flash_bwd_dq", "hetu_flash_bwd_dkv"),
-                 "products": 5,
+    "backward": {"events": "hetu_flash_bwd", "products": 5,
                  "tensors": 8}}      # q, k, v, o, do read; dq, dk, dv written
 
 

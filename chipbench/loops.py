@@ -175,24 +175,63 @@ class TrainLoop:
         rec["attempted"] = len(rec["step_ends"])
         rec["failed"] = int(np.sum(~np.isfinite(rec["losses"])))
         steps = np.diff([rec["t0"]] + rec["step_ends"]) * 1e3
-        self.say(f"steps {len(steps)}: step time p50 "
-                 f"{quantile(steps, 0.5):.2f} ms, p90 "
-                 f"{quantile(steps, 0.9):.2f} ms, max {steps.max():.2f} ms;"
+        p50 = quantile(steps, 0.5)
+        over = np.maximum(steps - p50, 0.0)
+        self.say(f"steps {len(steps)}: step time p50 {p50:.2f} ms, p90 "
+                 f"{quantile(steps, 0.9):.2f} ms, p99 "
+                 f"{quantile(steps, 0.99):.2f} ms, max {steps.max():.2f} ms;"
+                 f" {int(np.sum(steps > 1.05 * p50))} steps over 1.05 x p50,"
+                 f" all time over p50 {over.sum():.1f} ms "
+                 f"({100 * over.sum() / steps.sum():.2f}% of the window);"
                  f" loss {losses[0]:.3f} -> {losses[-1]:.3f}")
         return self.checks
 
     def trace_checks(self, reduced):
         """In a traced run: the kernels ran on the device as Mosaic calls,
-        the flash kernels on the local shard's shape."""
+        and flash attention did the configuration's work on every device:
+        both passes, on the local shard, once a layer and step.  Flash is
+        held by the pass its events name and the size of what the forward
+        pass writes, not by how many kernels carry a pass out or by the
+        order of their dimensions."""
+        from math import prod
+        from . import flops, trace_reduce as tr
         p = self.p
         keys = {k for ev in reduced["devices"].values() for _, _, k in ev}
         missing = [n for n in p.KERNELS if not any(n in k for k in keys)]
-        shard = p.expected_kernel_shapes()["flash_key"]
-        fwd = [k for k in keys if "hetu_flash_fwd" in k]
+        want = p.expected_kernel_shapes()
+        lo, hi = tr.window_of(reduced["host"], STEP_SPANS)
+        fwd_name, bwd_name = (flops.FLASH_PASSES[n]["events"]
+                              for n in ("forward", "backward"))
+        fwd = tr.events_holding(reduced, lo, hi, fwd_name)
+        bwd = tr.events_holding(reduced, lo, hi, bwd_name)
+        without = sorted(d for d in fwd if not fwd[d] or not bwd[d])
+        dtype = tr.HLO_DTYPES[want["compute_dtype"]]
+        wrote = {tr.first_result(k, fwd_name)
+                 for ev in fwd.values() for _, _, k in ev}
+        off_shard = [w for w in wrote if w is None or w[0] != dtype
+                     or prod(w[1]) != want["flash_elements"]]
+
+        def shown(results):
+            return sorted("unreadable" if w is None else
+                          f"{w[0]}{list(w[1])}" for w in results)
+        steps = tr.count_spans(reduced["host"], STEP_SPANS)
+        calls = {d: len(ev) for d, ev in fwd.items()}
+        due = want["attention_layers"] * steps
         return [(not missing, f"the device ran {p.KERNELS} (missing: "
                  f"{missing})"),
-                (bool(fwd) and all(shard in k for k in fwd),
-                 f"flash attention ran on the local shard {shard}")]
+                (bool(fwd) and not without,
+                 f"every device of the traced window ({sorted(fwd)}) ran "
+                 f"{fwd_name}* and {bwd_name}* (without one: {without})"),
+                (bool(wrote) and not off_shard,
+                 f"every flash forward call wrote the local shard's "
+                 f"{want['flash_rows']} x {p.seq} x {want['head_dim']} = "
+                 f"{want['flash_elements']} {dtype} elements in whatever "
+                 f"order (saw {shown(wrote)}; not that: "
+                 f"{shown(off_shard)})"),
+                (bool(calls) and set(calls.values()) == {due},
+                 f"flash forward calls on each device {calls}: "
+                 f"{want['attention_layers']} attention layer(s) x {steps} "
+                 f"traced steps = {due}")]
 
 
 LOOPS = {"train_loop": TrainLoop}

@@ -7,29 +7,7 @@ A reader that finds nothing to read returns None."""
 
 from __future__ import annotations
 
-import numpy as np
-
 from chipbench import flops, trace_reduce as tr
-
-
-def host_ms_outside_device(ctx, span):
-    """Mean over the traced spans named ``span`` of the span's length less
-    the time an operation ran on the device inside it, in ms: what the
-    host spent with the device waiting.  Averaged over the devices."""
-    t = ctx["trace"]
-    if t is None:
-        return None
-    lo, hi = t["summary"]["lo"], t["summary"]["hi"]
-    spans = [(s, s + d) for s, d, n in t["reduced"]["host"]
-             if n == span and lo <= s and s + d <= hi]
-    busy = [tr.busy(ev, lo, hi) for ev in t["reduced"]["devices"].values()]
-    busy = [b for b in busy if b]
-    if not spans or not busy:
-        return None
-    covered = np.mean([[tr.overlap(b, s, e) for s, e in spans]
-                       for b in busy], axis=0)
-    return float(np.mean([(e - s) - c for (s, e), c
-                          in zip(spans, covered)]) * 1e-6)
 
 
 def idle_share(ctx):
@@ -78,4 +56,42 @@ def roofline_share(ctx, names, call):
     ctx["say"](f"roofline of {sorted(k for k, v in found.items() if v)}: "
                f"least {least:.4f} s over measured {measured:.4f} s; bound "
                f"by {sorted(limits)}")
+    return 100.0 * least / measured
+
+
+def flash_roofline(ctx, causal=False):
+    """Flash attention's least possible time over the measured device time
+    of its events, forward and backward pass together, in percent.  A
+    pass's time is that of every event whose key holds the pass's name
+    (``flops.FLASH_PASSES``), however many kernels carry the pass out; a
+    training step runs each pass once for every forward call.  Operations
+    and bytes are the algorithm's, from the local shard's shapes; under a
+    ``causal`` mask it needs half the products (keys after the query
+    contribute nothing) and reads and writes the tensors whole."""
+    t = ctx["trace"]
+    if t is None:
+        return None
+    lo, hi = t["summary"]["lo"], t["summary"]["hi"]
+    found = {name: [d for ev in tr.events_holding(
+                        t["reduced"], lo, hi, p["events"]).values()
+                    for _, d, _ in ev]
+             for name, p in flops.FLASH_PASSES.items()}
+    if not all(found.values()):
+        return None
+    want = ctx["program"].expected_kernel_shapes()
+    calls = len(found["forward"])
+    least = measured = 0.0
+    limits = {}
+    for name, durs in found.items():
+        ops, nbytes = flops.flash_pass(name, want["flash_rows"],
+                                       ctx["program"].seq, want["head_dim"])
+        t_min, limits[name] = flops.roofline_seconds(
+            ops / 2.0 if causal else ops, nbytes, ctx["peaks"])
+        least += t_min * calls
+        measured += sum(durs) * 1e-9
+    ctx["say"](f"roofline of {'causal ' if causal else ''}flash attention: "
+               f"{calls} forward calls, events a pass "
+               f"{ {k: len(v) for k, v in found.items()} }; least "
+               f"{least:.4f} s over measured {measured:.4f} s; bound by "
+               f"{limits}")
     return 100.0 * least / measured

@@ -1,9 +1,10 @@
 """``python -m chipbench.selfcheck``: what can be checked where there is no
 chip.  (1) The trace reduction against the small recorded trace under
-``testdata/``.  (2) The traffic generator's promise that the seed never
-changes the amount of work.  (3) Every cell's control flow at toy size on
-the cpu platform, every line labelled, no result line.  Exits non-zero on
-the first failure.  Run it under ``JAX_PLATFORMS=cpu`` with
+``testdata/``, and on the same trace the attention readers and trace checks
+against what was recorded of it.  (2) The traffic generator's promise that
+the seed never changes the amount of work.  (3) Every cell's control flow at
+toy size on the cpu platform, every line labelled, no result line.  Exits
+non-zero on the first failure.  Run it under ``JAX_PLATFORMS=cpu`` with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``."""
 
 from __future__ import annotations
@@ -14,7 +15,7 @@ import sys
 
 import numpy as np
 
-from . import run, traffic, trace_reduce as tr
+from . import loops, peaks, run, traffic, trace_reduce as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -70,6 +71,57 @@ def check_reduction():
               f"{name}: gaps and busy time add up to the window")
 
 
+class RecordedProgram:
+    """What the readers and ``trace_checks`` ask of a program, for a trace
+    that was recorded: the shapes as the builder's
+    ``expected_kernel_shapes`` gives them."""
+
+    def __init__(self, shapes, seq, kernels):
+        self.shapes, self.seq, self.KERNELS = dict(shapes), seq, kernels
+        self.shapes["flash_dims"] = tuple(self.shapes["flash_dims"])
+
+    def expected_kernel_shapes(self):
+        return self.shapes
+
+
+def trace_ctx(reduced, program, device_kind, say=lambda msg: None):
+    """A reader's ``ctx`` for a reduced trace alone."""
+    summ = tr.summary(reduced, loops.STEP_SPANS, loops.SPAN_ORDER)
+    return {"trace": {"reduced": reduced, "summary": summ},
+            "program": program, "peaks": peaks.peaks_for(device_kind),
+            "say": say}
+
+
+def check_attention():
+    """The flash readers and the layout-copy reader give on the recorded
+    trace what the readers before them gave (recorded in ``expected.json``
+    before they were rewritten), and the trace passes ``trace_checks``."""
+    with open(os.path.join(HERE, "testdata", "expected.json")) as f:
+        expected = json.load(f)
+    for name, want in expected.items():
+        want = want.get("attention")
+        if want is None:
+            continue
+        reduced = tr.load_saved(os.path.join(HERE, "testdata", name))
+        program = RecordedProgram(want["shapes"], want["seq"],
+                                  tuple(want["kernels"]))
+        ctx = trace_ctx(reduced, program, want["device_kind"])
+        got = run.reader("flash_roofline")(ctx)
+        check(abs(got - want["flash_roofline"]) < 1e-9,
+              f"{name}: flash_roofline {got!r} as the reader by kernel "
+              f"names read it, {want['flash_roofline']!r}")
+        by_hand = sum(sec for _, sec in want["layout_copies"].values())
+        steps = tr.count_spans(reduced["host"], loops.STEP_SPANS)
+        got = run.reader("attn_layout_copy_ms_per_step")(ctx)
+        check(abs(got - want["attn_layout_copy_ms_per_step"]) < 1e-9
+              and abs(got - by_hand / steps * 1e3) < 1e-9,
+              f"{name}: attn_layout_copy_ms_per_step {got!r} is the "
+              f"recorded copies' {by_hand:.6f} s over {steps} steps")
+        loop = loops.TrainLoop(program, None, 0, None, None)
+        for ok, what in loop.trace_checks(reduced):
+            check(ok, f"{name}: {what}")
+
+
 def check_traffic():
     for name in sorted(os.listdir(os.path.join(HERE, "traffic"))):
         mix = traffic.load(name[:-5])
@@ -85,6 +137,7 @@ def check_traffic():
 
 def main():
     check_reduction()
+    check_attention()
     check_traffic()
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         cells = [w["name"] for w in json.load(f)["workloads"]]

@@ -1,0 +1,296 @@
+"""What the benchmark says of flash attention, on synthetic reduced traces
+(no chip): ``loops.TrainLoop.trace_checks``, both ``flash_roofline`` readers
+and ``attn_layout_copy_ms_per_step`` judge the work (rows x positions x head
+size on the local shard, passes a step), not the cut into kernels or the
+order of the dimensions.  Run with
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
+        python -m pytest chipbench/tests -q
+"""
+
+import importlib
+import os
+
+import pytest
+
+from chipbench import flops, loops, peaks, run, selfcheck
+from chipbench import trace_reduce as tr
+
+KIND = "TPU v5 lite"
+#: BERT-base on one shard of 64 sequences: 12 heads of 64, 512 positions
+SHAPES = {"flash_dims": (64, 12, 512, 64), "flash_elements": 25165824,
+          "flash_rows": 768, "head_dim": 64, "attention_layers": 12,
+          "compute_dtype": "bfloat16"}
+KERNELS = ("hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd")
+LAYOUTS = {"rows": "bf16_768_512_64", "in_place": "bf16_64_512_768",
+           "head_groups": "bf16_64_512_12_64"}
+CUTS = {"dq_dkv": ("_dq", "_dkv"), "fused": ("",)}
+STEP_NS, FWD_NS, BWD_NS = 100e6, 1.3e6, 2.6e6
+
+
+def program(**over):
+    return selfcheck.RecordedProgram(dict(SHAPES, **over), 512, KERNELS)
+
+
+def synth(fwd="bf16_768_512_64", cut=("_dq", "_dkv"), layers=12, steps=2,
+          devices=(0,), bare=(), extra=()):
+    """A reduced trace of ``steps`` steps on ``devices``: per layer one
+    forward event writing ``fwd`` and the backward pass cut into the
+    kernels ``cut`` (their times sum to ``BWD_NS`` whatever the cut), the
+    loss kernels once a step, and ``extra`` ``(key, ns)`` events a step.
+    Devices in ``bare`` run everything but flash attention."""
+    host, events = [], {d: [] for d in devices}
+    for i in range(steps):
+        t = 1e9 + i * STEP_NS
+        host.append((t, STEP_NS - 1e3, "executor_run"))
+        for d in devices:
+            ev, at = events[d], t + 5e6
+
+            def put(key, ns):
+                nonlocal at
+                ev.append((at, ns, key))
+                at += ns + 1e3
+            put("jvp_hetu_softmax_ce_fwd__f32_8192", 1e6)
+            for _ in range(layers if d not in bare else 0):
+                put(f"jvp_hetu_flash_fwd__{fwd}_f32_768_1_512", FWD_NS)
+            for key, ns in extra:
+                put(key, ns)
+            for _ in range(layers if d not in bare else 0):
+                for suffix in cut:
+                    put(f"transpose_jvp_hetu_flash_bwd{suffix}___"
+                        "bf16_768_512_64", BWD_NS / len(cut))
+            put("transpose_jvp_hetu_softmax_ce_bwd___bf16_8192_30522", 2e6)
+    return {"devices": events, "modules": {}, "host": host}
+
+
+def checks(reduced, prog=None):
+    loop = loops.TrainLoop(prog or program(), None, 0, None, None)
+    return loop.trace_checks(reduced)
+
+
+def by_hand(causal=False):
+    """Roofline share of 2 + 5 products over FWD_NS + BWD_NS a layer."""
+    pk = peaks.peaks_for(KIND)
+    least = 0.0
+    for name in ("forward", "backward"):
+        ops, nbytes = flops.flash_pass(name, 768, 512, 64)
+        least += flops.roofline_seconds(ops / 2 if causal else ops,
+                                        nbytes, pk)[0]
+    return 100.0 * least / ((FWD_NS + BWD_NS) * 1e-9)
+
+
+# -- (a) layouts and cuts that do the same work read the same ------------------
+
+@pytest.mark.parametrize("cut", CUTS)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_any_layout_and_cut_passes_the_trace_checks(layout, cut):
+    for ok, what in checks(synth(LAYOUTS[layout], CUTS[cut])):
+        assert ok, what
+
+
+@pytest.mark.parametrize("reader, causal", [("flash_roofline", False),
+                                            ("flash_roofline.dp4", False),
+                                            ("flash_roofline.olmoe", True)])
+@pytest.mark.parametrize("cut", CUTS)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_flash_roofline_reads_the_work_not_the_cut(layout, cut, reader,
+                                                   causal):
+    said = []
+    ctx = selfcheck.trace_ctx(synth(LAYOUTS[layout], CUTS[cut]), program(),
+                              KIND, said.append)
+    assert abs(run.reader(reader)(ctx) - by_hand(causal)) < 1e-9
+    assert "24 forward calls" in said[0]
+
+
+def test_flash_roofline_averages_nothing_away_on_four_devices():
+    ctx = selfcheck.trace_ctx(synth(devices=(0, 1, 2, 3)), program(), KIND)
+    assert abs(run.reader("flash_roofline.dp4")(ctx) - by_hand()) < 1e-9
+    for ok, what in checks(synth(devices=(0, 1, 2, 3))):
+        assert ok, what
+
+
+def test_recorded_trace_reads_what_the_reader_by_kernel_names_read():
+    """The chip trace under ``testdata/``: the values recorded before the
+    readers were rewritten, and the four trace checks."""
+    selfcheck.check_attention()
+
+
+# -- (b) what the trace checks refuse -----------------------------------------
+
+WRONG = {
+    "no_backward_event": (dict(cut=()), "hetu_flash_bwd"),
+    "forward_on_the_global_batch": (dict(fwd="bf16_3072_512_64"),
+                                    "local shard"),
+    "forward_in_f32": (dict(fwd="f32_768_512_64"), "local shard"),
+    "eleven_calls_where_twelve_layers": (dict(layers=11), "forward calls"),
+    "a_device_without_flash": (dict(devices=(0, 1), bare=(1,)),
+                               "without one: [1]"),
+    "no_flash_at_all": (dict(bare=(0,)), "without one: [0]"),
+}
+
+
+@pytest.mark.parametrize("case", WRONG)
+def test_wrong_traces_fail_the_check_that_names_the_fault(case):
+    kwargs, names_it = WRONG[case]
+    failed = [what for ok, what in checks(synth(**kwargs)) if not ok]
+    assert failed and any(names_it in what for what in failed), failed
+
+
+def test_a_named_kernel_that_is_missing_still_fails_by_name():
+    prog = program()
+    prog.KERNELS = KERNELS + ("hetu_moe_gmm_fwd",)
+    ok, what = checks(synth(), prog)[0]
+    assert not ok and "hetu_moe_gmm_fwd" in what
+
+
+def test_one_layer_at_head_128_as_the_olmoe_cell():
+    shapes = {"flash_dims": (2, 16, 4096, 128), "flash_rows": 32,
+              "flash_elements": 2 * 16 * 4096 * 128, "head_dim": 128,
+              "attention_layers": 1}
+    prog = selfcheck.RecordedProgram(dict(SHAPES, **shapes), 4096, KERNELS)
+    for ok, what in checks(synth("bf16_32_4096_128", layers=1), prog):
+        assert ok, what
+    failed = [w for ok, w in checks(synth("bf16_32_4096_128", layers=2),
+                                    prog) if not ok]
+    assert len(failed) == 1 and "forward calls" in failed[0]
+
+
+# -- (c) the head transposes ---------------------------------------------------
+
+COPIES = {
+    # key: counted?
+    "copy_bf16_64_12_512_64": True,
+    "copy_bf16_64_512_12_64": True,
+    "transpose_bf16_12_64_512_64": True,
+    "transpose_jvp_hetu_flash_bwd_dkv___bf16_768_512_64_bf16_768_512_64":
+        False,
+    "copy_bf16_64_512_768": False,
+    "copy_f32_64_12_512_64": False,
+    "copy_bf16_64_12_512_128": False,
+    "copy_bf16_64_12_512_64_bf16_64_12_512_64": False,
+    "copy-done_bf16_64_12_512_64": False,
+    "copy-start_bf16_64_12_512_64_bf16_64_12_512_64_u32": False,
+    "fusion_bf16_64_12_512_64": False,
+}
+
+
+@pytest.mark.parametrize("key", COPIES)
+def test_layout_copy_counts_xlas_copies_of_the_heads_only(key):
+    reduced = synth(extra=[(key, 0.25e6)] * 8, devices=(0, 1))
+    ctx = selfcheck.trace_ctx(reduced, program(), KIND)
+    got = run.reader("attn_layout_copy_ms_per_step")(ctx)
+    assert got == pytest.approx(2.0 if COPIES[key] else 0.0, abs=1e-12)
+    assert isinstance(got, float)
+
+
+@pytest.mark.parametrize("name", ["attn_layout_copy_ms_per_step",
+                                  "attn_layout_copy_ms_per_step.dp4",
+                                  "attn_layout_copy_ms_per_step.olmoe"])
+def test_layout_copy_is_zero_with_flash_and_nothing_without(name):
+    read = run.reader(name)
+    with_flash = selfcheck.trace_ctx(synth(), program(), KIND)
+    assert read(with_flash) == 0.0
+    without = selfcheck.trace_ctx(synth(bare=(0,)), program(), KIND)
+    assert read(without) is None
+    assert read(dict(with_flash, trace=None)) is None
+
+
+def test_layout_copy_says_what_it_took():
+    said = []
+    reduced = synth(extra=[("copy_bf16_64_12_512_64", 0.18e6)] * 84
+                    + [("copy_bf16_64_512_12_64", 0.2e6)] * 12)
+    ctx = selfcheck.trace_ctx(reduced, program(), KIND, said.append)
+    got = run.reader("attn_layout_copy_ms_per_step")(ctx)
+    assert got == pytest.approx(84 * 0.18 + 12 * 0.2)
+    assert "copy_bf16_64_12_512_64 x 84 = 15.120 ms" in said[0]
+    assert "copy_bf16_64_512_12_64 x 12 = 2.400 ms" in said[0]
+
+
+# -- the parts ----------------------------------------------------------------
+
+@pytest.mark.parametrize("key, after, want", [
+    ("jvp_hetu_flash_fwd__bf16_768_512_64_f32_768_1_512", "hetu_flash_fwd",
+     ("bf16", (768, 512, 64))),
+    ("hetu_flash_fwd_bf16_64_512_12_64", "hetu_flash_fwd",
+     ("bf16", (64, 512, 12, 64))),
+    ("hetu_flash_fwd_g128__bf16_64_512_768_f32_64_6_512", "hetu_flash_fwd",
+     ("bf16", (64, 512, 768))),
+    ("fusion_f32_3072_768_f32_3072_768", "", ("f32", (3072, 768))),
+    ("reduce_f32", "", ("f32", ())),
+    ("hetu_flash_fwd", "hetu_flash_fwd", None),
+    ("copy_bf16_64_12_512_64", "hetu_flash_fwd", None),
+])
+def test_first_result_of_an_op_key(key, after, want):
+    assert tr.first_result(key, after) == want
+
+
+def test_first_result_inverts_op_key():
+    name = ("%jvp_hetu_flash_fwd_.7 = (bf16[64,512,768]{2,1,0:T(8,128)(2,1)}"
+            ", f32[768,1,512]{2,1,0}) custom-call(bf16[64,512,768] %a)")
+    assert tr.first_result(tr.op_key(name), "hetu_flash_fwd") == (
+        "bf16", (64, 512, 768))
+
+
+def test_flash_passes_name_events_not_kernels():
+    assert {p["events"] for p in flops.FLASH_PASSES.values()} == {
+        "hetu_flash_fwd", "hetu_flash_bwd"}
+    assert flops.flash_pass("forward", 768, 512, 64) == (
+        2 * 2.0 * 768 * 512 * 512 * 64, 4.0 * 768 * 512 * 64 * 2)
+    assert flops.flash_pass("backward", 768, 512, 64) == (
+        5 * 2.0 * 768 * 512 * 512 * 64, 8.0 * 768 * 512 * 64 * 2)
+
+
+# -- the benchmark's own files agree with one another ---------------------------
+
+def bench():
+    return run.load_json(run.ROOT, "BENCHMARK.json")
+
+
+def test_every_per_layer_metric_has_a_reader_and_every_reader_a_metric():
+    names = {m["name"] for m in bench()["per_layer"]}
+    files = {f[:-3] for f in os.listdir(os.path.join(run.HERE, "metrics"))
+             if f.endswith(".py") and not f.startswith("_")}
+    for name in names:
+        assert callable(run.reader(name)), name
+    assert files - {"train_tokens_per_s"} <= (
+        names | {n.split(".")[0] for n in names})
+    assert not {n for n in names | files if n.startswith(
+        ("data_wait_ms_per_step", "executor_host_ms_per_step"))}
+
+
+@pytest.mark.parametrize("suffix, cell, moves", [
+    ("", "bert-base.b64-s512", "train_tokens_per_s"),
+    (".dp4", "bert-base.dp4-b256-s512", "train_tokens_per_s.dp4"),
+    (".olmoe", "olmoe-1b-7b.b2-s4096", "train_tokens_per_s")])
+def test_the_layout_copy_metric_is_declared_in_each_cell(suffix, cell, moves):
+    entry = next(m for m in bench()["per_layer"]
+                 if m["name"] == "attn_layout_copy_ms_per_step" + suffix)
+    assert entry == {"name": entry["name"], "unit": "ms", "better": "lower",
+                     "source": "device_trace", "layer": "kernels",
+                     "moves": moves, "workloads": [cell]}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in bench()["workloads"]])
+def test_builders_state_the_attention_work_consistently(cell):
+    """Each cell's program at toy size: the element count is the product of
+    the four dimensions and of rows x positions x head size."""
+    _, entry, config, mix = run.load_cell(cell)
+    config, mix = run.merge(config, config["toy"]), run.merge(mix, mix["toy"])
+    if entry["chips"] > 1:
+        import jax
+        if len(jax.devices()) < entry["chips"]:
+            pytest.skip("needs --xla_force_host_platform_device_count=4")
+    builder = importlib.import_module("chipbench.builders."
+                                      + config["builder"])
+    prog = builder.build(config, mix, 2 ** 31 + 5, lambda msg: None)
+    try:
+        want = prog.expected_kernel_shapes()
+    finally:
+        prog.close()
+    b, h, s, d = want["flash_dims"]
+    assert (s, d) == (prog.seq, want["head_dim"])
+    assert want["flash_rows"] == b * h
+    assert want["flash_elements"] == b * h * s * d
+    assert want["attention_layers"] == config["num_hidden_layers"]
+    assert want["compute_dtype"] in tr.HLO_DTYPES
+    assert not any("flash" in k for k in prog.KERNELS)

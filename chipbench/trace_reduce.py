@@ -57,6 +57,24 @@ def op_key(name):
             .strip("_"))[:96]
 
 
+#: element types as HLO text spells them, by the name jax.numpy gives them
+HLO_DTYPES = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
+RESULT = re.compile(r"(?:^|_)(pred|bf16|[fsuc]\d+)((?:_\d+)*)(?=_|$)")
+
+
+def first_result(key, after=""):
+    """``(dtype, dims)`` of the first result in an ``op_key``, looked for
+    after the first occurrence of ``after`` (an operation's own name may
+    hold what reads like a type), or None: ``("bf16", (768, 512, 64))`` of
+    ``jvp_hetu_flash_fwd__bf16_768_512_64_f32_768_1_512``.  A key is cut at
+    96 characters; a first result that does not fit reads short, not right."""
+    at = key.find(after)
+    m = RESULT.search(key, at + len(after)) if at >= 0 else None
+    if not m:
+        return None
+    return m.group(1), tuple(int(d) for d in m.group(2).split("_")[1:])
+
+
 def load(path, annotations):
     """``{"devices": {id: [(start, dur, key), ...]}, "modules": {id:
     [(start, dur, program), ...]}, "host": [(start, dur, name), ...]}`` of
@@ -170,6 +188,24 @@ def window_of(host, step_names):
     if not steps:
         raise ValueError(f"the trace holds no host span of {step_names}")
     return min(s for s, _ in steps), max(e for _, e in steps)
+
+
+def count_spans(host, names):
+    """How many host spans named in ``names`` the trace holds: the steps of
+    the traced window, which ``window_of`` draws around them."""
+    return sum(1 for _, _, n in host if n in names)
+
+
+def events_holding(reduced, lo, hi, part):
+    """``{device: [(start, dur, key), ...]}``: for every device on which an
+    operation started in ``[lo, hi]``, those of them whose key holds
+    ``part`` (an empty list where none does; all of them for ``""``)."""
+    out = {}
+    for dev, events in reduced["devices"].items():
+        inside = [e for e in events if lo <= e[0] <= hi]
+        if inside:
+            out[dev] = [e for e in inside if part in e[2]]
+    return out
 
 
 def op_totals(events, lo, hi):
