@@ -4,7 +4,10 @@ Reference: /root/reference/python/hetu/layers/attention.py MultiHeadAttention
 (the reference flattens to [B*S, H] between every projection).  Here the
 layer keeps the [B, S, H] layout end to end — projections are 3D matmuls XLA
 maps straight onto the MXU — and the core product is a single fused-attention
-op (ops/attention.py) lowered to Pallas flash attention on TPU.
+op (ops/attention.py) lowered to Pallas flash attention on TPU, which reads
+the projections' [B, S, heads*d] and writes the context in place: the layer
+moves no heads.  Grouped-query and ALiBi layers (and the inference graphs'
+fused head projection) keep the [B, heads, S, d] graph.
 
 Position-encoding variants for the Llama/Baichuan model tier (reference
 tools/Hetu-Galvatron/galvatron/models/llama, models/baichuan): ``rope_theta``
@@ -105,6 +108,37 @@ class MultiHeadAttention(BaseLayer):
                 "kv_seq_len != seq_len is only supported for non-causal, "
                 "non-rotary, non-alibi cross-attention")
         kv_seq_len = kv_seq_len or seq_len
+        if (self.fused_head_projection or self.alibi
+                or self.num_kv_heads != self.num_heads):
+            return self._attend_bhsd(query, key, value, attention_mask,
+                                     seq_len, kv_seq_len)
+        q, k, v = self.q_proj(query), self.k_proj(key), self.v_proj(value)
+        if self.q_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if self.rope_theta is not None:
+            q = self._rotate(q, seq_len)
+            k = self._rotate(k, kv_seq_len)
+        # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
+        q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.hidden_size))
+                   for x, n in ((q, seq_len), (k, kv_seq_len),
+                                (v, kv_seq_len)))
+        ctx_ = scaled_dot_product_attention_op(
+            q, k, v, mask=attention_mask, causal=self.causal,
+            dropout_keep=self.dropout_keep, num_heads=self.num_heads)
+        return self.out_proj(ctx_)
+
+    def _rotate(self, x, seq_len):
+        # rotary on the free [B, S, heads, d] view of the projection
+        x = array_reshape_op(
+            x, output_shape=(-1, seq_len, self.num_heads, self.head_dim))
+        x = rotary_embedding_op(x, theta=self.rope_theta, seq_axis=1)
+        return array_reshape_op(
+            x, output_shape=(-1, seq_len, self.hidden_size))
+
+    def _attend_bhsd(self, query, key, value, attention_mask, seq_len,
+                     kv_seq_len):
+        """The [B, heads, S, d] graph: heads split off and transposed
+        before the op, the context transposed back."""
         q = self._project_heads(query, self.q_proj, seq_len,
                                 self.num_heads, self.q_norm)
         k = self._project_heads(key, self.k_proj, kv_seq_len,

@@ -348,21 +348,25 @@ def _rms_norm_exp(node, ctx):
 
 @exporter("rotary_embedding")
 def _rotary_exp(node, ctx):
-    """RoPE (HF rotate_half convention) on [B, H, S, D]: the cos/sin
-    tables are precomputed constants (shapes are static), the rotation is
-    Slice/Neg/Concat/Mul/Add — plain opset ops (ops/rotary.py:33)."""
+    """RoPE (HF rotate_half convention) on [B, H, S, D], or on [B, S, H, D]
+    with ``seq_axis=1``: the cos/sin tables are precomputed constants
+    (shapes are static), the rotation is Slice/Neg/Concat/Mul/Add — plain
+    opset ops (ops/rotary.py:33)."""
     shape = ctx.shapes.get(node.inputs[0])
     if shape is None:
         raise NotImplementedError(
             "rotary_embedding export needs inferred shapes "
             "(placeholders must declare shapes)")
-    s, d = int(shape[-2]), int(shape[-1])
+    seq_axis = int(node.attrs.get("seq_axis", -2))
+    s, d = int(shape[seq_axis]), int(shape[-1])
     theta = float(node.attrs.get("theta", 10000.0))
     off = int(node.attrs.get("pos_offset", 0))
     pos = np.arange(off, off + s, dtype=np.float32)
     inv = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
     freqs = np.outer(pos, inv)
-    emb = np.concatenate([freqs, freqs], axis=-1)[None, None]   # [1,1,S,D]
+    along = [1] * len(shape)
+    along[seq_axis], along[-1] = s, d
+    emb = np.concatenate([freqs, freqs], axis=-1).reshape(along)
     cosc = ctx.const(f"{node.name}_cos", np.cos(emb).astype(np.float32))
     sinc = ctx.const(f"{node.name}_sin", np.sin(emb).astype(np.float32))
     ax = ctx.const(f"{node.name}_ax", np.asarray([-1], np.int64))
@@ -458,21 +462,39 @@ def _export_sdpa(node, ctx):
         raise NotImplementedError(
             f"attention export for {node.name} needs inferable shapes "
             "(declare placeholder shapes)")
-    d = qshape[-1]
-    scale = node.scale if node.scale is not None else 1.0 / float(np.sqrt(d))
     out = []
+    heads = node.num_heads
+    if heads is None:
+        d, s_q = qshape[-1], qshape[-2]
+        s_k = ctx.shapes.get(k, qshape)[-2]
+        q, k, v = q.name, k.name, v.name
+    else:
+        # [B, S, H*D] operands: Reshape + Transpose to [B, H, S, D] here,
+        # and the context back below
+        d, s_q = qshape[-1] // heads, qshape[-2]
+        s_k = ctx.shapes.get(k, qshape)[-2]
+
+        def split(x, tag, seq):
+            shp = ctx.const(f"{node.name}_{tag}_shape",
+                            np.asarray([-1, seq, heads, d], np.int64))
+            rs, tr = (ctx.aux(f"{node.name}_{tag}_{part}")
+                      for part in ("rs", "heads"))
+            out.append(NodeIR("Reshape", [x.name, shp], [rs]))
+            out.append(NodeIR("Transpose", [rs], [tr],
+                              {"perm": (0, 2, 1, 3)}))
+            return tr
+        q, k, v = split(q, "q", s_q), split(k, "k", s_k), split(v, "v", s_k)
+    scale = node.scale if node.scale is not None else 1.0 / float(np.sqrt(d))
     kt = ctx.aux(f"{node.name}_kT")
-    out.append(NodeIR("Transpose", [k.name], [kt], {"perm": (0, 1, 3, 2)}))
+    out.append(NodeIR("Transpose", [k], [kt], {"perm": (0, 1, 3, 2)}))
     scores = ctx.aux(f"{node.name}_scores")
-    out.append(NodeIR("MatMul", [q.name, kt], [scores]))
+    out.append(NodeIR("MatMul", [q, kt], [scores]))
     cur = ctx.aux(f"{node.name}_scaled")
     out.append(NodeIR("Mul", [scores,
                               ctx.const(f"{node.name}_scale",
                                         np.asarray(scale, np.float32))],
                       [cur]))
     if node.causal:
-        s_q = qshape[-2]
-        s_k = ctx.shapes.get(k, qshape)[-2]
         causal = np.where(
             np.arange(s_q)[:, None] >= np.arange(s_k)[None, :] - (s_k - s_q),
             0.0, -1e9).astype(np.float32)[None, None]
@@ -486,8 +508,18 @@ def _export_sdpa(node, ctx):
         cur = nxt
     probs = ctx.aux(f"{node.name}_probs")
     out.append(NodeIR("Softmax", [cur], [probs], {"axis": -1}))
-    out.append(NodeIR("MatMul", [probs, v.name], [node.name],
-                      name=node.name))
+    if heads is None:
+        out.append(NodeIR("MatMul", [probs, v], [node.name], name=node.name))
+        return out
+    ctx_heads, ctx_rows = (ctx.aux(f"{node.name}_ctx_{part}")
+                           for part in ("heads", "rows"))
+    out.append(NodeIR("MatMul", [probs, v], [ctx_heads]))
+    out.append(NodeIR("Transpose", [ctx_heads], [ctx_rows],
+                      {"perm": (0, 2, 1, 3)}))
+    out.append(NodeIR("Reshape", [ctx_rows, ctx.const(
+        f"{node.name}_ctx_shape", np.asarray([-1, s_q, heads * d],
+                                             np.int64))],
+        [node.name], name=node.name))
     return out
 
 

@@ -21,8 +21,17 @@ from ..graph.node import Op
 _FLASH_MIN_SEQ = 256  # below this the jnp path is faster (kernel overheads)
 
 
-def _flash_plan(q, k, v, mask, keep, mesh):
-    """Decide how attention lowers for these operands.
+def _split_heads(x, num_heads):
+    """[B, S, H*D] -> [B, H, S, D]: a transpose, not a view."""
+    b, s, width = x.shape
+    return x.reshape(b, s, num_heads, width // num_heads).transpose(
+        0, 2, 1, 3)
+
+
+def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None):
+    """Decide how attention lowers for these operands: ``[B, H, S, D]``,
+    or ``[B, S, H*D]`` with ``num_heads``, which is planned as the 4-D
+    array it is a view of.
 
     Returns ``(reason, batch_axes, head_axes)``: ``reason`` is None when
     the Pallas flash kernel runs (under ``shard_map`` over the named mesh
@@ -32,9 +41,11 @@ def _flash_plan(q, k, v, mask, keep, mesh):
     of at least ``_FLASH_MIN_SEQ`` and 8-aligned head sizes in
     [32, 512]."""
     from .pallas import dispatch
-    from .pallas.flash_attention import unsupported
+    from .pallas.flash_attention import heads_view, unsupported
     if not dispatch.mosaic():
         return f"platform:{dispatch.platform()}", (), ()
+    if num_heads is not None:
+        q, k, v = (heads_view(x, num_heads) for x in (q, k, v))
     why = unsupported(q, k, v, mask, keep)
     if why is not None:
         return why, (), ()
@@ -49,11 +60,17 @@ def _flash_plan(q, k, v, mask, keep, mesh):
 
 
 class ScaledDotProductAttentionOp(Op):
+    """q, k, v ``[B, H, S, D]`` -> ``[B, H, S, D]``; with ``num_heads``,
+    the projections' ``[B, S, H*D]`` -> ``[B, S, H*D]``: the flash kernel
+    then reads and writes the heads in place, and the jnp composition goes
+    through the free ``[B, S, H, D]`` view."""
+
     def __init__(self, q, k, v, mask=None, causal=False, scale=None,
-                 dropout_keep=1.0, name=None):
+                 dropout_keep=1.0, num_heads=None, name=None):
         inputs = [q, k, v] + ([mask] if mask is not None else [])
         super().__init__(*inputs, name=name)
         self.has_mask = mask is not None
+        self.num_heads = num_heads
         self.causal = causal
         self.scale = scale
         self.dropout_keep = dropout_keep
@@ -65,7 +82,41 @@ class ScaledDotProductAttentionOp(Op):
     def _compute(self, input_vals, ctx):
         q, k, v = input_vals[:3]
         mask = input_vals[3] if self.has_mask else None
-        d = q.shape[-1]
+        heads = self.num_heads
+        if heads is None:
+            return self._attend(q, k, v, mask, ctx, None)
+        if self._stays_in_place(q, k, v, mask, ctx):
+            return self._attend(q, k, v, mask, ctx, heads)
+        # the ring walks [B, H, S, D], and so does the kernel where a
+        # shard's heads do not come in lane-aligned groups
+        out = self._attend(*(_split_heads(x, heads) for x in (q, k, v)),
+                           mask, ctx, None)
+        return out.transpose(0, 2, 1, 3).reshape(q.shape)
+
+    def _keep(self, ctx):
+        return self.dropout_keep if ctx.training else 1.0
+
+    def _stays_in_place(self, q, k, v, mask, ctx):
+        """Whether [B, S, H*D] operands are attended as they lie: the jnp
+        composition reads them through a free view, the kernel needs each
+        shard's heads in groups of 128 lanes, the ring takes neither."""
+        from .pallas.flash_attention import heads_per_program
+        if ctx.mesh is not None and ctx.mesh.shape.get("cp", 1) > 1:
+            return False
+        why, _, head_axes = _flash_plan(q, k, v, mask, self._keep(ctx),
+                                        ctx.mesh, self.num_heads)
+        if why is not None:
+            return True
+        shards = 1
+        for axis in head_axes:
+            shards *= ctx.mesh.shape[axis]
+        return heads_per_program(self.num_heads // shards,
+                                 q.shape[-1] // self.num_heads) > 0
+
+    def _attend(self, q, k, v, mask, ctx, heads):
+        """``heads`` is None for [B, H, S, D] operands, the head count for
+        [B, S, H*D]."""
+        d = q.shape[-1] // (heads or 1)
         scale = self.scale if self.scale is not None else 1.0 / (d ** 0.5)
         # long-context: when the executor's mesh has a 'cp' axis, the
         # sequence dim is context-sharded — lower to flash ring attention
@@ -89,9 +140,9 @@ class ScaledDotProductAttentionOp(Op):
             from ..parallel.context_parallel import ring_attention
             return ring_attention(ctx.mesh, q, k, v, causal=self.causal,
                                   scale=scale)
-        keep = self.dropout_keep if ctx.training else 1.0
+        keep = self._keep(ctx)
         why, batch_axes, head_axes = _flash_plan(q, k, v, mask, keep,
-                                                 ctx.mesh)
+                                                 ctx.mesh, heads)
         from .pallas import dispatch
         if dispatch.record("flash_attention", why):
             from .pallas.flash_attention import (flash_attention,
@@ -101,13 +152,17 @@ class ScaledDotProductAttentionOp(Op):
                 seed = jax.random.bits(ctx.rng_for(self), (1,),
                                        "uint32").astype(jnp.int32)
             kw = dict(mask=mask, causal=self.causal, scale=scale,
-                      dropout_keep=keep, seed=seed)
+                      dropout_keep=keep, seed=seed, num_heads=heads)
             if batch_axes or head_axes:
                 return sharded_flash_attention(
                     ctx.mesh, q, k, v, batch_axes=batch_axes,
                     head_axes=head_axes, **kw)
             return flash_attention(q, k, v, **kw)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+        qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
+        if heads is not None:
+            q, k, v = (x.reshape(*x.shape[:2], heads, d) for x in (q, k, v))
+            qk, pv = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
+        scores = jnp.einsum(qk, q, k,
                             preferred_element_type=jnp.float32) * scale
         if self.causal:
             s_q, s_k = scores.shape[-2], scores.shape[-1]
@@ -121,12 +176,14 @@ class ScaledDotProductAttentionOp(Op):
             keep = jax.random.bernoulli(ctx.rng_for(self), self.dropout_keep,
                                         probs.shape)
             probs = jnp.where(keep, probs / self.dropout_keep, 0.0)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32).astype(v.dtype)
+        out = jnp.einsum(pv, probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32).astype(v.dtype)
+        return out if heads is None else out.reshape(*out.shape[:2], -1)
 
 
 def scaled_dot_product_attention_op(q, k, v, mask=None, causal=False,
-                                    scale=None, dropout_keep=1.0, name=None):
+                                    scale=None, dropout_keep=1.0,
+                                    num_heads=None, name=None):
     return ScaledDotProductAttentionOp(q, k, v, mask=mask, causal=causal,
                                        scale=scale, dropout_keep=dropout_keep,
-                                       name=name)
+                                       num_heads=num_heads, name=name)

@@ -78,11 +78,17 @@ def record(kernel, reason=None):
     return reason is None
 
 
+def counted(name):
+    """``[(labels, count), ...]`` of a trace-time counter's non-zero
+    samples (none while telemetry is disabled, since the registry then
+    counts nothing)."""
+    metric = telemetry.get_registry().snapshot().get(name, {"samples": []})
+    return [(s["labels"], int(s["value"]))
+            for s in metric["samples"] if s["value"]]
+
+
 def choices():
     """``{(kernel, impl, reason): count}`` recorded so far (empty while
-    telemetry is disabled, since the registry then counts nothing)."""
-    metric = telemetry.get_registry().snapshot().get(
-        "hetu_kernel_choice_total", {"samples": []})
-    return {(s["labels"]["kernel"], s["labels"]["impl"],
-             s["labels"]["reason"]): int(s["value"])
-            for s in metric["samples"] if s["value"]}
+    telemetry is disabled)."""
+    return {(lab["kernel"], lab["impl"], lab["reason"]): n
+            for lab, n in counted("hetu_kernel_choice_total")}

@@ -1,35 +1,61 @@
-"""Pallas TPU flash attention (FlashAttention-2 style, fwd + bwd kernels).
+"""Pallas TPU flash attention (FlashAttention-2 style): one forward and one
+backward kernel, reading and writing the heads where they lie.
 
 The reference composes attention from batched matmuls + a full [B,H,S,S]
 softmax (layers/attention.py) — O(S^2) HBM traffic, which OOMs BERT-base at
-per-chip batch 64.  This kernel keeps the score tile in VMEM with online
-softmax, so HBM traffic stays O(S·d):
+per-chip batch 64.  These kernels keep the score tile in VMEM with online
+softmax, so HBM traffic stays O(S·d).
 
-  forward : grid (B*H, S/block_q); the kv loop runs inside the kernel with
-            running (m, l, acc) carries; saves the logsumexp for backward.
-  backward: two kernels — dQ over q blocks, dK/dV over kv blocks — that
-            recompute P tiles from (Q, K, lse) instead of storing them
-            (the standard flash backward: dS = P∘(dO·Vᵀ − D),
-            D = rowsum(dO∘O)).
+Two layouts, one pair of kernel bodies.  The layers hand over the
+projections' ``[B, S, H*D]`` and get the context back the same way
+(``num_heads`` given): a program then walks ``g = 128 // D`` neighbouring
+heads (2 at head size 64, 4 at 32, 1 at a multiple of 128) as one block
+``(rows, g*D)``, 128 lanes wide, cut out of the array in place — no
+transpose on either side of either pass.  Callers that own ``[B, H, S, D]``
+(ring and Ulysses attention, Galvatron) and head sizes whose groups are not
+lane-aligned (80, 96) run the same bodies with ``g = 1`` over the free
+``[B*H, S, D]`` view; only the ``BlockSpec`` index maps differ (``_Walk``).
+Inside a program the heads are a static loop.  Head ``h``'s products are
+taken over the block's whole lane width with the other heads' lanes zeroed
+in one operand (``_only_head``): on a 128 x 128 matrix unit a contraction
+over 64 or an output 64 wide costs the same passes as one over 128, the
+zeros add nothing to the f32 sums, and the heads' outputs land in their own
+lanes of one tile, which is stored once.
+
+  forward : ``hetu_flash_fwd``, grid (B, H/g, Sq/block_q); the kv loop runs
+            inside the kernel with running (m, l, acc) carries; saves the
+            logsumexp ``[B, H/g, g, Sq]`` for the backward pass.
+  backward: ``hetu_flash_bwd``, grid (B, H/g, Sk/block_k) over key blocks,
+            a loop over the query blocks that see the key block inside.  A
+            tile's S, exp, dropout bits, dP and dS = P∘(dP − D) are formed
+            once and feed dV += P^T dO, dK += dS^T Q and dQ[i] += dS K.  dQ
+            accumulates in f32 VMEM scratch for the whole (Sq, g*D) of the
+            head group, resident across the key-block axis (the grid is
+            sequential), and is written at the last key block.
+            D = rowsum(dO∘O) comes from the O and dO rows the program
+            already holds.
   dropout : applied to the probability tiles in-kernel with the TPU PRNG,
-            reseeded per (seed, bh, q-block, kv-block) tile so the backward
-            kernels replay the identical mask; l accumulates un-dropped
-            sums so O = dropout(softmax(S))·V exactly.
+            reseeded per (seed, batch row, head, q-block, kv-block) tile so
+            the backward replays the identical mask in either layout; l
+            accumulates un-dropped sums so O = dropout(softmax(S))·V
+            exactly.  The 1/keep factor is applied to the (rows, g*D)
+            results, not to the (block_q, block_k) tiles.
 
 Supported: additive key mask [B, 1, 1, S] (BERT padding masks), causal,
-any head dim ≤ 512 and any seq ≥ 128: the wrapper zero-pads d to the
-8-aligned [32, 512] kernel envelope and pads seq up to a block multiple
-with -inf key-column masking, then slices the output (padding/slicing sit
-OUTSIDE the custom_vjp, so jnp.pad's own VJP zeroes the padded rows'
-cotangents and the gradients stay exact).  Returns None only for truly
-unsupported cases (d > 512, short seqs where the O(S^2) composition is
-cheaper, non-[B,1,1,S] masks) so callers fall back to the jnp composition
-(ops/attention.py).
+any head dim ≤ 512 and any seq ≥ 128: the wrapper pads seq up to a block
+multiple with -inf key-column masking and, in the 4-D layout, zero-pads d
+to the 8-aligned [32, 512] kernel envelope, then slices the output
+(padding/slicing sit OUTSIDE the custom_vjp, so jnp.pad's own VJP zeroes
+the padded rows' cotangents and the gradients stay exact).  Returns None
+only for truly unsupported cases (d > 512, short seqs where the O(S^2)
+composition is cheaper, non-[B,1,1,S] masks) so callers fall back to the
+jnp composition (ops/attention.py).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -37,7 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import interpret
+from ... import telemetry
+from .dispatch import counted, interpret
 
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
@@ -45,12 +72,13 @@ _LN2 = 0.6931471805599453
 _BLOCK_Q = 512
 _BLOCK_K = 512
 _NEG_INF = -1e30
+_LANES = 128
 
 
 def unsupported(q, k, v, mask=None, dropout_keep=1.0):
-    """Why the kernel cannot take these operands, or None when it can.
-    Callers that fall back to the jnp composition record this string
-    (ops/pallas/dispatch.py)."""
+    """Why the kernel cannot take these ``[B, H, S, D]`` operands, or None
+    when it can.  Callers that fall back to the jnp composition record this
+    string (ops/pallas/dispatch.py)."""
     if q.ndim != 4 or k.shape != q.shape or v.shape != q.shape:
         return "not_self_attention_4d"
     b, h, s, d = q.shape
@@ -67,6 +95,75 @@ def unsupported(q, k, v, mask=None, dropout_keep=1.0):
     if dropout_keep < 1.0 and interpret():
         return "dropout_prng_needs_mosaic"
     return None
+
+
+def heads_view(x, num_heads):
+    """The shape ``[B, H, S, D]`` of which ``x`` ``[B, S, H*D]`` is a view
+    (no array: what ``unsupported`` and the mesh plan read)."""
+    b, s, width = x.shape
+    return jax.ShapeDtypeStruct((b, num_heads, s, width // num_heads),
+                                x.dtype)
+
+
+def heads_per_program(num_heads, head_dim):
+    """Heads one program takes when ``[B, S, H*D]`` is read in place: as
+    many as fill the 128 lanes of a block; 0 when the heads cannot be cut
+    out of that array in lane-aligned groups (head sizes 80 or 96, a head
+    count the group does not divide), which is the ``[B, H, S, D]`` walk's
+    case."""
+    if head_dim % _LANES == 0:
+        return 1
+    if head_dim < 32 or _LANES % head_dim:
+        return 0
+    group = _LANES // head_dim
+    return 0 if num_heads % group else group
+
+
+class _Walk(NamedTuple):
+    """How the kernels address one call's tensors.  ``bshd`` is ``[B, S,
+    H*D]`` read in place, ``group`` heads a program; ``bhsd`` is ``[B, H, S,
+    D]`` through its free ``[B*H, S, D]`` view, one head a program.  Either
+    way a kernel sees blocks ``(1, rows, group*dim)`` on the grid ``(batch,
+    heads/group, row blocks)``."""
+    layout: str
+    batch: int
+    heads: int
+    dim: int
+    group: int
+
+    @property
+    def width(self):
+        return self.group * self.dim
+
+    def seq(self, x):
+        return x.shape[1 if self.layout == "bshd" else 2]
+
+    def flat(self, x):
+        if self.layout == "bshd":
+            return x
+        return x.reshape(self.batch * self.heads, x.shape[2], self.dim)
+
+    def unflat(self, x):
+        if self.layout == "bshd":
+            return x
+        return x.reshape(self.batch, self.heads, x.shape[1], self.dim)
+
+    def rows(self, pick):
+        """Index map of a row block for the program ``(b, hg, t)``;
+        ``pick(t)`` is the block's index along the rows."""
+        if self.layout == "bshd":
+            return lambda b, hg, t: (b, pick(t), hg)
+        heads = self.heads
+        return lambda b, hg, t: (b * heads + hg, pick(t), 0)
+
+
+def _walk(q, num_heads):
+    if q.ndim == 4:
+        b, h, _, d = q.shape
+        return _Walk("bhsd", b, h, d, 1)
+    b, _, width = q.shape
+    d = width // num_heads
+    return _Walk("bshd", b, num_heads, d, heads_per_program(num_heads, d))
 
 
 def _pad_plan(s):
@@ -96,40 +193,58 @@ def _tile_index(bh, qi, j, nq, nk):
 
 
 def _tile_keep(shape, seed_ref, tile, keep_prob):
-    """The deterministic keep mask for one prob tile.  ALL kernels (fwd,
-    dq, dkv) must obtain masks through this single helper — the backward
-    replays the forward's masks purely by reseeding with the same tile
-    index."""
+    """The deterministic keep mask for one prob tile.  BOTH kernels must
+    obtain masks through this single helper — the backward replays the
+    forward's masks purely by reseeding with the same tile index."""
     pltpu.prng_seed(seed_ref[0], tile)
     bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
     return bits < _keep_threshold(keep_prob)
 
 
-def _drop_tile(p, seed_ref, tile, keep_prob):
-    keep = _tile_keep(p.shape, seed_ref, tile, keep_prob)
-    return jnp.where(keep, p / keep_prob, 0.0)
+def _only_head(x, h, dim, group):
+    """``x`` (rows, group*dim) with the lanes of every head but ``h``
+    zeroed; ``x`` itself where a program has one head."""
+    if group == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * dim) & (lane < (h + 1) * dim), x,
+                     jnp.zeros_like(x))
+
+
+def _nt(a, b):
+    """a @ b^T with f32 accumulation.  Operands stay in their storage
+    dtype (bf16 models hit the MXU's bf16 rate — pre-casting to f32
+    forced f32-rate matmuls, ~4x slower); bf16->f32 is exact, so the
+    numerics on the input side are identical."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # -- forward ---------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 o_ref, lse_ref, *, scale, causal, block_k, q_len, k_len,
-                keep_prob, empty_lse_neg=False):
+                keep_prob, heads, group, empty_lse_neg=False):
     """offs_ref (optional SMEM int32[2] = [q_off, k_off]): GLOBAL sequence
     offsets of the local q/k blocks — the ring-attention path attends a
     rotating remote K/V block, so causal masking compares global positions.
     ``empty_lse_neg``: blockwise callers need lse=-inf semantics for rows
     with no live key in THIS block (so the cross-block logaddexp combine
     ignores them); self-attention callers need +inf (see comment below)."""
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    bq = q_ref.shape[1]
-    d = q_ref.shape[2]
-    # inputs stay in their storage dtype (bf16 models hit the MXU's
-    # bf16 rate — pre-casting to f32 forced f32-rate matmuls, ~4x
-    # slower); products/accumulation are f32 via preferred_element_type,
-    # identical numerics on the input side (bf16->f32 casts are exact)
-    q = q_ref[0]                                      # (bq, d)
+    b, hg, qi = (pl.program_id(a) for a in range(3))
+    bq, width = q_ref.shape[1], q_ref.shape[2]
+    dim = width // group
+    q_all = q_ref[0]                                  # (bq, g*d)
     q_off = offs_ref[0] if offs_ref is not None else 0
     k_off = offs_ref[1] if offs_ref is not None else 0
     row = (q_off + qi * bq
@@ -144,18 +259,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         nk_causal = jax.lax.clamp(0, hi, nk) if offs_ref is not None \
             else jax.lax.min(nk, hi)
 
-    def make_body(masked):
+    def make_body(q, h, masked):
+        bh = b * heads + hg * group + h
+
         def body(j, carry):
             m, l, acc = carry
             kb = k_ref[0, pl.ds(j * block_k, block_k), :]
-            vb = v_ref[0, pl.ds(j * block_k, block_k), :]
+            vb = _only_head(v_ref[0, pl.ds(j * block_k, block_k), :],
+                            h, dim, group)
             # scores tracked in BASE-2 units (s2 = s * log2(e)): exp2 is
             # the VPU's native exponential; lse converts back to natural
             # units at the end so the backward's exp(s - lse) contract is
             # unchanged
-            s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-                * (scale * _LOG2E)
+            s = _nt(q, kb) * (scale * _LOG2E)
             if mask_ref is not None:
                 s = s + (mask_ref[0, 0,
                                   pl.ds(j * block_k, block_k)][None, :]
@@ -171,364 +287,296 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
             # l accumulates UN-dropped sums: O = dropout(P_norm) @ V
             l_new = l * alpha + jnp.sum(p, axis=1)
             if keep_prob < 1.0:
-                nq, nk_tot = q_len // bq, k_len // block_k
-                p = _drop_tile(p, seed_ref,
-                               _tile_index(bh, qi, j, nq, nk_tot),
-                               keep_prob)
-            acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                nq = q_len // bq
+                keep = _tile_keep(p.shape, seed_ref,
+                                  _tile_index(bh, qi, j, nq, nk), keep_prob)
+                p = jnp.where(keep, p, 0.0)    # 1/keep_prob: at the end
+            acc_new = acc * alpha[:, None] + _nn(p.astype(vb.dtype), vb)
             return m_new, l_new, acc_new
         return body
 
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    if causal and k_len // block_k > 8:
-        # split loop: kv blocks fully below the diagonal need no mask —
-        # the where+iota per tile is pure VPU overhead on ~(nk-1)/nk of
-        # the causal work, alternating with the exp2 on the critical
-        # path.  Only worth it when there are MANY kv blocks (long
-        # context / ring shards); at nk <= ~8 the second loop's
-        # bookkeeping outweighs the saved masking (measured +0.1
-        # ms/layer on GPT-2.7B S=2048 with 512-blocks, -12% kernel time
-        # at S=8192).
-        lo = (q_off + qi * bq - k_off) // block_k
-        n_full = (jax.lax.clamp(0, lo, nk) if offs_ref is not None
-                  else jax.lax.max(0, jax.lax.min(nk, lo)))
-        carry = jax.lax.fori_loop(0, n_full, make_body(False),
-                                  (m0, l0, acc0))
-        m, l, acc = jax.lax.fori_loop(n_full, nk_causal, make_body(True),
-                                      carry)
-    else:
-        m, l, acc = jax.lax.fori_loop(0, nk_causal, make_body(True),
+    out = None
+    for h in range(group):
+        q = _only_head(q_all, h, dim, group)
+        m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((bq,), jnp.float32)
+        acc0 = jnp.zeros((bq, width), jnp.float32)
+        if causal and nk > 8:
+            # split loop: kv blocks fully below the diagonal need no mask —
+            # the where+iota per tile is pure VPU overhead on ~(nk-1)/nk of
+            # the causal work, alternating with the exp2 on the critical
+            # path.  Only worth it when there are MANY kv blocks (long
+            # context / ring shards); at nk <= ~8 the second loop's
+            # bookkeeping outweighs the saved masking (measured +0.1
+            # ms/layer on GPT-2.7B S=2048 with 512-blocks, -12% kernel time
+            # at S=8192).
+            lo = (q_off + qi * bq - k_off) // block_k
+            n_full = (jax.lax.clamp(0, lo, nk) if offs_ref is not None
+                      else jax.lax.max(0, jax.lax.min(nk, lo)))
+            carry = jax.lax.fori_loop(0, n_full, make_body(q, h, False),
                                       (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    m = m * _LN2    # back to natural-log units for the stored lse
-    # fully-masked rows (l == 0, every key at -inf): output is 0; store
-    # lse = +large so the backward's p = exp(s - lse) underflows to 0 —
-    # storing m (≈ -1e30) instead would give p = exp(0) = 1 everywhere
-    # and garbage dq/dk/dv for the row.  Blockwise (ring) callers instead
-    # want -large: their backward uses the COMBINED lse (never empty for a
-    # causal row), and the fwd combine must treat this block as weightless.
-    empty = _NEG_INF if empty_lse_neg else -_NEG_INF
-    lse = jnp.where(l == 0.0, empty, m + jnp.log(l_safe))
-    lse_ref[0, 0] = lse.astype(jnp.float32)
+            m, l, acc = jax.lax.fori_loop(n_full, nk_causal,
+                                          make_body(q, h, True), carry)
+        else:
+            m, l, acc = jax.lax.fori_loop(0, nk_causal,
+                                          make_body(q, h, True),
+                                          (m0, l0, acc0))
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        # acc is zero outside head h's lanes (V's other lanes were zeroed),
+        # so the heads' parts add up to the one tile that is stored
+        part = acc * ((1.0 / keep_prob) / l_safe)[:, None]
+        out = part if out is None else out + part
+        m = m * _LN2    # back to natural-log units for the stored lse
+        # fully-masked rows (l == 0, every key at -inf): output is 0; store
+        # lse = +large so the backward's p = exp(s - lse) underflows to 0 —
+        # storing m (≈ -1e30) instead would give p = exp(0) = 1 everywhere
+        # and garbage dq/dk/dv for the row.  Blockwise (ring) callers
+        # instead want -large: their backward uses the COMBINED lse (never
+        # empty for a causal row), and the fwd combine must treat this
+        # block as weightless.
+        empty = _NEG_INF if empty_lse_neg else -_NEG_INF
+        lse = jnp.where(l == 0.0, empty, m + jnp.log(l_safe))
+        lse_ref[0, 0, h] = lse.astype(jnp.float32)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _make_kern(base, has_mask, has_seed, n_out, has_offs=False, **consts):
+def _make_kern(base, n_in, has_mask, has_seed, has_offs, **consts):
     """Adapts a kernel with optional (mask_ref, seed_ref, offs_ref) slots
-    to the positional ref list pallas_call passes."""
+    after its ``n_in`` tensor operands to the positional ref list
+    pallas_call passes (operands, outputs, scratch)."""
 
     def kern(*refs):
-        n_in = len(refs) - n_out
-        ins = list(refs[:n_in])
-        outs = list(refs[n_in:])
-        offs_ref = ins.pop() if has_offs else None
-        seed_ref = ins.pop() if has_seed else None
-        mask_ref = ins.pop() if has_mask else None
-        base(*ins, mask_ref, seed_ref, offs_ref, *outs, **consts)
+        ins, rest = list(refs[:n_in]), list(refs[n_in:])
+        mask_ref = rest.pop(0) if has_mask else None
+        seed_ref = rest.pop(0) if has_seed else None
+        offs_ref = rest.pop(0) if has_offs else None
+        base(*ins, mask_ref, seed_ref, offs_ref, *rest, **consts)
 
     return kern
 
 
+def _extras(walk, mask, keep_prob, seed, offsets, sk):
+    """The optional operands, in ``_make_kern``'s order, and their specs:
+    the ``[B, 1, 1, Sk]`` mask follows the batch row, seed and offsets are
+    scalars in SMEM."""
+    args, specs = [], []
+    if mask is not None:
+        args.append(mask.reshape(walk.batch, 1, sk).astype(jnp.float32))
+        specs.append(pl.BlockSpec((1, 1, sk), lambda b, hg, t: (b, 0, 0)))
+    if keep_prob < 1.0:
+        args.append(seed.reshape(1).astype(jnp.int32))
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    if offsets is not None:
+        args.append(offsets)
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    return args, specs
+
+
+def _compiler_params(resident_bytes):
+    """Scoped VMEM from the shapes: the blocks and scratch a program holds
+    (blocks are double-buffered) beside its f32 (block_q, block_k) tiles.
+    Mosaic's default 16 MiB holds BERT's 512 rows, not the whole-sequence
+    blocks of a 4,096-row call."""
+    return pltpu.CompilerParams(vmem_limit_bytes=int(
+        min(100 << 20, (24 << 20) + 2 * resident_bytes)))
+
+
 def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
          block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-         empty_lse_neg=False):
+         empty_lse_neg=False, num_heads=None):
     """q: [b,h,sq,d]; k,v: [b,h,sk,d] (sq != sk in the blockwise/ring path,
-    where ``offsets`` = int32[2] global [q_off, k_off])."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-        pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0)),
-    ]
-    args = [qf, kf, vf]
-    if mask is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, sk), lambda bh, i, h=h: (bh // h, 0, 0)))
-        args.append(mask.reshape(b, 1, sk).astype(jnp.float32))
-    if keep_prob < 1.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(seed.reshape(1).astype(jnp.int32))
-    if offsets is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(offsets)
-    kern = _make_kern(_fwd_kernel, mask is not None, keep_prob < 1.0, 2,
-                      has_offs=offsets is not None,
+    where ``offsets`` = int32[2] global [q_off, k_off]), or all three
+    [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq])."""
+    w = _walk(q, num_heads)
+    sq, sk = w.seq(q), w.seq(k)
+    q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
+    kv_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
+    extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
+    kern = _make_kern(_fwd_kernel, 3, mask is not None, keep_prob < 1.0,
+                      offsets is not None,
                       scale=scale, causal=causal, block_k=block_k,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
+                      heads=w.heads, group=w.group,
                       empty_lse_neg=empty_lse_neg)
+    groups = w.heads // w.group
+    item = q.dtype.itemsize
     o, lse = pl.pallas_call(
         kern,
         name="hetu_flash_fwd",
         interpret=interpret(),
-        grid=(b * h, sq // block_q),
-        in_specs=in_specs,
+        grid=(w.batch, groups, sq // block_q),
+        in_specs=[q_spec, kv_spec, kv_spec] + extra_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i)),
+            q_spec,
+            pl.BlockSpec((1, 1, w.group, block_q),
+                         lambda b, hg, t: (b, hg, 0, t)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
-        ])(*args)
-    return o.reshape(b, h, sq, d), lse
+            jax.ShapeDtypeStruct(w.flat(q).shape, q.dtype),
+            jax.ShapeDtypeStruct((w.batch, groups, w.group, sq),
+                                 jnp.float32),
+        ],
+        compiler_params=_compiler_params(
+            (2 * block_q + 2 * sk) * w.width * item),
+    )(w.flat(q), w.flat(k), w.flat(v), *extra_args)
+    return w.unflat(o), lse
 
 
 # -- backward --------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, mask_ref,
-                   seed_ref, offs_ref, dq_ref, *, scale, causal, block_k,
-                   q_len, k_len, keep_prob):
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    bq = q_ref.shape[1]
-    d = q_ref.shape[2]
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0]
-    dsum = dsum_ref[0, 0]
-    q_off = offs_ref[0] if offs_ref is not None else 0
-    k_off = offs_ref[1] if offs_ref is not None else 0
-    row = (q_off + qi * bq
-           + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0))
-
-    def body(j, acc):
-        kb = k_ref[0, pl.ds(j * block_k, block_k), :]
-        vb = v_ref[0, pl.ds(j * block_k, block_k), :]
-        # base-2 scores (exp2 = native VPU exponential; p identical)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
-            * (scale * _LOG2E)
-        if mask_ref is not None:
-            s = s + (mask_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
-                     * _LOG2E)
-        if causal:
-            col = (k_off + j * block_k
-                   + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
-            s = jnp.where(row >= col, s, _NEG_INF)
-        p = jnp.exp2(s - (lse * _LOG2E)[:, None])
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if keep_prob < 1.0:  # replay the fwd tile mask on dP
-            nq, nk_tot = q_len // bq, k_len // block_k
-            dp = _drop_tile(dp, seed_ref,
-                            _tile_index(bh, qi, j, nq, nk_tot), keep_prob)
-        ds = p * (dp - dsum[:, None])
-        return acc + jax.lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    nk = k_len // block_k
-    if causal:
-        # above-diagonal kv tiles are fully masked (p == 0): skip them
-        hi = (q_off + (qi + 1) * bq - 1 - k_off) // block_k + 1
-        nk = jax.lax.clamp(0, hi, nk) if offs_ref is not None \
-            else jax.lax.min(nk, hi)
-    acc = jax.lax.fori_loop(0, nk, body, acc0)
-    dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, mask_ref,
-                    seed_ref, offs_ref, dk_ref, dv_ref, *, scale, causal,
-                    block_q, q_len, k_len, keep_prob):
-    bh = pl.program_id(0)
-    ki = pl.program_id(1)
-    bk = k_ref.shape[1]
-    d = k_ref.shape[2]
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
+                seed_ref, offs_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
+                scale, causal, block_q, q_len, k_len, keep_prob, heads,
+                group):
+    """One key block of one head group: every tile (query block i, this
+    key block) is formed once and feeds dV, dK and dQ[i]."""
+    b, hg, kj = (pl.program_id(a) for a in range(3))
+    bk, width = k_ref.shape[1], k_ref.shape[2]
+    dim = width // group
+    nq, nk = q_len // block_q, k_len // bk
     k = k_ref[0]
     v = v_ref[0]
+    k_heads = [_only_head(k, h, dim, group) for h in range(group)]
     q_off = offs_ref[0] if offs_ref is not None else 0
     k_off = offs_ref[1] if offs_ref is not None else 0
-    col = (k_off + ki * bk
-           + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1))
-    mblk = (mask_ref[0, 0, pl.ds(ki * bk, bk)][None, :]
+    col = (k_off + kj * bk
+           + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+           if causal else None)
+    mblk = (mask_ref[0, 0, pl.ds(kj * bk, bk)][None, :] * _LOG2E
             if mask_ref is not None else None)
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def body(i, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :]
-        dob = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)]
-        dsum = dsum_ref[0, 0, pl.ds(i * block_q, block_q)]
-        s = jax.lax.dot_general(qb, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
-            * (scale * _LOG2E)
-        if mblk is not None:
-            s = s + mblk * _LOG2E
-        if causal:
-            rr = (q_off + i * block_q
-                  + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0))
-            s = jnp.where(rr >= col, s, _NEG_INF)
-        p = jnp.exp2(s - (lse * _LOG2E)[:, None])
-        if keep_prob < 1.0:
-            # fwd seeded by tile (bh, q-block=i, kv-block=ki)
-            nq, nk_tot = q_len // block_q, k_len // bk
-            keep = _tile_keep(p.shape, seed_ref,
-                              _tile_index(bh, i, ki, nq, nk_tot),
-                              keep_prob)
-            p_dropped = jnp.where(keep, p / keep_prob, 0.0)
-        else:
-            keep = None
-            p_dropped = p
-        dv_new = dv + jax.lax.dot_general(
-            p_dropped.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(dob, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if keep is not None:
-            dp = jnp.where(keep, dp / keep_prob, 0.0)
-        ds = p * (dp - dsum[:, None])
-        dk_new = dk + jax.lax.dot_general(
-            ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+        rows = pl.ds(i * block_q, block_q)
+        qb = q_ref[0, rows, :]
+        dob = do_ref[0, rows, :]
+        # D = rowsum(dO∘O) a head, from the rows already here
+        od = dob.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
+        dq = jnp.zeros((block_q, width), jnp.float32)
+        for h in range(group):
+            qh = _only_head(qb, h, dim, group)
+            doh = _only_head(dob, h, dim, group)
+            dsum = jnp.sum(_only_head(od, h, dim, group), axis=1)
+            lse = lse_ref[0, 0, h, rows]
+            # base-2 scores (exp2 = native VPU exponential; p identical)
+            s = _nt(qh, k) * (scale * _LOG2E)
+            if mblk is not None:
+                s = s + mblk
+            if causal:
+                rr = (q_off + i * block_q
+                      + jax.lax.broadcasted_iota(jnp.int32,
+                                                 (block_q, bk), 0))
+                s = jnp.where(rr >= col, s, _NEG_INF)
+            p = jnp.exp2(s - (lse * _LOG2E)[:, None])
+            dp = _nt(doh, v)
+            if keep_prob < 1.0:
+                # replay the fwd tile (batch row x head, q-block i, kv-block
+                # kj); the 1/keep_prob of both dropped tiles is applied to
+                # the (rows, g*d) results: ds here is keep_prob x the true
+                keep = _tile_keep(
+                    p.shape, seed_ref,
+                    _tile_index(b * heads + hg * group + h, i, kj, nq, nk),
+                    keep_prob)
+                p_drop = jnp.where(keep, p, 0.0)
+                dp = jnp.where(keep, dp, 0.0)
+                dsum = dsum * keep_prob
+            else:
+                p_drop = p
+            ds = (p * (dp - dsum[:, None])).astype(qb.dtype)
+            dv = dv + _tn(p_drop.astype(dob.dtype), doh)
+            dk = dk + _tn(ds, qh)
+            dq = dq + _nn(ds, k_heads[h])
+        dq_acc[rows, :] += dq
+        return dk, dv
 
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
+    zeros = jnp.zeros((bk, width), jnp.float32)
     i_start = 0
     if causal:
         # q tiles strictly above the diagonal see none of this kv block;
         # with offsets the bound is dynamic (global positions)
-        lo = (k_off + ki * bk - q_off) // block_q
-        i_start = jax.lax.clamp(0, lo, q_len // block_q) \
-            if offs_ref is not None else lo
-    dk, dv = jax.lax.fori_loop(i_start, q_len // block_q, body,
-                               (dk0, dv0))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        lo = (k_off + kj * bk - q_off) // block_q
+        i_start = jax.lax.clamp(0, lo, nq) if offs_ref is not None else lo
+    dk, dv = jax.lax.fori_loop(i_start, nq, body, (zeros, zeros))
+    dk_ref[0] = (dk * (scale / keep_prob)).astype(dk_ref.dtype)
+    dv_ref[0] = (dv * (1.0 / keep_prob)).astype(dv_ref.dtype)
+
+    @pl.when(kj == nk - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * (scale / keep_prob)).astype(dq_ref.dtype)
 
 
 def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
-              block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    qf = q.reshape(b * h, sq, d)
-    kf, vf = (t.reshape(b * h, sk, d) for t in (k, v))
-    dof = dout.reshape(b * h, sq, d)
-    dsum = jnp.sum(dof.astype(jnp.float32)
-                   * o.reshape(b * h, sq, d).astype(jnp.float32),
-                   axis=-1)[:, None, :]                      # (BH, 1, Sq)
-    args = [qf, kf, vf, dof, lse, dsum]
-    base_specs = [
-        pl.BlockSpec((1, sq, d), lambda bh, i: (bh, 0, 0)),  # q (full)
-        pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0)),  # k
-        pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0)),  # v
-        pl.BlockSpec((1, sq, d), lambda bh, i: (bh, 0, 0)),  # do
-        pl.BlockSpec((1, 1, sq), lambda bh, i: (bh, 0, 0)),  # lse
-        pl.BlockSpec((1, 1, sq), lambda bh, i: (bh, 0, 0)),  # dsum
-    ]
-    extra_args, extra_specs = [], []
-    if mask is not None:
-        extra_args.append(mask.reshape(b, 1, sk).astype(jnp.float32))
-        extra_specs.append(pl.BlockSpec(
-            (1, 1, sk), lambda bh, i, h=h: (bh // h, 0, 0)))
-    if keep_prob < 1.0:
-        extra_args.append(seed.reshape(1).astype(jnp.int32))
-        extra_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if offsets is not None:
-        extra_args.append(offsets)
-        extra_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
-    dq_specs = list(base_specs)
-    dq_specs[0] = pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0))
-    dq_specs[3] = pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0))
-    dq_specs[4] = pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i))
-    dq_specs[5] = pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i))
-
-    dq_kern = _make_kern(_bwd_dq_kernel, mask is not None, keep_prob < 1.0,
-                         1, has_offs=offsets is not None,
-                         scale=scale, causal=causal, block_k=block_k,
-                         q_len=sq, k_len=sk, keep_prob=keep_prob)
-    dq = pl.pallas_call(
-        dq_kern, name="hetu_flash_bwd_dq", interpret=interpret(),
-        grid=(b * h, sq // block_q),
-        in_specs=dq_specs + extra_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-    )(*args, *extra_args)
-
-    dkv_specs = list(base_specs)
-    dkv_specs[1] = pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0))
-    dkv_specs[2] = pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0))
-    dkv_kern = _make_kern(_bwd_dkv_kernel, mask is not None,
-                          keep_prob < 1.0, 2, has_offs=offsets is not None,
-                          scale=scale, causal=causal,
-                          block_q=block_q, q_len=sq, k_len=sk,
-                          keep_prob=keep_prob)
-    dk, dv = pl.pallas_call(
-        dkv_kern, name="hetu_flash_bwd_dkv", interpret=interpret(),
-        grid=(b * h, sk // block_k),
-        in_specs=dkv_specs + extra_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
-        ])(*args, *extra_args)
-
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+              block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
+              num_heads=None):
+    """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
+    it."""
+    w = _walk(q, num_heads)
+    sq, sk = w.seq(q), w.seq(k)
+    whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
+    kv_spec = pl.BlockSpec((1, block_k, w.width), w.rows(lambda t: t))
+    lse_spec = pl.BlockSpec((1, 1, w.group, sq),
+                            lambda b, hg, t: (b, hg, 0, 0))
+    extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
+    kern = _make_kern(_bwd_kernel, 6, mask is not None, keep_prob < 1.0,
+                      offsets is not None,
+                      scale=scale, causal=causal, block_q=block_q,
+                      q_len=sq, k_len=sk, keep_prob=keep_prob,
+                      heads=w.heads, group=w.group)
+    item = q.dtype.itemsize
+    dq, dk, dv = pl.pallas_call(
+        kern, name="hetu_flash_bwd", interpret=interpret(),
+        grid=(w.batch, w.heads // w.group, sk // block_k),
+        in_specs=[whole_q, kv_spec, kv_spec, whole_q, whole_q, lse_spec]
+        + extra_specs,
+        out_specs=[whole_q, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(w.flat(t).shape, t.dtype)
+                   for t in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((sq, w.width), jnp.float32)],
+        compiler_params=_compiler_params(
+            (4 * sq + 4 * block_k) * w.width * item + sq * w.width * 4),
+    )(*(w.flat(t) for t in (q, k, v, o, dout)), lse, *extra_args)
+    return w.unflat(dq), w.unflat(dk), w.unflat(dv)
 
 
-# -- custom-vjp wrappers ---------------------------------------------------
-# two variants (with/without mask) keep the signatures positional; the
-# dropout seed is a traced uint32 tensor with zero cotangent.
+# -- custom-vjp wrapper ----------------------------------------------------
+# the mask (None or [B,1,1,S]) and the dropout seed (a traced int32 tensor)
+# are operands with zero cotangent.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_nomask(q, k, v, seed, causal, scale, keep_prob, block):
-    return _fwd(q, k, v, None, causal, scale, keep_prob, seed,
-                block_q=block, block_k=block)[0]
-
-
-def _flash_nomask_fwd(q, k, v, seed, causal, scale, keep_prob, block):
-    o, lse = _fwd(q, k, v, None, causal, scale, keep_prob, seed,
-                  block_q=block, block_k=block)
-    return o, (q, k, v, seed, o, lse)
-
-
-def _flash_nomask_bwd(causal, scale, keep_prob, block, res, g):
-    q, k, v, seed, o, lse = res
-    dq, dk, dv = _bwd_impl(q, k, v, None, o, lse, g, causal, scale,
-                           keep_prob, seed, block_q=block, block_k=block)
-    return dq, dk, dv, jnp.zeros_like(seed)
-
-
-_flash_nomask.defvjp(_flash_nomask_fwd, _flash_nomask_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_mask(q, k, v, mask, seed, causal, scale, keep_prob, block):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, seed, causal, scale, keep_prob, block, num_heads):
     return _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                block_q=block, block_k=block)[0]
+                block_q=block, block_k=block, num_heads=num_heads)[0]
 
 
-def _flash_mask_fwd(q, k, v, mask, seed, causal, scale, keep_prob, block):
+def _flash_fwd(q, k, v, mask, seed, causal, scale, keep_prob, block,
+               num_heads):
     o, lse = _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                  block_q=block, block_k=block)
+                  block_q=block, block_k=block, num_heads=num_heads)
     return o, (q, k, v, mask, seed, o, lse)
 
 
-def _flash_mask_bwd(causal, scale, keep_prob, block, res, g):
+def _flash_bwd(causal, scale, keep_prob, block, num_heads, res, g):
     q, k, v, mask, seed, o, lse = res
     dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, g, causal, scale,
-                           keep_prob, seed, block_q=block, block_k=block)
+                           keep_prob, seed, block_q=block, block_k=block,
+                           num_heads=num_heads)
     # The additive mask is treated as NON-differentiable data (our graphs
     # build it from placeholder attention masks).  A learned attention bias
     # must use the jnp fallback path, which differentiates the bias.
-    return dq, dk, dv, jnp.zeros_like(mask), jnp.zeros_like(seed)
+    return (dq, dk, dv, None if mask is None else jnp.zeros_like(mask),
+            jnp.zeros_like(seed))
 
 
-_flash_mask.defvjp(_flash_mask_fwd, _flash_mask_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+# jitted, so that the layers of a model with one attention shape share one
+# trace of the two kernel bodies (pallas_call itself traces its kernel anew
+# at every call)
+_flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9))
 
 
 # -- blockwise API (ring / context parallelism) ----------------------------
@@ -563,9 +611,8 @@ def flash_attention_block(q, k, v, q_off, k_off, *, causal=True,
     offsets = jnp.stack([q_off, k_off]).astype(jnp.int32)
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    o, lse = _fwd(q, k, v, None, causal, float(scale), 1.0,
-                  jnp.zeros((1,), jnp.int32), block_q=bq, block_k=bk,
-                  offsets=offsets, empty_lse_neg=True)
+    o, lse = _fwd(q, k, v, None, causal, float(scale), block_q=bq,
+                  block_k=bk, offsets=offsets, empty_lse_neg=True)
     return o, lse.reshape(b, h, sq)
 
 
@@ -580,27 +627,55 @@ def flash_attention_block_bwd(q, k, v, o, lse, dout, q_off, k_off, *,
     offsets = jnp.stack([q_off, k_off]).astype(jnp.int32)
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    return _bwd_impl(q, k, v, None, o, lse.reshape(b * h, 1, sq), dout,
-                     causal, float(scale), 1.0,
-                     jnp.zeros((1,), jnp.int32), block_q=bq, block_k=bk,
-                     offsets=offsets)
+    return _bwd_impl(q, k, v, None, o, lse.reshape(b, h, 1, sq), dout,
+                     causal, float(scale), 1.0, None, block_q=bq,
+                     block_k=bk, offsets=offsets)
+
+
+def _count_entry(walk):
+    """Trace-time count of the walk taken, beside ``dispatch.record``'s
+    count of the kernel-versus-jnp choice."""
+    telemetry.get_registry().counter(
+        "hetu_flash_attention_entry_total",
+        "Trace-time flash attention calls by operand layout and the heads "
+        "one program takes",
+        labels=("layout", "heads_per_program"),
+    ).labels(layout=walk.layout, heads_per_program=str(walk.group)).inc()
+
+
+def entries():
+    """``{(layout, heads_per_program): count}`` of the calls traced so far
+    (empty while telemetry is disabled)."""
+    return {(lab["layout"], int(lab["heads_per_program"])): n
+            for lab, n in counted("hetu_flash_attention_entry_total")}
 
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
-                    dropout_keep=1.0, seed=None):
+                    dropout_keep=1.0, seed=None, num_heads=None):
     """Fused attention; returns None when shapes are unsupported so the
     caller falls back to the jnp composition (ops/attention.py).
 
+    q, k, v are ``[B, H, S, D]``, or the projections' ``[B, S, H*D]`` with
+    ``num_heads`` (read, and the context written, in place); the latter
+    needs ``heads_per_program(num_heads, D)`` to be non-zero.
     ``dropout_keep`` < 1 applies attention-prob dropout in-kernel (TPU
     PRNG); ``seed`` must then be an int32/uint32 scalar array.
     """
-    if unsupported(q, k, v, mask, dropout_keep) is not None:
+    if q.ndim == 3:
+        if not heads_per_program(num_heads, q.shape[-1] // num_heads):
+            return None
+        views = (heads_view(t, num_heads) for t in (q, k, v))
+    else:
+        views = (q, k, v)
+    if unsupported(*views, mask, dropout_keep) is not None:
         return None
     if dropout_keep < 1.0 and seed is None:
         raise ValueError(
             "flash_attention: dropout_keep < 1 requires seed= (an int32 "
             "scalar array; the per-tile dropout masks derive from it)")
-    b, h, s, d = q.shape
+    w = _walk(q, num_heads)
+    _count_entry(w)
+    s, d = w.seq(q), w.dim
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     if dropout_keep >= 1.0:
@@ -608,47 +683,52 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
 
     # pad into the kernel envelope; padding/slicing live OUTSIDE the
     # custom_vjp so jnp.pad's VJP zero-fills the padded rows' cotangents
-    # and the gradients of the real region stay exact
+    # and the gradients of the real region stay exact.  In-place heads are
+    # 32, 64 or a multiple of 128 wide: never padded
     d_pad = max(32, -(-d // 8) * 8)
     s_pad, block = _pad_plan(s)
     if d_pad != d or s_pad != s:
-        pad3 = ((0, 0), (0, 0), (0, s_pad - s), (0, d_pad - d))
-        q, k, v = (jnp.pad(t, pad3) for t in (q, k, v))
+        pad = [(0, 0)] * q.ndim
+        pad[1 if q.ndim == 3 else 2] = (0, s_pad - s)
+        pad[-1] = (0, d_pad - d)
+        q, k, v = (jnp.pad(t, pad) for t in (q, k, v))
         if s_pad != s and not (causal and mask is None):
             # padded key columns must not attend; real causal rows never
             # see columns ≥ s, so pure-causal needs no mask
             base = (mask if mask is not None
-                    else jnp.zeros((b, 1, 1, s), jnp.float32))
+                    else jnp.zeros((w.batch, 1, 1, s), jnp.float32))
             mask = jnp.pad(base, ((0, 0), (0, 0), (0, 0), (0, s_pad - s)),
                            constant_values=_NEG_INF)
 
-    if mask is None:
-        out = _flash_nomask(q, k, v, seed, causal, float(scale),
-                            float(dropout_keep), block)
-    else:
-        out = _flash_mask(q, k, v, mask, seed, causal, float(scale),
-                          float(dropout_keep), block)
+    out = _flash_call(q, k, v, mask, seed, causal, float(scale),
+                      float(dropout_keep), block, num_heads)
     if d_pad != d or s_pad != s:
-        out = out[:, :, :s, :d]
+        out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :d]
     return out
 
 
 def sharded_flash_attention(mesh, q, k, v, mask=None, *, batch_axes=(),
-                            head_axes=(), seed=None, **kw):
+                            head_axes=(), seed=None, num_heads=None, **kw):
     """:func:`flash_attention` inside a GSPMD mesh program.
 
     ``pallas_call`` does not partition, and attention is local to one
     (batch row, head), so the kernel runs under ``shard_map`` on each
-    device's own slice: q/k/v ``[B, H, S, D]`` split their batch dim over
-    ``batch_axes`` and their head dim over ``head_axes`` (mesh axis names;
-    both dims must divide), the ``[B, 1, 1, S]`` mask follows the batch,
-    and the dropout seed is offset by the shard's index so that shards do
-    not repeat one mask.  Same return contract as ``flash_attention``."""
+    device's own slice: q/k/v split their batch dim over ``batch_axes`` and
+    their heads over ``head_axes`` (mesh axis names; both must divide) —
+    dim 1 of ``[B, H, S, D]``, the last dim of ``[B, S, H*D]``, whose
+    ``num_heads`` is the global count — the ``[B, 1, 1, S]`` mask follows
+    the batch, and the dropout seed is offset by the shard's index so that
+    shards do not repeat one mask.  Same return contract as
+    ``flash_attention``."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     batch_axes, head_axes = tuple(batch_axes), tuple(head_axes)
-    spec = P(batch_axes or None, head_axes or None, None, None)
+    if q.ndim == 3:
+        spec = P(batch_axes or None, None, head_axes or None)
+        num_heads //= int(np.prod([mesh.shape[a] for a in head_axes] or [1]))
+    else:
+        spec = P(batch_axes or None, head_axes or None, None, None)
     mspec = P(batch_axes or None, None, None, None)
     operands, specs = [q, k, v], [spec, spec, spec]
     if mask is not None:
@@ -666,7 +746,8 @@ def sharded_flash_attention(mesh, q, k, v, mask=None, *, batch_axes=(),
             shard = jax.lax.axis_index(batch_axes + head_axes)
             sd = sd + shard.astype(sd.dtype) * jnp.asarray(
                 -1640531535, sd.dtype)        # 0x9E3779B1, wraps in int32
-        return flash_attention(q, k, v, mask=m, seed=sd, **kw)
+        return flash_attention(q, k, v, mask=m, seed=sd,
+                               num_heads=num_heads, **kw)
 
     # pallas out_shapes carry no varying-axes annotations
     return shard_map(local, mesh=mesh, in_specs=tuple(specs),

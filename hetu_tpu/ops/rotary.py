@@ -30,12 +30,14 @@ def _rope_tables(seq_len, dim, theta, pos_offset=0):
     return jnp.cos(emb), jnp.sin(emb)
 
 
-def _rotary(x, *, theta=10000.0, pos_offset=0):
-    """Apply RoPE to [B, H, S, D] (HF rotate_half convention)."""
-    d, s = x.shape[-1], x.shape[-2]
-    cos, sin = _rope_tables(s, d, theta, pos_offset)
-    cos = cos[None, None, :, :]
-    sin = sin[None, None, :, :]
+def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2):
+    """Apply RoPE to [B, H, S, D] (HF rotate_half convention), or, with
+    ``seq_axis=1``, to the [B, S, H, D] view of a projection's output."""
+    d, s = x.shape[-1], x.shape[seq_axis]
+    along = [1] * x.ndim
+    along[seq_axis], along[-1] = s, d
+    cos, sin = (t.reshape(along)
+                for t in _rope_tables(s, d, theta, pos_offset))
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)

@@ -96,3 +96,14 @@ def clone_params_into(ex, prev):
         for k in ex.params:
             ex.params[k] = jnp.asarray(prev[ren[k]])
     return {k: np.asarray(v) for k, v in ex.params.items()}
+
+
+@pytest.fixture
+def live_registry():
+    """The process registry counts only while enabled."""
+    from hetu_tpu import telemetry
+    reg = telemetry.get_registry()
+    was = reg.enabled
+    reg.enable()
+    yield reg
+    reg.enabled = was

@@ -19,17 +19,6 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 
-@pytest.fixture
-def live_registry():
-    """The process registry counts only while enabled."""
-    from hetu_tpu import telemetry
-    reg = telemetry.get_registry()
-    was = reg.enabled
-    reg.enable()
-    yield reg
-    reg.enabled = was
-
-
 def test_smoke_fails_and_names_the_platform_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],

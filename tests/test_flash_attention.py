@@ -116,6 +116,228 @@ def test_fully_masked_rows_zero_output_and_grads(rng):
                                rtol=2e-4, atol=2e-4)
 
 
+# -- the in-place [B, S, H*D] entry ------------------------------------------
+# g = 128 // D heads a program (4, 2, 1 at head sizes 32, 64, 128), two head
+# groups each, so that the group index and the lanes within a group both
+# matter.
+
+def _to3(x):
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _to4(x, h):
+    b, s, width = x.shape
+    return x.reshape(b, s, h, width // h).transpose(0, 2, 1, 3)
+
+
+def _mask(rng, B, S):
+    return jnp.where(jnp.asarray(rng.random((B, 1, 1, S))) < 0.25,
+                     -1e9, 0.0).astype(jnp.float32)
+
+
+def _both_entries(q, k, v, mask, causal, block):
+    """(loss, (dq, dk, dv)) through the in-place and through the 4-D
+    entry, all as [B, H, S, D], at a forced block size."""
+    from hetu_tpu.ops.pallas import flash_attention as F
+    h, d = q.shape[1], q.shape[3]
+    seed = jnp.zeros((1,), jnp.int32)
+    scale = 1.0 / float(np.sqrt(d))
+
+    def in_place(q, k, v):
+        o = F._flash(_to3(q), _to3(k), _to3(v), mask, seed, causal, scale,
+                     1.0, block, h)
+        return jnp.sum(_to4(o, h) ** 2)
+
+    def four_d(q, k, v):
+        return jnp.sum(F._flash(q, k, v, mask, seed, causal, scale, 1.0,
+                                block, None) ** 2)
+
+    return [jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+            for f in (in_place, four_d)]
+
+
+_ONE_TILE = [(512, 512, c, m) for c in (False, True) for m in (False, True)]
+_SEVERAL = [(S, blk, c, m) for S, blk in ((1024, 512), (512, 256))
+            for c, m in ((True, False), (False, True))]
+
+
+@pytest.mark.parametrize("S,block,causal,with_mask", _ONE_TILE + _SEVERAL)
+@pytest.mark.parametrize("D", [32, 64, 128])
+def test_in_place_entry_matches_reference_and_4d_entry(rng, D, S, block,
+                                                       causal, with_mask):
+    H = 2 * max(1, 128 // D)
+    q, k, v = _qkv(rng, B=1, H=H, S=S, D=D)
+    mask = _mask(rng, 1, S) if with_mask else None
+    (l3, g3), (l4, g4) = _both_entries(q, k, v, mask, causal, block)
+    want_l, want_g = jax.value_and_grad(
+        lambda *a: jnp.sum(ref_attn(*a, mask=mask, causal=causal) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(l3), float(want_l), rtol=1e-5)
+    for got, other, want in zip(g3, g4, want_g):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        if D >= 64 and block == 512:
+            # one pair of kernel bodies: the zeroed lanes of the other
+            # heads add exact zeros, so the entries agree to the last bit
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(other))
+        else:
+            # at four heads a program, or on 256-row tiles, XLA's CPU code
+            # (interpret mode) sums D = rowsum(dO∘O) over a group's 128
+            # lanes in another order than over one head's: f32 rounding
+            np.testing.assert_allclose(np.asarray(got), np.asarray(other),
+                                       rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("D", [32, 64, 128])
+def test_in_place_fully_masked_rows_zero_output_and_grads(rng, D):
+    H = 2 * max(1, 128 // D)
+    q, k, v = (_to3(t) for t in _qkv(rng, B=2, H=H, S=256, D=D))
+    mask = jnp.zeros((2, 1, 1, 256), jnp.float32).at[1].set(-jnp.inf)
+    out = flash_attention(q, k, v, mask=mask, num_heads=H)
+    np.testing.assert_allclose(np.asarray(out[1]), 0.0, atol=1e-6)
+    want0 = ref_attn(*(_to4(t[:1], H) for t in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(_to4(out[:1], H)),
+                               np.asarray(want0), rtol=2e-5, atol=2e-5)
+    grads = jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, mask=mask, num_heads=H) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g[1]), 0.0, atol=1e-6)
+
+
+def test_in_place_entry_pads_odd_sequences(rng):
+    H, D, S = 4, 64, 333
+    q, k, v = _qkv(rng, B=1, H=H, S=S, D=D)
+    for causal in (False, True):
+        out = flash_attention(_to3(q), _to3(k), _to3(v), causal=causal,
+                              num_heads=H)
+        assert out.shape == (1, S, H * D)
+        np.testing.assert_allclose(
+            np.asarray(_to4(out, H)),
+            np.asarray(ref_attn(q, k, v, causal=causal)),
+            rtol=2e-5, atol=2e-5)
+
+
+def test_in_place_entry_needs_lane_aligned_head_groups():
+    from hetu_tpu.ops.pallas.flash_attention import heads_per_program
+    assert [heads_per_program(12, d) for d in (32, 64, 128, 256)] == [
+        4, 2, 1, 1]
+    # 80 and 96 neither divide 128 nor are a multiple of it; 3 heads of 64
+    # do not pair up; heads under 32 wide are padded by the 4-D walk
+    assert [heads_per_program(*hd) for hd in ((32, 80), (8, 96), (3, 64),
+                                              (8, 16))] == [0, 0, 0, 0]
+    q = jnp.zeros((1, 256, 3 * 64))
+    assert flash_attention(q, q, q, num_heads=3) is None
+    q = jnp.zeros((1, 100, 128))
+    assert flash_attention(q, q, q, num_heads=2) is None     # seq < 128
+
+
+def test_blockwise_api_matches_reference_with_offsets(rng):
+    """One ring rank's work: 256 local queries at global offset 512 against
+    a K/V block of 512 (offset 0) and one of 256 (offset 512), causal;
+    forward combined by logaddexp, backward from the combined (o, lse)."""
+    from hetu_tpu.ops.pallas.flash_attention import (
+        flash_attention_block, flash_attention_block_bwd)
+    B, H, D, S = 1, 2, 64, 768
+    q, k, v = _qkv(rng, B=B, H=H, S=S, D=D)
+    q_loc = q[:, :, 512:]
+    blocks = [(0, 512), (512, 256)]
+    o = jnp.zeros(q_loc.shape, jnp.float32)
+    lse = jnp.full(q_loc.shape[:-1], -1e30, jnp.float32)
+    for off, n in blocks:
+        o_b, lse_b = flash_attention_block(
+            q_loc, k[:, :, off:off + n], v[:, :, off:off + n],
+            jnp.int32(512), jnp.int32(off))
+        new = jnp.logaddexp(lse, lse_b)
+        o = (o * jnp.exp(lse - new)[..., None]
+             + o_b * jnp.exp(lse_b - new)[..., None])
+        lse = new
+
+    def ref_loss(q_loc, k, v):
+        full = ref_attn(jnp.concatenate([q[:, :, :512], q_loc], axis=2),
+                        k, v, causal=True)
+        return jnp.sum(full[:, :, 512:] ** 2)
+
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(ref_attn(q, k, v, causal=True)[:, :, 512:]),
+        rtol=2e-5, atol=2e-5)
+    want = jax.grad(ref_loss, argnums=(0, 1, 2))(q_loc, k, v)
+    dout = 2.0 * o
+    dq = jnp.zeros_like(q_loc)
+    dk, dv = [], []
+    for off, n in blocks:
+        dq_b, dk_b, dv_b = flash_attention_block_bwd(
+            q_loc, k[:, :, off:off + n], v[:, :, off:off + n], o, lse, dout,
+            jnp.int32(512), jnp.int32(off))
+        dq = dq + dq_b
+        dk.append(dk_b)
+        dv.append(dv_b)
+    for got, w in zip((dq, jnp.concatenate(dk, axis=2),
+                       jnp.concatenate(dv, axis=2)), want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters,
+    pallas kernels' bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("heads,layout,group,transposes", [
+    (4, "bshd", 2, 0),      # pairs of 64-wide heads, read in place
+    (3, "bhsd", 1, 8),      # 3 heads do not pair: q, k, v, o and cotangents
+])
+def test_grad_through_the_op_is_two_kernels(monkeypatch, live_registry,
+                                            heads, layout, group,
+                                            transposes):
+    """What a TPU step would hold for the layer's [B, S, H*D] operands:
+    one forward and one backward kernel, and no transpose beside them
+    where the heads come in lane groups."""
+    import types
+    import hetu_tpu as ht
+    from hetu_tpu.ops.pallas import dispatch, flash_attention as F
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    shape = (2, 512, heads * 64)
+    nodes = [ht.placeholder_op(f"fa2k_{heads}_{n}", shape) for n in "qkv"]
+    op = ht.scaled_dot_product_attention_op(*nodes, num_heads=heads)
+    ctx = types.SimpleNamespace(mesh=None, training=False)
+    x = jnp.zeros(shape, jnp.bfloat16)
+    before = F.entries().get((layout, group), 0)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(op._compute([q, k, v], ctx)
+                                .astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2)))(x, x, x)
+    eqns = list(_primitives(jaxpr.jaxpr))
+    kernels = sorted(e.params["name"] for e in eqns
+                     if e.primitive.name == "pallas_call")
+    assert kernels == ["hetu_flash_bwd", "hetu_flash_fwd"]
+    assert sum(e.primitive.name == "transpose" for e in eqns) == transposes
+    assert F.entries()[(layout, group)] == before + 1
+    assert all(len(k) == 3 for k in dispatch.choices())
+
+
+def test_grad_through_the_4d_entry_is_two_kernels():
+    q = jnp.zeros((2, 4, 512, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True) ** 2),
+        argnums=(0, 1, 2)))(q, q, q)
+    eqns = list(_primitives(jaxpr.jaxpr))
+    assert sorted(e.params["name"] for e in eqns
+                  if e.primitive.name == "pallas_call") == [
+        "hetu_flash_bwd", "hetu_flash_fwd"]
+    assert not any(e.primitive.name == "transpose" for e in eqns)
+
+
 def test_unsupported_shapes_fall_back(rng):
     # short seqs -> None (the O(S^2) composition is cheaper than padding)
     q = jnp.zeros((1, 2, 100, 64))
@@ -318,6 +540,32 @@ def test_sharded_flash_attention_matches_unsharded(rng):
                                    rtol=2e-4, atol=2e-4)
 
 
+def test_sharded_in_place_flash_attention_matches_unsharded(rng):
+    """[B, S, H*D] under shard_map: batch over dp, the hidden width (whole
+    heads) over tp, two local heads a program."""
+    from hetu_tpu.ops.pallas.flash_attention import sharded_flash_attention
+    mesh = _mesh({"dp": 2, "tp": 2})
+    H = 4
+    q, k, v = (_to3(t) for t in _qkv(rng, B=2, H=H, S=128, D=64))
+    mask = _mask(rng, 2, 128)
+
+    def sharded(q, k, v):
+        return sharded_flash_attention(mesh, q, k, v, mask,
+                                       batch_axes=("dp",),
+                                       head_axes=("tp",), num_heads=H)
+
+    want = flash_attention(q, k, v, mask=mask, num_heads=H)
+    np.testing.assert_allclose(np.asarray(jax.jit(sharded)(q, k, v)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(sharded(*a) ** 2),
+                           argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, mask=mask, num_heads=H) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def test_sharded_softmax_ce_matches_unsharded(rng):
     from hetu_tpu.ops.pallas.softmax_ce import (fused_softmax_ce_sparse,
                                                 sharded_softmax_ce_sparse)
@@ -365,3 +613,116 @@ def test_kernel_plans_follow_the_mesh(monkeypatch):
     assert losses._ce_kernel_plan(
         y, -1, _mesh({"dp": 2, "tp": 2}))[0] == "mesh_axis:tp=2"
     assert losses._ce_kernel_plan(y, 0, None)[0] == "class_dim_not_last"
+
+
+def test_flash_plan_on_in_place_operands(monkeypatch):
+    """[B, S, H*D] operands are planned as the [B, H, S, D] array they are a
+    view of, and stay in place where each shard's heads pair up."""
+    import types
+    import hetu_tpu as ht
+    from hetu_tpu.ops import attention
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((8, 512, 12 * 64), jnp.bfloat16)
+    plan = lambda mesh, x=q, h=12: attention._flash_plan(x, x, x, None, 0.9,
+                                                         mesh, h)
+    assert plan(None) == (None, (), ())
+    assert plan(_mesh({"dp": 4})) == (None, ("dp",), ())
+    assert plan(_mesh({"dp": 2, "tp": 2})) == (None, ("dp",), ("tp",))
+    assert plan(_mesh({"tp": 8}))[0] == "mesh_axis:tp=8"    # 12 heads
+    short = jax.ShapeDtypeStruct((8, 128, 768), jnp.bfloat16)
+    assert plan(None, short)[0] == "seq<256"
+    mask = jax.ShapeDtypeStruct((8, 1, 512, 512), jnp.float32)
+    assert attention._flash_plan(q, q, q, mask, 1.0, None, 12)[0] \
+        == "mask_not_b11s"
+
+    def stays(mesh, heads):
+        x = jax.ShapeDtypeStruct((8, 512, heads * 64), jnp.bfloat16)
+        nodes = [ht.placeholder_op(f"fp_{heads}_{n}", x.shape) for n in "qkv"]
+        op = ht.scaled_dot_product_attention_op(*nodes, num_heads=heads)
+        ctx = types.SimpleNamespace(mesh=mesh, training=False)
+        return op._stays_in_place(x, x, x, None, ctx)
+
+    assert stays(None, 12) and stays(_mesh({"dp": 4}), 12)
+    assert stays(_mesh({"dp": 2, "tp": 2}), 12)       # 6 local heads pair up
+    assert not stays(_mesh({"dp": 2, "tp": 4}), 12)   # 3 local heads do not
+    assert not stays(None, 3)
+    assert not stays(_mesh({"dp": 2, "cp": 2}), 12)   # the ring owns 4-D
+    # the jnp composition reads any [B, S, H*D] through a free view
+    monkeypatch.setattr(dispatch, "platform", lambda: "cpu")
+    assert stays(None, 3)
+
+
+# -- the kernels compiled for the chip ---------------------------------------
+# Interpret mode cannot see what Mosaic refuses (lane and sublane alignment,
+# VMEM).  libtpu is installed, so the kernels compile here for a described,
+# not attached, v5e at the cells' real shapes; nothing runs.  All such tests
+# stay in this one file: one process at a time may load libtpu.
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:           # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    from hetu_tpu.ops.pallas import dispatch, flash_attention as F
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    monkeypatch.setattr(F, "interpret", lambda: False)
+
+
+@pytest.mark.parametrize("shape,heads,with_mask,causal,keep", [
+    ((64, 512, 768), 12, True, False, 0.9),     # a BERT-base shard
+    ((2, 4096, 2048), 16, False, True, 1.0),    # OLMoE: head 128, 8 x 8 tiles
+    ((4, 1024, 256), 8, False, True, 1.0),      # head 32: four heads a program
+    ((2, 32, 2048, 80), None, False, True, 1.0),    # GPT-2.7B: the 4-D walk
+])
+def test_kernels_compile_for_v5e(v5e, as_on_tpu, shape, heads, with_mask,
+                                 causal, keep):
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    q = sds(shape, jnp.bfloat16)
+    s_len = shape[1] if heads else shape[2]
+    mask = sds((shape[0], 1, 1, s_len), jnp.float32) if with_mask else None
+
+    def loss(q, k, v, mask, seed):
+        return jnp.sum(flash_attention(
+            q, k, v, mask=mask, causal=causal, dropout_keep=keep, seed=seed,
+            num_heads=heads).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q, mask, sds((1,), jnp.int32)).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_flash_fwd" in kernels[0] and "hetu_flash_bwd" in kernels[1]
+
+
+def test_in_place_kernels_compile_per_shard_on_four_chips(v5e, as_on_tpu):
+    """DataParallel(4)'s BERT shard under shard_map: the kernels see the
+    local [64, 512, 768], and nothing is transposed or copied around them."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from hetu_tpu.ops.pallas.flash_attention import sharded_flash_attention
+    mesh = Mesh(np.array(v5e.devices).reshape(4), ("dp",))
+    put = lambda s, dt, spec: jax.ShapeDtypeStruct(
+        s, dt, sharding=NamedSharding(mesh, spec))
+    q = put((256, 512, 768), jnp.bfloat16, P("dp"))
+
+    def loss(q, mask, seed):
+        return jnp.sum(sharded_flash_attention(
+            mesh, q, q, q, mask, batch_axes=("dp",), seed=seed,
+            dropout_keep=0.9, num_heads=12).astype(jnp.float32))
+
+    hlo = jax.jit(jax.grad(loss)).lower(
+        q, put((256, 1, 1, 512), jnp.float32, P("dp")),
+        put((1,), jnp.int32, P())).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert all("bf16[64,512,768]" in ln for ln in kernels)
+    assert not re.findall(r" = bf16\[[\d,]+\]\S* (?:copy|transpose)\(", hlo)

@@ -196,23 +196,27 @@ def test_onnx_bytes_roundtrip_seq2seq(rng):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
-def test_onnx_bytes_roundtrip_llama(rng):
+@pytest.mark.parametrize("kv_heads,tiles", [
+    (2, 4),        # grouped-query: the [B, H, S, D] graph, K/V tiled
+    (4, None),     # multi-head: attention on [B, S, H*D], RoPE on its view
+])
+def test_onnx_bytes_roundtrip_llama(rng, kv_heads, tiles):
     """Llama tier through ModelProto bytes: RMSNorm, RoPE (constant
     cos/sin tables + Slice/Neg/Concat rotation), GQA repeat_kv
     (Reshape/Tile/Reshape), SwiGLU — all as standard opset ops, so any
     ONNX consumer can run the modern-LLM tier."""
     from hetu_tpu.models import LlamaConfig, LlamaForCausalLM
     c = LlamaConfig(vocab_size=64, hidden_size=16, num_layers=2,
-                    num_heads=4, num_kv_heads=2, intermediate_size=32,
-                    seq_len=8)
+                    num_heads=4, num_kv_heads=kv_heads,
+                    intermediate_size=32, seq_len=8)
     ids = ht.placeholder_op("llx_ids", (2, 8), dtype=np.int32)
-    logits = LlamaForCausalLM(c, name="llx")(ids)
+    logits = LlamaForCausalLM(c, name=f"llx{kv_heads}")(ids)
     ex = ht.Executor({"inference": [logits]})
     model = hx.deserialize_model(
         hx.serialize_model(hx.hetu2onnx([logits], ex.params)))
     counts = model.summary()["op_counts"]
     # RoPE rotations (2/layer on q,k) and GQA tiles survived lowering
-    assert counts.get("Neg") == 4 and counts.get("Tile") == 4
+    assert counts.get("Neg") == 4 and counts.get("Tile") == tiles
     assert counts.get("Sigmoid") == 2          # SwiGLU silu
     ph, outs = hx.onnx2hetu(model)
     ex2 = ht.Executor({"inference": outs})
