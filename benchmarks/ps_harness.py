@@ -1,7 +1,6 @@
-"""Shared W&D-over-PS measurement harness, used by bench.py's `wdl_ps`
-stage and benchmarks/ps_scale_bench.py so the HET protocol (cache
-settings, zipf traffic, feed rotation, timing discipline) lives in ONE
-place and cannot drift between the two entry points."""
+"""The W&D-over-PS measurement harness of benchmarks/ps_scale_bench.py:
+the HET protocol (cache settings, zipf traffic, feed rotation, timing
+discipline) in one place."""
 
 from __future__ import annotations
 

@@ -5,7 +5,7 @@ One process drives the two hot paths once, through the entry points a user
 calls (``import hetu_tpu as ht``, ``ht.Executor``, ``InferenceEngine``, the
 ``parallel/strategies.py`` strategies, ``serving_mesh``, ``EngineFleet``):
 
-* trainer leg: BERT-base as ``bench.py`` builds it at full size (hidden 768,
+* trainer leg: BERT-base at its published size (hidden 768,
   12 layers, 12 heads, vocabulary 30,522, batch 64, sequence 512, bf16 over
   f32 masters, AdamW, dropout on with ``rng_impl="rbg"``), eight steps on
   one fixed batch;
@@ -47,7 +47,6 @@ FLASH_KERNELS = ("hetu_flash_fwd", "hetu_flash_bwd")
 CE_KERNELS = ("hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd")
 
 FULL = {
-    # bench.py bench_bert without --quick
     "bert": dict(batch=64, seq=512, config=dict(
         vocab_size=30522, hidden_size=768, num_hidden_layers=12,
         max_position_embeddings=512), steps=8),

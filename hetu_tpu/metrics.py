@@ -100,10 +100,9 @@ def confusion_matrix(y_pred, y_true, num_classes):
 
 
 # -- serving latency statistics ---------------------------------------------
-# Shared by the serving engine and `bench.py --serve` so the percentile
-# math lives in exactly one place (linear interpolation over the sorted
-# sample, numpy's default — stable for the small per-round request
-# counts the bench replays).
+# The serving engine's percentile math, in exactly one place (linear
+# interpolation over the sorted sample, numpy's default — stable for
+# small request counts).
 
 def percentile(values, q):
     """q-th percentile (0..100) of a 1-D sample; nan on empty input."""

@@ -22,8 +22,8 @@ The planner closes the loop, end to end:
   winner into the concrete things the runtime consumes: a mesh +
   per-layer shardings, a ``parallel.strategies`` annotation, a serving
   tp size, and a JSON plan artifact carrying the predicted iteration
-  time + per-stage memory.  ``bench.py --plan`` executes the emitted
-  plan and gates predicted-vs-measured error (``plan_pred_err``).
+  time + per-stage memory (``tests/test_planner.py`` executes an
+  emitted plan end to end).
 - :mod:`.fleet_plan` — search tp_size × replica_count × page-pool
   geometry under a fleet HBM budget and a declared ``SLO`` from
   measured serving costs; ``FleetController.replan()`` adopts the

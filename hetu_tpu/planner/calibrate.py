@@ -2,8 +2,8 @@
 
 Two paths feed the search, both measured:
 
-1. :func:`calibrate_hp_layers` — the HP-layer path ``bench.py --plan``
-   uses: time each distinct layer spec's compiled **fwd+bwd** on the
+1. :func:`calibrate_hp_layers` — the HP-layer path: time each
+   distinct layer spec's compiled **fwd+bwd** on the
    live backend (``value_and_grad``, so the cost model's ``bwd = 2 ×
    fwd`` convention is calibrated against what will actually run), read
    activation memory from the XLA temp-bytes slope over two batch
@@ -177,7 +177,7 @@ def measured_ici_gbps(mesh=None):
 
 def calibrate_and_save(path, specs, batch=2, seq=64, reps=5,
                        devices=None, mesh=None):
-    """The whole calibration pass ``bench.py --plan`` runs: measured
+    """The whole calibration pass: measured
     HP-layer profiles + measured ICI bandwidth, written as the
     versioned profile artifact.  Returns ``(layers, ici_gbps, meta)``
     (the artifact is at ``path``)."""

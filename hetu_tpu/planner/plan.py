@@ -8,8 +8,7 @@ the perf gate need:
 - the winning ``HybridParallelConfig`` (the executable part),
 - the PREDICTED iteration time and per-stage memory — recomputed from
   the cost model over the winning assignment, so the artifact's number
-  is exactly the quantity ``bench.py --plan`` gates against the
-  measured run (``plan_pred_err``),
+  is exactly the quantity a measured run is compared with,
 - provenance: which DP core ran, the profile's calibration meta, the
   ICI bandwidth the comm terms were priced with.
 

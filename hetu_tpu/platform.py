@@ -19,7 +19,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def enable_compile_cache():
     """Give jax's persistent compilation cache a directory; returns it.
 
-    Entry points (``chip_smoke.py``, ``bench.py``, the ``examples/``
+    Entry points (``chip_smoke.py``, ``chipbench``, the ``examples/``
     mains) call this before their first compilation; importing
     ``hetu_tpu`` does not.  When ``JAX_COMPILATION_CACHE_DIR`` is set,
     jax has already read it and nothing is changed here.  Otherwise the

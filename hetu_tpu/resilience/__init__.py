@@ -15,8 +15,8 @@ training loop survive those, at near-zero steady-state cost:
   hook that flushes a final checkpoint so a killed run resumes bitwise;
 * :mod:`faults` — deterministic, seed-driven fault injection (NaN
   batches, dataloader errors, silent prefetch-producer death, PS RPC
-  delay/drop, torn files, simulated preemption) backing the tests and
-  ``bench.py --chaos``;
+  delay/drop, torn files, simulated preemption) backing the tests
+  (``tests/test_chaos_stages.py`` runs one stage a fault class);
 * :func:`retry` (retry.py) — the one backoff/jitter/deadline retry
   policy shared by the PS transport and dataset fetch paths;
 * :class:`ElasticTrainer` (elastic.py) — the capacity-change

@@ -159,7 +159,7 @@ def drop_rpc(table, calls=1):
 # -- serving faults --------------------------------------------------------
 # The serving-engine counterparts of the training-path faults: each one
 # makes a production failure of the continuous-batching engine happen at
-# a KNOWN place (bench.py --chaos --serve and tests/test_serving_
+# a KNOWN place (tests/test_chaos_stages.py and tests/test_serving_
 # robustness.py drive them).
 
 def poison_slot_kv(engine, slot, value=np.nan):
@@ -270,8 +270,8 @@ def thrash_cache(cache, n_keys, seed=0, lo=0, hi=None):
 
 
 # -- fleet faults ----------------------------------------------------------
-# Replica-level failures for the fleet layer (bench.py --chaos --serve
-# --fleet and tests/test_fleet.py): where the serving faults above hit
+# Replica-level failures for the fleet layer (tests/test_chaos_stages.py
+# and tests/test_fleet.py): where the serving faults above hit
 # one slot/consumer, these take out a WHOLE engine — the blast radius
 # the EngineFleet's quarantine/failover/restart machinery must contain.
 

@@ -13,11 +13,9 @@ supervised engine replicas behind a latency-aware router with health
 state machines, circuit-broken quarantine, failover of in-flight
 requests (bitwise-identical greedy streams via teacher-forced replay),
 and supervised restarts over the shared compile-once program cache.
-``bench.py --serve`` replays a Poisson arrival trace through the engine
-and its static-batch twin; ``bench.py --chaos --serve`` injects serving
-faults and proves one engine survives them; ``bench.py --chaos --serve
---fleet`` kills, wedges, and rolls whole replicas and proves the fleet
-loses nothing.
+``tests/test_chaos_stages.py`` injects every serving fault and holds one
+engine to surviving it, then kills, wedges and rolls whole replicas and
+holds the fleet to losing nothing.
 
 Engines scale past one chip with TENSOR-PARALLEL serving (sharding.py,
 docs/SHARDING.md): ``InferenceEngine(..., paged=True, mesh=
@@ -44,8 +42,6 @@ subpackage (embedding/) serves batched sparse-feature lookups + CTR
 scoring through the identical Scheduler — a HET-style device hot-row
 cache over the PS table tier, packed-lookup scoring, and
 ``EngineFleet(engine_factory=EmbeddingServer)`` for cluster routing.
-``bench.py --serve-embed`` replays a seeded Zipfian key trace against
-an uncached host-tier twin.
 
 The fleet also moves LIVE state between replicas (kv_transfer.py): a
 mid-decode request's refcounted KV pages — raw float32 rows or the
@@ -67,8 +63,7 @@ Above the fleet sits the SLO control plane (control.py): a declared
 :class:`~.control.SLO` plus a :class:`~.control.FleetController` that
 autoscales replicas, sheds provably-infeasible work at admission with a
 typed :class:`~.control.SLOReject`, and walks a staged brownout ladder
-under sustained violation.  ``bench.py --slo`` replays a bursty diurnal
-trace through a controlled fleet vs its static twin.
+under sustained violation.
 """
 
 from .kv_cache import PagedKVCache, QuantizedKVPool, SlotKVCache

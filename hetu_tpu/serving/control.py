@@ -35,9 +35,7 @@ The controller is clock-injectable (defaults to the fleet's clock) and
 drives the same way the fleet does: call :meth:`FleetController.tick`
 after each ``pump()`` in manual mode, or :meth:`start` a supervisor
 thread next to a threaded fleet.  ``telemetry.enable(debug=True)``
-mounts :func:`slo_report` at ``/slo``.  The bench story is
-``bench.py --slo``: a seeded bursty diurnal trace through a controlled
-fleet vs its static twin, SLO attainment as the headline.
+mounts :func:`slo_report` at ``/slo``.
 """
 
 from __future__ import annotations

@@ -21,8 +21,7 @@ bounded-queue admission (typed ``EngineOverloaded``), deadlines/TTL,
 ``cancel()``, an in-graph finiteness sentinel, telemetry instruments,
 and ``EngineFleet`` routing/failover (``engine_factory=
 EmbeddingServer``) all work unchanged for microsecond-scale embedding
-traffic.  ``bench.py --serve-embed`` replays a seeded Zipfian key trace
-against an uncached host-tier twin.
+traffic.
 """
 
 from .hot_cache import DeviceHotRowCache, EMBED_BUCKETS

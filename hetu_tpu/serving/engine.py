@@ -882,7 +882,7 @@ class InferenceEngine:
         """Capture cost/memory for both serving programs through
         ``profiler``'s signature cache (profile names
         ``{prefix}_prefill`` / ``{prefix}_decode``; the prefix defaults
-        to the adapter name, matching ``bench.py --profile``).  Only a
+        to the adapter name).  Only a
         cache MISS builds the AOT programs — :meth:`cost_programs` runs
         at most once per call and not at all when both signatures hit,
         so calling this every controller tick never re-traces."""

@@ -22,8 +22,8 @@ Typical wiring::
     print(telemetry.report())             # snapshot + phase breakdown
     telemetry.shutdown()
 
-``bench.py --telemetry`` drives exactly this around every stage and
-appends :func:`report` to the stage's detail JSON.
+``tests/test_chaos_stages.py`` runs every fault stage with exactly this
+on and reads the alert, goodput and request-trace planes back.
 """
 
 from __future__ import annotations

@@ -15,9 +15,8 @@ so every derived signal here is unit-testable without a device:
 * :func:`derive` — the full per-program derived block bench/report use.
 
 On CPU the table returns a NOMINAL host peak: the absolute MFU is
-meaningless there (and flagged ``peak_source="nominal_cpu"``), but the
-ratio is stable run-to-run, which is what the perf-regression harness
-(tools/perf_diff.py) diffs.
+meaningless there (and flagged ``peak_source="nominal_cpu"``); device
+numbers come from a chip run (``python3 -m chipbench.run``) only.
 """
 
 from __future__ import annotations

@@ -223,7 +223,7 @@ def _install_synthetic_device_capture(tmp_path):
                # host lane: dispatch work + a python tracer frame
                {"ph": "X", "pid": 1, "tid": 7, "name": "ExecuteSharded",
                 "ts": 0.0, "dur": 900.0},
-               {"ph": "X", "pid": 1, "tid": 7, "name": "$bench.py:12 f",
+               {"ph": "X", "pid": 1, "tid": 7, "name": "$train.py:12 f",
                 "ts": 1.0, "dur": 5.0},
            ]}
     d = tmp_path / "devcap" / "plugins" / "profile" / "0001"

@@ -532,49 +532,6 @@ def test_fleet_stats_surface(served):
     fleet.stop()
 
 
-# -- fleet chaos bench, end to end -------------------------------------------
-
-@pytest.mark.timeout(420)
-def test_chaos_fleet_bench_subprocess(tmp_path):
-    """bench.py --chaos --serve --fleet --quick: all five fleet chaos
-    stages recover with zero accepted-request loss and balanced audits,
-    the single-engine twin demonstrably loses its in-flight streams on
-    the same seed, and FLEET_FULL.json honors the no-clobber contract."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    detail = tmp_path / "FLEET_FULL.json"
-    detail.write_text('{"previous": "round"}\n')
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               HETU_FLEET_JSON=str(detail))
-    root = os.path.join(os.path.dirname(__file__), "..")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"),
-         "--chaos", "--serve", "--fleet", "--quick"],
-        capture_output=True, text=True, timeout=400, env=env, cwd=root)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "chaos_fleet_resilience"
-    assert out["all_stages_recovered"] is True
-    assert out["zero_accepted_loss"] is True
-    full = json.loads(detail.read_text())
-    assert full["slot_audit_balanced"] is True
-    assert {"engine_crash", "engine_wedge", "slow_engine",
-            "rolling_restart", "burst_failover"} <= set(full["stages"])
-    for name, stage in full["stages"].items():
-        assert stage["faults_recovered"] >= stage["faults_injected"], \
-            name
-    crash = full["stages"]["engine_crash"]
-    # failed-over greedy streams bitwise identical to uninterrupted
-    assert crash["token_parity"] is True
-    assert crash["trace_counts"] == {"prefill": 1, "step": 1}
-    # the single-engine twin LOSES its in-flight streams on the same seed
-    twin = crash["single_engine_twin"]
-    assert twin["engine_died"] and twin["lost_in_flight_streams"] > 0
-
-
 def test_no_nondaemon_threads_survive_fleet(served):
     """Fleet drivers/supervisors are daemons and are joined at stop —
     nothing non-daemon may outlive the fleet (the conftest fixture

@@ -298,9 +298,21 @@ def test_spec_crash_failover_mid_speculation_bitwise(rng):
         reqs += [fleet.submit(p, 10, temperature=0.8, top_k=8,
                               seed=7 + i)
                  for i, p in enumerate(prompts[4:])]
-        fleet.pump(3)
-        victim = max(fleet._replicas, key=lambda r: len(r.inflight))
-        assert victim.inflight
+        # kill a replica that provably holds a stream MID-speculation:
+        # tokens emitted AND tokens outstanding.  A fixed pump count
+        # cannot promise that — windows of spec_k + 1 accepted tokens
+        # finish a 10-token request in three iterations.
+        def mid_stream(rep):
+            return [a for _, a in rep.inflight.values()
+                    if 0 < len(a.tokens) < a.max_new]
+
+        for _ in range(10):
+            fleet.pump(1)
+            victim = max(fleet._replicas,
+                         key=lambda r: len(mid_stream(r)))
+            if mid_stream(victim):
+                break
+        assert victim.inflight and mid_stream(victim)
         faults.crash_engine(victim.engine)
         fleet.wait(reqs)
     assert fleet.stats()["failovers"] >= 1
