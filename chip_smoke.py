@@ -42,9 +42,10 @@ import time
 
 REHEARSAL_TAG = "[REHEARSAL cpu toy-size] "
 
-#: the four Mosaic kernels a BERT train step must contain on the chip
+#: the Mosaic kernels a BERT train step must contain on the chip
 FLASH_KERNELS = ("hetu_flash_fwd", "hetu_flash_bwd")
 CE_KERNELS = ("hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd")
+DROPOUT_KERNELS = ("hetu_dropout_mask",)
 
 FULL = {
     "bert": dict(batch=64, seq=512, config=dict(
@@ -287,12 +288,15 @@ def check_kernels(smoke, ex, feed, choices, strategy, c, batch):
     dp, tp = axes.get("dp", 1), axes.get("tp", 1)
     # the 2-class NSP head is below the softmax-CE kernel's 1024-class
     # floor by design; under a tp axis the MLM head's vocabulary is sharded
-    # and the loss keeps its jnp form
+    # and the loss keeps its jnp form, and so does the dropout mask, which
+    # the shards of a replicated activation must agree on
     allowed = {("softmax_ce", "jnp", "vocab<1024")}
     if tp > 1:
-        allowed.add(("softmax_ce", "jnp", f"mesh_axis:tp={tp}"))
+        allowed |= {(k, "jnp", f"mesh_axis:tp={tp}")
+                    for k in ("softmax_ce", "dropout")}
     if smoke.rehearsal:
-        allowed.add(("flash_attention", "jnp", "platform:cpu"))
+        allowed |= {(k, "jnp", "platform:cpu")
+                    for k in ("flash_attention", "dropout")}
     fallbacks = {k: n for k, n in choices.items() if k[1] == "jnp"}
     unexpected = {k: n for k, n in fallbacks.items() if k not in allowed}
     smoke.check(not unexpected,
@@ -310,7 +314,7 @@ def check_kernels(smoke, ex, feed, choices, strategy, c, batch):
               f"(lowered again for reading in {time.perf_counter() - t0:.1f}"
               " s): " + ", ".join(f"{k} x{len(v)} on {v[0]}"
                                   for k, v in sorted(calls.items())))
-    want = FLASH_KERNELS + (CE_KERNELS if tp == 1 else ())
+    want = FLASH_KERNELS + (CE_KERNELS + DROPOUT_KERNELS if tp == 1 else ())
     for name in want:
         smoke.check(len(calls.get(name, ())) >= 1,
                     f"{name} is in the compiled step as a Mosaic call")

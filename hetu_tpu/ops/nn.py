@@ -320,6 +320,33 @@ def batch_normalization_op(x, scale, bias, momentum=0.1, eps=1e-5,
                        channel_axis=channel_axis, name=name)
 
 
+def _dropout_mask_plan(shape, mesh):
+    """Decide where the keep mask of an activation of ``shape`` is drawn.
+
+    Returns ``(reason, batch_axes)``: ``reason`` is None when the Pallas
+    kernel draws it from the chip's generator (ops/pallas/dropout.py; on
+    each device for its own rows, under ``shard_map`` over ``batch_axes``,
+    when that tuple is non-empty), else why ``jax.random.bernoulli``
+    does.  The kernel is taken on ``tpu`` only (the generator does not
+    exist elsewhere), for shards that are whole int8 tiles, and under a
+    mesh only where nothing but ``dp`` splits the program: on any other
+    axis the operand may be replicated and its shards must agree on one
+    mask."""
+    from .pallas import dispatch
+    from .pallas.dropout import unsupported
+    if not dispatch.mosaic():
+        return f"platform:{dispatch.platform()}", ()
+    why = unsupported(shape)
+    if why is not None:
+        return why, ()
+    why, axes = dispatch.shard_axes(mesh, {"dp": shape[0]})
+    if why is not None:
+        return why, ()
+    shards = mesh.shape["dp"] if axes["dp"] else 1
+    why = unsupported((shape[0] // shards,) + tuple(shape[1:]))
+    return why, axes["dp"] if why is None else ()
+
+
 class DropoutOp(Op):
     """Inverted dropout (reference Dropout.cu / CudnnDropout)."""
 
@@ -335,9 +362,25 @@ class DropoutOp(Op):
         (x,) = input_vals
         if not ctx.training or self.keep_prob >= 1.0:
             return x
-        mask = jax.random.bernoulli(ctx.rng_for(self), self.keep_prob,
-                                    x.shape)
+        mask = self._keep_mask(x.shape, ctx)
         return jnp.where(mask, x / self.keep_prob, 0.0).astype(x.dtype)
+
+    def _keep_mask(self, shape, ctx):
+        from .pallas import dispatch
+        why, batch_axes = _dropout_mask_plan(shape, ctx.mesh)
+        if not dispatch.record("dropout", why):
+            return jax.random.bernoulli(ctx.rng_for(self), self.keep_prob,
+                                        shape)
+        from .pallas.dropout import dropout_mask, sharded_dropout_mask
+        seed = jax.random.bits(ctx.rng_for(self), (1,),
+                               "uint32").astype(jnp.int32)
+        if batch_axes:
+            mask = sharded_dropout_mask(ctx.mesh, seed, shape,
+                                        self.keep_prob,
+                                        batch_axes=batch_axes)
+        else:
+            mask = dropout_mask(seed, shape, self.keep_prob)
+        return mask != 0
 
 
 def dropout_op(x, keep_prob=0.9, name=None):

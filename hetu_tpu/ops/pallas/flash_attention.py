@@ -707,6 +707,16 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     return out
 
 
+def shard_seed(seed, axes):
+    """Inside ``shard_map``: ``seed`` offset by this shard's index along
+    the mesh ``axes``, so that shards do not repeat one dropout mask."""
+    if not axes:
+        return seed
+    shard = jax.lax.axis_index(tuple(axes))
+    return seed + shard.astype(seed.dtype) * jnp.asarray(
+        -1640531535, seed.dtype)              # 0x9E3779B1, wraps in int32
+
+
 def sharded_flash_attention(mesh, q, k, v, mask=None, *, batch_axes=(),
                             head_axes=(), seed=None, num_heads=None, **kw):
     """:func:`flash_attention` inside a GSPMD mesh program.
@@ -742,10 +752,8 @@ def sharded_flash_attention(mesh, q, k, v, mask=None, *, batch_axes=(),
         rest = list(rest)
         m = rest.pop(0) if mask is not None else None
         sd = rest.pop(0) if seed is not None else None
-        if sd is not None and batch_axes + head_axes:
-            shard = jax.lax.axis_index(batch_axes + head_axes)
-            sd = sd + shard.astype(sd.dtype) * jnp.asarray(
-                -1640531535, sd.dtype)        # 0x9E3779B1, wraps in int32
+        if sd is not None:
+            sd = shard_seed(sd, batch_axes + head_axes)
         return flash_attention(q, k, v, mask=m, seed=sd,
                                num_heads=num_heads, **kw)
 

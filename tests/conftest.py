@@ -98,6 +98,21 @@ def clone_params_into(ex, prev):
     return {k: np.asarray(v) for k, v in ex.params.items()}
 
 
+def jaxpr_primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations' parameters (jit, shard_map, custom_vjp, scan), except
+    inside a ``pallas_call``: the kernel's own body is not the program's."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from jaxpr_primitives(sub)
+
+
 @pytest.fixture
 def live_registry():
     """The process registry counts only while enabled."""

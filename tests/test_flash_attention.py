@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from conftest import jaxpr_primitives
 from hetu_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -279,20 +280,6 @@ def test_blockwise_api_matches_reference_with_offsets(rng):
                                    rtol=2e-4, atol=2e-4)
 
 
-def _primitives(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs in its parameters,
-    pallas kernels' bodies left out."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name == "pallas_call":
-            continue
-        for val in eqn.params.values():
-            for sub in (val if isinstance(val, (list, tuple)) else [val]):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _primitives(sub)
-
-
 @pytest.mark.parametrize("heads,layout,group,transposes", [
     (4, "bshd", 2, 0),      # pairs of 64-wide heads, read in place
     (3, "bhsd", 1, 8),      # 3 heads do not pair: q, k, v, o and cotangents
@@ -317,7 +304,7 @@ def test_grad_through_the_op_is_two_kernels(monkeypatch, live_registry,
         lambda q, k, v: jnp.sum(op._compute([q, k, v], ctx)
                                 .astype(jnp.float32) ** 2),
         argnums=(0, 1, 2)))(x, x, x)
-    eqns = list(_primitives(jaxpr.jaxpr))
+    eqns = list(jaxpr_primitives(jaxpr.jaxpr))
     kernels = sorted(e.params["name"] for e in eqns
                      if e.primitive.name == "pallas_call")
     assert kernels == ["hetu_flash_bwd", "hetu_flash_fwd"]
@@ -331,7 +318,7 @@ def test_grad_through_the_4d_entry_is_two_kernels():
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True) ** 2),
         argnums=(0, 1, 2)))(q, q, q)
-    eqns = list(_primitives(jaxpr.jaxpr))
+    eqns = list(jaxpr_primitives(jaxpr.jaxpr))
     assert sorted(e.params["name"] for e in eqns
                   if e.primitive.name == "pallas_call") == [
         "hetu_flash_bwd", "hetu_flash_fwd"]
@@ -671,9 +658,13 @@ def v5e():
 
 @pytest.fixture
 def as_on_tpu(monkeypatch):
-    from hetu_tpu.ops.pallas import dispatch, flash_attention as F
+    from hetu_tpu.ops.pallas import dispatch, dropout, flash_attention as F
     monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
     monkeypatch.setattr(F, "interpret", lambda: False)
+    monkeypatch.setattr(dropout, "interpret", lambda: False)
+    dropout._mask.clear_cache()      # interpret() is read when it is traced
+    yield
+    dropout._mask.clear_cache()
 
 
 @pytest.mark.parametrize("shape,heads,with_mask,causal,keep", [
@@ -726,3 +717,47 @@ def test_in_place_kernels_compile_per_shard_on_four_chips(v5e, as_on_tpu):
     assert len(kernels) == 2
     assert all("bf16[64,512,768]" in ln for ln in kernels)
     assert not re.findall(r" = bf16\[[\d,]+\]\S* (?:copy|transpose)\(", hlo)
+
+
+@pytest.mark.parametrize("dp", [1, 4])
+def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
+    """BERT's hidden dropout, forward and backward, on one chip and under
+    DataParallel(4): one ``hetu_dropout_mask`` call a dropout, writing the
+    int8 mask of the device's own [64 x 512, 768] rows, and no random word
+    of the activation anywhere (``rbg`` draws the u32[1] seeds)."""
+    import re
+    import types
+    import hetu_tpu as ht
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+    shape = (64 * dp, 512, 768)
+    if dp == 1:
+        mesh, on = None, lambda spec: SingleDeviceSharding(v5e.devices[0])
+    else:
+        mesh = Mesh(np.array(v5e.devices).reshape(dp), ("dp",))
+        on = lambda spec: NamedSharding(mesh, spec)
+    ops = [ht.dropout_op(ht.placeholder_op(f"dmc_{dp}_{i}", shape), 0.9)
+           for i in range(2)]
+
+    def loss(x, key_data):
+        key = jax.random.wrap_key_data(key_data, impl="rbg")
+        ctx = types.SimpleNamespace(
+            training=True, mesh=mesh,
+            rng_for=lambda op: jax.random.fold_in(key, op.id))
+        for op in ops:
+            x = op._compute([x * 2], ctx)
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=on(P("dp"))),
+        jax.ShapeDtypeStruct((4,), jnp.uint32, sharding=on(P()))
+    ).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == len(ops)
+    assert all("hetu_dropout_mask" in ln and " = s8[32768,768]" in ln
+               for ln in kernels)
+    drawn = re.findall(r" = u32\[([\d,]*)\]\S* rng-bit-generator\(", hlo)
+    assert drawn and set(drawn) == {"1"}
+    words = [int(np.prod([int(n) for n in dims.split(",") if n]))
+             for dims in re.findall(r"u32\[([\d,]*)\]", hlo)]
+    assert max(words) <= 4
