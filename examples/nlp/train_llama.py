@@ -12,6 +12,12 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            --seq-len 4096 --batch-size 2     (OLMoE's block as published:
            QK-norm, top-8 of 64 dropless experts, balance and z losses; one
            layer and its 206 M-parameter embedding and head fill a v5e)
+       python examples/nlp/train_llama.py --model qwen3-next-80b-a3b \
+           --layers 4 --experts-held 0:32 --vocab-rows 18992 \
+           --seq-len 8192 --batch-size 1     (Qwen3-Next at one chip's share
+           of a 16-way expert-parallel job: three Gated DeltaNet layers and
+           one gated-attention layer, 32 of 512 experts held with the shared
+           expert, an eighth of the vocabulary)
 """
 
 import os
@@ -28,13 +34,14 @@ import jax.numpy as jnp
 import hetu_tpu as ht
 from hetu_tpu.layers.moe import record_moe_load
 from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
-                             load_hf_llama_weights)
+                             Qwen3NextConfig, Qwen3NextForCausalLM,
+                             QWEN3_NEXT_CONFIGS, load_hf_llama_weights)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="llama-7b",
-                    choices=list(LLAMA_CONFIGS))
+                    choices=list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -43,8 +50,13 @@ def main():
                     help="override hidden size (0 = model default)")
     ap.add_argument("--intermediate", type=int, default=0,
                     help="override FFN size (0 = model default)")
-    ap.add_argument("--vocab", type=int, default=0,
-                    help="override vocab size (0 = model default)")
+    ap.add_argument("--vocab", "--vocab-rows", type=int, default=0,
+                    help="override vocab size (0 = model default); where "
+                         "it is a slice of the published vocabulary, ids, "
+                         "logits and the loss are over the slice")
+    ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
+                    help="qwen3-next: the experts of each layer this chip "
+                         "holds, of the router's full width")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--tp", type=int, default=1)
@@ -55,20 +67,29 @@ def main():
                     help="path to a transformers checkpoint dir to load")
     args = ap.parse_args()
 
-    base = dict(LLAMA_CONFIGS[args.model])
-    for field, val in (("num_layers", args.layers),
-                       ("hidden_size", args.hidden),
-                       ("intermediate_size", args.intermediate),
+    # (configs, config class, model class, the names of depth and FFN width)
+    family = ((QWEN3_NEXT_CONFIGS, Qwen3NextConfig, Qwen3NextForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in QWEN3_NEXT_CONFIGS else
+              (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
+               "num_layers", "intermediate_size"))
+    configs, config_cls, model_cls, depth, width = family
+    base = dict(configs[args.model])
+    for field, val in ((depth, args.layers), ("hidden_size", args.hidden),
+                       (width, args.intermediate),
                        ("vocab_size", args.vocab)):
         if val:
             base[field] = val
-    c = LlamaConfig(seq_len=args.seq_len, **base)
+    if args.experts_held:
+        base["experts_held"] = tuple(
+            int(n) for n in args.experts_held.split(":"))
+    c = config_cls(seq_len=args.seq_len, **base)
     rng = np.random.default_rng(0)
     B, S = args.batch_size, args.seq_len
 
     ids = ht.placeholder_op("ids", (B, S), dtype=np.int32)
     labels = ht.placeholder_op("labels", (B, S), dtype=np.int32)
-    model = LlamaForCausalLM(c, pipeline_stages=args.pp or None)
+    model = model_cls(c, pipeline_stages=args.pp or None)
     loss = model.loss(ids, labels)
     opt = ht.AdamWOptimizer(learning_rate=args.lr, weight_decay=0.01)
 

@@ -15,14 +15,20 @@ applies rotary embeddings to q/k before the attention product; ``alibi``
 adds the per-head linear bias instead; ``num_kv_heads`` < num_heads gives
 grouped-query attention (K/V projected to the smaller head count and
 broadcast back at the attention einsum); ``qk_norm`` applies an RMSNorm to
-the projected queries and keys (OLMoE).
+the projected queries and keys (OLMoE), ``qk_norm="head"`` one to each head's
+query and key (Qwen3, Qwen3-Next).  ``head_dim`` sets the head size apart
+from ``hidden_size // num_heads`` (Qwen3-Next: 16 heads of 256 on a hidden
+size of 2,048), ``rotary_dim`` rotates only the first dimensions of a head,
+``output_gate`` doubles the query projection and multiplies the context by
+the sigmoid of its second half, head by head.
 """
 
 from __future__ import annotations
 
 from .base import BaseLayer, fresh_name
 from .common import Linear, RMSNorm
-from ..ops import array_reshape_op, transpose_op, head_split_linear_op
+from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
+                   split_op, sigmoid_op)
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.rotary import rotary_embedding_op, repeat_kv_op, alibi_bias_op
 
@@ -32,15 +38,20 @@ class MultiHeadAttention(BaseLayer):
                  dropout_rate=0.0, causal_mask=False, num_kv_heads=None,
                  rope_theta=None, alibi=False, bias=True,
                  fused_head_projection=False, qk_norm=False,
-                 qk_norm_eps=1e-5, name=None):
-        assert hidden_size % num_heads == 0
+                 qk_norm_eps=1e-5, head_dim=None, rotary_dim=None,
+                 output_gate=False, qk_norm_zero_centered=False, name=None):
+        assert head_dim is not None or hidden_size % num_heads == 0
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
         self.hidden_size = hidden_size
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads or num_heads
         assert num_heads % self.num_kv_heads == 0
-        self.head_dim = hidden_size // num_heads
+        self.head_dim = head_dim or hidden_size // num_heads
+        #: width of the heads side by side: the context's, and q's
+        self.inner = self.num_heads * self.head_dim
+        self.rotary_dim = rotary_dim
+        self.output_gate = output_gate
         self.sequence_length = sequence_length
         self.dropout_keep = 1.0 - dropout_rate
         self.causal = causal_mask
@@ -48,29 +59,40 @@ class MultiHeadAttention(BaseLayer):
         self.alibi = alibi
         assert not (alibi and rope_theta), "pick one position encoding"
         kv_dim = self.num_kv_heads * self.head_dim
-        self.q_proj = Linear(hidden_size, hidden_size, bias=bias,
-                             name=f"{name}_q")
+        # with the output gate each head's query is followed by its gate:
+        # [.., heads, 2 d] (HF Qwen3NextAttention), not two halves of the row
+        self.q_proj = Linear(hidden_size,
+                             self.inner * (2 if output_gate else 1),
+                             bias=bias, name=f"{name}_q")
         self.k_proj = Linear(hidden_size, kv_dim, bias=bias,
                              name=f"{name}_k")
         self.v_proj = Linear(hidden_size, kv_dim, bias=bias,
                              name=f"{name}_v")
-        self.out_proj = Linear(hidden_size, hidden_size, bias=bias,
+        self.out_proj = Linear(self.inner, hidden_size, bias=bias,
                                name=f"{name}_out")
         # QK-norm (OLMoE, OLMo 2): an RMSNorm over the whole projected
         # width of q and of k, before the head split and the rotation
         self.q_norm = self.k_norm = None
-        if qk_norm:
+        self.qk_norm_per_head = qk_norm == "head"
+        if self.qk_norm_per_head:
+            self.q_norm, self.k_norm = (
+                RMSNorm(self.head_dim, eps=qk_norm_eps,
+                        zero_centered=qk_norm_zero_centered,
+                        name=f"{name}_{n}_norm") for n in "qk")
+        elif qk_norm:
             assert not fused_head_projection, (
                 "qk_norm normalises the projection before the head split")
-            self.q_norm = RMSNorm(hidden_size, eps=qk_norm_eps,
+            self.q_norm = RMSNorm(self.inner, eps=qk_norm_eps,
                                   name=f"{name}_q_norm")
             self.k_norm = RMSNorm(kv_dim, eps=qk_norm_eps,
                                   name=f"{name}_k_norm")
 
-    def _split_heads(self, x, seq_len, n_heads):
+    def _split_heads(self, x, seq_len, n_heads, head_norm=None):
         # [B, S, H] (or [B*S, H]) -> [B, heads, S, d]
         x = array_reshape_op(
             x, output_shape=(-1, seq_len, n_heads, self.head_dim))
+        if head_norm is not None:
+            x = head_norm(x)
         return transpose_op(x, perm=(0, 2, 1, 3))
 
     def _project_heads(self, x, proj, seq_len, n_heads, norm=None):
@@ -86,6 +108,8 @@ class MultiHeadAttention(BaseLayer):
                 *([] if proj.bias is None else [proj.bias]),
                 seq_len=seq_len, n_heads=n_heads, head_dim=self.head_dim)
         x = proj(x)
+        if self.qk_norm_per_head:
+            return self._split_heads(x, seq_len, n_heads, head_norm=norm)
         return self._split_heads(x if norm is None else norm(x), seq_len,
                                  n_heads)
 
@@ -109,7 +133,9 @@ class MultiHeadAttention(BaseLayer):
                 "non-rotary, non-alibi cross-attention")
         kv_seq_len = kv_seq_len or seq_len
         if (self.fused_head_projection or self.alibi
-                or self.num_kv_heads != self.num_heads):
+                or self.num_kv_heads != self.num_heads
+                or self.qk_norm_per_head or self.output_gate
+                or self.rotary_dim is not None):
             return self._attend_bhsd(query, key, value, attention_mask,
                                      seq_len, kv_seq_len)
         q, k, v = self.q_proj(query), self.k_proj(key), self.v_proj(value)
@@ -119,7 +145,7 @@ class MultiHeadAttention(BaseLayer):
             q = self._rotate(q, seq_len)
             k = self._rotate(k, kv_seq_len)
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
-        q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.hidden_size))
+        q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.inner))
                    for x, n in ((q, seq_len), (k, kv_seq_len),
                                 (v, kv_seq_len)))
         ctx_ = scaled_dot_product_attention_op(
@@ -133,21 +159,36 @@ class MultiHeadAttention(BaseLayer):
             x, output_shape=(-1, seq_len, self.num_heads, self.head_dim))
         x = rotary_embedding_op(x, theta=self.rope_theta, seq_axis=1)
         return array_reshape_op(
-            x, output_shape=(-1, seq_len, self.hidden_size))
+            x, output_shape=(-1, seq_len, self.inner))
 
     def _attend_bhsd(self, query, key, value, attention_mask, seq_len,
                      kv_seq_len):
         """The [B, heads, S, d] graph: heads split off and transposed
         before the op, the context transposed back."""
-        q = self._project_heads(query, self.q_proj, seq_len,
-                                self.num_heads, self.q_norm)
+        gate = None
+        if self.output_gate:
+            # [B, S, heads, 2 d]: a head's query, then its gate
+            qg = array_reshape_op(self.q_proj(query), output_shape=(
+                -1, seq_len, self.num_heads, 2 * self.head_dim))
+            gate = array_reshape_op(
+                split_op(qg, axes=3, indices=1, splits=2),
+                output_shape=(-1, seq_len, self.inner))
+            q = self._split_heads(
+                split_op(qg, axes=3, indices=0, splits=2), seq_len,
+                self.num_heads,
+                head_norm=self.q_norm if self.qk_norm_per_head else None)
+        else:
+            q = self._project_heads(query, self.q_proj, seq_len,
+                                    self.num_heads, self.q_norm)
         k = self._project_heads(key, self.k_proj, kv_seq_len,
                                 self.num_kv_heads, self.k_norm)
         v = self._project_heads(value, self.v_proj, kv_seq_len,
                                 self.num_kv_heads)
         if self.rope_theta is not None:
-            q = rotary_embedding_op(q, theta=self.rope_theta)
-            k = rotary_embedding_op(k, theta=self.rope_theta)
+            kw = ({} if self.rotary_dim is None
+                  else {"rotary_dim": self.rotary_dim})
+            q = rotary_embedding_op(q, theta=self.rope_theta, **kw)
+            k = rotary_embedding_op(k, theta=self.rope_theta, **kw)
         if self.num_kv_heads != self.num_heads:
             rep = self.num_heads // self.num_kv_heads
             k = repeat_kv_op(k, n_rep=rep)
@@ -161,5 +202,7 @@ class MultiHeadAttention(BaseLayer):
             dropout_keep=self.dropout_keep)
         ctx_ = transpose_op(ctx_, perm=(0, 2, 1, 3))
         ctx_ = array_reshape_op(ctx_,
-                                output_shape=(-1, seq_len, self.hidden_size))
+                                output_shape=(-1, seq_len, self.inner))
+        if gate is not None:
+            ctx_ = ctx_ * sigmoid_op(gate)
         return self.out_proj(ctx_)

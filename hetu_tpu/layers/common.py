@@ -152,12 +152,23 @@ class LayerNorm(BaseLayer):
 
 
 class RMSNorm(BaseLayer):
-    def __init__(self, hidden_size, eps=1e-6, name=None):
+    """``x / rms(x) * w``, over the last axis (a head's width when ``x`` is
+    a ``[..., heads, d]`` view).  ``zero_centered`` stores ``w`` about zero
+    and scales by ``1 + w`` in f32 (Qwen3-Next, Gemma)."""
+
+    def __init__(self, hidden_size, eps=1e-6, zero_centered=False,
+                 name=None):
         name = fresh_name(name or "rmsnorm")
-        self.scale = VariableOp(f"{name}_scale", (hidden_size,), init.ones())
+        self.zero_centered = zero_centered
+        self.scale = VariableOp(
+            f"{name}_scale", (hidden_size,),
+            init.zeros() if zero_centered else init.ones())
         self.eps = eps
 
     def __call__(self, x):
+        if self.zero_centered:
+            return rms_norm_op(x, self.scale, eps=self.eps,
+                               zero_centered=True)
         return rms_norm_op(x, self.scale, eps=self.eps)
 
 

@@ -158,7 +158,7 @@ class _MoEOp(Op):
 
     def __init__(self, x, gate, w1, b1, w2, b2, num_experts, capacity_factor,
                  k, ep_axis=None, ids=None, sparse=True, w3=None,
-                 load_var=None, name=None):
+                 load_var=None, held=None, name=None):
         # swiglu experts are biasless: b1/b2 are None and stay out of the
         # graph entirely (no dead optimizer state / checkpoint entries)
         inputs = [x, w1, w2] if b1 is None else [x, w1, b1, w2, b2]
@@ -183,6 +183,9 @@ class _MoEOp(Op):
         self.has_w3 = w3 is not None
         self.has_ids = ids is not None
         self.load_var = load_var
+        self.held = held
+        assert held is None or capacity_factor is None, (
+            "a share of the experts (held=) is laid out by the dropless path")
         if capacity_factor is None:
             assert w3 is not None and hasattr(gate, "route"), (
                 "dropless routing (capacity_factor=None) runs swiglu "
@@ -223,13 +226,15 @@ class _MoEOp(Op):
                     x.reshape(-1, x.shape[-1]), wg, self.k))
         return memo[self.id][1]
 
-    def _record_load(self, ctx, routed, kept):
-        """Hand the per-expert pair counts of this step to the executor's
-        state (``MoELayer.load()`` fetches them beside the loss)."""
+    def _record_load(self, ctx, *rows):
+        """Hand the per-expert pair counts of this step (routed, kept; with
+        ``held`` a third row whose first entry is the pairs routed to experts
+        held elsewhere) to the executor's state (``MoELayer.load()`` fetches
+        them beside the loss)."""
         import jax.numpy as jnp
         if self.load_var is not None:
             ctx.record_update(self.load_var, jnp.stack(
-                [routed, kept]).astype(jnp.float32))
+                rows).astype(jnp.float32))
 
     def _compute(self, input_vals, ctx):
         import jax
@@ -243,9 +248,19 @@ class _MoEOp(Op):
         T = tokens.shape[0]
         if self.dropless:
             _, idx, gate, _ = self.routing(x, wg, ctx)
-            y, load = dropless_moe(tokens, idx, gate, w1, w3, w2,
-                                   mesh=ctx.mesh)
-            self._record_load(ctx, load, load)
+            if self.held is None:
+                y, load = dropless_moe(tokens, idx, gate, w1, w3, w2,
+                                       mesh=ctx.mesh)
+                self._record_load(ctx, load, load)
+                return y.reshape(orig_shape)
+            from ..ops.moe import held_rows
+            count = self.held[1]
+            y, lay = dropless_moe(
+                tokens, idx, gate, w1, w3, w2, mesh=ctx.mesh, held=self.held,
+                rows=held_rows(T * self.k, self.num_experts, count))
+            self._record_load(
+                ctx, lay["load"], lay["kept"],
+                jnp.zeros((count,), jnp.int32).at[0].set(lay["elsewhere"]))
             return y.reshape(orig_shape)
         C = self._capacity(T)
 
@@ -375,6 +390,22 @@ class MoEChosenOp(Op):
         return self.moe.routing(x, wg, ctx)[1]
 
 
+def _shared_expert(x, w_gate, w_up, w_down, w_sg):
+    import jax
+    y = (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+    return y * jax.nn.sigmoid(x @ w_sg)
+
+
+def shared_expert_op(x, w_gate, w_up, w_down, w_sg):
+    """The shared expert of a sparse block (Qwen2-MoE, Qwen3-Next): a SwiGLU
+    FFN every token goes through, scaled token by token by the sigmoid of a
+    one-column gate: ``sigmoid(x w_sg) W_d (silu(W_g x) * W_u x)``.  Its
+    device operations carry the scope ``hetu_moe_shared``."""
+    from ..ops.base import ScopedOp
+    return ScopedOp(_shared_expert, "hetu_moe_shared", x, w_gate, w_up,
+                    w_down, w_sg)
+
+
 class MoELoadOp(Op):
     """``[2, E]`` f32: the (token, choice) pairs routed to each expert and
     those of them the layer computed (all, on the dropless path), as the
@@ -402,13 +433,31 @@ class MoELayer(BaseLayer):
     products over the pairs sorted by expert; it needs the ``top`` gate and
     swiglu experts.  ``renorm_topk`` is the gate's ``renorm``.
     ``track_load`` adds a ``[2, E]`` state variable of per-expert pair
-    counts (routed, kept) that ``load()`` fetches."""
+    counts (routed, kept) that ``load()`` fetches.
+
+    ``held=(first, count)`` is one device's share of an expert-parallel
+    layer without the other devices: the router keeps its ``num_experts``
+    outputs and ``k`` a token, the layer holds the weights of the experts
+    ``first .. first + count - 1`` alone and computes only the pairs routed to
+    them (dropless path); what the absent experts would add is left out and
+    nothing stands in for it.  The rows laid out are bounded by twice the
+    mean share (``ops/moe.py held_rows``), pairs over the bound are counted
+    as dropped; the load is ``[3, count]`` then (routed here, kept, and in
+    ``[2, 0]`` the pairs routed elsewhere).  ``shared_width`` adds a shared expert of that width, gated by
+    a sigmoid (``shared_expert_op``), computed for every token."""
 
     def __init__(self, hidden_size, intermediate_size, num_experts, k=2,
                  capacity_factor=1.25, gate="top", ep_axis=None,
                  num_groups=None, sparse=True, expert_act="gelu",
-                 renorm_topk=True, track_load=False, name=None):
+                 renorm_topk=True, track_load=False, held=None,
+                 shared_width=None, name=None):
         name = fresh_name(name or "moe")
+        self.held = held
+        n_held = num_experts
+        if held is not None:
+            first, n_held = held
+            assert 0 <= first and first + n_held <= num_experts, held
+            assert ep_axis is None, "held= is one device's share, unsharded"
         if isinstance(gate, BaseLayer):
             self.gate = gate                      # caller-built gate
         elif gate == "top":
@@ -428,23 +477,31 @@ class MoELayer(BaseLayer):
         assert expert_act in ("gelu", "swiglu")
         self.expert_act = expert_act
         self.w1 = VariableOp(f"{name}_w1",
-                             (num_experts, hidden_size, intermediate_size),
+                             (n_held, hidden_size, intermediate_size),
                              init.xavier_uniform())
-        self.b1 = VariableOp(f"{name}_b1", (num_experts, intermediate_size),
+        self.b1 = VariableOp(f"{name}_b1", (n_held, intermediate_size),
                              init.zeros()) \
             if expert_act == "gelu" else None
         self.w2 = VariableOp(f"{name}_w2",
-                             (num_experts, intermediate_size, hidden_size),
+                             (n_held, intermediate_size, hidden_size),
                              init.xavier_uniform())
-        self.b2 = VariableOp(f"{name}_b2", (num_experts, hidden_size),
+        self.b2 = VariableOp(f"{name}_b2", (n_held, hidden_size),
                              init.zeros()) \
             if expert_act == "gelu" else None
         # swiglu experts (Mixtral-style, reference-beyond): gated FFN
         # silu(x@w1) * (x@w3) @ w2, no biases
         self.w3 = VariableOp(f"{name}_w3",
-                             (num_experts, hidden_size, intermediate_size),
+                             (n_held, hidden_size, intermediate_size),
                              init.xavier_uniform()) \
             if expert_act == "swiglu" else None
+        self.shared = None
+        if shared_width:
+            self.shared = tuple(
+                VariableOp(f"{name}_shared_{n}", shape, init.xavier_uniform())
+                for n, shape in (("gate", (hidden_size, shared_width)),
+                                 ("up", (hidden_size, shared_width)),
+                                 ("out", (shared_width, hidden_size)),
+                                 ("sigmoid", (hidden_size, 1))))
         self.num_experts = num_experts
         self.capacity_factor = capacity_factor
         self.k = k
@@ -454,7 +511,8 @@ class MoELayer(BaseLayer):
         # form and is the default memory-safe path
         self.sparse = sparse
         self.load_var = VariableOp(
-            f"{name}_load", (2, num_experts), init.zeros(),
+            f"{name}_load",
+            (2, num_experts) if held is None else (3, n_held), init.zeros(),
             trainable=False) if track_load else None
         if ep_axis is not None:
             ep_vars = [v for v in (self.w1, self.b1, self.w2, self.b2,
@@ -473,7 +531,9 @@ class MoELayer(BaseLayer):
                               self.capacity_factor, self.k,
                               ep_axis=self.ep_axis, ids=ids,
                               sparse=self.sparse, w3=self.w3,
-                              load_var=self.load_var)
+                              load_var=self.load_var, held=self.held)
+        if self.shared is not None:
+            return self.last_op + shared_expert_op(x, *self.shared)
         return self.last_op
 
     def aux_loss(self):
@@ -504,11 +564,21 @@ def record_moe_load(layer, load):
     * ``hetu_moe_expert_load_max_over_mean{layer}``: the fullest expert's
       pairs over the mean, this step (1.0 is perfectly even).
 
+    From a layer that holds a share of its experts (``MoELayer(held=)``,
+    ``[3, count]``) ``routed``, ``dropped`` and the gauge are over the held
+    experts, and ``hetu_moe_pairs_elsewhere_total{layer}`` counts the pairs
+    the router sent to experts this device does not hold.
+
     The registry counts nothing while telemetry is disabled."""
     from .. import telemetry
     reg = telemetry.get_registry()
-    routed, kept = np.asarray(load, np.float64)
+    load = np.asarray(load, np.float64)
+    routed, kept = load[:2]
     total = routed.sum()
+    if len(load) == 3 and total + load[2, 0] > 0:
+        reg.counter("hetu_moe_pairs_elsewhere_total",
+                    "Routed pairs whose expert another device holds",
+                    labels=("layer",)).labels(layer=layer).inc(load[2, 0])
     if total <= 0:          # the state's initial zeros: no step has run
         return
     reg.counter("hetu_moe_pairs_routed_total",

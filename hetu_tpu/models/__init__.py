@@ -8,6 +8,8 @@ from .gnn import (DistGCN15D, GCNLayerOp, distgcn_15d_op, gcn_conv_op,
                   normalized_adjacency)
 from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM,
                     BaichuanForCausalLM, LLAMA_CONFIGS)
+from .qwen3_next import (Qwen3NextConfig, Qwen3NextModel,
+                         Qwen3NextForCausalLM, QWEN3_NEXT_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,

@@ -184,10 +184,17 @@ class LlamaModel:
         self.embed = Embedding(c.vocab_size, c.hidden_size,
                                initializer=init.normal(0.0, 0.02),
                                name=f"{name}_embed")
-        self.layers = [LlamaDecoderLayer(c, name=f"{name}_layer{i}")
+        self.layers = [self._layer(i, f"{name}_layer{i}")
                        for i in range(c.num_layers)]
-        self.norm = RMSNorm(c.hidden_size, eps=c.rms_eps,
-                            name=f"{name}_norm")
+        self.norm = self._norm(f"{name}_norm")
+
+    def _layer(self, i, name):
+        """Decoder layer ``i``; a family whose layers differ overrides it."""
+        return LlamaDecoderLayer(self.config, name=name)
+
+    def _norm(self, name):
+        return RMSNorm(self.config.hidden_size, eps=self.config.rms_eps,
+                       name=name)
 
     def _scope(self, layer_idx=None):
         S = self.pipeline_stages
@@ -213,10 +220,13 @@ class LlamaModel:
 
 
 class LlamaForCausalLM:
+    #: the decoder under the head; a family with its own layers sets its own
+    model_cls = LlamaModel
+
     @scoped_init
     def __init__(self, config, name="llama", pipeline_stages=None):
-        self.model = LlamaModel(config, name=name,
-                                pipeline_stages=pipeline_stages)
+        self.model = self.model_cls(config, name=name,
+                                    pipeline_stages=pipeline_stages)
         self.config = config
         with (stage(pipeline_stages - 1) if pipeline_stages
               else nullcontext()):

@@ -28,6 +28,22 @@ class SimpleOp(Op):
         return self.impl(*input_vals, **self.attrs)
 
 
+class ScopedOp(Op):
+    """``fn(*inputs, **attrs)`` under ``jax.named_scope(scope)``: a region
+    of the step that the device trace's readers find by its scope in the
+    compiled program's ``op_name`` (forward and backward)."""
+
+    def __init__(self, fn, scope, *inputs, **attrs):
+        super().__init__(*inputs, **attrs)
+        self.name = f"{scope}_{self.id}"
+        self.fn, self.scope = fn, scope
+
+    def _compute(self, input_vals, ctx):
+        import jax
+        with jax.named_scope(self.scope):
+            return self.fn(*input_vals, **self.attrs)
+
+
 def _peek_id():
     from ..graph import node as _n
     return _n._node_counter[0] + 1

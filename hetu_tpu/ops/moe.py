@@ -16,6 +16,8 @@ parallel/collectives.hierarchical_all_to_all for DCN×ICI topologies.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -410,7 +412,17 @@ def router_z_loss(logits):
     return jnp.mean(z * z)
 
 
-def grouped_layout(idx, num_experts, tile=None):
+def held_rows(pairs, num_experts, count):
+    """The static bound on the rows of pairs that land on ``count`` held
+    experts of ``num_experts``: twice the mean share of ``pairs`` (token,
+    choice) pairs.  Which pairs land on the held experts changes from batch
+    to batch, the buffer's size cannot.  Twice, from a v5e (PR 31): with 32
+    of 512 experts held the fullest layer and step used 0.74-0.80 of it in
+    14 runs, and rows for all ``T k`` pairs cost 11% of the step and 1.6 GiB."""
+    return min(pairs, -(-2 * pairs * count // num_experts))
+
+
+def grouped_layout(idx, num_experts, tile=None, held=None, rows=None):
     """Where each (token, choice) pair sits once the pairs are sorted by
     expert.  Pair ``p = t * k + c``.  Returns a dict:
 
@@ -426,14 +438,37 @@ def grouped_layout(idx, num_experts, tile=None):
     tile, so a row tile belongs to one expert (``tile_expert``);
     ``M = T k + E tile`` is the static bound, the tiles from ``n_used`` on
     hold nothing and are assigned to the last expert.  Only sorts, prefix
-    sums and gathers: no scatter."""
+    sums and gathers: no scatter.
+
+    ``held=(first, count)``: this device holds the experts ``first ..
+    first + count - 1`` of ``num_experts`` and lays out only the pairs routed
+    to them; ``load`` is ``[count]``.  The rows are bounded by ``rows`` pairs
+    (``held_rows``; plus the tile padding), whatever the batch routes here:
+    a pair beyond the bound gets no row.  ``kept`` ``[count]`` are the pairs
+    at each held expert that did get one, ``elsewhere`` the pairs routed to
+    experts held by other devices; a pair without a row has
+    ``slot_of_pair == M``, past the end."""
     E = num_experts
     flat = idx.reshape(-1)
     P = flat.shape[0]
+    if held is not None:
+        first, E = held
+        local = flat - first
+        mine = (local >= 0) & (local < E)
+        flat = jnp.where(mine, local, E)       # E sorts after every expert
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     rank = jnp.argsort(order).astype(jnp.int32)      # position of pair p
-    load = expert_load(idx, E)
+    load = expert_load(idx if held is None else flat, E)
     start = jnp.cumsum(load) - load
+    if held is not None and tile is None:
+        M = P if rows is None else rows
+        kept = jnp.clip(M - start, 0, load)
+        has_row = mine & (rank < M)
+        n = jnp.arange(M, dtype=jnp.int32)
+        return {"load": load, "kept": kept, "elsewhere": P - jnp.sum(load),
+                "slot_of_pair": jnp.where(has_row, rank, M),
+                "pair_of_slot": jnp.where(n < jnp.sum(kept), order[:M], -1),
+                "rows": M}
     if tile is None:
         return {"load": load, "slot_of_pair": rank, "pair_of_slot": order,
                 "rows": P}
@@ -442,6 +477,10 @@ def grouped_layout(idx, num_experts, tile=None):
     pstart = (tile_end - tiles) * tile
     slot_of_pair = pstart[flat] + rank - start[flat]
     M = P + E * tile
+    if held is not None:
+        M = -(-(P if rows is None else rows) // tile) * tile + E * tile
+        kept = jnp.clip(M - pstart, 0, load)
+        slot_of_pair = jnp.where(mine & (slot_of_pair < M), slot_of_pair, M)
     n_tiles = M // tile
     tile_expert = jnp.minimum(
         jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
@@ -452,10 +491,15 @@ def grouped_layout(idx, num_experts, tile=None):
     valid = (r >= 0) & (r < load[e_of_slot])
     pair_of_slot = jnp.where(
         valid, order[jnp.clip(start[e_of_slot] + r, 0, P - 1)], -1)
-    return {"load": load, "slot_of_pair": slot_of_pair.astype(jnp.int32),
-            "pair_of_slot": pair_of_slot.astype(jnp.int32),
-            "tile_expert": tile_expert,
-            "n_used": tile_end[-1:].astype(jnp.int32), "rows": M}
+    lay = {"load": load, "slot_of_pair": slot_of_pair.astype(jnp.int32),
+           "pair_of_slot": pair_of_slot.astype(jnp.int32),
+           "tile_expert": tile_expert,
+           "n_used": tile_end[-1:].astype(jnp.int32), "rows": M}
+    if held is not None:
+        lay.update(kept=kept, elsewhere=P - jnp.sum(load),
+                   n_used=jnp.minimum(lay["n_used"], n_tiles),
+                   has_tile=pstart < M)
+    return lay
 
 
 @jax.custom_vjp
@@ -503,19 +547,102 @@ def _rows_back_bwd(res, d_pairs):
 
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
+# -- a share of the experts: rows <-> tokens without a [T k, H] array ---------
+#
+# Of the T k pairs of a layer that holds count of E experts, count / E land
+# here.  The two gathers above walk all T k pairs (their backward passes
+# read k rows a token), which for 32 of 512 experts is sixteen times the
+# rows there are.  The held layout goes between tokens and ROWS instead:
+# tokens -> rows is a gather of the rows' tokens, rows -> tokens a product
+# with the one-hot matrix of the rows' tokens (no scatter-add: 2 T M H
+# operations on the matrix unit, which the expert products leave idle).
+# Each is the other's backward pass.
+
+def _hot(tok, T, dtype):
+    """``[T, M]``: 1 where row ``s`` is token ``t``'s; a row of padding
+    (``tok == T``) has no 1."""
+    return (tok[None, :] == jnp.arange(T, dtype=tok.dtype)[:, None]
+            ).astype(dtype)
+
+
+def _sum_rows(v, tok, T):
+    full = jax.lax.Precision.HIGHEST if v.dtype == jnp.float32 else None
+    return jnp.matmul(_hot(tok, T, v.dtype), v, precision=full,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+def _take_rows(x, tok):
+    return jnp.take(x, tok, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _tokens_to_rows(tokens, tok):
+    """``xs[s] = tokens[tok[s]]`` (zeros where ``tok[s] == T``)."""
+    return _take_rows(tokens, tok)
+
+
+_tokens_to_rows.defvjp(
+    lambda tokens, tok: (_take_rows(tokens, tok), (tok, tokens.shape[0])),
+    lambda res, d_xs: (_sum_rows(d_xs, *res), None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_to_tokens(v, tok, T):
+    """``y[t] = sum of v[s] over the rows s of token t``."""
+    return _sum_rows(v, tok, T)
+
+
+_rows_to_tokens.defvjp(
+    lambda v, tok, T: (_sum_rows(v, tok, T), tok),
+    lambda T, tok, dy: (_take_rows(dy, tok), None))
+
+
+@jax.custom_vjp
+def _pairs_to_rows(by_pair, pair_of_slot, slot_of_pair):
+    """``by_row[s] = by_pair[pair_of_slot[s]]`` (0 in a row of padding) for
+    one number a pair; backward a gather too (a pair without a row reads
+    0)."""
+    src = jnp.where(pair_of_slot >= 0, pair_of_slot, by_pair.shape[0])
+    return _take_rows(by_pair, src)
+
+
+_pairs_to_rows.defvjp(
+    lambda by_pair, pair_of_slot, slot_of_pair: (
+        _pairs_to_rows(by_pair, pair_of_slot, slot_of_pair), slot_of_pair),
+    lambda slot_of_pair, d: (_take_rows(d, slot_of_pair), None, None))
+
+
+@jax.custom_vjp
+def _grad_where(w, keep):
+    """``w`` as it is; its gradient is zero where ``keep [E]`` is false (an
+    expert without a row tile inside the held layout's bound: the ``dw``
+    kernel never visits it and would leave its block unwritten)."""
+    return w
+
+
+_grad_where.defvjp(
+    lambda w, keep: (w, keep),
+    lambda keep, g: (jnp.where(keep[:, None, None], g, 0), None))
+
 #: rows of one tile of the Pallas grouped products: with 64 experts the
 #: padding is E x tile / 2 rows on average, 12.5% of OLMoE's 65,536 pairs
 GMM_TILE = 256
+#: and where a share of the experts is held: at 160 pairs an expert under a
+#: bound of 320 rows, 128-row tiles gave a 386.1 ms step on a v5e, 256-row
+#: tiles 393.3, 8-row tiles 406.9 (PR 31)
+HELD_TILE = 128
 
 
 def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
-                 impl=None):
+                 impl=None, tile=None):
     """``(impl, tile)`` of the grouped products: ``"pallas"`` (the
     ``hetu_moe_gmm_*`` kernels over tile-aligned groups) or ``"ragged"``
     (``jax.lax.ragged_dot`` over the pairs in expert order), recorded in
-    ``dispatch.choices()`` under ``moe_gmm``.  ``impl`` forces one."""
+    ``dispatch.choices()`` under ``moe_gmm``.  ``impl`` forces one, ``tile``
+    the rows of a tile (default: 256 from 256 pairs an expert on, else 8)."""
     from .pallas import dispatch, moe_gmm
-    tile = GMM_TILE if pairs // num_experts >= GMM_TILE else 8
+    if tile is None:
+        tile = GMM_TILE if pairs // num_experts >= GMM_TILE else 8
     if impl == "ragged":
         why = "caller:impl=ragged"
     elif mesh is not None:
@@ -529,31 +656,57 @@ def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
 
 
 def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
-                 impl=None):
+                 impl=None, held=None, rows=None):
     """``y[t] = sum_c gate[t, c] * W_down,e( silu(W_gate,e x_t) * W_up,e
     x_t )`` with ``e = idx[t, c]``; no pair is dropped.  ``tokens [T, H]``,
     ``idx, gate [T, k]``, weights ``[E, H, F]``, ``[E, H, F]``,
-    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``."""
+    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``.
+
+    ``held=(first, count)``: the weights are those of ``count`` experts of
+    the ``num_experts`` that ``idx`` ranges over, and ``y`` is their part of
+    the sum alone: what the experts on other devices would add is left out
+    (``grouped_layout``).  ``rows`` bounds the pairs laid out (``held_rows``);
+    pairs beyond it are not computed and are counted.  Returns ``(y, lay)``
+    then, with the layout's ``load``, ``kept`` and ``elsewhere``."""
     T, H = tokens.shape
     k = idx.shape[1]
     E, _, F = w_gate.shape
-    how, tile = grouped_impl(T * k, E, H, F, tokens.dtype, mesh, impl)
+    pairs, tile = T * k, None
+    if held is not None:
+        pairs = rows or pairs
+        tile = HELD_TILE if pairs // E >= HELD_TILE else 8
+        pairs = -(-pairs // tile) * tile       # the layout rounds up too
+    how, tile = grouped_impl(pairs, E, H, F, tokens.dtype, mesh, impl, tile)
     with jax.named_scope("hetu_moe_dispatch"):
-        lay = grouped_layout(idx, E, tile)
-        xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
+        if held is None:
+            lay = grouped_layout(idx, E, tile)
+            xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
+        else:
+            lay = grouped_layout(idx, None, tile, held=held, rows=rows)
+            tok = jnp.where(lay["pair_of_slot"] >= 0,
+                            lay["pair_of_slot"] // k, T)
+            xs = _tokens_to_rows(tokens, tok)
     with jax.named_scope("hetu_moe_experts"):
         if how == "pallas":
             from .pallas.moe_gmm import grouped_matmul
 
             def product(a, w):
+                if held is not None:
+                    w = _grad_where(w, lay["has_tile"])
                 return grouped_matmul(a, w, lay["tile_expert"],
                                       lay["n_used"], tile, E)
         else:
             def product(a, w):
-                return jax.lax.ragged_dot(a, w, lay["load"])
+                return jax.lax.ragged_dot(a, w, lay.get("kept", lay["load"]))
         act = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
         out = product(act, w_down)
     with jax.named_scope("hetu_moe_combine"):
+        if held is not None:
+            g = _pairs_to_rows(gate.reshape(-1), lay["pair_of_slot"],
+                               lay["slot_of_pair"])
+            weighted = (out.astype(jnp.float32) * g[:, None]).astype(
+                tokens.dtype)
+            return _rows_to_tokens(weighted, tok, T), lay
         pairs = _rows_back(out, lay["pair_of_slot"], lay["slot_of_pair"])
         y = jnp.sum(pairs.reshape(T, k, H).astype(jnp.float32)
                     * gate[:, :, None], axis=1).astype(tokens.dtype)

@@ -151,9 +151,14 @@ def _layer_norm(x, scale, bias, eps=1e-5):
 layer_normalization_op = simple_op(_layer_norm, "layer_normalization")
 
 
-def _rms_norm(x, scale, eps=1e-6):
+def _rms_norm(x, scale, eps=1e-6, zero_centered=False):
+    """``zero_centered``: the weight is stored about zero and the scale is
+    ``1 + w`` in f32 (Gemma, Qwen3-Next); the default scales by ``w``."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    if zero_centered:
+        return (xf * lax.rsqrt(var + eps)
+                * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
     return (xf * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 

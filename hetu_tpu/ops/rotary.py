@@ -30,9 +30,18 @@ def _rope_tables(seq_len, dim, theta, pos_offset=0):
     return jnp.cos(emb), jnp.sin(emb)
 
 
-def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2):
+def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
+            rotary_dim=None):
     """Apply RoPE to [B, H, S, D] (HF rotate_half convention), or, with
-    ``seq_axis=1``, to the [B, S, H, D] view of a projection's output."""
+    ``seq_axis=1``, to the [B, S, H, D] view of a projection's output.
+    ``rotary_dim`` rotates the first ``rotary_dim`` of the ``D`` dimensions
+    (frequencies ``theta^(-2i / rotary_dim)``) and passes the rest through
+    (partial rotary: GPT-NeoX, Qwen3-Next)."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        assert 0 < rotary_dim < x.shape[-1] and rotary_dim % 2 == 0
+        turned = _rotary(x[..., :rotary_dim], theta=theta,
+                         pos_offset=pos_offset, seq_axis=seq_axis)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d, s = x.shape[-1], x.shape[seq_axis]
     along = [1] * x.ndim
     along[seq_axis], along[-1] = s, d

@@ -672,6 +672,7 @@ def as_on_tpu(monkeypatch):
     ((2, 4096, 2048), 16, False, True, 1.0),    # OLMoE: head 128, 8 x 8 tiles
     ((4, 1024, 256), 8, False, True, 1.0),      # head 32: four heads a program
     ((2, 32, 2048, 80), None, False, True, 1.0),    # GPT-2.7B: the 4-D walk
+    ((1, 16, 8192, 256), None, False, True, 1.0),   # Qwen3-Next: head 256
 ])
 def test_kernels_compile_for_v5e(v5e, as_on_tpu, shape, heads, with_mask,
                                  causal, keep):
