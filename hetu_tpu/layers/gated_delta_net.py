@@ -68,10 +68,10 @@ def _z(qkvz, **dims):
     return _split(qkvz, **dims)[1]
 
 
-def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep):
+def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
     import jax
     import jax.numpy as jnp
-    from ..ops.gated_delta import chunk_gated_delta_rule
+    from ..ops import gated_delta
     B, S, _ = mixed.shape
     f32 = jnp.float32
     kd = key_heads * dk
@@ -84,11 +84,34 @@ def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep):
     def unit(t):            # L2 norm over a head, then one copy a value head
         t = t.reshape(B, S, key_heads, dk).astype(f32)
         t = t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-        return jnp.repeat(t, rep, axis=2)
+        # the copies side by side along the lanes: [.., key heads, rep x dk]
+        # is the layout of the [B, S, value heads x dk] the rule's kernels
+        # read, where jnp.repeat's [.., key heads, rep, dk] is copied again
+        return jnp.concatenate([t] * rep, -1).reshape(B, S, key_heads * rep,
+                                                      dk)
     q = (unit(q) * dk ** -0.5).astype(mixed.dtype)
     k = unit(k).astype(mixed.dtype)
     v = v.reshape(B, S, key_heads * rep, dv)
-    return chunk_gated_delta_rule(q, k, v, g, beta)[0]
+    return (rule or gated_delta.chunk_gated_delta_rule)(q, k, v, g, beta)[0]
+
+
+class _ScanOp(_Scoped):
+    """The ``hetu_gdn_scan`` node.  A ``pallas_call`` does not partition
+    under GSPMD and ``chunk_gated_delta_rule`` cannot see a mesh, so under
+    one this node calls the rule's ``jax.numpy`` form itself, and says so
+    where there was a kernel to take (reason ``mesh``)."""
+
+    def _compute(self, input_vals, ctx):
+        import jax
+        from ..ops import gated_delta
+        from ..ops.pallas import dispatch
+        rule = None
+        if ctx.mesh is not None:
+            rule = gated_delta.chunk_gated_delta_rule_jnp
+            if dispatch.mosaic():
+                dispatch.record("gated_delta", "mesh")
+        with jax.named_scope(self.scope):
+            return self.fn(*input_vals, rule=rule, **self.attrs)
 
 
 def _out(o, z, w_norm, w_out, *, eps):
@@ -150,7 +173,7 @@ class GatedDeltaNet(BaseLayer):
         mixed = _Scoped(_mixed, "hetu_gdn_proj", qkvz, **self.dims)
         z = _Scoped(_z, "hetu_gdn_proj", qkvz, **self.dims)
         mixed = _Scoped(causal_conv, "hetu_gdn_conv", mixed, self.conv)
-        o = _Scoped(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
+        o = _ScanOp(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
                     self.dt_bias, **self.dims)
         return _Scoped(_out, "hetu_gdn_out", o, z, self.norm, self.out_proj,
                        eps=self.eps)

@@ -38,18 +38,37 @@ Precision.  The state, the decays, the solve and every product that reads
 or writes the state are f32 at the highest matmul precision whatever the
 compute type; the three products that stay inside a chunk (``K K^T``,
 ``Q K^T``, ``P U``) take their operands in the compute type and add in f32.
-The backward pass is JAX's own through the solve and the scan (the chunk
-states are its residuals: ``d_k x d_v`` f32 a chunk and head).
+The kernels hold to the same: an f32 operand of theirs enters the matrix
+unit as its three bf16 parts (six bf16 passes a product of two, what
+``HIGHEST`` is on a TPU), beside bf16 q, k, v as beside f32 ones.
+The backward pass of the ``jax.numpy`` form is JAX's own through the solve
+and the scan (the chunk states are its residuals: ``d_k x d_v`` f32 a chunk
+and head).
 
 Shapes: ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``g, beta [B, T, H]``
 (``H`` value heads; q and k already repeated for them, normalised and
 scaled by the caller); returns ``o [B, T, H, d_v]`` in ``v``'s type and
 the final state ``[B, H, d_k, d_v]`` f32.
 
-``chunk_gated_delta_rule`` is what the layer's ``hetu_gdn_scan`` node calls
-(``layers/gated_delta_net.py``) and what the benchmark's long-memory probe
-calls (``chipbench/builders/qwen3_next.py`` ``delta_rule_gap``): a kernel for
-the rule goes behind this function, so that both run it.
+What runs where.  ``chunk_gated_delta_rule`` is what the layer's
+``hetu_gdn_scan`` node calls (``layers/gated_delta_net.py``) and what the
+benchmark's long-memory probe calls (``chipbench/builders/qwen3_next.py``
+``delta_rule_gap``).  On a TPU it runs as two Pallas kernels,
+``hetu_gdn_fwd`` and ``hetu_gdn_bwd`` (``ops/pallas/gated_delta.py``, a
+``jax.custom_vjp``: one walk over chunk states in VMEM each way; the
+backward keeps the chunk-start states and rebuilds everything else), where
+it can read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk``
+64, q, k and v all bf16 or all f32; any ``T``, ``B`` and ``H``.  Each call
+counts its choice at trace time in ``hetu_kernel_choice_total{kernel=
+"gated_delta", impl, reason}``: ``pallas``, or ``jnp`` with
+``head_dim_not_128_aligned``, ``chunk!=64``, ``dtype:<name>`` or
+``dtype:mixed``.  A mesh is the one thing the function cannot see (a
+``pallas_call`` does not partition under GSPMD): the scan node reads it,
+calls ``chunk_gated_delta_rule_jnp`` itself and counts ``mesh``.  On any
+other platform there is no Mosaic and no choice: nothing is counted and
+``chunk_gated_delta_rule_jnp`` runs, bit for bit what this function was
+before it had kernels.  The kernels themselves run anywhere when called
+directly (interpret mode on the CPU): ``tests/test_gated_delta_kernel.py``.
 """
 
 from __future__ import annotations
@@ -87,7 +106,20 @@ def recurrent_gated_delta_rule(q, k, v, g, beta, state_dtype=jnp.float32):
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
-    """The chunked form; see the module's docstring."""
+    """The chunked form; see the module's docstring.  On a TPU the Pallas
+    kernel pair where its rule takes the operands, else (and on any other
+    platform, where there is no choice to record) the ``jax.numpy`` form."""
+    from .pallas import dispatch, gated_delta as kernels
+    if dispatch.mosaic() and dispatch.record(
+            "gated_delta", kernels.unsupported(q, k, v, chunk)):
+        return kernels.gated_delta_rule(q, k, v, g, beta)
+    return chunk_gated_delta_rule_jnp(q, k, v, g, beta, chunk)
+
+
+def chunk_gated_delta_rule_jnp(q, k, v, g, beta, chunk=CHUNK):
+    """The chunked form in ``jax.numpy``: what the kernels are held to, and
+    what runs wherever they do not (a scan node under a mesh calls it
+    itself, ``layers/gated_delta_net.py``)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     f32, ct = jnp.float32, v.dtype
