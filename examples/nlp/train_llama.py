@@ -18,6 +18,13 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            of a 16-way expert-parallel job: three Gated DeltaNet layers and
            one gated-attention layer, 32 of 512 experts held with the shared
            expert, an eighth of the vocabulary)
+       python examples/nlp/train_llama.py --model nemotron-3-nano-30b-a3b \
+           --layers 9 --experts-held 0:8 --vocab-rows 16384 \
+           --seq-len 8192 --batch-size 1     (Nemotron-3-Nano at one chip's
+           share of a 16-way expert-parallel job: the first nine blocks
+           MEMEM*EME, four Mamba-2 mixers, four expert layers with 8 of 128
+           relu2 experts held and the shared expert, one attention layer, an
+           eighth of the vocabulary)
 """
 
 import os
@@ -35,13 +42,16 @@ import hetu_tpu as ht
 from hetu_tpu.layers.moe import record_moe_load
 from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              Qwen3NextConfig, Qwen3NextForCausalLM,
-                             QWEN3_NEXT_CONFIGS, load_hf_llama_weights)
+                             QWEN3_NEXT_CONFIGS, NemotronHConfig,
+                             NemotronHForCausalLM, NEMOTRON_H_CONFIGS,
+                             load_hf_llama_weights)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="llama-7b",
-                    choices=list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS))
+                    choices=(list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS)
+                             + list(NEMOTRON_H_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -55,8 +65,8 @@ def main():
                          "it is a slice of the published vocabulary, ids, "
                          "logits and the loss are over the slice")
     ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
-                    help="qwen3-next: the experts of each layer this chip "
-                         "holds, of the router's full width")
+                    help="qwen3-next, nemotron: the experts of each layer "
+                         "this chip holds, of the router's full width")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--tp", type=int, default=1)
@@ -71,10 +81,18 @@ def main():
     family = ((QWEN3_NEXT_CONFIGS, Qwen3NextConfig, Qwen3NextForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in QWEN3_NEXT_CONFIGS else
+              (NEMOTRON_H_CONFIGS, NemotronHConfig, NemotronHForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in NEMOTRON_H_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
     base = dict(configs[args.model])
+    if args.layers and args.model in NEMOTRON_H_CONFIGS:
+        # a block is chosen by the pattern's character: fewer blocks are
+        # the pattern's first
+        from hetu_tpu.models.nemotron_h import PATTERN
+        base["hybrid_override_pattern"] = PATTERN[:args.layers]
     for field, val in ((depth, args.layers), ("hidden_size", args.hidden),
                        (width, args.intermediate),
                        ("vocab_size", args.vocab)):
@@ -102,8 +120,11 @@ def main():
         from hetu_tpu.parallel import MegatronLM
         kwargs.update(dist_strategy=MegatronLM(dp=args.dp, tp=args.tp))
     # an MoE model's per-expert load rides the loss's fetch: [2, E] a layer
+    # (and a sigmoid-scored router's selection bias, [E] a layer)
     loads = model.moe_loads() if c.num_experts and not args.pp else []
-    ex = ht.Executor({"train": [loss, opt.minimize(loss)] + loads},
+    biases = (model.router_biases()
+              if loads and hasattr(model, "router_biases") else [])
+    ex = ht.Executor({"train": [loss, opt.minimize(loss)] + loads + biases},
                      **kwargs)
 
     if args.hf_import:
@@ -118,8 +139,9 @@ def main():
         feed = {ids: tok[:, :-1], labels: tok[:, 1:]}
         out = ex.run("train", feed_dict=feed,
                      convert_to_numpy_ret_vals=True)
-        for i, load in enumerate(out[2:]):
-            record_moe_load(f"layer{i}", load)
+        for i, load in enumerate(out[2:2 + len(loads)]):
+            record_moe_load(f"layer{i}", load,
+                            bias=out[2 + len(loads) + i] if biases else None)
         if step % 5 == 0 or step == args.steps - 1:
             print(f"step {step:4d}  loss {out[0]:.4f}")
 

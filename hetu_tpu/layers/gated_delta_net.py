@@ -5,7 +5,8 @@ every four layers; HF ``Qwen3NextGatedDeltaNet``).
                      (r value heads a key head): q, k, v, z per key head
     ba   = x W_ba    viewed [.., key heads, r + r]: b, a per value head
     [q | k | v] flattened -> depthwise causal convolution (width 4, left
-                     padding, no bias) -> SiLU -> split back
+                     padding; this model's has no bias) -> SiLU -> split back
+                     (ops/causal_conv.py, shared with layers/mamba2.py)
     beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)      (f32)
     q, k L2-normalised over d_k, repeated for their value heads,
                      q scaled by d_k^-1/2
@@ -28,20 +29,7 @@ from .base import BaseLayer, fresh_name
 from .. import initializers as init
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
-
-
-def causal_conv(x, w):
-    """Depthwise causal convolution over the sequence: ``x [B, S, C]``,
-    ``w [K, C]``, ``y_t = sum_j w_j x_(t - K + 1 + j)`` with zeros before the
-    first position; then SiLU.  ``K`` shifted products: no im2col, no
-    transposition of the 8,192 channels."""
-    import jax
-    import jax.numpy as jnp
-    K, S = w.shape[0], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    y = sum(xp[:, j:j + S].astype(jnp.float32) * w[j].astype(jnp.float32)
-            for j in range(K))
-    return jax.nn.silu(y).astype(x.dtype)
+from ..ops.causal_conv import causal_conv
 
 
 def _split(qkvz, *, key_heads, dk, dv, rep):

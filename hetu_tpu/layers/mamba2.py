@@ -1,0 +1,144 @@
+"""Mamba-2 mixer: the state-space layer of Nemotron-H (the ``M`` blocks; HF
+``NemotronHMamba2Mixer``).  ``H`` heads of ``P`` channels (``d = H P``),
+``G`` groups, state size ``N``:
+
+    [z | xBC | dt] = x W_in      widths d, d + 2 G N, H; no bias
+    xBC = silu(causal_conv(xBC, w [K, d + 2 G N]) + b)     (ops/causal_conv.py)
+    xBC -> x [.., H, P], B [.., G, N], C [.., G, N]; head h reads group
+                                 h // (H / G)
+    dt = softplus(dt + dt_bias);  A = -exp(A_log)                      (f32)
+    S_t = exp(dt_t A) S_(t-1) + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+                                 (ops/ssd.py, chunks of ``chunk`` positions)
+    y = y silu(z);  y = y / rms(y over each group's d / G channels) * w
+    out = y W_out
+
+``dt`` is not clamped (the family's ``time_step_limit`` is ``(0, inf)``).
+
+Four graph nodes, each under a ``jax.named_scope`` that the device trace's
+readers find in the compiled step's ``op_name``: ``hetu_ssm_proj`` (the
+input projection and its three parts), ``hetu_ssm_conv``, ``hetu_ssm_scan``
+(the gates, the chunked scan and the skip) and ``hetu_ssm_out`` (the gate,
+the grouped norm and the output projection).  A decode step, and the state
+``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a serving
+cache, are not here (ROADMAP Queue 2).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .base import BaseLayer, fresh_name
+from .. import initializers as init
+from ..graph.node import VariableOp
+from ..ops.base import ScopedOp as _Scoped
+from ..ops.causal_conv import causal_conv
+
+
+def _project(x, w):
+    return x @ w
+
+
+def _part(zxbcdt, *, lo, hi):
+    return zxbcdt[..., lo:hi]
+
+
+def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
+          state, chunk):
+    import jax
+    import jax.numpy as jnp
+    from ..ops import ssd
+    B, S, _ = xbc.shape
+    f32 = jnp.float32
+    d, gn = heads * head_dim, groups * state
+    x = xbc[..., :d].reshape(B, S, heads, head_dim)
+    Bm = xbc[..., d:d + gn].reshape(B, S, groups, state)
+    Cm = xbc[..., d + gn:].reshape(B, S, groups, state)
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    y, _ = ssd.chunk_ssd(x, dt, -jnp.exp(a_log.astype(f32)), Bm, Cm,
+                         chunk=chunk)
+    y = y.astype(f32) + d_skip.astype(f32)[:, None] * x.astype(f32)
+    return y.astype(xbc.dtype).reshape(B, S, d)
+
+
+def _out(y, z, w_norm, w_out, *, groups, eps):
+    """``y silu(z)``, RMSNorm over each of ``groups`` runs of channels scaled
+    by ``w_norm`` (HF ``MambaRMSNormGated``, the gate before the norm), then
+    the output projection."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    g = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    g = g.reshape(g.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    g = g.reshape(y.shape) * w_norm.astype(f32)
+    return g.astype(y.dtype) @ w_out
+
+
+def _a_log(key, shape, dtype=np.float32):
+    """``log U(1, 16)``: the family's initial ``A_log``."""
+    import jax
+    import jax.numpy as jnp
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+def _dt_bias(dt_min, dt_max, floor):
+    """The inverse softplus of a log-uniform draw in ``[dt_min, dt_max]``
+    floored at ``floor``: the softplus of the initial bias is the step."""
+    def draw(key, shape, dtype=np.float32):
+        import jax
+        import jax.numpy as jnp
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
+        dt = jnp.maximum(dt, floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return draw
+
+
+class Mamba2(BaseLayer):
+    """``out_scale`` divides the initial output projection (the family's
+    ``rescale_prenorm_residual``: by the square root of the model's depth)."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, n_groups, state_size,
+                 conv_kernel=4, chunk=128, eps=1e-5, dt_min=1e-3, dt_max=0.1,
+                 dt_floor=1e-4, out_scale=1.0, name=None):
+        name = fresh_name(name or "mamba2")
+        assert num_heads % n_groups == 0, (num_heads, n_groups)
+        d = num_heads * head_dim
+        conv_dim = d + 2 * n_groups * state_size
+        self.dims = dict(heads=num_heads, head_dim=head_dim, groups=n_groups,
+                         state=state_size, chunk=chunk)
+        self.parts = {"z": (0, d), "xbc": (d, d + conv_dim),
+                      "dt": (d + conv_dim, d + conv_dim + num_heads)}
+        self.groups, self.eps = n_groups, eps
+        self.in_proj = VariableOp(
+            f"{name}_in_weight", (hidden_size, d + conv_dim + num_heads),
+            init.xavier_normal())
+        # torch's Conv1d default: uniform within 1 / sqrt(fan_in = kernel)
+        bound = 1.0 / np.sqrt(conv_kernel)
+        self.conv = VariableOp(f"{name}_conv_weight", (conv_kernel, conv_dim),
+                               init.uniform(-bound, bound))
+        self.conv_bias = VariableOp(f"{name}_conv_bias", (conv_dim,),
+                                    init.uniform(-bound, bound))
+        self.dt_bias = VariableOp(f"{name}_dt_bias", (num_heads,),
+                                  _dt_bias(dt_min, dt_max, dt_floor))
+        self.a_log = VariableOp(f"{name}_a_log", (num_heads,), _a_log)
+        self.d_skip = VariableOp(f"{name}_d", (num_heads,), init.ones())
+        self.norm = VariableOp(f"{name}_norm_scale", (d,), init.ones())
+        bound = out_scale / np.sqrt(d)      # kaiming_uniform(a=sqrt(5))
+        self.out_proj = VariableOp(f"{name}_out_weight", (d, hidden_size),
+                                   init.uniform(-bound, bound))
+
+    def __call__(self, x):
+        # one node for the projection: its backward pass is then one product
+        # for the weight, whatever reads the parts
+        zxbcdt = _Scoped(_project, "hetu_ssm_proj", x, self.in_proj)
+        z, xbc, dt = (_Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
+                      for lo, hi in (self.parts[n] for n in
+                                     ("z", "xbc", "dt")))
+        xbc = _Scoped(causal_conv, "hetu_ssm_conv", xbc, self.conv,
+                      self.conv_bias)
+        y = _Scoped(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
+                    self.a_log, self.d_skip, **self.dims)
+        return _Scoped(_out, "hetu_ssm_out", y, z, self.norm, self.out_proj,
+                       groups=self.groups, eps=self.eps)

@@ -42,13 +42,27 @@ class TopKGate(BaseLayer):
     is the GShard top-1/top-2 case).  ``renorm`` rescales a token's kept
     gates to sum to 1 (GShard top-2, Mixtral); False uses the softmax
     weights as they are (OLMoE).  Routing hyper-parameters (k, capacity)
-    live on the MoELayer, the single source of truth."""
+    live on the MoELayer, the single source of truth.
 
-    def __init__(self, hidden_size, num_experts, renorm=True, name=None):
+    ``score="sigmoid"`` (dropless path only) is the router of DeepSeek-V3
+    and Nemotron-H (``ops/moe.py top_k_route``): sigmoid scores, the experts
+    chosen by ``score + bias``, the gates the chosen scores times ``scale``.
+    ``bias`` is a ``[E]`` variable that is no weight: it has no gradient and
+    no optimizer state, and the layer's op moves it inside the training step
+    by ``bias_rate * sign(mean(load) - load)`` from the step's pair counts
+    over all ``E`` experts (DeepSeek-V3's auxiliary-loss-free balancing);
+    ``bias_rate=None`` leaves it where it is."""
+
+    def __init__(self, hidden_size, num_experts, renorm=True, name=None,
+                 score="softmax", scale=None, bias_rate=None):
         name = fresh_name(name or "gate")
         self.renorm = renorm
+        self.score, self.scale, self.bias_rate = score, scale, bias_rate
         self.wg = VariableOp(f"{name}_w", (hidden_size, num_experts),
                              init.xavier_uniform())
+        self.bias = VariableOp(f"{name}_bias", (num_experts,), init.zeros(),
+                               trainable=False) \
+            if score == "sigmoid" else None
 
     def gating(self, tokens, wg, ids, k, capacity):
         return top_k_gating(tokens @ wg, k, capacity,
@@ -58,7 +72,7 @@ class TopKGate(BaseLayer):
         return top_k_gating_choices(tokens @ wg, k, capacity,
                                     second_renorm=self.renorm)
 
-    def route(self, tokens, wg, k):
+    def route(self, tokens, wg, k, bias=None):
         """Dropless routing: ``(logits, idx, gate, probs)``, the logits and
         everything after them in f32 at full matmul precision, so that which
         experts a token takes does not depend on the compute type."""
@@ -68,7 +82,9 @@ class TopKGate(BaseLayer):
         logits = jnp.matmul(tokens.astype(jnp.float32),
                             wg.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        return (logits,) + top_k_route(logits, k, renorm=self.renorm)
+        return (logits,) + top_k_route(logits, k, renorm=self.renorm,
+                                       score=self.score, bias=bias,
+                                       scale=self.scale)
 
     def aux(self, tokens, wg, ids, k):
         return top_k_balance_aux(tokens @ wg)
@@ -169,6 +185,10 @@ class _MoEOp(Op):
             inputs.append(gate.wg)
         if ids is not None:
             inputs.append(ids)
+        self.bias_var = getattr(gate, "bias", None)
+        self._bias_at = len(inputs)
+        if self.bias_var is not None:
+            inputs.append(self.bias_var)
         if load_var is not None:
             # last input, read by nobody: it puts the variable into every
             # program that runs this op, so its update has a state to go to
@@ -187,9 +207,14 @@ class _MoEOp(Op):
         assert held is None or capacity_factor is None, (
             "a share of the experts (held=) is laid out by the dropless path")
         if capacity_factor is None:
-            assert w3 is not None and hasattr(gate, "route"), (
-                "dropless routing (capacity_factor=None) runs swiglu "
-                "experts behind a TopKGate")
+            assert b1 is None and hasattr(gate, "route"), (
+                "dropless routing (capacity_factor=None) runs experts "
+                "without biases (swiglu, relu2) behind a TopKGate")
+        else:
+            assert self.bias_var is None and (w3 is not None
+                                              or b1 is not None), (
+                "a sigmoid-scored router and relu2 experts are laid out by "
+                "the dropless path (capacity_factor=None)")
 
     @property
     def dropless(self):
@@ -209,27 +234,58 @@ class _MoEOp(Op):
         ids = rest.pop(0) if self.has_ids else None
         return x, w1, b1, w2, b2, w3, wg, ids
 
+    def _bias(self, input_vals, ctx):
+        """The router's selection bias in f32: under a lower compute type
+        the f32 master, as an optimizer reads a weight (a bias of 0.5 moved
+        by 0.001 in bf16 would not move)."""
+        if self.bias_var is None:
+            return None
+        if ctx.master_params is not None:
+            return ctx.master_params[self.bias_var.name]
+        return input_vals[self._bias_at]
+
     def _capacity(self, T):
         return max(int(np.ceil(self.capacity_factor * T * self.k
                                / self.num_experts)), 1)
 
-    def routing(self, x, wg, ctx):
-        """The dropless routing of ``x``, traced once per trace: the loss
-        terms (``MoEAuxLossOp``, ``MoEZLossOp``) read what the layer itself
-        routed by.  Keyed by the identity of ``x``, so a node evaluated in
-        another trace (a remat body, a second program) routes afresh."""
+    def routing(self, input_vals, ctx):
+        """The dropless routing of this op's tokens, traced once per trace:
+        the loss terms (``MoEAuxLossOp``, ``MoEZLossOp``) read what the layer
+        itself routed by.  Keyed by the identity of ``x``, so a node
+        evaluated in another trace (a remat body, a second program) routes
+        afresh."""
         import jax
+        x, wg = input_vals[0], self._unpack(input_vals)[6]
         memo = ctx.__dict__.setdefault("_moe_routing", {})
         if self.id not in memo or memo[self.id][0] is not x:
+            extra = (() if self.bias_var is None
+                     else (self._bias(input_vals, ctx),))
             with jax.named_scope("hetu_moe_route"):
                 memo[self.id] = (x, self.gate.route(
-                    x.reshape(-1, x.shape[-1]), wg, self.k))
+                    x.reshape(-1, x.shape[-1]), wg, self.k, *extra))
         return memo[self.id][1]
 
+    def _move_bias(self, input_vals, idx, ctx):
+        """``bias += rate * sign(mean(load) - load)`` from this step's pair
+        counts over all experts, as a state update of the training step."""
+        import jax
+        import jax.numpy as jnp
+        from ..ops.moe import expert_load
+        if (self.bias_var is None or not self.gate.bias_rate
+                or not ctx.training):
+            return
+        rate = self.gate.bias_rate
+        with jax.named_scope("hetu_moe_route"):
+            load = expert_load(idx, self.num_experts).astype(jnp.float32)
+            bias = self._bias(input_vals, ctx).astype(jnp.float32)
+            ctx.record_update(self.bias_var, bias + rate * jnp.sign(
+                jnp.mean(load) - load))
+
     def _record_load(self, ctx, *rows):
-        """Hand the per-expert pair counts of this step (routed, kept; with
-        ``held`` a third row whose first entry is the pairs routed to experts
-        held elsewhere) to the executor's state (``MoELayer.load()`` fetches
+        """Hand the per-expert pair counts of this step (routed, computed;
+        with ``held`` a third row whose first entry is the pairs routed to
+        experts held elsewhere, and a fourth of the pairs computed by a pass
+        after the first) to the executor's state (``MoELayer.load()`` fetches
         them beside the loss)."""
         import jax.numpy as jnp
         if self.load_var is not None:
@@ -247,20 +303,26 @@ class _MoEOp(Op):
         tokens = x.reshape(-1, h)
         T = tokens.shape[0]
         if self.dropless:
-            _, idx, gate, _ = self.routing(x, wg, ctx)
+            _, idx, gate, _ = self.routing(input_vals, ctx)
+            self._move_bias(input_vals, idx, ctx)
+            # an expert that is not gated (relu2) has no w3: w1 is its up
+            # projection
+            w_gate, w_up = (w1, w3) if self.has_w3 else (None, w1)
             if self.held is None:
-                y, load = dropless_moe(tokens, idx, gate, w1, w3, w2,
+                y, load = dropless_moe(tokens, idx, gate, w_gate, w_up, w2,
                                        mesh=ctx.mesh)
                 self._record_load(ctx, load, load)
                 return y.reshape(orig_shape)
             from ..ops.moe import held_rows
             count = self.held[1]
             y, lay = dropless_moe(
-                tokens, idx, gate, w1, w3, w2, mesh=ctx.mesh, held=self.held,
+                tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
+                held=self.held,
                 rows=held_rows(T * self.k, self.num_experts, count))
             self._record_load(
-                ctx, lay["load"], lay["kept"],
-                jnp.zeros((count,), jnp.int32).at[0].set(lay["elsewhere"]))
+                ctx, lay["load"], lay["computed"],
+                jnp.zeros((count,), jnp.int32).at[0].set(lay["elsewhere"]),
+                lay["computed"] - lay["kept"])
             return y.reshape(orig_shape)
         C = self._capacity(T)
 
@@ -340,9 +402,12 @@ class MoEAuxLossOp(Op):
             # balance loss over top-k counts, from the routing the layer
             # itself ran by (ops/moe.py load_balancing_loss)
             from ..ops.moe import load_balancing_loss, expert_load
-            _, idx, _, probs = self.moe.routing(x, wg, ctx)
-            return load_balancing_loss(
-                probs, expert_load(idx, self.moe.num_experts))
+            _, idx, _, probs = self.moe.routing(input_vals, ctx)
+            load = expert_load(idx, self.moe.num_experts)
+            if getattr(self.moe.gate, "score", "softmax") == "sigmoid":
+                # the share of the pairs, DeepSeek-V3's f_i
+                return load_balancing_loss(probs, load, pairs=idx.size)
+            return load_balancing_loss(probs, load)
         if not getattr(self.moe.gate, "has_aux", True):
             # hash/balance gates have identically-zero aux: skip the
             # dispatch recompute entirely
@@ -372,8 +437,7 @@ class MoEZLossOp(Op):
 
     def _compute(self, input_vals, ctx):
         from ..ops.moe import router_z_loss
-        x, _, _, _, _, _, wg, _ = self.moe._unpack(input_vals)
-        return router_z_loss(self.moe.routing(x, wg, ctx)[0])
+        return router_z_loss(self.moe.routing(input_vals, ctx)[0])
 
 
 class MoEChosenOp(Op):
@@ -386,24 +450,33 @@ class MoEChosenOp(Op):
         self.moe = moe_op
 
     def _compute(self, input_vals, ctx):
-        x, _, _, _, _, _, wg, _ = self.moe._unpack(input_vals)
-        return self.moe.routing(x, wg, ctx)[1]
+        return self.moe.routing(input_vals, ctx)[1]
 
 
-def _shared_expert(x, w_gate, w_up, w_down, w_sg):
+def _shared_expert(x, *w, act="swiglu", gated=True):
     import jax
-    y = (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
-    return y * jax.nn.sigmoid(x @ w_sg)
+    import jax.numpy as jnp
+    w = list(w)
+    w_sg = w.pop() if gated else None
+    if act == "swiglu":
+        w_gate, w_up, w_down = w
+        y = (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+    else:
+        w_up, w_down = w
+        y = jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+    return y if w_sg is None else y * jax.nn.sigmoid(x @ w_sg)
 
 
-def shared_expert_op(x, w_gate, w_up, w_down, w_sg):
-    """The shared expert of a sparse block (Qwen2-MoE, Qwen3-Next): a SwiGLU
-    FFN every token goes through, scaled token by token by the sigmoid of a
-    one-column gate: ``sigmoid(x w_sg) W_d (silu(W_g x) * W_u x)``.  Its
-    device operations carry the scope ``hetu_moe_shared``."""
+def shared_expert_op(x, *w, act="swiglu", gated=True):
+    """The shared expert of a sparse block, an FFN every token goes through.
+    Qwen2-MoE and Qwen3-Next: SwiGLU, scaled token by token by the sigmoid
+    of a one-column gate, ``sigmoid(x w_sg) W_d (silu(W_g x) * W_u x)``
+    (``w`` = gate, up, down, sigmoid).  Nemotron-H: ``act="relu2"``,
+    ``gated=False``: ``W_d relu(W_u x)^2`` (``w`` = up, down).  Its device
+    operations carry the scope ``hetu_moe_shared``."""
     from ..ops.base import ScopedOp
-    return ScopedOp(_shared_expert, "hetu_moe_shared", x, w_gate, w_up,
-                    w_down, w_sg)
+    return ScopedOp(_shared_expert, "hetu_moe_shared", x, *w, act=act,
+                    gated=gated)
 
 
 class MoELoadOp(Op):
@@ -415,14 +488,19 @@ class MoELoadOp(Op):
     so fetching it beside the loss adds 512 bytes to the step's one
     device-to-host copy and no second pass.  In a program where the MoE op
     has not run by the time this node is evaluated it gives the state's
-    value, the last step's counts."""
+    value, the last step's counts.  ``MoELayer.router_bias()`` reads the
+    router's selection bias, as this step moved it, the same way."""
 
     def __init__(self, load_var):
         super().__init__(load_var, name=f"{load_var.name}_read")
         self.var = load_var
 
     def _compute(self, input_vals, ctx):
-        return ctx.updates.get(self.var, input_vals[0])
+        if self.var in ctx.updates:
+            return ctx.updates[self.var]
+        if ctx.master_params is not None:   # not the compute type's rounding
+            return ctx.master_params[self.var.name]
+        return input_vals[0]
 
 
 class MoELayer(BaseLayer):
@@ -431,7 +509,14 @@ class MoELayer(BaseLayer):
     ``capacity_factor=None`` is the dropless path (ops/moe.py
     ``dropless_moe``): every (token, choice) pair is computed, by grouped
     products over the pairs sorted by expert; it needs the ``top`` gate and
-    swiglu experts.  ``renorm_topk`` is the gate's ``renorm``.
+    experts without biases: ``expert_act="swiglu"`` (``silu(x W1) * (x W3)``
+    then ``W2``, three grouped products a pass) or ``"relu2"`` (``relu(x
+    W1)^2`` then ``W2``, two; no ``w3``).  ``"gelu"`` experts have biases and
+    run behind a capacity; ``"relu2"`` runs on the dropless path alone.
+    ``renorm_topk`` is the gate's ``renorm``; ``router_score="sigmoid"``,
+    ``router_scale`` and ``router_bias_rate`` are the gate's ``score``,
+    ``scale`` and ``bias_rate`` (``TopKGate``; dropless path alone), and
+    ``router_bias()`` fetches the bias as ``load()`` fetches the load.
     ``track_load`` adds a ``[2, E]`` state variable of per-expert pair
     counts (routed, kept) that ``load()`` fetches.
 
@@ -440,17 +525,23 @@ class MoELayer(BaseLayer):
     outputs and ``k`` a token, the layer holds the weights of the experts
     ``first .. first + count - 1`` alone and computes only the pairs routed to
     them (dropless path); what the absent experts would add is left out and
-    nothing stands in for it.  The rows laid out are bounded by twice the
-    mean share (``ops/moe.py held_rows``), pairs over the bound are counted
-    as dropped; the load is ``[3, count]`` then (routed here, kept, and in
-    ``[2, 0]`` the pairs routed elsewhere).  ``shared_width`` adds a shared expert of that width, gated by
-    a sigmoid (``shared_expert_op``), computed for every token."""
+    nothing stands in for it.  One pass lays out rows for twice the mean
+    share (``ops/moe.py held_rows``); pairs a batch routes here over that
+    bound are computed by further passes (``dropless_moe``), none is dropped.
+    The load is ``[4, count]`` then: routed here, computed (the same),
+    in ``[2, 0]`` the pairs routed elsewhere, and the pairs that took a pass
+    after the first.
+    ``shared_width`` adds a shared expert of that width and of the experts'
+    kind (swiglu or relu2), computed for every token, and ``shared_gate``
+    says whether the sigmoid of a one-column gate scales it
+    (``shared_expert_op``)."""
 
     def __init__(self, hidden_size, intermediate_size, num_experts, k=2,
                  capacity_factor=1.25, gate="top", ep_axis=None,
                  num_groups=None, sparse=True, expert_act="gelu",
                  renorm_topk=True, track_load=False, held=None,
-                 shared_width=None, name=None):
+                 shared_width=None, shared_gate=True, router_score="softmax",
+                 router_scale=None, router_bias_rate=None, name=None):
         name = fresh_name(name or "moe")
         self.held = held
         n_held = num_experts
@@ -462,7 +553,9 @@ class MoELayer(BaseLayer):
             self.gate = gate                      # caller-built gate
         elif gate == "top":
             self.gate = TopKGate(hidden_size, num_experts,
-                                 renorm=renorm_topk, name=name)
+                                 renorm=renorm_topk, name=name,
+                                 score=router_score, scale=router_scale,
+                                 bias_rate=router_bias_rate)
         elif gate == "hash":
             self.gate = HashGate(num_experts)
         elif gate == "ktop1":
@@ -474,7 +567,13 @@ class MoELayer(BaseLayer):
             self.gate = BalanceGate(hidden_size, num_experts, name=name)
         else:
             raise ValueError(gate)
-        assert expert_act in ("gelu", "swiglu")
+        assert expert_act in ("gelu", "swiglu", "relu2"), expert_act
+        assert router_score == "softmax" or gate == "top", (
+            "the sigmoid-scored router is the top gate's")
+        assert capacity_factor is None or (
+            expert_act != "relu2" and router_score == "softmax"), (
+            "relu2 experts and the sigmoid-scored router run on the "
+            "dropless path (capacity_factor=None)")
         self.expert_act = expert_act
         self.w1 = VariableOp(f"{name}_w1",
                              (n_held, hidden_size, intermediate_size),
@@ -495,13 +594,18 @@ class MoELayer(BaseLayer):
                              init.xavier_uniform()) \
             if expert_act == "swiglu" else None
         self.shared = None
+        self.shared_kind = dict(act=expert_act, gated=bool(shared_gate))
         if shared_width:
+            assert expert_act in ("swiglu", "relu2"), expert_act
+            parts = ([("gate", (hidden_size, shared_width))]
+                     if expert_act == "swiglu" else [])
+            parts += [("up", (hidden_size, shared_width)),
+                      ("out", (shared_width, hidden_size))]
+            if shared_gate:
+                parts.append(("sigmoid", (hidden_size, 1)))
             self.shared = tuple(
                 VariableOp(f"{name}_shared_{n}", shape, init.xavier_uniform())
-                for n, shape in (("gate", (hidden_size, shared_width)),
-                                 ("up", (hidden_size, shared_width)),
-                                 ("out", (shared_width, hidden_size)),
-                                 ("sigmoid", (hidden_size, 1))))
+                for n, shape in parts)
         self.num_experts = num_experts
         self.capacity_factor = capacity_factor
         self.k = k
@@ -512,7 +616,7 @@ class MoELayer(BaseLayer):
         self.sparse = sparse
         self.load_var = VariableOp(
             f"{name}_load",
-            (2, num_experts) if held is None else (3, n_held), init.zeros(),
+            (2, num_experts) if held is None else (4, n_held), init.zeros(),
             trainable=False) if track_load else None
         if ep_axis is not None:
             ep_vars = [v for v in (self.w1, self.b1, self.w2, self.b2,
@@ -533,7 +637,8 @@ class MoELayer(BaseLayer):
                               sparse=self.sparse, w3=self.w3,
                               load_var=self.load_var, held=self.held)
         if self.shared is not None:
-            return self.last_op + shared_expert_op(x, *self.shared)
+            return self.last_op + shared_expert_op(x, *self.shared,
+                                                   **self.shared_kind)
         return self.last_op
 
     def aux_loss(self):
@@ -552,11 +657,20 @@ class MoELayer(BaseLayer):
         assert self.load_var is not None, "MoELayer(track_load=True)"
         return MoELoadOp(self.load_var)
 
+    def router_bias(self):
+        """``[E]`` f32: the router's selection bias after this step's move
+        (``router_score="sigmoid"``)."""
+        assert getattr(self.gate, "bias", None) is not None
+        return MoELoadOp(self.gate.bias)
 
-def record_moe_load(layer, load):
+
+def record_moe_load(layer, load, bias=None):
     """Count one step's per-expert load of MoE layer ``layer`` (a label) in
     the telemetry registry.  ``load`` is the fetched value of
     ``MoELayer.load()``, ``[2, E]``: pairs routed and pairs computed.
+    ``bias``, the fetched value of ``MoELayer.router_bias()``, sets
+    ``hetu_moe_router_bias_max_abs{layer}``: how far the sigmoid-scored
+    router's selection bias has moved from zero, over all experts.
 
     * ``hetu_moe_pairs_routed_total{layer}``: (token, choice) pairs routed;
     * ``hetu_moe_pairs_dropped_total{layer}``: those of them no expert
@@ -565,20 +679,31 @@ def record_moe_load(layer, load):
       pairs over the mean, this step (1.0 is perfectly even).
 
     From a layer that holds a share of its experts (``MoELayer(held=)``,
-    ``[3, count]``) ``routed``, ``dropped`` and the gauge are over the held
-    experts, and ``hetu_moe_pairs_elsewhere_total{layer}`` counts the pairs
-    the router sent to experts this device does not hold.
+    ``[4, count]``) ``routed``, ``dropped`` and the gauge are over the held
+    experts, ``hetu_moe_pairs_elsewhere_total{layer}`` counts the pairs the
+    router sent to experts this device does not hold, and
+    ``hetu_moe_pairs_over_bound_total{layer}`` those of the held experts'
+    pairs that one pass's rows did not hold and a further pass computed.
 
     The registry counts nothing while telemetry is disabled."""
     from .. import telemetry
     reg = telemetry.get_registry()
+    if bias is not None:
+        reg.gauge("hetu_moe_router_bias_max_abs",
+                  "Largest |selection bias| of the router, last step",
+                  labels=("layer",)).labels(layer=layer).set(
+                      float(np.abs(np.asarray(bias, np.float64)).max()))
     load = np.asarray(load, np.float64)
     routed, kept = load[:2]
     total = routed.sum()
-    if len(load) == 3 and total + load[2, 0] > 0:
+    if len(load) > 2 and total + load[2, 0] > 0:
         reg.counter("hetu_moe_pairs_elsewhere_total",
                     "Routed pairs whose expert another device holds",
                     labels=("layer",)).labels(layer=layer).inc(load[2, 0])
+        reg.counter("hetu_moe_pairs_over_bound_total",
+                    "Pairs on held experts computed by a pass after the first",
+                    labels=("layer",)).labels(layer=layer).inc(
+                        load[3].sum() if len(load) > 3 else 0)
     if total <= 0:          # the state's initial zeros: no step has run
         return
     reg.counter("hetu_moe_pairs_routed_total",

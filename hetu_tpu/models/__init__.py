@@ -10,6 +10,8 @@ from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM,
                     BaichuanForCausalLM, LLAMA_CONFIGS)
 from .qwen3_next import (Qwen3NextConfig, Qwen3NextModel,
                          Qwen3NextForCausalLM, QWEN3_NEXT_CONFIGS)
+from .nemotron_h import (NemotronHConfig, NemotronHModel,
+                         NemotronHForCausalLM, NEMOTRON_H_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,

@@ -260,8 +260,8 @@ class LlamaForCausalLM:
         ce = softmax_cross_entropy_sparse_op(logits, flat, ignored_index=-1)
         terms = {"ce": MaskedMeanOp(ce, flat)}
         loss = terms["ce"]
-        if c.num_experts:
-            mlps = [layer.mlp for layer in self.model.layers]
+        mlps = self.moe_layers() if c.num_experts else []
+        if mlps:
             terms["lbl"] = reduce(add, [m.aux_loss() for m in mlps])
             loss = loss + c.moe_aux_coeff * terms["lbl"]
             if c.moe_z_coeff:
@@ -269,10 +269,15 @@ class LlamaForCausalLM:
                 loss = loss + c.moe_z_coeff * terms["z"]
         return loss, terms
 
+    def moe_layers(self):
+        """The model's sparse expert layers, in order (a family whose blocks
+        are not all mixer-then-FFN pairs overrides it)."""
+        return [layer.mlp for layer in self.model.layers]
+
     def moe_loads(self):
         """One ``[2, E]`` node a layer of (pairs routed, pairs computed) by
         expert, to fetch beside the loss (layers/moe.py ``MoELoadOp``)."""
-        return [layer.mlp.load() for layer in self.model.layers]
+        return [m.load() for m in self.moe_layers()]
 
 
 def BaichuanForCausalLM(config, name="baichuan", pipeline_stages=None):

@@ -372,21 +372,38 @@ def sam_group_sum(x, group_idx, num_groups):
 # rows of a capacity buffer (at the no-drop capacity factor E/k that is E/k
 # times the expert work).
 
-def top_k_route(logits, k, renorm=False):
+def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
+                scale=None):
     """``(idx [T, k] int32, gate [T, k] f32, probs [T, E] f32)``: the ``k``
     largest softmax probabilities of each token, largest first, ties to the
     lower expert index; ``renorm`` rescales them to sum to 1 (Mixtral), the
     default uses them as they are (OLMoE's ``norm_topk_prob: false``).
     Everything in f32 whatever the logits' type, so that routing does not
-    depend on the compute type."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    _, idx = jax.lax.top_k(probs, k)
+    depend on the compute type.
+
+    ``score="sigmoid"`` is the router of DeepSeek-V3 (arXiv:2412.19437) and
+    Nemotron-H: the scores are ``s = sigmoid(logits)``, the ``k`` experts are
+    the largest of ``s + bias`` (``bias [E]``: a selection bias that balances
+    the load and is no part of the weights), the gates are the chosen ``s``
+    themselves (renormalised where ``renorm``) times ``scale``, and ``probs``
+    is ``s / sum_j s_j``, what the balance loss averages."""
+    assert score in ("softmax", "sigmoid"), score
+    assert bias is None or score == "sigmoid", "the bias selects by sigmoid"
+    if score == "softmax":
+        scores = probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    _, idx = jax.lax.top_k(scores if bias is None
+                           else scores + bias.astype(jnp.float32), k)
     # the gates by a one-hot product, not top_k's values: its backward
     # pass is then a product too and not a scatter-add into [T, E]
-    gate = jnp.sum(jax.nn.one_hot(idx, probs.shape[-1], dtype=probs.dtype)
-                   * probs[:, None, :], axis=-1)
+    gate = jnp.sum(jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)
+                   * scores[:, None, :], axis=-1)
     if renorm:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if scale is not None:
+        gate = gate * scale
     return idx.astype(jnp.int32), gate, probs
 
 
@@ -396,13 +413,15 @@ def expert_load(idx, num_experts):
                                   dtype=jnp.int32), axis=0)
 
 
-def load_balancing_loss(probs, load):
+def load_balancing_loss(probs, load, pairs=None):
     """``E * sum_i (n_i / T) * mean_t p_t,i`` with ``n_i`` the (token,
     choice) pairs at expert ``i``: the form of HF
     ``load_balancing_loss_func`` (Switch eq. 4 over top-k counts).  The
-    counts carry no gradient."""
+    counts carry no gradient.  ``pairs`` (``T k``) divides the counts in
+    place of ``T``: ``n_i / (T k)`` is the share of the pairs routed to
+    ``i`` (DeepSeek-V3's ``f_i``, eq. 18, which is ``k`` times smaller)."""
     T, E = probs.shape
-    frac = jax.lax.stop_gradient(load.astype(jnp.float32)) / T
+    frac = jax.lax.stop_gradient(load.astype(jnp.float32)) / (pairs or T)
     return E * jnp.sum(frac * jnp.mean(probs, axis=0))
 
 
@@ -413,16 +432,24 @@ def router_z_loss(logits):
 
 
 def held_rows(pairs, num_experts, count):
-    """The static bound on the rows of pairs that land on ``count`` held
-    experts of ``num_experts``: twice the mean share of ``pairs`` (token,
-    choice) pairs.  Which pairs land on the held experts changes from batch
-    to batch, the buffer's size cannot.  Twice, from a v5e (PR 31): with 32
+    """The static bound on the rows ONE PASS lays out of the pairs that land
+    on ``count`` held experts of ``num_experts``: twice the mean share of
+    ``pairs`` (token, choice) pairs.  Which pairs land on the held experts
+    changes from batch to batch, the buffer's size cannot; what a batch
+    routes here beyond the bound is computed by further passes over the same
+    buffer (``dropless_moe``), so the bound decides how often a second pass
+    runs and never whether a pair is computed.  Twice, from a v5e: with 32
     of 512 experts held the fullest layer and step used 0.74-0.80 of it in
-    14 runs, and rows for all ``T k`` pairs cost 11% of the step and 1.6 GiB."""
+    14 runs, and rows for all ``T k`` pairs cost 11% of the step and 1.6 GiB
+    (PR 31); with 8 of 128 the loads average out less (at initial weights 1
+    share in 10 took over 1.47 times the mean, 1 in 100 over 2.1) and a
+    router that learns sent up to 4.4 times the mean for some tens of steps,
+    where four times the mean in one pass cost 19 ms of every step (PR 33)."""
     return min(pairs, -(-2 * pairs * count // num_experts))
 
 
-def grouped_layout(idx, num_experts, tile=None, held=None, rows=None):
+def grouped_layout(idx, num_experts, tile=None, held=None, rows=None,
+                   offset=0):
     """Where each (token, choice) pair sits once the pairs are sorted by
     expert.  Pair ``p = t * k + c``.  Returns a dict:
 
@@ -442,33 +469,72 @@ def grouped_layout(idx, num_experts, tile=None, held=None, rows=None):
 
     ``held=(first, count)``: this device holds the experts ``first ..
     first + count - 1`` of ``num_experts`` and lays out only the pairs routed
-    to them; ``load`` is ``[count]``.  The rows are bounded by ``rows`` pairs
-    (``held_rows``; plus the tile padding), whatever the batch routes here:
-    a pair beyond the bound gets no row.  ``kept`` ``[count]`` are the pairs
-    at each held expert that did get one, ``elsewhere`` the pairs routed to
-    experts held by other devices; a pair without a row has
-    ``slot_of_pair == M``, past the end."""
+    to them; ``load`` is ``[count]``.  The layout is a WINDOW of ``M`` rows
+    (``rows`` pairs, ``held_rows``, plus the tile padding) onto the rows all
+    of those pairs would take, ``total`` of them, starting at row ``offset``
+    (a multiple of ``M``; may be traced): a pair outside the window has
+    ``slot_of_pair == M``, past the end, and windows at ``0, M, 2 M, ..``
+    below ``total`` hold every pair once.  ``kept`` ``[count]`` are the pairs
+    of each held expert inside the window, ``elsewhere`` the pairs routed to
+    experts held by other devices, ``has_tile`` ``[count]`` (with ``tile``)
+    the experts that own a row tile of the window.  (``sorted_pairs`` then
+    ``layout_window``: the sorts are the same for every window.)"""
+    return layout_window(sorted_pairs(idx, num_experts, held), tile,
+                         held=held, rows=rows, offset=offset)
+
+
+def sorted_pairs(idx, num_experts, held=None):
+    """The pairs of ``idx [T, k]`` sorted by expert, what every window of a
+    layout starts from: ``flat`` (the expert of pair ``p``; with ``held`` the
+    index among the held experts, or their count for an expert held
+    elsewhere), ``order`` (the pairs in expert order), ``rank`` (the position
+    of pair ``p`` in it), ``load`` and ``start`` (each expert's pairs and
+    where they begin)."""
     E = num_experts
     flat = idx.reshape(-1)
-    P = flat.shape[0]
     if held is not None:
         first, E = held
         local = flat - first
-        mine = (local >= 0) & (local < E)
-        flat = jnp.where(mine, local, E)       # E sorts after every expert
+        flat = jnp.where((local >= 0) & (local < E), local, E)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    rank = jnp.argsort(order).astype(jnp.int32)      # position of pair p
-    load = expert_load(idx if held is None else flat, E)
-    start = jnp.cumsum(load) - load
+    load = expert_load(flat, E)      # one_hot gives an index of E no count
+    return {"flat": flat, "order": order,
+            "rank": jnp.argsort(order).astype(jnp.int32), "load": load,
+            "start": jnp.cumsum(load) - load}
+
+
+def layout_window(pairs, tile=None, held=None, rows=None, offset=0):
+    """``grouped_layout`` from ``sorted_pairs``."""
+    flat, order, rank, load, start = (
+        pairs[n] for n in ("flat", "order", "rank", "load", "start"))
+    P, E = flat.shape[0], load.shape[0]
+    mine = flat < E
+    assert held is not None or (isinstance(offset, int) and offset == 0)
+
+    def shifted(n, by):
+        # the window at row 0 is the layout itself: nothing to add
+        return n if isinstance(by, int) and by == 0 else n + by
+
+    def window(first_row, M, slot):
+        """``kept`` and ``slot_of_pair`` of the rows ``offset .. offset + M``
+        of a layout where expert ``e`` starts at ``first_row[e]`` and pair
+        ``p`` sits in row ``slot[p]``."""
+        kept = (jnp.clip(offset + M - first_row, 0, load)
+                - jnp.clip(offset - first_row, 0, load))
+        inside = mine & (slot >= offset) & (slot < offset + M)
+        return kept, jnp.where(inside, slot - offset, M).astype(jnp.int32)
+
     if held is not None and tile is None:
         M = P if rows is None else rows
-        kept = jnp.clip(M - start, 0, load)
-        has_row = mine & (rank < M)
-        n = jnp.arange(M, dtype=jnp.int32)
-        return {"load": load, "kept": kept, "elsewhere": P - jnp.sum(load),
-                "slot_of_pair": jnp.where(has_row, rank, M),
-                "pair_of_slot": jnp.where(n < jnp.sum(kept), order[:M], -1),
-                "rows": M}
+        kept, slot_of_pair = window(start, M, rank)
+        n = shifted(jnp.arange(M, dtype=jnp.int32), offset)
+        total = jnp.sum(load)
+        return {"load": load, "kept": kept, "elsewhere": P - total,
+                "slot_of_pair": slot_of_pair,
+                "pair_of_slot": jnp.where(
+                    n < total, jnp.take(order, n, mode="fill",
+                                        fill_value=-1), -1),
+                "rows": M, "total": total}
     if tile is None:
         return {"load": load, "slot_of_pair": rank, "pair_of_slot": order,
                 "rows": P}
@@ -479,15 +545,16 @@ def grouped_layout(idx, num_experts, tile=None, held=None, rows=None):
     M = P + E * tile
     if held is not None:
         M = -(-(P if rows is None else rows) // tile) * tile + E * tile
-        kept = jnp.clip(M - pstart, 0, load)
-        slot_of_pair = jnp.where(mine & (slot_of_pair < M), slot_of_pair, M)
+        kept, slot_of_pair = window(pstart, M, slot_of_pair)
     n_tiles = M // tile
     tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+        jnp.searchsorted(tile_end,
+                         shifted(jnp.arange(n_tiles, dtype=jnp.int32),
+                                 offset // tile),
                          side="right", method="compare_all"),
         E - 1).astype(jnp.int32)
     e_of_slot = jnp.repeat(tile_expert, tile)
-    r = jnp.arange(M, dtype=jnp.int32) - pstart[e_of_slot]
+    r = shifted(jnp.arange(M, dtype=jnp.int32), offset) - pstart[e_of_slot]
     valid = (r >= 0) & (r < load[e_of_slot])
     pair_of_slot = jnp.where(
         valid, order[jnp.clip(start[e_of_slot] + r, 0, P - 1)], -1)
@@ -497,8 +564,10 @@ def grouped_layout(idx, num_experts, tile=None, held=None, rows=None):
            "n_used": tile_end[-1:].astype(jnp.int32), "rows": M}
     if held is not None:
         lay.update(kept=kept, elsewhere=P - jnp.sum(load),
-                   n_used=jnp.minimum(lay["n_used"], n_tiles),
-                   has_tile=pstart < M)
+                   n_used=jnp.clip(lay["n_used"] - offset // tile, 0,
+                                   n_tiles).astype(jnp.int32),
+                   has_tile=(pstart < offset + M) & (tile_end * tile > offset),
+                   total=tile_end[-1] * tile)
     return lay
 
 
@@ -655,59 +724,146 @@ def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
         else ("ragged", None)
 
 
+def _every_window(one_pass, step, tokens, idx, gate, weights):
+    """``(y, lay)``: the sum of ``one_pass(tokens, idx, gate, weights,
+    offset)[0]`` (``idx``: whatever says where the pairs go, integers, no
+    gradient) over the windows ``offset = 0, step, 2 step, ..`` below the
+    layout's ``total`` rows, and the first window's ``lay`` (arrays alone)
+    with ``computed``, the pairs of each expert that all windows held.
+    The first window is an ordinary differentiated call.  The others run
+    only where a batch routes more pairs to the held experts than one window
+    holds, as many as it takes, in a ``while`` loop over the same buffers;
+    their backward pass is a loop too and computes each window again, so a
+    step keeps nothing of them whatever their number."""
+    def more(total):
+        return lambda c: c[0] < total
+
+    def fwd(tokens, idx, gate, weights):
+        y, pull, lay = jax.vjp(
+            lambda t, g, w: one_pass(t, idx, g, w, jnp.int32(0)),
+            tokens, gate, weights, has_aux=True)
+
+        def add(c):
+            y_here, here = one_pass(tokens, idx, gate, weights, c[0])
+            return c[0] + step, c[1] + y_here, c[2] + here["kept"]
+        _, y, computed = jax.lax.while_loop(
+            more(lay["total"]), add, (jnp.int32(step), y, lay["kept"]))
+        return ((y, dict(lay, computed=computed)),
+                (pull, tokens, idx, gate, weights, lay["total"]))
+
+    def bwd(res, ct):
+        pull, tokens, idx, gate, weights, total = res
+        dy = ct[0]
+
+        def add(c):
+            _, pull_here = jax.vjp(
+                lambda t, g, w: one_pass(t, idx, g, w, c[0])[0], tokens,
+                gate, weights)
+            return c[0] + step, jax.tree_util.tree_map(
+                jnp.add, c[1], pull_here(dy))
+        _, (d_tokens, d_gate, d_weights) = jax.lax.while_loop(
+            more(total), add, (jnp.int32(step), pull(dy)))
+        return d_tokens, None, d_gate, d_weights
+
+    run = jax.custom_vjp(lambda *a: fwd(*a)[0])
+    run.defvjp(fwd, bwd)
+    return run(tokens, idx, gate, weights)
+
+
+def _experts(xs, lay, w_gate, w_up, w_down, how, tile, held):
+    """The expert FFNs over the rows ``xs`` of layout ``lay``: gated
+    (``silu(x W_gate) * x W_up``) or, with ``w_gate=None``, ``relu(x
+    W_up)^2``, then ``W_down``, as grouped products of the form ``how``."""
+    if how == "pallas":
+        from .pallas.moe_gmm import grouped_matmul
+
+        def product(a, w):
+            if held is not None:
+                w = _grad_where(w, lay["has_tile"])
+            return grouped_matmul(a, w, lay["tile_expert"], lay["n_used"],
+                                  tile, w_up.shape[0])
+    else:
+        def product(a, w):
+            return jax.lax.ragged_dot(a, w, lay.get("kept", lay["load"]))
+    if w_gate is None:
+        act = jnp.square(jax.nn.relu(product(xs, w_up)))
+    else:
+        act = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
+    return product(act, w_down)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "held", "rows", "tile",
+                                             "how"))
+def _held_pass(tokens, by_expert, gate, weights, offset, *, k, held, rows,
+               tile, how):
+    """The held experts' part of ``y`` from the pairs in the window of rows
+    at ``offset`` (``layout_window`` of ``by_expert``), and that window's
+    counts.  A jitted function: a model's expert layers and every pass over
+    their rows, forward and recomputed, are one trace."""
+    T = tokens.shape[0]
+    with jax.named_scope("hetu_moe_dispatch"):
+        lay = layout_window(by_expert, tile, held=held, rows=rows,
+                            offset=offset)
+        tok = jnp.where(lay["pair_of_slot"] >= 0, lay["pair_of_slot"] // k, T)
+        xs = _tokens_to_rows(tokens, tok)
+    with jax.named_scope("hetu_moe_experts"):
+        out = _experts(xs, lay, *weights, how, tile, held)
+    with jax.named_scope("hetu_moe_combine"):
+        g = _pairs_to_rows(gate.reshape(-1), lay["pair_of_slot"],
+                           lay["slot_of_pair"])
+        weighted = (out.astype(jnp.float32) * g[:, None]).astype(tokens.dtype)
+        return _rows_to_tokens(weighted, tok, T), dict(
+            {n: lay[n] for n in ("load", "kept", "elsewhere", "total")},
+            computed=lay["kept"])
+
+
 def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
                  impl=None, held=None, rows=None):
     """``y[t] = sum_c gate[t, c] * W_down,e( silu(W_gate,e x_t) * W_up,e
     x_t )`` with ``e = idx[t, c]``; no pair is dropped.  ``tokens [T, H]``,
     ``idx, gate [T, k]``, weights ``[E, H, F]``, ``[E, H, F]``,
-    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``.
+    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``.  ``w_gate=None`` is an
+    expert that is not gated, ``W_down,e relu(W_up,e x_t)^2`` (Nemotron-H's
+    ``relu2``): two grouped products a pass where the gated expert runs
+    three.
 
     ``held=(first, count)``: the weights are those of ``count`` experts of
     the ``num_experts`` that ``idx`` ranges over, and ``y`` is their part of
     the sum alone: what the experts on other devices would add is left out
-    (``grouped_layout``).  ``rows`` bounds the pairs laid out (``held_rows``);
-    pairs beyond it are not computed and are counted.  Returns ``(y, lay)``
-    then, with the layout's ``load``, ``kept`` and ``elsewhere``."""
+    (``grouped_layout``).  ``rows`` bounds the pairs ONE pass lays out
+    (``held_rows``); what a batch routes here beyond it is computed by
+    further passes over the same rows (``_every_window``), so no pair is
+    dropped here either.  Returns ``(y, lay)`` then, with the layout's
+    ``load`` and ``elsewhere``, ``kept``, the pairs the first pass held, and
+    ``computed``, the pairs all passes held (``load``, counted)."""
     T, H = tokens.shape
     k = idx.shape[1]
-    E, _, F = w_gate.shape
+    E, _, F = w_up.shape
     pairs, tile = T * k, None
     if held is not None:
         pairs = rows or pairs
         tile = HELD_TILE if pairs // E >= HELD_TILE else 8
         pairs = -(-pairs // tile) * tile       # the layout rounds up too
     how, tile = grouped_impl(pairs, E, H, F, tokens.dtype, mesh, impl, tile)
-    with jax.named_scope("hetu_moe_dispatch"):
-        if held is None:
+    if held is None:
+        with jax.named_scope("hetu_moe_dispatch"):
             lay = grouped_layout(idx, E, tile)
             xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
-        else:
-            lay = grouped_layout(idx, None, tile, held=held, rows=rows)
-            tok = jnp.where(lay["pair_of_slot"] >= 0,
-                            lay["pair_of_slot"] // k, T)
-            xs = _tokens_to_rows(tokens, tok)
-    with jax.named_scope("hetu_moe_experts"):
-        if how == "pallas":
-            from .pallas.moe_gmm import grouped_matmul
+        with jax.named_scope("hetu_moe_experts"):
+            out = _experts(xs, lay, w_gate, w_up, w_down, how, tile, None)
+        with jax.named_scope("hetu_moe_combine"):
+            by_pair = _rows_back(out, lay["pair_of_slot"],
+                                 lay["slot_of_pair"])
+            y = jnp.sum(by_pair.reshape(T, k, H).astype(jnp.float32)
+                        * gate[:, :, None], axis=1).astype(tokens.dtype)
+        return y, lay["load"]
 
-            def product(a, w):
-                if held is not None:
-                    w = _grad_where(w, lay["has_tile"])
-                return grouped_matmul(a, w, lay["tile_expert"],
-                                      lay["n_used"], tile, E)
-        else:
-            def product(a, w):
-                return jax.lax.ragged_dot(a, w, lay.get("kept", lay["load"]))
-        act = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
-        out = product(act, w_down)
-    with jax.named_scope("hetu_moe_combine"):
-        if held is not None:
-            g = _pairs_to_rows(gate.reshape(-1), lay["pair_of_slot"],
-                               lay["slot_of_pair"])
-            weighted = (out.astype(jnp.float32) * g[:, None]).astype(
-                tokens.dtype)
-            return _rows_to_tokens(weighted, tok, T), lay
-        pairs = _rows_back(out, lay["pair_of_slot"], lay["slot_of_pair"])
-        y = jnp.sum(pairs.reshape(T, k, H).astype(jnp.float32)
-                    * gate[:, :, None], axis=1).astype(tokens.dtype)
-    return y, lay["load"]
+    one_pass = functools.partial(_held_pass, k=k, held=tuple(held),
+                                 rows=rows, tile=tile, how=how)
+    weights = (w_gate, w_up, w_down)
+    with jax.named_scope("hetu_moe_dispatch"):
+        by_expert = sorted_pairs(idx, None, held)   # once for every window
+    if rows is None:                   # rows for every pair: one window
+        return one_pass(tokens, by_expert, gate, weights, jnp.int32(0))
+    return _every_window(one_pass, rows if tile is None else pairs + E * tile,
+                         tokens, by_expert, gate, weights)

@@ -39,6 +39,9 @@ from . import dispatch
 #: weight block and the f32 accumulator, double-buffered, are about 14 MiB;
 #: Mosaic's default scoped limit is 16 MiB of the v5e's 128
 VMEM_LIMIT = 64 * 2 ** 20
+#: the widest dimension taken as one block where no multiple of 128 divides
+#: it: the default contraction block
+WHOLE_WIDTH = 2048
 
 
 def _fit(dim, want):
@@ -61,7 +64,11 @@ def unsupported(m, k, n, tm, dtype):
     if m % tm:
         return f"rows_not_tile_aligned:{m}%{tm}"
     if dispatch.mosaic():
-        if k % 128 or n % 128 or tm % 8:
+        # a width that is no multiple of 128 has no 128-aligned divisor:
+        # ``_fit`` takes it whole, as one block the size of the array's
+        # dimension, which Mosaic takes (Nemotron-H's experts are 1,856
+        # wide, 14.5 x 128) so long as a block of it fits the scoped VMEM
+        if any(d % 128 and d > WHOLE_WIDTH for d in (k, n)) or tm % 8:
             return "dims_not_128_aligned"
         if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
                                     jnp.dtype(jnp.float32)):
@@ -83,6 +90,13 @@ def _live(i, n_used):
     return jnp.minimum(i, n_used[0] - 1)
 
 
+# The kernels are jitted functions: a layer's three or two products, its
+# backward pass, every layer of a model and every pass over a held layout's
+# rows call the same few (shapes, options), and each is traced and lowered
+# once a program, not once a call site (tracing a kernel is Python time).
+
+@functools.partial(jax.jit, static_argnames=("tm", "transpose_rhs", "tk",
+                                             "tn", "name"))
 def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
         tk=2048, tn=1024, name="hetu_moe_gmm_fwd"):
     """``out[tile i] = x[tile i] @ w[tile_expert[i]]`` (``w[...]^T`` with
@@ -139,6 +153,8 @@ def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
     )(tile_expert, n_used, x, w)
 
 
+@functools.partial(jax.jit, static_argnames=("num_experts", "tm", "tk",
+                                             "tn", "name"))
 def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm, tk=2048, tn=512,
          name="hetu_moe_gmm_dw"):
     """``dw[e] = sum over expert e's row tiles of x_tile^T @ dy_tile``."""

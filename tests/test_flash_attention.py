@@ -762,3 +762,70 @@ def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
     words = [int(np.prod([int(n) for n in dims.split(",") if n]))
              for dims in re.findall(r"u32\[([\d,]*)\]", hlo)]
     assert max(words) <= 4
+
+
+def test_held_relu2_experts_compile_for_v5e_at_a_width_off_128(v5e, as_on_tpu):
+    """Nemotron-H's expert layer as the cell holds it (8 of 128 experts of
+    width 1,856 = 14.5 x 128 over 8,192 tokens of 2,688, 6 a token): the
+    grouped products are the Pallas kernels, six a pass and step (up, down;
+    their dx; their dw), the width that no multiple of 128 divides taken as
+    one block, and no ``ragged-dot`` stands in.  What a batch routes here
+    over one pass's rows takes two ``while`` loops of the same kernels."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops import moe as moe_ops
+    from hetu_tpu.ops.pallas import moe_gmm
+    T, H, F, E, held, k = 8192, 2688, 1856, 128, (0, 8), 6
+    assert moe_gmm.unsupported(7168, H, F, 128, jnp.bfloat16) is None
+    assert moe_gmm.unsupported(7168, 4224, F, 128, jnp.bfloat16) is None
+    assert moe_gmm.unsupported(7168, H, 2112, 128, jnp.bfloat16) == (
+        "dims_not_128_aligned")          # too wide to be one block
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+
+    def loss(x, wr, w_up, w_down):
+        idx, gate, _ = moe_ops.top_k_route(
+            x.astype(jnp.float32) @ wr, k, renorm=True, score="sigmoid",
+            bias=jnp.zeros((E,), jnp.float32), scale=2.5)
+        y, _ = moe_ops.dropless_moe(
+            x, idx, gate, None, w_up, w_down, held=held,
+            rows=moe_ops.held_rows(T * k, E, held[1]))
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 2, 3))).lower(
+        sds((T, H), jnp.bfloat16), sds((H, E), jnp.float32),
+        sds((held[1], H, F), jnp.bfloat16),
+        sds((held[1], F, H), jnp.bfloat16)).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    # the first pass over the rows (up, down; their dx; their dw), the loop
+    # of further passes forward, and its backward loop that computes a pass
+    # again before its dx and dw
+    assert [sum(n in ln for ln in kernels) for n in (
+        "hetu_moe_gmm_fwd", "hetu_moe_gmm_dx", "hetu_moe_gmm_dw")] == [
+            6, 4, 4]
+    assert len(re.findall(r"\bwhile\(", hlo)) == 2
+    assert "ragged-dot" not in hlo
+
+
+def test_mamba2_scan_node_compiles_for_v5e(v5e, as_on_tpu):
+    """The Nemotron-H cell's ``hetu_ssm_scan`` node (64 heads of 64, state
+    128, 8 groups, 8,192 positions in chunks of 128, bf16), forward and
+    backward: plain XLA (no kernel yet), its walk over chunk states a
+    ``while``."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.layers.mamba2 import _scan
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    dims = dict(heads=64, head_dim=64, groups=8, state=128, chunk=128)
+
+    def loss(xbc, dt, dt_bias, a_log, d_skip):
+        return jnp.sum(_scan(xbc, dt, dt_bias, a_log, d_skip,
+                             **dims).astype(jnp.float32) ** 2)
+
+    vec = sds((64,), jnp.float32)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((1, 8192, 6144), jnp.bfloat16), sds((1, 8192, 64), jnp.bfloat16),
+        vec, vec, vec).compile().as_text()
+    assert "tpu_custom_call" not in hlo
+    assert re.findall(r"\bwhile\(", hlo)

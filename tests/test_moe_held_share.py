@@ -4,7 +4,9 @@ count))``) tied to the whole layer: at a small size the routed parts that the
 layer's output and the plain reference's; the shares' pair counters add up
 to ``T k``; a batch that overflows the static row bound is counted as dropped
 and not silently lost; both forms of the grouped products lay out held
-experts alike."""
+experts alike.  The same for Nemotron-H's expert layer (sigmoid scores with a
+selection bias, experts that are not gated, a shared expert without a
+sigmoid gate), and the rule that moves the bias."""
 
 import numpy as np
 import pytest
@@ -17,6 +19,7 @@ from hetu_tpu import telemetry
 from hetu_tpu.layers.moe import MoELayer, record_moe_load
 from hetu_tpu.ops import moe as moe_ops
 
+from chipbench.reference import nemotron_h as ref_nemotron
 from chipbench.reference import qwen3_next as ref
 
 T, H, F, E, K = 48, 32, 16, 32, 4
@@ -96,7 +99,7 @@ def test_pair_counters_add_up(shares):
     assert whole_load[0].sum() == T * K
     here = [load for _, load in shares["parts"]]
     for load in here:
-        assert load.shape == (3, PER)
+        assert load.shape == (4, PER) and not load[3].any()
         assert load[0].sum() + load[2, 0] == T * K
         np.testing.assert_array_equal(load[1], load[0])
     np.testing.assert_array_equal(np.concatenate([l[0] for l in here]),
@@ -104,11 +107,12 @@ def test_pair_counters_add_up(shares):
     assert sum(l[0].sum() for l in here) == T * K
 
 
-def test_overflow_of_the_row_bound_is_counted():
+def test_overflow_of_the_row_bound_is_computed_and_counted():
     """A router that sends every token to experts 0..3, all held here: 192
-    pairs against a bound of 2 x 192 x 4 / 32 = 48 rows.  The first 48 rows
-    in expert order are expert 0's, so the layer computes expert 0's part,
-    says it kept 48 of 192, and the counters call the rest dropped."""
+    pairs against a bound of 2 x 192 x 4 / 32 = 48 rows a pass.  The first
+    48 rows in expert order are expert 0's; three further passes compute
+    experts 1 to 3, so the layer gives all four experts' part, says it
+    computed every pair, and counts 144 of them over the bound."""
     x = ht.placeholder_op("x", (T, H))
     part = layer("over", held=(0, 4))
     ex = ht.Executor({"f": [part(x), part.load()]}, seed=5)
@@ -118,26 +122,29 @@ def test_overflow_of_the_row_bound_is_counted():
     xv = np.random.default_rng(3).normal(size=(T, H)).astype(np.float32)
     xv[:, 0] = 1.0
     y, load = ex.run("f", feed_dict={x: xv}, convert_to_numpy_ret_vals=True)
+    assert load.shape == (4, 4)
     np.testing.assert_array_equal(load[0], [T] * 4)
-    np.testing.assert_array_equal(load[1], [T, 0, 0, 0])
-    assert load[2, 0] == 0 and np.isfinite(y).all()
-    w1, w3, w2 = (np.asarray(ex.params[v.name][0])
-                  for v in (part.w1, part.w3, part.w2))
+    np.testing.assert_array_equal(load[1], load[0])
+    np.testing.assert_array_equal(load[3], [0, T, T, T])
+    assert load[2, 0] == 0
+    w1, w3, w2 = (np.asarray(ex.params[v.name]) for v in (part.w1, part.w3,
+                                                          part.w2))
     p = jax.nn.softmax(jnp.asarray(xv @ router), -1)[:, :K]
-    p0 = np.asarray(p[:, 0] / p.sum(-1))
-    want = p0[:, None] * np.asarray(
-        (jax.nn.silu(xv @ w1) * (xv @ w3)) @ w2)
-    np.testing.assert_allclose(y, want, atol=2e-6)
+    p = np.asarray(p / p.sum(-1, keepdims=True))
+    want = sum(p[:, e:e + 1] * np.asarray(
+        (jax.nn.silu(xv @ w1[e]) * (xv @ w3[e])) @ w2[e]) for e in range(4))
+    np.testing.assert_allclose(y, want, atol=4e-6)
     telemetry.enable()
     try:
         telemetry.get_registry().reset()
         record_moe_load("layer0", load)
-        record_moe_load("layer0", [[3, 1], [3, 1], [20, 0]])
+        record_moe_load("layer0", [[3, 1], [2, 1], [20, 0]])
         snap = telemetry.get_registry().snapshot()
         value = {n: snap[n]["samples"][0]["value"] for n in snap
                  if n.startswith("hetu_moe_")}
         assert value["hetu_moe_pairs_routed_total"] == 4 * T + 4
-        assert value["hetu_moe_pairs_dropped_total"] == 3 * T
+        assert value["hetu_moe_pairs_dropped_total"] == 1
+        assert value["hetu_moe_pairs_over_bound_total"] == 3 * T
         assert value["hetu_moe_pairs_elsewhere_total"] == 20
         assert value["hetu_moe_expert_load_max_over_mean"] == 1.5
     finally:
@@ -200,34 +207,65 @@ def test_held_grouped_products_forward_and_backward(impl, held):
             np.testing.assert_allclose(g, w, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["ragged", "pallas"])
-def test_held_layout_over_the_bound(impl):
-    """Fewer rows than pairs land here: the pairs kept are the first in
-    expert order, the others read zeros forward and backward, and ``kept``
-    says how many each expert computed."""
+@pytest.mark.parametrize("impl,held,k,rows", [
+    ("ragged", (2, 5), 4, 40), ("pallas", (2, 5), 4, 40),
+    ("ragged", (0, 4), 3, 8), ("pallas", (6, 6), 3, 16),
+    ("ragged", (0, 16), 4, 24), ("pallas", (0, 16), 4, 24)])
+def test_held_layout_over_the_bound(impl, held, k, rows):
+    """Fewer rows a pass than pairs land here: ``kept`` says what the first
+    pass held, the first pairs in expert order, and further passes over the
+    same rows (up to eleven here) compute the others, so values and the
+    gradient of every operand are the dense computation's."""
     args = held_inputs(seed=4)
-    held, k, rows = (2, 5), 4, 40
     y, lay = jax.jit(held_op(k, held, impl, rows))(*args)
     load, kept = np.asarray(lay["load"]), np.asarray(lay["kept"])
     assert load.sum() > rows and (kept <= load).all()
-    assert np.isfinite(np.asarray(y)).all()
+    assert 0 < kept.sum() < load.sum()
+    np.testing.assert_array_equal(lay["computed"], load)
     if impl == "ragged":
-        assert kept.sum() == rows
-    # the experts wholly kept give their dense part; zeroing the others'
-    # weights in the dense computation leaves exactly that
-    whole = kept == load
-    assert whole.any() and not whole.all()
-    part = np.zeros(16, bool)
-    part[held[0]:held[0] + held[1]] = ~whole
-    if (kept[~whole] == 0).all():
-        x, wg, w1, w3, w2 = args
-        w2 = w2 * jnp.asarray(~part, jnp.float32)[:, None, None]
-        np.testing.assert_allclose(y, dense_share(x, wg, w1, w3, w2, k, held),
-                                   atol=2e-6)
-    g = jax.jit(jax.grad(lambda *a: jnp.sum(
-        held_op(k, held, impl, rows)(*a)[0] ** 2), argnums=(0, 2)))(
-            *args)
-    assert all(np.isfinite(np.asarray(t)).all() for t in g)
+        assert kept.sum() == rows and int(lay["total"]) == load.sum()
+    assert int(lay["total"]) > 2 * rows
+    np.testing.assert_allclose(y, dense_share(*args, k, held), atol=2e-6)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(
+        held_op(k, held, impl, rows)(*a)[0] ** 2), argnums=range(5)))(*args)
+    want = jax.grad(lambda *a: jnp.sum(dense_share(*a, k, held) ** 2),
+                    argnums=range(5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+@pytest.mark.parametrize("tile", [None, 8])
+def test_windows_of_the_held_layout_hold_every_pair_once(tile):
+    """The windows at 0, M, 2 M, .. below ``total``: every pair routed to a
+    held expert has a row in exactly one of them, the row holds that pair,
+    and a row tile's rows are one expert's."""
+    idx = jnp.asarray(np.random.default_rng(2).integers(0, 16, (64, 4)),
+                      jnp.int32)
+    held, rows = (3, 6), 16
+    flat = np.asarray(idx).reshape(-1)
+    mine = (flat >= 3) & (flat < 9)
+    seen = np.zeros(flat.size, int)
+    first = moe_ops.grouped_layout(idx, None, tile, held=held, rows=rows)
+    M, total = first["rows"], int(first["total"])
+    assert total > M
+    kept = 0
+    for offset in range(0, total, M):
+        lay = jax.jit(lambda o: moe_ops.grouped_layout(
+            idx, None, tile, held=held, rows=rows, offset=o))(offset)
+        slot, pair = (np.asarray(lay[n]) for n in ("slot_of_pair",
+                                                   "pair_of_slot"))
+        here = slot < M
+        seen += here
+        np.testing.assert_array_equal(pair[slot[here]], np.flatnonzero(here))
+        assert (pair >= 0).sum() == here.sum() == np.asarray(lay["kept"]).sum()
+        kept += here.sum()
+        if tile:
+            experts = np.repeat(np.asarray(lay["tile_expert"]), tile)
+            used = pair >= 0
+            np.testing.assert_array_equal(flat[pair[used]] - 3, experts[used])
+            assert used[int(lay["n_used"][0]) * tile:].sum() == 0
+    np.testing.assert_array_equal(seen, mine.astype(int))
+    assert kept == mine.sum() == np.asarray(first["load"]).sum()
 
 
 def test_held_none_is_todays_layer():
@@ -238,3 +276,145 @@ def test_held_none_is_todays_layer():
     assert m.shared is None and m.held is None
     op = m(ht.placeholder_op("x", (T, H)))
     assert op.held is None and op is m.last_op
+
+
+# -- Nemotron-H's expert layer: sigmoid scores with a bias, relu2 experts -----
+
+NEMOTRON = {"num_experts_per_tok": K, "norm_topk_prob": True,
+            "routed_scaling_factor": 2.5}
+
+
+def relu2_layer(name, **kw):
+    return MoELayer(H, F, E, k=K, capacity_factor=None, expert_act="relu2",
+                    renorm_topk=True, track_load=True, router_score="sigmoid",
+                    router_scale=2.5, shared_gate=False, name=name, **kw)
+
+
+@pytest.fixture(scope="module")
+def relu2_shares():
+    """The uncut layer and its 16 shares in one program, the shares' weights
+    and the router's bias cut out of the uncut layer's; the bias is off zero
+    so that it decides some choices."""
+    x = ht.placeholder_op("x", (T, H))
+    whole = relu2_layer("rwhole", shared_width=F)
+    parts = [relu2_layer(f"rshare{j}", held=(PER * j, PER))
+             for j in range(SHARES)]
+    assert whole.w3 is None and len(whole.shared) == 2
+    fetch = [whole(x), whole.load(), whole.chosen()]
+    for p in parts:
+        fetch += [p(x), p.load()]
+    ex = ht.Executor({"f": fetch}, seed=13)
+    # small enough that no share's pairs pass its row bound (twice the mean)
+    bias = jnp.asarray(np.random.default_rng(4).normal(0, 0.03, E),
+                       jnp.float32)
+    ex.params[whole.gate.bias.name] = bias
+    for j, p in enumerate(parts):
+        ex.params[p.gate.wg.name] = ex.params[whole.gate.wg.name]
+        ex.params[p.gate.bias.name] = bias
+        for mine, theirs in ((p.w1, whole.w1), (p.w2, whole.w2)):
+            ex.params[mine.name] = ex.params[theirs.name][PER * j:
+                                                          PER * (j + 1)]
+    weights = {n: np.asarray(ex.params[v.name]) for n, v in (
+        ("router", whole.gate.wg), ("router_bias", whole.gate.bias),
+        ("w_up", whole.w1), ("w_down", whole.w2),
+        *zip(("shared_up", "shared_down"), whole.shared))}
+    xv = np.random.default_rng(2).normal(size=(T, H)).astype(np.float32)
+    out = ex.run("f", feed_dict={x: xv}, convert_to_numpy_ret_vals=True)
+    return dict(x=xv, weights=weights, whole=out[:3],
+                parts=list(zip(out[3::2], out[4::2])))
+
+
+def relu2_shared(x, w):
+    return np.asarray(jnp.square(jax.nn.relu(x @ w["shared_up"]))
+                      @ w["shared_down"])
+
+
+def test_relu2_shares_add_up_to_the_uncut_layer_and_the_references(
+        relu2_shares):
+    """The 16 shares' routed parts plus the shared expert ONCE are the uncut
+    layer, which is the reference's; the reference's choices are the
+    layer's, and the bias decided some of them."""
+    s = relu2_shares
+    y_whole, load, chosen = s["whole"]
+    total = sum(y for y, _ in s["parts"]) + relu2_shared(s["x"],
+                                                         s["weights"])
+    np.testing.assert_allclose(total, y_whole, atol=2e-6)
+    assert np.abs(s["parts"][0][0] - y_whole).max() > 1e-2
+    y_ref, (_, chosen_ref) = ref_nemotron.moe(
+        jnp.asarray(s["x"]), s["weights"], NEMOTRON, lambda a, b: a @ b)
+    np.testing.assert_allclose(y_whole, y_ref, atol=2e-6)
+    np.testing.assert_array_equal(np.sort(chosen, -1),
+                                  np.sort(chosen_ref, -1))
+    unbiased = dict(s["weights"], router_bias=np.zeros(E, np.float32))
+    _, (_, plain) = ref_nemotron.moe(jnp.asarray(s["x"]), unbiased, NEMOTRON,
+                                     lambda a, b: a @ b)
+    assert (np.sort(plain, -1) != np.sort(chosen_ref, -1)).any()
+    assert load[0].sum() == T * K
+    here = [l for _, l in s["parts"]]
+    np.testing.assert_array_equal(np.concatenate([l[0] for l in here]),
+                                  load[0])
+    for l in here:
+        np.testing.assert_array_equal(l[1], l[0])      # nothing dropped
+
+
+@pytest.mark.parametrize("j", [0, 5, SHARES - 1])
+def test_a_relu2_share_is_the_references_share(relu2_shares, j):
+    s = relu2_shares
+    w = dict(s["weights"])
+    for n in ("w_up", "w_down"):
+        w[n] = w[n][PER * j:PER * (j + 1)]
+    y, _ = ref_nemotron.moe(jnp.asarray(s["x"]), w, NEMOTRON,
+                            lambda a, b: a @ b, held=(PER * j, PER))
+    routed_only = np.asarray(y) - relu2_shared(s["x"], w)
+    np.testing.assert_allclose(s["parts"][j][0], routed_only, atol=2e-6)
+
+
+def test_the_router_bias_moves_by_its_rule_and_is_no_weight():
+    """One training step: ``bias += u sign(mean(load) - load)`` from the
+    step's pair counts over ALL experts (this device holds 8 of 32), read
+    from the f32 master under bf16 compute; the bias has no gradient and no
+    AdamW state, and an evaluation leaves it where it is."""
+    from hetu_tpu.graph.node import graph_variables
+    u = 0.01
+    x = ht.placeholder_op("x", (T, H))
+    part = relu2_layer("rule", held=(8, 8), shared_width=F,
+                       router_bias_rate=u)
+    loss = ht.reduce_mean_op(part(x) * part(x), axes=[0, 1])
+    opt = ht.AdamWOptimizer(learning_rate=1e-3, weight_decay=0.1)
+    ex = ht.Executor(
+        {"train": [loss, opt.minimize(loss), part.router_bias(),
+                   part.chosen()],
+         "eval": [loss, part.router_bias()]},
+        seed=17, compute_dtype=jnp.bfloat16)
+    name = part.gate.bias.name
+    trainable = {v.name for v in graph_variables([loss],
+                                                 trainable_only=True)}
+    assert name not in trainable and part.gate.wg.name in trainable
+    # a host copy: the training step donates the state's buffers
+    start = np.random.default_rng(6).normal(0, 0.3, E).astype(np.float32)
+    ex.params[name] = jnp.asarray(start)
+    xv = np.random.default_rng(3).normal(size=(T, H)).astype(np.float32)
+    _, fetched = ex.run("eval", feed_dict={x: xv},
+                        convert_to_numpy_ret_vals=True)
+    np.testing.assert_array_equal(fetched, start)
+    np.testing.assert_array_equal(ex.params[name], start)
+    _, _, moved, chosen = ex.run("train", feed_dict={x: xv},
+                                 convert_to_numpy_ret_vals=True)
+    load = np.bincount(chosen.reshape(-1), minlength=E)
+    want = start + u * np.sign(load.mean() - load)
+    np.testing.assert_allclose(moved, want, atol=1e-7)
+    np.testing.assert_allclose(ex.params[name], want, atol=1e-7)
+    assert ex.params[name].dtype == jnp.float32
+    assert (load > load.mean()).any() and (load < load.mean()).any()
+    states = [k for state in ex.opt_state.values()
+              for k in jax.tree_util.tree_leaves_with_path(state)]
+    assert states and not any(name in jax.tree_util.keystr(path)
+                              for path, _ in states)
+
+
+def test_relu2_and_sigmoid_run_on_the_dropless_path_alone():
+    with pytest.raises(AssertionError, match="dropless"):
+        MoELayer(H, F, E, k=K, expert_act="relu2", name="bad1")
+    with pytest.raises(AssertionError, match="dropless"):
+        MoELayer(H, F, E, k=K, expert_act="swiglu", router_score="sigmoid",
+                 name="bad2")

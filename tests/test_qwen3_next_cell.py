@@ -132,6 +132,9 @@ def test_a_window_that_drops_a_pair_is_not_correct(monkeypatch, capsys):
     the window's checks as well as by the first batch's ``dropped``."""
     from hetu_tpu.ops import moe as moe_ops
     monkeypatch.setattr(moe_ops, "held_rows", lambda pairs, E, count: 8)
+    # and a program that stops after the first pass over its rows
+    monkeypatch.setattr(moe_ops, "_every_window",
+                        lambda one_pass, step, *args: one_pass(*args, 0))
     prog, _ = hybrid_toy()
     try:
         feed = prog.make_batches(2 ** 31 + 3, 1)[0]
@@ -145,6 +148,33 @@ def test_a_window_that_drops_a_pair_is_not_correct(monkeypatch, capsys):
     assert "WRONG every loss is finite" in out
     assert "WRONG the program's dropped" in out
     assert "not finite: 0\n" not in out
+
+
+def test_a_window_over_the_row_bound_drops_nothing(monkeypatch, capsys):
+    """With rows for 8 pairs a pass the batch overflows in every expert
+    layer; the further passes compute the rest, so a step's loss is the
+    unbounded program's and the rehearsal ends correct."""
+    from hetu_tpu.ops import moe as moe_ops
+    prog, _ = hybrid_toy()
+    try:
+        feed = prog.make_batches(2 ** 31 + 3, 1)[0]
+        want = prog.step(feed)
+    finally:
+        prog.close()
+    monkeypatch.setattr(moe_ops, "held_rows", lambda pairs, E, count: 8)
+    prog, _ = hybrid_toy()
+    try:
+        got = prog.step(prog.make_batches(2 ** 31 + 3, 1)[0])
+        assert np.isfinite(got) and prog.steps_dropping == 0
+        assert prog.held_peak > 8
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+    finally:
+        prog.close()
+    rc = run.main(["--workload", CELL, "--seed", str(2 ** 31 + 13),
+                   "--seconds", "1", "--trace", "0"], rehearsal=True)
+    out = capsys.readouterr().out
+    assert rc == 0 and "WRONG" not in out, out
+    assert "not finite: 0\n" in out
 
 
 def test_a_bf16_state_fails_the_delta_rule_probe(monkeypatch):

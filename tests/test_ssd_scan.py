@@ -1,0 +1,113 @@
+"""The chunked state-space scan (``hetu_tpu/ops/ssd.py chunk_ssd``) against
+the recurrence one position at a time, the program's own
+(``recurrent_ssd``) and the plain reference's
+(``chipbench/reference/nemotron_h.py ssm_recurrence``): outputs, the last
+state and the gradient of every input, over several chunk counts with a
+ragged last chunk, in f32 and with bf16 inputs.  A state carried in bf16 must
+fail the tolerance the chunked form passes.
+
+Decays: ``dt`` about 0.7 and ``A`` of 0.003 to 1, so that the slowest head
+forgets 0.2% a position and still holds the first position at the last."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from hetu_tpu.ops.ssd import chunk_ssd, recurrent_ssd, segsum
+
+from chipbench.reference import nemotron_h as ref
+
+B, H, P, G, N = 2, 8, 16, 2, 32
+#: relative to the largest entry: f32 in another order of summation
+TOL = 2e-5
+
+
+def inputs(T, seed=0, dtype=jnp.float32):
+    r = np.random.default_rng(seed)
+    x = jnp.asarray(r.normal(size=(B, T, H, P)), dtype)
+    dt = jnp.asarray(np.logaddexp(0, r.normal(size=(B, T, H))), jnp.float32)
+    A = -jnp.asarray(np.geomspace(3e-3, 1.0, H), jnp.float32)
+    Bm, Cm = (jnp.asarray(r.normal(size=(B, T, G, N)) * N ** -0.5, dtype)
+              for _ in range(2))
+    return x, dt, A, Bm, Cm
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+def reference(x, dt, A, Bm, Cm, **kw):
+    with jax.default_matmul_precision("highest"):
+        return ref.ssm_recurrence(*(t.astype(jnp.float32) for t in (
+            x, dt, A, Bm, Cm)), **kw)
+
+
+@pytest.mark.parametrize("T,chunk", [(16, 16), (70, 16), (96, 32), (40, 128)])
+@pytest.mark.parametrize("other", [recurrent_ssd, reference],
+                         ids=["recurrent_ssd", "reference"])
+def test_chunked_is_the_recurrence(T, chunk, other):
+    args = inputs(T)
+    y, last = jax.jit(lambda *a: chunk_ssd(*a, chunk=chunk))(*args)
+    y_want, last_want = jax.jit(other)(*args)
+    assert y.shape == (B, T, H, P) and last.shape == (B, H, P, N)
+    assert last.dtype == jnp.float32
+    assert rel(y, y_want) < TOL and rel(last, last_want) < TOL
+
+
+@pytest.mark.parametrize("T,chunk", [(48, 16), (70, 32)])
+def test_gradient_of_every_input(T, chunk):
+    args = inputs(T, seed=1)
+    w_y, w_s = (jnp.asarray(np.random.default_rng(2).normal(size=s),
+                            jnp.float32)
+                for s in ((B, T, H, P), (B, H, P, N)))
+
+    def loss(fn):
+        def f(*a):
+            y, last = fn(*a)
+            return jnp.sum(y * w_y) + jnp.sum(last * w_s)
+        return jax.jit(jax.grad(f, argnums=range(5)))
+    got = loss(lambda *a: chunk_ssd(*a, chunk=chunk))(*args)
+    want = loss(reference)(*args)
+    for name, g, w in zip(("x", "dt", "A", "B", "C"), got, want):
+        assert np.abs(np.asarray(w)).max() > 0, name
+        assert rel(g, w) < 5 * TOL, name
+
+
+def test_bf16_inputs_keep_an_f32_state():
+    """x, B and C in bf16: the output is bf16, the state f32, and both are
+    the f32 recurrence's on the same (rounded) inputs to what the bf16
+    operands of the four products cost, a few parts in a thousand."""
+    args = inputs(96, seed=3, dtype=jnp.bfloat16)
+    y, last = jax.jit(lambda *a: chunk_ssd(*a, chunk=32))(*args)
+    y_want, last_want = jax.jit(reference)(*args)
+    assert y.dtype == jnp.bfloat16 and last.dtype == jnp.float32
+    assert rel(y.astype(jnp.float32), y_want) < 1e-2
+    assert rel(last, last_want) < 5e-3
+
+
+@pytest.mark.parametrize("other", [
+    lambda *a: recurrent_ssd(*a, state_dtype=jnp.bfloat16),
+    lambda *a: reference(*a, state_dtype=jnp.bfloat16)],
+    ids=["recurrent_ssd", "reference"])
+def test_a_bf16_state_fails_the_tolerance(other):
+    args = inputs(96, seed=4)
+    _, last_want = jax.jit(reference)(*args)
+    _, last = jax.jit(other)(*args)
+    assert rel(last, last_want) > 100 * TOL
+
+
+def test_segsum_is_a_sum_of_the_terms_between():
+    a = -jnp.asarray(np.random.default_rng(5).uniform(0, 2, (3, 7)),
+                     jnp.float32)
+    seg = np.asarray(segsum(a))
+    for t in range(7):
+        for s in range(7):
+            want = float(np.asarray(a)[1, s + 1:t + 1].sum()) if s <= t \
+                else -np.inf
+            assert seg[1, t, s] == pytest.approx(want, abs=1e-6)
+    # no cancellation: after a running sum of -2,000 a small step is exact
+    big = jnp.asarray([-2000.0, -1e-3, -1e-3], jnp.float32)
+    assert float(segsum(big)[2, 0]) == pytest.approx(-2e-3, rel=1e-6)
