@@ -18,9 +18,13 @@ Four graph nodes, each under a ``jax.named_scope`` that the device trace's
 readers find in the compiled step's ``op_name``: ``hetu_ssm_proj`` (the
 input projection and its three parts), ``hetu_ssm_conv``, ``hetu_ssm_scan``
 (the gates, the chunked scan and the skip) and ``hetu_ssm_out`` (the gate,
-the grouped norm and the output projection).  A decode step, and the state
-``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a serving
-cache, are not here (ROADMAP Queue 2).
+the grouped norm and the output projection).  The scan is
+``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
+``hetu_ssd_bwd`` where their rule takes the operands, under a mesh and on
+any other platform the ``jax.numpy`` form (``_ScanOp``); the softplus,
+``-exp(A_log)`` and the skip stay XLA's under the same scope.  A decode step,
+and the state ``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a
+serving cache, are not here (ROADMAP Queue 2).
 """
 
 from __future__ import annotations
@@ -43,7 +47,7 @@ def _part(zxbcdt, *, lo, hi):
 
 
 def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
-          state, chunk):
+          state, chunk, scan=None):
     import jax
     import jax.numpy as jnp
     from ..ops import ssd
@@ -54,10 +58,29 @@ def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
     Bm = xbc[..., d:d + gn].reshape(B, S, groups, state)
     Cm = xbc[..., d + gn:].reshape(B, S, groups, state)
     dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-    y, _ = ssd.chunk_ssd(x, dt, -jnp.exp(a_log.astype(f32)), Bm, Cm,
-                         chunk=chunk)
+    y, _ = (scan or ssd.chunk_ssd)(x, dt, -jnp.exp(a_log.astype(f32)), Bm,
+                                   Cm, chunk=chunk)
     y = y.astype(f32) + d_skip.astype(f32)[:, None] * x.astype(f32)
     return y.astype(xbc.dtype).reshape(B, S, d)
+
+
+class _ScanOp(_Scoped):
+    """The ``hetu_ssm_scan`` node.  A ``pallas_call`` does not partition
+    under GSPMD and ``chunk_ssd`` cannot see a mesh, so under one this node
+    calls the scan's ``jax.numpy`` form itself, and says so where there was
+    a kernel to take (reason ``mesh``)."""
+
+    def _compute(self, input_vals, ctx):
+        import jax
+        from ..ops import ssd
+        from ..ops.pallas import dispatch
+        scan = None
+        if ctx.mesh is not None:
+            scan = ssd.chunk_ssd_jnp
+            if dispatch.mosaic():
+                dispatch.record("ssd", "mesh")
+        with jax.named_scope(self.scope):
+            return self.fn(*input_vals, scan=scan, **self.attrs)
 
 
 def _out(y, z, w_norm, w_out, *, groups, eps):
@@ -138,7 +161,7 @@ class Mamba2(BaseLayer):
                                      ("z", "xbc", "dt")))
         xbc = _Scoped(causal_conv, "hetu_ssm_conv", xbc, self.conv,
                       self.conv_bias)
-        y = _Scoped(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
+        y = _ScanOp(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
                     self.a_log, self.d_skip, **self.dims)
         return _Scoped(_out, "hetu_ssm_out", y, z, self.norm, self.out_proj,
                        groups=self.groups, eps=self.eps)

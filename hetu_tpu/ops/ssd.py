@@ -17,8 +17,8 @@ hold the chunked one to, and what a decode step will run.
 
 ``chunk_ssd`` is the same function in chunks of ``chunk`` positions
 (section 6 of the paper, as ``ssd_minimal`` and HF ``modeling_nemotron_h``
-cut it).  With ``a_t = dt_t A`` and ``seg[t, s] = a_(s+1) + .. + a_t`` (``s <=
-t``, inside a chunk):
+cut it; ``chunk_ssd_jnp`` writes it out).  With ``a_t = dt_t A`` and
+``seg[t, s] = a_(s+1) + .. + a_t`` (``s <= t``, inside a chunk):
 
 1. inside a chunk, ``y_t += sum_(s<=t) exp(seg[t, s]) (C_t . B_s) dt_s x_s``:
    ``(C B^T * L)(dt x)`` with ``L = exp(seg)``;
@@ -40,13 +40,33 @@ precision took 20.6 and read the same, PR 33.  The sums from a chunk's start,
 Precision.  ``dt``, ``A``, every decay and the state from chunk to chunk are
 f32 whatever the compute type.  The four products take their operands in
 the compute type (``x``'s) and add in f32; with f32 operands they run at the
-highest matmul precision.  The backward pass is JAX's own through the
-products and the scan.
+highest matmul precision.  The backward pass of the ``jax.numpy`` form is
+JAX's own through the products and the scan.
 
 Shapes: ``x [b, T, H, P]``, ``dt [b, T, H]`` (after its softplus), ``A
 [H]``, ``B, C [b, T, G, N]``; returns ``y [b, T, H, P]`` in ``x``'s type and
-the last state ``[b, H, P, N]`` f32.  Plain ``jax.numpy``: there is no
-kernel, so nothing is counted in ``hetu_kernel_choice_total``.
+the last state ``[b, H, P, N]`` f32.
+
+What runs where.  ``chunk_ssd`` is what the layer's ``hetu_ssm_scan`` node
+calls (``layers/mamba2.py``) and what the benchmark's long-memory probe
+calls (``chipbench/builders/nemotron_h.py`` ``ssd_state_gap``).  On a TPU it
+runs as two Pallas kernels, ``hetu_ssd_fwd`` and ``hetu_ssd_bwd``
+(``ops/pallas/ssd.py``, a ``jax.custom_vjp``: one walk over chunk states in
+VMEM each way, a group's heads a program, the decays built in VMEM from
+``dt`` and ``A``; the backward keeps the chunk-start states and rebuilds
+everything else), where it can read that they apply: ``P`` a multiple of 64
+with a group's ``H / G`` heads filling whole 128-lane tiles, ``N`` a multiple
+of 128, ``chunk`` 128, ``x``, ``B`` and ``C`` all bf16 or all f32; any
+``b``, ``T``, ``H`` and ``G``.  Each call counts its choice at trace time in
+``hetu_kernel_choice_total{kernel="ssd", impl, reason}``: ``pallas``,
+or ``jnp`` with ``head_dim_not_64_aligned``, ``state_not_128_aligned``,
+``chunk!=128``, ``dtype:<name>`` or ``dtype:mixed``.  A mesh is the one thing
+the function cannot see (a ``pallas_call`` does not partition under GSPMD):
+the scan node reads it, calls ``chunk_ssd_jnp`` itself and counts ``mesh``.
+On any other platform there is no Mosaic and no choice: nothing is counted
+and ``chunk_ssd_jnp`` runs, bit for bit what this function was before it had
+kernels.  The kernels themselves run anywhere when called directly
+(interpret mode on the CPU): ``tests/test_ssd_kernel.py``.
 """
 
 from __future__ import annotations
@@ -96,7 +116,20 @@ def segsum(a):
 
 
 def chunk_ssd(x, dt, A, B, C, chunk=CHUNK):
-    """The chunked form; see the module's docstring."""
+    """The chunked form; see the module's docstring.  On a TPU the Pallas
+    kernel pair where its rule takes the operands, else (and on any other
+    platform, where there is no choice to record) the ``jax.numpy`` form."""
+    from .pallas import dispatch, ssd as kernels
+    if dispatch.mosaic() and dispatch.record(
+            "ssd", kernels.unsupported(x, B, C, chunk)):
+        return kernels.ssd(x, dt, A, B, C)
+    return chunk_ssd_jnp(x, dt, A, B, C, chunk)
+
+
+def chunk_ssd_jnp(x, dt, A, B, C, chunk=CHUNK):
+    """The chunked form in ``jax.numpy``: what the kernels are held to, and
+    what runs wherever they do not (a scan node under a mesh calls it
+    itself, ``layers/mamba2.py``)."""
     b, T, H, P = x.shape
     G, N = B.shape[2:]
     R = H // G

@@ -810,18 +810,21 @@ def test_held_relu2_experts_compile_for_v5e_at_a_width_off_128(v5e, as_on_tpu):
 def test_mamba2_scan_node_compiles_for_v5e(v5e, as_on_tpu):
     """The Nemotron-H cell's ``hetu_ssm_scan`` node (64 heads of 64, state
     128, 8 groups, 8,192 positions in chunks of 128, bf16), forward and
-    backward: plain XLA (no kernel yet), its walk over chunk states a
-    ``while``."""
+    backward, with the ``jax.numpy`` scan it runs under a mesh: plain XLA,
+    its walk over chunk states a ``while``.  (Off a mesh the node runs the
+    kernel pair since PR 34: ``tests/test_ssd_kernel.py`` compiles that.)"""
     import re
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu.layers.mamba2 import _scan
+    from hetu_tpu.ops.ssd import chunk_ssd_jnp
     one = SingleDeviceSharding(v5e.devices[0])
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
     dims = dict(heads=64, head_dim=64, groups=8, state=128, chunk=128)
 
     def loss(xbc, dt, dt_bias, a_log, d_skip):
         return jnp.sum(_scan(xbc, dt, dt_bias, a_log, d_skip,
-                             **dims).astype(jnp.float32) ** 2)
+                             scan=chunk_ssd_jnp, **dims
+                             ).astype(jnp.float32) ** 2)
 
     vec = sds((64,), jnp.float32)
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
