@@ -14,7 +14,7 @@ import numpy as np
 
 from .graph import (Op, PlaceholderOp, VariableOp, find_topo_sort,
                     graph_variables, gradients, Executor, stage,
-                    name_scope, remat)
+                    name_scope, remat, scope, scopes)
 from . import initializers as init
 from .ops import *  # noqa: F401,F403
 from .optim import (SGDOptimizer, MomentumOptimizer, AdaGradOptimizer,

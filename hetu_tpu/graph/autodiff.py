@@ -40,6 +40,8 @@ class GradientsBundleOp(Op):
         self.grad_out = grad_out
         inputs = [loss] + self.xs + ([grad_out] if grad_out is not None else [])
         super().__init__(*inputs, name=f"grads_of_{loss.name}")
+        # no block of its own: the inner `evaluate` names each node's
+        self.scope = None
         self.loss = loss
 
     # evaluated via _compute_with_env (special-cased by trace/executor)
@@ -93,6 +95,7 @@ class GradientSliceOp(Op):
 
     def __init__(self, bundle, idx, of):
         super().__init__(bundle, name=f"grad_{of.name}")
+        self.scope = None
         self.idx = idx
         self.of = of  # the x this is the gradient of
 

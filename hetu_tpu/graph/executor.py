@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .node import (Op, PlaceholderOp, VariableOp, find_topo_sort,
-                   graph_variables)
+                   graph_variables, named_scope)
 from .trace import TraceContext, evaluate
 from .. import telemetry as _telemetry
 
@@ -295,8 +295,9 @@ class SubExecutor:
                                               is not None else None))
             ctx.opt_state = opt_state
             bindings = {}
-            for v in self.variables:
-                bindings[v] = cast(params[v.name])
+            with named_scope("hetu_param_cast"):
+                for v in self.variables:
+                    bindings[v] = cast(params[v.name])
             for p in placeholders:
                 bindings[p] = cast(feeds[p.name])
             vals, env = evaluate(eval_nodes, bindings, ctx, topo=topo)

@@ -19,6 +19,8 @@ users of Hetu find the same surface.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 
 _node_counter = [0]
@@ -107,6 +109,74 @@ def current_stage():
     return _stage_stack()[-1]
 
 
+_scope_tls = _threading.local()
+_scope_names = {}
+_SCOPE_NAME = re.compile(r"hetu_[a-z0-9_]+")
+
+
+def _scope_stack():
+    stack = getattr(_scope_tls, "stack", None)
+    if stack is None:
+        stack = _scope_tls.stack = [None]
+    return stack
+
+
+def _known_scope(name):
+    """``name``, recorded among the names `scopes` reads back.  A reader of
+    the device trace finds a block by its name anywhere in an instruction's
+    ``op_name``, so no name may lie inside another."""
+    if name not in _scope_names:
+        if not _SCOPE_NAME.fullmatch(name):
+            raise ValueError(f"a scope is named hetu_[a-z0-9_]+, not {name!r}")
+        clash = [n for n in _scope_names if n in name or name in n]
+        if clash:
+            raise ValueError(f"scope {name!r} and {clash[0]!r}: one name "
+                             "lies inside the other")
+        _scope_names[name] = None
+    return name
+
+
+class scope:
+    """Block scope: ops created inside take ``name`` as their ``scope``, and
+    `evaluate` (graph/trace.py) runs each of them under
+    ``jax.named_scope(name)``.  XLA keeps the name in every instruction's
+    ``op_name``, forward, backward (``transpose(jvp(name))``) and recomputed,
+    which is how the device trace's readers give a block its device time
+    (docs/PROFILING.md).  Metadata only: the compiled step is the same.
+
+    Nests: the INNERMOST scope wins, one name an op.  Wrap a layer's
+    ``__call__`` body::
+
+        with ht.scope("hetu_attn"):
+            q = self.q_proj(x)
+    """
+
+    def __init__(self, name):
+        self.name = _known_scope(name)
+
+    def __enter__(self):
+        _scope_stack().append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _scope_stack().pop()
+        return False
+
+
+def scopes():
+    """Every scope name given so far, in the order given: to `scope`, to a
+    ``ScopedOp`` or to `named_scope`."""
+    return tuple(_scope_names)
+
+
+def named_scope(name):
+    """``jax.named_scope(name)`` for a region INSIDE one op's ``_compute``
+    (trace time), with the name recorded as `scope` records it.  It stands
+    after the op's own scope in ``op_name``, and the readers take the last."""
+    import jax
+    return jax.named_scope(_known_scope(name))
+
+
 _naming_tls = _threading.local()
 
 
@@ -175,7 +245,7 @@ class Op:
 
     __slots__ = (
         "id", "name", "inputs", "attrs", "dist_state", "raw_ctx",
-        "remat_scope", "_shape_cache",
+        "remat_scope", "scope", "_shape_cache",
     )
 
     def __init__(self, *inputs, name=None, **attrs):
@@ -197,6 +267,9 @@ class Op:
         _rs = _remat_stack()
         self.remat_scope = next((s for s in _rs[1:] if s is not None),
                                 None) if len(_rs) > 1 else None
+        # `with scope(name):` block name (jax.named_scope at trace time), or
+        # None.  The INNERMOST active scope wins.
+        self.scope = _scope_stack()[-1]
         self._shape_cache = None
 
     # -- graph protocol ----------------------------------------------------

@@ -11,6 +11,8 @@ per-node Python hot loop).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
@@ -179,11 +181,14 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
         if _remat and node.remat_scope is not None:
             eval_remat_group(node.remat_scope)
             continue
-        if hasattr(node, "_compute_with_env"):
-            env[node] = node._compute_with_env(env, ctx)
-        else:
-            input_vals = [env[i] for i in node.inputs]
-            env[node] = node._compute(input_vals, ctx)
+        # the node's block (`ht.scope`) into every instruction's op_name
+        with (jax.named_scope(node.scope) if node.scope is not None
+              else nullcontext()):
+            if hasattr(node, "_compute_with_env"):
+                env[node] = node._compute_with_env(env, ctx)
+            else:
+                input_vals = [env[i] for i in node.inputs]
+                env[node] = node._compute(input_vals, ctx)
         # interior sharding annotations (set by a Strategy or ht.dispatch)
         # lower to with_sharding_constraint — the per-node reshard points
         # the reference's rewrite pass materialized as comm ops

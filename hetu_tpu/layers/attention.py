@@ -26,6 +26,7 @@ the sigmoid of its second half, head by head.
 from __future__ import annotations
 
 from .base import BaseLayer, fresh_name
+from ..graph.node import scope
 from .common import Linear, RMSNorm
 from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
@@ -119,6 +120,12 @@ class MultiHeadAttention(BaseLayer):
         supports cross-attention over a memory of different length
         (reference examples/nlp/hetu_transformer.py multihead_attention,
         decoder side)."""
+        with scope("hetu_attn"):
+            return self._attend(query, key, value, attention_mask, seq_len,
+                                kv_seq_len)
+
+    def _attend(self, query, key, value, attention_mask, seq_len,
+                kv_seq_len):
         seq_len = seq_len or self.sequence_length
         assert seq_len is not None, "sequence length required"
         if kv_seq_len is not None and kv_seq_len != seq_len:

@@ -10,7 +10,7 @@ import numpy as np
 
 from .base import BaseLayer, fresh_name
 from .. import initializers as init
-from ..graph.node import VariableOp
+from ..graph.node import VariableOp, scope
 from ..ops import (matmul_op, linear_op, broadcastto_op, conv2d_op,
                    conv2d_add_bias_op, conv2d_hwio_op,
                    conv2d_hwio_add_bias_op, conv2d_nhwc_op,
@@ -181,7 +181,8 @@ class Embedding(BaseLayer):
             initializer or init.normal(0.0, 0.01))
 
     def __call__(self, ids):
-        return embedding_lookup_op(self.weight, ids)
+        with scope("hetu_embed"):
+            return embedding_lookup_op(self.weight, ids)
 
 
 class DropOut(BaseLayer):

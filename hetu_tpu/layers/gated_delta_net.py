@@ -90,7 +90,6 @@ class _ScanOp(_Scoped):
     where there was a kernel to take (reason ``mesh``)."""
 
     def _compute(self, input_vals, ctx):
-        import jax
         from ..ops import gated_delta
         from ..ops.pallas import dispatch
         rule = None
@@ -98,8 +97,7 @@ class _ScanOp(_Scoped):
             rule = gated_delta.chunk_gated_delta_rule_jnp
             if dispatch.mosaic():
                 dispatch.record("gated_delta", "mesh")
-        with jax.named_scope(self.scope):
-            return self.fn(*input_vals, rule=rule, **self.attrs)
+        return self.fn(*input_vals, rule=rule, **self.attrs)
 
 
 def _out(o, z, w_norm, w_out, *, eps):

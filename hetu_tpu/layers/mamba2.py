@@ -71,7 +71,6 @@ class _ScanOp(_Scoped):
     a kernel to take (reason ``mesh``)."""
 
     def _compute(self, input_vals, ctx):
-        import jax
         from ..ops import ssd
         from ..ops.pallas import dispatch
         scan = None
@@ -79,8 +78,7 @@ class _ScanOp(_Scoped):
             scan = ssd.chunk_ssd_jnp
             if dispatch.mosaic():
                 dispatch.record("ssd", "mesh")
-        with jax.named_scope(self.scope):
-            return self.fn(*input_vals, scan=scan, **self.attrs)
+        return self.fn(*input_vals, scan=scan, **self.attrs)
 
 
 def _out(y, z, w_norm, w_out, *, groups, eps):

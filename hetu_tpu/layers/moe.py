@@ -18,7 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 from .base import BaseLayer, fresh_name
-from ..graph.node import Op, VariableOp
+from ..graph.node import Op, VariableOp, named_scope, scope
 from .. import initializers as init
 from ..ops.moe import (top_k_gating, hash_gating, ktop1_gating, sam_gating,
                        base_balance_gating, top_k_balance_aux,
@@ -254,13 +254,12 @@ class _MoEOp(Op):
         itself routed by.  Keyed by the identity of ``x``, so a node
         evaluated in another trace (a remat body, a second program) routes
         afresh."""
-        import jax
         x, wg = input_vals[0], self._unpack(input_vals)[6]
         memo = ctx.__dict__.setdefault("_moe_routing", {})
         if self.id not in memo or memo[self.id][0] is not x:
             extra = (() if self.bias_var is None
                      else (self._bias(input_vals, ctx),))
-            with jax.named_scope("hetu_moe_route"):
+            with named_scope("hetu_moe_route"):
                 memo[self.id] = (x, self.gate.route(
                     x.reshape(-1, x.shape[-1]), wg, self.k, *extra))
         return memo[self.id][1]
@@ -268,14 +267,13 @@ class _MoEOp(Op):
     def _move_bias(self, input_vals, idx, ctx):
         """``bias += rate * sign(mean(load) - load)`` from this step's pair
         counts over all experts, as a state update of the training step."""
-        import jax
         import jax.numpy as jnp
         from ..ops.moe import expert_load
         if (self.bias_var is None or not self.gate.bias_rate
                 or not ctx.training):
             return
         rate = self.gate.bias_rate
-        with jax.named_scope("hetu_moe_route"):
+        with named_scope("hetu_moe_route"):
             load = expert_load(idx, self.num_experts).astype(jnp.float32)
             bias = self._bias(input_vals, ctx).astype(jnp.float32)
             ctx.record_update(self.bias_var, bias + rate * jnp.sign(
@@ -630,38 +628,48 @@ class MoELayer(BaseLayer):
         if self.gate.wg is None and ids is None:
             raise ValueError(
                 "hash-gated MoELayer requires token ids: moe(x, ids=...)")
-        self.last_op = _MoEOp(x, self.gate, self.w1, self.b1, self.w2,
-                              self.b2, self.num_experts,
-                              self.capacity_factor, self.k,
-                              ep_axis=self.ep_axis, ids=ids,
-                              sparse=self.sparse, w3=self.w3,
-                              load_var=self.load_var, held=self.held)
-        if self.shared is not None:
-            return self.last_op + shared_expert_op(x, *self.shared,
-                                                   **self.shared_kind)
+        # what of the block lies outside its five regions (the reshapes,
+        # the load's counts, the loop of further passes, the sum with the
+        # shared expert) has a name of its own: the regions' names are what
+        # the moe_block metrics read, and hold what they held
+        with scope("hetu_moe_other"):
+            self.last_op = _MoEOp(x, self.gate, self.w1, self.b1, self.w2,
+                                  self.b2, self.num_experts,
+                                  self.capacity_factor, self.k,
+                                  ep_axis=self.ep_axis, ids=ids,
+                                  sparse=self.sparse, w3=self.w3,
+                                  load_var=self.load_var, held=self.held)
+            if self.shared is not None:
+                return self.last_op + shared_expert_op(x, *self.shared,
+                                                       **self.shared_kind)
         return self.last_op
 
     def aux_loss(self):
         assert self.last_op is not None
-        return MoEAuxLossOp(self.last_op)
+        with scope("hetu_loss"):
+            return MoEAuxLossOp(self.last_op)
 
     def z_loss(self):
         assert self.last_op is not None
-        return MoEZLossOp(self.last_op)
+        with scope("hetu_loss"):
+            return MoEZLossOp(self.last_op)
 
     def chosen(self):
         assert self.last_op is not None
-        return MoEChosenOp(self.last_op)
+        with scope("hetu_moe_other"):
+            return MoEChosenOp(self.last_op)
 
     def load(self):
         assert self.load_var is not None, "MoELayer(track_load=True)"
-        return MoELoadOp(self.load_var)
+        with scope("hetu_moe_other"):
+            return MoELoadOp(self.load_var)
 
     def router_bias(self):
         """``[E]`` f32: the router's selection bias after this step's move
         (``router_score="sigmoid"``)."""
         assert getattr(self.gate, "bias", None) is not None
-        return MoELoadOp(self.gate.bias)
+        with scope("hetu_moe_other"):
+            return MoELoadOp(self.gate.bias)
 
 
 def record_moe_load(layer, load, bias=None):

@@ -9,6 +9,7 @@ parallel/ strategies via dist_state annotations on the weight Variables.
 from __future__ import annotations
 
 from .base import BaseLayer, fresh_name
+from ..graph.node import scope
 from .common import Linear, LayerNorm
 from .attention import MultiHeadAttention
 from ..ops import gelu_op, dropout_op
@@ -26,11 +27,12 @@ class TransformerFFN(BaseLayer):
         self.dropout_rate = dropout_rate
 
     def __call__(self, x):
-        h = self.activation(self.dense1(x))
-        h = self.dense2(h)
-        if self.dropout_rate > 0:
-            h = dropout_op(h, keep_prob=1.0 - self.dropout_rate)
-        return h
+        with scope("hetu_mlp"):
+            h = self.activation(self.dense1(x))
+            h = self.dense2(h)
+            if self.dropout_rate > 0:
+                h = dropout_op(h, keep_prob=1.0 - self.dropout_rate)
+            return h
 
 
 class TransformerLayer(BaseLayer):
@@ -55,14 +57,24 @@ class TransformerLayer(BaseLayer):
         self.pre_norm = pre_norm
 
     def __call__(self, x, attention_mask=None, seq_len=None):
+        # norms and residual sums are the block `hetu_norm`; the sublayers
+        # name their own
         if self.pre_norm:
-            a_in = self.ln1(x)
+            with scope("hetu_norm"):
+                a_in = self.ln1(x)
             a = self.attn(a_in, a_in, a_in, attention_mask=attention_mask,
                           seq_len=seq_len)
-            x = x + a
-            return x + self.ffn(self.ln2(x))
+            with scope("hetu_norm"):
+                x = x + a
+                f_in = self.ln2(x)
+            f = self.ffn(f_in)
+            with scope("hetu_norm"):
+                return x + f
         else:
             a = self.attn(x, x, x, attention_mask=attention_mask,
                           seq_len=seq_len)
-            x = self.ln1(x + a)
-            return self.ln2(x + self.ffn(x))
+            with scope("hetu_norm"):
+                x = self.ln1(x + a)
+            f = self.ffn(x)
+            with scope("hetu_norm"):
+                return self.ln2(x + f)

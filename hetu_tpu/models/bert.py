@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..graph.node import Op, VariableOp, scoped_init
+from ..graph.node import Op, VariableOp, scope, scoped_init
 from .. import initializers as init
 from ..layers import (Linear, LayerNorm, Embedding, TransformerLayer,
                       fresh_name)
@@ -85,12 +85,13 @@ class BertEmbeddings:
         self.config = config
 
     def __call__(self, input_ids, token_type_ids):
-        x = self.word(input_ids) + self.token_type(token_type_ids)
-        x = x + PositionIdsOp(self.position, x, self.config.seq_len)
-        x = self.ln(x)
-        if self.dropout_keep < 1.0:
-            x = dropout_op(x, keep_prob=self.dropout_keep)
-        return x
+        with scope("hetu_embed"):
+            x = self.word(input_ids) + self.token_type(token_type_ids)
+            x = x + PositionIdsOp(self.position, x, self.config.seq_len)
+            x = self.ln(x)
+            if self.dropout_keep < 1.0:
+                x = dropout_op(x, keep_prob=self.dropout_keep)
+            return x
 
 
 class BertModel:
@@ -111,13 +112,15 @@ class BertModel:
                              name=f"{name}_pooler")
 
     def __call__(self, input_ids, token_type_ids, attention_mask=None):
-        mask = AttentionMaskOp(attention_mask) \
-            if attention_mask is not None else None
+        with scope("hetu_attn"):
+            mask = AttentionMaskOp(attention_mask) \
+                if attention_mask is not None else None
         x = self.embeddings(input_ids, token_type_ids)
         for layer in self.encoder:
             x = layer(x, attention_mask=mask, seq_len=self.config.seq_len)
         # pooled = tanh(W @ x[:, 0])
-        pooled = tanh_op(self.pooler(FirstTokenOp(x)))
+        with scope("hetu_head"):
+            pooled = tanh_op(self.pooler(FirstTokenOp(x)))
         return x, pooled
 
 
@@ -147,13 +150,15 @@ class BertForPreTraining:
 
     def __call__(self, input_ids, token_type_ids, attention_mask):
         seq, pooled = self.bert(input_ids, token_type_ids, attention_mask)
-        h = self.mlm_ln(gelu_op(self.mlm_transform(
-            array_reshape_op(seq, output_shape=(-1,
-                                                self.config.hidden_size)))))
+        with scope("hetu_head"):
+            flat = array_reshape_op(
+                seq, output_shape=(-1, self.config.hidden_size))
+            return self._mlm_logits(flat), self.nsp(pooled)
+
+    def _mlm_logits(self, h_in):
+        h = self.mlm_ln(gelu_op(self.mlm_transform(h_in)))
         logits = matmul_op(h, self.bert.embeddings.word.weight, trans_B=True)
-        logits = logits + broadcastto_op(self.mlm_bias, logits)
-        nsp_logits = self.nsp(pooled)
-        return logits, nsp_logits
+        return logits + broadcastto_op(self.mlm_bias, logits)
 
     def loss(self, input_ids, token_type_ids, attention_mask, mlm_labels,
              nsp_labels):
@@ -169,27 +174,34 @@ class BertForPreTraining:
         """
         c = self.config
         seq, pooled = self.bert(input_ids, token_type_ids, attention_mask)
-        flat = array_reshape_op(seq, output_shape=(-1, c.hidden_size))
         frac = c.mlm_bucket_frac
         n_tokens = None
         shape = getattr(mlm_labels, "shape", None)
         if frac is not None and shape is not None and shape[0] is not None:
             n_tokens = int(shape[0])
-        if n_tokens is not None:
-            bucket = min(n_tokens, -(-int(n_tokens * frac) // 128) * 128)
-            h_in = MaskedSelectOp(flat, mlm_labels, bucket=bucket)
-            labels_in = MaskedSelectLabelsOp(mlm_labels, bucket=bucket)
-        else:
-            h_in, labels_in = flat, mlm_labels
-        h = self.mlm_ln(gelu_op(self.mlm_transform(h_in)))
-        logits = matmul_op(h, self.bert.embeddings.word.weight, trans_B=True)
-        logits = logits + broadcastto_op(self.mlm_bias, logits)
-        ce = softmax_cross_entropy_sparse_op(logits, labels_in,
-                                             ignored_index=-1)
-        mlm_loss = MaskedMeanOp(ce, labels_in)
-        nsp_loss = reduce_mean_op(softmax_cross_entropy_sparse_op(
-            self.nsp(pooled), nsp_labels))
-        return mlm_loss + nsp_loss
+        with scope("hetu_head"):
+            flat = array_reshape_op(seq, output_shape=(-1, c.hidden_size))
+            if n_tokens is not None:
+                bucket = min(n_tokens, -(-int(n_tokens * frac) // 128) * 128)
+                h_in = MaskedSelectOp(flat, mlm_labels, bucket=bucket)
+                with scope("hetu_loss"):
+                    labels_in = MaskedSelectLabelsOp(mlm_labels,
+                                                     bucket=bucket)
+            else:
+                h_in, labels_in = flat, mlm_labels
+            logits = self._mlm_logits(h_in)
+        # (nodes are made in the order they always were: a node's id names
+        # it and seeds its dropout)
+        with scope("hetu_loss"):
+            ce = softmax_cross_entropy_sparse_op(logits, labels_in,
+                                                 ignored_index=-1)
+            mlm_loss = MaskedMeanOp(ce, labels_in)
+        with scope("hetu_head"):
+            nsp_logits = self.nsp(pooled)
+        with scope("hetu_loss"):
+            nsp_loss = reduce_mean_op(softmax_cross_entropy_sparse_op(
+                nsp_logits, nsp_labels))
+            return mlm_loss + nsp_loss
 
 
 class MaskedSelectOp(Op):
@@ -280,14 +292,16 @@ class BertForSequenceClassification:
 
     def __call__(self, input_ids, token_type_ids, attention_mask=None):
         _, pooled = self.bert(input_ids, token_type_ids, attention_mask)
-        if self.dropout_keep < 1.0:
-            pooled = dropout_op(pooled, self.dropout_keep)
-        return self.classifier(pooled)
+        with scope("hetu_head"):
+            if self.dropout_keep < 1.0:
+                pooled = dropout_op(pooled, self.dropout_keep)
+            return self.classifier(pooled)
 
     def loss(self, input_ids, token_type_ids, attention_mask, labels):
         logits = self(input_ids, token_type_ids, attention_mask)
-        return reduce_mean_op(
-            softmax_cross_entropy_sparse_op(logits, labels)), logits
+        with scope("hetu_loss"):
+            return reduce_mean_op(
+                softmax_cross_entropy_sparse_op(logits, labels)), logits
 
 
 class MaskedMeanOp(Op):

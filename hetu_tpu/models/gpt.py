@@ -12,7 +12,7 @@ from contextlib import nullcontext
 
 import numpy as np
 
-from ..graph.node import Op, VariableOp, stage, scoped_init
+from ..graph.node import Op, VariableOp, scope, stage, scoped_init
 from .. import initializers as init
 from ..layers import Embedding, LayerNorm, TransformerLayer
 from ..ops import (array_reshape_op, matmul_op, reduce_mean_op,
@@ -85,7 +85,7 @@ class GPTModel:
 
     def __call__(self, input_ids):
         c = self.config
-        with self._scope():
+        with self._scope(), scope("hetu_embed"):
             x = self.wte(input_ids)
             x = x + PositionIdsOp(self.wpe, x, c.seq_len)
             if c.dropout_prob > 0:
@@ -94,7 +94,7 @@ class GPTModel:
             with self._scope(i):
                 x = layer(x, seq_len=c.seq_len)
         with (stage(self.pipeline_stages - 1) if self.pipeline_stages
-              else nullcontext()):
+              else nullcontext()), scope("hetu_head"):
             return self.ln_f(x)
 
 
@@ -107,15 +107,17 @@ class GPTLMHeadModel:
 
     def __call__(self, input_ids):
         h = self.transformer(input_ids)
-        h = array_reshape_op(h,
-                             output_shape=(-1, self.config.hidden_size))
-        return matmul_op(h, self.transformer.wte.weight, trans_B=True)
+        with scope("hetu_head"):
+            h = array_reshape_op(h,
+                                 output_shape=(-1, self.config.hidden_size))
+            return matmul_op(h, self.transformer.wte.weight, trans_B=True)
 
     def loss(self, input_ids, labels):
         """labels: [B, S] next-token ids with -1 at padded positions."""
         logits = self(input_ids)
-        ce = softmax_cross_entropy_sparse_op(
-            logits, array_reshape_op(labels, output_shape=(-1,)),
-            ignored_index=-1)
-        return MaskedMeanOp(ce, array_reshape_op(labels,
-                                                 output_shape=(-1,)))
+        with scope("hetu_loss"):
+            ce = softmax_cross_entropy_sparse_op(
+                logits, array_reshape_op(labels, output_shape=(-1,)),
+                ignored_index=-1)
+            return MaskedMeanOp(ce, array_reshape_op(labels,
+                                                     output_shape=(-1,)))

@@ -19,7 +19,7 @@ from operator import add
 
 import numpy as np
 
-from ..graph.node import stage, scoped_init
+from ..graph.node import scope, stage, scoped_init
 from .. import initializers as init
 from ..layers import Embedding, Linear, RMSNorm
 from ..layers.base import BaseLayer
@@ -139,7 +139,8 @@ class LlamaMLP(BaseLayer):
                            name=f"{name}_out")
 
     def __call__(self, x):
-        return self.down(silu_op(self.gate(x)) * self.up(x))
+        with scope("hetu_mlp"):
+            return self.down(silu_op(self.gate(x)) * self.up(x))
 
 
 class LlamaDecoderLayer(BaseLayer):
@@ -170,9 +171,17 @@ class LlamaDecoderLayer(BaseLayer):
                                  name=f"{name}_post_norm")
 
     def __call__(self, x, seq_len=None):
-        a_in = self.input_norm(x)
-        x = x + self.attn(a_in, a_in, a_in, seq_len=seq_len)
-        return x + self.mlp(self.post_norm(x))
+        # norms and residual sums are the block `hetu_norm`; the sublayers
+        # name their own
+        with scope("hetu_norm"):
+            a_in = self.input_norm(x)
+        a = self.attn(a_in, a_in, a_in, seq_len=seq_len)
+        with scope("hetu_norm"):
+            x = x + a
+            m_in = self.post_norm(x)
+        m = self.mlp(m_in)
+        with scope("hetu_norm"):
+            return x + m
 
 
 class LlamaModel:
@@ -215,7 +224,7 @@ class LlamaModel:
             with self._scope(i):
                 x = layer(x, seq_len=self.config.seq_len)
         with (stage(self.pipeline_stages - 1) if self.pipeline_stages
-              else nullcontext()):
+              else nullcontext()), scope("hetu_head"):
             return self.norm(x)
 
 
@@ -238,10 +247,12 @@ class LlamaForCausalLM:
 
     def __call__(self, input_ids):
         h = self.model(input_ids)
-        h = array_reshape_op(h, output_shape=(-1, self.config.hidden_size))
-        if self.lm_head is None:
-            return matmul_op(h, self.model.embed.weight, trans_B=True)
-        return self.lm_head(h)
+        with scope("hetu_head"):
+            h = array_reshape_op(h,
+                                 output_shape=(-1, self.config.hidden_size))
+            if self.lm_head is None:
+                return matmul_op(h, self.model.embed.weight, trans_B=True)
+            return self.lm_head(h)
 
     def loss(self, input_ids, labels):
         """labels: [B, S] next-token ids with -1 at ignored positions
@@ -256,18 +267,20 @@ class LlamaForCausalLM:
         ``moe_z_coeff`` is set)."""
         c = self.config
         logits = self(input_ids)
-        flat = array_reshape_op(labels, output_shape=(-1,))
-        ce = softmax_cross_entropy_sparse_op(logits, flat, ignored_index=-1)
-        terms = {"ce": MaskedMeanOp(ce, flat)}
-        loss = terms["ce"]
-        mlps = self.moe_layers() if c.num_experts else []
-        if mlps:
-            terms["lbl"] = reduce(add, [m.aux_loss() for m in mlps])
-            loss = loss + c.moe_aux_coeff * terms["lbl"]
-            if c.moe_z_coeff:
-                terms["z"] = reduce(add, [m.z_loss() for m in mlps])
-                loss = loss + c.moe_z_coeff * terms["z"]
-        return loss, terms
+        with scope("hetu_loss"):
+            flat = array_reshape_op(labels, output_shape=(-1,))
+            ce = softmax_cross_entropy_sparse_op(logits, flat,
+                                                 ignored_index=-1)
+            terms = {"ce": MaskedMeanOp(ce, flat)}
+            loss = terms["ce"]
+            mlps = self.moe_layers() if c.num_experts else []
+            if mlps:
+                terms["lbl"] = reduce(add, [m.aux_loss() for m in mlps])
+                loss = loss + c.moe_aux_coeff * terms["lbl"]
+                if c.moe_z_coeff:
+                    terms["z"] = reduce(add, [m.z_loss() for m in mlps])
+                    loss = loss + c.moe_z_coeff * terms["z"]
+            return loss, terms
 
     def moe_layers(self):
         """The model's sparse expert layers, in order (a family whose blocks

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 from contextlib import nullcontext
 
-from ..graph.node import remat as remat_scope
+from ..graph.node import remat as remat_scope, scope
 from ..layers import RMSNorm
 from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
@@ -156,11 +156,15 @@ class NemotronHBlock(BaseLayer):
     def __call__(self, x, seq_len=None):
         # the norm is inside the recomputed group: what the backward pass
         # keeps of a recomputed mixer is the residual stream alone
+        # (the norm and the residual sum are the block `hetu_norm`; the
+        # sublayer names its own)
         with self._scope():
-            h = self.norm(x)
+            with scope("hetu_norm"):
+                h = self.norm(x)
             y = (self.mixer(h, h, h, seq_len=seq_len) if self.kind == "*"
                  else self.mixer(h))
-        return x + y
+        with scope("hetu_norm"):
+            return x + y
 
 
 class NemotronHModel(LlamaModel):

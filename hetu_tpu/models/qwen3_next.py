@@ -35,7 +35,7 @@ from __future__ import annotations
 
 from contextlib import nullcontext
 
-from ..graph.node import remat as remat_scope
+from ..graph.node import remat as remat_scope, scope
 from ..layers import RMSNorm
 from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
@@ -135,12 +135,19 @@ class Qwen3NextDecoderLayer(BaseLayer):
     def __call__(self, x, seq_len=None):
         # the norm is inside the recomputed group: what the backward pass
         # keeps of a recomputed mixer is the residual stream alone
+        # (norms and residual sums are the block `hetu_norm`; the sublayers
+        # name their own)
         with self._mixer_scope():
-            a_in = self.input_norm(x)
+            with scope("hetu_norm"):
+                a_in = self.input_norm(x)
             mixed = (self.attn(a_in, a_in, a_in, seq_len=seq_len)
                      if self.kind == "full_attention" else self.gdn(a_in))
-        x = x + mixed
-        return x + self.mlp(self.post_norm(x))
+        with scope("hetu_norm"):
+            x = x + mixed
+            m_in = self.post_norm(x)
+        m = self.mlp(m_in)
+        with scope("hetu_norm"):
+            return x + m
 
 
 class Qwen3NextModel(LlamaModel):

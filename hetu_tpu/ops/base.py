@@ -10,7 +10,7 @@ train/eval mode, or state updates subclass Op directly in their modules.
 
 from __future__ import annotations
 
-from ..graph.node import Op
+from ..graph.node import Op, scope as _scope
 
 
 class SimpleOp(Op):
@@ -29,19 +29,18 @@ class SimpleOp(Op):
 
 
 class ScopedOp(Op):
-    """``fn(*inputs, **attrs)`` under ``jax.named_scope(scope)``: a region
-    of the step that the device trace's readers find by its scope in the
-    compiled program's ``op_name`` (forward and backward)."""
+    """``fn(*inputs, **attrs)`` as one node of the block ``scope``, whatever
+    `ht.scope` is open around it: a region of the step that the device
+    trace's readers find by its scope in the compiled program's ``op_name``
+    (forward and backward; `evaluate` opens the scope)."""
 
     def __init__(self, fn, scope, *inputs, **attrs):
         super().__init__(*inputs, **attrs)
         self.name = f"{scope}_{self.id}"
-        self.fn, self.scope = fn, scope
+        self.fn, self.scope = fn, _scope(scope).name
 
     def _compute(self, input_vals, ctx):
-        import jax
-        with jax.named_scope(self.scope):
-            return self.fn(*input_vals, **self.attrs)
+        return self.fn(*input_vals, **self.attrs)
 
 
 def _peek_id():

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import simple_op
+from ..graph.node import named_scope
 
 
 def top_k_gating(logits, k, capacity, *, second_renorm=True,
@@ -801,14 +802,14 @@ def _held_pass(tokens, by_expert, gate, weights, offset, *, k, held, rows,
     counts.  A jitted function: a model's expert layers and every pass over
     their rows, forward and recomputed, are one trace."""
     T = tokens.shape[0]
-    with jax.named_scope("hetu_moe_dispatch"):
+    with named_scope("hetu_moe_dispatch"):
         lay = layout_window(by_expert, tile, held=held, rows=rows,
                             offset=offset)
         tok = jnp.where(lay["pair_of_slot"] >= 0, lay["pair_of_slot"] // k, T)
         xs = _tokens_to_rows(tokens, tok)
-    with jax.named_scope("hetu_moe_experts"):
+    with named_scope("hetu_moe_experts"):
         out = _experts(xs, lay, *weights, how, tile, held)
-    with jax.named_scope("hetu_moe_combine"):
+    with named_scope("hetu_moe_combine"):
         g = _pairs_to_rows(gate.reshape(-1), lay["pair_of_slot"],
                            lay["slot_of_pair"])
         weighted = (out.astype(jnp.float32) * g[:, None]).astype(tokens.dtype)
@@ -846,12 +847,12 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
         pairs = -(-pairs // tile) * tile       # the layout rounds up too
     how, tile = grouped_impl(pairs, E, H, F, tokens.dtype, mesh, impl, tile)
     if held is None:
-        with jax.named_scope("hetu_moe_dispatch"):
+        with named_scope("hetu_moe_dispatch"):
             lay = grouped_layout(idx, E, tile)
             xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
-        with jax.named_scope("hetu_moe_experts"):
+        with named_scope("hetu_moe_experts"):
             out = _experts(xs, lay, w_gate, w_up, w_down, how, tile, None)
-        with jax.named_scope("hetu_moe_combine"):
+        with named_scope("hetu_moe_combine"):
             by_pair = _rows_back(out, lay["pair_of_slot"],
                                  lay["slot_of_pair"])
             y = jnp.sum(by_pair.reshape(T, k, H).astype(jnp.float32)
@@ -861,7 +862,7 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
     one_pass = functools.partial(_held_pass, k=k, held=tuple(held),
                                  rows=rows, tile=tile, how=how)
     weights = (w_gate, w_up, w_down)
-    with jax.named_scope("hetu_moe_dispatch"):
+    with named_scope("hetu_moe_dispatch"):
         by_expert = sorted_pairs(idx, None, held)   # once for every window
     if rows is None:                   # rows for every pair: one window
         return one_pass(tokens, by_expert, gate, weights, jnp.int32(0))

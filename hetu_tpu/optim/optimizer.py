@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..graph.node import Op, VariableOp
+from ..graph.node import Op, VariableOp, scope
 from ..graph.autodiff import gradients
 from .lr_scheduler import as_schedule
 
@@ -270,6 +270,9 @@ class OptimizerOp(Op):
         extra = [n for _, sites in self.sparse
                  for g, ids in sites for n in (g, ids)]
         super().__init__(*grads, *extra, name=f"optimizer_{_opt_count()}")
+        # the clip, the update and the masters' write are one block, under
+        # whatever `ht.scope` the caller builds the optimizer
+        self.scope = scope("hetu_optim").name
         self.var_list = list(var_list)
         self.optimizer = optimizer
         self.clip_global_norm = clip_global_norm
