@@ -16,6 +16,7 @@ parallel/collectives.hierarchical_all_to_all for DCN×ICI topologies.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -623,10 +624,25 @@ _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 # here.  The two gathers above walk all T k pairs (their backward passes
 # read k rows a token), which for 32 of 512 experts is sixteen times the
 # rows there are.  The held layout goes between tokens and ROWS instead:
-# tokens -> rows is a gather of the rows' tokens, rows -> tokens a product
-# with the one-hot matrix of the rows' tokens (no scatter-add: 2 T M H
-# operations on the matrix unit, which the expert products leave idle).
-# Each is the other's backward pass.
+# tokens -> rows is a gather of the rows' tokens, rows -> tokens the sum of
+# each token's rows (no scatter-add).  Each is the other's backward pass.
+#
+# The sum has two forms.  ``_sum_rows`` without ``seg`` is a product with
+# the [T, M] one-hot matrix of the rows' tokens: 2 T M H operations, nearly
+# all of them by zero.  The device runs one operation at a time, so the
+# matrix unit is not "idle" for it: on a v5e the two products a layer were
+# 20 ms of Qwen3-Next's 274 ms step and 16.5 of Nemotron-H's 268 (ledger,
+# PR 35: ``fusion_bf16_8192_2048``, ``fusion_bf16_8192_2688``), more than
+# the experts' own products.  It stays as the ``jax.numpy`` form (the CPU, a
+# mesh, shapes the kernel does not take) and as the tests' oracle.  With
+# ``seg`` (``token_tiles``) the rows are gathered into token order and each
+# tile of tokens sums its own rows in ``hetu_moe_rows_sum``
+# (ops/pallas/moe_rows.py): the same bf16 rows, f32 sums and one rounding,
+# 2 M' tt H operations.  ``rows_impl`` says which runs.
+
+#: tokens of one tile of the segmented sum
+ROWS_TOKENS = 512
+
 
 def _hot(tok, T, dtype):
     """``[T, M]``: 1 where row ``s`` is token ``t``'s; a row of padding
@@ -635,7 +651,44 @@ def _hot(tok, T, dtype):
             ).astype(dtype)
 
 
-def _sum_rows(v, tok, T):
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class TokenTiles:
+    """The rows of a window in TOKEN order (``token_tiles``)."""
+    src: jax.Array
+    loc: jax.Array
+    tile_group: jax.Array
+    n_used: jax.Array
+    tt: int = dataclasses.field(metadata={"static": True})
+
+
+def token_tiles(tok, T, tt, tile):
+    """The rows of a window in TOKEN order, for the segmented sum: the layout
+    of ``grouped_layout`` with the tile of ``tt`` tokens a row belongs to in
+    the expert's place and a row of padding (``tok == T``) as a pair held
+    elsewhere.  ``M' = M + (T / tt) tile`` rows: ``src [M']`` the row in
+    each, ``loc [1, M']`` which of its tile's tokens that row is (``-1``:
+    none, a row of padding: ``src`` names row 0 there and the kernel adds
+    it to no token, where a gather that fills zeros in took twice the time
+    on a v5e), ``tile_group`` and ``n_used`` as ``tile_expert`` and
+    ``n_used`` there.  It depends on ``tok`` alone: one pass computes it
+    once for both sums, forward and backward."""
+    M, G = tok.shape[0], T // tt
+    lay = layout_window(sorted_pairs(tok // tt, None, (0, G)), tile,
+                        held=(0, G), rows=M)
+    row = lay["pair_of_slot"]
+    first = jnp.repeat(lay["tile_expert"], tile) * tt
+    src = jnp.maximum(row, 0)
+    loc = jnp.where(row >= 0, tok[src] - first, -1)
+    return TokenTiles(src, loc[None, :], lay["tile_expert"], lay["n_used"],
+                      tt)
+
+
+def _sum_rows(v, tok, T, seg=None):
+    if seg is not None:
+        from .pallas.moe_rows import rows_sum
+        return rows_sum(jnp.take(v, seg.src, axis=0, mode="clip"), seg.loc,
+                        seg.tile_group, seg.n_used, tokens=T, tt=seg.tt)
     full = jax.lax.Precision.HIGHEST if v.dtype == jnp.float32 else None
     return jnp.matmul(_hot(tok, T, v.dtype), v, precision=full,
                       preferred_element_type=jnp.float32).astype(v.dtype)
@@ -646,25 +699,27 @@ def _take_rows(x, tok):
 
 
 @jax.custom_vjp
-def _tokens_to_rows(tokens, tok):
-    """``xs[s] = tokens[tok[s]]`` (zeros where ``tok[s] == T``)."""
+def _tokens_to_rows(tokens, tok, seg):
+    """``xs[s] = tokens[tok[s]]`` (zeros where ``tok[s] == T``); ``seg``
+    (``token_tiles``) is for the backward pass, the sum of a token's rows."""
     return _take_rows(tokens, tok)
 
 
 _tokens_to_rows.defvjp(
-    lambda tokens, tok: (_take_rows(tokens, tok), (tok, tokens.shape[0])),
-    lambda res, d_xs: (_sum_rows(d_xs, *res), None))
+    lambda tokens, tok, seg: (_take_rows(tokens, tok),
+                              (tok, tokens.shape[0], seg)),
+    lambda res, d_xs: (_sum_rows(d_xs, *res), None, None))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rows_to_tokens(v, tok, T):
+def _rows_to_tokens(v, tok, T, seg):
     """``y[t] = sum of v[s] over the rows s of token t``."""
-    return _sum_rows(v, tok, T)
+    return _sum_rows(v, tok, T, seg)
 
 
 _rows_to_tokens.defvjp(
-    lambda v, tok, T: (_sum_rows(v, tok, T), tok),
-    lambda T, tok, dy: (_take_rows(dy, tok), None))
+    lambda v, tok, T, seg: (_sum_rows(v, tok, T, seg), tok),
+    lambda T, tok, dy: (_take_rows(dy, tok), None, None))
 
 
 @jax.custom_vjp
@@ -723,6 +778,27 @@ def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
         why = moe_gmm.unsupported(pairs, hidden, inter, tile, dtype)
     return ("pallas", tile) if dispatch.record("moe_gmm", why) \
         else ("ragged", None)
+
+
+def rows_impl(how, tile, mesh, tokens, hidden, dtype):
+    """The tokens of one tile of the segmented sum that takes a held pass's
+    rows to tokens (``token_tiles``, ``hetu_moe_rows_sum``), or ``None`` for
+    the one-hot product (``_sum_rows``), from what the grouped products
+    themselves are (``how``, ``tile`` of ``grouped_impl``); recorded in
+    ``dispatch.choices()`` under ``moe_rows``.  Nothing is recorded on a
+    platform without Mosaic unless the caller asked for the kernels
+    (``impl="pallas"``: interpret mode): there is no choice to record."""
+    from .pallas import dispatch, moe_rows
+    if how != "pallas" and not dispatch.mosaic():
+        return None
+    tt = ROWS_TOKENS if tokens >= ROWS_TOKENS else 8
+    if mesh is not None:
+        why = "mesh"            # pallas_call does not partition under GSPMD
+    elif how != "pallas":
+        why = f"moe_gmm:{how}"  # the products' own reason is moe_gmm's
+    else:
+        why = moe_rows.unsupported(tokens, hidden, tt, tile, dtype)
+    return tt if dispatch.record("moe_rows", why) else None
 
 
 def _every_window(one_pass, step, tokens, idx, gate, weights):
@@ -794,26 +870,29 @@ def _experts(xs, lay, w_gate, w_up, w_down, how, tile, held):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "held", "rows", "tile",
-                                             "how"))
+                                             "how", "tt"))
 def _held_pass(tokens, by_expert, gate, weights, offset, *, k, held, rows,
-               tile, how):
+               tile, how, tt):
     """The held experts' part of ``y`` from the pairs in the window of rows
     at ``offset`` (``layout_window`` of ``by_expert``), and that window's
-    counts.  A jitted function: a model's expert layers and every pass over
-    their rows, forward and recomputed, are one trace."""
+    counts; ``tt`` (``rows_impl``): the rows go back to tokens by the
+    segmented sum over tiles of ``tt`` tokens.  A jitted function: a model's
+    expert layers and every pass over their rows, forward and recomputed,
+    are one trace."""
     T = tokens.shape[0]
     with named_scope("hetu_moe_dispatch"):
         lay = layout_window(by_expert, tile, held=held, rows=rows,
                             offset=offset)
         tok = jnp.where(lay["pair_of_slot"] >= 0, lay["pair_of_slot"] // k, T)
-        xs = _tokens_to_rows(tokens, tok)
+        seg = None if tt is None else token_tiles(tok, T, tt, tile)
+        xs = _tokens_to_rows(tokens, tok, seg)
     with named_scope("hetu_moe_experts"):
         out = _experts(xs, lay, *weights, how, tile, held)
     with named_scope("hetu_moe_combine"):
         g = _pairs_to_rows(gate.reshape(-1), lay["pair_of_slot"],
                            lay["slot_of_pair"])
         weighted = (out.astype(jnp.float32) * g[:, None]).astype(tokens.dtype)
-        return _rows_to_tokens(weighted, tok, T), dict(
+        return _rows_to_tokens(weighted, tok, T, seg), dict(
             {n: lay[n] for n in ("load", "kept", "elsewhere", "total")},
             computed=lay["kept"])
 
@@ -859,8 +938,9 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
                         * gate[:, :, None], axis=1).astype(tokens.dtype)
         return y, lay["load"]
 
-    one_pass = functools.partial(_held_pass, k=k, held=tuple(held),
-                                 rows=rows, tile=tile, how=how)
+    one_pass = functools.partial(
+        _held_pass, k=k, held=tuple(held), rows=rows, tile=tile, how=how,
+        tt=rows_impl(how, tile, mesh, T, H, tokens.dtype))
     weights = (w_gate, w_up, w_down)
     with named_scope("hetu_moe_dispatch"):
         by_expert = sorted_pairs(idx, None, held)   # once for every window

@@ -28,7 +28,8 @@ KERNELS = ("hetu_dropout_mask", "hetu_flash_fwd", "hetu_flash_bwd",
            "hetu_gdn_fwd", "hetu_gdn_bwd", "hetu_moe_row_gather",
            "hetu_moe_gmm_fwd", "hetu_moe_gmm_dw", "hetu_moe_gmm_dx",
            "hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd",
-           "hetu_packed_embedding_write", "hetu_ssd_fwd", "hetu_ssd_bwd")
+           "hetu_packed_embedding_write", "hetu_ssd_fwd", "hetu_ssd_bwd",
+           "hetu_moe_rows_sum")
 BLOCKS = ("hetu_attn", "hetu_mlp", "hetu_embed", "hetu_head", "hetu_loss",
           "hetu_optim", "hetu_param_cast", "hetu_norm", "hetu_moe_other",
           "hetu_moe_route", "hetu_moe_dispatch", "hetu_moe_experts",

@@ -418,3 +418,202 @@ def test_relu2_and_sigmoid_run_on_the_dropless_path_alone():
     with pytest.raises(AssertionError, match="dropless"):
         MoELayer(H, F, E, k=K, expert_act="swiglu", router_score="sigmoid",
                  name="bad2")
+
+
+# -- rows to tokens: the sum over token tiles against the one-hot product -----
+
+def window_of_rows(case, T=64, M=96, k=6):
+    """``tok [M]`` of a held window's rows (``T``: a row of padding)."""
+    r = np.random.default_rng(7)
+    if case == "padding":              # what a layout gives: most rows none
+        tok = np.where(r.random(M) < 0.4, r.integers(0, T, M), T)
+    elif case == "empty_tile":         # the tokens 16..31 own no row
+        tok = r.integers(0, T - 16, M)
+        tok = np.where(tok >= 16, tok + 16, tok)
+    elif case == "k_rows":             # token 5 owns k rows, others one
+        tok = np.full(M, T)
+        at = r.permutation(M)
+        tok[at[:k]] = 5
+        tok[at[k:k + 40]] = r.permutation(np.delete(np.arange(T), 5))[:40]
+    elif case == "one_tile":           # every row in the tile 8..15
+        tok = r.integers(8, 16, M)
+    return jnp.asarray(tok, jnp.int32)
+
+
+def assert_rounded_alike(got, want, dtype):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=2e-6)
+    else:       # one unit in the last place of bf16: 8 bits of significand
+        assert (np.abs(got - want)
+                <= 2.0 ** -7 * np.maximum(np.abs(want), 2.0 ** -126)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("H", [256, 384])
+@pytest.mark.parametrize("case", ["padding", "empty_tile", "k_rows",
+                                  "one_tile"])
+def test_segmented_sum_is_the_one_hot_products(case, H, dtype):
+    """``_sum_rows`` over token tiles (``token_tiles``, ``hetu_moe_rows_sum``
+    in interpret mode) against the ``[T, M]`` product: the values, the sum as
+    the backward pass of ``_tokens_to_rows``, and ``_rows_to_tokens``'s own
+    backward pass, which is the gather it was."""
+    T, tt, tile = 64, 8, 8
+    tok = window_of_rows(case, T)
+    r = np.random.default_rng(3)
+    v = jnp.asarray(r.normal(size=(tok.shape[0], H)), dtype)
+    seg = jax.jit(lambda t: moe_ops.token_tiles(t, T, tt, tile))(tok)
+    assert seg.src.shape[0] == tok.shape[0] + T // tt * tile
+    assert int((seg.loc >= 0).sum()) == int((tok < T).sum())
+    want = moe_ops._sum_rows(v, tok, T)
+    assert_rounded_alike(moe_ops._sum_rows(v, tok, T, seg), want, dtype)
+    # H in blocks of 128: two and three programs a row tile
+    from hetu_tpu.ops.pallas.moe_rows import rows_sum
+    blocks = rows_sum(jnp.take(v, seg.src, axis=0), seg.loc, seg.tile_group,
+                      seg.n_used, tokens=T, tt=tt, th=128)
+    assert_rounded_alike(blocks, want, dtype)
+    tokens = jnp.asarray(r.normal(size=(T, H)), dtype)
+    xs, pull = jax.vjp(lambda x: moe_ops._tokens_to_rows(x, tok, seg), tokens)
+    np.testing.assert_array_equal(xs, moe_ops._take_rows(tokens, tok))
+    assert_rounded_alike(pull(v)[0], want, dtype)
+    dy = jnp.asarray(r.normal(size=(T, H)), dtype)
+    y, back = jax.vjp(lambda a: moe_ops._rows_to_tokens(a, tok, T, seg), v)
+    assert_rounded_alike(y, want, dtype)
+    np.testing.assert_array_equal(back(dy)[0], moe_ops._take_rows(dy, tok))
+
+
+def test_segmented_sum_of_a_window_past_the_first():
+    """The rows of the layout's second window, its ``offset`` traced: the
+    token tiles are laid out from that window's ``tok`` and the sum is the
+    product's."""
+    T, k, tile, held, rows = 64, 4, 8, (3, 6), 16
+    idx = jnp.asarray(np.random.default_rng(2).integers(0, 16, (T, k)),
+                      jnp.int32)
+    first = moe_ops.grouped_layout(idx, None, tile, held=held, rows=rows)
+    M = first["rows"]
+    assert int(first["total"]) > M
+    v = jnp.asarray(np.random.default_rng(5).normal(size=(M, 256)),
+                    jnp.float32)
+
+    @jax.jit
+    def both(offset):
+        lay = moe_ops.grouped_layout(idx, None, tile, held=held, rows=rows,
+                                     offset=offset)
+        tok = jnp.where(lay["pair_of_slot"] >= 0, lay["pair_of_slot"] // k, T)
+        seg = moe_ops.token_tiles(tok, T, 8, tile)
+        return (tok, moe_ops._sum_rows(v, tok, T, seg),
+                moe_ops._sum_rows(v, tok, T))
+    tok0, _, _ = both(0)
+    tok1, got, want = both(M)
+    assert (np.asarray(tok1) < T).any() and (np.asarray(tok1)
+                                             != np.asarray(tok0)).any()
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+# -- which form runs, and that it is counted ----------------------------------
+
+def rows_choices():
+    from hetu_tpu.ops.pallas import dispatch
+    return {k: n for k, n in dispatch.choices().items() if k[0] == "moe_rows"}
+
+
+@pytest.mark.parametrize("platform,args,tt,counted", [
+    ("tpu", ("pallas", 128, None, 8192, 2048, jnp.bfloat16), 512,
+     ("pallas", "")),
+    ("tpu", ("pallas", 128, None, 8192, 2688, jnp.float32), 512,
+     ("pallas", "")),
+    ("tpu", ("ragged", None, object(), 8192, 2048, jnp.bfloat16), None,
+     ("jnp", "mesh")),
+    ("tpu", ("ragged", None, None, 8192, 2048, jnp.bfloat16), None,
+     ("jnp", "moe_gmm:ragged")),
+    ("tpu", ("pallas", 128, None, 8192 + 256, 2048, jnp.bfloat16), None,
+     ("jnp", "tokens_not_tile_aligned:8448%512")),
+    ("tpu", ("pallas", 128, None, 8192, 2000, jnp.bfloat16), None,
+     ("jnp", "dims_not_128_aligned")),
+    ("tpu", ("pallas", 8, None, 8192, 2048, jnp.bfloat16), None,
+     ("jnp", "dims_not_128_aligned")),
+    ("tpu", ("pallas", 128, None, 8192, 2048, jnp.float16), None,
+     ("jnp", "dtype:float16")),
+    ("cpu", ("pallas", 8, None, 64, 32, jnp.float32), 8, ("pallas", "")),
+    ("cpu", ("pallas", 8, None, 60, 32, jnp.float32), None,
+     ("jnp", "tokens_not_tile_aligned:60%8")),
+    ("cpu", ("ragged", None, None, 8192, 2048, jnp.bfloat16), None, None),
+    ("cpu", ("ragged", None, object(), 8192, 2048, jnp.bfloat16), None, None),
+])
+def test_rows_impl_counts_its_choice(live_registry, monkeypatch, platform,
+                                     args, tt, counted):
+    """``hetu_kernel_choice_total{kernel="moe_rows"}``: ``pallas``, each
+    ``jnp`` reason from a shape that causes it, and nothing on a platform
+    without Mosaic unless the caller asked for the kernels."""
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(dispatch, "platform", lambda: platform)
+    before = rows_choices()
+    assert moe_ops.rows_impl(*args) == tt
+    after = rows_choices()
+    grew = {k[1:]: n - before.get(k, 0) for k, n in after.items()
+            if n != before.get(k, 0)}
+    assert grew == ({counted: 1} if counted else {})
+
+
+@pytest.mark.parametrize("impl,counted", [("pallas", 1), (None, 0),
+                                          ("ragged", 0)])
+def test_a_held_layer_counts_its_rows_choice_once_a_call(live_registry, impl,
+                                                         counted):
+    key = ("moe_rows", "pallas", "")
+    before = rows_choices()
+    args = held_inputs(seed=1)
+    y, _ = held_op(3, (0, 4), impl)(*args)
+    np.testing.assert_allclose(y, dense_share(*args, 3, (0, 4)), atol=2e-6)
+    after = rows_choices()
+    assert after.get(key, 0) - before.get(key, 0) == counted
+    assert {k for k in after if k != key} == {k for k in before if k != key}
+
+
+def test_the_kernel_stands_in_a_held_layers_step_and_in_no_other(
+        live_registry, monkeypatch):
+    """The platform read as ``tpu``: a held layer at aligned sizes lowers to
+    ``hetu_moe_rows_sum`` forward and backward and counts ``pallas``; a layer
+    that holds every expert (the OLMoE builder's toy step, ``held=None``)
+    records no ``moe_rows`` choice and lowers to no such kernel."""
+    import importlib
+    from chipbench import run
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    jax.clear_caches()                  # traces made in interpret mode
+    try:
+        before = rows_choices()
+        shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+            (512, 128), (128, 16), (16, 128, 128), (16, 128, 128),
+            (16, 128, 128))]
+        held, rows = (0, 8), moe_ops.held_rows(512 * 4, 16, 8)
+        text = jax.jit(jax.grad(lambda *a: jnp.sum(
+            held_op(4, held, None, rows)(*a)[0].astype(jnp.float32)),
+            argnums=(0, 2))).trace(*shapes).lower(
+                lowering_platforms=("tpu",)).as_text()
+        assert text.count("hetu_moe_rows_sum") >= 2
+        assert "hetu_moe_gmm_fwd" in text
+        grew = {k: n - before.get(k, 0) for k, n in rows_choices().items()
+                if n != before.get(k, 0)}
+        assert grew == {("moe_rows", "pallas", ""): 1}
+
+        before = rows_choices()
+        _, _, config, mix = run.load_cell("olmoe-1b-7b.b2-s4096")
+        config, mix = run.merge(config, config["toy"]), run.merge(mix,
+                                                                  mix["toy"])
+        builder = importlib.import_module("chipbench.builders."
+                                          + config["builder"])
+        with ht.name_scope():
+            prog = builder.build(config, mix, 2 ** 31 + 7, lambda msg: None)
+        try:
+            assert all(m.held is None for m in prog.model.moe_layers())
+            sub = prog.ex.subexecutor["train"]
+            if sub._jitted is None:
+                sub._build()
+            text = sub._jitted.trace(*sub._abstract_args(None)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        finally:
+            prog.close()
+        assert "hetu_moe_rows_sum" not in text
+        assert rows_choices() == before
+    finally:
+        jax.clear_caches()
