@@ -44,14 +44,17 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              Qwen3NextConfig, Qwen3NextForCausalLM,
                              QWEN3_NEXT_CONFIGS, NemotronHConfig,
                              NemotronHForCausalLM, NEMOTRON_H_CONFIGS,
-                             load_hf_llama_weights)
+                             GraniteHybridConfig, GraniteHybridForCausalLM,
+                             GRANITE_HYBRID_CONFIGS, load_hf_llama_weights,
+                             load_hf_granite_hybrid_weights)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="llama-7b",
                     choices=(list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS)
-                             + list(NEMOTRON_H_CONFIGS)))
+                             + list(NEMOTRON_H_CONFIGS)
+                             + list(GRANITE_HYBRID_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -84,6 +87,10 @@ def main():
               (NEMOTRON_H_CONFIGS, NemotronHConfig, NemotronHForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in NEMOTRON_H_CONFIGS else
+              (GRANITE_HYBRID_CONFIGS, GraniteHybridConfig,
+               GraniteHybridForCausalLM, "num_hidden_layers",
+               "shared_intermediate_size")
+              if args.model in GRANITE_HYBRID_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
@@ -93,6 +100,9 @@ def main():
         # the pattern's first
         from hetu_tpu.models.nemotron_h import PATTERN
         base["hybrid_override_pattern"] = PATTERN[:args.layers]
+    if args.layers and args.model in GRANITE_HYBRID_CONFIGS:
+        from hetu_tpu.models.granite_hybrid import LAYER_TYPES
+        base["layer_types"] = LAYER_TYPES[:args.layers]
     for field, val in ((depth, args.layers), ("hidden_size", args.hidden),
                        (width, args.intermediate),
                        ("vocab_size", args.vocab)):
@@ -131,7 +141,9 @@ def main():
         import transformers
         hf = transformers.AutoModelForCausalLM.from_pretrained(
             args.hf_import)
-        load_hf_llama_weights(ex, model, hf.state_dict())
+        (load_hf_granite_hybrid_weights
+         if args.model in GRANITE_HYBRID_CONFIGS
+         else load_hf_llama_weights)(ex, model, hf.state_dict())
         print(f"imported weights from {args.hf_import}")
 
     for step in range(args.steps):

@@ -20,7 +20,9 @@ query and key (Qwen3, Qwen3-Next).  ``head_dim`` sets the head size apart
 from ``hidden_size // num_heads`` (Qwen3-Next: 16 heads of 256 on a hidden
 size of 2,048), ``rotary_dim`` rotates only the first dimensions of a head,
 ``output_gate`` doubles the query projection and multiplies the context by
-the sigmoid of its second half, head by head.
+the sigmoid of its second half, head by head.  ``scale`` is what the scores
+are multiplied by before the softmax where it is not ``head_dim ** -0.5``
+(Granite 4.0: ``attention_multiplier`` 1/64 on heads of 64).
 """
 
 from __future__ import annotations
@@ -40,7 +42,8 @@ class MultiHeadAttention(BaseLayer):
                  rope_theta=None, alibi=False, bias=True,
                  fused_head_projection=False, qk_norm=False,
                  qk_norm_eps=1e-5, head_dim=None, rotary_dim=None,
-                 output_gate=False, qk_norm_zero_centered=False, name=None):
+                 output_gate=False, qk_norm_zero_centered=False, scale=None,
+                 name=None):
         assert head_dim is not None or hidden_size % num_heads == 0
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
@@ -53,6 +56,7 @@ class MultiHeadAttention(BaseLayer):
         self.inner = self.num_heads * self.head_dim
         self.rotary_dim = rotary_dim
         self.output_gate = output_gate
+        self.scale = scale
         self.sequence_length = sequence_length
         self.dropout_keep = 1.0 - dropout_rate
         self.causal = causal_mask
@@ -157,7 +161,8 @@ class MultiHeadAttention(BaseLayer):
                                 (v, kv_seq_len)))
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
-            dropout_keep=self.dropout_keep, num_heads=self.num_heads)
+            scale=self.scale, dropout_keep=self.dropout_keep,
+            num_heads=self.num_heads)
         return self.out_proj(ctx_)
 
     def _rotate(self, x, seq_len):
@@ -206,7 +211,7 @@ class MultiHeadAttention(BaseLayer):
                               else attention_mask + bias)
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
-            dropout_keep=self.dropout_keep)
+            scale=self.scale, dropout_keep=self.dropout_keep)
         ctx_ = transpose_op(ctx_, perm=(0, 2, 1, 3))
         ctx_ = array_reshape_op(ctx_,
                                 output_shape=(-1, seq_len, self.inner))

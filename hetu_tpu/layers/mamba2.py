@@ -1,5 +1,8 @@
 """Mamba-2 mixer: the state-space layer of Nemotron-H (the ``M`` blocks; HF
-``NemotronHMamba2Mixer``).  ``H`` heads of ``P`` channels (``d = H P``),
+``NemotronHMamba2Mixer``: 64 heads in 8 groups) and of Granite 4.0-H (the
+``mamba`` layers; HF ``GraniteMoeHybridMambaLayer``: 64 heads in ONE group, so
+every head reads the same ``B`` and ``C`` and the gated norm below runs over
+all ``d`` = 4,096 channels).  ``H`` heads of ``P`` channels (``d = H P``),
 ``G`` groups, state size ``N``:
 
     [z | xBC | dt] = x W_in      widths d, d + 2 G N, H; no bias
@@ -20,7 +23,8 @@ input projection and its three parts), ``hetu_ssm_conv``, ``hetu_ssm_scan``
 (the gates, the chunked scan and the skip) and ``hetu_ssm_out`` (the gate,
 the grouped norm and the output projection).  The scan is
 ``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
-``hetu_ssd_bwd`` where their rule takes the operands, under a mesh and on
+``hetu_ssd_bwd`` where their rule takes the operands (a group of more than
+eight heads as blocks of heads, ``ops/pallas/ssd.py``), under a mesh and on
 any other platform the ``jax.numpy`` form (``_ScanOp``); the softplus,
 ``-exp(A_log)`` and the skip stay XLA's under the same scope.  A decode step,
 and the state ``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a

@@ -17,6 +17,17 @@ import numpy as np
 import jax.numpy as jnp
 
 
+def _numpy_state(state_dict):
+    """A transformers state_dict as numpy arrays (torch tensors or arrays
+    in), without the ``model.`` prefix where the keys have it."""
+    sd = {}
+    for k, v in state_dict.items():
+        v = v.detach().cpu().numpy() if hasattr(v, "detach") else \
+            np.asarray(v)
+        sd[k[6:] if k.startswith("model.") else k] = v
+    return sd
+
+
 def _put(params, name, value):
     if name not in params:
         raise KeyError(f"no variable {name!r} in executor params")
@@ -128,11 +139,7 @@ def load_hf_llama_weights(executor, model, state_dict, name="llama"):
     rotary op follows HF's rotate_half convention, so q/k come over
     unpermuted.
     """
-    sd = {}
-    for k, v in state_dict.items():
-        v = v.detach().cpu().numpy() if hasattr(v, "detach") else \
-            np.asarray(v)
-        sd[k[6:] if k.startswith("model.") else k] = v
+    sd = _numpy_state(state_dict)
     p = executor.params
     cfg = model.config
     _put(p, f"{name}_embed_table", sd["embed_tokens.weight"])
@@ -169,6 +176,54 @@ def load_hf_llama_weights(executor, model, state_dict, name="llama"):
             "checkpoint has an untied lm_head.weight but the model was "
             "built with tie_embeddings=True — its logits would silently "
             "diverge; rebuild with tie_embeddings=False")
+    return executor
+
+
+def load_hf_granite_hybrid_weights(executor, model, state_dict,
+                                   name="granite"):
+    """Copy a transformers ``granitemoehybrid`` state_dict (dense:
+    ``num_local_experts`` 0) into a ``GraniteHybridForCausalLM``.
+
+    A Mamba layer's ``mamba.in_proj`` ``[z | xBC | dt]`` and ``out_proj``
+    come over transposed, ``mamba.conv1d.weight`` ``[C, 1, K]`` as ``[K,
+    C]``, ``dt_bias``, ``A_log``, ``D`` and the gated norm's weight as they
+    are; an attention layer's four projections transposed; every layer's
+    fused ``shared_mlp.input_linear`` ``[2 I, H]`` is cut into the gate and
+    the up matrix (HF chunks its output in that order).  The head is tied.
+    Accepts state_dicts with or without the ``model.`` prefix; where the
+    model holds a slice of the vocabulary, the first rows of the table."""
+    sd = _numpy_state(state_dict)
+    p = executor.params
+    cfg = model.config
+    _put(p, f"{name}_embed_table",
+         sd["embed_tokens.weight"][:cfg.vocab_size])
+    for i, kind in enumerate(cfg.layer_types):
+        hf = f"layers.{i}."
+        our = f"{name}_layer{i}"
+        if kind == "mamba":
+            m = hf + "mamba."
+            _put(p, f"{our}_mamba_in_weight", sd[m + "in_proj.weight"].T)
+            _put(p, f"{our}_mamba_conv_weight",
+                 sd[m + "conv1d.weight"][:, 0, :].T)
+            _put(p, f"{our}_mamba_conv_bias", sd[m + "conv1d.bias"])
+            for ours, theirs in (("dt_bias", "dt_bias"), ("a_log", "A_log"),
+                                 ("d", "D"), ("norm_scale", "norm.weight")):
+                _put(p, f"{our}_mamba_{ours}", sd[m + theirs])
+            _put(p, f"{our}_mamba_out_weight", sd[m + "out_proj.weight"].T)
+        else:
+            for proj, hname in (("q", "q_proj"), ("k", "k_proj"),
+                                ("v", "v_proj"), ("out", "o_proj")):
+                _put(p, f"{our}_attn_{proj}_weight",
+                     sd[hf + f"self_attn.{hname}.weight"].T)
+        gate, up = np.split(sd[hf + "shared_mlp.input_linear.weight"], 2)
+        _put(p, f"{our}_mlp_gate_weight", gate.T)
+        _put(p, f"{our}_mlp_up_weight", up.T)
+        _put(p, f"{our}_mlp_out_weight",
+             sd[hf + "shared_mlp.output_linear.weight"].T)
+        _put(p, f"{our}_input_norm_scale", sd[hf + "input_layernorm.weight"])
+        _put(p, f"{our}_post_norm_scale",
+             sd[hf + "post_attention_layernorm.weight"])
+    _put(p, f"{name}_norm_scale", sd["norm.weight"])
     return executor
 
 
@@ -215,11 +270,7 @@ def load_hf_mixtral_weights(executor, model, state_dict, name="llama"):
     the MoELayer's [E, H, F]/[E, F, H] tensors.  Gating math matches:
     top-2 renormalization of full-softmax probs equals Mixtral's softmax
     over the top-2 logits, and capacity_factor >= E/k drops nothing."""
-    sd = {}
-    for k, v in state_dict.items():
-        v = v.detach().cpu().numpy() if hasattr(v, "detach") else \
-            np.asarray(v)
-        sd[k[6:] if k.startswith("model.") else k] = v
+    sd = _numpy_state(state_dict)
     p = executor.params
     cfg = model.config
     E = cfg.num_experts

@@ -217,9 +217,14 @@ class LlamaModel:
                 return stage(s)
         return stage(S - 1)
 
+    def _embed(self, input_ids):
+        """The hidden states the first layer reads; a family that scales its
+        embeddings overrides it."""
+        return self.embed(input_ids)
+
     def __call__(self, input_ids):
         with self._scope():
-            x = self.embed(input_ids)
+            x = self._embed(input_ids)
         for i, layer in enumerate(self.layers):
             with self._scope(i):
                 x = layer(x, seq_len=self.config.seq_len)
@@ -259,14 +264,17 @@ class LlamaForCausalLM:
         (caller shifts, matching GPTLMHeadModel's convention)."""
         return self.loss_terms(input_ids, labels)[0]
 
-    def loss_terms(self, input_ids, labels):
+    def loss_terms(self, input_ids, labels, logits=None):
         """``(loss, {"ce": ..., "lbl": ..., "z": ...})``: the training loss
         and the nodes it is the weighted sum of: the mean cross-entropy
         over labelled positions and, for an MoE model, the balance loss and
         the router z-loss, each summed over layers (the z term only where
-        ``moe_z_coeff`` is set)."""
+        ``moe_z_coeff`` is set).  ``logits``: this model's output on
+        ``input_ids`` where the caller holds it already (to fetch it beside
+        the loss without a second forward graph)."""
         c = self.config
-        logits = self(input_ids)
+        if logits is None:
+            logits = self(input_ids)
         with scope("hetu_loss"):
             flat = array_reshape_op(labels, output_shape=(-1,))
             ce = softmax_cross_entropy_sparse_op(logits, flat,

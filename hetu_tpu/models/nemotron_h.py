@@ -115,6 +115,17 @@ NEMOTRON_H_CONFIGS = {
 }
 
 
+def normed_mixer(norm, mixer, x, recompute, attention=False, seq_len=None):
+    """``mixer(norm(x))``, both inside one recomputed group where
+    ``recompute``: what the backward pass keeps of a recomputed mixer is the
+    residual stream alone.  The norm stands under the block `hetu_norm`;
+    the mixer names its own.  (Shared with ``granite_hybrid.py``.)"""
+    with (remat_scope() if recompute else nullcontext()):
+        with scope("hetu_norm"):
+            h = norm(x)
+        return mixer(h, h, h, seq_len=seq_len) if attention else mixer(h)
+
+
 class NemotronHBlock(BaseLayer):
     """One sublayer behind one norm and one residual.  An expert block's
     layer is ``mlp`` (what the loss terms and the load read)."""
@@ -150,19 +161,13 @@ class NemotronHBlock(BaseLayer):
                 router_score="sigmoid", router_scale=c.routed_scaling_factor,
                 router_bias_rate=c.router_bias_update_rate,
                 name=f"{name}_moe")
-        recompute = c.remat == "mamba" and kind == "M"
-        self._scope = remat_scope if recompute else nullcontext
+        self.recompute = c.remat == "mamba" and kind == "M"
 
     def __call__(self, x, seq_len=None):
-        # the norm is inside the recomputed group: what the backward pass
-        # keeps of a recomputed mixer is the residual stream alone
         # (the norm and the residual sum are the block `hetu_norm`; the
         # sublayer names its own)
-        with self._scope():
-            with scope("hetu_norm"):
-                h = self.norm(x)
-            y = (self.mixer(h, h, h, seq_len=seq_len) if self.kind == "*"
-                 else self.mixer(h))
+        y = normed_mixer(self.norm, self.mixer, x, self.recompute,
+                         attention=self.kind == "*", seq_len=seq_len)
         with scope("hetu_norm"):
             return x + y
 

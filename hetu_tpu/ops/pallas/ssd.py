@@ -2,8 +2,19 @@
 mathematics and the ``jax.numpy`` form these are held to).
 
 ``hetu_ssd_fwd``: grid (batch, group, block of chunks), the last axis
-sequential.  A program holds the ``R = H / G`` heads of one group: their
-states stay in VMEM scratch from the first chunk to the last, transposed and
+sequential.  A program holds the ``R = H / G`` heads of one group where they
+are at most ``HEADS`` = 8 (Nemotron-H).  A wider group (Granite 4.0-H: all 64
+heads read ONE ``B`` and ``C``; held whole, its blocks would be 68 MiB of the
+64 MiB limit) is cut into ``wide`` blocks of ``R`` heads, the most up to
+eight that divide it, and the grid's second axis runs over blocks of heads:
+``wide`` neighbours read the same rows of ``B`` and ``C`` in place (their
+block index is the group's), and in the backward pass each writes its own
+part of ``dB`` and ``dC``, which XLA adds up over the group's blocks in f32.
+(Broadcasting ``B`` and ``C`` to ``wide`` sub-groups outside, so that the
+kernels see eight heads a group, is the same but for 2 x 16 MiB written and
+read again at the Granite cell's shape: 3.31 against 3.25 ms a mixer forward
+and backward, v5e, PERF.md, PR 37.)  Below, "group" is what a program holds:
+its states stay in VMEM scratch from the first chunk to the last, transposed and
 side by side, ``S^T [N, R P]`` f32, so that what all heads share is one
 product (``C S^T`` for the group's ``R P`` lanes at once).  It walks
 ``CHUNKS`` chunks of 128 positions: it reads their ``x`` rows in place from
@@ -72,13 +83,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry
 from . import dispatch
-from .gated_delta import _params, _walk      # the same loop and VMEM limit
+from .gated_delta import VMEM_LIMIT, _params, _walk    # the same loop
 
 #: positions a chunk (``ops.ssd.CHUNK``; the kernels are written for it)
 L = 128
 #: chunks a program walks (4 and 16 measured the same)
 CHUNKS = 8
+#: heads a program holds at most: ``dt`` and ``a`` of eight heads are one f32
+#: register ``[8, 128]``, and eight heads of 64 are what the blocks below were
+#: sized and measured at.  A group of more heads (Granite 4.0-H: all 64 heads
+#: read ONE ``B`` and ``C``) is walked as blocks of heads, a program each.
+HEADS = 8
 # (scoped VMEM: the backward program's x, dy, dx blocks and eight kept states,
 # double-buffered, are about 12 MiB of ``gated_delta.VMEM_LIMIT``'s 64)
 
@@ -315,35 +332,41 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
     _walk(nc, body)
 
 
-def _plan(x, Bm, dt, reverse):
-    """Grid, the kernels' static sizes and the block specs of x / y, B / C,
-    dt / a, the kept states and a state; ``reverse``: the blocks of chunks
-    from the last to the first."""
+def _plan(x, Bm, dt, reverse, wide):
+    """Grid, the kernels' static sizes and the block specs of x / y, B / C
+    (and dB / dC), dt / a, the kept states and a state; ``reverse``: the
+    blocks of chunks from the last to the first.  The grid's second axis is
+    over blocks of heads (``dt``'s second dimension), ``wide`` of them to a
+    group: they read the one ``B`` and ``C`` of their group and each writes
+    its own part of ``dB`` and ``dC``."""
     import jax.experimental.pallas as pl
     b, G, blocks, nc, R, _ = dt.shape
-    rp, N = x.shape[2] // G, Bm.shape[2] // G
+    rp, N = x.shape[2] // G, Bm.shape[2] * wide // G
     at = (lambda i: blocks - 1 - i) if reverse else (lambda i: i)
     seq = lambda d: pl.BlockSpec((None, nc * L, d),
                                  lambda b, g, i: (b, at(i), g))
+    group = seq(N) if wide == 1 else pl.BlockSpec(
+        (None, nc * L, N), lambda b, g, i: (b, at(i), g // wide))
     gate = pl.BlockSpec((None, None, None, nc, R, L),
                         lambda b, g, i: (b, g, at(i), 0, 0, 0))
     kept = pl.BlockSpec((None, None, None, nc, N, rp),
                         lambda b, g, i: (b, g, at(i), 0, 0, 0))
     state = pl.BlockSpec((None, None, N, rp), lambda b, g, i: (b, g, 0, 0))
     return ((b, G, blocks), dict(nc=nc, heads=R, p=rp // R),
-            (seq(rp), seq(N), gate, kept, state))
+            (seq(rp), group, seq(N), gate, kept, state))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fwd_call(x, dt, a, Bm, Cm, *, interpret):
-    """``x [b, T, H P]``, ``B, C [b, T, G N]``, ``dt, a [b, G, T / (n L), n,
-    R, L]`` f32 (``n`` chunks a program): ``(y [b, T, H P], last state^T [b,
-    G, N, R P], chunk-start states^T [b, G, T / (n L), n, N, R P])``."""
+@functools.partial(jax.jit, static_argnames=("interpret", "wide"))
+def _fwd_call(x, dt, a, Bm, Cm, *, interpret, wide):
+    """``x [b, T, H P]``, ``B, C [b, T, G N]``, ``dt, a [b, G', T / (n L), n,
+    R, L]`` f32 (``n`` chunks a program; ``G' = wide G`` blocks of ``R`` heads,
+    ``wide`` to a group): ``(y [b, T, H P], last state^T [b, G', N, R P],
+    chunk-start states^T [b, G', T / (n L), n, N, R P])``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     b, G, blocks, nc = dt.shape[:4]
-    grid, dims, (xy, bc, gate, kept, state) = _plan(x, Bm, dt, False)
-    rp, N = x.shape[2] // G, Bm.shape[2] // G
+    grid, dims, (xy, bc, _, gate, kept, state) = _plan(x, Bm, dt, False, wide)
+    rp, N = x.shape[2] // G, Bm.shape[2] * wide // G
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **dims),
         name="hetu_ssd_fwd", grid=grid,
@@ -356,19 +379,25 @@ def _fwd_call(x, dt, a, Bm, Cm, *, interpret):
     )(x, Bm, Cm, dt, a)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "wide"))
+def _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast, *, interpret,
+              wide):
+    """``dx``, ``dB`` and ``dC`` (``[b, T, G' N]``: a block of heads' own part,
+    which ``_scan_bwd`` adds up over a group's ``wide`` blocks), ``ddt`` and
+    ``da``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (xy, bc, gate, kept, state) = _plan(x, Bm, dt, True)
+    grid, dims, (xy, bc, dbc, gate, kept, state) = _plan(x, Bm, dt, True,
+                                                         wide)
+    parts = Bm.shape[:2] + (Bm.shape[2] * wide,)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, **dims),
         name="hetu_ssd_bwd", grid=grid,
         in_specs=[xy, bc, bc, gate, gate, kept, state, xy, state],
-        out_specs=[xy, bc, bc, gate, gate],
+        out_specs=[xy, dbc, dbc, gate, gate],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(Bm.shape, Bm.dtype),
-                   jax.ShapeDtypeStruct(Cm.shape, Cm.dtype),
+                   jax.ShapeDtypeStruct(parts, Bm.dtype),
+                   jax.ShapeDtypeStruct(parts, Cm.dtype),
                    jax.ShapeDtypeStruct(dt.shape, _F32),
                    jax.ShapeDtypeStruct(dt.shape, _F32)],
         scratch_shapes=[pltpu.VMEM(states.shape[-2:], _F32),
@@ -377,35 +406,59 @@ def _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast, *, interpret):
     )(x, Bm, Cm, dt, a, states, last, dy, dlast)
 
 
-@jax.custom_vjp
-def _scan(x, dt, a, Bm, Cm):
-    return _scan_fwd(x, dt, a, Bm, Cm)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _scan(x, dt, a, Bm, Cm, wide):
+    return _scan_fwd(x, dt, a, Bm, Cm, wide)[0]
 
 
-def _scan_fwd(x, dt, a, Bm, Cm):
-    y, last, states = _fwd_call(x, dt, a, Bm, Cm,
+def _scan_fwd(x, dt, a, Bm, Cm, wide):
+    y, last, states = _fwd_call(x, dt, a, Bm, Cm, wide=wide,
                                 interpret=dispatch.interpret())
     return (y, last), (x, dt, a, Bm, Cm, states, last)
 
 
-def _scan_bwd(res, grads):
+def _scan_bwd(wide, res, grads):
     x, dt, a, Bm, Cm, states, last = res
     dy, dlast = grads
     dx, dB, dC, ddt, da = _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast,
-                                    interpret=dispatch.interpret())
+                                    wide=wide, interpret=dispatch.interpret())
+    if wide > 1:            # a group's dB, dC: the sum of its blocks' parts
+        N = Bm.shape[2] * wide // dt.shape[1]
+        dB, dC = (t.reshape(t.shape[:2] + (-1, wide, N)).sum(3, dtype=_F32)
+                  .astype(t.dtype).reshape(Bm.shape) for t in (dB, dC))
     return dx, ddt, da, dB, dC
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+def heads_a_program(R, P):
+    """How many of a group's ``R`` heads of ``P`` channels one program holds:
+    the most, up to ``HEADS``, that divide ``R`` and fill whole 128-lane
+    tiles; 0 where none do."""
+    return next((r for r in range(min(R, HEADS), 0, -1)
+                 if R % r == 0 and (r * P) % 128 == 0), 0)
+
+
+def _block_bytes(r, P, N, itemsize, nc=CHUNKS):
+    """Bytes of VMEM the backward program's blocks take, each with the
+    pipeline's second buffer (the forward's are fewer): x, dy and dx, B, C
+    and their gradients, dt, a and theirs, the kept states, the last state
+    and its gradient, and the two scratch arrays."""
+    rows, rp = nc * L, r * P
+    blocks = (3 * rows * rp * itemsize + 4 * rows * N * itemsize
+              + 4 * nc * max(r, 8) * L * 4 + (nc + 2) * N * rp * 4)
+    return 2 * blocks + N * rp * 4 + max(r, 8) * L * 4
+
+
 def unsupported(x, Bm, Cm, chunk):
     """Why the kernels do not take ``chunk_ssd``'s operands, or None when
     they do."""
     P, R, N = x.shape[-1], x.shape[-2] // Bm.shape[-2], Bm.shape[-1]
+    r = heads_a_program(R, P)
     if chunk != L:
         return f"chunk!={L}"
-    if P % 64 or (R * P) % 128:
+    if P % 64 or not r:
         return "head_dim_not_64_aligned"
     if N % 128:
         return "state_not_128_aligned"
@@ -413,7 +466,29 @@ def unsupported(x, Bm, Cm, chunk):
         return "dtype:mixed"
     if jnp.dtype(x.dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
         return f"dtype:{jnp.dtype(x.dtype).name}"
+    # what one program holds however a group is cut: the rest of the limit
+    # is the chains' own values
+    if _block_bytes(r, P, N, jnp.dtype(x.dtype).itemsize) > VMEM_LIMIT // 2:
+        return "blocks_over_vmem"
     return None
+
+
+def _count_entry(R, r):
+    """Trace-time count of the cut taken, beside ``dispatch.record``'s count
+    of the kernel-versus-jnp choice."""
+    telemetry.get_registry().counter(
+        "hetu_ssd_entry_total",
+        "Trace-time calls of the state-space scan's kernels by the heads of "
+        "a group and the heads one program holds",
+        labels=("heads_a_group", "heads_a_program"),
+    ).labels(heads_a_group=str(R), heads_a_program=str(r)).inc()
+
+
+def entries():
+    """``{(heads_a_group, heads_a_program): count}`` of the calls traced so
+    far (empty while telemetry is disabled)."""
+    return {(int(lab["heads_a_group"]), int(lab["heads_a_program"])): n
+            for lab, n in dispatch.counted("hetu_ssd_entry_total")}
 
 
 def ssd(x, dt, A, Bm, Cm):
@@ -421,10 +496,16 @@ def ssd(x, dt, A, Bm, Cm):
     P]``, ``dt [b, T, H]``, ``A [H]``, ``B, C [b, T, G, N]`` -> ``(y [b, T, H,
     P]`` in ``x``'s type, the last state ``[b, H, P, N]`` f32)``.  Any ``T``:
     positions of padding write nothing and decay nothing (dt 0) and their
-    outputs are cut off."""
+    outputs are cut off.  A group of more than ``HEADS`` heads runs as
+    ``wide`` blocks of ``R`` heads, each a program of its own on the grid's
+    second axis (below, ``G`` counts those blocks): a group's blocks read its
+    ``B`` and ``C`` rows in place, once a block, and the group's ``dB`` and
+    ``dC`` are the sum of what its blocks write."""
     b, T, H, P = x.shape
-    G, N = Bm.shape[2:]
-    R = H // G
+    N = Bm.shape[3]
+    R = heads_a_program(H // Bm.shape[2], P)
+    G, wide = H // R, H // Bm.shape[2] // R
+    _count_entry(wide * R, R)
     nc = min(CHUNKS, -(-T // L))
     blocks = -(-T // (nc * L))
     pad = blocks * nc * L - T
@@ -437,6 +518,6 @@ def ssd(x, dt, A, Bm, Cm):
     dt = rows(dt.astype(_F32)).reshape(b, blocks, nc, L, G, R)
     dt = dt.transpose(0, 4, 1, 2, 5, 3)
     a = dt * A.astype(_F32).reshape(G, 1, 1, R, 1)
-    y, last = _scan(rows(x), dt, a, rows(Bm), rows(Cm))
+    y, last = _scan(rows(x), dt, a, rows(Bm), rows(Cm), wide)
     last = last.reshape(b, G, N, R, P).transpose(0, 1, 3, 4, 2)
     return y[:, :T].reshape(b, T, H, P), last.reshape(b, H, P, N)

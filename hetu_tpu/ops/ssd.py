@@ -1,6 +1,6 @@
 """The selective state-space recurrence of Mamba-2 (Dao and Gu 2024,
-arXiv:2405.21060; the ``M`` blocks of Nemotron-H), in its state-space dual
-form over chunks.
+arXiv:2405.21060; the ``M`` blocks of Nemotron-H and the ``mamba`` layers of
+Granite 4.0-H), in its state-space dual form over chunks.
 
 Per head, with a state ``S`` ``[P, N]`` (``P`` the head's channels, ``N`` the
 state size) that starts at zero, for each position ``t``::
@@ -9,7 +9,8 @@ state size) that starts at zero, for each position ``t``::
     y_t = S C_t
 
 ``B`` and ``C`` are shared by the heads of a group (``G`` groups, head ``h``
-reads group ``h // (H / G)``).  The skip ``D x_t``, the gate and the norm
+reads group ``h // (H / G)``; Nemotron-H has 8 groups of 8 heads, Granite
+4.0-H ONE group of all 64).  The skip ``D x_t``, the gate and the norm
 are the layer's (``layers/mamba2.py``).
 
 ``recurrent_ssd`` is that loop, one position at a time: the form the tests
@@ -52,15 +53,18 @@ calls (``layers/mamba2.py``) and what the benchmark's long-memory probe
 calls (``chipbench/builders/nemotron_h.py`` ``ssd_state_gap``).  On a TPU it
 runs as two Pallas kernels, ``hetu_ssd_fwd`` and ``hetu_ssd_bwd``
 (``ops/pallas/ssd.py``, a ``jax.custom_vjp``: one walk over chunk states in
-VMEM each way, a group's heads a program, the decays built in VMEM from
-``dt`` and ``A``; the backward keeps the chunk-start states and rebuilds
-everything else), where it can read that they apply: ``P`` a multiple of 64
-with a group's ``H / G`` heads filling whole 128-lane tiles, ``N`` a multiple
-of 128, ``chunk`` 128, ``x``, ``B`` and ``C`` all bf16 or all f32; any
-``b``, ``T``, ``H`` and ``G``.  Each call counts its choice at trace time in
+VMEM each way, up to eight of a group's heads a program and a wider group
+as blocks of heads on the grid, each reading the group's ``B`` and ``C`` in
+place, the group's ``dB`` and ``dC`` summed over its blocks; the decays built
+in VMEM from ``dt`` and ``A``; the backward keeps the chunk-start states and
+rebuilds everything else), where it can read that they apply: ``P`` a
+multiple of 64 with some block of up to eight of a group's ``H / G`` heads
+filling whole 128-lane tiles, ``N`` a multiple of 128, ``chunk`` 128, ``x``,
+``B`` and ``C`` all bf16 or all f32, a program's blocks within half the
+kernels' VMEM; any ``b``, ``T``, ``H`` and ``G``.  Each call counts its choice at trace time in
 ``hetu_kernel_choice_total{kernel="ssd", impl, reason}``: ``pallas``,
 or ``jnp`` with ``head_dim_not_64_aligned``, ``state_not_128_aligned``,
-``chunk!=128``, ``dtype:<name>`` or ``dtype:mixed``.  A mesh is the one thing
+``chunk!=128``, ``dtype:<name>``, ``dtype:mixed`` or ``blocks_over_vmem``.  A mesh is the one thing
 the function cannot see (a ``pallas_call`` does not partition under GSPMD):
 the scan node reads it, calls ``chunk_ssd_jnp`` itself and counts ``mesh``.
 On any other platform there is no Mosaic and no choice: nothing is counted

@@ -79,6 +79,24 @@ def no_leaked_nondaemon_threads(request):
         f"{[t.name for t in leaked]}")
 
 
+@pytest.fixture(autouse=True)
+def compile_cache_as_found():
+    """A test that points jax's persistent compile cache leaves the process
+    as it found it.  The benchmark's ``run.main`` points it at
+    ``<checkout>/.jax_cache`` (``platform.enable_compile_cache``) for the
+    rest of the process, and a later test of the same worker that compiles
+    one step twice and compares the texts (``test_block_scopes.py``) would
+    be handed its first build for the second: which worker runs which file
+    is xdist's choice, so the failure came and went."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    if jax.config.jax_compilation_cache_dir != before:
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

@@ -6,7 +6,8 @@ inputs; the fastest and the slowest decays the model can draw; a long memory,
 where a bf16 state is seen and the kernels are not; the rule by which
 ``chunk_ssd`` takes them; the ``Mamba2`` layer through them against the same
 layer through the ``jax.numpy`` form; and the layer's train step compiled for
-a described v5e."""
+a described v5e; and a group of more heads than a program holds (Granite
+4.0-H: 64 heads on one ``B`` and ``C``) cut into blocks of heads."""
 
 import types
 
@@ -65,13 +66,16 @@ NAMES = ("x", "dt", "A", "B", "C")
 
 @pytest.mark.parametrize("T,b,H,G", [(128, 1, 4, 1), (200, 2, 8, 1),
                                      (300, 1, 8, 2), (70, 2, 16, 2),
-                                     (1100, 1, 4, 1)])
+                                     (1100, 1, 4, 1), (200, 1, 16, 1),
+                                     (130, 2, 64, 1), (140, 1, 24, 2)])
 def test_kernels_are_the_recurrence(T, b, H, G):
     """f32 operands, at the tolerances ``tests/test_ssd_scan.py`` holds the
     ``jax.numpy`` form to: outputs, last state and the gradient of x, dt, A,
     B and C; 4 and 8 heads a group, one and two groups and sequences, lengths
     that are and are not a multiple of the chunk, one and several chunks a
-    program and more than one program a sequence."""
+    program and more than one program a sequence; and groups of 16, of 64
+    and of 12 heads, which run as two and eight blocks of eight heads and
+    two of six: ``dB`` and ``dC`` are then sums over a group's blocks."""
     args = ssd_inputs(T, b, H, G)
     y1, s1 = jax.jit(recurrent_ssd)(*args)
     y2, s2 = kernels.ssd(*args)
@@ -87,7 +91,8 @@ def test_kernels_are_the_recurrence(T, b, H, G):
 
 
 @pytest.mark.parametrize("T,b,H,G", [(128, 2, 4, 1), (300, 1, 8, 2),
-                                     (1000, 1, 4, 1)])
+                                     (1000, 1, 4, 1), (300, 1, 16, 1),
+                                     (200, 1, 64, 1)])
 def test_kernels_are_the_chunked_form_in_bf16(T, b, H, G):
     """bf16 x, B and C against the ``jax.numpy`` chunked form on the same
     operands (the last case is the benchmark's probe cut short: 4 heads of
@@ -120,6 +125,45 @@ def test_kernels_are_the_chunked_form_in_bf16(T, b, H, G):
         # a slow head's state carries for hundreds of positions: either
         # form is the nearer one on some seed
         assert name == "A" or l2_gap(g, t) < 1.2 * l2_gap(w, t) + 1e-4, name
+
+
+@pytest.mark.parametrize("H,G,P_,want", [
+    (64, 8, 64, (8, 8)),          # Nemotron-H: a group a program, as before
+    (8, 1, 128, (8, 8)), (4, 1, 64, (4, 4)),
+    (64, 1, 64, (64, 8)),         # Granite 4.0-H: eight blocks of eight
+    (16, 1, 64, (16, 8)), (24, 2, 64, (12, 6)), (10, 1, 128, (10, 5))])
+def test_a_wide_group_is_cut_into_blocks_of_heads(live_registry, H, G, P_,
+                                                  want):
+    """The most heads, up to eight, that divide a group and fill 128-lane
+    tiles; counted where the call is traced."""
+    before = kernels.entries()
+    assert kernels.heads_a_program(H // G, P_) == want[1]
+    sds = jax.ShapeDtypeStruct
+    bc = sds((1, 256, G, N), jnp.bfloat16)
+    y, last = jax.eval_shape(
+        kernels.ssd, sds((1, 256, H, P_), jnp.bfloat16),
+        sds((1, 256, H), jnp.float32), sds((H,), jnp.float32), bc, bc)
+    assert y.shape == (1, 256, H, P_) and last.shape == (1, H, P_, N)
+    assert {k: n - before.get(k, 0) for k, n in kernels.entries().items()
+            if n > before.get(k, 0)} == {want: 1}
+
+
+def test_eight_heads_a_group_read_their_rows_as_before():
+    """At eight heads a group nothing of the cut is in the call: one block a
+    group, ``B`` and ``C`` blocks indexed by the group, ``dB`` and ``dC``
+    written whole (no sum over blocks in the traced function)."""
+    grad = weighted_grad(kernels.ssd, (1, 256, 16, P), (1, 16, P, N))
+
+    def sums_after_the_backward_kernel(G):
+        text = str(jax.make_jaxpr(grad)(*ssd_inputs(256, 1, 16, G,
+                                                    jnp.bfloat16)))
+        assert (text.count("hetu_ssd_fwd"), text.count("hetu_ssd_bwd")) == (
+            1, 1)
+        return text.split("hetu_ssd_bwd")[1].count("reduce_sum")
+    # two groups of eight: the sums that give dA from da and nothing else;
+    # one group of sixteen: dB's and dC's over the group's two blocks too
+    assert sums_after_the_backward_kernel(1) == (
+        sums_after_the_backward_kernel(2) + 2)
 
 
 @pytest.mark.parametrize("rate,dt_mean", [(3.0, 1.0), (1e-4, 1.0),
@@ -207,7 +251,9 @@ def test_nothing_is_recorded_on_the_cpu(ssd_choices, monkeypatch):
 
 @pytest.mark.parametrize("why,kw,chunk", [
     (None, {}, 128),                           # the benchmark's probe
-    (None, dict(H=64, G=8, T=8192), 128),      # the cell's mixer
+    (None, dict(H=64, G=8, T=8192), 128),      # the Nemotron-H cell's mixer
+    (None, dict(H=64, G=1, T=8192), 128),      # the Granite cell's mixer
+    ("blocks_over_vmem", dict(p=512, n=1024, H=8, G=1), 128),
     (None, dict(dtype=jnp.float32, H=6, G=3, T=100, b=3, p=128), 128),
     ("head_dim_not_64_aligned", dict(p=16, n=32, H=8, G=2), 128),
     ("head_dim_not_64_aligned", dict(H=3, G=1), 128),     # 192 lanes a group
@@ -339,19 +385,24 @@ def as_on_tpu(monkeypatch):
     jax.clear_caches()
 
 
-def test_layer_train_step_compiles_for_v5e(v5e, as_on_tpu, ssd_choices):
+@pytest.mark.parametrize("groups", [8, 1])
+def test_layer_train_step_compiles_for_v5e(v5e, as_on_tpu, ssd_choices,
+                                           groups):
     """The Nemotron-H cell's mixer (64 heads of 64 in 8 groups, state 128,
     8,192 positions, bf16 over f32 masters) recomputed in the backward pass
     as the cell's are, with AdamW: ``hetu_ssd_fwd`` twice (forward and
     recomputed forward), ``hetu_ssd_bwd`` once and nothing else of the
     scan's: no ``while``, no ``[.., 128, 128]`` array in HBM; the kernels read
-    and write ``x [1, 8192, 4096]`` in place."""
+    and write ``x [1, 8192, 4096]`` in place.  And the Granite cell's, all 64
+    heads in ONE group: held whole by a program that is 68 MiB of VMEM
+    against the limit of 64 (``RESOURCE_EXHAUSTED`` before the group was cut
+    into blocks of eight heads), the same three calls."""
     import re
     from jax.sharding import SingleDeviceSharding
     import hetu_tpu as ht
     from hetu_tpu.layers.mamba2 import Mamba2
-    layer = Mamba2(256, 64, P, 8, N, name="ssk_v5e")
-    x = ht.placeholder_op("ssk_v5e_x", (1, 8192, 256))
+    layer = Mamba2(256, 64, P, groups, N, name=f"ssk_v5e_g{groups}")
+    x = ht.placeholder_op(f"ssk_v5e_g{groups}_x", (1, 8192, 256))
     with ht.remat():
         y = layer(x)
     loss = ht.reduce_sum_op(y * y, axes=[0, 1, 2])
