@@ -15,7 +15,9 @@ every four layers; HF ``Qwen3NextGatedDeltaNet``).
 
 Four graph nodes, each under a ``jax.named_scope`` that the device trace's
 readers find in the compiled step's ``op_name``: ``hetu_gdn_proj`` (the two
-projections and the split), ``hetu_gdn_conv``, ``hetu_gdn_scan`` (gates,
+projections and the split), ``hetu_gdn_conv`` (``ops/causal_conv.py ConvOp``:
+on a TPU the Pallas kernels ``hetu_conv_fwd`` and ``hetu_conv_bwd``),
+``hetu_gdn_scan`` (gates,
 normalisation and the chunked delta rule) and ``hetu_gdn_out`` (the gated
 norm and the output projection).  A decode step and the recurrent state in
 a serving cache are not here (ROADMAP Queue 2, M7).
@@ -29,7 +31,7 @@ from .base import BaseLayer, fresh_name
 from .. import initializers as init
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
-from ..ops.causal_conv import causal_conv
+from ..ops.causal_conv import ConvOp, causal_conv      # noqa: F401
 
 
 def _split(qkvz, *, key_heads, dk, dv, rep):
@@ -158,7 +160,7 @@ class GatedDeltaNet(BaseLayer):
         ba = _Scoped(_project, "hetu_gdn_proj", x, self.in_proj_ba)
         mixed = _Scoped(_mixed, "hetu_gdn_proj", qkvz, **self.dims)
         z = _Scoped(_z, "hetu_gdn_proj", qkvz, **self.dims)
-        mixed = _Scoped(causal_conv, "hetu_gdn_conv", mixed, self.conv)
+        mixed = ConvOp("hetu_gdn_conv", mixed, self.conv)
         o = _ScanOp(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
                     self.dt_bias, **self.dims)
         return _Scoped(_out, "hetu_gdn_out", o, z, self.norm, self.out_proj,

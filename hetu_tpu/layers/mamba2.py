@@ -19,7 +19,9 @@ all ``d`` = 4,096 channels).  ``H`` heads of ``P`` channels (``d = H P``),
 
 Four graph nodes, each under a ``jax.named_scope`` that the device trace's
 readers find in the compiled step's ``op_name``: ``hetu_ssm_proj`` (the
-input projection and its three parts), ``hetu_ssm_conv``, ``hetu_ssm_scan``
+input projection and its parts), ``hetu_ssm_conv`` (``ops/causal_conv.py
+ConvOp``, which reads ``xBC`` out of the projection's output itself: on a TPU
+the Pallas kernels ``hetu_conv_fwd`` and ``hetu_conv_bwd``), ``hetu_ssm_scan``
 (the gates, the chunked scan and the skip) and ``hetu_ssm_out`` (the gate,
 the grouped norm and the output projection).  The scan is
 ``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
@@ -39,7 +41,7 @@ from .base import BaseLayer, fresh_name
 from .. import initializers as init
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
-from ..ops.causal_conv import causal_conv
+from ..ops.causal_conv import ConvOp
 
 
 def _project(x, w):
@@ -158,11 +160,11 @@ class Mamba2(BaseLayer):
         # one node for the projection: its backward pass is then one product
         # for the weight, whatever reads the parts
         zxbcdt = _Scoped(_project, "hetu_ssm_proj", x, self.in_proj)
-        z, xbc, dt = (_Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
-                      for lo, hi in (self.parts[n] for n in
-                                     ("z", "xbc", "dt")))
-        xbc = _Scoped(causal_conv, "hetu_ssm_conv", xbc, self.conv,
-                      self.conv_bias)
+        z, dt = (_Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
+                 for lo, hi in (self.parts[n] for n in ("z", "dt")))
+        # the convolution reads its channels in place where it can
+        xbc = ConvOp("hetu_ssm_conv", zxbcdt, self.conv, self.conv_bias,
+                     window=self.parts["xbc"])
         y = _ScanOp(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
                     self.a_log, self.d_skip, **self.dims)
         return _Scoped(_out, "hetu_ssm_out", y, z, self.norm, self.out_proj,

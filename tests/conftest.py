@@ -116,6 +116,39 @@ def clone_params_into(ex, prev):
     return {k: np.asarray(v) for k, v in ex.params.items()}
 
 
+def lowered_for_tpu(monkeypatch, build):
+    """The text of the train step of the benchmark program ``build()`` makes,
+    lowered for a TPU (nothing is compiled or run) with the platform read as
+    ``tpu`` while it is built and traced; jax's caches emptied around it,
+    since a kernel's jitted entry keeps what it read of the platform when it
+    was traced."""
+    import jax
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    jax.clear_caches()
+    prog = build()
+    try:
+        sub = prog.ex.subexecutor["train"]
+        if sub._jitted is None:
+            sub._build()
+        return sub._jitted.trace(*sub._abstract_args(None)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        prog.close()
+        jax.clear_caches()
+
+
+def conv_calls(text):
+    """How often a lowered step calls the causal convolution's two kernel
+    entries (``ops/pallas/causal_conv.py``; each holds its one
+    ``tpu_custom_call``): ``(forward, backward)``."""
+    import re
+    names = ("hetu_conv_fwd", "hetu_conv_bwd")
+    assert all(f'kernel_name = "{name}"' in text for name in names)
+    return tuple(len(re.findall(rf"call @{name}(_\d+)?\(", text))
+                 for name in names)
+
+
 def jaxpr_primitives(jaxpr):
     """Every equation of a jaxpr and of the jaxprs nested in its
     equations' parameters (jit, shard_map, custom_vjp, scan), except

@@ -720,6 +720,46 @@ def test_in_place_kernels_compile_per_shard_on_four_chips(v5e, as_on_tpu):
     assert not re.findall(r" = bf16\[[\d,]+\]\S* (?:copy|transpose)\(", hlo)
 
 
+@pytest.mark.parametrize("wide,window,bias,dtype", [
+    (8192, None, False, "bfloat16"),     # Qwen3-Next: q | k | v, no bias
+    (10304, (4096, 10240), True, "bfloat16"),   # Nemotron-H: xBC inside
+    (8512, (4096, 8448), True, "bfloat16"),     # Granite: 17 x 256 lanes
+    (10304, (4096, 10240), True, "float32"),    # f32 tiles have half the rows
+])
+def test_causal_conv_kernels_compile_for_v5e(v5e, as_on_tpu, wide, window,
+                                             bias, dtype):
+    """The mixers' convolution at the three hybrid cells' shapes, forward and
+    backward: ``hetu_conv_fwd`` and ``hetu_conv_bwd`` once each under the
+    default scoped VMEM, the window read in place out of the projection's
+    output (its first lane, 4,096, is no multiple of Granite's 4,352 / 17
+    lanes a tile), and no f32 ``[S, C]`` array in HBM."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops.causal_conv import causal_conv
+    one = SingleDeviceSharding(v5e.devices[0])
+    dtype = jnp.dtype(dtype)
+    sds = lambda *s: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+    lo, hi = window or (0, wide)
+    args = (sds(1, 8192, wide), sds(4, hi - lo)) + (
+        (sds(hi - lo),) if bias else ())
+
+    def loss(x, w, b=None):
+        y = causal_conv(x, w, b, window)
+        assert y.shape == (1, 8192, hi - lo)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+        *args).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_conv_fwd" in kernels[0] and "hetu_conv_bwd" in kernels[1]
+    assert f"[1,8192,{wide}]" in kernels[0]
+    if dtype == jnp.bfloat16:
+        entry = hlo[hlo.index("\nENTRY "):]        # what reaches HBM
+        assert len(re.findall(r" = bf16\[1,8192,\d+\]\S* ", entry)) >= 3
+        assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)
+
+
 @pytest.mark.parametrize("dp", [1, 4])
 def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
     """BERT's hidden dropout, forward and backward, on one chip and under

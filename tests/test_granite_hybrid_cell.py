@@ -121,7 +121,7 @@ def test_flops_of_the_cut_configuration():
     assert fn.ssd_chunk(128, 64, 128, 64) < fn.ssd_chunk(128, 64, 128, 8)
 
 
-def hybrid_toy(say=lambda msg: None, seq=192):
+def hybrid_toy(say=lambda msg: None, seq=192, **widths):
     """The cell's program at toy widths with the cell's own period (nine
     Mamba-2 layers, one attention layer; the configuration's own ``toy`` is
     all attention, see its ``why_all_attention``) over a whole chunk of 128
@@ -129,12 +129,25 @@ def hybrid_toy(say=lambda msg: None, seq=192):
     from chipbench.builders import granite_hybrid as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])
-    config.update(num_hidden_layers=10, layer_types=KINDS)
+    config.update(num_hidden_layers=10, layer_types=KINDS, **widths)
     mix = run.merge(mix, mix["toy"])
     mix["seq"] = seq
     config["max_position_embeddings"] = max(
         seq, config["max_position_embeddings"])
     return builder.build(config, mix, 2 ** 31 + 3, say), mix
+
+
+def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
+    """Nine Mamba-2 layers, each mixer recomputed in the backward pass: the
+    convolution reads ``xBC`` in place out of ``[z | xBC | dt]`` (at toy
+    widths with a state of 64: lanes 128 to 384 of 392, with a bias) in
+    ``hetu_conv_fwd`` eighteen times and ``hetu_conv_bwd`` nine, as in the
+    cell's step (PERF.md section 3)."""
+    from conftest import conv_calls, lowered_for_tpu
+    text = lowered_for_tpu(
+        monkeypatch, lambda: hybrid_toy(mamba_d_state=64)[0])
+    assert conv_calls(text) == (18, 9)
+    assert "x392x" in text and "x256x" in text
 
 
 def test_the_hybrid_runs_through_the_benchmarks_loop():

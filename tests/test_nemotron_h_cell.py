@@ -96,7 +96,7 @@ def test_flops_of_the_cut_configuration():
                           + 64 * 64 * 64 * 128 * 4)
 
 
-def hybrid_toy(say=lambda msg: None):
+def hybrid_toy(say=lambda msg: None, **widths):
     """The cell's program at toy widths with the cell's own pattern (four
     Mamba-2 mixers, four expert blocks, one attention block; the
     configuration's own ``toy`` is all attention, see its
@@ -104,9 +104,23 @@ def hybrid_toy(say=lambda msg: None):
     from chipbench.builders import nemotron_h as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])
-    config.update(num_hidden_layers=9, hybrid_override_pattern=PATTERN)
+    config.update(num_hidden_layers=9, hybrid_override_pattern=PATTERN,
+                  **widths)
     mix = run.merge(mix, mix["toy"])
     return builder.build(config, mix, 2 ** 31 + 3, say), mix
+
+
+def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
+    """Four Mamba-2 mixers, each recomputed in the backward pass: the
+    convolution reads ``xBC`` in place out of ``[z | xBC | dt]`` (at toy
+    widths with a state of 32: lanes 128 to 384 of 392, with a bias) in
+    ``hetu_conv_fwd`` eight times and ``hetu_conv_bwd`` four, as in the
+    cell's step (PERF.md section 3)."""
+    from conftest import conv_calls, lowered_for_tpu
+    text = lowered_for_tpu(
+        monkeypatch, lambda: hybrid_toy(ssm_state_size=32)[0])
+    assert conv_calls(text) == (8, 4)
+    assert "x392x" in text and "x256x" in text
 
 
 def test_the_cells_builder_at_a_hybrid_toy_size():

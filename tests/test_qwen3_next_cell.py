@@ -90,6 +90,16 @@ def hybrid_toy(say=lambda msg: None):
     return builder.build(config, mix, 2 ** 31 + 3, say), mix
 
 
+def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
+    """Three DeltaNet layers, each recomputed in the backward pass: the
+    convolution over ``q | k | v`` (2 x 32 + 64 = 128 lanes at toy widths, no
+    bias, no window) is ``hetu_conv_fwd`` six times and ``hetu_conv_bwd``
+    three, as in the cell's step (PERF.md section 3)."""
+    from conftest import conv_calls, lowered_for_tpu
+    text = lowered_for_tpu(monkeypatch, lambda: hybrid_toy()[0])
+    assert conv_calls(text) == (6, 3)
+
+
 def test_the_cells_builder_at_a_hybrid_toy_size():
     """The benchmark's builder on the cell's configuration and traffic at
     toy widths and the published layer pattern: the program's loss terms
