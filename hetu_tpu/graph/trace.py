@@ -159,18 +159,31 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
             env.update(env2)
         outs = group_outputs[scope]
 
+        updated = []
+
         def f(*in_vals):
             # bind ONLY the group's external inputs: everything the group
             # needs flows through the checkpoint boundary as an argument
             # (no closure captures), so the vjp recomputes exactly the
             # group's interior and saves only `ins`
+            before = dict(ctx.updates)
             vals, _ = evaluate(outs, dict(zip(ins, in_vals)), ctx,
                                _remat=False)
-            return tuple(vals)
+            # what an op of the group hands to the step's state (an expert
+            # layer's load and selection bias) leaves the checkpointed
+            # function as a result, not as a tracer of its trace
+            new = {v: val for v, val in ctx.updates.items()
+                   if before.get(v) is not val}
+            ctx.updates.clear()
+            ctx.updates.update(before)
+            updated[:] = list(new)
+            return tuple(vals), tuple(new.values())
 
-        out_vals = jax.checkpoint(f)(*[env[i] for i in ins])
+        out_vals, new_vals = jax.checkpoint(f)(*[env[i] for i in ins])
         for n, v in zip(outs, out_vals):
             env[n] = v
+        for var, val in zip(updated, new_vals):
+            ctx.record_update(var, val)
         done_ids.update(gids)
 
     for node in topo:

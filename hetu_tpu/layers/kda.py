@@ -1,0 +1,142 @@
+"""Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692; the
+linear-attention layers of Ling-3.0): a delta rule whose state decays every
+key channel by a factor of its own.
+
+    [q~ | k~ | v~ | f | z] = x W_in          (five blocks of heads x d)
+    q~, k~, v~ -> depthwise causal convolution (width 4, no bias) -> SiLU
+                     (ops/causal_conv.py, a window of the projection)
+    q = l2norm(q~) / sqrt(d),  k = l2norm(k~)            per head
+    g = lower_bound * sigmoid(exp(A_log_h) * (f + dt_bias))    in [lower_bound, 0)
+                     (f32; flash-linear-attention's lower-bound "safe" gate:
+                     a bound below is what lets the chunked rule take
+                     exponents sub-chunk by sub-chunk, ops/kda.py)
+    beta = sigmoid(x w_beta)                             one number a head
+    o = KDA(q, k, v, g, beta)                            (ops/kda.py)
+    y = [ o / rms(o) * w_n * sigmoid(z) ] W_out          per head
+
+The decay and output-gate projections are single full-rank matrices (the
+configuration's ``no_kda_lora``), so all five projections of ``x`` are one
+product.  Four graph nodes under the scopes ``hetu_kda_proj``,
+``hetu_kda_conv`` (``ConvOp``: on a TPU ``hetu_conv_fwd`` / ``hetu_conv_bwd``),
+``hetu_kda_scan`` (norms, gates and the chunked rule: on a TPU
+``hetu_kda_fwd`` / ``hetu_kda_bwd``) and ``hetu_kda_out``.  A decode step and
+the recurrent state in a serving cache are not here (ROADMAP Queue 2, M7).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .base import BaseLayer, fresh_name
+from .. import initializers as init
+from ..graph.node import VariableOp
+from ..ops.base import ScopedOp as _Scoped
+from ..ops.causal_conv import ConvOp
+from .mamba2 import _a_log, _dt_bias
+
+
+def _project(x, w):
+    return x @ w
+
+
+def gate(f, a_log, dt_bias, lower_bound):
+    """The decay's logarithm, ``[.., heads, d]`` f32 in ``[lower_bound, 0)``
+    from the projection ``f [.., heads, d]``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    rate = jnp.exp(a_log.astype(f32))[:, None]
+    return lower_bound * jax.nn.sigmoid(
+        rate * (f.astype(f32) + dt_bias.astype(f32).reshape(rate.shape[0],
+                                                            -1)))
+
+
+def _scan(proj, mixed, beta_lin, a_log, dt_bias, *, heads, d, lower_bound,
+          rule=None):
+    import jax
+    import jax.numpy as jnp
+    from ..ops import kda
+    B, S, _ = mixed.shape
+    f32 = jnp.float32
+    hd = heads * d
+
+    def unit(t):
+        t = t.reshape(B, S, heads, d).astype(f32)
+        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+    q = (unit(mixed[..., :hd]) * d ** -0.5).astype(mixed.dtype)
+    k = unit(mixed[..., hd:2 * hd]).astype(mixed.dtype)
+    v = mixed[..., 2 * hd:].reshape(B, S, heads, d)
+    g = gate(proj[..., 3 * hd:4 * hd].reshape(B, S, heads, d), a_log,
+             dt_bias, lower_bound)
+    beta = jax.nn.sigmoid(beta_lin.astype(f32))
+    return (rule or kda.chunk_kda)(q, k, v, g, beta)[0]
+
+
+class _ScanOp(_Scoped):
+    """The ``hetu_kda_scan`` node.  A ``pallas_call`` does not partition
+    under GSPMD and ``chunk_kda`` cannot see a mesh, so under one this node
+    calls the rule's ``jax.numpy`` form itself, and says so where there was a
+    kernel to take (reason ``mesh``)."""
+
+    def _compute(self, input_vals, ctx):
+        from ..ops import kda
+        from ..ops.pallas import dispatch
+        rule = None
+        if ctx.mesh is not None:
+            rule = kda.chunk_kda_jnp
+            if dispatch.mosaic():
+                dispatch.record("kda", "mesh")
+        return self.fn(*input_vals, rule=rule, **self.attrs)
+
+
+def _out(o, proj, w_norm, w_out, *, eps):
+    """RMSNorm over each head's ``d`` scaled by ``w_norm``, gated channel by
+    channel by ``sigmoid(z)`` (the last block of the projection), then the
+    output projection."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    B, S, heads, d = o.shape
+    z = proj[..., 4 * heads * d:].reshape(o.shape)
+    of = o.astype(f32)
+    of = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
+    y = (w_norm * of.astype(o.dtype)).astype(f32) * jax.nn.sigmoid(
+        z.astype(f32))
+    return y.astype(o.dtype).reshape(B, S, -1) @ w_out
+
+
+class KimiDeltaAttention(BaseLayer):
+    def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
+                 lower_bound=-5.0, eps=1e-6, name=None):
+        name = fresh_name(name or "kda")
+        self.dims = dict(heads=num_heads, d=head_dim)
+        self.lower_bound, self.eps = float(lower_bound), eps
+        hd = num_heads * head_dim
+        #: q, k, v (convolved), the decay's projection, the output gate
+        self.in_proj = VariableOp(f"{name}_in_weight", (hidden_size, 5 * hd),
+                                  init.xavier_normal())
+        self.beta_proj = VariableOp(f"{name}_beta_weight",
+                                    (hidden_size, num_heads),
+                                    init.xavier_normal())
+        # torch's Conv1d default: uniform within 1 / sqrt(fan_in = kernel)
+        bound = 1.0 / np.sqrt(conv_kernel)
+        self.conv = VariableOp(f"{name}_conv_weight", (conv_kernel, 3 * hd),
+                               init.uniform(-bound, bound))
+        # A ~ U(1, 16) a head and dt log-uniform in [0.001, 0.1] a channel:
+        # as the Mamba family and flash-linear-attention's KDA initialise
+        self.a_log = VariableOp(f"{name}_a_log", (num_heads,), _a_log)
+        self.dt_bias = VariableOp(f"{name}_dt_bias", (hd,),
+                                  _dt_bias(1e-3, 1e-1, 1e-4))
+        self.norm = VariableOp(f"{name}_norm_scale", (head_dim,), init.ones())
+        self.out_proj = VariableOp(f"{name}_out_weight", (hd, hidden_size),
+                                   init.xavier_normal())
+
+    def __call__(self, x):
+        hd = self.dims["heads"] * self.dims["d"]
+        proj = _Scoped(_project, "hetu_kda_proj", x, self.in_proj)
+        beta = _Scoped(_project, "hetu_kda_proj", x, self.beta_proj)
+        mixed = ConvOp("hetu_kda_conv", proj, self.conv, window=(0, 3 * hd))
+        o = _ScanOp(_scan, "hetu_kda_scan", proj, mixed, beta, self.a_log,
+                    self.dt_bias, lower_bound=self.lower_bound, **self.dims)
+        return _Scoped(_out, "hetu_kda_out", o, proj, self.norm,
+                       self.out_proj, eps=self.eps)

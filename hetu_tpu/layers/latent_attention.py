@@ -1,0 +1,150 @@
+"""Multi-head latent attention, the training half (DeepSeek-V2,
+arXiv:2405.04434; the softmax layers of Ling-3.0): keys and values come out
+of one low-rank latent, a head's query and key are a part without position
+and a rotary part, and the scores are wider than the values.
+
+    [q_h^nope (d_n) | q_h^rope (d_r)] = (x W_q)_h          no low-rank query
+    [c (r) | k^rope (d_r)] = x W_kva ;  c = RMSNorm_r(c)
+    [k_h^nope (d_n) | v_h (d_v)] = (c W_kvb)_h
+    q_h = RMSNorm(q_h),  k_h = RMSNorm([k_h^nope | k^rope])  over d_n + d_r
+                     (``qk_norm``: learned, one weight for all heads), then
+                     rotary on the last d_r of both (HF's half-split form)
+    o_h = softmax_causal(q_h k_h^T / sqrt(d_n + d_r)) v_h
+    y = [ o_h * sigmoid(x w_gate)_h ] W_o                  one gate a head
+
+The core product is the one fused-attention op (``ops/attention.py``): on a
+TPU the flash kernels over ``[B, H, S, d_n + d_r]`` queries and keys and
+``[B, H, S, d_v]`` values, no operand padded to the other's width.  What a
+serving cache would hold (the latent ``c`` and ``k^rope``, and the absorbed
+decode that reads them) is not here (ROADMAP Queue 2, M7).
+"""
+
+from __future__ import annotations
+
+from .base import BaseLayer, fresh_name
+from .. import initializers as init
+from ..graph.node import VariableOp, scope
+from ..ops.attention import scaled_dot_product_attention_op
+from ..ops.base import ScopedOp as _Scoped
+
+_SCOPE = "hetu_attn"
+
+
+def _project(x, w):
+    return x @ w
+
+
+def _rms(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (xf * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope_last(x, d_rope, theta):
+    """Rotary on the last ``d_rope`` of ``x [B, S, H, d]``."""
+    import jax.numpy as jnp
+    from ..ops.rotary import _rotary
+    keep = x.shape[-1] - d_rope
+    return jnp.concatenate(
+        [x[..., :keep], _rotary(x[..., keep:], theta=theta, seq_axis=1)], -1)
+
+
+def _latent(kva, w_norm, *, rank, eps):
+    """The normed latent ``c [B, S, r]`` out of ``x W_kva``."""
+    return _rms(kva[..., :rank], w_norm, eps)
+
+
+def _queries(q, *w_norm, heads, d_rope, theta, eps):
+    """``x W_q [B, S, H (d_n + d_r)]`` -> ``[B, H, S, d_n + d_r]``; ``w_norm``:
+    the norm's weight where the layer has one."""
+    B, S, _ = q.shape
+    q = q.reshape(B, S, heads, -1)
+    if w_norm:
+        q = _rms(q, w_norm[0], eps)
+    return _rope_last(q, d_rope, theta).transpose(0, 2, 1, 3)
+
+
+def _keys(kvb, kva, *w_norm, heads, d_nope, d_rope, rank, theta, eps):
+    """A head's key: its own part of ``c W_kvb`` beside the one rotary part
+    all heads share: ``[B, H, S, d_n + d_r]``."""
+    import jax.numpy as jnp
+    B, S, _ = kvb.shape
+    nope = kvb.reshape(B, S, heads, -1)[..., :d_nope]
+    rope = jnp.broadcast_to(kva[..., None, rank:], (B, S, heads, d_rope))
+    k = jnp.concatenate([nope, rope], -1)
+    if w_norm:
+        k = _rms(k, w_norm[0], eps)
+    return _rope_last(k, d_rope, theta).transpose(0, 2, 1, 3)
+
+
+def _values(kvb, *, heads, d_nope):
+    B, S, _ = kvb.shape
+    return kvb.reshape(B, S, heads, -1)[..., d_nope:].transpose(0, 2, 1, 3)
+
+
+def _out(ctx_, w_out, *gate):
+    """``[B, H, S, d_v]`` -> ``[B, S, H d_v]``, each head times the sigmoid
+    of its one gate number (where the layer has a gate), then ``W_o``."""
+    import jax
+    import jax.numpy as jnp
+    o = ctx_.transpose(0, 2, 1, 3)
+    if gate:
+        o = (o.astype(jnp.float32)
+             * jax.nn.sigmoid(gate[0].astype(jnp.float32))[..., None]
+             ).astype(ctx_.dtype)
+    return o.reshape(o.shape[:2] + (-1,)) @ w_out
+
+
+class LatentAttention(BaseLayer):
+    def __init__(self, hidden_size, num_heads, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, qk_norm=True, head_gate=True, eps=1e-6,
+                 name=None):
+        name = fresh_name(name or "mla")
+        self.num_heads = num_heads
+        self.d_nope, self.d_rope, self.d_v = (qk_nope_head_dim,
+                                              qk_rope_head_dim, v_head_dim)
+        self.rank, self.theta, self.eps = kv_lora_rank, rope_theta, eps
+        d_qk = qk_nope_head_dim + qk_rope_head_dim
+        self.d_qk = d_qk
+
+        def var(n, shape, how=None):
+            return VariableOp(f"{name}_{n}", shape,
+                              how or init.xavier_normal())
+        self.q_proj = var("q_weight", (hidden_size, num_heads * d_qk))
+        self.kva_proj = var("kva_weight",
+                            (hidden_size, kv_lora_rank + qk_rope_head_dim))
+        self.kv_norm = var("kv_norm_scale", (kv_lora_rank,), init.ones())
+        self.kvb_proj = var("kvb_weight",
+                            (kv_lora_rank,
+                             num_heads * (qk_nope_head_dim + v_head_dim)))
+        self.q_norm = self.k_norm = None
+        if qk_norm:
+            self.q_norm = var("q_norm_scale", (d_qk,), init.ones())
+            self.k_norm = var("k_norm_scale", (d_qk,), init.ones())
+        self.gate_proj = (var("gate_weight", (hidden_size, num_heads))
+                          if head_gate else None)
+        self.out_proj = var("out_weight",
+                            (num_heads * v_head_dim, hidden_size))
+
+    def __call__(self, x):
+        S = lambda fn, *a, **kw: _Scoped(fn, _SCOPE, *a, **kw)
+        rot = dict(heads=self.num_heads, d_rope=self.d_rope,
+                   theta=self.theta, eps=self.eps)
+        kva = S(_project, x, self.kva_proj)
+        c = S(_latent, kva, self.kv_norm, rank=self.rank, eps=self.eps)
+        kvb = S(_project, c, self.kvb_proj)
+        normed = self.q_norm is not None
+        q = S(_queries, S(_project, x, self.q_proj),
+              *([self.q_norm] if normed else []), **rot)
+        k = S(_keys, kvb, kva, *([self.k_norm] if normed else []),
+              d_nope=self.d_nope, rank=self.rank, **rot)
+        v = S(_values, kvb, heads=self.num_heads, d_nope=self.d_nope)
+        with scope(_SCOPE):
+            ctx_ = scaled_dot_product_attention_op(
+                q, k, v, causal=True, scale=self.d_qk ** -0.5)
+        gate = ([] if self.gate_proj is None
+                else [S(_project, x, self.gate_proj)])
+        return S(_out, ctx_, self.out_proj, *gate)

@@ -51,13 +51,17 @@ class TopKGate(BaseLayer):
     no optimizer state, and the layer's op moves it inside the training step
     by ``bias_rate * sign(mean(load) - load)`` from the step's pair counts
     over all ``E`` experts (DeepSeek-V3's auxiliary-loss-free balancing);
-    ``bias_rate=None`` leaves it where it is."""
+    ``bias_rate=None`` leaves it where it is.  ``groups=(n_group,
+    topk_group)`` limits the choice to the experts of the best groups
+    (dropless path only)."""
 
     def __init__(self, hidden_size, num_experts, renorm=True, name=None,
-                 score="softmax", scale=None, bias_rate=None):
+                 score="softmax", scale=None, bias_rate=None, groups=None):
         name = fresh_name(name or "gate")
         self.renorm = renorm
         self.score, self.scale, self.bias_rate = score, scale, bias_rate
+        #: (n_group, topk_group): group-limited selection (``top_k_route``)
+        self.groups = groups
         self.wg = VariableOp(f"{name}_w", (hidden_size, num_experts),
                              init.xavier_uniform())
         self.bias = VariableOp(f"{name}_bias", (num_experts,), init.zeros(),
@@ -84,7 +88,7 @@ class TopKGate(BaseLayer):
                             precision=jax.lax.Precision.HIGHEST)
         return (logits,) + top_k_route(logits, k, renorm=self.renorm,
                                        score=self.score, bias=bias,
-                                       scale=self.scale)
+                                       scale=self.scale, groups=self.groups)
 
     def aux(self, tokens, wg, ids, k):
         return top_k_balance_aux(tokens @ wg)
@@ -513,7 +517,8 @@ class MoELayer(BaseLayer):
     run behind a capacity; ``"relu2"`` runs on the dropless path alone.
     ``renorm_topk`` is the gate's ``renorm``; ``router_score="sigmoid"``,
     ``router_scale`` and ``router_bias_rate`` are the gate's ``score``,
-    ``scale`` and ``bias_rate`` (``TopKGate``; dropless path alone), and
+    ``scale`` and ``bias_rate``, ``router_groups`` its ``groups`` (``TopKGate``;
+    dropless path alone), and
     ``router_bias()`` fetches the bias as ``load()`` fetches the load.
     ``track_load`` adds a ``[2, E]`` state variable of per-expert pair
     counts (routed, kept) that ``load()`` fetches.
@@ -539,7 +544,8 @@ class MoELayer(BaseLayer):
                  num_groups=None, sparse=True, expert_act="gelu",
                  renorm_topk=True, track_load=False, held=None,
                  shared_width=None, shared_gate=True, router_score="softmax",
-                 router_scale=None, router_bias_rate=None, name=None):
+                 router_scale=None, router_bias_rate=None,
+                 router_groups=None, name=None):
         name = fresh_name(name or "moe")
         self.held = held
         n_held = num_experts
@@ -553,7 +559,10 @@ class MoELayer(BaseLayer):
             self.gate = TopKGate(hidden_size, num_experts,
                                  renorm=renorm_topk, name=name,
                                  score=router_score, scale=router_scale,
-                                 bias_rate=router_bias_rate)
+                                 bias_rate=router_bias_rate,
+                                 groups=router_groups)
+            assert router_groups is None or capacity_factor is None, (
+                "group-limited selection is the dropless path's")
         elif gate == "hash":
             self.gate = HashGate(num_experts)
         elif gate == "ktop1":

@@ -15,11 +15,14 @@ from .nemotron_h import (NemotronHConfig, NemotronHModel,
 from .granite_hybrid import (GraniteHybridConfig, GraniteHybridModel,
                              GraniteHybridForCausalLM,
                              GRANITE_HYBRID_CONFIGS)
+from .ling3 import (Ling3Config, Ling3Model, Ling3ForCausalLM,
+                    LING3_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,
                         load_hf_mixtral_weights,
-                        load_hf_granite_hybrid_weights)
+                        load_hf_granite_hybrid_weights,
+                        load_hf_ling3_weights)
 from .zoo import (LogReg, CNN3, AlexNet, VGG, vgg16, vgg19,
                   RNNClassifier, LSTMClassifier)
 from .rec import (RatingModelHead, MFHead, GMFHead, MLPHead, NeuMFHead,

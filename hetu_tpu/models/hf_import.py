@@ -301,3 +301,89 @@ def load_hf_mixtral_weights(executor, model, state_dict, name="llama"):
     if model.lm_head is not None:
         _put(p, f"{name}_lm_head_weight", sd["lm_head.weight"].T)
     return executor
+
+
+#: key prefixes of a Ling-3.0 checkpoint that name what is not modelled
+LING3_REFUSED = ("visual.", "vision", "image_", "video_", "audio", "mtp.",
+                 "mtp_", "nextn", "eh_proj", "enorm", "hnorm")
+
+
+def load_hf_ling3_weights(executor, model, state_dict, name="ling3"):
+    """Copy a Ling-3.0 (``bailing_hybrid``) language-model state_dict into a
+    ``Ling3ForCausalLM``, for the layers that are modelled.
+
+    The catalog row shows no modelling code, so the key names are a reading:
+    Ling 2.0's (``BailingMoeV2``: ``word_embeddings``, ``attention.``,
+    ``mlp.gate.weight`` with ``expert_bias``, ``mlp.experts.<j>.``,
+    ``mlp.shared_experts.``) and, for a KDA layer, flash-linear-attention's
+    (``q_proj`` .. ``o_proj``, ``q_conv1d``, ``f_proj``, ``g_proj``,
+    ``b_proj``, ``A_log``, ``dt_bias``, ``o_norm``).  Matrices come over
+    transposed; a KDA layer's five projections are laid side by side (``q |
+    k | v | f | g``) and its three ``[C, 1, K]`` convolutions as one ``[K, 3
+    C]``; a latent layer's ``kv_a_proj_with_mqa``, ``kv_a_layernorm`` and
+    ``kv_b_proj`` as they are named in DeepSeek-V2's.  With
+    ``experts_held`` the held experts alone are read; where the model holds a
+    slice of the vocabulary, the first rows.  **A checkpoint's vision and
+    multi-token-prediction weights are refused by name** (``LING3_REFUSED``),
+    not skipped: the model that would come out is not the checkpoint's."""
+    sd = _numpy_state(state_dict)
+    bad = sorted(k for k in sd if any(k.startswith(r) or f".{r}" in k
+                                      for r in LING3_REFUSED))
+    if bad:
+        raise ValueError(
+            "Ling-3.0 import: the vision tower and multi-token prediction "
+            f"are not modelled; the checkpoint holds {bad[:4]} "
+            f"({len(bad)} such keys)")
+    p = executor.params
+    cfg = model.config
+    t = lambda key: sd[key].T
+    _put(p, f"{name}_embed_table",
+         sd["word_embeddings.weight"][:cfg.vocab_size])
+    for i, layer in enumerate(model.model.layers):
+        hf = f"layers.{i}."
+        a, m, f = hf + "attention.", layer.mixer, layer.mlp
+        if layer.kind == "attention":
+            _put(p, m.q_proj.name, t(a + "q_proj.weight"))
+            _put(p, m.kva_proj.name, t(a + "kv_a_proj_with_mqa.weight"))
+            _put(p, m.kv_norm.name, sd[a + "kv_a_layernorm.weight"])
+            _put(p, m.kvb_proj.name, t(a + "kv_b_proj.weight"))
+            _put(p, m.q_norm.name, sd[a + "query_layernorm.weight"])
+            _put(p, m.k_norm.name, sd[a + "key_layernorm.weight"])
+            _put(p, m.gate_proj.name, t(a + "g_proj.weight"))
+            _put(p, m.out_proj.name, t(a + "dense.weight"))
+        else:
+            _put(p, m.in_proj.name, np.concatenate(
+                [t(a + f"{n}_proj.weight") for n in "qkvfg"], axis=1))
+            _put(p, m.beta_proj.name, t(a + "b_proj.weight"))
+            _put(p, m.conv.name, np.concatenate(
+                [sd[a + f"{n}_conv1d.weight"][:, 0, :].T for n in "qkv"],
+                axis=1))
+            _put(p, m.a_log.name, sd[a + "A_log"].reshape(-1))
+            _put(p, m.dt_bias.name, sd[a + "dt_bias"].reshape(-1))
+            _put(p, m.norm.name, sd[a + "o_norm.weight"])
+            _put(p, m.out_proj.name, t(a + "o_proj.weight"))
+        if layer.dense:
+            for ours, theirs in ((f.gate, "gate_proj"), (f.up, "up_proj"),
+                                 (f.down, "down_proj")):
+                _put(p, ours.weight.name, t(hf + f"mlp.{theirs}.weight"))
+        else:
+            first, count = cfg.experts_held or (0, cfg.num_experts)
+            _put(p, f.gate.wg.name, t(hf + "mlp.gate.weight"))
+            _put(p, f.gate.bias.name, sd[hf + "mlp.gate.expert_bias"])
+            for var, theirs in ((f.w1, "gate_proj"), (f.w3, "up_proj"),
+                                (f.w2, "down_proj")):
+                _put(p, var.name, np.stack(
+                    [t(hf + f"mlp.experts.{j}.{theirs}.weight")
+                     for j in range(first, first + count)]))
+            for var, theirs in zip(f.shared, ("gate_proj", "up_proj",
+                                              "down_proj")):
+                _put(p, var.name,
+                     t(hf + f"mlp.shared_experts.{theirs}.weight"))
+        _put(p, layer.input_norm.scale.name,
+             sd[hf + "input_layernorm.weight"])
+        _put(p, layer.post_norm.scale.name,
+             sd[hf + "post_attention_layernorm.weight"])
+    _put(p, model.model.norm.scale.name, sd["norm.weight"])
+    _put(p, model.lm_head.weight.name,
+         t("lm_head.weight")[:, :cfg.vocab_size])
+    return executor

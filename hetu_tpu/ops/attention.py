@@ -51,7 +51,8 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None):
         return why, (), ()
     if q.shape[-2] < _FLASH_MIN_SEQ:
         return f"seq<{_FLASH_MIN_SEQ}", (), ()
-    if not (32 <= q.shape[-1] <= 512 and q.shape[-1] % 8 == 0):
+    if not all(32 <= d <= 512 and d % 8 == 0
+               for d in (q.shape[-1], v.shape[-1])):
         return "head_dim_not_8_aligned_in_32_512", (), ()
     # under a mesh: per shard, batch over 'dp' and heads over 'tp'
     why, axes = dispatch.shard_axes(mesh, {"dp": q.shape[0],
@@ -91,7 +92,7 @@ class ScaledDotProductAttentionOp(Op):
         # shard's heads do not come in lane-aligned groups
         out = self._attend(*(_split_heads(x, heads) for x in (q, k, v)),
                            mask, ctx, None)
-        return out.transpose(0, 2, 1, 3).reshape(q.shape)
+        return out.transpose(0, 2, 1, 3).reshape(q.shape[:2] + (-1,))
 
     def _keep(self, ctx):
         return self.dropout_keep if ctx.training else 1.0
@@ -160,7 +161,7 @@ class ScaledDotProductAttentionOp(Op):
             return flash_attention(q, k, v, **kw)
         qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
         if heads is not None:
-            q, k, v = (x.reshape(*x.shape[:2], heads, d) for x in (q, k, v))
+            q, k, v = (x.reshape(*x.shape[:2], heads, -1) for x in (q, k, v))
             qk, pv = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
         scores = jnp.einsum(qk, q, k,
                             preferred_element_type=jnp.float32) * scale

@@ -375,7 +375,7 @@ def sam_group_sum(x, group_idx, num_groups):
 # times the expert work).
 
 def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
-                scale=None):
+                scale=None, groups=None):
     """``(idx [T, k] int32, gate [T, k] f32, probs [T, E] f32)``: the ``k``
     largest softmax probabilities of each token, largest first, ties to the
     lower expert index; ``renorm`` rescales them to sum to 1 (Mixtral), the
@@ -388,7 +388,14 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
     the largest of ``s + bias`` (``bias [E]``: a selection bias that balances
     the load and is no part of the weights), the gates are the chosen ``s``
     themselves (renormalised where ``renorm``) times ``scale``, and ``probs``
-    is ``s / sum_j s_j``, what the balance loss averages."""
+    is ``s / sum_j s_j``, what the balance loss averages.
+
+    ``groups=(n_group, topk_group)`` is DeepSeek-V3's group-limited selection
+    (eq. 16's node-limited routing; Ling 2.0's ``BailingMoeV2``): the experts
+    in ``n_group`` groups of neighbours, a group scored by the sum of its two
+    largest ``s + bias``, the ``topk_group`` best groups kept (ties to the
+    lower group) and the ``k`` experts chosen among theirs alone.  One group
+    is the ungrouped router, bit for bit."""
     assert score in ("softmax", "sigmoid"), score
     assert bias is None or score == "sigmoid", "the bias selects by sigmoid"
     if score == "softmax":
@@ -396,8 +403,20 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
     else:
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
         probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
-    _, idx = jax.lax.top_k(scores if bias is None
-                           else scores + bias.astype(jnp.float32), k)
+    chosen_by = (scores if bias is None
+                 else scores + bias.astype(jnp.float32))
+    if groups is not None and groups[0] > 1:
+        n_group, topk_group = groups
+        T, E = chosen_by.shape
+        assert E % n_group == 0 and k <= topk_group * (E // n_group), groups
+        by_group = chosen_by.reshape(T, n_group, E // n_group)
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32),
+                       axis=1) > 0                           # [T, n_group]
+        chosen_by = jnp.where(kept[:, :, None], by_group,
+                              -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(chosen_by, k)
     # the gates by a one-hot product, not top_k's values: its backward
     # pass is then a product too and not a scatter-add into [T, E]
     gate = jnp.sum(jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)

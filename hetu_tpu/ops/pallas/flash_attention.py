@@ -79,9 +79,11 @@ def unsupported(q, k, v, mask=None, dropout_keep=1.0):
     """Why the kernel cannot take these ``[B, H, S, D]`` operands, or None
     when it can.  Callers that fall back to the jnp composition record this
     string (ops/pallas/dispatch.py)."""
-    if q.ndim != 4 or k.shape != q.shape or v.shape != q.shape:
+    if (q.ndim != 4 or k.shape != q.shape or v.ndim != 4
+            or v.shape[:3] != q.shape[:3]):
         return "not_self_attention_4d"
     b, h, s, d = q.shape
+    d = max(d, v.shape[3])
     # head dim is always the FULL last block dim, so Mosaic only needs it
     # 8-aligned (the wrapper pads to that); > 512 would blow VMEM tiles
     if d > 512:
@@ -244,6 +246,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
     b, hg, qi = (pl.program_id(a) for a in range(3))
     bq, width = q_ref.shape[1], q_ref.shape[2]
     dim = width // group
+    # v and o are as wide as q and k, or (one head a program) of a head size
+    # of their own: scores over q's width, the context over v's
+    v_width = v_ref.shape[2]
     q_all = q_ref[0]                                  # (bq, g*d)
     q_off = offs_ref[0] if offs_ref is not None else 0
     k_off = offs_ref[1] if offs_ref is not None else 0
@@ -300,7 +305,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         q = _only_head(q_all, h, dim, group)
         m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((bq,), jnp.float32)
-        acc0 = jnp.zeros((bq, width), jnp.float32)
+        acc0 = jnp.zeros((bq, v_width), jnp.float32)
         if causal and nk > 8:
             # split loop: kv blocks fully below the diagonal need no mask —
             # the where+iota per tile is pure VPU overhead on ~(nk-1)/nk of
@@ -387,10 +392,12 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
     """q: [b,h,sq,d]; k,v: [b,h,sk,d] (sq != sk in the blockwise/ring path,
     where ``offsets`` = int32[2] global [q_off, k_off]), or all three
     [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq])."""
-    w = _walk(q, num_heads)
+    w, wv = _walk(q, num_heads), _walk(v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
-    kv_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
+    o_spec = pl.BlockSpec((1, block_q, wv.width), w.rows(lambda t: t))
+    k_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
+    v_spec = pl.BlockSpec((1, sk, wv.width), w.rows(lambda t: 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
     kern = _make_kern(_fwd_kernel, 3, mask is not None, keep_prob < 1.0,
                       offsets is not None,
@@ -405,21 +412,22 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
         name="hetu_flash_fwd",
         interpret=interpret(),
         grid=(w.batch, groups, sq // block_q),
-        in_specs=[q_spec, kv_spec, kv_spec] + extra_specs,
+        in_specs=[q_spec, k_spec, v_spec] + extra_specs,
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((1, 1, w.group, block_q),
                          lambda b, hg, t: (b, hg, 0, t)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(w.flat(q).shape, q.dtype),
+            jax.ShapeDtypeStruct(
+                w.flat(q).shape[:-1] + wv.flat(v).shape[-1:], q.dtype),
             jax.ShapeDtypeStruct((w.batch, groups, w.group, sq),
                                  jnp.float32),
         ],
         compiler_params=_compiler_params(
-            (2 * block_q + 2 * sk) * w.width * item),
-    )(w.flat(q), w.flat(k), w.flat(v), *extra_args)
-    return w.unflat(o), lse
+            (block_q + sk) * (w.width + wv.width) * item),
+    )(w.flat(q), w.flat(k), wv.flat(v), *extra_args)
+    return wv.unflat(o), lse
 
 
 # -- backward --------------------------------------------------------------
@@ -494,13 +502,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
         return dk, dv
 
     zeros = jnp.zeros((bk, width), jnp.float32)
+    v_zeros = (zeros if v_ref.shape[2] == width
+               else jnp.zeros((bk, v_ref.shape[2]), jnp.float32))
     i_start = 0
     if causal:
         # q tiles strictly above the diagonal see none of this kv block;
         # with offsets the bound is dynamic (global positions)
         lo = (k_off + kj * bk - q_off) // block_q
         i_start = jax.lax.clamp(0, lo, nq) if offs_ref is not None else lo
-    dk, dv = jax.lax.fori_loop(i_start, nq, body, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(i_start, nq, body, (zeros, v_zeros))
     dk_ref[0] = (dk * (scale / keep_prob)).astype(dk_ref.dtype)
     dv_ref[0] = (dv * (1.0 / keep_prob)).astype(dv_ref.dtype)
 
@@ -514,10 +524,12 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
               num_heads=None):
     """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
     it."""
-    w = _walk(q, num_heads)
+    w, wv = _walk(q, num_heads), _walk(v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
-    kv_spec = pl.BlockSpec((1, block_k, w.width), w.rows(lambda t: t))
+    whole_o = pl.BlockSpec((1, sq, wv.width), w.rows(lambda t: 0))
+    k_spec = pl.BlockSpec((1, block_k, w.width), w.rows(lambda t: t))
+    v_spec = pl.BlockSpec((1, block_k, wv.width), w.rows(lambda t: t))
     lse_spec = pl.BlockSpec((1, 1, w.group, sq),
                             lambda b, hg, t: (b, hg, 0, 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
@@ -530,16 +542,18 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
     dq, dk, dv = pl.pallas_call(
         kern, name="hetu_flash_bwd", interpret=interpret(),
         grid=(w.batch, w.heads // w.group, sk // block_k),
-        in_specs=[whole_q, kv_spec, kv_spec, whole_q, whole_q, lse_spec]
+        in_specs=[whole_q, k_spec, v_spec, whole_o, whole_o, lse_spec]
         + extra_specs,
-        out_specs=[whole_q, kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(w.flat(t).shape, t.dtype)
-                   for t in (q, k, v)],
+        out_specs=[whole_q, k_spec, v_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.flat(t).shape, t.dtype)
+                   for x, t in ((w, q), (w, k), (wv, v))],
         scratch_shapes=[pltpu.VMEM((sq, w.width), jnp.float32)],
         compiler_params=_compiler_params(
-            (4 * sq + 4 * block_k) * w.width * item + sq * w.width * 4),
-    )(*(w.flat(t) for t in (q, k, v, o, dout)), lse, *extra_args)
-    return w.unflat(dq), w.unflat(dk), w.unflat(dv)
+            2 * (sq + block_k) * (w.width + wv.width) * item
+            + sq * w.width * 4),
+    )(w.flat(q), w.flat(k), wv.flat(v), wv.flat(o), wv.flat(dout), lse,
+      *extra_args)
+    return w.unflat(dq), w.unflat(dk), wv.unflat(dv)
 
 
 # -- custom-vjp wrapper ----------------------------------------------------
@@ -632,15 +646,18 @@ def flash_attention_block_bwd(q, k, v, o, lse, dout, q_off, k_off, *,
                      block_k=bk, offsets=offsets)
 
 
-def _count_entry(walk):
+def _count_entry(walk, v_dim):
     """Trace-time count of the walk taken, beside ``dispatch.record``'s
-    count of the kernel-versus-jnp choice."""
+    count of the kernel-versus-jnp choice.  Values narrower (or wider) than
+    the keys are the layout ``bhsd_v<head size of v>``."""
     telemetry.get_registry().counter(
         "hetu_flash_attention_entry_total",
         "Trace-time flash attention calls by operand layout and the heads "
         "one program takes",
         labels=("layout", "heads_per_program"),
-    ).labels(layout=walk.layout, heads_per_program=str(walk.group)).inc()
+    ).labels(layout=walk.layout + ("" if v_dim == walk.dim
+                                   else f"_v{v_dim}"),
+             heads_per_program=str(walk.group)).inc()
 
 
 def entries():
@@ -674,7 +691,11 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
             "flash_attention: dropout_keep < 1 requires seed= (an int32 "
             "scalar array; the per-tile dropout masks derive from it)")
     w = _walk(q, num_heads)
-    _count_entry(w)
+    dv = _walk(v, num_heads).dim
+    # two head sizes (latent attention: keys 192 wide, values 128) come as
+    # [B, H, S, D]; heads read in place are one size
+    assert dv == w.dim or q.ndim == 4, (q.shape, v.shape)
+    _count_entry(w, dv)
     s, d = w.seq(q), w.dim
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -685,13 +706,15 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     # custom_vjp so jnp.pad's VJP zero-fills the padded rows' cotangents
     # and the gradients of the real region stay exact.  In-place heads are
     # 32, 64 or a multiple of 128 wide: never padded
-    d_pad = max(32, -(-d // 8) * 8)
+    d_pad, dv_pad = (max(32, -(-x // 8) * 8) for x in (d, dv))
     s_pad, block = _pad_plan(s)
-    if d_pad != d or s_pad != s:
+    if d_pad != d or dv_pad != dv or s_pad != s:
         pad = [(0, 0)] * q.ndim
         pad[1 if q.ndim == 3 else 2] = (0, s_pad - s)
         pad[-1] = (0, d_pad - d)
-        q, k, v = (jnp.pad(t, pad) for t in (q, k, v))
+        q, k = (jnp.pad(t, pad) for t in (q, k))
+        pad[-1] = (0, dv_pad - dv)
+        v = jnp.pad(v, pad)
         if s_pad != s and not (causal and mask is None):
             # padded key columns must not attend; real causal rows never
             # see columns ≥ s, so pure-causal needs no mask
@@ -702,8 +725,8 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
 
     out = _flash_call(q, k, v, mask, seed, causal, float(scale),
                       float(dropout_keep), block, num_heads)
-    if d_pad != d or s_pad != s:
-        out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :d]
+    if d_pad != d or dv_pad != dv or s_pad != s:
+        out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :dv]
     return out
 
 

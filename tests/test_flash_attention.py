@@ -695,6 +695,31 @@ def test_kernels_compile_for_v5e(v5e, as_on_tpu, shape, heads, with_mask,
     assert "hetu_flash_fwd" in kernels[0] and "hetu_flash_bwd" in kernels[1]
 
 
+def test_keys_wider_than_values_compile_for_v5e(v5e, as_on_tpu):
+    """The Ling-3.0 cell's latent-attention layer: 32 heads over 8,192
+    positions, queries and keys 192 wide, values and the context 128, no
+    operand padded to the other's width."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    q = sds((1, 32, 8192, 192), jnp.bfloat16)
+    v = sds((1, 32, 8192, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       scale=192 ** -0.5).astype(
+                                           jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_flash_fwd" in kernels[0] and "hetu_flash_bwd" in kernels[1]
+    assert "bf16[32,8192,128]" in kernels[0].split(" custom-call")[0]
+    assert "bf16[32,8192,192]" in kernels[1].split(" custom-call")[0]
+    assert "bf16[32,8192,128]" in kernels[1].split(" custom-call")[0]
+
+
 def test_in_place_kernels_compile_per_shard_on_four_chips(v5e, as_on_tpu):
     """DataParallel(4)'s BERT shard under shard_map: the kernels see the
     local [64, 512, 768], and nothing is transposed or copied around them."""

@@ -352,3 +352,51 @@ def test_layer_step_compiles_for_v5e(v5e, gdn_choices, monkeypatch):
     assert "triangular_solve" not in hlo.lower()
     assert not re.findall(r"\bwhile\(", hlo)
     assert not re.findall(r" = f32\[[\d,]*64,64\]\S* ", hlo)
+
+
+def test_kda_layer_step_compiles_for_v5e(v5e, monkeypatch):
+    """The Ling-3.0 cell's KDA mixer (32 heads of 128, 8,192 positions, bf16,
+    a decay a channel in f32), forward and backward from the scan node's
+    inputs: ``hetu_kda_fwd`` and ``hetu_kda_bwd`` and nothing else of the
+    rule's: no ``triangular_solve``, no ``while``, no ``[.., 64, 64]`` f32
+    array in HBM; the kernels read and write ``[1, 8192, 4096]`` in place
+    (``hetu_gdn_*``'s helpers inside: the Qwen3-Next case above is what holds
+    those to what they were)."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu import telemetry
+    from hetu_tpu.layers.kda import _scan
+    telemetry.enable()
+    try:
+        before = {k: n for k, n in dispatch.choices().items()
+                  if k[0] == "kda"}
+        monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+        one = SingleDeviceSharding(v5e.devices[0])
+        sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+        H = 32
+
+        def loss(proj, mixed, beta, a_log, dt_bias):
+            with jax.named_scope("hetu_kda_scan"):
+                o = _scan(proj, mixed, beta, a_log, dt_bias, heads=H, d=D,
+                          lower_bound=-5.0)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            sds((1, 8192, 5 * H * D), jnp.bfloat16),
+            sds((1, 8192, 3 * H * D), jnp.bfloat16),
+            sds((1, 8192, H), jnp.bfloat16), sds((H,), jnp.float32),
+            sds((H * D,), jnp.float32)).compile().as_text()
+        after = {k: n for k, n in dispatch.choices().items()
+                 if k[0] == "kda"}
+    finally:
+        telemetry.disable()
+    assert after.get(("kda", "pallas", ""), 0) == before.get(
+        ("kda", "pallas", ""), 0) + 1
+    assert not [k for k in after if k[1] == "jnp" and k not in before]
+    kernels_ = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels_) == 2
+    assert "hetu_kda_fwd" in kernels_[0] and "hetu_kda_bwd" in kernels_[1]
+    assert all("bf16[1,8192,4096]" in ln for ln in kernels_)
+    assert "triangular_solve" not in hlo.lower()
+    assert not re.findall(r"\bwhile\(", hlo)
+    assert not re.findall(r" = f32\[[\d,]*64,64\]\S* ", hlo)
