@@ -18,9 +18,18 @@ The decay and output-gate projections are single full-rank matrices (the
 configuration's ``no_kda_lora``), so all five projections of ``x`` are one
 product.  Four graph nodes under the scopes ``hetu_kda_proj``,
 ``hetu_kda_conv`` (``ConvOp``: on a TPU ``hetu_conv_fwd`` / ``hetu_conv_bwd``),
-``hetu_kda_scan`` (norms, gates and the chunked rule: on a TPU
-``hetu_kda_fwd`` / ``hetu_kda_bwd``) and ``hetu_kda_out``.  A decode step and
-the recurrent state in a serving cache are not here (ROADMAP Queue 2, M7).
+``hetu_kda_scan`` and ``hetu_kda_out``.  Where the rule's kernels apply (a
+TPU, no mesh, heads of a multiple of 128, bf16 or f32: ``ops/kda.py
+chunk_kda_in_place``) the scan node is ``hetu_kda_fwd`` / ``hetu_kda_bwd``
+reading the convolution's output and the projection's ``f`` and ``z`` windows
+in place, with the norms, the gate and the gated norm of ``o`` taken a head
+on the chunk in VMEM: it hands ``hetu_kda_out`` the normalised, gated ``[B,
+S, heads d]``, that node is the product alone, and no ``[B, S, heads, d]``
+view is formed (PR 41; the backward keeps ``mixed``, ``proj``, ``beta`` and
+the chunk-start states).  Elsewhere the scan node is the ``jax.numpy`` norms
+and gate around ``chunk_kda`` and ``hetu_kda_out`` norms and gates the 4-D
+``o`` it is handed.  A decode step and the recurrent state in a serving cache
+are not here (ROADMAP Queue 2, M7).
 """
 
 from __future__ import annotations
@@ -51,14 +60,25 @@ def gate(f, a_log, dt_bias, lower_bound):
                                                             -1)))
 
 
-def _scan(proj, mixed, beta_lin, a_log, dt_bias, *, heads, d, lower_bound,
-          rule=None):
+def _scan(proj, mixed, beta_lin, a_log, dt_bias, w_norm, *, heads, d,
+          lower_bound, eps, rule=None):
+    """Norms, gates and the rule: where the kernels apply (``rule`` None
+    and ``chunk_kda_in_place`` takes the operands) the already normalised
+    and gated ``y [B, S, heads d]``, else ``o [B, S, heads, d]`` for
+    ``_out``'s norm and gate."""
     import jax
     import jax.numpy as jnp
     from ..ops import kda
     B, S, _ = mixed.shape
     f32 = jnp.float32
     hd = heads * d
+    beta = jax.nn.sigmoid(beta_lin.astype(f32))
+    if rule is None:
+        y = kda.chunk_kda_in_place(mixed, proj, beta, a_log, dt_bias, w_norm,
+                                   heads=heads, lower_bound=lower_bound,
+                                   eps=eps)
+        if y is not None:
+            return y
 
     def unit(t):
         t = t.reshape(B, S, heads, d).astype(f32)
@@ -68,7 +88,6 @@ def _scan(proj, mixed, beta_lin, a_log, dt_bias, *, heads, d, lower_bound,
     v = mixed[..., 2 * hd:].reshape(B, S, heads, d)
     g = gate(proj[..., 3 * hd:4 * hd].reshape(B, S, heads, d), a_log,
              dt_bias, lower_bound)
-    beta = jax.nn.sigmoid(beta_lin.astype(f32))
     return (rule or kda.chunk_kda)(q, k, v, g, beta)[0]
 
 
@@ -92,9 +111,12 @@ class _ScanOp(_Scoped):
 def _out(o, proj, w_norm, w_out, *, eps):
     """RMSNorm over each head's ``d`` scaled by ``w_norm``, gated channel by
     channel by ``sigmoid(z)`` (the last block of the projection), then the
-    output projection."""
+    output projection; the product alone where the scan node hands over ``y
+    [B, S, heads d]``, normalised and gated in its kernel."""
     import jax
     import jax.numpy as jnp
+    if o.ndim == 3:
+        return o @ w_out
     f32 = jnp.float32
     B, S, heads, d = o.shape
     z = proj[..., 4 * heads * d:].reshape(o.shape)
@@ -137,6 +159,7 @@ class KimiDeltaAttention(BaseLayer):
         beta = _Scoped(_project, "hetu_kda_proj", x, self.beta_proj)
         mixed = ConvOp("hetu_kda_conv", proj, self.conv, window=(0, 3 * hd))
         o = _ScanOp(_scan, "hetu_kda_scan", proj, mixed, beta, self.a_log,
-                    self.dt_bias, lower_bound=self.lower_bound, **self.dims)
+                    self.dt_bias, self.norm, lower_bound=self.lower_bound,
+                    eps=self.eps, **self.dims)
         return _Scoped(_out, "hetu_kda_out", o, proj, self.norm,
                        self.out_proj, eps=self.eps)

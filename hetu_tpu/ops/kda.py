@@ -52,21 +52,29 @@ Shapes: ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``g [B, T, H, d_k]``
 f32, ``beta [B, T, H]``; returns ``o [B, T, H, d_v]`` in ``v``'s type and the
 final state ``[B, H, d_k, d_v]`` f32.
 
-What runs where.  ``chunk_kda`` is what the layer's ``hetu_kda_scan`` node
-calls (``layers/kda.py``) and what the benchmark's probe calls.  On a TPU it
-runs as two Pallas kernels, ``hetu_kda_fwd`` and ``hetu_kda_bwd``
-(``ops/pallas/kda.py``, a ``jax.custom_vjp``; the backward keeps the
-chunk-start states and rebuilds the rest), where it can read that they
-apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk`` 64, q, k and v all
-bf16 or all f32, ``g`` f32.  Each call counts its choice at trace time in
-``hetu_kernel_choice_total{kernel="kda", impl, reason}``: ``pallas``, or
-``jnp`` with ``head_dim_not_128_aligned``, ``chunk!=64``, ``dtype:<name>``,
-``dtype:mixed`` or ``gate_dtype:<name>``.  A mesh is the one thing the
-function cannot see: the scan node reads it, calls ``chunk_kda_jnp`` itself
-and counts ``mesh``.  On any other platform there is no Mosaic and no
-choice: nothing is counted and ``chunk_kda_jnp`` runs.  The kernels
-themselves run anywhere when called directly (interpret mode on the CPU):
-``tests/test_kda.py``.
+What runs where.  ``chunk_kda`` is what the benchmark's probe calls and what
+the layer's ``hetu_kda_scan`` node (``layers/kda.py``) calls wherever the
+kernels do not run.  On a TPU it runs as two Pallas kernels, ``hetu_kda_fwd``
+and ``hetu_kda_bwd`` (``ops/pallas/kda.py``, a ``jax.custom_vjp``; the
+backward keeps the chunk-start states and rebuilds the rest), where it can
+read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk`` 64, q,
+k and v all bf16 or all f32, ``g`` f32.  Each call counts its choice at
+trace time in ``hetu_kernel_choice_total{kernel="kda", impl, reason}``:
+``pallas``, or ``jnp`` with ``head_dim_not_128_aligned``, ``chunk!=64``,
+``dtype:<name>``, ``dtype:mixed`` or ``gate_dtype:<name>``.
+``chunk_kda_in_place`` (PR 41) is the same kernel pair under the same rule
+handed what the layer has: it reads the convolution's output ``[B, T, 3 H
+d]`` and the ``f`` and ``z`` windows of the projection ``[B, T, 5 H d]`` in
+place, takes the head's norms, its gate and the gated norm of its output on
+the chunk in VMEM, and returns the ``[B, T, H d]`` that the output product
+reads; it counts ``pallas`` once a call too, and returns None where
+``chunk_kda`` would take the ``jax.numpy`` form (which then counts why).
+Which of the two ran is in ``hetu_kda_entry_total{form}`` (``in_place`` /
+``plain``).  A mesh is the one thing the functions cannot see: the scan node
+reads it, calls ``chunk_kda_jnp`` itself and counts ``mesh``.  On any other
+platform there is no Mosaic and no choice: nothing is counted and
+``chunk_kda_jnp`` runs.  The kernels themselves run anywhere when called
+directly (interpret mode on the CPU): ``tests/test_kda.py``.
 """
 
 from __future__ import annotations
@@ -113,6 +121,31 @@ def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
             "kda", kernels.unsupported(q, k, v, g, chunk)):
         return kernels.kda(q, k, v, g, beta)
     return chunk_kda_jnp(q, k, v, g, beta, chunk)
+
+
+def chunk_kda_in_place(mixed, proj, beta, a_log, dt_bias, scale, *, heads,
+                       lower_bound, eps):
+    """The whole mixer between its convolution and its output product where
+    ``chunk_kda``'s kernels apply, else None (the caller then runs its
+    ``jax.numpy`` form around ``chunk_kda``, which counts why): on a TPU,
+    under the rule ``chunk_kda`` reads, from ``mixed [B, T, 3 H d]`` (``q~ |
+    k~ | v``), ``proj [B, T, 5 H d]`` (``.. | f | z``), ``beta [B, T, H]``,
+    ``a_log [H]``, ``dt_bias [H d]`` and the norm's ``scale [d]`` to the
+    normalised, gated ``y [B, T, H d]`` (``ops/pallas/kda.py kda_in_place``:
+    the windows read in place, no ``[B, T, H, d]`` view formed)."""
+    from .pallas import dispatch, kda as kernels
+    B, T, _ = mixed.shape
+    d = mixed.shape[2] // (3 * heads)
+    head = jax.ShapeDtypeStruct((B, T, heads, d), mixed.dtype)
+    reason = kernels.unsupported(
+        head, jax.ShapeDtypeStruct(head.shape, proj.dtype), head,
+        jax.ShapeDtypeStruct(head.shape, jnp.float32), CHUNK)
+    if not dispatch.mosaic() or reason is not None:
+        return None
+    dispatch.record("kda")
+    rate = jnp.repeat(jnp.exp(a_log.astype(jnp.float32)), d)
+    return kernels.kda_in_place(mixed, proj, beta, rate, dt_bias, scale,
+                                lower_bound=lower_bound, eps=eps)
 
 
 def _pair_decay(a, k, G, ct, sub=SUB):

@@ -13,13 +13,33 @@ VMEM (``_chunks``) and writes ``o`` once and the state each chunk starts
 from.
 
 ``hetu_kda_bwd``: the same grid with the chunks in reverse and ``dS`` in
-VMEM scratch.  A program rebuilds its chunks from q, k, v, g, beta and the
-kept chunk-start states and pulls ``do`` and ``dS`` back through them: the
+VMEM scratch.  A program rebuilds its chunks from its operands and the kept
+chunk-start states and pulls ``do`` and ``dS`` back through them: the
 backward pass of a chunk is ``jax.vjp`` of ``_chunks``, traced into the
 kernel.  Two rules are given by hand so that what Mosaic is handed are the
 three forms of product it lowers without a transposition (``_mm``) and no
 walk back through the substitution (``_inverses``: ``dL = -T^T dT T^T``).
-What the backward keeps is the chunk-start states and nothing else.
+What the backward keeps is the operands, the chunk-start states and nothing
+else.
+
+Two entries of the one kernel pair, by what the caller holds (counted in
+``hetu_kda_entry_total{form}``, ``entries()``).  ``kda`` (``plain``) takes
+``chunk_kda``'s ``q, k, v, g, beta``.  ``kda_in_place`` (``in_place``, PR
+41) takes what the layer has: the convolution's output ``mixed [B, T, 3 H
+d]`` (``q~ | k~ | v``) and the projection ``proj [B, T, 5 H d]`` (``.. | f |
+z``), each window a block spec whose lane-block index starts at the window's
+(every offset a multiple of ``HEADS d`` lanes; no slice exists in HBM), and
+the small parameters as rows.  The head's norms and its gate are then a
+prologue of ``_open`` on the ``[64, 128]`` chunk in VMEM (``q = l2norm(q~) /
+sqrt(d)``, ``k = l2norm(k~)``, ``g = lower_bound sigmoid(rate (f + bias))``,
+f32) and the gated RMS norm an epilogue of ``_close`` on the f32 ``o``
+before its one cast, so the layer forms no ``[B, T, H, d]`` view, which on a
+TPU is another tiling and a pass over HBM each way.  Their cotangents come
+from the same ``jax.vjp``: the kernel writes ``dq~, dk~, dv, df, dz`` in the
+compute type and the small parameters' as f32 partial sums a batch row and
+head (output blocks resident over the sequence; XLA adds them).  No f32 ``[B,
+T, H d]`` array reaches HBM in either pass, and the residuals are ``mixed``,
+``proj``, ``beta`` and the chunk-start states.
 
 Inside a chunk the decays are taken sub-chunk by sub-chunk of 16 positions
 (``_pair``): the diagonal ``[16, 16]`` blocks from one ``[64, 64]`` product
@@ -36,7 +56,9 @@ yield between dependent stages, and the heads' triangular inverses are one
 being the transposition of that trace, is interleaved the same way.  On a
 v5e a layer of 32 heads over 8,192 positions (my chip run, PR 40): one head
 a program 10.3 ms forward and 25.7 forward and backward, two 6.4 and 17.4,
-four 5.7 and 15.7.
+four 5.7 and 15.7.  In the Ling-3.0 step (my chip run, PR 41): ``plain`` 4.61
+ms a forward and 9.38 a backward call with 64 ms a step of XLA's norms,
+gates and re-tilings around them, ``in_place`` 4.75 and 10.05 with 3.7.
 
 Precision as ``ops/pallas/gated_delta.py``: the state, the decays, ``T`` and
 every operand of a product with them are f32, multiplied as bf16 passes
@@ -53,9 +75,10 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
-from .gated_delta import (C, CHUNKS, VMEM_LIMIT, _NN, _NT, _TN, _dot, _dot32,
-                          _iotas, _lanes, _pick, _put, _rows, _to_col,
-                          _together, _unit_lower_inverse, _walk)
+from ... import telemetry
+from .gated_delta import (C, CHUNKS, _NN, _NT, _TN, _dot, _dot32,
+                          _iotas, _lanes, _params, _pick, _put, _rows,
+                          _to_col, _together, _unit_lower_inverse, _walk)
 from ..kda import SUB
 
 _F32 = jnp.float32
@@ -162,11 +185,28 @@ def _inverses_bwd(Ts, dTs):
 _inverses.defvjp(_inverses_fwd, _inverses_bwd)
 
 
-def _open(q, k, g, beta_row):
+def _unit(x):
+    """The rows of ``x [C, d]`` over their norms, f32 (the layer's
+    ``l2norm``: 1e-6 under the root)."""
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6)
+
+
+def _open(q, k, g, beta_row, gate=None):
     """A chunk up to its triangle ``L``: what the state does not enter.  A
     generator, as ``_close``: it yields between stages that depend on each
-    other and returns its value at the end (``_together``)."""
+    other and returns its value at the end (``_together``).  ``gate``: None,
+    or ``(rate, bias [1, d_k] f32, lower_bound)`` where ``q, k`` are the
+    convolution's ``q~, k~`` and ``g`` the projection's ``f``: the head's
+    norms and its gate are then taken here, on the chunk (rounded to the
+    compute type where the layer's ``jax.numpy`` form rounds them)."""
     ct = k.dtype
+    if gate is not None:
+        rate, bias, lower = gate
+        q = (_unit(q) * q.shape[1] ** -0.5).astype(ct)
+        k = _unit(k).astype(ct)
+        g = lower * jax.nn.sigmoid(rate * (g.astype(_F32) + bias))
+        yield
     row, col = _iotas()
     eye, lower = row == col, row >= col
     qf, kf = q.astype(_F32), k.astype(_F32)
@@ -191,9 +231,11 @@ def _open(q, k, g, beta_row):
     return dict(L=L, P=P, G=G, qf=qf, kf=kf, G_end=ends[-1])
 
 
-def _close(c, T, v, beta_row, S):
+def _close(c, T, v, beta_row, S, norm=None):
     """The rest of a chunk from the state ``S`` it starts at: ``(o f32 [C,
-    d_v], the next state)``."""
+    d_v], the next state)``.  ``norm``: None, or ``(z [C, d_v], scale [1,
+    d_v] f32, eps)``: ``o`` is then the head's gated RMS norm of it, ``o
+    rsqrt(mean(o^2) + eps) scale sigmoid(z)``, taken on the f32 chunk."""
     ct, G, kf = v.dtype, c["G"], c["kf"]
     Tb = T * beta_row
     eG = jnp.exp(G)
@@ -205,6 +247,10 @@ def _close(c, T, v, beta_row, S):
     o = _mm(c["qf"] * eG, S, _NN, True) + _mm(c["P"], u.astype(ct), _NN,
                                                False)
     yield
+    if norm is not None:
+        z, scale, eps = norm
+        o = (o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+             * scale * jax.nn.sigmoid(z.astype(_F32)))
     # the whole chunk's decay a channel, as a column: [1, d_k] -> [d_k, 1]
     dk = G.shape[1]
     eye_k = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
@@ -216,23 +262,52 @@ def _close(c, T, v, beta_row, S):
     return o, S_next
 
 
-def _chunks(heads):
+def _chunks(heads, gate=None):
     """One chunk of each of a program's heads, their chains in step: ``heads``
     a tuple of ``(q, k, v, g, beta_row, S)`` (``q, k [C, d_k]``, ``v [C,
     d_v]`` in the compute type, ``g [C, d_k]`` f32, ``beta_row [1, C]`` f32,
     ``S [d_k, d_v]`` f32) -> a tuple of ``(o f32 [C, d_v], the next state)``.
-    Its backward pass is ``jax.vjp`` of it, whose order is this one's
-    reversed: interleaved as well."""
-    opened = _together(_open(q, k, g, b) for q, k, _, g, b, _ in heads)
+    ``gate = (lower_bound, eps)`` (static): a head is ``(q~, k~, v, f,
+    beta_row, S, z, rate, bias, scale)`` as the layer has them and ``o`` what
+    its output product reads (``_open``, ``_close``).  Its backward pass is
+    ``jax.vjp`` of it, whose order is this one's reversed: interleaved as
+    well."""
+    def small(h):
+        if gate is None:
+            return None, None
+        z, rate, bias, scale = h[6:]
+        return (rate, bias, gate[0]), (z, scale, gate[1])
+    opened = _together(_open(h[0], h[1], h[3], h[4], small(h)[0])
+                       for h in heads)
     Ts = _inverses(tuple(c["L"] for c in opened))
     return tuple(_together(
-        _close(c, T, v, b, S)
-        for c, T, (_, _, v, _, b, S) in zip(opened, Ts, heads)))
+        _close(c, T, h[2], h[4], h[5], small(h)[1])
+        for c, T, h in zip(opened, Ts, heads)))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
-                s_ref, *, nc, hb, dk, dv):
+def _head(ins, rows, j, h, kl, vl, S):
+    """Head ``h``'s operands of ``_chunks`` at chunk ``j`` from a kernel's
+    input refs: ``q, k, v, g, beta`` and, in place, ``z, rate, bias, scale``
+    behind them."""
+    q_ref, k_ref, v_ref, g_ref, b_ref, *small = ins
+    head = (q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
+            g_ref[rows, kl], _pick(b_ref[h], j), S)
+    if small:
+        z_ref, rate_ref, bias_ref, scale_ref = small
+        head += (z_ref[rows, vl], rate_ref[:, kl], bias_ref[:, kl],
+                 scale_ref[...])
+    return head
+
+
+def _inputs(gate):
+    """How many of a kernel's refs ``_head`` reads."""
+    return 5 if gate is None else 9
+
+
+def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
     import jax.experimental.pallas as pl
+    n = _inputs(gate)
+    ins, (o_ref, last_ref, s0_ref, s_ref) = refs[:n], refs[n:]
     i = pl.program_id(2)
     lanes = _lanes(hb, dk, dv)
 
@@ -244,10 +319,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
         rows = _rows(j)
         for h in range(hb):
             s0_ref[h, j] = s_ref[h]
-        outs = _chunks(tuple(
-            (q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-             g_ref[rows, kl], _pick(b_ref[h], j), s_ref[h])
-            for h, (kl, vl) in enumerate(lanes)))
+        outs = _chunks(tuple(_head(ins, rows, j, h, kl, vl, s_ref[h])
+                             for h, (kl, vl) in enumerate(lanes)), gate)
         for h, (o, S) in enumerate(outs):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
@@ -258,123 +331,168 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
         last_ref[...] = s_ref[...]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, nc, hb, dk,
-                dv):
+def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
     import jax.experimental.pallas as pl
+    n = _inputs(gate)
+    ins, (s0_ref, do_ref, dlast_ref) = refs[:n], refs[n:n + 3]
+    (dq_ref, dk_ref, dv_ref, dg_ref, db_ref, *dsmall), ds_ref = (
+        refs[n + 3:-1], refs[-1])
     lanes = _lanes(hb, dk, dv)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = dlast_ref[...]
+        for ref in dsmall[1:]:           # the small parameters' partial sums
+            ref[...] = jnp.zeros_like(ref)
 
-    def body(n):
-        j = nc - 1 - n
+    def body(m):
+        j = nc - 1 - m
         rows = _rows(j)
-        _, pull = jax.vjp(_chunks, tuple(
-            (q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-             g_ref[rows, kl], _pick(b_ref[h], j), s0_ref[h, j])
+        _, pull = jax.vjp(functools.partial(_chunks, gate=gate), tuple(
+            _head(ins, rows, j, h, kl, vl, s0_ref[h, j])
             for h, (kl, vl) in enumerate(lanes)))
         (grads,) = pull(tuple(
             (do_ref[rows, vl].astype(_F32), ds_ref[h])
             for h, (_, vl) in enumerate(lanes)))
-        for h, (dq, dk_, dv_, dg, dbeta, dS) in enumerate(grads):
+        for h, (dq, dk_, dv_, dg, dbeta, dS, *rest) in enumerate(grads):
             kl, vl = lanes[h]
             dq_ref[rows, kl] = dq.astype(dq_ref.dtype)
             dk_ref[rows, kl] = dk_.astype(dk_ref.dtype)
             dv_ref[rows, vl] = dv_.astype(dv_ref.dtype)
-            dg_ref[rows, kl] = dg
+            dg_ref[rows, kl] = dg.astype(dg_ref.dtype)
             _put(db_ref.at[h], j, dbeta)
             ds_ref[h] = dS
+            if rest:
+                dz_ref, *sums = dsmall
+                dz_ref[rows, vl] = rest[0].astype(dz_ref.dtype)
+                for ref, part in zip(sums, rest[1:]):
+                    ref[:, kl] += part
     _walk(nc, body)
 
 
-def _params(interpret):
-    from jax.experimental.pallas import tpu as pltpu
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT)
-
-
-def _plan(beta, q, v, reverse):
-    """Grid, the kernels' static sizes and the block specs of q / k / g, v /
-    o, beta, the kept states and a state; ``reverse``: the blocks of chunks
-    from the last to the first."""
+def _plan(ops, gate, reverse):
+    """Grid, the kernels' static sizes, their inputs with a block spec each,
+    and the block specs by name of a ``[B, T, H d]`` array (``qk``, ``vo``),
+    beta, the kept states, a state and a small parameter's partial sums;
+    ``reverse``: the blocks of chunks from the last to the first.  ``ops``:
+    ``q, k, v, g, beta``, or with a ``gate`` ``mixed, proj, beta, rate, bias,
+    scale``: ``mixed [B, T, 3 H d]`` is then read as its three and ``proj
+    [B, T, 5 H d]`` as its last two windows of ``H d`` lanes, each a block
+    whose lane index starts at the window's."""
     import jax.experimental.pallas as pl
+    beta = ops[4 if gate is None else 2]
     B, H, groups, nc, _ = beta.shape
-    dk, dv = q.shape[2] // H, v.shape[2] // H
+    if gate is None:
+        dk, dv = ops[0].shape[2] // H, ops[2].shape[2] // H
+    else:
+        dk = dv = ops[0].shape[2] // (3 * H)
     hb = math.gcd(H, HEADS)
     at = (lambda i: groups - 1 - i) if reverse else (lambda i: i)
-    seq = lambda d: pl.BlockSpec((None, nc * C, hb * d),
-                                 lambda b, h, i: (b, at(i), h))
-    gate = pl.BlockSpec((None, hb, None, nc, C),
+    seq = lambda d, window=0: pl.BlockSpec(
+        (None, nc * C, hb * d),
+        lambda b, h, i: (b, at(i), window * (H // hb) + h))
+    rows = pl.BlockSpec((None, hb, None, nc, C),
                         lambda b, h, i: (b, h, at(i), 0, 0))
-    kept = pl.BlockSpec((None, hb, None, nc, dk, dv),
-                        lambda b, h, i: (b, h, at(i), 0, 0, 0))
-    state = pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0))
-    return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv),
-            (seq(dk), seq(dv), gate, kept, state))
+    names = dict(
+        qk=seq(dk), vo=seq(dv), rows=rows,
+        kept=pl.BlockSpec((None, hb, None, nc, dk, dv),
+                          lambda b, h, i: (b, h, at(i), 0, 0, 0)),
+        state=pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0)),
+        sums=pl.BlockSpec((None, 1, hb * dk), lambda b, h, i: (b, 0, h)))
+    if gate is None:
+        args, specs = ops, [seq(dk), seq(dk), seq(dv), seq(dk), rows]
+    else:
+        mixed, proj, beta, rate, bias, scale = ops
+        lane = pl.BlockSpec((1, hb * dk), lambda b, h, i: (0, h))
+        args = (mixed, mixed, mixed, proj, beta, proj, rate, bias, scale)
+        specs = [seq(dk), seq(dk, 1), seq(dv, 2), seq(dk, 3), rows,
+                 seq(dv, 4), lane, lane,
+                 pl.BlockSpec((1, dv), lambda b, h, i: (0, 0))]
+    return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv, gate=gate),
+            args, specs, names)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fwd_call(q, k, v, g, beta, *, interpret):
+@functools.partial(jax.jit, static_argnames=("gate", "interpret"))
+def _fwd_call(*ops, gate, interpret):
     """``q, k, g [B, T, H dk]``, ``v [B, T, H dv]``, ``beta [B, H, T / (n C),
-    n, C]`` f32 (``n`` chunks a program): ``(o [B, T, H dv], last state [B, H,
-    dk, dv], chunk-start states [B, H, T / (n C), n, dk, dv])``."""
+    n, C]`` f32 (``n`` chunks a program), or with a ``gate`` the operands
+    ``_plan`` names: ``(o [B, T, H dv], last state [B, H, dk, dv],
+    chunk-start states [B, H, T / (n C), n, dk, dv])``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (qk, vo, gate, kept, state) = _plan(beta, q, v, False)
-    B, H, groups, nc, _ = beta.shape
-    dk, dv, hb = dims["dk"], dims["dv"], dims["hb"]
+    grid, dims, args, specs, at = _plan(ops, gate, False)
+    nc, dk, dv, hb = (dims[n] for n in ("nc", "dk", "dv", "hb"))
+    B, H, groups = grid[0], grid[1] * hb, grid[2]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **dims),
-        name="hetu_kda_fwd", grid=grid,
-        in_specs=[qk, qk, vo, qk, gate], out_specs=[vo, state, kept],
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+        name="hetu_kda_fwd", grid=grid, in_specs=specs,
+        out_specs=[at["vo"], at["state"], at["kept"]],
+        out_shape=[jax.ShapeDtypeStruct(args[0].shape[:2] + (H * dv,),
+                                        args[2].dtype),
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=_params(interpret), interpret=interpret,
-    )(q, k, v, g, beta)
+    )(*args)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _bwd_call(q, k, v, g, beta, states, do, dlast, *, interpret):
+@functools.partial(jax.jit, static_argnames=("gate", "interpret"))
+def _bwd_call(*ops, gate, interpret):
+    """``_fwd_call``'s operands, the kept states, ``do`` and the last
+    state's cotangent: ``dq, dk, dv, dg, dbeta`` and, with a ``gate`` (where
+    they are ``dq~, dk~, dv, df``, all ``[B, T, H d]`` in the compute type),
+    ``dz`` and the partial sums ``[B, 1, H d]`` f32 of ``rate``'s, ``bias``'s
+    and, a head, ``scale``'s."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (qk, vo, gate, kept, state) = _plan(beta, q, v, True)
+    *ops, states, do, dlast = ops
+    grid, dims, args, specs, at = _plan(ops, gate, True)
+    qk, vo = at["qk"], at["vo"]
+    dk, dv, H = dims["dk"], dims["dv"], states.shape[1]
+    like = lambda x, d, dtype=None: jax.ShapeDtypeStruct(
+        x.shape[:2] + (H * d,), dtype or x.dtype)
+    q, k, v, g, beta = args[:5]
+    sums = jax.ShapeDtypeStruct((grid[0], 1, H * dk), _F32)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, **dims),
         name="hetu_kda_bwd", grid=grid,
-        in_specs=[qk, qk, vo, qk, gate, kept, vo, state],
-        out_specs=[qk, qk, vo, qk, gate],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(g.shape, _F32),
-                   jax.ShapeDtypeStruct(beta.shape, _F32)],
-        scratch_shapes=[pltpu.VMEM((dims["hb"], dims["dk"], dims["dv"]),
-                                   _F32)],
+        in_specs=specs + [at["kept"], vo, at["state"]],
+        out_specs=[qk, qk, vo, qk, at["rows"]] + (
+            [] if gate is None else [vo] + [at["sums"]] * 3),
+        out_shape=[like(q, dk), like(k, dk), like(v, dv),
+                   like(g, dk, _F32 if gate is None else None),
+                   jax.ShapeDtypeStruct(beta.shape, _F32)] + (
+                       [] if gate is None else [like(v, dv)] + [sums] * 3),
+        scratch_shapes=[pltpu.VMEM((dims["hb"], dk, dv), _F32)],
         compiler_params=_params(interpret), interpret=interpret,
-    )(q, k, v, g, beta, states, do, dlast)
+    )(*args, states, do, dlast)
 
 
-@jax.custom_vjp
-def _rule(q, k, v, g, beta):
-    return _rule_fwd(q, k, v, g, beta)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rule(gate, *ops):
+    return _rule_fwd(gate, *ops)[0]
 
 
-def _rule_fwd(q, k, v, g, beta):
-    o, last, states = _fwd_call(q, k, v, g, beta,
+def _rule_fwd(gate, *ops):
+    o, last, states = _fwd_call(*ops, gate=gate,
                                 interpret=dispatch.interpret())
-    return (o, last), (q, k, v, g, beta, states)
+    return (o, last), ops + (states,)
 
 
-def _rule_bwd(res, grads):
-    do, dlast = grads
-    return tuple(_bwd_call(*res, do, dlast, interpret=dispatch.interpret()))
+def _rule_bwd(gate, res, grads):
+    out = _bwd_call(*res, *grads, gate=gate, interpret=dispatch.interpret())
+    if gate is None:
+        return tuple(out)
+    dq, dk, dv, df, dbeta, dz, drate, dbias, dscale = out
+    proj, scale = res[1], res[5]
+    # the windows' cotangents in the arrays': nothing reads the first three
+    # windows of ``proj`` here
+    rest = jnp.zeros(proj.shape[:2] + (proj.shape[2] - 2 * df.shape[2],),
+                     proj.dtype)
+    return (jnp.concatenate([dq, dk, dv], -1),
+            jnp.concatenate([rest, df, dz], -1), dbeta, drate.sum(0),
+            dbias.sum(0),
+            dscale.reshape(-1, scale.shape[1]).sum(0, keepdims=True))
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -396,6 +514,42 @@ def unsupported(q, k, v, g, chunk):
     return None
 
 
+def _count_entry(form):
+    """Trace-time count of the entry taken, beside ``dispatch.record``'s
+    count of the kernel-versus-jnp choice."""
+    telemetry.get_registry().counter(
+        "hetu_kda_entry_total",
+        "Trace-time calls of the delta rule's kernels by what they are "
+        "handed: the layer's arrays read in place with its norms and gates "
+        "in the kernel, or q, k, v and g",
+        labels=("form",),
+    ).labels(form=form).inc()
+
+
+def entries():
+    """``{form: count}`` of the calls traced so far, ``form`` ``in_place`` or
+    ``plain`` (empty while telemetry is disabled)."""
+    return {lab["form"]: n
+            for lab, n in dispatch.counted("hetu_kda_entry_total")}
+
+
+def _cut(T):
+    """Chunks a program, programs along the sequence and the positions of
+    padding behind ``T``."""
+    nc = min(CHUNKS, -(-T // C))
+    groups = -(-T // (nc * C))
+    return nc, groups, groups * nc * C - T
+
+
+def _beta(beta, nc, groups, pad):
+    """``[B, T, H] -> [B, H, T' / (n C), n, C]`` f32, 0 at the padding."""
+    B, _, H = beta.shape
+    beta = beta.astype(_F32)
+    if pad:
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    return jnp.moveaxis(beta, 2, 1).reshape(B, H, groups, nc, C)
+
+
 def kda(q, k, v, g, beta):
     """``chunk_kda`` at chunk 64 through the kernel pair: ``q, k [B, T, H,
     d_k]``, ``v [B, T, H, d_v]``, ``g [B, T, H, d_k]`` f32, ``beta [B, T, H]``
@@ -404,17 +558,44 @@ def kda(q, k, v, g, beta):
     decay nothing (g 0) and their outputs are cut off."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
-    nc = min(CHUNKS, -(-T // C))
-    groups = -(-T // (nc * C))
-    pad = groups * nc * C - T
+    nc, groups, pad = _cut(T)
+    _count_entry("plain")
 
     def rows(x):                       # [B, T, H, d] -> [B, T', H d]
         x = x.reshape(B, T, -1)
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
-    beta = beta.astype(_F32)
-    if pad:
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    beta = jnp.moveaxis(beta, 2, 1).reshape(B, H, groups, nc, C)
-    o, last = _rule(rows(q), rows(k), rows(v), rows(g), beta)
+    o, last = _rule(None, rows(q), rows(k), rows(v), rows(g),
+                    _beta(beta, nc, groups, pad))
     return o[:, :T].reshape(B, T, H, dv), last
+
+
+#: the projection's value at positions of padding: ``f`` there shuts the
+#: gate (``sigmoid`` of it times any rate is 0 and so is its slope: g 0, as
+#: ``kda``'s padding) and ``z`` the output's
+_SHUT = -1e9
+
+
+def kda_in_place(mixed, proj, beta, rate, bias, scale, *, lower_bound, eps):
+    """The mixer between its convolution and its output product through the
+    same kernel pair: ``mixed [B, T, 3 H d]`` (``q~ | k~ | v``, the
+    convolution's output) and ``proj [B, T, 5 H d]`` (of which ``f`` at lane
+    ``3 H d`` and ``z`` at ``4 H d``) are read in place, a window a block
+    spec; ``beta [B, T, H]``; ``rate`` and ``bias`` one number a channel ``[H
+    d]``, ``scale [d]`` -> ``y [B, T, H d]`` in ``mixed``'s type, with ``q =
+    l2norm(q~) / sqrt(d)``, ``k = l2norm(k~)``, ``g = lower_bound
+    sigmoid(rate (f + bias))`` and ``y = o rsqrt(mean(o^2) + eps) scale
+    sigmoid(z)`` a head, all on the chunk in VMEM.  Any ``T`` (a padded copy
+    where 512 does not divide it)."""
+    B, T, H = beta.shape
+    nc, groups, pad = _cut(T)
+    _count_entry("in_place")
+    if pad:
+        mixed = jnp.pad(mixed, ((0, 0), (0, pad), (0, 0)))
+        proj = jnp.pad(proj, ((0, 0), (0, pad), (0, 0)),
+                       constant_values=_SHUT)
+    row = lambda x: x.astype(_F32).reshape(1, -1)
+    y, _ = _rule((float(lower_bound), float(eps)), mixed, proj,
+                 _beta(beta, nc, groups, pad), row(rate), row(bias),
+                 row(scale))
+    return y[:, :T]

@@ -116,12 +116,13 @@ def clone_params_into(ex, prev):
     return {k: np.asarray(v) for k, v in ex.params.items()}
 
 
-def lowered_for_tpu(monkeypatch, build):
+def lowered_for_tpu(monkeypatch, build, debug_info=False):
     """The text of the train step of the benchmark program ``build()`` makes,
     lowered for a TPU (nothing is compiled or run) with the platform read as
     ``tpu`` while it is built and traced; jax's caches emptied around it,
     since a kernel's jitted entry keeps what it read of the platform when it
-    was traced."""
+    was traced.  ``debug_info``: every operation with its ``loc``, whose
+    name holds the block scopes it was traced under."""
     import jax
     from hetu_tpu.ops.pallas import dispatch
     monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
@@ -132,7 +133,7 @@ def lowered_for_tpu(monkeypatch, build):
         if sub._jitted is None:
             sub._build()
         return sub._jitted.trace(*sub._abstract_args(None)).lower(
-            lowering_platforms=("tpu",)).as_text()
+            lowering_platforms=("tpu",)).as_text(debug_info=debug_info)
     finally:
         prog.close()
         jax.clear_caches()
@@ -140,13 +141,22 @@ def lowered_for_tpu(monkeypatch, build):
 
 def conv_calls(text):
     """How often a lowered step calls the causal convolution's two kernel
-    entries (``ops/pallas/causal_conv.py``; each holds its one
-    ``tpu_custom_call``): ``(forward, backward)``."""
-    import re
+    entries (``ops/pallas/causal_conv.py``): ``(forward, backward)``."""
     names = ("hetu_conv_fwd", "hetu_conv_bwd")
     assert all(f'kernel_name = "{name}"' in text for name in names)
-    return tuple(len(re.findall(rf"call @{name}(_\d+)?\(", text))
-                 for name in names)
+    return tuple(kernel_calls(text, name) for name in names)
+
+
+def kernel_calls(text, kernel):
+    """How often a lowered program calls the jitted entries that hold the
+    Pallas kernel ``kernel`` (a ``tpu_custom_call`` of that ``kernel_name``
+    in a private function's body)."""
+    import re
+    bodies = re.split(r"\n\s*func\.func ", text)
+    names = {re.match(r"(?:private )?@([\w.]+)", body).group(1)
+             for body in bodies[1:] if f'kernel_name = "{kernel}"' in body}
+    return sum(len(re.findall(rf"call @{re.escape(name)}\(", text))
+               for name in names)
 
 
 def jaxpr_primitives(jaxpr):

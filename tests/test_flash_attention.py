@@ -897,3 +897,42 @@ def test_mamba2_scan_node_compiles_for_v5e(v5e, as_on_tpu):
         vec, vec, vec).compile().as_text()
     assert "tpu_custom_call" not in hlo
     assert re.findall(r"\bwhile\(", hlo)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kda_scan_node_compiles_for_v5e_in_place(v5e, as_on_tpu, dtype):
+    """The Ling-3.0 cell's ``hetu_kda_scan`` and ``hetu_kda_out`` (32 heads
+    of 128 over 8,192 positions; f32 for the blocks' twice the bytes),
+    forward and backward from the nodes' inputs: ``hetu_kda_fwd`` and
+    ``hetu_kda_bwd`` once each under their scoped VMEM, reading the
+    convolution's ``[1, 8192, 12288]`` and the projection's ``[1, 8192,
+    20480]`` in place, and no array by heads ``[.., 32, 128]`` nor (bf16) an
+    f32 ``[1, 8192, ..]`` in HBM around them."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.layers.kda import _out, _scan
+    one = SingleDeviceSharding(v5e.devices[0])
+    dtype = jnp.dtype(dtype)
+    sds = lambda *s: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+    H, d, S, hidden = 32, 128, 8192, 2560
+
+    def loss(proj, mixed, beta, a_log, dt_bias, norm, w_out):
+        y = _scan(proj, mixed, beta, a_log, dt_bias, norm, heads=H, d=d,
+                  lower_bound=-5.0, eps=1e-6)
+        assert y.shape == (1, S, H * d)
+        return jnp.sum(_out(y, proj, norm, w_out, eps=1e-6).astype(
+            jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        sds(1, S, 5 * H * d), sds(1, S, 3 * H * d), sds(1, S, H), sds(H),
+        sds(H * d), sds(d), sds(H * d, hidden)).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_kda_fwd" in kernels[0] and "hetu_kda_bwd" in kernels[1]
+    for wide in (12288, 20480):
+        assert f"[1,8192,{wide}]" in kernels[0]
+        assert f"[1,8192,{wide}]" in kernels[1]
+    entry = hlo[hlo.index("\nENTRY "):]            # what reaches HBM
+    assert not re.findall(r" = \w+\[[\d,]*,32,128\]\S* ", entry)
+    if dtype == jnp.bfloat16:
+        assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)

@@ -359,9 +359,10 @@ def test_kda_layer_step_compiles_for_v5e(v5e, monkeypatch):
     a decay a channel in f32), forward and backward from the scan node's
     inputs: ``hetu_kda_fwd`` and ``hetu_kda_bwd`` and nothing else of the
     rule's: no ``triangular_solve``, no ``while``, no ``[.., 64, 64]`` f32
-    array in HBM; the kernels read and write ``[1, 8192, 4096]`` in place
-    (``hetu_gdn_*``'s helpers inside: the Qwen3-Next case above is what holds
-    those to what they were)."""
+    array in HBM; the kernels write ``[1, 8192, 4096]`` as the output product
+    reads it (``hetu_gdn_*``'s helpers inside: the Qwen3-Next case above is
+    what holds those to what they were; what the in-place entry reads and
+    leaves out of HBM is held in ``tests/test_flash_attention.py``)."""
     import re
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu import telemetry
@@ -375,17 +376,18 @@ def test_kda_layer_step_compiles_for_v5e(v5e, monkeypatch):
         sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
         H = 32
 
-        def loss(proj, mixed, beta, a_log, dt_bias):
+        def loss(proj, mixed, beta, a_log, dt_bias, w_norm):
             with jax.named_scope("hetu_kda_scan"):
-                o = _scan(proj, mixed, beta, a_log, dt_bias, heads=H, d=D,
-                          lower_bound=-5.0)
+                o = _scan(proj, mixed, beta, a_log, dt_bias, w_norm, heads=H,
+                          d=D, lower_bound=-5.0, eps=1e-6)
             return jnp.sum(o.astype(jnp.float32) ** 2)
 
-        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
             sds((1, 8192, 5 * H * D), jnp.bfloat16),
             sds((1, 8192, 3 * H * D), jnp.bfloat16),
             sds((1, 8192, H), jnp.bfloat16), sds((H,), jnp.float32),
-            sds((H * D,), jnp.float32)).compile().as_text()
+            sds((H * D,), jnp.float32),
+            sds((D,), jnp.float32)).compile().as_text()
         after = {k: n for k, n in dispatch.choices().items()
                  if k[0] == "kda"}
     finally:
