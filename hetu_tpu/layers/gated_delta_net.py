@@ -19,8 +19,13 @@ projections and the split), ``hetu_gdn_conv`` (``ops/causal_conv.py ConvOp``:
 on a TPU the Pallas kernels ``hetu_conv_fwd`` and ``hetu_conv_bwd``),
 ``hetu_gdn_scan`` (gates,
 normalisation and the chunked delta rule) and ``hetu_gdn_out`` (the gated
-norm and the output projection).  A decode step and the recurrent state in
-a serving cache are not here (ROADMAP Queue 2, M7).
+norm and the output projection: ``ops/gated_norm.py OutOp``, which reads ``z``
+out of ``qkvz`` itself; on a TPU the norm and the gate are the Pallas kernels
+``hetu_gated_norm_fwd`` and ``hetu_gated_norm_bwd`` on the scan's ``[B, S,
+value heads x d_v]``, ``z`` a key head's 2 d_v lanes of ``qkvz`` read in
+place, and ``_out`` below is the ``jax.numpy`` form they are held to; under a
+mesh and on any other platform ``_out`` runs).  A decode step and the
+recurrent state in a serving cache are not here (ROADMAP Queue 2, M7).
 """
 
 from __future__ import annotations
@@ -32,30 +37,22 @@ from .. import initializers as init
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
 from ..ops.causal_conv import ConvOp, causal_conv      # noqa: F401
-
-
-def _split(qkvz, *, key_heads, dk, dv, rep):
-    """``[B, S, 2 key_dim + 2 value_dim]`` in key-head-major order ->
-    ``(q | k | v) [B, S, 2 key_dim + value_dim]`` and ``z [B, S, value
-    heads, dv]``."""
-    import jax.numpy as jnp
-    B, S, _ = qkvz.shape
-    x = qkvz.reshape(B, S, key_heads, 2 * dk + 2 * rep * dv)
-    q, k, v, z = jnp.split(x, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
-    mixed = jnp.concatenate([t.reshape(B, S, -1) for t in (q, k, v)], -1)
-    return mixed, z.reshape(B, S, key_heads * rep, dv)
+from ..ops.gated_norm import OutOp, Window
 
 
 def _project(x, w):
     return x @ w
 
 
-def _mixed(qkvz, **dims):
-    return _split(qkvz, **dims)[0]
-
-
-def _z(qkvz, **dims):
-    return _split(qkvz, **dims)[1]
+def _mixed(qkvz, *, key_heads, dk, dv, rep):
+    """``[B, S, 2 key_dim + 2 value_dim]`` in key-head-major order (``q, k, v,
+    z`` a key head) -> ``(q | k | v) [B, S, 2 key_dim + value_dim]``; ``z``,
+    the last ``rep dv`` lanes of a key head, is the output node's to read."""
+    import jax.numpy as jnp
+    B, S, _ = qkvz.shape
+    x = qkvz.reshape(B, S, key_heads, 2 * dk + 2 * rep * dv)
+    q, k, v, _ = jnp.split(x, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
+    return jnp.concatenate([t.reshape(B, S, -1) for t in (q, k, v)], -1)
 
 
 def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
@@ -82,7 +79,8 @@ def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
     q = (unit(q) * dk ** -0.5).astype(mixed.dtype)
     k = unit(k).astype(mixed.dtype)
     v = v.reshape(B, S, key_heads * rep, dv)
-    return (rule or gated_delta.chunk_gated_delta_rule)(q, k, v, g, beta)[0]
+    o = (rule or gated_delta.chunk_gated_delta_rule)(q, k, v, g, beta)[0]
+    return o.reshape(B, S, -1)        # as the rule's kernels wrote it
 
 
 class _ScanOp(_Scoped):
@@ -103,15 +101,17 @@ class _ScanOp(_Scoped):
 
 
 def _out(o, z, w_norm, w_out, *, eps):
-    """RMSNorm over each head's ``dv`` scaled by ``w_norm`` (about one, not
-    zero-centred), gated by ``silu(z)``, then the output projection."""
+    """``o``, ``z [B, S, value heads x dv]``: RMSNorm over each head's ``dv``
+    scaled by ``w_norm`` (about one, not zero-centred), gated by ``silu(z)``,
+    then the output projection."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    of = o.astype(f32)
+    of, zf = (t.reshape(t.shape[:2] + (-1, w_norm.shape[0])).astype(f32)
+              for t in (o, z))
     of = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
-    y = (w_norm * of.astype(o.dtype)).astype(f32) * jax.nn.silu(z.astype(f32))
-    return y.astype(o.dtype).reshape(o.shape[:2] + (-1,)) @ w_out
+    y = (w_norm * of.astype(o.dtype)).astype(f32) * jax.nn.silu(zf)
+    return y.astype(o.dtype).reshape(o.shape) @ w_out
 
 
 def _log_uniform(key, shape, dtype=np.float32):
@@ -159,10 +159,13 @@ class GatedDeltaNet(BaseLayer):
         qkvz = _Scoped(_project, "hetu_gdn_proj", x, self.in_proj_qkvz)
         ba = _Scoped(_project, "hetu_gdn_proj", x, self.in_proj_ba)
         mixed = _Scoped(_mixed, "hetu_gdn_proj", qkvz, **self.dims)
-        z = _Scoped(_z, "hetu_gdn_proj", qkvz, **self.dims)
         mixed = ConvOp("hetu_gdn_conv", mixed, self.conv)
         o = _ScanOp(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
                     self.dt_bias, **self.dims)
-        return _Scoped(_out, "hetu_gdn_out", o, z, self.norm, self.out_proj,
-                       eps=self.eps)
+        # z where ``_mixed`` leaves it: the last ``rep dv`` lanes of a key head
+        dk, dv, rep = (self.dims[n] for n in ("dk", "dv", "rep"))
+        return OutOp(_out, "hetu_gdn_out", o, qkvz, self.norm, self.out_proj,
+                     window=Window(2 * dk + rep * dv, rep * dv,
+                                   2 * dk + 2 * rep * dv),
+                     width=dv, gate_first=False, eps=self.eps)
 

@@ -23,7 +23,11 @@ input projection and its parts), ``hetu_ssm_conv`` (``ops/causal_conv.py
 ConvOp``, which reads ``xBC`` out of the projection's output itself: on a TPU
 the Pallas kernels ``hetu_conv_fwd`` and ``hetu_conv_bwd``), ``hetu_ssm_scan``
 (the gates, the chunked scan and the skip) and ``hetu_ssm_out`` (the gate,
-the grouped norm and the output projection).  The scan is
+the grouped norm and the output projection: ``ops/gated_norm.py OutOp``, which
+reads ``z`` out of the projection's output itself; on a TPU the gate and the
+norm are the Pallas kernels ``hetu_gated_norm_fwd`` and ``hetu_gated_norm_bwd``
+over blocks of whole groups, and ``_out`` below is the ``jax.numpy`` form they
+are held to; under a mesh and on any other platform ``_out`` runs).  The scan is
 ``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
 ``hetu_ssd_bwd`` where their rule takes the operands (a group of more than
 eight heads as blocks of heads, ``ops/pallas/ssd.py``), under a mesh and on
@@ -42,6 +46,7 @@ from .. import initializers as init
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
 from ..ops.causal_conv import ConvOp
+from ..ops.gated_norm import OutOp, Window
 
 
 def _project(x, w):
@@ -160,12 +165,15 @@ class Mamba2(BaseLayer):
         # one node for the projection: its backward pass is then one product
         # for the weight, whatever reads the parts
         zxbcdt = _Scoped(_project, "hetu_ssm_proj", x, self.in_proj)
-        z, dt = (_Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
-                 for lo, hi in (self.parts[n] for n in ("z", "dt")))
+        lo, hi = self.parts["dt"]
+        dt = _Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
         # the convolution reads its channels in place where it can
         xbc = ConvOp("hetu_ssm_conv", zxbcdt, self.conv, self.conv_bias,
                      window=self.parts["xbc"])
         y = _ScanOp(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
                     self.a_log, self.d_skip, **self.dims)
-        return _Scoped(_out, "hetu_ssm_out", y, z, self.norm, self.out_proj,
-                       groups=self.groups, eps=self.eps)
+        d = self.parts["z"][1]
+        return OutOp(_out, "hetu_ssm_out", y, zxbcdt, self.norm,
+                     self.out_proj, window=Window(0, d, d),
+                     width=d // self.groups, gate_first=True,
+                     groups=self.groups, eps=self.eps)

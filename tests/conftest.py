@@ -159,6 +159,31 @@ def kernel_calls(text, kernel):
                for name in names)
 
 
+def gated_norm_calls(text):
+    """How often a lowered step calls the gated norm's two kernel entries
+    (``ops/pallas/gated_norm.py``): ``(forward, backward)``."""
+    return tuple(kernel_calls(text, name) for name in
+                 ("hetu_gated_norm_fwd", "hetu_gated_norm_bwd"))
+
+
+def arrays_under(text, scope, dims):
+    """``(operations, views)`` of a step lowered with ``debug_info``: how
+    many operations were traced under the block ``scope``, and those of them
+    that read or write an f32 array of rank 4 whose last dimensions are
+    ``dims`` (a view by groups or by heads)."""
+    import re
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    view = re.compile(r"tensor<\d+x\d+x%dx%dxf32>" % dims)
+    seen, views = 0, []
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if at and scope in names.get(at.group(1), ""):
+            seen += 1
+            if view.search(line):
+                views.append(line.strip()[:160])
+    return seen, views
+
+
 def jaxpr_primitives(jaxpr):
     """Every equation of a jaxpr and of the jaxprs nested in its
     equations' parameters (jit, shard_map, custom_vjp, scan), except

@@ -785,6 +785,52 @@ def test_causal_conv_kernels_compile_for_v5e(v5e, as_on_tpu, wide, window,
         assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("wide,width,gate_first,window,scale", [
+    (12288, 128, False, (512, 256, 768), 128),    # Qwen3-Next: a value head
+    (10304, 512, True, (0, 4096, 4096), 4096),    # Nemotron-H: eight groups
+    (8512, 4096, True, (0, 4096, 4096), 4096),    # Granite: ONE group
+])
+def test_gated_norm_kernels_compile_for_v5e(v5e, as_on_tpu, wide, width,
+                                            gate_first, window, scale, dtype):
+    """The mixers' gated norm at the three hybrid cells' shapes, forward and
+    backward: ``hetu_gated_norm_fwd`` and ``hetu_gated_norm_bwd`` once each
+    under the default scoped VMEM (the group of 4,096 lanes in f32 is the one
+    to watch: blocks of 64 rows), ``z`` read in place out of the projection's
+    output, and around them no f32 ``[1, 8192, ..]`` array (bf16) nor a view
+    by groups or heads in HBM."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops.pallas import gated_norm
+    one = SingleDeviceSharding(v5e.devices[0])
+    dtype = jnp.dtype(dtype)
+    sds = lambda *s: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+    assert gated_norm.unsupported(sds(1, 8192, 4096), sds(1, 8192, wide),
+                                  sds(scale), width=width) is None
+    assert gated_norm.in_place(4096, width, window)
+
+    def loss(o, proj, w):
+        y = gated_norm.gated_norm(o, proj, w, width=width, eps=1e-6,
+                                  gate_first=gate_first,
+                                  window=gated_norm.Window(*window))
+        assert y.shape == o.shape and y.dtype == o.dtype
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(1, 8192, 4096), sds(1, 8192, wide), sds(scale)
+    ).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_gated_norm_fwd" in kernels[0]
+    assert "hetu_gated_norm_bwd" in kernels[1]
+    assert all(f"[1,8192,{wide}]" in ln for ln in kernels)
+    entry = hlo[hlo.index("\nENTRY "):]            # what reaches HBM
+    assert not re.findall(
+        rf" = \w+\[1,8192,{4096 // width},{width}\]\S* ", entry)
+    if dtype == jnp.bfloat16:
+        assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)
+
+
 @pytest.mark.parametrize("dp", [1, 4])
 def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
     """BERT's hidden dropout, forward and backward, on one chip and under

@@ -150,6 +150,22 @@ def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
     assert "x392x" in text and "x256x" in text
 
 
+def test_the_lowered_train_step_holds_the_gated_norms_kernels(monkeypatch):
+    """Nine Mamba-2 layers, each mixer recomputed in the backward pass, ONE
+    group over all 128 channels: the gate and the norm are
+    ``hetu_gated_norm_fwd`` eighteen times and ``hetu_gated_norm_bwd`` nine,
+    ``z`` read out of ``[z | xBC | dt]`` (392 lanes) where it lies, and under
+    ``hetu_ssm_out`` no f32 array by groups ``[.., 1, 128]`` is formed,
+    forward or backward (PR 44; the ``jax.numpy`` form makes several)."""
+    from conftest import arrays_under, gated_norm_calls, lowered_for_tpu
+    text = lowered_for_tpu(
+        monkeypatch, lambda: hybrid_toy(mamba_d_state=64)[0], debug_info=True)
+    assert gated_norm_calls(text) == (18, 9)
+    assert "x392x" in text
+    seen, views = arrays_under(text, "hetu_ssm_out", (1, 128))
+    assert seen > 90 and not views, views[:3]
+
+
 def test_the_hybrid_runs_through_the_benchmarks_loop():
     """The benchmark's loop (prepare, a window, finish) over the builder's
     program at toy widths and the cell's period: every check that decides

@@ -145,25 +145,16 @@ def test_the_lowered_train_step_forms_no_view_by_heads_around_the_rule(
     reading the layer's ``[B, S, H d]`` arrays in place (PR 41): under
     ``hetu_kda_scan`` and ``hetu_kda_out`` no f32 array of rank 4 that ends in
     ``(heads, head size)`` is formed, forward or backward."""
-    import re
-    from conftest import kernel_calls, lowered_for_tpu
+    from conftest import arrays_under, kernel_calls, lowered_for_tpu
     text = lowered_for_tpu(monkeypatch, lambda: hybrid_toy(head_dim=128)[0],
                            debug_info=True)
     assert kernel_calls(text, "hetu_kda_fwd") == 12
     assert kernel_calls(text, "hetu_kda_bwd") == 6
     _, _, config, _ = run.load_cell(CELL)
     heads = run.merge(config, config["toy"])["num_attention_heads"]
-    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    by_heads = re.compile(rf"tensor<\d+x\d+x{heads}x128xf32>")
-    seen, views = 0, []
-    for line in text.splitlines():
-        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
-        name = names.get(at.group(1), "") if at else ""
-        if "hetu_kda_scan" in name or "hetu_kda_out" in name:
-            seen += 1
-            if by_heads.search(line):
-                views.append((name, line.strip()[:160]))
-    assert seen > 100 and not views, views[:3]
+    seen, views = zip(*(arrays_under(text, scope, (heads, 128))
+                        for scope in ("hetu_kda_scan", "hetu_kda_out")))
+    assert sum(seen) > 100 and not any(views), views
 
 
 def test_cell_rehearses(capsys):

@@ -123,6 +123,25 @@ def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
     assert "x392x" in text and "x256x" in text
 
 
+def test_the_lowered_train_step_holds_the_gated_norms_kernels(monkeypatch):
+    """Four Mamba-2 mixers, each recomputed in the backward pass, with heads
+    of 32 channels (256 channels in two groups of 128 lanes): the gate and the
+    grouped norm are ``hetu_gated_norm_fwd`` eight times and
+    ``hetu_gated_norm_bwd`` four, ``z`` read out of ``[z | xBC | dt]`` (648
+    lanes) where it lies, and under ``hetu_ssm_out`` no f32 array by groups
+    ``[.., 2, 128]`` is formed, forward or backward (PR 44; the ``jax.numpy``
+    form makes several)."""
+    from conftest import arrays_under, gated_norm_calls, lowered_for_tpu
+    text = lowered_for_tpu(
+        monkeypatch, lambda: hybrid_toy(ssm_state_size=32,
+                                        mamba_head_dim=32)[0],
+        debug_info=True)
+    assert gated_norm_calls(text) == (8, 4)
+    assert "x648x" in text
+    seen, views = arrays_under(text, "hetu_ssm_out", (2, 128))
+    assert seen > 40 and not views, views[:3]
+
+
 def test_the_cells_builder_at_a_hybrid_toy_size():
     """The benchmark's builder on the cell's configuration and traffic at
     toy widths and the cell's pattern: the program's loss terms against the

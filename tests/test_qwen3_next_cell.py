@@ -78,14 +78,14 @@ def test_flops_of_the_cut_configuration():
     assert nbytes > 0 and fq.layer_kinds(c).count("full_attention") == 1
 
 
-def hybrid_toy(say=lambda msg: None):
+def hybrid_toy(say=lambda msg: None, **widths):
     """The cell's program at toy widths with the published layer pattern
     (three DeltaNet layers, one attention layer; the configuration's own
     ``toy`` is all attention, see its ``why_all_attention``)."""
     from chipbench.builders import qwen3_next as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])
-    config.update(num_hidden_layers=4, full_attention_interval=4)
+    config.update(num_hidden_layers=4, full_attention_interval=4, **widths)
     mix = run.merge(mix, mix["toy"])
     return builder.build(config, mix, 2 ** 31 + 3, say), mix
 
@@ -98,6 +98,25 @@ def test_the_lowered_train_step_holds_the_convolutions_kernels(monkeypatch):
     from conftest import conv_calls, lowered_for_tpu
     text = lowered_for_tpu(monkeypatch, lambda: hybrid_toy()[0])
     assert conv_calls(text) == (6, 3)
+
+
+def test_the_lowered_train_step_holds_the_gated_norms_kernels(monkeypatch):
+    """Three DeltaNet layers, each recomputed in the backward pass, at the
+    cell's own head sizes (128 and 128, two value heads a key head): the norm
+    over a value head and its gate are ``hetu_gated_norm_fwd`` six times and
+    ``hetu_gated_norm_bwd`` three, ``z`` read out of ``qkvz`` (2 x 768 lanes)
+    where it lies, and under ``hetu_gdn_out`` no f32 array by heads ``[..,
+    4, 128]`` is formed, forward or backward (PR 44; the ``jax.numpy`` form
+    makes several)."""
+    from conftest import arrays_under, gated_norm_calls, lowered_for_tpu
+    text = lowered_for_tpu(
+        monkeypatch, lambda: hybrid_toy(linear_key_head_dim=128,
+                                        linear_value_head_dim=128)[0],
+        debug_info=True)
+    assert gated_norm_calls(text) == (6, 3)
+    assert "x1536x" in text
+    seen, views = arrays_under(text, "hetu_gdn_out", (4, 128))
+    assert seen > 30 and not views, views[:3]
 
 
 def test_the_cells_builder_at_a_hybrid_toy_size():
