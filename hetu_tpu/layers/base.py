@@ -19,6 +19,13 @@ def fresh_name(prefix):
     return f"{prefix}{c}" if c else prefix
 
 
+def project(x, w):
+    """The function of a projection's node (``ScopedOp(project, scope, x,
+    w)``): one node a projection, so that its backward pass is one product
+    for the weight whatever reads the parts."""
+    return x @ w
+
+
 class BaseLayer:
     def __call__(self, *args, **kwargs):
         raise NotImplementedError

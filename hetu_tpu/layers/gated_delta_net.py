@@ -32,16 +32,14 @@ from __future__ import annotations
 
 import numpy as np
 
-from .base import BaseLayer, fresh_name
+from . import recurrent
+from .base import BaseLayer, fresh_name, project
 from .. import initializers as init
 from ..graph.node import VariableOp
-from ..ops.base import ScopedOp as _Scoped
+from ..ops import gated_delta
+from ..ops.base import KernelOp, ScopedOp as _Scoped
 from ..ops.causal_conv import ConvOp, causal_conv      # noqa: F401
 from ..ops.gated_norm import OutOp, Window
-
-
-def _project(x, w):
-    return x @ w
 
 
 def _mixed(qkvz, *, key_heads, dk, dv, rep):
@@ -58,7 +56,6 @@ def _mixed(qkvz, *, key_heads, dk, dv, rep):
 def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
     import jax
     import jax.numpy as jnp
-    from ..ops import gated_delta
     B, S, _ = mixed.shape
     f32 = jnp.float32
     kd = key_heads * dk
@@ -83,23 +80,6 @@ def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
     return o.reshape(B, S, -1)        # as the rule's kernels wrote it
 
 
-class _ScanOp(_Scoped):
-    """The ``hetu_gdn_scan`` node.  A ``pallas_call`` does not partition
-    under GSPMD and ``chunk_gated_delta_rule`` cannot see a mesh, so under
-    one this node calls the rule's ``jax.numpy`` form itself, and says so
-    where there was a kernel to take (reason ``mesh``)."""
-
-    def _compute(self, input_vals, ctx):
-        from ..ops import gated_delta
-        from ..ops.pallas import dispatch
-        rule = None
-        if ctx.mesh is not None:
-            rule = gated_delta.chunk_gated_delta_rule_jnp
-            if dispatch.mosaic():
-                dispatch.record("gated_delta", "mesh")
-        return self.fn(*input_vals, rule=rule, **self.attrs)
-
-
 def _out(o, z, w_norm, w_out, *, eps):
     """``o``, ``z [B, S, value heads x dv]``: RMSNorm over each head's ``dv``
     scaled by ``w_norm`` (about one, not zero-centred), gated by ``silu(z)``,
@@ -112,14 +92,6 @@ def _out(o, z, w_norm, w_out, *, eps):
     of = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
     y = (w_norm * of.astype(o.dtype)).astype(f32) * jax.nn.silu(zf)
     return y.astype(o.dtype).reshape(o.shape) @ w_out
-
-
-def _log_uniform(key, shape, dtype=np.float32):
-    """``log U(0, 16)``: HF's initial ``A_log``."""
-    import jax
-    import jax.numpy as jnp
-    a = jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0)
-    return jnp.log(a).astype(dtype)
 
 
 class GatedDeltaNet(BaseLayer):
@@ -144,7 +116,8 @@ class GatedDeltaNet(BaseLayer):
             f"{name}_conv_weight", (conv_kernel, 2 * key_dim + value_dim),
             init.uniform(-bound, bound))
         # A ~ U(0, 16) and A_log = log A, dt_bias ones: as HF initialises
-        self.a_log = VariableOp(f"{name}_a_log", (num_v_heads,), _log_uniform)
+        self.a_log = VariableOp(f"{name}_a_log", (num_v_heads,),
+                                recurrent.log_uniform(1e-3, 16.0))
         self.dt_bias = VariableOp(f"{name}_dt_bias", (num_v_heads,),
                                   init.ones())
         self.norm = VariableOp(f"{name}_norm_scale", (head_v_dim,),
@@ -154,14 +127,13 @@ class GatedDeltaNet(BaseLayer):
                                    init.xavier_normal())
 
     def __call__(self, x):
-        # one node a projection: its backward pass is then one product for
-        # the weight, whatever reads the parts
-        qkvz = _Scoped(_project, "hetu_gdn_proj", x, self.in_proj_qkvz)
-        ba = _Scoped(_project, "hetu_gdn_proj", x, self.in_proj_ba)
+        qkvz = _Scoped(project, "hetu_gdn_proj", x, self.in_proj_qkvz)
+        ba = _Scoped(project, "hetu_gdn_proj", x, self.in_proj_ba)
         mixed = _Scoped(_mixed, "hetu_gdn_proj", qkvz, **self.dims)
         mixed = ConvOp("hetu_gdn_conv", mixed, self.conv)
-        o = _ScanOp(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
-                    self.dt_bias, **self.dims)
+        o = KernelOp(_scan, "hetu_gdn_scan", mixed, ba, self.a_log,
+                     self.dt_bias, kernel="gated_delta", form=lambda:
+                     gated_delta.chunk_gated_delta_rule_jnp, **self.dims)
         # z where ``_mixed`` leaves it: the last ``rep dv`` lanes of a key head
         dk, dv, rep = (self.dims[n] for n in ("dk", "dv", "rep"))
         return OutOp(_out, "hetu_gdn_out", o, qkvz, self.norm, self.out_proj,

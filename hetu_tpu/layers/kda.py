@@ -36,16 +36,13 @@ from __future__ import annotations
 
 import numpy as np
 
-from .base import BaseLayer, fresh_name
+from . import recurrent
+from .base import BaseLayer, fresh_name, project
 from .. import initializers as init
 from ..graph.node import VariableOp
-from ..ops.base import ScopedOp as _Scoped
+from ..ops import kda
+from ..ops.base import KernelOp, ScopedOp as _Scoped
 from ..ops.causal_conv import ConvOp
-from .mamba2 import _a_log, _dt_bias
-
-
-def _project(x, w):
-    return x @ w
 
 
 def gate(f, a_log, dt_bias, lower_bound):
@@ -68,7 +65,6 @@ def _scan(proj, mixed, beta_lin, a_log, dt_bias, w_norm, *, heads, d,
     ``_out``'s norm and gate."""
     import jax
     import jax.numpy as jnp
-    from ..ops import kda
     B, S, _ = mixed.shape
     f32 = jnp.float32
     hd = heads * d
@@ -89,23 +85,6 @@ def _scan(proj, mixed, beta_lin, a_log, dt_bias, w_norm, *, heads, d,
     g = gate(proj[..., 3 * hd:4 * hd].reshape(B, S, heads, d), a_log,
              dt_bias, lower_bound)
     return (rule or kda.chunk_kda)(q, k, v, g, beta)[0]
-
-
-class _ScanOp(_Scoped):
-    """The ``hetu_kda_scan`` node.  A ``pallas_call`` does not partition
-    under GSPMD and ``chunk_kda`` cannot see a mesh, so under one this node
-    calls the rule's ``jax.numpy`` form itself, and says so where there was a
-    kernel to take (reason ``mesh``)."""
-
-    def _compute(self, input_vals, ctx):
-        from ..ops import kda
-        from ..ops.pallas import dispatch
-        rule = None
-        if ctx.mesh is not None:
-            rule = kda.chunk_kda_jnp
-            if dispatch.mosaic():
-                dispatch.record("kda", "mesh")
-        return self.fn(*input_vals, rule=rule, **self.attrs)
 
 
 def _out(o, proj, w_norm, w_out, *, eps):
@@ -146,20 +125,22 @@ class KimiDeltaAttention(BaseLayer):
                                init.uniform(-bound, bound))
         # A ~ U(1, 16) a head and dt log-uniform in [0.001, 0.1] a channel:
         # as the Mamba family and flash-linear-attention's KDA initialise
-        self.a_log = VariableOp(f"{name}_a_log", (num_heads,), _a_log)
+        self.a_log = VariableOp(f"{name}_a_log", (num_heads,),
+                                recurrent.log_uniform(1.0, 16.0))
         self.dt_bias = VariableOp(f"{name}_dt_bias", (hd,),
-                                  _dt_bias(1e-3, 1e-1, 1e-4))
+                                  recurrent.dt_bias(1e-3, 1e-1, 1e-4))
         self.norm = VariableOp(f"{name}_norm_scale", (head_dim,), init.ones())
         self.out_proj = VariableOp(f"{name}_out_weight", (hd, hidden_size),
                                    init.xavier_normal())
 
     def __call__(self, x):
         hd = self.dims["heads"] * self.dims["d"]
-        proj = _Scoped(_project, "hetu_kda_proj", x, self.in_proj)
-        beta = _Scoped(_project, "hetu_kda_proj", x, self.beta_proj)
+        proj = _Scoped(project, "hetu_kda_proj", x, self.in_proj)
+        beta = _Scoped(project, "hetu_kda_proj", x, self.beta_proj)
         mixed = ConvOp("hetu_kda_conv", proj, self.conv, window=(0, 3 * hd))
-        o = _ScanOp(_scan, "hetu_kda_scan", proj, mixed, beta, self.a_log,
-                    self.dt_bias, self.norm, lower_bound=self.lower_bound,
-                    eps=self.eps, **self.dims)
+        o = KernelOp(_scan, "hetu_kda_scan", proj, mixed, beta, self.a_log,
+                     self.dt_bias, self.norm, kernel="kda",
+                     form=lambda: kda.chunk_kda_jnp,
+                     lower_bound=self.lower_bound, eps=self.eps, **self.dims)
         return _Scoped(_out, "hetu_kda_out", o, proj, self.norm,
                        self.out_proj, eps=self.eps)

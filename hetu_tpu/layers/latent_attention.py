@@ -21,17 +21,13 @@ decode that reads them) is not here (ROADMAP Queue 2, M7).
 
 from __future__ import annotations
 
-from .base import BaseLayer, fresh_name
+from .base import BaseLayer, fresh_name, project
 from .. import initializers as init
 from ..graph.node import VariableOp, scope
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.base import ScopedOp as _Scoped
 
 _SCOPE = "hetu_attn"
-
-
-def _project(x, w):
-    return x @ w
 
 
 def _rms(x, w, eps):
@@ -133,11 +129,11 @@ class LatentAttention(BaseLayer):
         S = lambda fn, *a, **kw: _Scoped(fn, _SCOPE, *a, **kw)
         rot = dict(heads=self.num_heads, d_rope=self.d_rope,
                    theta=self.theta, eps=self.eps)
-        kva = S(_project, x, self.kva_proj)
+        kva = S(project, x, self.kva_proj)
         c = S(_latent, kva, self.kv_norm, rank=self.rank, eps=self.eps)
-        kvb = S(_project, c, self.kvb_proj)
+        kvb = S(project, c, self.kvb_proj)
         normed = self.q_norm is not None
-        q = S(_queries, S(_project, x, self.q_proj),
+        q = S(_queries, S(project, x, self.q_proj),
               *([self.q_norm] if normed else []), **rot)
         k = S(_keys, kvb, kva, *([self.k_norm] if normed else []),
               d_nope=self.d_nope, rank=self.rank, **rot)
@@ -146,5 +142,5 @@ class LatentAttention(BaseLayer):
             ctx_ = scaled_dot_product_attention_op(
                 q, k, v, causal=True, scale=self.d_qk ** -0.5)
         gate = ([] if self.gate_proj is None
-                else [S(_project, x, self.gate_proj)])
+                else [S(project, x, self.gate_proj)])
         return S(_out, ctx_, self.out_proj, *gate)

@@ -31,8 +31,9 @@ are held to; under a mesh and on any other platform ``_out`` runs).  The scan is
 ``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
 ``hetu_ssd_bwd`` where their rule takes the operands (a group of more than
 eight heads as blocks of heads, ``ops/pallas/ssd.py``), under a mesh and on
-any other platform the ``jax.numpy`` form (``_ScanOp``); the softplus,
-``-exp(A_log)`` and the skip stay XLA's under the same scope.  A decode step,
+any other platform the ``jax.numpy`` form (the node is an ``ops/base.py
+KernelOp``); the softplus, ``-exp(A_log)`` and the skip stay XLA's under the
+same scope.  A decode step,
 and the state ``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a
 serving cache, are not here (ROADMAP Queue 2).
 """
@@ -41,16 +42,14 @@ from __future__ import annotations
 
 import numpy as np
 
-from .base import BaseLayer, fresh_name
+from . import recurrent
+from .base import BaseLayer, fresh_name, project
 from .. import initializers as init
 from ..graph.node import VariableOp
-from ..ops.base import ScopedOp as _Scoped
+from ..ops import ssd
+from ..ops.base import KernelOp, ScopedOp as _Scoped
 from ..ops.causal_conv import ConvOp
 from ..ops.gated_norm import OutOp, Window
-
-
-def _project(x, w):
-    return x @ w
 
 
 def _part(zxbcdt, *, lo, hi):
@@ -58,10 +57,9 @@ def _part(zxbcdt, *, lo, hi):
 
 
 def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
-          state, chunk, scan=None):
+          state, chunk, rule=None):
     import jax
     import jax.numpy as jnp
-    from ..ops import ssd
     B, S, _ = xbc.shape
     f32 = jnp.float32
     d, gn = heads * head_dim, groups * state
@@ -69,27 +67,10 @@ def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
     Bm = xbc[..., d:d + gn].reshape(B, S, groups, state)
     Cm = xbc[..., d + gn:].reshape(B, S, groups, state)
     dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-    y, _ = (scan or ssd.chunk_ssd)(x, dt, -jnp.exp(a_log.astype(f32)), Bm,
+    y, _ = (rule or ssd.chunk_ssd)(x, dt, -jnp.exp(a_log.astype(f32)), Bm,
                                    Cm, chunk=chunk)
     y = y.astype(f32) + d_skip.astype(f32)[:, None] * x.astype(f32)
     return y.astype(xbc.dtype).reshape(B, S, d)
-
-
-class _ScanOp(_Scoped):
-    """The ``hetu_ssm_scan`` node.  A ``pallas_call`` does not partition
-    under GSPMD and ``chunk_ssd`` cannot see a mesh, so under one this node
-    calls the scan's ``jax.numpy`` form itself, and says so where there was
-    a kernel to take (reason ``mesh``)."""
-
-    def _compute(self, input_vals, ctx):
-        from ..ops import ssd
-        from ..ops.pallas import dispatch
-        scan = None
-        if ctx.mesh is not None:
-            scan = ssd.chunk_ssd_jnp
-            if dispatch.mosaic():
-                dispatch.record("ssd", "mesh")
-        return self.fn(*input_vals, scan=scan, **self.attrs)
 
 
 def _out(y, z, w_norm, w_out, *, groups, eps):
@@ -104,27 +85,6 @@ def _out(y, z, w_norm, w_out, *, groups, eps):
     g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
     g = g.reshape(y.shape) * w_norm.astype(f32)
     return g.astype(y.dtype) @ w_out
-
-
-def _a_log(key, shape, dtype=np.float32):
-    """``log U(1, 16)``: the family's initial ``A_log``."""
-    import jax
-    import jax.numpy as jnp
-    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
-                   ).astype(dtype)
-
-
-def _dt_bias(dt_min, dt_max, floor):
-    """The inverse softplus of a log-uniform draw in ``[dt_min, dt_max]``
-    floored at ``floor``: the softplus of the initial bias is the step."""
-    def draw(key, shape, dtype=np.float32):
-        import jax
-        import jax.numpy as jnp
-        u = jax.random.uniform(key, shape, jnp.float32)
-        dt = jnp.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
-        dt = jnp.maximum(dt, floor)
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return draw
 
 
 class Mamba2(BaseLayer):
@@ -153,8 +113,9 @@ class Mamba2(BaseLayer):
         self.conv_bias = VariableOp(f"{name}_conv_bias", (conv_dim,),
                                     init.uniform(-bound, bound))
         self.dt_bias = VariableOp(f"{name}_dt_bias", (num_heads,),
-                                  _dt_bias(dt_min, dt_max, dt_floor))
-        self.a_log = VariableOp(f"{name}_a_log", (num_heads,), _a_log)
+                                  recurrent.dt_bias(dt_min, dt_max, dt_floor))
+        self.a_log = VariableOp(f"{name}_a_log", (num_heads,),
+                                recurrent.log_uniform(1.0, 16.0))
         self.d_skip = VariableOp(f"{name}_d", (num_heads,), init.ones())
         self.norm = VariableOp(f"{name}_norm_scale", (d,), init.ones())
         bound = out_scale / np.sqrt(d)      # kaiming_uniform(a=sqrt(5))
@@ -162,16 +123,15 @@ class Mamba2(BaseLayer):
                                    init.uniform(-bound, bound))
 
     def __call__(self, x):
-        # one node for the projection: its backward pass is then one product
-        # for the weight, whatever reads the parts
-        zxbcdt = _Scoped(_project, "hetu_ssm_proj", x, self.in_proj)
+        zxbcdt = _Scoped(project, "hetu_ssm_proj", x, self.in_proj)
         lo, hi = self.parts["dt"]
         dt = _Scoped(_part, "hetu_ssm_proj", zxbcdt, lo=lo, hi=hi)
         # the convolution reads its channels in place where it can
         xbc = ConvOp("hetu_ssm_conv", zxbcdt, self.conv, self.conv_bias,
                      window=self.parts["xbc"])
-        y = _ScanOp(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
-                    self.a_log, self.d_skip, **self.dims)
+        y = KernelOp(_scan, "hetu_ssm_scan", xbc, dt, self.dt_bias,
+                     self.a_log, self.d_skip, kernel="ssd",
+                     form=lambda: ssd.chunk_ssd_jnp, **self.dims)
         d = self.parts["z"][1]
         return OutOp(_out, "hetu_ssm_out", y, zxbcdt, self.norm,
                      self.out_proj, window=Window(0, d, d),

@@ -1,0 +1,33 @@
+"""The initialisers the recurrent mixers (``gated_delta_net.py``,
+``mamba2.py``, ``kda.py``) share, of their decays and steps.  Their nodes are
+shared too: a projection is ``ScopedOp(project, ..)`` (``base.py``), the scan
+``ops/base.py KernelOp``, the convolution ``ops/causal_conv.py ConvOp``, the
+scalar mixers' output ``ops/gated_norm.py OutOp``; the data flow between the
+nodes is each layer's own."""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def log_uniform(lo, hi):
+    """``log U(lo, hi)``: an initial ``A_log``."""
+    def draw(key, shape, dtype=np.float32):
+        import jax
+        import jax.numpy as jnp
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, lo, hi)
+                       ).astype(dtype)
+    return draw
+
+
+def dt_bias(dt_min, dt_max, floor):
+    """The inverse softplus of a log-uniform draw in ``[dt_min, dt_max]``
+    floored at ``floor``: the softplus of the initial bias is the step."""
+    def draw(key, shape, dtype=np.float32):
+        import jax
+        import jax.numpy as jnp
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
+        dt = jnp.maximum(dt, floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return draw

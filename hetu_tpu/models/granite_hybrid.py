@@ -36,8 +36,8 @@ from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
 from ..layers.mamba2 import Mamba2
 from ..ops import ssd
-from .llama import LlamaForCausalLM, LlamaMLP, LlamaModel
-from .nemotron_h import normed_mixer
+from .llama import (LlamaForCausalLM, LlamaMLP, LlamaModel,
+                    residual_sublayer)
 
 #: the published ``layer_types``: attention at layers 5, 15, 25 and 35
 LAYER_TYPES = tuple("attention" if i % 10 == 5 else "mamba"
@@ -120,17 +120,10 @@ class GraniteHybridDecoderLayer(BaseLayer):
         self.recompute = c.remat == "mamba" and kind == "mamba"
 
     def __call__(self, x, seq_len=None):
-        # norms and the scaled residual sums are the block `hetu_norm`; the
-        # sublayers name their own
-        a = normed_mixer(self.input_norm, self.mixer, x, self.recompute,
-                         attention=self.kind == "attention",
-                         seq_len=seq_len)
-        with scope("hetu_norm"):
-            x = x + a * self.scale
-            m_in = self.post_norm(x)
-        m = self.mlp(m_in)
-        with scope("hetu_norm"):
-            return x + m * self.scale
+        x = residual_sublayer(x, self.input_norm, self.mixer, self.recompute,
+                              self.scale, seq_len=seq_len)
+        return residual_sublayer(x, self.post_norm, self.mlp,
+                                 scale=self.scale)
 
 
 class GraniteHybridModel(LlamaModel):

@@ -39,7 +39,8 @@ from ..layers.base import BaseLayer
 from ..layers.kda import KimiDeltaAttention
 from ..layers.latent_attention import LatentAttention
 from ..layers.moe import MoELayer
-from .llama import LlamaForCausalLM, LlamaMLP, LlamaModel
+from .llama import (LlamaForCausalLM, LlamaMLP, LlamaModel,
+                    residual_sublayer)
 
 
 class Ling3Config:
@@ -155,27 +156,19 @@ class Ling3DecoderLayer(BaseLayer):
                 router_groups=c.router_groups, name=f"{name}_moe")
         self.input_norm, self.post_norm = norm("input_norm"), norm("post_norm")
         self._layer_scope = remat_scope if c.remat == "layer" else nullcontext
-        self._mixer_scope = (remat_scope if c.remat == "mixer"
-                             and self.kind == "kda" else nullcontext)
+        self.recompute = c.remat == "mixer" and self.kind == "kda"
+
+    def _mix(self, h):
+        #: the mixer's output node of the last call (a benchmark fetches the
+        #: latent layer's beside the logits)
+        self.mixer_out = self.mixer(h)
+        return self.mixer_out
 
     def __call__(self, x, seq_len=None):
-        # norms and residual sums are the block `hetu_norm`; the sublayers
-        # name their own.  What the backward pass keeps of a recomputed
-        # group is what enters it: the residual stream
-        with self._layer_scope():
-            with self._mixer_scope():
-                with scope("hetu_norm"):
-                    a_in = self.input_norm(x)
-                mixed = self.mixer(a_in)
-            #: the mixer's output node of the last call (a benchmark fetches
-            #: the latent layer's beside the logits)
-            self.mixer_out = mixed
-            with scope("hetu_norm"):
-                x = x + mixed
-                m_in = self.post_norm(x)
-            m = self.mlp(m_in)
-            with scope("hetu_norm"):
-                return x + m
+        with self._layer_scope():       # the whole layer one recomputed group
+            x = residual_sublayer(x, self.input_norm, self._mix,
+                                  self.recompute)
+            return residual_sublayer(x, self.post_norm, self.mlp)
 
 
 class Ling3Model(LlamaModel):

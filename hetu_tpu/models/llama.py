@@ -19,7 +19,7 @@ from operator import add
 
 import numpy as np
 
-from ..graph.node import scope, stage, scoped_init
+from ..graph.node import remat, scope, stage, scoped_init
 from .. import initializers as init
 from ..layers import Embedding, Linear, RMSNorm
 from ..layers.base import BaseLayer
@@ -143,6 +143,24 @@ class LlamaMLP(BaseLayer):
             return self.down(silu_op(self.gate(x)) * self.up(x))
 
 
+def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
+                      seq_len=None):
+    """One pre-norm residual sublayer, ``x + sublayer(norm(x))`` (``* scale``
+    where a family multiplies its residual branches): the norm and the sum
+    under the block `hetu_norm`, the sublayer under the names it gives
+    itself; a ``MultiHeadAttention`` is called ``(h, h, h, seq_len=)``,
+    anything else, a wrapper around one too, ``(h)``.  ``recompute`` puts
+    the norm and the sublayer inside one recomputed group: what the backward
+    pass keeps of it is what enters it, the residual stream alone."""
+    with (remat() if recompute else nullcontext()):
+        with scope("hetu_norm"):
+            h = norm(x)
+        y = (sublayer(h, h, h, seq_len=seq_len)
+             if isinstance(sublayer, MultiHeadAttention) else sublayer(h))
+    with scope("hetu_norm"):
+        return x + (y if scale is None else y * scale)
+
+
 class LlamaDecoderLayer(BaseLayer):
     def __init__(self, config, name):
         c = config
@@ -171,17 +189,8 @@ class LlamaDecoderLayer(BaseLayer):
                                  name=f"{name}_post_norm")
 
     def __call__(self, x, seq_len=None):
-        # norms and residual sums are the block `hetu_norm`; the sublayers
-        # name their own
-        with scope("hetu_norm"):
-            a_in = self.input_norm(x)
-        a = self.attn(a_in, a_in, a_in, seq_len=seq_len)
-        with scope("hetu_norm"):
-            x = x + a
-            m_in = self.post_norm(x)
-        m = self.mlp(m_in)
-        with scope("hetu_norm"):
-            return x + m
+        x = residual_sublayer(x, self.input_norm, self.attn, seq_len=seq_len)
+        return residual_sublayer(x, self.post_norm, self.mlp)
 
 
 class LlamaModel:

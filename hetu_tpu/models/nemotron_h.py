@@ -37,15 +37,12 @@ activations a block keeps) or None.
 
 from __future__ import annotations
 
-from contextlib import nullcontext
-
-from ..graph.node import remat as remat_scope, scope
 from ..layers import RMSNorm
 from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
 from ..layers.mamba2 import Mamba2
 from ..layers.moe import MoELayer
-from .llama import LlamaForCausalLM, LlamaModel
+from .llama import LlamaForCausalLM, LlamaModel, residual_sublayer
 
 #: the published pattern: 23 Mamba-2 mixers, 23 expert layers, 6 attention
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -115,17 +112,6 @@ NEMOTRON_H_CONFIGS = {
 }
 
 
-def normed_mixer(norm, mixer, x, recompute, attention=False, seq_len=None):
-    """``mixer(norm(x))``, both inside one recomputed group where
-    ``recompute``: what the backward pass keeps of a recomputed mixer is the
-    residual stream alone.  The norm stands under the block `hetu_norm`;
-    the mixer names its own.  (Shared with ``granite_hybrid.py``.)"""
-    with (remat_scope() if recompute else nullcontext()):
-        with scope("hetu_norm"):
-            h = norm(x)
-        return mixer(h, h, h, seq_len=seq_len) if attention else mixer(h)
-
-
 class NemotronHBlock(BaseLayer):
     """One sublayer behind one norm and one residual.  An expert block's
     layer is ``mlp`` (what the loss terms and the load read)."""
@@ -164,12 +150,8 @@ class NemotronHBlock(BaseLayer):
         self.recompute = c.remat == "mamba" and kind == "M"
 
     def __call__(self, x, seq_len=None):
-        # (the norm and the residual sum are the block `hetu_norm`; the
-        # sublayer names its own)
-        y = normed_mixer(self.norm, self.mixer, x, self.recompute,
-                         attention=self.kind == "*", seq_len=seq_len)
-        with scope("hetu_norm"):
-            return x + y
+        return residual_sublayer(x, self.norm, self.mixer, self.recompute,
+                                 seq_len=seq_len)
 
 
 class NemotronHModel(LlamaModel):

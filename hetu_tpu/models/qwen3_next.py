@@ -33,15 +33,12 @@ kernel runs once a layer and step) or None.
 
 from __future__ import annotations
 
-from contextlib import nullcontext
-
-from ..graph.node import remat as remat_scope, scope
 from ..layers import RMSNorm
 from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
 from ..layers.gated_delta_net import GatedDeltaNet
 from ..layers.moe import MoELayer
-from .llama import LlamaForCausalLM, LlamaModel
+from .llama import LlamaForCausalLM, LlamaModel, residual_sublayer
 
 
 class Qwen3NextConfig:
@@ -129,25 +126,13 @@ class Qwen3NextDecoderLayer(BaseLayer):
             held=c.experts_held, shared_width=c.shared_width,
             name=f"{name}_moe")
         self.input_norm, self.post_norm = norm("input_norm"), norm("post_norm")
-        recompute = c.remat == "gdn" and kind == "linear_attention"
-        self._mixer_scope = remat_scope if recompute else nullcontext
+        self.recompute = c.remat == "gdn" and kind == "linear_attention"
 
     def __call__(self, x, seq_len=None):
-        # the norm is inside the recomputed group: what the backward pass
-        # keeps of a recomputed mixer is the residual stream alone
-        # (norms and residual sums are the block `hetu_norm`; the sublayers
-        # name their own)
-        with self._mixer_scope():
-            with scope("hetu_norm"):
-                a_in = self.input_norm(x)
-            mixed = (self.attn(a_in, a_in, a_in, seq_len=seq_len)
-                     if self.kind == "full_attention" else self.gdn(a_in))
-        with scope("hetu_norm"):
-            x = x + mixed
-            m_in = self.post_norm(x)
-        m = self.mlp(m_in)
-        with scope("hetu_norm"):
-            return x + m
+        mixer = self.attn if self.kind == "full_attention" else self.gdn
+        x = residual_sublayer(x, self.input_norm, mixer, self.recompute,
+                              seq_len=seq_len)
+        return residual_sublayer(x, self.post_norm, self.mlp)
 
 
 class Qwen3NextModel(LlamaModel):

@@ -43,6 +43,26 @@ class ScopedOp(Op):
         return self.fn(*input_vals, **self.attrs)
 
 
+class KernelOp(ScopedOp):
+    """A ``ScopedOp`` whose ``fn`` chooses between a Pallas kernel and its
+    ``jax.numpy`` form but cannot see a mesh.  ``kernel`` is the label the
+    choice is counted under, ``form()`` the ``jax.numpy`` form, looked up
+    when the node computes.  Under a mesh the node asks ``dispatch.take``
+    itself and hands ``fn`` that form as ``rule=``; off a mesh ``rule`` is
+    None and ``fn`` asks."""
+
+    def __init__(self, fn, scope, *inputs, kernel, form, **attrs):
+        super().__init__(fn, scope, *inputs, **attrs)
+        self.kernel, self.form = kernel, form
+
+    def _compute(self, input_vals, ctx):
+        from .pallas import dispatch
+        if ctx.mesh is None:
+            return self.fn(*input_vals, rule=None, **self.attrs)
+        dispatch.take(self.kernel, ctx.mesh)   # its record: never the kernel
+        return self.fn(*input_vals, rule=self.form(), **self.attrs)
+
+
 def _peek_id():
     from ..graph import node as _n
     return _n._node_counter[0] + 1

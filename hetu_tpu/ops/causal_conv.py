@@ -19,27 +19,25 @@ offset and width multiples of 128 lanes, up to 8 taps, ``x`` bf16 or f32, the
 sequence a multiple of 16; any batch.  Each call counts its choice at trace
 time in ``hetu_kernel_choice_total{kernel="causal_conv", impl, reason}``:
 ``pallas``, or ``jnp`` with ``channels_not_128_aligned``, ``taps>8``,
-``dtype:<name>`` or ``seq_not_16_aligned``.  A mesh is the one
-thing the function cannot see (a ``pallas_call`` does not partition under
-GSPMD): the node reads it, calls ``causal_conv_jnp`` itself and counts
-``mesh``.  On any other platform there is no Mosaic and no choice: nothing is
-counted and ``causal_conv_jnp`` runs, bit for bit what this function was
-before it had kernels.  The kernels themselves run anywhere when called
-directly (interpret mode on the CPU): ``tests/test_causal_conv_kernel.py``.
+``dtype:<name>`` or ``seq_not_16_aligned``.  What a mesh (which the node
+sees: ``ConvOp``, an ``ops/base.py KernelOp``) and a platform without Mosaic
+mean is ``dispatch.take``'s rule; ``causal_conv_jnp`` then runs, bit for bit
+what this function was before it had kernels.  The kernels themselves run
+anywhere when called directly (interpret mode on the CPU):
+``tests/test_causal_conv_kernel.py``.
 """
 
 from __future__ import annotations
 
-from .base import ScopedOp
+from .base import KernelOp
 
 
 def causal_conv(x, w, b=None, window=None):
-    """On a TPU the Pallas kernel pair where its rule takes the operands,
-    else (and on any other platform, where there is no choice to record) the
-    ``jax.numpy`` form."""
+    """The Pallas kernel pair where ``dispatch.take`` and its rule allow,
+    else the ``jax.numpy`` form."""
     from .pallas import causal_conv as kernels, dispatch
-    if dispatch.mosaic() and dispatch.record(
-            "causal_conv", kernels.unsupported(x, w, b, window)):
+    if dispatch.take("causal_conv", None,
+                     kernels.unsupported(x, w, b, window)):
         return kernels.conv(x, w, b, window)
     return causal_conv_jnp(x, w, b, window)
 
@@ -61,20 +59,15 @@ def causal_conv_jnp(x, w, b=None, window=None):
     return jax.nn.silu(y).astype(x.dtype)
 
 
-class ConvOp(ScopedOp):
-    """The convolution's node, ``ConvOp(scope, x, w[, b], window=)``.  A
-    ``pallas_call`` does not partition under GSPMD and ``causal_conv`` cannot
-    see a mesh, so under one this node calls the ``jax.numpy`` form itself,
-    and says so where there was a kernel to take (reason ``mesh``)."""
+def _conv(*operands, window, rule):
+    return (rule or causal_conv)(*operands, window=window)
+
+
+class ConvOp(KernelOp):
+    """The convolution's node, ``ConvOp(scope, x, w[, b], window=)``: under a
+    mesh it calls the ``jax.numpy`` form itself (``ops/base.py KernelOp``)."""
 
     def __init__(self, scope, *inputs, window=None):
-        super().__init__(causal_conv, scope, *inputs, window=window)
-
-    def _compute(self, input_vals, ctx):
-        from .pallas import dispatch
-        fn = self.fn
-        if ctx.mesh is not None:
-            fn = causal_conv_jnp
-            if dispatch.mosaic():
-                dispatch.record("causal_conv", "mesh")
-        return fn(*input_vals, **self.attrs)
+        super().__init__(_conv, scope, *inputs, kernel="causal_conv",
+                         form=lambda: causal_conv_jnp,
+                         window=window)

@@ -62,11 +62,9 @@ it can read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk``
 counts its choice at trace time in ``hetu_kernel_choice_total{kernel=
 "gated_delta", impl, reason}``: ``pallas``, or ``jnp`` with
 ``head_dim_not_128_aligned``, ``chunk!=64``, ``dtype:<name>`` or
-``dtype:mixed``.  A mesh is the one thing the function cannot see (a
-``pallas_call`` does not partition under GSPMD): the scan node reads it,
-calls ``chunk_gated_delta_rule_jnp`` itself and counts ``mesh``.  On any
-other platform there is no Mosaic and no choice: nothing is counted and
-``chunk_gated_delta_rule_jnp`` runs, bit for bit what this function was
+``dtype:mixed``.  What a mesh (which the scan node sees, ``ops/base.py
+KernelOp``) and a platform without Mosaic mean is ``dispatch.take``'s rule;
+``chunk_gated_delta_rule_jnp`` then runs, bit for bit what this function was
 before it had kernels.  The kernels themselves run anywhere when called
 directly (interpret mode on the CPU): ``tests/test_gated_delta_kernel.py``.
 """
@@ -106,12 +104,11 @@ def recurrent_gated_delta_rule(q, k, v, g, beta, state_dtype=jnp.float32):
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
-    """The chunked form; see the module's docstring.  On a TPU the Pallas
-    kernel pair where its rule takes the operands, else (and on any other
-    platform, where there is no choice to record) the ``jax.numpy`` form."""
+    """The chunked form; see the module's docstring: the Pallas kernel pair
+    where ``dispatch.take`` and its rule allow, else the ``jax.numpy`` form."""
     from .pallas import dispatch, gated_delta as kernels
-    if dispatch.mosaic() and dispatch.record(
-            "gated_delta", kernels.unsupported(q, k, v, chunk)):
+    if dispatch.take("gated_delta", None,
+                     kernels.unsupported(q, k, v, chunk)):
         return kernels.gated_delta_rule(q, k, v, g, beta)
     return chunk_gated_delta_rule_jnp(q, k, v, g, beta, chunk)
 

@@ -21,11 +21,11 @@ the sequence a multiple of 16, a group of 16 rows within a block.  Each call
 counts its choice at trace time in ``hetu_kernel_choice_total{kernel=
 "gated_norm", impl, reason}``: ``pallas``, or ``jnp`` with
 ``width_not_128_aligned``, ``scale_not_a_group_or_all``, ``dtype:<name>``,
-``dtype:mixed``, ``seq_not_16_aligned``, ``group_wider_than_a_block`` or, under
-a mesh (a ``pallas_call`` does not partition under GSPMD), ``mesh``.  On any
-other platform there is no Mosaic and no choice: nothing is counted and the
-layer's form runs on the slice of ``z``.  The kernels themselves run anywhere
-when called directly (interpret mode on the CPU): ``tests/test_gated_norm.py``.
+``dtype:mixed``, ``seq_not_16_aligned`` or ``group_wider_than_a_block``; what a
+mesh and a platform without Mosaic mean is ``dispatch.take``'s rule, and the
+layer's form then runs on the slice of ``z``.  The kernels themselves run
+anywhere when called directly (interpret mode on the CPU):
+``tests/test_gated_norm.py``.
 """
 
 from __future__ import annotations
@@ -48,9 +48,8 @@ class OutOp(ScopedOp):
 
     def _compute(self, input_vals, ctx):
         o, wide, scale, w_out = input_vals
-        if dispatch.mosaic() and dispatch.record(
-                "gated_norm", "mesh" if ctx.mesh is not None else
-                kernels.unsupported(o, wide, scale, width=self.how["width"])):
+        if dispatch.take("gated_norm", ctx.mesh, kernels.unsupported(
+                o, wide, scale, width=self.how["width"])):
             return kernels.gated_norm(o, wide, scale, eps=self.attrs["eps"],
                                       **self.how) @ w_out
         z = kernels.take(wide, self.how["window"], o.shape[2])

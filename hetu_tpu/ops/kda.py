@@ -70,10 +70,9 @@ the chunk in VMEM, and returns the ``[B, T, H d]`` that the output product
 reads; it counts ``pallas`` once a call too, and returns None where
 ``chunk_kda`` would take the ``jax.numpy`` form (which then counts why).
 Which of the two ran is in ``hetu_kda_entry_total{form}`` (``in_place`` /
-``plain``).  A mesh is the one thing the functions cannot see: the scan node
-reads it, calls ``chunk_kda_jnp`` itself and counts ``mesh``.  On any other
-platform there is no Mosaic and no choice: nothing is counted and
-``chunk_kda_jnp`` runs.  The kernels themselves run anywhere when called
+``plain``).  What a mesh (which the scan node sees, ``ops/base.py
+KernelOp``) and a platform without Mosaic mean is ``dispatch.take``'s rule;
+``chunk_kda_jnp`` then runs.  The kernels themselves run anywhere when called
 directly (interpret mode on the CPU): ``tests/test_kda.py``.
 """
 
@@ -113,12 +112,11 @@ def recurrent_kda(q, k, v, g, beta, state_dtype=jnp.float32):
 
 
 def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
-    """The chunked form; see the module's docstring.  On a TPU the Pallas
-    kernel pair where its rule takes the operands, else (and on any other
-    platform, where there is no choice to record) the ``jax.numpy`` form."""
+    """The chunked form; see the module's docstring: the Pallas kernel pair
+    where ``dispatch.take`` and its rule allow, else the ``jax.numpy`` form."""
     from .pallas import dispatch, kda as kernels
-    if dispatch.mosaic() and dispatch.record(
-            "kda", kernels.unsupported(q, k, v, g, chunk)):
+    if dispatch.take("kda", None,
+                     kernels.unsupported(q, k, v, g, chunk)):
         return kernels.kda(q, k, v, g, beta)
     return chunk_kda_jnp(q, k, v, g, beta, chunk)
 
@@ -140,9 +138,9 @@ def chunk_kda_in_place(mixed, proj, beta, a_log, dt_bias, scale, *, heads,
     reason = kernels.unsupported(
         head, jax.ShapeDtypeStruct(head.shape, proj.dtype), head,
         jax.ShapeDtypeStruct(head.shape, jnp.float32), CHUNK)
-    if not dispatch.mosaic() or reason is not None:
+    # a refusal is ``chunk_kda``'s to count, when the caller falls back on it
+    if reason is not None or not dispatch.take("kda", None):
         return None
-    dispatch.record("kda")
     rate = jnp.repeat(jnp.exp(a_log.astype(jnp.float32)), d)
     return kernels.kda_in_place(mixed, proj, beta, rate, dt_bias, scale,
                                 lower_bound=lower_bound, eps=eps)

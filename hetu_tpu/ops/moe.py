@@ -787,16 +787,11 @@ def grouped_impl(pairs, num_experts, hidden, inter, dtype, mesh=None,
     from .pallas import dispatch, moe_gmm
     if tile is None:
         tile = GMM_TILE if pairs // num_experts >= GMM_TILE else 8
-    if impl == "ragged":
-        why = "caller:impl=ragged"
-    elif mesh is not None:
-        why = "mesh"            # pallas_call does not partition under GSPMD
-    elif impl is None and not dispatch.mosaic():
-        why = f"platform:{dispatch.platform()}"
-    else:
-        why = moe_gmm.unsupported(pairs, hidden, inter, tile, dtype)
-    return ("pallas", tile) if dispatch.record("moe_gmm", why) \
-        else ("ragged", None)
+    why = moe_gmm.unsupported(pairs, hidden, inter, tile, dtype)
+    if impl == "ragged":        # the caller's word comes before the mesh's
+        mesh, why = None, "caller:impl=ragged"
+    return ("pallas", tile) if dispatch.take(
+        "moe_gmm", mesh, why, asked=impl is not None) else ("ragged", None)
 
 
 def rows_impl(how, tile, mesh, tokens, hidden, dtype):
@@ -808,16 +803,13 @@ def rows_impl(how, tile, mesh, tokens, hidden, dtype):
     platform without Mosaic unless the caller asked for the kernels
     (``impl="pallas"``: interpret mode): there is no choice to record."""
     from .pallas import dispatch, moe_rows
-    if how != "pallas" and not dispatch.mosaic():
-        return None
     tt = ROWS_TOKENS if tokens >= ROWS_TOKENS else 8
-    if mesh is not None:
-        why = "mesh"            # pallas_call does not partition under GSPMD
-    elif how != "pallas":
-        why = f"moe_gmm:{how}"  # the products' own reason is moe_gmm's
-    else:
+    if how == "pallas":
         why = moe_rows.unsupported(tokens, hidden, tt, tile, dtype)
-    return tt if dispatch.record("moe_rows", why) else None
+    else:
+        why = f"moe_gmm:{how}"  # the products' own reason is moe_gmm's
+    return tt if dispatch.take("moe_rows", mesh, why,
+                               asked=how == "pallas") else None
 
 
 def _every_window(one_pass, step, tokens, idx, gate, weights):

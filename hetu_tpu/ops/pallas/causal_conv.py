@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
+from .common import fit, params
 
 #: rows of ``x`` before a tile that the backward pass reads with it (a bf16
 #: tile is 16 rows; the last ``K - 1 <= 8`` are used).  Chunks and tiles are
@@ -63,13 +64,6 @@ HALO = 16
 LANES, TILE, CHUNK = 512, 2 ** 20, 32 * 512
 
 _F32 = jnp.float32
-
-
-def _fit(n, most, unit):
-    """The largest multiple of ``unit`` up to ``most`` that divides ``n``
-    (0 where none does)."""
-    return next((t for t in range(min(most, n) // unit * unit, 0, -unit)
-                 if n % t == 0), 0)
 
 
 def unsupported(x, w, b=None, window=None):
@@ -193,12 +187,6 @@ def _bwd_kernel(*refs, bias, chunk):
     edge[...] = jax.lax.fori_loop(0, n, step, edge[...])
 
 
-def _params(interpret, order):
-    from jax.experimental.pallas import tpu as pltpu
-    return None if interpret else pltpu.CompilerParams(
-        dimension_semantics=order)
-
-
 def _window(*block_and_map):
     """A block given by its first element, not its index: a window's first
     lane is a multiple of 128, not of the tile."""
@@ -212,9 +200,9 @@ def _plan(x, lo, width, lanes, tile, chunk):
     first lane of channel tile ``c`` in ``x``, and the block of ``k`` rows of
     taps (or of the bias)."""
     import jax.experimental.pallas as pl
-    tc = _fit(width, lanes, 128)
-    ts = _fit(x.shape[1], max(tile // (tc * x.dtype.itemsize), HALO), HALO)
-    return (tc, ts, _fit(ts, max(chunk // tc, HALO), HALO),
+    tc = fit(width, lanes, 128)
+    ts = fit(x.shape[1], max(tile // (tc * x.dtype.itemsize), HALO), HALO)
+    return (tc, ts, fit(ts, max(chunk // tc, HALO), HALO),
             (width // tc, x.shape[0], x.shape[1] // ts),
             lambda c: pl.multiple_of(lo + c * tc, 128),
             lambda k: pl.BlockSpec((k, tc), lambda c, b, s: (0, c)))
@@ -239,7 +227,7 @@ def hetu_conv_fwd(x, w, b, *, lo, width, interpret, lanes=LANES, tile=TILE,
         out_specs=pl.BlockSpec((None, ts, tc), lambda c, b, s: (b, s, c)),
         out_shape=jax.ShapeDtypeStruct((B, S, width), x.dtype),
         scratch_shapes=[pltpu.VMEM((8, tc), _F32)],
-        compiler_params=_params(interpret,
+        compiler_params=params(interpret,
                                 ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*((x, w) + ((b,) if b is not None else ())))
@@ -277,7 +265,7 @@ def hetu_conv_bwd(x, w, b, dy, *, lo, width, interpret, lanes=LANES,
                        [jax.ShapeDtypeStruct((8, width), _F32)]
                        if bias else []),
         scratch_shapes=[pltpu.VMEM((8, tc), _F32)],
-        compiler_params=_params(interpret,
+        compiler_params=params(interpret,
                                 ("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*((x, x, w) + ((b,) if bias else ()) + (dy,)))

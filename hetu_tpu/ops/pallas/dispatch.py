@@ -2,8 +2,11 @@
 
 Three decisions live here so they are made once and the same way for
 every kernel file: which platform the program is being compiled for,
-whether a ``pallas_call`` runs in interpret mode, and the record of each
-kernel-versus-``jnp`` choice an op makes while it is traced.
+whether a ``pallas_call`` runs in interpret mode, and each
+kernel-versus-``jnp`` choice an op makes while it is traced, with its
+record.  A kernel with a per-shard form has a plan of its own that reads
+the mesh (``shard_axes``) and hands ``record`` its reason; every other
+kernel asks ``take``.
 
 A choice is counted in the telemetry registry
 (``hetu_kernel_choice_total{kernel, impl, reason}``) and, when the
@@ -76,6 +79,38 @@ def record(kernel, reason=None):
     if reason is not None and mosaic():
         _log.warning("%s: jnp form on tpu (%s)", kernel, reason)
     return reason is None
+
+
+#: the reason of a kernel that has no per-shard form, under a mesh
+MESH = "mesh"
+
+#: kernels behind a function that was ``jax.numpy`` before it had them: on a
+#: platform without Mosaic there is no choice, so ``take`` records nothing
+#: there unless the caller asked for the kernels.  Any other label says
+#: ``platform:<name>``, as the kernels with a plan do.
+NO_CHOICE_OFF_TPU = frozenset({"gated_delta", "ssd", "kda", "causal_conv",
+                               "gated_norm", "moe_rows"})
+
+
+def take(kernel, mesh, reason=None, asked=False):
+    """Whether ``kernel``'s Pallas form runs, recorded: the one rule of
+    every kernel that has no per-shard form.
+
+    ``mesh`` is the mesh the calling node sees, or None (a function that
+    cannot see one hands None, and its node asks for it under a mesh: a
+    ``pallas_call`` does not partition under GSPMD, so under a mesh the
+    ``jax.numpy`` form runs and the reason is ``mesh``).  ``reason`` is the
+    kernel file's own ``unsupported(..)``: None, or why its rule refuses
+    the operands.  ``asked``: the caller named the form itself, so the
+    platform is not a reason (interpret mode where there is no Mosaic)."""
+    there = mosaic() or asked
+    if not there and kernel in NO_CHOICE_OFF_TPU:
+        return False
+    if mesh is not None:
+        reason = MESH
+    elif not there:
+        reason = f"platform:{platform()}"
+    return record(kernel, reason)
 
 
 def counted(name):

@@ -9,7 +9,7 @@ the generator does not partition: every device draws the words of the
   ``hetu_dropout_mask``: grid over row blocks of the ``[rows, lanes]`` view
   of an activation.  A block reseeds the per-core PRNG with (seed, block
   index), draws its words in VMEM, compares them with the u32 threshold
-  flash attention's in-kernel dropout uses (``_tile_keep``) and stores the
+  flash attention's in-kernel dropout uses (``tile_keep``) and stores the
   keep mask as int8.  The words never reach HBM.
 
 The kernel needs a shape, not the activation: the caller applies the mask
@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import interpret
-from .flash_attention import _tile_keep, shard_seed
+from .flash_attention import tile_keep, shard_seed
 
 _LANES = 128
 _SUBLANES = 32            # int8 tiles are (32, 128)
@@ -48,7 +48,7 @@ def unsupported(shape):
 
 
 def _mask_kernel(seed_ref, out_ref, *, keep_prob):
-    keep = _tile_keep(out_ref.shape, seed_ref, pl.program_id(0), keep_prob)
+    keep = tile_keep(out_ref.shape, seed_ref, pl.program_id(0), keep_prob)
     out_ref[...] = keep.astype(out_ref.dtype)
 
 

@@ -194,7 +194,7 @@ def _tile_index(bh, qi, j, nq, nk):
     return (bh * nq + qi) * nk + j
 
 
-def _tile_keep(shape, seed_ref, tile, keep_prob):
+def tile_keep(shape, seed_ref, tile, keep_prob):
     """The deterministic keep mask for one prob tile.  BOTH kernels must
     obtain masks through this single helper — the backward replays the
     forward's masks purely by reseeding with the same tile index."""
@@ -293,7 +293,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
             l_new = l * alpha + jnp.sum(p, axis=1)
             if keep_prob < 1.0:
                 nq = q_len // bq
-                keep = _tile_keep(p.shape, seed_ref,
+                keep = tile_keep(p.shape, seed_ref,
                                   _tile_index(bh, qi, j, nq, nk), keep_prob)
                 p = jnp.where(keep, p, 0.0)    # 1/keep_prob: at the end
             acc_new = acc * alpha[:, None] + _nn(p.astype(vb.dtype), vb)
@@ -485,7 +485,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 # replay the fwd tile (batch row x head, q-block i, kv-block
                 # kj); the 1/keep_prob of both dropped tiles is applied to
                 # the (rows, g*d) results: ds here is keep_prob x the true
-                keep = _tile_keep(
+                keep = tile_keep(
                     p.shape, seed_ref,
                     _tile_index(b * heads + hg * group + h, i, kj, nq, nk),
                     keep_prob)

@@ -30,7 +30,7 @@ program again in the backward pass would add two state products a chunk.
 
 A chunk is one chain of dependent steps (the solve, ``T rhs``, ``W S``, the
 state), and Mosaic's scheduler stays close to program order.  So a program
-holds several heads and runs their chains in step (``_together``: the chunk
+holds several heads and runs their chains in step (``together``: the chunk
 functions are generators that yield between dependent stages): this alone
 took a layer's forward pass from 6.4 to 3.3 ms on a v5e.  A stage is a
 jitted function of values, so the heads, the two kernels and every call
@@ -46,7 +46,7 @@ Precision.  The state, the decays, ``T`` and every operand of a product
 with them stay f32; ``K K^T``, ``Q K^T``, ``P u`` and their gradient products
 take operands in the compute type and add in f32, as the ``jax.numpy`` form.
 An f32 product runs as bf16 passes over the operands' three bf16 parts
-(``_dot32``): all 24 bits of an f32 mantissa, six passes, what XLA's
+(``dot32``): all 24 bits of an f32 mantissa, six passes, what XLA's
 ``HIGHEST`` gives the ``jax.numpy`` form, whatever the inputs' type; an
 operand that is bf16 already (q, k, v, do) is one part, exactly, and its
 product with an f32 operand three passes.  (Two parts, 16 bits in three
@@ -65,84 +65,17 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
+from .common import (C, CHUNKS, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot,
+                     dot32, head_lanes, iotas, params, pick, put, to_col,
+                     to_row, together, unit_lower_inverse, walk)
 
-#: positions a chunk (``ops.gated_delta.CHUNK``; the kernels are written for it)
-C = 64
-#: chunks a program walks: amortises the cost of a grid step over eight
-#: chunks (4 and 16 measured the same)
-CHUNKS = 8
 #: heads a program runs in step (at two parts an f32 operand: 1: 7.2 + 9.2
 #: ms a layer forward + backward on a v5e, 2: 4.2 + 5.9, 4: 3.3 + 4.7, 8:
 #: 3.4 + 4.0 and four times the time to compile; at three parts 4: 4.4 + 6.9)
 HEADS = 4
-#: rows of a diagonal block solved row by row before the merges (8 and 32
-#: measured 1-5% slower)
-DIAG = 16
-#: scoped VMEM the kernels may use: the backward program's seven [512, 512]
-#: blocks and 32 kept states, double-buffered, are about 11 MiB, and its
-#: body spills f32 [64, 128] and [128, 128] values beside them
-VMEM_LIMIT = 64 * 2 ** 20
 
 _F32 = jnp.float32
 _BF16 = jnp.bfloat16
-_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
-
-
-def _dot(a, b, dims):
-    """``a . b`` contracting ``dims``, operands as they are, f32 sums."""
-    return jax.lax.dot_general(a, b, (dims, ((), ())),
-                               preferred_element_type=_F32)
-
-
-#: bf16 parts of an f32 operand: 3 x 8 bits, all of its mantissa
-PARTS = 3
-
-
-def _parts(x):
-    """``x`` as bf16 arrays that add up to it: itself where it is bf16, else
-    the ``PARTS`` leading bf16 parts of an f32 ``x`` (8 bits of mantissa
-    each)."""
-    if x.dtype == _BF16:
-        return [x]
-    out = []
-    for _ in range(PARTS - 1):
-        out.append(x.astype(_BF16))
-        x = x - out[-1].astype(_F32)
-    return out + [x.astype(_BF16)]
-
-
-def _dot32(a, b, dims):
-    """A product with f32 operands on the matrix unit, at f32 precision: bf16
-    passes over the pairs of parts whose indices add up to less than
-    ``PARTS`` (six for two f32 operands, as XLA's ``HIGHEST``; what is left
-    out is under 2^-24 of the product), f32 sums, least terms first.  An
-    operand that is bf16 is one part: three passes."""
-    ap, bp = _parts(a), _parts(b)
-    out = None
-    for i in reversed(range(len(ap))):
-        for j in reversed(range(len(bp))):
-            if i + j < PARTS:
-                t = _dot(ap[i], bp[j], dims)
-                out = t if out is None else out + t
-    return out
-
-
-def _iotas():
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    return row, col
-
-
-def _to_col(r, eye):
-    """``[1, C] -> [C, 1]``, exactly (one term a sum)."""
-    return jnp.sum(jnp.where(eye, jnp.broadcast_to(r, (C, C)), 0.0), axis=1,
-                   keepdims=True)
-
-
-def _to_row(c, eye):
-    """``[C, 1] -> [1, C]``, exactly."""
-    return jnp.sum(jnp.where(eye, jnp.broadcast_to(c, (C, C)), 0.0), axis=0,
-                   keepdims=True)
 
 
 # A chunk's chain is cut into stages, each a jitted function of values: a
@@ -152,88 +85,29 @@ def _to_row(c, eye):
 # kernels' traces now take 2.3 s together and the set-up is the parent's
 # (PERF.md, PR 32).  Mosaic lowers the stages inline, so the kernel is the
 # one the plain functions would give.  The generators below yield between
-# stages (``_together``).
+# stages (``together``).
 
-
-def _solve_masks():
-    """``(rel, pick)``, int32 ``[C, C]``: the column's offset from the start
-    of the row's diagonal block, and the row's index inside its block where
-    the column is in that block too (else -1)."""
-    row, col = _iotas()
-    shift = DIAG.bit_length() - 1
-    same = (row >> shift) == (col >> shift)
-    return (col - ((row >> shift) << shift),
-            jnp.where(same, row & (DIAG - 1), -1))
-
-
-@jax.jit
-def _solve_open(L, LT):
-    rel, pick = _solve_masks()
-    return (jnp.where(pick >= 0, -L, 0.0), jnp.where(pick >= 0, -LT, 0.0),
-            rel, pick)
-
-
-@functools.partial(jax.jit, static_argnames="i")
-def _solve_row(A, ATn, rel, pick, *, i):
-    # coefficient of row j for row i of j's block: -L[block(j) + i, j]
-    c = jnp.sum(jnp.where(rel == i, ATn, 0.0), axis=1, keepdims=True)
-    r = jnp.sum(c * A, axis=0, keepdims=True)
-    return jnp.where(pick == i, A + r, A)
-
-
-@functools.partial(jax.jit, static_argnames="size")
-def _merge_left(T, L, *, size):
-    row, col = _iotas()
-    s = size.bit_length() - 1
-    off = ((row >> (s + 1)) == (col >> (s + 1))) & ((row >> s) != (col >> s))
-    return _dot32(T, jnp.where(off, L, 0.0), _NN)
-
-
-@jax.jit
-def _merge_right(T, TB):
-    return T - _dot32(TB, T, _NN)
-
-
-def _unit_lower_inverse(L, LT):
-    """``(I + L)^-1`` for a strictly lower triangular ``L [C, C]`` given with
-    its transpose; see the module's docstring.  (Walking the rows by sublane
-    tiles of 8, so that a step touches only the tiles it reads and writes,
-    was 0.8% of the cell's step faster and seven times the stages.)"""
-    A, ATn, rel, pick = _solve_open(L, LT)
-    for i in range(1, DIAG):
-        A = _solve_row(A, ATn, rel, pick, i=i)
-        yield
-    row, col = _iotas()
-    T = A + jnp.where(row == col, 1.0, 0.0)
-    size = DIAG
-    while size < C:
-        TB = _merge_left(T, L, size=size)
-        yield
-        T = _merge_right(T, TB)
-        yield
-        size *= 2
-    return T
 
 
 @jax.jit
 def _chunk_open(k, g_row, beta_row):
     """The running sum ``G``, the masked decays, ``K_beta`` and the triangle
     ``L = strict(K_beta K^T D)`` with its transpose."""
-    row, col = _iotas()
+    row, col = iotas()
     eye, lower = row == col, row >= col
     G = jnp.sum(jnp.where(lower, jnp.broadcast_to(g_row, (C, C)), 0.0),
                 axis=1, keepdims=True)                       # [C, 1]
-    beta = _to_col(beta_row, eye)
-    G_row = _to_row(G, eye)
+    beta = to_col(beta_row, eye)
+    G_row = to_row(G, eye)
     diff = G - G_row                                         # G_t - G_s
     # masked before the exp: above the diagonal the difference is positive
     D = jnp.exp(jnp.where(lower, diff, -jnp.inf))
     DT = jnp.exp(jnp.where(row <= col, -diff, -jnp.inf))
     kb = (k.astype(_F32) * beta).astype(k.dtype)
-    KK = _dot(kb, k, _NT)
+    KK = dot(kb, k, NT)
     return dict(G=G, G_row=G_row, beta=beta, D=D, kb=kb, KK=KK,
                 L=jnp.where(row > col, KK * D, 0.0),
-                LT=jnp.where(row < col, _dot(k, kb, _NT) * DT, 0.0))
+                LT=jnp.where(row < col, dot(k, kb, NT) * DT, 0.0))
 
 
 @jax.jit
@@ -242,14 +116,14 @@ def _chunk_rhs(T, k, v, beta_row, G_row):
     columns, so that v and k enter as they are (one part where they are
     bf16)."""
     Tb = T * beta_row
-    return _dot32(Tb, v, _NN), _dot32(Tb * jnp.exp(G_row), k, _NN)
+    return dot32(Tb, v, NN), dot32(Tb * jnp.exp(G_row), k, NN)
 
 
 @jax.jit
 def _chunk_close(q, k, G, D):
-    row, col = _iotas()
+    row, col = iotas()
     G_end = G[C - 1:C]                                       # [1, 1]
-    QK = _dot(q, k, _NT)
+    QK = dot(q, k, NT)
     return dict(QK=QK, P=jnp.where(row >= col, QK * D, 0.0).astype(k.dtype),
                 eG=jnp.exp(G), e_end=jnp.exp(G_end - G), a=jnp.exp(G_end))
 
@@ -258,10 +132,10 @@ def _chunk(q, k, v, g_row, beta_row):
     """What a chunk's forward and backward passes share and the state does
     not enter.  A generator, as the three functions around it: it yields
     between stages that depend on each other and returns its value at the
-    end (``_together``)."""
+    end (``together``)."""
     c = _chunk_open(k, g_row, beta_row)
     yield
-    T = yield from _unit_lower_inverse(c["L"], c["LT"])
+    T = yield from unit_lower_inverse(c["L"], c["LT"])
     Vp, W = _chunk_rhs(T, k, v, beta_row, c["G_row"])
     yield
     return dict({n: c[n] for n in ("beta", "D", "kb", "KK")}, T=T, Vp=Vp,
@@ -270,13 +144,13 @@ def _chunk(q, k, v, g_row, beta_row):
 
 @jax.jit
 def _u(Vp, W, S):
-    return Vp - _dot32(W, S, _NN)
+    return Vp - dot32(W, S, NN)
 
 
 @jax.jit
 def _fwd_close(q, k, S, u, c):
-    o = _dot32(q, S, _NN) * c["eG"] + _dot(c["P"], u.astype(k.dtype), _NN)
-    return o, S * c["a"] + _dot32(k, u * c["e_end"], _TN)
+    o = dot32(q, S, NN) * c["eG"] + dot(c["P"], u.astype(k.dtype), NN)
+    return o, S * c["a"] + dot32(k, u * c["e_end"], TN)
 
 
 def _chunk_fwd(q, k, v, g_row, beta_row, S):
@@ -290,39 +164,39 @@ def _chunk_fwd(q, k, v, g_row, beta_row, S):
 # o = (q exp(G)) S + P u;  S_next = a S + (k e_end)^T u;  u = V' - W S
 @jax.jit
 def _bwd_u(k, do, dS, u, c):
-    dP = _dot(do, u.astype(k.dtype), _NT)
-    du = _dot(c["P"], do, _TN) + _dot32(k, dS, _NN) * c["e_end"]
-    return dP, du, _dot32(u, dS, _NT)                        # .., dk_end
+    dP = dot(do, u.astype(k.dtype), NT)
+    du = dot(c["P"], do, TN) + dot32(k, dS, NN) * c["e_end"]
+    return dP, du, dot32(u, dS, NT)                          # .., dk_end
 
 
 @jax.jit
 def _bwd_state(q, do, S, dS, du, c):
     da = jnp.sum(jnp.sum(dS * S, axis=1, keepdims=True), axis=0,
                  keepdims=True)
-    dQe = _dot32(do, S, _NT)
-    dW = -_dot32(du, S, _NT)
-    dS0 = (dS * c["a"] + _dot32(q, do.astype(_F32) * c["eG"], _TN)
-           - _dot32(c["W"], du, _TN))
+    dQe = dot32(do, S, NT)
+    dW = -dot32(du, S, NT)
+    dS0 = (dS * c["a"] + dot32(q, do.astype(_F32) * c["eG"], TN)
+           - dot32(c["W"], du, TN))
     return da, dQe, dW, dS0
 
 
 @jax.jit
 def _bwd_rhs(T, du, dW):
     # [V' | W] = T [beta v | beta exp(G) k]
-    return _dot32(T, du, _TN), _dot32(T, dW, _TN)
+    return dot32(T, du, TN), dot32(T, dW, TN)
 
 
 @jax.jit
 def _bwd_solve(dRv, dRw, Vp, W):
-    row, col = _iotas()
-    return jnp.where(row > col, -(_dot32(dRv, Vp, _NT)
-                                  + _dot32(dRw, W, _NT)), 0.0)
+    row, col = iotas()
+    return jnp.where(row > col, -(dot32(dRv, Vp, NT)
+                                  + dot32(dRw, W, NT)), 0.0)
 
 
 @jax.jit
 def _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c):
     ct, beta, eG, D, e_end = k.dtype, c["beta"], c["eG"], c["D"], c["e_end"]
-    row, col = _iotas()
+    row, col = iotas()
     eye = row == col
     qf, kf, vf = (t.astype(_F32) for t in (q, k, v))
     dv = dRv * beta
@@ -331,9 +205,9 @@ def _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c):
     # L = strict(K_beta K^T D), P = lower(Q K^T D)
     dPD = jnp.where(row >= col, dP, 0.0)
     dKK, dQK = (dL * D).astype(ct), (dPD * D).astype(ct)
-    dkb = _dot(dKK, k, _NN)
-    dq = _dot(dQK, k, _NN) + dQe * eG
-    dk = (dk + _dot(dKK, c["kb"], _TN) + _dot(dQK, q, _TN) + dkb * beta
+    dkb = dot(dKK, k, NN)
+    dq = dot(dQK, k, NN) + dQe * eG
+    dk = (dk + dot(dKK, c["kb"], TN) + dot(dQK, q, TN) + dkb * beta
           + dk_end * e_end)
     dbeta = (jnp.sum(dRv * vf + dkb * kf, axis=1, keepdims=True)
              + kRw * eG)
@@ -342,13 +216,13 @@ def _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c):
     # D = exp(G_t - G_s): the gradient of the difference, then of G
     dDiff = (dL * c["KK"] + dPD * c["QK"]) * D
     dG = (deG * eG - dE + jnp.sum(dDiff, axis=1, keepdims=True)
-          - _to_col(jnp.sum(dDiff, axis=0, keepdims=True), eye))
+          - to_col(jnp.sum(dDiff, axis=0, keepdims=True), eye))
     dG_end = jnp.sum(dE, axis=0, keepdims=True) + da * c["a"]
     dG = dG + jnp.where(row[:, :1] == C - 1, dG_end, 0.0)
     # G is g's running sum: dg_t = sum of dG from t on
     dg = jnp.sum(jnp.where(row >= col, jnp.broadcast_to(dG, (C, C)), 0.0),
                  axis=0, keepdims=True)
-    return dq, dk, dv, dg, _to_row(dbeta, eye)
+    return dq, dk, dv, dg, to_row(dbeta, eye)
 
 
 def _chunk_bwd(q, k, v, g_row, beta_row, S, do, dS):
@@ -370,76 +244,28 @@ def _chunk_bwd(q, k, v, g_row, beta_row, S, do, dS):
     return _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c) + (dS0,)
 
 
-def _together(gens):
-    """Run generators in step, one stage each in turn, and return their
-    values: the chains of a program's heads are then interleaved in program
-    order, which Mosaic's scheduler stays close to."""
-    gens, out = list(gens), {}
-    live = list(range(len(gens)))
-    while live:
-        for i in list(live):
-            try:
-                next(gens[i])
-            except StopIteration as stop:
-                out[i] = stop.value
-                live.remove(i)
-    return [out[i] for i in range(len(gens))]
-
-
-def _pick(block, j):
-    """Row ``j`` (traced) of a small ``[n, C]`` block, ``[1, C]``."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
-    return jnp.sum(jnp.where(rows == j, block, 0.0), axis=0, keepdims=True)
-
-
-def _put(ref, j, row):
-    """Write ``row [1, C]`` as row ``j`` (traced) of a small block."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, ref.shape, 0)
-    ref[...] = jnp.where(rows == j, jnp.broadcast_to(row, ref.shape),
-                         ref[...])
-
-
-def _walk(nc, body):
-    """``body(j)`` for the program's chunks ``j``."""
-    def step(j, carry):
-        body(j)
-        return carry
-    jax.lax.fori_loop(0, nc, step, 0)
-
-
-def _rows(j):
-    import jax.experimental.pallas as pl
-    return pl.ds(pl.multiple_of(j * C, C), C)
-
-
-def _lanes(hb, dk, dv):
-    """The lanes of each of a program's heads in its q / k and v / o blocks."""
-    return [(slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv))
-            for h in range(hb)]
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
                 s_ref, *, nc, hb, dk, dv):
     import jax.experimental.pallas as pl
     i = pl.program_id(2)
-    lanes = _lanes(hb, dk, dv)
+    lanes = head_lanes(hb, dk, dv)
 
     @pl.when(i == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     def body(j):
-        rows = _rows(j)
+        rows = chunk_rows(j, C)
         for h in range(hb):
             s0_ref[h, j] = s_ref[h]
-        outs = _together(
+        outs = together(
             _chunk_fwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-                       _pick(g_ref[h], j), _pick(b_ref[h], j), s_ref[h])
+                       pick(g_ref[h], j), pick(b_ref[h], j), s_ref[h])
             for h, (kl, vl) in enumerate(lanes))
         for h, (o, S) in enumerate(outs):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
-    _walk(nc, body)
+    walk(nc, body)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
@@ -450,7 +276,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, nc, hb, dk,
                 dv):
     import jax.experimental.pallas as pl
-    lanes = _lanes(hb, dk, dv)
+    lanes = head_lanes(hb, dk, dv)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -458,10 +284,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
 
     def body(n):
         j = nc - 1 - n
-        rows = _rows(j)
-        outs = _together(
+        rows = chunk_rows(j, C)
+        outs = together(
             _chunk_bwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-                       _pick(g_ref[h], j), _pick(b_ref[h], j), s0_ref[h, j],
+                       pick(g_ref[h], j), pick(b_ref[h], j), s0_ref[h, j],
                        do_ref[rows, vl], ds_ref[h])
             for h, (kl, vl) in enumerate(lanes))
         for h, (dq, dk_, dv_, dg, dbeta, dS) in enumerate(outs):
@@ -469,19 +295,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
             dq_ref[rows, kl] = dq.astype(dq_ref.dtype)
             dk_ref[rows, kl] = dk_.astype(dk_ref.dtype)
             dv_ref[rows, vl] = dv_.astype(dv_ref.dtype)
-            _put(dg_ref.at[h], j, dg)
-            _put(db_ref.at[h], j, dbeta)
+            put(dg_ref.at[h], j, dg)
+            put(db_ref.at[h], j, dbeta)
             ds_ref[h] = dS
-    _walk(nc, body)
+    walk(nc, body)
 
-
-def _params(interpret):
-    from jax.experimental.pallas import tpu as pltpu
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _plan(g, q, v, reverse):
@@ -522,7 +340,8 @@ def _fwd_call(q, k, v, g, beta, *, interpret):
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(q, k, v, g, beta)
 
 
@@ -543,7 +362,8 @@ def _bwd_call(q, k, v, g, beta, states, do, dlast, *, interpret):
                    jax.ShapeDtypeStruct(g.shape, _F32)],
         scratch_shapes=[pltpu.VMEM((dims["hb"], dims["dk"], dims["dv"]),
                                    _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(q, k, v, g, beta, states, do, dlast)
 
 

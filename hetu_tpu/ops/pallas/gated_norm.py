@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
-from .causal_conv import _fit, _params
+from .common import fit, params
 
 #: rows a chunk and a block are multiples of: a bf16 tile's sublanes
 ROWS = 16
@@ -83,7 +83,7 @@ def _lanes(channels, width, window, most):
     """The lanes of a block: the most whole groups up to ``most`` lanes that
     tile the channels and, with a window, its runs (0 where none does)."""
     span = channels if window is None else math.gcd(*window)
-    return _fit(math.gcd(channels, span), max(most, width), width)
+    return fit(math.gcd(channels, span), max(most, width), width)
 
 
 def in_place(channels, width, window):
@@ -207,10 +207,10 @@ def _plan(o, width, window, lanes, tile, chunk):
     import jax.experimental.pallas as pl
     B, S, C = o.shape
     tl = _lanes(C, width, window, lanes)
-    ts = _fit(S, max(tile // (tl * o.dtype.itemsize), ROWS), ROWS)
+    ts = fit(S, max(tile // (tl * o.dtype.itemsize), ROWS), ROWS)
     lo, span, stride = window or (0, C, C)
     z_lane = lambda c: (lo + c * tl // span * stride + c * tl % span) // tl
-    return (_fit(ts, max(chunk // width, ROWS), ROWS), (C // tl, B, S // ts),
+    return (fit(ts, max(chunk // width, ROWS), ROWS), (C // tl, B, S // ts),
             pl.BlockSpec((None, ts, tl), lambda c, b, s: (b, s, c)),
             pl.BlockSpec((None, ts, tl), lambda c, b, s: (b, s, z_lane(c))),
             lambda k: pl.BlockSpec((k, tl), lambda c, b, s: (0, c)))
@@ -234,7 +234,7 @@ def hetu_gated_norm_fwd(o, z, w, *, width, gate_first, eps, window, interpret,
         name="hetu_gated_norm_fwd", grid=grid,
         in_specs=[block, z_block, row(1)], out_specs=block,
         out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
-        compiler_params=_params(interpret, ("parallel",) * 3),
+        compiler_params=params(interpret, ("parallel",) * 3),
         interpret=interpret,
     )(o, z, w)
 
@@ -256,7 +256,7 @@ def hetu_gated_norm_bwd(o, z, w, dy, *, width, gate_first, eps, window,
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
                    jax.ShapeDtypeStruct(o.shape, z.dtype),
                    jax.ShapeDtypeStruct((8, o.shape[2]), _F32)],
-        compiler_params=_params(interpret,
+        compiler_params=params(interpret,
                                 ("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(o, z, w, dy)

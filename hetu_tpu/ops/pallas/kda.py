@@ -50,7 +50,7 @@ end, clamped at one where they lie before it; columns decayed to it).
 
 A chunk is one chain of dependent steps and Mosaic's scheduler stays close
 to program order, so a program's heads run their chains in step as the
-scalar rule's do (``_together``: ``_open`` and ``_close`` are generators that
+scalar rule's do (``together``: ``_open`` and ``_close`` are generators that
 yield between dependent stages, and the heads' triangular inverses are one
 ``custom_vjp`` that runs their substitutions in step); the backward pass,
 being the transposition of that trace, is interleaved the same way.  On a
@@ -62,7 +62,7 @@ gates and re-tilings around them, ``in_place`` 4.75 and 10.05 with 3.7.
 
 Precision as ``ops/pallas/gated_delta.py``: the state, the decays, ``T`` and
 every operand of a product with them are f32, multiplied as bf16 passes
-over their three bf16 parts (``_dot32``); the pair matrices and ``P u`` take
+over their three bf16 parts (``dot32``); the pair matrices and ``P u`` take
 their operands in the compute type.
 """
 
@@ -76,34 +76,34 @@ import jax.numpy as jnp
 
 from . import dispatch
 from ... import telemetry
-from .gated_delta import (C, CHUNKS, _NN, _NT, _TN, _dot, _dot32,
-                          _iotas, _lanes, _params, _pick, _put, _rows,
-                          _to_col, _together, _unit_lower_inverse, _walk)
+from .common import (C, CHUNKS, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot,
+                     dot32, head_lanes, iotas, params, pick, put, to_col,
+                     together, unit_lower_inverse, walk)
 from ..kda import SUB
 
 _F32 = jnp.float32
 _BF16 = jnp.bfloat16
 
-#: heads a program runs in step (``_together``); the module's docstring has
+#: heads a program runs in step (``together``); the module's docstring has
 #: the times of 1, 2 and 4
 HEADS = 4
 
 #: the cotangents' products of ``c = a . b`` by form: (operands, form) of da
 #: and of db, ``g`` the cotangent of ``c``
 _TRANSPOSED = {
-    _NN: (("g", "b", _NT), ("a", "g", _TN)),
-    _NT: (("g", "b", _NN), ("g", "a", _TN)),
-    _TN: (("b", "g", _NT), ("a", "g", _NN)),
+    NN: (("g", "b", NT), ("a", "g", TN)),
+    NT: (("g", "b", NN), ("g", "a", TN)),
+    TN: (("b", "g", NT), ("a", "g", NN)),
 }
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _mm(a, b, dims, hi):
     """``a . b`` contracting ``dims`` with f32 sums; ``hi``: at f32 precision
-    (``_dot32``), else operands as they are (the compute type).  Its
+    (``dot32``), else operands as they are (the compute type).  Its
     cotangents are products of the same three forms, so no transposition
     reaches Mosaic."""
-    return (_dot32 if hi else _dot)(a, b, dims)
+    return (dot32 if hi else dot)(a, b, dims)
 
 
 def _mm_fwd(a, b, dims, hi):
@@ -125,9 +125,9 @@ _mm.defvjp(_mm_fwd, _mm_bwd)
 
 def _inverse_cotangent(T, dT):
     """``dL`` of ``T = (I + L)^-1``: ``-T^T dT T^T`` below the diagonal."""
-    row, col = _iotas()
+    row, col = iotas()
     return jnp.where(row > col,
-                     -_mm(_mm(T, dT, _TN, True), T, _NT, True), 0.0)
+                     -_mm(_mm(T, dT, TN, True), T, NT, True), 0.0)
 
 
 def _row_of(x, j):
@@ -143,20 +143,20 @@ def _pair(a, kf, G, ends, mid, to_end, ct):
     d]`` f32; ``ends``: the running sum at each sub-chunk's last position,
     ``mid`` / ``to_end``: for each row the sum at the middle of its sub-chunk
     and ``exp(its sub-chunk's last sum - G)``."""
-    row, col = _iotas()
+    row, col = iotas()
     shift = SUB.bit_length() - 1
     rb, cb = row >> shift, col >> shift
     sub = rb[:, :1]                                          # [C, 1]
     inv = (kf * jnp.exp(mid - G)).astype(ct)
     out = jnp.where((rb == cb) & (row >= col),
-                    _mm((a * jnp.exp(G - mid)).astype(ct), inv, _NT,
+                    _mm((a * jnp.exp(G - mid)).astype(ct), inv, NT,
                         False), 0.0)
     cols = kf * to_end
     for j, end in enumerate(ends[:-1]):
         # rows after sub-chunk j, decayed from its end; before it the
         # exponent is positive, clamped, and the entry masked
         rows = (a * jnp.exp(jnp.minimum(G - end, 0.0))).astype(ct)
-        block = _mm(rows, jnp.where(sub == j, cols, 0.0).astype(ct), _NT,
+        block = _mm(rows, jnp.where(sub == j, cols, 0.0).astype(ct), NT,
                     False)
         out = jnp.where((cb == j) & (rb > j), block, out)
     return out
@@ -166,11 +166,11 @@ def _pair(a, kf, G, ends, mid, to_end, ct):
 def _inverses(Ls):
     """``(I + L)^-1`` of each of a tuple of strictly lower triangular ``L [C,
     C]`` by the scalar rule's blocked substitution, the substitutions run in
-    step (``_together``): one head's fifteen dependent row steps fill the
+    step (``together``): one head's fifteen dependent row steps fill the
     gaps of another's."""
-    eye = jnp.where(jnp.equal(*_iotas()), 1.0, 0.0).astype(_BF16)
-    return tuple(_together(
-        _unit_lower_inverse(L, _dot32(L, eye, _TN)) for L in Ls))
+    eye = jnp.where(jnp.equal(*iotas()), 1.0, 0.0).astype(_BF16)
+    return tuple(together(
+        unit_lower_inverse(L, dot32(L, eye, TN)) for L in Ls))
 
 
 def _inverses_fwd(Ls):
@@ -195,7 +195,7 @@ def _unit(x):
 def _open(q, k, g, beta_row, gate=None):
     """A chunk up to its triangle ``L``: what the state does not enter.  A
     generator, as ``_close``: it yields between stages that depend on each
-    other and returns its value at the end (``_together``).  ``gate``: None,
+    other and returns its value at the end (``together``).  ``gate``: None,
     or ``(rate, bias [1, d_k] f32, lower_bound)`` where ``q, k`` are the
     convolution's ``q~, k~`` and ``g`` the projection's ``f``: the head's
     norms and its gate are then taken here, on the chunk (rounded to the
@@ -207,11 +207,11 @@ def _open(q, k, g, beta_row, gate=None):
         k = _unit(k).astype(ct)
         g = lower * jax.nn.sigmoid(rate * (g.astype(_F32) + bias))
         yield
-    row, col = _iotas()
+    row, col = iotas()
     eye, lower = row == col, row >= col
     qf, kf = q.astype(_F32), k.astype(_F32)
     # the running sum as a product with the triangle of ones (exact in bf16)
-    G = _mm(jnp.where(lower, 1.0, 0.0).astype(_BF16), g, _NN, True)
+    G = _mm(jnp.where(lower, 1.0, 0.0).astype(_BF16), g, NN, True)
     yield
     n = C // SUB
     ends = [_row_of(G, SUB * (j + 1) - 1) for j in range(n)]
@@ -223,7 +223,7 @@ def _open(q, k, g, beta_row, gate=None):
         end = jnp.where(sub == j, ends[j], end)
     to_end = jnp.exp(end - G)
     yield
-    beta = _to_col(beta_row, eye)
+    beta = to_col(beta_row, eye)
     L = jnp.where(row > col,
                   _pair(kf, kf, G, ends, mid, to_end, ct) * beta, 0.0)
     yield
@@ -239,12 +239,12 @@ def _close(c, T, v, beta_row, S, norm=None):
     ct, G, kf = v.dtype, c["G"], c["kf"]
     Tb = T * beta_row
     eG = jnp.exp(G)
-    Vp = _mm(Tb, v, _NN, True)
-    W = _mm(Tb, kf * eG, _NN, True)
+    Vp = _mm(Tb, v, NN, True)
+    W = _mm(Tb, kf * eG, NN, True)
     yield
-    u = Vp - _mm(W, S, _NN, True)
+    u = Vp - _mm(W, S, NN, True)
     yield
-    o = _mm(c["qf"] * eG, S, _NN, True) + _mm(c["P"], u.astype(ct), _NN,
+    o = _mm(c["qf"] * eG, S, NN, True) + _mm(c["P"], u.astype(ct), NN,
                                                False)
     yield
     if norm is not None:
@@ -258,7 +258,7 @@ def _close(c, T, v, beta_row, S, norm=None):
     a_col = jnp.sum(jnp.where(eye_k, jnp.broadcast_to(jnp.exp(c["G_end"]),
                                                       (dk, dk)), 0.0),
                     axis=1, keepdims=True)
-    S_next = S * a_col + _mm(kf * jnp.exp(c["G_end"] - G), u, _TN, True)
+    S_next = S * a_col + _mm(kf * jnp.exp(c["G_end"] - G), u, TN, True)
     return o, S_next
 
 
@@ -277,10 +277,10 @@ def _chunks(heads, gate=None):
             return None, None
         z, rate, bias, scale = h[6:]
         return (rate, bias, gate[0]), (z, scale, gate[1])
-    opened = _together(_open(h[0], h[1], h[3], h[4], small(h)[0])
+    opened = together(_open(h[0], h[1], h[3], h[4], small(h)[0])
                        for h in heads)
     Ts = _inverses(tuple(c["L"] for c in opened))
-    return tuple(_together(
+    return tuple(together(
         _close(c, T, h[2], h[4], h[5], small(h)[1])
         for c, T, h in zip(opened, Ts, heads)))
 
@@ -291,7 +291,7 @@ def _head(ins, rows, j, h, kl, vl, S):
     behind them."""
     q_ref, k_ref, v_ref, g_ref, b_ref, *small = ins
     head = (q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-            g_ref[rows, kl], _pick(b_ref[h], j), S)
+            g_ref[rows, kl], pick(b_ref[h], j), S)
     if small:
         z_ref, rate_ref, bias_ref, scale_ref = small
         head += (z_ref[rows, vl], rate_ref[:, kl], bias_ref[:, kl],
@@ -309,14 +309,14 @@ def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
     n = _inputs(gate)
     ins, (o_ref, last_ref, s0_ref, s_ref) = refs[:n], refs[n:]
     i = pl.program_id(2)
-    lanes = _lanes(hb, dk, dv)
+    lanes = head_lanes(hb, dk, dv)
 
     @pl.when(i == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     def body(j):
-        rows = _rows(j)
+        rows = chunk_rows(j, C)
         for h in range(hb):
             s0_ref[h, j] = s_ref[h]
         outs = _chunks(tuple(_head(ins, rows, j, h, kl, vl, s_ref[h])
@@ -324,7 +324,7 @@ def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
         for h, (o, S) in enumerate(outs):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
-    _walk(nc, body)
+    walk(nc, body)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
@@ -337,7 +337,7 @@ def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
     ins, (s0_ref, do_ref, dlast_ref) = refs[:n], refs[n:n + 3]
     (dq_ref, dk_ref, dv_ref, dg_ref, db_ref, *dsmall), ds_ref = (
         refs[n + 3:-1], refs[-1])
-    lanes = _lanes(hb, dk, dv)
+    lanes = head_lanes(hb, dk, dv)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -347,7 +347,7 @@ def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
 
     def body(m):
         j = nc - 1 - m
-        rows = _rows(j)
+        rows = chunk_rows(j, C)
         _, pull = jax.vjp(functools.partial(_chunks, gate=gate), tuple(
             _head(ins, rows, j, h, kl, vl, s0_ref[h, j])
             for h, (kl, vl) in enumerate(lanes)))
@@ -360,14 +360,14 @@ def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
             dk_ref[rows, kl] = dk_.astype(dk_ref.dtype)
             dv_ref[rows, vl] = dv_.astype(dv_ref.dtype)
             dg_ref[rows, kl] = dg.astype(dg_ref.dtype)
-            _put(db_ref.at[h], j, dbeta)
+            put(db_ref.at[h], j, dbeta)
             ds_ref[h] = dS
             if rest:
                 dz_ref, *sums = dsmall
                 dz_ref[rows, vl] = rest[0].astype(dz_ref.dtype)
                 for ref, part in zip(sums, rest[1:]):
                     ref[:, kl] += part
-    _walk(nc, body)
+    walk(nc, body)
 
 
 def _plan(ops, gate, reverse):
@@ -432,7 +432,8 @@ def _fwd_call(*ops, gate, interpret):
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(*args)
 
 
@@ -464,7 +465,8 @@ def _bwd_call(*ops, gate, interpret):
                    jax.ShapeDtypeStruct(beta.shape, _F32)] + (
                        [] if gate is None else [like(v, dv)] + [sums] * 3),
         scratch_shapes=[pltpu.VMEM((dims["hb"], dk, dv), _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(*args, states, do, dlast)
 
 

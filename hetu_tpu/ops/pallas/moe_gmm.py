@@ -34,27 +34,20 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
+from .common import VMEM_LIMIT, WALK, fit, params
 
-#: scoped VMEM the kernels may use: a [256, 2048] row tile, a [2048, 1024]
-#: weight block and the f32 accumulator, double-buffered, are about 14 MiB;
-#: Mosaic's default scoped limit is 16 MiB of the v5e's 128
-VMEM_LIMIT = 64 * 2 ** 20
+# (scoped VMEM: a [256, 2048] row tile, a [2048, 1024] weight block and the
+# f32 accumulator, double-buffered, are about 14 MiB of ``VMEM_LIMIT``'s 64;
+# Mosaic's default scoped limit is 16 MiB of the v5e's 128)
 #: the widest dimension taken as one block where no multiple of 128 divides
 #: it: the default contraction block
 WHOLE_WIDTH = 2048
 
 
-def _fit(dim, want):
+def fit128(dim, want):
     """The largest multiple of 128 that divides ``dim`` and is at most
-    ``want`` (``dim`` itself when it is smaller than 128 or ``want``)."""
-    if dim <= want:
-        return dim
-    t = want - want % 128
-    while t >= 128:
-        if dim % t == 0:
-            return t
-        t -= 128
-    return dim
+    ``want`` (``dim`` itself when it is at most ``want`` or none does)."""
+    return dim if dim <= want else fit(dim, want, 128) or dim
 
 
 def unsupported(m, k, n, tm, dtype):
@@ -65,7 +58,7 @@ def unsupported(m, k, n, tm, dtype):
         return f"rows_not_tile_aligned:{m}%{tm}"
     if dispatch.mosaic():
         # a width that is no multiple of 128 has no 128-aligned divisor:
-        # ``_fit`` takes it whole, as one block the size of the array's
+        # ``fit128`` takes it whole, as one block the size of the array's
         # dimension, which Mosaic takes (Nemotron-H's experts are 1,856
         # wide, 14.5 x 128) so long as a block of it fits the scoped VMEM
         if any(d % 128 and d > WHOLE_WIDTH for d in (k, n)) or tm % 8:
@@ -76,15 +69,7 @@ def unsupported(m, k, n, tm, dtype):
     return None
 
 
-def _params(semantics):
-    from jax.experimental.pallas import tpu as pltpu
-    if dispatch.interpret():
-        return None
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=VMEM_LIMIT)
-
-
-def _live(i, n_used):
+def live(i, n_used):
     """Row tile ``i``, or the last live one for a skipped tile: its block
     index then repeats and Pallas fetches nothing new."""
     return jnp.minimum(i, n_used[0] - 1)
@@ -105,7 +90,7 @@ def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
     from jax.experimental.pallas import tpu as pltpu
     m, k = x.shape
     n = w.shape[1] if transpose_rhs else w.shape[2]
-    tk, tn = _fit(k, tk), _fit(n, tn)
+    tk, tn = fit128(k, tk), fit128(n, tn)
     tiles_k = k // tk
     contract = (((1,), (1,)), ((), ())) if transpose_rhs \
         else (((1,), (0,)), ((), ()))
@@ -130,11 +115,11 @@ def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
     if transpose_rhs:
         w_spec = pl.BlockSpec(
             (None, tn, tk),
-            lambda j, i, kk, te, nu: (te[_live(i, nu)], j, kk))
+            lambda j, i, kk, te, nu: (te[live(i, nu)], j, kk))
     else:
         w_spec = pl.BlockSpec(
             (None, tk, tn),
-            lambda j, i, kk, te, nu: (te[_live(i, nu)], kk, j))
+            lambda j, i, kk, te, nu: (te[live(i, nu)], kk, j))
     return pl.pallas_call(
         kernel, name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -142,13 +127,15 @@ def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
             grid=(n // tn, m // tm, tiles_k),
             in_specs=[
                 pl.BlockSpec((tm, tk),
-                             lambda j, i, kk, te, nu: (_live(i, nu), kk)),
+                             lambda j, i, kk, te, nu: (live(i, nu), kk)),
                 w_spec],
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda j, i, kk, te, nu: (i, j)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=_params(("parallel", "arbitrary", "arbitrary")),
+        compiler_params=params(
+            dispatch.interpret(), ("parallel", "arbitrary", "arbitrary"),
+            VMEM_LIMIT),
         interpret=dispatch.interpret(),
     )(tile_expert, n_used, x, w)
 
@@ -162,7 +149,7 @@ def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm, tk=2048, tn=512,
     from jax.experimental.pallas import tpu as pltpu
     m, k = x.shape
     n = dy.shape[1]
-    tk, tn = _fit(k, tk), _fit(n, tn)
+    tk, tn = fit128(k, tk), fit128(n, tn)
     tiles_m = m // tm
 
     def kernel(te, nu, x_ref, dy_ref, o_ref, acc):
@@ -193,14 +180,14 @@ def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm, tk=2048, tn=512,
             grid=(k // tk, n // tn, tiles_m),
             in_specs=[
                 pl.BlockSpec((tm, tk),
-                             lambda a, b, i, te, nu: (_live(i, nu), a)),
+                             lambda a, b, i, te, nu: (live(i, nu), a)),
                 pl.BlockSpec((tm, tn),
-                             lambda a, b, i, te, nu: (_live(i, nu), b))],
+                             lambda a, b, i, te, nu: (live(i, nu), b))],
             out_specs=pl.BlockSpec((None, tk, tn),
                                    lambda a, b, i, te, nu: (te[i], a, b)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((num_experts, k, n), x.dtype),
-        compiler_params=_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=params(dispatch.interpret(), WALK, VMEM_LIMIT),
         interpret=dispatch.interpret(),
     )(tile_expert, n_used, x, dy)
 

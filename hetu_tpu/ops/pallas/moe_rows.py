@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch
-from .moe_gmm import _fit, _live, _params
+from .common import VMEM_LIMIT, params
+from .moe_gmm import fit128, live
 
 
 def unsupported(tokens, hidden, tt, tm, dtype):
@@ -58,7 +59,7 @@ def rows_sum(rows, loc, tile_group, n_used, *, tokens, tt, th=2048):
     from jax.experimental.pallas import tpu as pltpu
     m, h = rows.shape
     tiles_m = tile_group.shape[0]
-    tm, th = m // tiles_m, _fit(h, th)
+    tm, th = m // tiles_m, fit128(h, th)
     full = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
 
     def kernel(tg, nu, loc_ref, rows_ref, o_ref, acc):
@@ -94,13 +95,14 @@ def rows_sum(rows, loc, tile_group, n_used, *, tokens, tt, th=2048):
             grid=(h // th, tiles_m),
             in_specs=[
                 pl.BlockSpec((1, tm),
-                             lambda j, i, tg, nu: (0, _live(i, nu))),
+                             lambda j, i, tg, nu: (0, live(i, nu))),
                 pl.BlockSpec((tm, th),
-                             lambda j, i, tg, nu: (_live(i, nu), j))],
+                             lambda j, i, tg, nu: (live(i, nu), j))],
             out_specs=pl.BlockSpec((tt, th),
                                    lambda j, i, tg, nu: (tg[i], j)),
             scratch_shapes=[pltpu.VMEM((tt, th), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((tokens, h), rows.dtype),
-        compiler_params=_params(("parallel", "arbitrary")),
+        compiler_params=params(dispatch.interpret(),
+                               ("parallel", "arbitrary"), VMEM_LIMIT),
         interpret=dispatch.interpret(),
     )(tile_group, n_used, loc, rows)

@@ -85,7 +85,8 @@ import jax.numpy as jnp
 
 from ... import telemetry
 from . import dispatch
-from .gated_delta import VMEM_LIMIT, _params, _walk    # the same loop
+from .common import (NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, params,
+                     walk)
 
 #: positions a chunk (``ops.ssd.CHUNK``; the kernels are written for it)
 L = 128
@@ -97,10 +98,9 @@ CHUNKS = 8
 #: read ONE ``B`` and ``C``) is walked as blocks of heads, a program each.
 HEADS = 8
 # (scoped VMEM: the backward program's x, dy, dx blocks and eight kept states,
-# double-buffered, are about 12 MiB of ``gated_delta.VMEM_LIMIT``'s 64)
+# double-buffered, are about 12 MiB of ``common.VMEM_LIMIT``'s 64)
 
 _F32 = jnp.float32
-_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
 def _dot(a, b, dims):
@@ -163,7 +163,7 @@ def _open(Bm, Cm, dt_r, a_r):
     cs_r = _running_sum(a_r)
     last = cs_r[:, L - 1:]
     return dict(dt_r=dt_r, cs_r=cs_r, cs_c=cs_r.T, last=last,
-                te_r=jnp.exp(last - cs_r), CB=_dot(Cm, Bm, _NT),
+                te_r=jnp.exp(last - cs_r), CB=_dot(Cm, Bm, NT),
                 BT=Bm.astype(_F32).T)
 
 
@@ -188,9 +188,9 @@ def _head_fwd(x, CB, CS, ST, BT, cs_c, cs_r, dt_r, te_r, fs_c, last):
     f32 [L, P], next state)``."""
     ct = x.dtype
     sc = (CB * _decays(cs_c, cs_r) * dt_r).astype(ct)
-    y = _dot(sc, x, _NN) + CS * fs_c
+    y = _dot(sc, x, NN) + CS * fs_c
     return y, ST * _through(last, x) + _dot(
-        (BT * (dt_r * te_r)).astype(ct), x, _NN)
+        (BT * (dt_r * te_r)).astype(ct), x, NN)
 
 
 @jax.jit
@@ -208,19 +208,19 @@ def _head_bwd(x, dy, CB, S0T, dST, BT, CT, cs_c, cs_r, dt_r, te_r, fs_r,
     ct = x.dtype
     Lm = _decays(cs_c, cs_r)
     sc = (CB * Lm * dt_r).astype(ct)
-    U = _dot(dy, x, _NT) * Lm                                    # [L, L]
+    U = _dot(dy, x, NT) * Lm                                     # [L, L]
     W = U * CB
-    Z = _dot(S0T.astype(ct), dy, _NT)                            # [N, L]
+    Z = _dot(S0T.astype(ct), dy, NT)                             # [N, L]
     dcs = (jnp.sum((W * dt_r).T, axis=0, keepdims=True)
            + jnp.sum(CT * Z, axis=0, keepdims=True) * fs_r)
     w_r = dt_r * te_r
     dSc = dST.astype(ct)
-    dx = _dot(sc, dy, _TN) + _dot((BT * w_r).astype(ct), dSc, _TN)
-    M = _dot(dSc, x, _NT)                                        # [N, L]
+    dx = _dot(sc, dy, TN) + _dot((BT * w_r).astype(ct), dSc, TN)
+    M = _dot(dSc, x, NT)                                         # [N, L]
     state = jnp.sum(BT * M, axis=0, keepdims=True) * te_r
     return (dx, U * dt_r, M * w_r, Z * fs_r,
             jnp.sum(W, axis=0, keepdims=True) + state, dcs,
-            dST * _through(last, x) + _dot((CT * fs_r).astype(ct), dy, _NN))
+            dST * _through(last, x) + _dot((CT * fs_r).astype(ct), dy, NN))
 
 
 @functools.partial(jax.jit, static_argnames="heads")
@@ -234,7 +234,7 @@ def _state_dot(dST, ST, *, heads):
     lane = jax.lax.broadcasted_iota(jnp.int32, (heads, rp), 1)
     lo = jax.lax.broadcasted_iota(jnp.int32, (heads, rp), 0) * (rp // heads)
     mine = (lane >= lo) & (lane < lo + rp // heads)
-    return _dot(jnp.where(mine, e, 0.0), jnp.ones((rp, L), _F32), _NN)
+    return _dot(jnp.where(mine, e, 0.0), jnp.ones((rp, L), _F32), NN)
 
 
 @jax.jit
@@ -246,14 +246,9 @@ def _bwd_close(Bm, Cm, BT, CT, dCB, dBT, dCT, dcs, ddt, end, dt_r):
     just after the chunk, ``<dS, S>`` of the state the chunk ends at."""
     ct = Bm.dtype
     dCBc = dCB.astype(ct)
-    dBT = dBT + _dot(CT.astype(ct), dCBc, _NN)
-    dCT = dCT + _dot(BT.astype(ct), dCBc, _NT)
+    dBT = dBT + _dot(CT.astype(ct), dCBc, NN)
+    dCT = dCT + _dot(BT.astype(ct), dCBc, NT)
     return dBT.T, dCT.T, _sum_from(dcs - dt_r * ddt) + end
-
-
-def _rows(j):
-    import jax.experimental.pallas as pl
-    return pl.ds(pl.multiple_of(j * L, L), L)
 
 
 def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
@@ -267,13 +262,13 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     def body(j):
-        rows = _rows(j)
+        rows = chunk_rows(j, L)
         ST = s_ref[...]
         s0_ref[j] = ST
         Cm = c_ref[rows, :]
         c = _open(b_ref[rows, :], Cm, dt_ref[j], a_ref[j])
         c["fs_c"] = jnp.exp(c["cs_c"])     # the decay from the chunk's start
-        CS = _dot(Cm, ST.astype(Cm.dtype), _NN)       # [L, R P]: every head's
+        CS = _dot(Cm, ST.astype(Cm.dtype), NN)        # [L, R P]: every head's
         for h, at in enumerate(lanes):
             y, S = _head_fwd(
                 x_ref[rows, at], c["CB"], CS[:, at], ST[:, at], c["BT"],
@@ -281,7 +276,7 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
                                  "last")))
             y_ref[rows, at] = y.astype(y_ref.dtype)
             s_ref[:, at] = S
-    _walk(nc, body)
+    walk(nc, body)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
@@ -301,7 +296,7 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
 
     def body(n):
         j = nc - 1 - n
-        rows = _rows(j)
+        rows = chunk_rows(j, L)
         Bm, Cm, S0T, dST = b_ref[rows, :], c_ref[rows, :], s0_ref[j], \
             ds_ref[...]
         c = _open(Bm, Cm, dt_ref[j], a_ref[j])
@@ -329,7 +324,7 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
         dc_ref[rows, :] = dC.astype(dc_ref.dtype)
         ddt_ref[j] = ddt
         da_ref[j] = da
-    _walk(nc, body)
+    walk(nc, body)
 
 
 def _plan(x, Bm, dt, reverse, wide):
@@ -375,7 +370,8 @@ def _fwd_call(x, dt, a, Bm, Cm, *, interpret, wide):
                    jax.ShapeDtypeStruct((b, G, N, rp), _F32),
                    jax.ShapeDtypeStruct((b, G, blocks, nc, N, rp), _F32)],
         scratch_shapes=[pltpu.VMEM((N, rp), _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(x, Bm, Cm, dt, a)
 
 
@@ -402,7 +398,8 @@ def _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast, *, interpret,
                    jax.ShapeDtypeStruct(dt.shape, _F32)],
         scratch_shapes=[pltpu.VMEM(states.shape[-2:], _F32),
                         pltpu.VMEM((dims["heads"], L), _F32)],
-        compiler_params=_params(interpret), interpret=interpret,
+        compiler_params=params(interpret, WALK, VMEM_LIMIT),
+        interpret=interpret,
     )(x, Bm, Cm, dt, a, states, last, dy, dlast)
 
 

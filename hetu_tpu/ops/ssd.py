@@ -61,14 +61,13 @@ rebuilds everything else), where it can read that they apply: ``P`` a
 multiple of 64 with some block of up to eight of a group's ``H / G`` heads
 filling whole 128-lane tiles, ``N`` a multiple of 128, ``chunk`` 128, ``x``,
 ``B`` and ``C`` all bf16 or all f32, a program's blocks within half the
-kernels' VMEM; any ``b``, ``T``, ``H`` and ``G``.  Each call counts its choice at trace time in
-``hetu_kernel_choice_total{kernel="ssd", impl, reason}``: ``pallas``,
-or ``jnp`` with ``head_dim_not_64_aligned``, ``state_not_128_aligned``,
-``chunk!=128``, ``dtype:<name>``, ``dtype:mixed`` or ``blocks_over_vmem``.  A mesh is the one thing
-the function cannot see (a ``pallas_call`` does not partition under GSPMD):
-the scan node reads it, calls ``chunk_ssd_jnp`` itself and counts ``mesh``.
-On any other platform there is no Mosaic and no choice: nothing is counted
-and ``chunk_ssd_jnp`` runs, bit for bit what this function was before it had
+kernels' VMEM; any ``b``, ``T``, ``H`` and ``G``.  Each call counts its choice
+at trace time in ``hetu_kernel_choice_total{kernel="ssd", impl, reason}``:
+``pallas``, or ``jnp`` with ``head_dim_not_64_aligned``,
+``state_not_128_aligned``, ``chunk!=128``, ``dtype:<name>``, ``dtype:mixed`` or
+``blocks_over_vmem``.  What a mesh (which the scan node sees, ``ops/base.py
+KernelOp``) and a platform without Mosaic mean is ``dispatch.take``'s rule;
+``chunk_ssd_jnp`` then runs, bit for bit what this function was before it had
 kernels.  The kernels themselves run anywhere when called directly
 (interpret mode on the CPU): ``tests/test_ssd_kernel.py``.
 """
@@ -120,12 +119,10 @@ def segsum(a):
 
 
 def chunk_ssd(x, dt, A, B, C, chunk=CHUNK):
-    """The chunked form; see the module's docstring.  On a TPU the Pallas
-    kernel pair where its rule takes the operands, else (and on any other
-    platform, where there is no choice to record) the ``jax.numpy`` form."""
+    """The chunked form; see the module's docstring: the Pallas kernel pair
+    where ``dispatch.take`` and its rule allow, else the ``jax.numpy`` form."""
     from .pallas import dispatch, ssd as kernels
-    if dispatch.mosaic() and dispatch.record(
-            "ssd", kernels.unsupported(x, B, C, chunk)):
+    if dispatch.take("ssd", None, kernels.unsupported(x, B, C, chunk)):
         return kernels.ssd(x, dt, A, B, C)
     return chunk_ssd_jnp(x, dt, A, B, C, chunk)
 

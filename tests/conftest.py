@@ -139,6 +139,41 @@ def lowered_for_tpu(monkeypatch, build, debug_info=False):
         jax.clear_caches()
 
 
+def without_locations(text):
+    """A program's text with nothing left that a moved source line moves.
+    Of a lowered program (``as_text()``, with or without ``debug_info``): the
+    ``loc(..)`` of every operation and the ``#loc`` table, and inside every
+    Mosaic payload (a ``tpu_custom_call``'s ``body``: base64 of MLIR bytecode
+    that carries each operation's Python call stack, file names and line
+    numbers) the same, by putting the kernel's assembly printed without debug
+    information in the payload's place.  Of a compiled module's text: every
+    instruction's ``metadata={..}`` and the file and stack-frame tables it
+    points into.  Two trees give equal texts exactly when they give the same
+    program."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True     # the serialised ``stable_mosaic``
+
+    def kernel(match):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(2)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return match.group(1) + asm.replace("\n", " ") + match.group(3)
+    text = re.sub(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)', kernel, text)
+    text = re.sub(r"^#loc\d*\b.*\n", "", text, flags=re.M)
+    text = re.sub(r" loc\((?:[^()]|\((?:[^()]|\([^()]*\))*\))*\)", "", text)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    head, _, rest = text.partition("\nFileNames")
+    if rest:
+        rest = rest[rest.index("\n\n", rest.index("StackFrames")):]
+    return head + rest
+
+
 def conv_calls(text):
     """How often a lowered step calls the causal convolution's two kernel
     entries (``ops/pallas/causal_conv.py``): ``(forward, backward)``."""

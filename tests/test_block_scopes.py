@@ -144,16 +144,12 @@ CELLS = {
 
 def stripped(text, renamed=False):
     """A compiled module's text without what ``named_scope`` and the source
-    lines write: instruction metadata and the tables it points into.
-    ``renamed``: instructions numbered in their order too, where XLA names
-    them after their ``op_name`` (under a mesh ``jvp_jit_take_along_axis``
-    is ``jit_take_along_axis`` once a block's name stands inside the
-    ``jvp``)."""
-    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
-    head, _, rest = text.partition("\nFileNames")
-    if rest:
-        rest = rest[rest.index("\n\n", rest.index("StackFrames")):]
-    text = head + rest
+    lines write (``conftest.without_locations``).  ``renamed``: instructions
+    numbered in their order too, where XLA names them after their ``op_name``
+    (under a mesh ``jvp_jit_take_along_axis`` is ``jit_take_along_axis`` once
+    a block's name stands inside the ``jvp``)."""
+    from conftest import without_locations
+    text = without_locations(text)
     if renamed:
         names = {}
         text = re.sub(r"%[\w.-]+", lambda m: names.setdefault(
@@ -200,3 +196,38 @@ def test_the_compiled_step_is_the_step_without_scopes(family, monkeypatch):
     assert not re.search(r"hetu_(optim|norm|attn|head|loss|embed)", without)
     renamed = family == "dp4"
     assert stripped(with_scopes, renamed) == stripped(without, renamed)
+
+
+MOVED = """
+import jax
+from jax.experimental import pallas as pl
+
+def double(x_ref, o_ref):
+    o_ref[...] = x_ref[...] * 2.0
+
+def program(x):
+    return pl.pallas_call(double, out_shape=jax.ShapeDtypeStruct(
+        x.shape, x.dtype))(x) + 1.0
+"""
+
+
+@pytest.mark.parametrize("debug_info", [False, True])
+def test_a_moved_line_moves_nothing_once_locations_are_stripped(debug_info):
+    """What a comparison of two trees' step programs stands on: the same
+    program written seven lines lower lowers to another text (a Mosaic
+    payload carries its call stacks' line numbers; with ``debug_info`` so
+    does every operation) and to the same text without its locations, while
+    another program stays another."""
+    from conftest import without_locations
+
+    def lowered(source, pad):
+        space = {}
+        exec(compile("\n" * pad + source, "moved.py", "exec"), space)
+        return jax.jit(space["program"]).trace(
+            jax.ShapeDtypeStruct((8, 128), "float32")).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=debug_info)
+    here, lower = lowered(MOVED, 0), lowered(MOVED, 7)
+    assert "tpu_custom_call" in here and here != lower
+    assert without_locations(here) == without_locations(lower)
+    other = lowered(MOVED.replace("* 2.0", "* 3.0"), 0)
+    assert without_locations(other) != without_locations(here)

@@ -8,7 +8,6 @@ window read in place out of a wider array; batch 2; the rule by which
 for a described v5e at the cells' shapes: ``tests/test_flash_attention.py``,
 where the other such compiles are.)"""
 
-import types
 
 import numpy as np
 import pytest
@@ -17,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu.ops import causal_conv as op
-from hetu_tpu.ops.causal_conv import ConvOp, causal_conv, causal_conv_jnp
+from hetu_tpu.ops.causal_conv import causal_conv, causal_conv_jnp
 from hetu_tpu.ops.pallas import causal_conv as kernels, dispatch
 
 
@@ -256,55 +255,6 @@ def test_rule_reads_its_operands_as_on_tpu(conv_choices, monkeypatch, why, x,
         assert len(taken) == 1 and conv_choices() == {("pallas", ""): 1}
     else:
         assert not taken and conv_choices() == {("jnp", why): 1}
-
-
-def conv_nodes():
-    """The convolution's node of each of the two mixers."""
-    import hetu_tpu as ht
-    from hetu_tpu.layers.gated_delta_net import GatedDeltaNet
-    from hetu_tpu.layers.mamba2 import Mamba2
-    x = ht.placeholder_op("cck_mesh_x", (1, 64, 64))
-    ssm = Mamba2(64, 8, 16, 1, 64, name="cck_mesh_ssm")(x)
-    gdn = GatedDeltaNet(64, 2, 4, 16, 16, name="cck_mesh_gdn")(x)
-    nodes = {"hetu_ssm_conv": ssm.inputs[0].inputs[0],
-             "hetu_gdn_conv": gdn.inputs[0].inputs[0]}
-    for scope, node in nodes.items():
-        assert isinstance(node, ConvOp) and node.scope == scope
-    return nodes
-
-
-@pytest.mark.parametrize("scope,args,window", [
-    ("hetu_ssm_conv", [(1, 64, 392), (4, 256), (256,)], (128, 384)),
-    ("hetu_gdn_conv", [(1, 64, 128), (4, 128)], None),
-])
-@pytest.mark.parametrize("platform,mesh,want", [
-    ("tpu", None, {("pallas", ""): 1}),
-    ("tpu", "a mesh", {("jnp", "mesh"): 1}),
-    ("cpu", "a mesh", {}),
-    ("cpu", None, {}),
-])
-def test_conv_node_reads_the_mesh(conv_choices, monkeypatch, platform, mesh,
-                                  want, scope, args, window):
-    """The one thing the function cannot see is the node's: under a mesh the
-    mixers' convolution node calls the ``jax.numpy`` form itself (a
-    ``pallas_call`` does not partition under GSPMD) and records ``mesh``
-    where there was a kernel to take.  One node class for both mixers; the
-    Mamba-2 layer's reads its window out of the projection's output."""
-    monkeypatch.setattr(dispatch, "platform", lambda: platform)
-    called = []
-    monkeypatch.setattr(kernels, "conv", lambda *a: called.append("pallas") or
-                        causal_conv_jnp(*a))
-    plain = causal_conv_jnp
-    monkeypatch.setattr(op, "causal_conv_jnp",
-                        lambda *a, **k: called.append("jnp") or plain(*a, **k))
-    node = conv_nodes()[scope]
-    assert node.attrs == {"window": window}
-    ctx = types.SimpleNamespace(mesh=mesh)
-    out = jax.eval_shape(lambda *a: node._compute(list(a), ctx),
-                         *(sds(s) for s in args))
-    assert out.shape == args[0][:2] + args[1][1:]
-    assert called == (["pallas"] if want == {("pallas", ""): 1} else ["jnp"])
-    assert conv_choices() == want
 
 
 # -- the layers through the kernels -----------------------------------------------

@@ -191,7 +191,7 @@ def test_keep_threshold_has_one_definition():
     """Attention's in-kernel dropout and the mask kernel compare the same
     words with the same threshold, through one helper."""
     from hetu_tpu.ops.pallas import dropout as D, flash_attention as F
-    assert D._tile_keep is F._tile_keep
+    assert D.tile_keep is F.tile_keep
     assert not hasattr(D, "_keep_threshold")
     assert int(F._keep_threshold(0.9)) == int(0.9 * 2 ** 32)
     assert int(F._keep_threshold(1.0)) == 2 ** 32 - 1

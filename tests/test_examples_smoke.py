@@ -65,6 +65,21 @@ def test_ps_scale_bench_smoke():
     assert out["in_graph_feasible_at_largest"] is True  # quick sizes fit
 
 
+@pytest.mark.parametrize("model, layers", [
+    ("olmoe-1b-7b", "1"),               # softmax router: loads, no bias
+    ("nemotron-3-nano-30b-a3b", "2"),   # sigmoid router: loads and biases
+])
+def test_train_llama_example_fetches_an_moe_models_loads(model, layers):
+    """The example's fetch list (loss, update, a load a layer and, for the
+    families that have ``router_biases()``, a bias a layer) builds and runs
+    for a router with a selection bias and for one without."""
+    r = _run(["examples/nlp/train_llama.py", "--model", model, "--layers",
+              layers, "--hidden", "64", "--intermediate", "32", "--vocab",
+              "128", "--seq-len", "64", "--batch-size", "1", "--steps", "2"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "step    1  loss" in r.stdout
+
+
 def test_ctr_sparse_opt_example_smoke():
     """train_ctr --sparse-opt (lazy in-graph table updates) runs."""
     r = _run(["examples/ctr/train_ctr.py", "--model", "wdl", "--steps",

@@ -397,7 +397,7 @@ def test_dropout_replay_matches_extracted_mask(rng):
 
     def mask_kernel(seed_ref, out_ref):
         bh, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        keep = F._tile_keep((bq, bk), seed_ref,
+        keep = F.tile_keep((bq, bk), seed_ref,
                             F._tile_index(bh, qi, j, nq, nk), keep_prob)
         out_ref[0] = keep.astype(jnp.float32)
 
@@ -934,7 +934,7 @@ def test_mamba2_scan_node_compiles_for_v5e(v5e, as_on_tpu):
 
     def loss(xbc, dt, dt_bias, a_log, d_skip):
         return jnp.sum(_scan(xbc, dt, dt_bias, a_log, d_skip,
-                             scan=chunk_ssd_jnp, **dims
+                             rule=chunk_ssd_jnp, **dims
                              ).astype(jnp.float32) ** 2)
 
     vec = sds((64,), jnp.float32)

@@ -7,7 +7,6 @@ memory, where a bf16 state is seen and the kernels are not; the rule by which
 them against the same layer through the ``jax.numpy`` form; and the layer's
 step compiled for a described v5e."""
 
-import types
 
 import numpy as np
 import pytest
@@ -19,7 +18,7 @@ from hetu_tpu.ops import gated_delta
 from hetu_tpu.ops.gated_delta import (chunk_gated_delta_rule,
                                       chunk_gated_delta_rule_jnp,
                                       recurrent_gated_delta_rule)
-from hetu_tpu.ops.pallas import dispatch, gated_delta as kernels
+from hetu_tpu.ops.pallas import common, dispatch, gated_delta as kernels
 
 D = 128                       # the published head size, keys and values
 
@@ -111,7 +110,7 @@ def test_bf16_operands_keep_every_pass_of_the_state_products(rate, seed,
     x = delta_inputs(256, 1, 2, jnp.bfloat16, seed=seed, rate=rate)
     want = jax.jit(chunk_gated_delta_rule_jnp)(*x)[1]
     assert l2_gap(kernels.gated_delta_rule(*x)[1], want) < 1e-6
-    monkeypatch.setattr(kernels, "PARTS", 2)
+    monkeypatch.setattr(common, "PARTS", 2)
     jax.clear_caches()
     try:
         assert l2_gap(kernels.gated_delta_rule(*x)[1], want) > 2e-6
@@ -124,7 +123,7 @@ def test_bf16_operands_keep_every_pass_of_the_state_products(rate, seed,
     (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.float32),
     (jnp.float32, jnp.bfloat16)])
 def test_an_f32_product_is_six_passes_and_none_less(left, right, monkeypatch):
-    """``_dot32``, which every product with the state, ``T``, ``W`` and ``V'``
+    """``common.dot32``, which every product with the state, ``T``, ``W`` and ``V'``
     goes through, forward and backward: an f32 operand enters with all of its
     mantissa and a bf16 one as it is; one part fewer is seen."""
     r = np.random.default_rng(11)
@@ -133,11 +132,11 @@ def test_an_f32_product_is_six_passes_and_none_less(left, right, monkeypatch):
     want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
 
     def gap():
-        got = kernels._dot32(a, b, kernels._NN)
+        got = common.dot32(a, b, common.NN)
         assert got.dtype == jnp.float32
         return l2_gap(got, want)
     assert gap() < 1e-7
-    monkeypatch.setattr(kernels, "PARTS", 2)
+    monkeypatch.setattr(common, "PARTS", 2)
     assert gap() > 1e-6
 
 
@@ -221,46 +220,6 @@ def test_rule_reads_its_operands_as_on_tpu(gdn_choices, monkeypatch, why,
         assert len(taken) == 1 and gdn_choices() == {("pallas", ""): 1}
     else:
         assert not taken and gdn_choices() == {("jnp", why): 1}
-
-
-def scan_node():
-    import hetu_tpu as ht
-    from hetu_tpu.layers.gated_delta_net import GatedDeltaNet
-    layer = GatedDeltaNet(256, 1, 2, D, D, name="gdk_mesh")
-    x = ht.placeholder_op("gdk_mesh_x", (1, 64, 256))
-    node = layer(x).inputs[0]
-    assert node.scope == "hetu_gdn_scan"
-    return node
-
-
-@pytest.mark.parametrize("platform,mesh,want", [
-    ("tpu", None, {("pallas", ""): 1}),
-    ("tpu", "a mesh", {("jnp", "mesh"): 1}),
-    ("cpu", "a mesh", {}),
-])
-def test_scan_node_reads_the_mesh(gdn_choices, monkeypatch, platform, mesh,
-                                  want):
-    """The one thing the function cannot see is the node's: under a mesh the
-    ``hetu_gdn_scan`` node calls the ``jax.numpy`` form itself (a
-    ``pallas_call`` does not partition under GSPMD) and records ``mesh``
-    where there was a kernel to take."""
-    monkeypatch.setattr(dispatch, "platform", lambda: platform)
-    called = []
-    monkeypatch.setattr(kernels, "gated_delta_rule",
-                        lambda *a: called.append("pallas") or
-                        chunk_gated_delta_rule_jnp(*a))
-    plain = chunk_gated_delta_rule_jnp
-    monkeypatch.setattr(gated_delta, "chunk_gated_delta_rule_jnp",
-                        lambda *a, **k: called.append("jnp") or plain(*a, **k))
-    node = scan_node()
-    ctx = types.SimpleNamespace(mesh=mesh)
-    sds = jax.ShapeDtypeStruct
-    jax.eval_shape(lambda *a: node._compute(list(a), ctx),
-                   sds((1, 64, 2 * D + 2 * D), jnp.bfloat16),
-                   sds((1, 64, 4), jnp.bfloat16), sds((2,), jnp.float32),
-                   sds((2,), jnp.float32))
-    assert called == (["pallas"] if want == {("pallas", ""): 1} else ["jnp"])
-    assert gdn_choices() == want
 
 
 # -- the layer through the kernels ---------------------------------------------

@@ -301,47 +301,28 @@ def out_nodes(lanes):
     return nodes
 
 
-@pytest.mark.parametrize("scope,args", [
+@pytest.mark.parametrize("scope,args,window,first", [
     # y, [z | xBC | dt], the scale, the output weight
-    ("hetu_ssm_out", [(1, 64, 256), (1, 64, 648), (256,), (256, 64)]),
-    ("hetu_gdn_out", [(1, 64, 512), (1, 64, 1536), (128,), (512, 64)]),
+    ("hetu_ssm_out", [(1, 64, 256), (1, 64, 648), (256,), (256, 64)],
+     Window(0, 256, 256), True),
+    ("hetu_gdn_out", [(1, 64, 512), (1, 64, 1536), (128,), (512, 64)],
+     Window(512, 256, 768), False),
 ])
-@pytest.mark.parametrize("platform,mesh,want", [
-    ("tpu", None, {("pallas", ""): 1}),
-    ("tpu", "a mesh", {("jnp", "mesh"): 1}),
-    ("cpu", "a mesh", {}),
-    ("cpu", None, {}),
-])
-def test_out_node_reads_the_mesh_and_the_platform(norm_choices, monkeypatch,
-                                                  platform, mesh, want, scope,
-                                                  args):
-    """One node class for both mixers.  On a TPU it takes the kernels and
-    counts ``pallas``; under a mesh it calls the layer's ``jax.numpy`` form (a
-    ``pallas_call`` does not partition under GSPMD) and counts ``mesh``; on
-    any other platform there is no choice and nothing is counted."""
-    monkeypatch.setattr(dispatch, "platform", lambda: platform)
+def test_out_node_hands_the_kernels_where_z_lies(monkeypatch, scope, args,
+                                                 window, first):
+    """One node class for both mixers: what differs is handed in as data.
+    (What the node takes on which platform and under a mesh:
+    ``tests/test_kernel_dispatch.py``.)"""
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
     called = []
     monkeypatch.setattr(kernels, "gated_norm", lambda o, *a, **k:
-                        called.append(("pallas", k)) or o)
+                        called.append(k) or o)
     node = out_nodes(128)[scope]
-    plain = node.fn
-    node.fn = lambda *a, **k: called.append(("jnp", k)) or plain(*a, **k)
-    ctx = types.SimpleNamespace(mesh=mesh)
-    out = jax.eval_shape(lambda *a: node._compute(list(a), ctx),
-                         *(sds(s) for s in args))
+    out = jax.eval_shape(lambda *a: node._compute(
+        list(a), types.SimpleNamespace(mesh=None)), *(sds(s) for s in args))
     assert out.shape == (1, 64, 64)
-    (impl, kw), = called
-    assert impl == ("pallas" if want == {("pallas", ""): 1} else "jnp")
-    if scope == "hetu_gdn_out":
-        window, width, first = Window(512, 256, 768), 128, False
-    else:
-        window, width, first = Window(0, 256, 256), 128, True
-    if impl == "pallas":
-        assert kw == dict(window=window, width=width, gate_first=first,
-                          eps=node.attrs["eps"])
-    else:
-        assert kw == node.attrs
-    assert norm_choices() == want
+    assert called == [dict(window=window, width=128, gate_first=first,
+                           eps=node.attrs["eps"])]
 
 
 @pytest.mark.parametrize("scope,args,why", [
@@ -372,7 +353,7 @@ def layer_loss_and_grads(kind, through, monkeypatch):
     taken = []
     if through:
         monkeypatch.setattr(op, "dispatch", types.SimpleNamespace(
-            mosaic=lambda: True, record=lambda kernel, why:
+            take=lambda kernel, mesh, why:
             taken.append((kernel, why)) or why is None))
     name = f"gn_{kind}_{int(through)}"
     if kind == "ssm":           # d = 256 in two groups, z | xBC | dt = 648

@@ -9,7 +9,6 @@ layer through the ``jax.numpy`` form; and the layer's train step compiled for
 a described v5e; and a group of more heads than a program holds (Granite
 4.0-H: 64 heads on one ``B`` and ``C``) cut into blocks of heads."""
 
-import types
 
 import numpy as np
 import pytest
@@ -277,45 +276,6 @@ def test_rule_reads_its_operands_as_on_tpu(ssd_choices, monkeypatch, why, kw,
         assert len(taken) == 1 and ssd_choices() == {("pallas", ""): 1}
     else:
         assert not taken and ssd_choices() == {("jnp", why): 1}
-
-
-def scan_node():
-    import hetu_tpu as ht
-    from hetu_tpu.layers.mamba2 import Mamba2
-    layer = Mamba2(256, 4, P, 1, N, name="ssk_mesh")
-    x = ht.placeholder_op("ssk_mesh_x", (1, 128, 256))
-    node = layer(x).inputs[0]
-    assert node.scope == "hetu_ssm_scan"
-    return node
-
-
-@pytest.mark.parametrize("platform,mesh,want", [
-    ("tpu", None, {("pallas", ""): 1}),
-    ("tpu", "a mesh", {("jnp", "mesh"): 1}),
-    ("cpu", "a mesh", {}),
-])
-def test_scan_node_reads_the_mesh(ssd_choices, monkeypatch, platform, mesh,
-                                  want):
-    """The one thing the function cannot see is the node's: under a mesh the
-    ``hetu_ssm_scan`` node calls the ``jax.numpy`` form itself (a
-    ``pallas_call`` does not partition under GSPMD) and records ``mesh``
-    where there was a kernel to take."""
-    monkeypatch.setattr(dispatch, "platform", lambda: platform)
-    called = []
-    monkeypatch.setattr(kernels, "ssd", lambda *a: called.append("pallas") or
-                        chunk_ssd_jnp(*a))
-    plain = chunk_ssd_jnp
-    monkeypatch.setattr(ssd, "chunk_ssd_jnp",
-                        lambda *a, **k: called.append("jnp") or plain(*a, **k))
-    node = scan_node()
-    ctx = types.SimpleNamespace(mesh=mesh)
-    sds = jax.ShapeDtypeStruct
-    jax.eval_shape(lambda *a: node._compute(list(a), ctx),
-                   sds((1, 128, 4 * P + 2 * N), jnp.bfloat16),
-                   sds((1, 128, 4), jnp.bfloat16), sds((4,), jnp.float32),
-                   sds((4,), jnp.float32), sds((4,), jnp.float32))
-    assert called == (["pallas"] if want == {("pallas", ""): 1} else ["jnp"])
-    assert ssd_choices() == want
 
 
 # -- the layer through the kernels ---------------------------------------------
