@@ -139,6 +139,7 @@ class Program:
                 "flash_elements": batch * heads * self.seq * hd,
                 "flash_rows": batch * heads, "head_dim": hd,
                 "attention_layers": c["num_hidden_layers"],
+                "causal": False,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.ce_rows() // dp}
 

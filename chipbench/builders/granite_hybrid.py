@@ -13,8 +13,8 @@ there are one pipeline stage, ``vocab_size`` the slice, all listed in
 optimizer, the compute type, what is recomputed and the chunk the scan
 runs at), this builder, ``reference/granite_hybrid.py`` (the plain
 reference, given the same slice), ``flops_granitehybrid.py`` (operations and
-bytes) and the readers ``metrics/*.granite.py`` with ``metrics/_scopes.py``
-and ``metrics/_blocks.py``.
+bytes) and the readers ``metrics/*.granite_hybrid.py`` with
+``metrics/_scopes.py`` and ``metrics/_blocks.py``.
 """
 
 from __future__ import annotations
@@ -234,6 +234,7 @@ class Program(LlamaProgram):
                 "flash_elements": self.batch * heads * self.seq * hd,
                 "flash_rows": self.batch * heads, "head_dim": hd,
                 "attention_layers": self.model.attention_layers,
+                "causal": True,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq}
 

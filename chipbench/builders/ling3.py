@@ -13,7 +13,7 @@ the ``deployment`` group holds the published counts; ``job`` the optimizer
 and what is recomputed), this builder, ``reference/ling3.py`` (the plain
 reference, given the same held experts and the same slice),
 ``flops_ling3.py`` (operations and bytes) and the readers
-``metrics/*.ling.py``, ``metrics/kda_*.py``.
+``metrics/*.ling3.py``, ``metrics/kda_*.py``.
 """
 
 from __future__ import annotations
@@ -278,6 +278,7 @@ class Program(Qwen3NextProgram):
                 "score_dim": c["qk_nope_head_dim"] + c["qk_rope_head_dim"],
                 "attention_layers": (self.model.attention_layers
                                      * self.forward_passes),
+                "causal": True,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq,
                 "moe_pairs": self.tokens_per_step * c["num_experts_per_tok"]}

@@ -184,6 +184,7 @@ class Program:
                 "flash_elements": self.batch * heads * self.seq * hd,
                 "flash_rows": self.batch * heads, "head_dim": hd,
                 "attention_layers": c["num_hidden_layers"],
+                "causal": True,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq,
                 "moe_pairs": self.tokens_per_step * c["num_experts_per_tok"]}

@@ -18,8 +18,8 @@ the loss weight, the bias's update rate, what is
 recomputed), this builder,
 ``reference/nemotron_h.py`` (the plain reference, given the same held experts
 and the same slice), ``flops_nemotronh.py`` (operations and bytes) and the
-readers ``metrics/*.nemotronh.py``, ``metrics/ssm_block_device_ms_per_step.py``
-and ``metrics/ssd_scan_roofline.py`` with ``metrics/_scopes.py``.
+readers ``metrics/*.nemotron_h.py`` and
+``metrics/ssm_block_device_ms_per_step.py`` with ``metrics/_scopes.py``.
 """
 
 from __future__ import annotations
@@ -274,6 +274,7 @@ class Program(LlamaProgram):
                 "flash_elements": self.batch * heads * self.seq * hd,
                 "flash_rows": self.batch * heads, "head_dim": hd,
                 "attention_layers": self.model.attention_layers,
+                "causal": True,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq,
                 "moe_pairs": self.tokens_per_step * c["num_experts_per_tok"]}

@@ -15,7 +15,7 @@ is shared and which experts this one holds; ``job`` the optimizer, the loss
 weight, what is recomputed),
 this builder, ``reference/qwen3_next.py`` (the plain reference, given the
 same held experts and the same slice), ``flops_qwen3next.py`` (operations and
-bytes) and the readers ``metrics/*.qwen3next.py``, ``metrics/gdn_*.py``,
+bytes) and the readers ``metrics/*.qwen3_next.py``, ``metrics/gdn_*.py``,
 ``metrics/moe_held_pair_share.py`` with ``metrics/_scopes.py``.
 """
 
@@ -241,6 +241,7 @@ class Program(LlamaProgram):
                 "flash_elements": self.batch * heads * self.seq * hd,
                 "flash_rows": self.batch * heads, "head_dim": hd,
                 "attention_layers": layers,
+                "causal": True,
                 "compute_dtype": c["job"]["compute_dtype"],
                 "ce_rows": self.batch * self.seq,
                 "moe_pairs": self.tokens_per_step * c["num_experts_per_tok"]}

@@ -59,15 +59,17 @@ def roofline_share(ctx, names, call):
     return 100.0 * least / measured
 
 
-def flash_roofline(ctx, causal=False):
+def flash_roofline(ctx):
     """Flash attention's least possible time over the measured device time
     of its events, forward and backward pass together, in percent.  A
     pass's time is that of every event whose key holds the pass's name
     (``flops.FLASH_PASSES``), however many kernels carry the pass out; a
     training step runs each pass once for every forward call.  Operations
     and bytes are the algorithm's, from the local shard's shapes; under a
-    ``causal`` mask it needs half the products (keys after the query
-    contribute nothing) and reads and writes the tensors whole."""
+    causal mask, which the program states (``expected_kernel_shapes()``'s
+    ``causal``, which every builder has to state), it needs half the products
+    (keys after the query contribute nothing) and reads and writes the
+    tensors whole."""
     t = ctx["trace"]
     if t is None:
         return None
@@ -79,6 +81,7 @@ def flash_roofline(ctx, causal=False):
     if not all(found.values()):
         return None
     want = ctx["program"].expected_kernel_shapes()
+    causal = want["causal"]
     calls = len(found["forward"])
     least = measured = 0.0
     limits = {}

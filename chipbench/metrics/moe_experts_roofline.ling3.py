@@ -6,4 +6,4 @@ computed here, against the held experts' weights).  The measured time of the
 layer too: they earn nothing."""
 from chipbench.run import reader
 
-read = reader("moe_experts_roofline.qwen3next")
+read = reader("moe_experts_roofline", "qwen3_next")

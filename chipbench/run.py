@@ -6,8 +6,8 @@ this machine, and print the contract's one JSON line last.
 Nothing here knows a cell, a configuration or a traffic mix by name: the
 cell names its configuration and traffic, the configuration file names its
 builder, the traffic file its kind, and every metric is a reader file under
-``chipbench/metrics/`` found by the metric's name.  Lines before the last
-are for people: each starts with ``chipbench:``.
+``chipbench/metrics/`` found by the metric's quantity and the builder's name.
+Lines before the last are for people: each starts with ``chipbench:``.
 """
 
 from __future__ import annotations
@@ -72,19 +72,44 @@ def metrics_of(bench, group, cell):
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def reader(name):
-    """``read(ctx)`` of ``chipbench/metrics/<name>.py``.  A name with a
-    suffix after a dot (``device_idle_share.dp4``: one quantity in cells
-    whose end-to-end metrics differ) falls back to the file of the name
-    before the dot where it has none of its own."""
-    path = os.path.join(HERE, "metrics", name + ".py")
-    if not os.path.exists(path):
-        path = os.path.join(HERE, "metrics", name.split(".")[0] + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench.metrics." + name.replace(".", "__"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+def reader_path(name, builder=None):
+    """The file that reads the metric ``name`` for a configuration whose
+    ``builder`` is the family named, or None.  The quantity is the name
+    before the first dot: what follows (``.dp4``) only tells entries of one
+    quantity apart that move different end-to-end metrics, or that a test
+    still pins one to a cell, and finds no file.
+    ``metrics/<quantity>.<builder>.py`` where the family has arithmetic of
+    its own, else ``metrics/<quantity>.py``; a quantity that has only
+    families' files (``mfu``) has nothing for a family without one."""
+    quantity = name.split(".")[0]
+    for stem in ([f"{quantity}.{builder}"] if builder else []) + [quantity]:
+        path = os.path.join(HERE, "metrics", stem + ".py")
+        if os.path.exists(path):
+            return path
+    return None
+
+
+def reader(name, family=None):
+    """``read(ctx)`` of the metric ``name`` (``reader_path``).  The family is
+    the one ``ctx["config"]`` names, looked up at the call; only a family's
+    file that hands its ``read`` on to another family's arithmetic
+    (``metrics/moe_experts_roofline.ling3.py``) names that ``family``
+    instead.  Where no file reads the quantity for the family, ``read`` says
+    so and returns None: the metric is left out of the line."""
+    def read(ctx):
+        whose = family or (ctx.get("config") or {}).get("builder")
+        path = reader_path(name, whose)
+        if path is None:
+            ctx["say"](f"{name}: no reader of {name.split('.')[0]} for the "
+                       f"family {whose!r} under chipbench/metrics/")
+            return None
+        spec = importlib.util.spec_from_file_location(
+            "chipbench.metrics."
+            + os.path.basename(path)[:-3].replace(".", "__"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.read(ctx)
+    return read
 
 
 def merge(base, over):

@@ -9,18 +9,21 @@ order of the dimensions.  Run with
 """
 
 import importlib
-import os
 
 import pytest
 
 from chipbench import flops, loops, peaks, run, selfcheck
 from chipbench import trace_reduce as tr
+# the declarations' cases, which tier-1 collects here: this is the one file
+# of this directory that tests/ imports
+from chipbench.tests.test_per_layer_entries import *  # noqa: F401,F403
+from chipbench.tests.test_per_layer_entries import (KIND, MASK_AT_PR45,
+                                                     bench)
 
-KIND = "TPU v5 lite"
 #: BERT-base on one shard of 64 sequences: 12 heads of 64, 512 positions
 SHAPES = {"flash_dims": (64, 12, 512, 64), "flash_elements": 25165824,
           "flash_rows": 768, "head_dim": 64, "attention_layers": 12,
-          "compute_dtype": "bfloat16"}
+          "causal": False, "compute_dtype": "bfloat16"}
 KERNELS = ("hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd")
 LAYOUTS = {"rows": "bf16_768_512_64", "in_place": "bf16_64_512_768",
            "head_groups": "bf16_64_512_12_64"}
@@ -90,14 +93,15 @@ def test_any_layout_and_cut_passes_the_trace_checks(layout, cut):
 
 @pytest.mark.parametrize("reader, causal", [("flash_roofline", False),
                                             ("flash_roofline.dp4", False),
-                                            ("flash_roofline.olmoe", True)])
+                                            ("flash_roofline", True)])
 @pytest.mark.parametrize("cut", CUTS)
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_flash_roofline_reads_the_work_not_the_cut(layout, cut, reader,
                                                    causal):
+    """The mask is what the program states (``expected_kernel_shapes``)."""
     said = []
-    ctx = selfcheck.trace_ctx(synth(LAYOUTS[layout], CUTS[cut]), program(),
-                              KIND, said.append)
+    ctx = selfcheck.trace_ctx(synth(LAYOUTS[layout], CUTS[cut]),
+                              program(causal=causal), KIND, said.append)
     assert abs(run.reader(reader)(ctx) - by_hand(causal)) < 1e-9
     assert "24 forward calls" in said[0]
 
@@ -183,14 +187,17 @@ def test_layout_copy_counts_xlas_copies_of_the_heads_only(key):
     assert isinstance(got, float)
 
 
-@pytest.mark.parametrize("name", ["attn_layout_copy_ms_per_step",
-                                  "attn_layout_copy_ms_per_step.dp4",
-                                  "attn_layout_copy_ms_per_step.olmoe"])
-def test_layout_copy_is_zero_with_flash_and_nothing_without(name):
-    read = run.reader(name)
-    with_flash = selfcheck.trace_ctx(synth(), program(), KIND)
+@pytest.mark.parametrize("name, family", [
+    ("attn_layout_copy_ms_per_step", None),
+    ("attn_layout_copy_ms_per_step.dp4", "bert"),
+    ("attn_layout_copy_ms_per_step", "llama")])
+def test_layout_copy_is_zero_with_flash_and_nothing_without(name, family):
+    read, config = run.reader(name), family and {"builder": family}
+    with_flash = dict(selfcheck.trace_ctx(synth(), program(), KIND),
+                      config=config)
     assert read(with_flash) == 0.0
-    without = selfcheck.trace_ctx(synth(bare=(0,)), program(), KIND)
+    without = dict(selfcheck.trace_ctx(synth(bare=(0,)), program(), KIND),
+                   config=config)
     assert read(without) is None
     assert read(dict(with_flash, trace=None)) is None
 
@@ -242,38 +249,16 @@ def test_flash_passes_name_events_not_kernels():
 
 # -- the benchmark's own files agree with one another ---------------------------
 
-def bench():
-    return run.load_json(run.ROOT, "BENCHMARK.json")
-
-
-def test_every_per_layer_metric_has_a_reader_and_every_reader_a_metric():
-    names = {m["name"] for m in bench()["per_layer"]}
-    files = {f[:-3] for f in os.listdir(os.path.join(run.HERE, "metrics"))
-             if f.endswith(".py") and not f.startswith("_")}
-    for name in names:
-        assert callable(run.reader(name)), name
-    assert files - {"train_tokens_per_s"} <= (
-        names | {n.split(".")[0] for n in names})
-    assert not {n for n in names | files if n.startswith(
-        ("data_wait_ms_per_step", "executor_host_ms_per_step"))}
-
-
-@pytest.mark.parametrize("suffix, cell, moves", [
-    ("", "bert-base.b64-s512", "train_tokens_per_s"),
-    (".dp4", "bert-base.dp4-b256-s512", "train_tokens_per_s.dp4"),
-    (".olmoe", "olmoe-1b-7b.b2-s4096", "train_tokens_per_s")])
-def test_the_layout_copy_metric_is_declared_in_each_cell(suffix, cell, moves):
-    entry = next(m for m in bench()["per_layer"]
-                 if m["name"] == "attn_layout_copy_ms_per_step" + suffix)
-    assert entry == {"name": entry["name"], "unit": "ms", "better": "lower",
-                     "source": "device_trace", "layer": "kernels",
-                     "moves": moves, "workloads": [cell]}
-
-
 @pytest.mark.parametrize("cell", [w["name"] for w in bench()["workloads"]])
 def test_builders_state_the_attention_work_consistently(cell):
     """Each cell's program at toy size: the element count is the product of
-    the four dimensions and of rows x positions x head size."""
+    the four dimensions and of rows x positions x head size;
+    ``attention_layers`` is the flash forward calls of one step, counted on
+    the program itself (the attention nodes of its train subgraph, one inside
+    ``ht.remat()`` twice: its forward pass runs again in the backward pass);
+    ``causal`` is the mask of those nodes, all alike, and the one the
+    family's ``flash_roofline`` file held before the program was asked
+    (``test_per_layer_entries.MASK_AT_PR45``, where the family had one)."""
     _, entry, config, mix = run.load_cell(cell)
     config, mix = run.merge(config, config["toy"]), run.merge(mix, mix["toy"])
     if entry["chips"] > 1:
@@ -285,12 +270,18 @@ def test_builders_state_the_attention_work_consistently(cell):
     prog = builder.build(config, mix, 2 ** 31 + 5, lambda msg: None)
     try:
         want = prog.expected_kernel_shapes()
+        nodes = [node for node in prog.ex.subexecutor["train"].topo
+                 if type(node).__name__ == "ScaledDotProductAttentionOp"]
+        calls = sum(1 if node.remat_scope is None else 2 for node in nodes)
     finally:
         prog.close()
     b, h, s, d = want["flash_dims"]
     assert (s, d) == (prog.seq, want["head_dim"])
     assert want["flash_rows"] == b * h
     assert want["flash_elements"] == b * h * s * d
-    assert want["attention_layers"] == config["num_hidden_layers"]
+    assert want["attention_layers"] == calls > 0
+    assert {node.causal for node in nodes} == {want["causal"]}
+    assert MASK_AT_PR45.get(config["builder"], want["causal"]) is \
+        want["causal"]
     assert want["compute_dtype"] in tr.HLO_DTYPES
     assert not any("flash" in k for k in prog.KERNELS)

@@ -1,6 +1,7 @@
 """The Granite 4.0-H cell's readers off the chip.  The cell's rehearsal builds
-no Mamba-2 mixer (the configuration's ``why_all_attention``), so the
-``.granite`` readers that read the mixers' scopes are held here, as
+one Mamba-2 layer on the CPU, where there is no device trace (the
+configuration's ``why_pattern``), so the family's readers (``metrics/
+*.granite_hybrid.py``) and those that read the mixers' scopes are held here, as
 ``test_nemotronh_readers.py`` holds the Nemotron-H cell's: the hybrid
 (``MMMMM*MMMM``) is built at toy widths by the cell's builder, its train step
 compiled, and a device trace synthesised from the compiled step's own ENTRY
@@ -31,12 +32,15 @@ STEPS, STEP_NS = 2, 80e6
 
 def build(hybrid):
     """The cell's program at toy widths; ``hybrid``: the cell's period over
-    two chunks of positions, so that the walk is a loop."""
+    two chunks of positions, so that the walk is a loop; else two attention
+    layers and no mixer."""
     _, _, config, mix = run.load_cell(CELL)
     config, mix = run.merge(config, config["toy"]), run.merge(mix, mix["toy"])
     if hybrid:
         config.update(num_hidden_layers=10, layer_types=KINDS)
         mix["seq"] = 256
+    else:
+        config.update(num_hidden_layers=2, layer_types=["attention"] * 2)
     builder = importlib.import_module("chipbench.builders."
                                       + config["builder"])
     return (builder.build(config, mix, 2 ** 31 + 7, lambda msg: None),
@@ -109,7 +113,7 @@ def test_the_hybrids_step_carries_every_scope(hybrid):
 def test_ssm_block_is_the_sum_of_its_scopes_with_loops_taken_whole(hybrid):
     ctx, want, _, said = hybrid
     del said[:]
-    got = run.reader("ssm_block_device_ms_per_step.granite")(ctx)
+    got = run.reader("ssm_block_device_ms_per_step")(ctx)
     assert got == pytest.approx(sum(want[s] for s in SSM), rel=1e-9)
     assert f"{STEPS} executions of 'jit_step_fn'" in said[0]
     assert not any("split by counts" in line for line in said), said
@@ -125,7 +129,7 @@ def test_ssd_scan_roofline_credits_one_group_at_the_chunk_run(hybrid):
     ops, nbytes = fg.ssd_step(c, prog.tokens_per_step, 128)
     least, _ = flops.roofline_seconds(ops, nbytes, peaks.peaks_for(KIND))
     by_hand = 100.0 * 9 * least / (want["hetu_ssm_scan"] * 1e-3)
-    got = run.reader("ssd_scan_roofline.granite")(ctx)
+    got = run.reader("ssd_scan_roofline")(ctx)
     assert got == pytest.approx(by_hand, rel=1e-9)
     other = fg.ssd_step(c, prog.tokens_per_step, 256)
     assert other != (ops, nbytes)
@@ -136,13 +140,13 @@ def test_mfu_credits_the_models_operations_and_nothing_recomputed(hybrid):
     c, prog = ctx["config"], ctx["program"]
     total = sum(fg.forward_flops_per_token(c, prog.seq).values())
     rate = prog.tokens_per_step * 8 / 4.0
-    got = run.reader("mfu.granite")(ctx)
+    got = run.reader("mfu")(ctx)
     assert got == pytest.approx(100.0 * 3 * total * rate / 197e12, rel=1e-9)
-    assert run.reader("mfu.granite")(dict(ctx, peaks=None)) is None
+    assert run.reader("mfu")(dict(ctx, peaks=None)) is None
 
 
-@pytest.mark.parametrize("name", ["ssm_block_device_ms_per_step.granite",
-                                  "ssd_scan_roofline.granite"])
+@pytest.mark.parametrize("name", ["ssm_block_device_ms_per_step",
+                                  "ssd_scan_roofline"])
 def test_nothing_to_read_without_a_trace_or_without_the_scopes(hybrid, name):
     """No trace: None.  A step with no Mamba-2 mixer (the rehearsal's
     program; a parent commit's, whatever it runs): None, said, not raised."""
@@ -157,18 +161,3 @@ def test_nothing_to_read_without_a_trace_or_without_the_scopes(hybrid, name):
         assert any("carries" in line for line in said), said
     finally:
         prog.close()
-
-
-def test_the_cells_metrics_are_declared_with_readers():
-    bench = run.load_json(run.ROOT, "BENCHMARK.json")
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    names = {m["name"] for m in mine}
-    assert len(names) == 18 and all(n.endswith(".granite") for n in names)
-    assert {"ssd_scan_roofline.granite", "flash_roofline.granite",
-            "softmax_ce_roofline.granite", "mfu.granite",
-            "peak_hbm_share.granite"} <= names
-    assert all(m["moves"] == "train_tokens_per_s" for m in mine)
-    assert all(callable(run.reader(n)) for n in names)
-    rates = next(m for m in bench["end_to_end"]
-                 if m["name"] == "train_tokens_per_s")
-    assert CELL in rates["workloads"]

@@ -1,7 +1,8 @@
-"""The Nemotron-H cell's readers off the chip.  The cell's rehearsal builds no
-Mamba-2 mixer and no expert block (the configuration's ``why_all_attention``),
-so ``ssm_block_device_ms_per_step``, ``ssd_scan_roofline`` and the
-``.nemotronh`` readers of the expert blocks are held here: the hybrid
+"""The Nemotron-H cell's readers off the chip.  The cell's rehearsal builds
+one block of each kind on the CPU, where there is no device trace (the
+configuration's ``why_pattern``), so ``ssm_block_device_ms_per_step``,
+``ssd_scan_roofline`` and the family's readers of the expert blocks
+(``metrics/*.nemotron_h.py``) are held here: the hybrid
 (``MEMEM*EME``) is built at toy widths by the cell's builder, its train step
 compiled, and a device trace synthesised from the compiled step's own ENTRY
 instructions: one event an instruction with a time of its own, every
@@ -36,13 +37,16 @@ ELSEWHERE, WARM = 96.0, 3
 
 def build(hybrid):
     """The cell's program at toy widths; ``hybrid``: the cell's pattern over
-    sixteen chunks of positions, so that the walk is a loop."""
+    sixteen chunks of positions, so that the walk is a loop; else two
+    attention blocks and no mixer."""
     _, _, config, mix = run.load_cell(CELL)
     config, mix = run.merge(config, config["toy"]), run.merge(mix, mix["toy"])
     if hybrid:
         config.update(num_hidden_layers=9,
                       hybrid_override_pattern="MEMEM*EME")
         mix["seq"] = 256
+    else:
+        config.update(num_hidden_layers=2, hybrid_override_pattern="**")
     builder = importlib.import_module("chipbench.builders."
                                       + config["builder"])
     return (builder.build(config, mix, 2 ** 31 + 7, lambda msg: None),
@@ -156,10 +160,10 @@ def test_ssd_scan_roofline_is_the_chunked_scans_work_over_the_scope(hybrid):
 
 def test_moe_block_counts_the_shared_expert(hybrid):
     ctx, want, _, _ = hybrid
-    got = run.reader("moe_block_device_ms_per_step.nemotronh")(ctx)
+    got = run.reader("moe_block_device_ms_per_step")(ctx)
     assert got == pytest.approx(sum(want[s] for s in MOE), rel=1e-9)
     assert want["hetu_moe_shared"] > 0
-    assert run.reader("moe_block_device_ms_per_step.nemotronh")(
+    assert run.reader("moe_block_device_ms_per_step")(
         dict(ctx, registry={})) is None
 
 
@@ -171,7 +175,7 @@ def test_experts_roofline_credits_six_products_of_the_pairs_here(hybrid):
         c["moe_intermediate_size"]), peaks.peaks_for(KIND))[0]
         for n in PAIRS.values())
     measured = STEPS * len(PAIRS) * 2 * sum(GMM_NS.values()) * 1e-9
-    got = run.reader("moe_experts_roofline.nemotronh")(ctx)
+    got = run.reader("moe_experts_roofline")(ctx)
     assert got == pytest.approx(100.0 * STEPS * least / measured, rel=1e-9)
 
 
@@ -182,12 +186,12 @@ def test_mfu_and_the_counters_readers(hybrid):
     held = c["num_experts_per_tok"] * here / (here + ELSEWHERE * len(PAIRS))
     total = sum(fn.forward_flops_per_token(c, prog.seq, held).values())
     rate = prog.tokens_per_step * 8 / 4.0
-    got = run.reader("mfu.nemotronh")(ctx)
+    got = run.reader("mfu")(ctx)
     assert got == pytest.approx(100.0 * 3 * total * rate / 197e12, rel=1e-9)
-    assert run.reader("moe_held_pair_share.nemotronh")(ctx) == pytest.approx(
+    assert run.reader("moe_held_pair_share")(ctx) == pytest.approx(
         100.0 * here / (here + ELSEWHERE * len(PAIRS)))
-    assert run.reader("moe_dropped_share.nemotronh")(ctx) == 0.0
-    assert run.reader("moe_load_max_over_mean.nemotronh")(ctx) == 1.9
+    assert run.reader("moe_dropped_share")(ctx) == 0.0
+    assert run.reader("moe_load_max_over_mean")(ctx) == 1.9
 
 
 @pytest.mark.parametrize("name", ["ssm_block_device_ms_per_step",
@@ -206,18 +210,3 @@ def test_nothing_to_read_without_a_trace_or_without_the_scopes(hybrid, name):
         assert any("carries" in line for line in said), said
     finally:
         prog.close()
-
-
-def test_the_cells_metrics_are_declared_with_readers():
-    bench = run.load_json(run.ROOT, "BENCHMARK.json")
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    names = {m["name"] for m in mine}
-    assert {"ssm_block_device_ms_per_step", "ssd_scan_roofline"} <= names
-    assert len(names) == 18 and all(
-        n.endswith(".nemotronh") or n.startswith(("ssm_", "ssd_"))
-        for n in names)
-    assert all(m["moves"] == "train_tokens_per_s" for m in mine)
-    assert all(callable(run.reader(n)) for n in names)
-    rates = next(m for m in bench["end_to_end"]
-                 if m["name"] == "train_tokens_per_s")
-    assert rates["workloads"][-1] == CELL

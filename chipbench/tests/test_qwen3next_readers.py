@@ -1,5 +1,6 @@
 """The Qwen3-Next cell's scope readers off the chip.  The cell's rehearsal
-builds no Gated DeltaNet layer (the configuration's ``why_all_attention``), so
+builds one Gated DeltaNet layer on the CPU, where there is no device trace
+(the configuration's ``why_pattern``), so
 ``gdn_block_device_ms_per_step``, ``gdn_scan_roofline`` and the control flow
 of ``metrics/_scopes.py`` are held here: the hybrid (three DeltaNet layers, one
 attention layer) is built at toy widths by the cell's builder, its train step
@@ -27,13 +28,16 @@ STEPS, STEP_NS = 2, 50e6
 
 def build(hybrid):
     """The cell's program at toy widths; ``hybrid``: the published layer
-    pattern over four chunks of positions, so that the walk is a loop."""
+    pattern over four chunks of positions, so that the walk is a loop; else
+    two attention layers and no DeltaNet layer."""
     import importlib
     _, _, config, mix = run.load_cell(CELL)
     config, mix = run.merge(config, config["toy"]), run.merge(mix, mix["toy"])
     if hybrid:
         config.update(num_hidden_layers=4, full_attention_interval=4)
         mix["seq"] = 256
+    else:
+        config.update(num_hidden_layers=2, full_attention_interval=1)
     builder = importlib.import_module("chipbench.builders."
                                       + config["builder"])
     return builder.build(config, mix, 2 ** 31 + 7, lambda msg: None), config
