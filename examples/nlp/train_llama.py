@@ -45,7 +45,9 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              QWEN3_NEXT_CONFIGS, NemotronHConfig,
                              NemotronHForCausalLM, NEMOTRON_H_CONFIGS,
                              GraniteHybridConfig, GraniteHybridForCausalLM,
-                             GRANITE_HYBRID_CONFIGS, load_hf_llama_weights,
+                             GRANITE_HYBRID_CONFIGS, OuroConfig,
+                             OuroForCausalLM, OURO_CONFIGS,
+                             record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
 
@@ -54,7 +56,8 @@ def main():
     ap.add_argument("--model", default="llama-7b",
                     choices=(list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS)
                              + list(NEMOTRON_H_CONFIGS)
-                             + list(GRANITE_HYBRID_CONFIGS)))
+                             + list(GRANITE_HYBRID_CONFIGS)
+                             + list(OURO_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -91,6 +94,9 @@ def main():
                GraniteHybridForCausalLM, "num_hidden_layers",
                "shared_intermediate_size")
               if args.model in GRANITE_HYBRID_CONFIGS else
+              (OURO_CONFIGS, OuroConfig, OuroForCausalLM,
+               "num_layers", "intermediate_size")
+              if args.model in OURO_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
@@ -134,8 +140,10 @@ def main():
     loads = model.moe_loads() if c.num_experts and not args.pp else []
     biases = (model.router_biases()
               if loads and hasattr(model, "router_biases") else [])
-    ex = ht.Executor({"train": [loss, opt.minimize(loss)] + loads + biases},
-                     **kwargs)
+    # a looped model's mean exit shares, [P], ride it too
+    shares = [model.exit_shares] if args.model in OURO_CONFIGS else []
+    ex = ht.Executor({"train": ([loss, opt.minimize(loss)] + loads + biases
+                                + shares)}, **kwargs)
 
     if args.hf_import:
         import transformers
@@ -154,6 +162,8 @@ def main():
         for i, load in enumerate(out[2:2 + len(loads)]):
             record_moe_load(f"layer{i}", load,
                             bias=out[2 + len(loads) + i] if biases else None)
+        if shares:
+            record_exit_shares(out[-1])
         if step % 5 == 0 or step == args.steps - 1:
             print(f"step {step:4d}  loss {out[0]:.4f}")
 

@@ -60,6 +60,19 @@ class TraceContext:
         self.updates[var] = value
 
 
+@jax.custom_vjp
+def _grad_link(x):
+    """``x``; its cotangent passes an optimization barrier, so that what
+    flows on from here is a buffer of its own and not a term of a sum that
+    XLA fuses further down (`evaluate`: a variable read by several
+    recomputed groups)."""
+    return x
+
+
+_grad_link.defvjp(lambda x: (x, None),
+                  lambda _, ct: (jax.lax.optimization_barrier(ct),))
+
+
 def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
              _remat=True):
     """Evaluate ``eval_nodes`` given ``bindings`` {node: value}.
@@ -113,6 +126,21 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
                     and node.remat_scope is not None):
                 remat_groups.setdefault(node.remat_scope, []).append(node)
     group_outputs = {}
+    # a variable that enters several groups (a stack of layers walked
+    # several times on one set of weights): its gradient is the sum of one
+    # product a group, and XLA fuses that whole sum into the optimizer's
+    # update, so every group's product lives to the end of the backward
+    # pass.  `_grad_link` chains the uses instead: each group reads the
+    # value through one more link, and the cotangent passes a barrier at
+    # every link, so the sum is kept as ONE running accumulator
+    shared = {}
+    if len(remat_groups) > 1:
+        uses = {}
+        for group in remat_groups.values():
+            for var in {i for n in group for i in n.inputs
+                        if isinstance(i, VariableOp)}:
+                uses[var] = uses.get(var, 0) + 1
+        shared = {var: env[var] for var, n in uses.items() if n > 1}
     if remat_groups:
         eval_ids = {n.id for n in eval_nodes}
         consumed_outside = {}
@@ -179,7 +207,11 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
             updated[:] = list(new)
             return tuple(vals), tuple(new.values())
 
-        out_vals, new_vals = jax.checkpoint(f)(*[env[i] for i in ins])
+        for i in ins:
+            if i in shared:
+                shared[i] = _grad_link(shared[i])
+        out_vals, new_vals = jax.checkpoint(f)(
+            *[shared[i] if i in shared else env[i] for i in ins])
         for n, v in zip(outs, out_vals):
             env[n] = v
         for var, val in zip(updated, new_vals):

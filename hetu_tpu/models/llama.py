@@ -144,19 +144,26 @@ class LlamaMLP(BaseLayer):
 
 
 def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
-                      seq_len=None):
+                      seq_len=None, post_norm=None):
     """One pre-norm residual sublayer, ``x + sublayer(norm(x))`` (``* scale``
     where a family multiplies its residual branches): the norm and the sum
     under the block `hetu_norm`, the sublayer under the names it gives
     itself; a ``MultiHeadAttention`` is called ``(h, h, h, seq_len=)``,
     anything else, a wrapper around one too, ``(h)``.  ``recompute`` puts
     the norm and the sublayer inside one recomputed group: what the backward
-    pass keeps of it is what enters it, the residual stream alone."""
+    pass keeps of it is what enters it, the residual stream alone.
+    ``post_norm`` is a second norm BEHIND the sublayer (sandwich),
+    ``x + post_norm(sublayer(norm(x)))``: it and the sum then stand inside
+    the recomputed group too."""
     with (remat() if recompute else nullcontext()):
         with scope("hetu_norm"):
             h = norm(x)
         y = (sublayer(h, h, h, seq_len=seq_len)
              if isinstance(sublayer, MultiHeadAttention) else sublayer(h))
+        if post_norm is not None:
+            with scope("hetu_norm"):
+                y = post_norm(y)
+                return x + (y if scale is None else y * scale)
     with scope("hetu_norm"):
         return x + (y if scale is None else y * scale)
 
