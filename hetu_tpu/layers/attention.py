@@ -33,7 +33,8 @@ from .common import Linear, RMSNorm
 from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
 from ..ops.attention import scaled_dot_product_attention_op
-from ..ops.rotary import rotary_embedding_op, repeat_kv_op, alibi_bias_op
+from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
+                          repeat_kv_op, alibi_bias_op)
 
 
 class MultiHeadAttention(BaseLayer):
@@ -43,7 +44,7 @@ class MultiHeadAttention(BaseLayer):
                  fused_head_projection=False, qk_norm=False,
                  qk_norm_eps=1e-5, head_dim=None, rotary_dim=None,
                  output_gate=False, qk_norm_zero_centered=False, scale=None,
-                 name=None):
+                 rope_tables=None, name=None):
         assert head_dim is not None or hidden_size % num_heads == 0
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
@@ -61,6 +62,9 @@ class MultiHeadAttention(BaseLayer):
         self.dropout_keep = 1.0 - dropout_rate
         self.causal = causal_mask
         self.rope_theta = rope_theta
+        #: where the rotary kernels' tables come from: a model's layers share
+        #: one (``ops/rotary.py RopeTables``)
+        self.rope_tables = rope_tables or RopeTables()
         self.alibi = alibi
         assert not (alibi and rope_theta), "pick one position encoding"
         kv_dim = self.num_kv_heads * self.head_dim
@@ -153,8 +157,9 @@ class MultiHeadAttention(BaseLayer):
         if self.q_norm is not None:
             q, k = self.q_norm(q), self.k_norm(k)
         if self.rope_theta is not None:
-            q = self._rotate(q, seq_len)
-            k = self._rotate(k, kv_seq_len)
+            # q and k together, on the projections' [B, S, heads * d]
+            q, k = rotary_pair_op(q, k, self.rope_tables(
+                seq_len, self.head_dim, self.rope_theta))
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
         q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.inner))
                    for x, n in ((q, seq_len), (k, kv_seq_len),
@@ -164,14 +169,6 @@ class MultiHeadAttention(BaseLayer):
             scale=self.scale, dropout_keep=self.dropout_keep,
             num_heads=self.num_heads)
         return self.out_proj(ctx_)
-
-    def _rotate(self, x, seq_len):
-        # rotary on the free [B, S, heads, d] view of the projection
-        x = array_reshape_op(
-            x, output_shape=(-1, seq_len, self.num_heads, self.head_dim))
-        x = rotary_embedding_op(x, theta=self.rope_theta, seq_axis=1)
-        return array_reshape_op(
-            x, output_shape=(-1, seq_len, self.inner))
 
     def _attend_bhsd(self, query, key, value, attention_mask, seq_len,
                      kv_seq_len):

@@ -24,6 +24,7 @@ from .. import initializers as init
 from ..layers import Embedding, Linear, RMSNorm
 from ..layers.base import BaseLayer
 from ..layers.attention import MultiHeadAttention
+from ..ops.rotary import RopeTables
 from ..ops import (array_reshape_op, matmul_op, silu_op,
                    softmax_cross_entropy_sparse_op)
 from .bert import MaskedMeanOp
@@ -169,7 +170,7 @@ def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
 
 
 class LlamaDecoderLayer(BaseLayer):
-    def __init__(self, config, name):
+    def __init__(self, config, name, rope_tables=None):
         c = config
         self.attn = MultiHeadAttention(
             c.hidden_size, c.num_heads, sequence_length=c.seq_len,
@@ -178,7 +179,7 @@ class LlamaDecoderLayer(BaseLayer):
                         if c.position_embedding == "rope" else None),
             alibi=c.position_embedding == "alibi", bias=False,
             qk_norm=c.qk_norm, qk_norm_eps=c.rms_eps,
-            name=f"{name}_attn")
+            rope_tables=rope_tables, name=f"{name}_attn")
         if c.num_experts:
             from ..layers.moe import MoELayer
             self.mlp = MoELayer(c.hidden_size, c.intermediate_size,
@@ -209,13 +210,16 @@ class LlamaModel:
         self.embed = Embedding(c.vocab_size, c.hidden_size,
                                initializer=init.normal(0.0, 0.02),
                                name=f"{name}_embed")
+        #: the rotary tables' nodes, one for all layers
+        self.rope_tables = RopeTables()
         self.layers = [self._layer(i, f"{name}_layer{i}")
                        for i in range(c.num_layers)]
         self.norm = self._norm(f"{name}_norm")
 
     def _layer(self, i, name):
         """Decoder layer ``i``; a family whose layers differ overrides it."""
-        return LlamaDecoderLayer(self.config, name=name)
+        return LlamaDecoderLayer(self.config, name=name,
+                                 rope_tables=self.rope_tables)
 
     def _norm(self, name):
         return RMSNorm(self.config.hidden_size, eps=self.config.rms_eps,

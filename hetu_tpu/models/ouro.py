@@ -85,8 +85,8 @@ OURO_CONFIGS = {
 class OuroDecoderLayer(LlamaDecoderLayer):
     """The Llama layer with a second norm behind each sublayer."""
 
-    def __init__(self, config, name):
-        super().__init__(config, name)
+    def __init__(self, config, name, rope_tables=None):
+        super().__init__(config, name, rope_tables=rope_tables)
         c = config
         self.attn_out_norm = RMSNorm(c.hidden_size, eps=c.rms_eps,
                                      name=f"{name}_input_norm_2")
@@ -121,7 +121,8 @@ class OuroModel(LlamaModel):
         super().__init__(config, name=name)
 
     def _layer(self, i, name):
-        return OuroDecoderLayer(self.config, name=name)
+        return OuroDecoderLayer(self.config, name=name,
+                                rope_tables=self.rope_tables)
 
     def walk(self, x, loop_pass=None):
         """One pass: the ``k`` layers and the final norm on ``x [B, S, H]``

@@ -346,35 +346,25 @@ def _rms_norm_exp(node, ctx):
     ]
 
 
-@exporter("rotary_embedding")
-def _rotary_exp(node, ctx):
-    """RoPE (HF rotate_half convention) on [B, H, S, D], or on [B, S, H, D]
-    with ``seq_axis=1``: the cos/sin tables are precomputed constants
-    (shapes are static), the rotation is Slice/Neg/Concat/Mul/Add — plain
-    opset ops (ops/rotary.py:33)."""
-    shape = ctx.shapes.get(node.inputs[0])
-    if shape is None:
-        raise NotImplementedError(
-            "rotary_embedding export needs inferred shapes "
-            "(placeholders must declare shapes)")
-    seq_axis = int(node.attrs.get("seq_axis", -2))
+def _rotary_nodes(ctx, hint, x, out, shape, seq_axis, theta, off=0):
+    """``out = rotary(x)`` for ``x`` of ``shape`` (HF rotate_half
+    convention): the cos/sin tables are precomputed constants (shapes are
+    static), the rotation is Slice/Neg/Concat/Mul/Add — plain opset ops
+    (ops/rotary.py ``_rotary``)."""
     s, d = int(shape[seq_axis]), int(shape[-1])
-    theta = float(node.attrs.get("theta", 10000.0))
-    off = int(node.attrs.get("pos_offset", 0))
     pos = np.arange(off, off + s, dtype=np.float32)
     inv = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
     freqs = np.outer(pos, inv)
     along = [1] * len(shape)
     along[seq_axis], along[-1] = s, d
     emb = np.concatenate([freqs, freqs], axis=-1).reshape(along)
-    cosc = ctx.const(f"{node.name}_cos", np.cos(emb).astype(np.float32))
-    sinc = ctx.const(f"{node.name}_sin", np.sin(emb).astype(np.float32))
-    ax = ctx.const(f"{node.name}_ax", np.asarray([-1], np.int64))
-    s0 = ctx.const(f"{node.name}_0", np.asarray([0], np.int64))
-    sh = ctx.const(f"{node.name}_h", np.asarray([d // 2], np.int64))
-    sd_ = ctx.const(f"{node.name}_d", np.asarray([d], np.int64))
-    x = _in(node, 0)
-    x1, x2, neg, rot, xc, rs = (ctx.aux(f"{node.name}_{h}") for h in
+    cosc = ctx.const(f"{hint}_cos", np.cos(emb).astype(np.float32))
+    sinc = ctx.const(f"{hint}_sin", np.sin(emb).astype(np.float32))
+    ax = ctx.const(f"{hint}_ax", np.asarray([-1], np.int64))
+    s0 = ctx.const(f"{hint}_0", np.asarray([0], np.int64))
+    sh = ctx.const(f"{hint}_h", np.asarray([d // 2], np.int64))
+    sd_ = ctx.const(f"{hint}_d", np.asarray([d], np.int64))
+    x1, x2, neg, rot, xc, rs = (ctx.aux(f"{hint}_{h}") for h in
                                 ("x1", "x2", "neg", "rot", "xcos", "rsin"))
     return [
         NodeIR("Slice", [x, s0, sh, ax], [x1]),
@@ -383,8 +373,57 @@ def _rotary_exp(node, ctx):
         NodeIR("Concat", [neg, x1], [rot], {"axis": -1}),
         NodeIR("Mul", [x, cosc], [xc]),
         NodeIR("Mul", [rot, sinc], [rs]),
-        NodeIR("Add", [xc, rs], [node.name], name=node.name),
+        NodeIR("Add", [xc, rs], [out], name=out),
     ]
+
+
+@exporter("rotary_embedding")
+def _rotary_exp(node, ctx):
+    """RoPE on [B, H, S, D], or on [B, S, H, D] with ``seq_axis=1``."""
+    shape = ctx.shapes.get(node.inputs[0])
+    if shape is None:
+        raise NotImplementedError(
+            "rotary_embedding export needs inferred shapes "
+            "(placeholders must declare shapes)")
+    return _rotary_nodes(ctx, node.name, _in(node, 0), node.name, shape,
+                         int(node.attrs.get("seq_axis", -2)),
+                         float(node.attrs.get("theta", 10000.0)),
+                         int(node.attrs.get("pos_offset", 0)))
+
+
+@exporter("rope_tables")
+def _rope_tables_exp(node, ctx):
+    """The kernels' tables: ``rotary_pair`` exports constants of its own."""
+    return []
+
+
+@exporter("rotary_pair")
+def _rotary_pair_exp(node, ctx):
+    """q and k ``[B, S, H D]`` rotated on their ``[B, S, H, D]`` views, as
+    the outputs ``<name>_0`` and ``<name>_1`` that ``pair_item`` reads."""
+    tables = ctx.shapes.get(node.inputs[2])
+    if tables is None:
+        raise NotImplementedError("rotary_pair export needs inferred shapes")
+    s, d = (int(v) for v in tables[1:])
+    out = []
+    for i in range(2):
+        hint = f"{node.name}_{i}"
+        width = int(ctx.shapes[node.inputs[i]][-1])
+        by_head = ctx.const(f"{hint}_by_head",
+                            np.asarray([-1, s, width // d, d], np.int64))
+        flat = ctx.const(f"{hint}_flat", np.asarray([-1, s, width], np.int64))
+        view, turned = ctx.aux(f"{hint}_view"), ctx.aux(f"{hint}_turned")
+        out += [NodeIR("Reshape", [_in(node, i), by_head], [view])]
+        out += _rotary_nodes(ctx, hint, view, turned, (1, s, width // d, d),
+                             1, float(node.attrs["theta"]))
+        out += [NodeIR("Reshape", [turned, flat], [hint], name=hint)]
+    return out
+
+
+@exporter("pair_item")
+def _pair_item_exp(node, ctx):
+    return [NodeIR("Identity", [f"{_in(node, 0)}_{node.attrs['index']}"],
+                   [node.name], name=node.name)]
 
 
 @exporter("repeat_kv")
@@ -570,7 +609,8 @@ def _infer_shapes(eval_nodes, params):
         outs = jax.eval_shape(f, feed_structs)
     except Exception:
         return {}
-    shapes = {n: tuple(o.shape) for n, o in zip(interior, outs)}
+    shapes = {n: tuple(o.shape) for n, o in zip(interior, outs)
+              if hasattr(o, "shape")}      # a pair's value is a tuple
     shapes.update({p: tuple(p.shape) for p in phs})
     shapes.update({vr: tuple(np.shape(params[vr.name])) for vr in vars_})
     return shapes

@@ -89,7 +89,7 @@ MESH = "mesh"
 #: there unless the caller asked for the kernels.  Any other label says
 #: ``platform:<name>``, as the kernels with a plan do.
 NO_CHOICE_OFF_TPU = frozenset({"gated_delta", "ssd", "kda", "causal_conv",
-                               "gated_norm", "moe_rows"})
+                               "gated_norm", "moe_rows", "rotary"})
 
 
 def take(kernel, mesh, reason=None, asked=False):

@@ -4,10 +4,30 @@ The reference's Llama family applies rotary embeddings inside its
 flash-attn mixer (tools/Hetu-Galvatron/galvatron/models/llama/
 LlamaModel_sequential.py:14 imports rotary_pos_embedding) and its
 Baichuan-13B family uses ALiBi biases (models/baichuan/).  Here RoPE is a
-pure pre-transform on q/k — the cos/sin tables are built from static
-shapes, so XLA constant-folds them once per compile and fuses the rotation
-into the surrounding projection matmuls; flash attention then runs
-unchanged on the rotated tensors.
+pure pre-transform on q/k and flash attention runs unchanged on the rotated
+tensors.
+
+What runs where.  ``_rotary`` is the ``jax.numpy`` form: tables built from
+static shapes at the call, the rotation in f32 on a view by heads.  XLA does
+NOT fuse it into the projections around it: on the projection's ``[B, S, H,
+d]`` view (``seq_axis=1``) the Ouro cell's traced step (ledger, PR 47) spent
+about 230 ms of 1,421 on it, 96 times a step: the f32 reshapes between ``[..,
+H d]`` and ``[.., H, d]`` are passes over HBM, the two halves of a 128-lane
+head are sliced, negated, concatenated and padded, and the tables are
+broadcast at every call.  So a multi-head attention layer hands q and k
+together to ``rotary_pair_op``, which on a TPU runs them through the kernel
+pair ``hetu_rope_fwd`` / ``hetu_rope_bwd`` (``ops/pallas/rotary.py``: on the
+flat ``[B, S, H d]``, one read and one write a tensor) where a head is whole
+lane tiles, q and k are both bf16 or both f32 and as wide as each other and the
+sequence is a multiple of 16, and reads the tables from ONE node a model,
+sequence length, head size and base (``RopeTables``).  Each call counts its choice
+in ``hetu_kernel_choice_total{kernel="rotary", impl, reason}``: ``pallas``, or
+``jnp`` with ``head_dim_not_128_aligned``, ``dtype:<name>``, ``dtype:mixed``,
+``seq_not_16_aligned`` or ``q_k_widths_differ``; what a mesh and a platform
+without Mosaic mean is ``dispatch.take``'s rule, and ``_rotary(seq_axis=1)``
+then runs on each tensor's view.  ``rotary_embedding_op`` (grouped-query and
+partial-rotary layers on ``[B, H, S, D]``, latent attention's ``_rope_last``)
+is ``_rotary`` everywhere.
 
 Conventions match huggingface's ``rotate_half`` (non-interleaved halves),
 so HF Llama checkpoints import bit-tight (tests/test_torch_parity.py).
@@ -17,7 +37,9 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .base import simple_op
+from .base import SimpleOp, simple_op
+from .pallas import dispatch, rotary as kernels
+from ..graph.node import current_stage
 
 
 def _rope_tables(seq_len, dim, theta, pos_offset=0):
@@ -54,6 +76,66 @@ def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
 
 
 rotary_embedding_op = simple_op(_rotary, "rotary_embedding")
+
+
+def _pair_tables(*, seq_len, dim, theta):
+    """``[2, S, D]`` f32: ``cos`` and ``sin±``, the sine with
+    ``rotate_half``'s sign on it (``-sin`` on the first ``D / 2`` lanes), so
+    that ``rotate_half(x) sin = roll(x, D / 2) sin±``."""
+    cos, sin = _rope_tables(seq_len, dim, theta)
+    return jnp.stack([cos, jnp.where(jnp.arange(dim) < dim // 2, -sin, sin)])
+
+
+_pair_tables_op = simple_op(_pair_tables, "rope_tables")
+
+
+class RopeTables:
+    """The ``_pair_tables`` nodes of one model: ONE a sequence length, head
+    size, base and pipeline stage, made for the layer that asks first and read
+    by every layer (and every application of a layer) after it, outside any
+    ``ht.remat()`` group, which then reads it as an input.  An attention layer
+    has its own unless its model hands all its layers one
+    (``models/llama.py``)."""
+
+    def __init__(self):
+        self.nodes = {}
+
+    def __call__(self, seq_len, dim, theta):
+        key = (seq_len, dim, float(theta), current_stage())
+        if key not in self.nodes:
+            node = self.nodes[key] = _pair_tables_op(
+                seq_len=seq_len, dim=dim, theta=key[2])
+            node.remat_scope = None
+        return self.nodes[key]
+
+
+class RotaryPairOp(SimpleOp):
+    """``(q, k, tables) -> (q', k')`` on ``[B, S, H d]`` (or a caller's
+    ``[B S, H d]``): the kernel pair where ``dispatch.take`` says so, else
+    ``impl`` (``_rotary``) on each tensor's view by heads."""
+
+    def _compute(self, input_vals, ctx):
+        q, k, tables = input_vals
+        seq_len, d = tables.shape[1:]
+        q, k = (x.reshape(-1, seq_len, x.shape[-1]) for x in (q, k))
+        if dispatch.take("rotary", ctx.mesh,
+                         kernels.unsupported(q, k, head_dim=d)):
+            return kernels.rope(q, k, tables)
+        return tuple(
+            self.impl(x.reshape(*x.shape[:2], -1, d), seq_axis=1,
+                      **self.attrs).reshape(x.shape) for x in (q, k))
+
+
+_rotary_pair_op = simple_op(_rotary, "rotary_pair", node_cls=RotaryPairOp)
+pair_item_op = simple_op(lambda pair, *, index: pair[index], "pair_item")
+
+
+def rotary_pair_op(q, k, tables):
+    """The nodes of q and k ``[B, S, H d]`` rotated, both from one node;
+    ``tables``: a ``RopeTables`` node of their sequence length, head size and
+    base."""
+    pair = _rotary_pair_op(q, k, tables, theta=tables.attrs["theta"])
+    return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
 
 
 def _repeat_kv(x, *, n_rep):

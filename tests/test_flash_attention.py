@@ -831,6 +831,42 @@ def test_gated_norm_kernels_compile_for_v5e(v5e, as_on_tpu, wide, width,
         assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", [(1, 8192, 2048),     # the Ouro cell
+                                   (2, 4096, 2048)])    # OLMoE's
+def test_rotary_kernels_compile_for_v5e(v5e, as_on_tpu, shape, dtype):
+    """Rotary on the projections' ``[B, S, 16 x 128]``, q and k in one call,
+    forward and backward: ``hetu_rope_fwd`` and ``hetu_rope_bwd`` once each
+    under the default scoped VMEM (blocks of 256 rows in bf16, 128 in f32),
+    and around them no view by heads nor (bf16) an f32 array of q's shape in
+    HBM."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops.pallas import rotary
+    one = SingleDeviceSharding(v5e.devices[0])
+    dtype = jnp.dtype(dtype)
+    sds = lambda s, dt=dtype: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    B, S, W = shape
+    assert rotary.unsupported(sds(shape), sds(shape), head_dim=128) is None
+
+    def loss(q, k, tables):
+        a, b = rotary.rope(q, k, tables)
+        assert a.shape == b.shape == shape and a.dtype == b.dtype == dtype
+        return jnp.sum(a.astype(jnp.float32) ** 2
+                       + b.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sds(shape), sds(shape), sds((2, S, 128), jnp.float32)
+    ).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_rope_fwd" in kernels[0] and "hetu_rope_bwd" in kernels[1]
+    entry = hlo[hlo.index("\nENTRY "):]            # what reaches HBM
+    assert not re.findall(rf" = \w+\[{B},{S},16,128\]\S* ", entry)
+    if dtype == jnp.bfloat16:
+        assert not re.findall(rf" = f32\[{B},{S},{W}\]\S* ", entry)
+
+
 @pytest.mark.parametrize("dp", [1, 4])
 def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
     """BERT's hidden dropout, forward and backward, on one chip and under

@@ -6,7 +6,7 @@ pinned three ways.
    ``dispatch.py`` alone, ``dispatch.record`` is called there and by the three
    ops that have a per-shard plan alone, and no module of ``ops/pallas/``,
    ``layers/`` or ``models/`` imports an underscore name from a sibling.
-2. The function itself: for each of the ten kernel labels, on ``tpu`` and
+2. The function itself: for each of the eleven kernel labels, on ``tpu`` and
    ``cpu``, with and without a mesh, the key it records is the one in the
    table below, written out from what the call sites recorded before there
    was one function.
@@ -108,6 +108,7 @@ TABLE = {
     "causal_conv": (PALLAS, MESH, NOTHING, NOTHING),
     "gated_norm": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_rows": (PALLAS, MESH, NOTHING, NOTHING),
+    "rotary": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_gmm": (PALLAS, MESH, CPU, MESH),
 }
 
@@ -171,9 +172,11 @@ D, P, N = 128, 64, 128
 
 
 def layer_nodes():
-    """Every node of the three mixers that stands in front of a kernel
-    without a per-shard form, by scope."""
+    """Every node of the three mixers, and the attention layer's rotary pair,
+    that stands in front of a kernel without a per-shard form, by scope."""
     import hetu_tpu as ht
+    from hetu_tpu.graph.node import find_topo_sort
+    from hetu_tpu.layers.attention import MultiHeadAttention
     from hetu_tpu.layers.gated_delta_net import GatedDeltaNet
     from hetu_tpu.layers.kda import KimiDeltaAttention
     from hetu_tpu.layers.mamba2 import Mamba2
@@ -181,6 +184,8 @@ def layer_nodes():
     gdn = GatedDeltaNet(256, 1, 2, D, D, name="kd_gdn")(x)
     ssm = Mamba2(256, 4, P, 1, N, name="kd_ssm")(x)
     kda = KimiDeltaAttention(256, 2, D, name="kd_kda")(x)
+    attn = MultiHeadAttention(256, 2, sequence_length=64, rope_theta=1e4,
+                              name="kd_attn")(x, x, x)
     x = ht.placeholder_op("kd_x64", (1, 64, 64))
     small_ssm = Mamba2(64, 8, 16, 1, 64, name="kd_ssm_conv")(x)
     small_gdn = GatedDeltaNet(64, 2, 4, 16, 16, name="kd_gdn_conv")(x)
@@ -190,7 +195,9 @@ def layer_nodes():
              "hetu_gdn_conv": small_gdn.inputs[0].inputs[0],
              "hetu_ssm_out": Mamba2(64, 8, 32, 2, 64, name="kd_ssm_out")(x),
              "hetu_gdn_out": GatedDeltaNet(64, 2, 4, D, D,
-                                           name="kd_gdn_out")(x)}
+                                           name="kd_gdn_out")(x),
+             "hetu_attn": next(n for n in find_topo_sort([attn])
+                               if getattr(n, "op_kind", "") == "rotary_pair")}
     assert all(node.scope == scope for scope, node in nodes.items())
     return nodes
 
@@ -228,6 +235,9 @@ NODES = {
     "hetu_gdn_out": ("gated_norm", "pallas.gated_norm:gated_norm", None,
                      [bf16(1, 64, 512), bf16(1, 64, 1536), bf16(128),
                       bf16(512, 64)]),
+    # q, k and the tables: the form (``_rotary``) is the node's
+    "hetu_attn": ("rotary", "pallas.rotary:rope", None,
+                  [bf16(1, 64, 2 * D), bf16(1, 64, 2 * D), f32(2, 64, D)]),
 }
 
 ON_TPU = ("tpu", None, {PALLAS: 1})
@@ -240,6 +250,7 @@ CASES = [(scope,) + case for scope, cases in [
     ("hetu_gdn_conv", [ON_TPU] + UNDER_A_MESH + [("cpu", None, {})]),
     ("hetu_ssm_out", [ON_TPU] + UNDER_A_MESH + [("cpu", None, {})]),
     ("hetu_gdn_out", [ON_TPU] + UNDER_A_MESH + [("cpu", None, {})]),
+    ("hetu_attn", [ON_TPU] + UNDER_A_MESH + [("cpu", None, {})]),
 ] for case in cases]
 
 
@@ -264,11 +275,14 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
     monkeypatch.setattr(dispatch, "platform", lambda: platform)
     node = layer_nodes()[scope]
     called = []
-    if form is None:            # OutOp: the form is the layer's, on the node
-        plain, node.fn = node.fn, lambda *a, **k: (
-            called.append("jnp") or plain(*a, **k))
+    if form is None:            # the form is the layer's, on the node
+        held = "fn" if hasattr(node, "fn") else "impl"
+        plain = getattr(node, held)
+        setattr(node, held, lambda *a, **k: (
+            called.append("jnp") or plain(*a, **k)))
         stub(monkeypatch, kernel, called, "pallas",
-             like=lambda: lambda o, *a, **k: o)
+             like=lambda: (lambda q, k, t: (q, k)) if label == "rotary"
+             else lambda o, *a, **k: o)
     elif scope == "hetu_kda_scan":      # the in-place entry: [B, S, H d]
         stub(monkeypatch, form, called, "jnp")
         stub(monkeypatch, kernel, called, "pallas",
@@ -279,5 +293,7 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
              like=lambda: lambda *a: real(*a))
     ctx = types.SimpleNamespace(mesh=mesh)
     jax.eval_shape(lambda *a: node._compute(list(a), ctx), *operands)
-    assert called == (["pallas"] if want == {PALLAS: 1} else ["jnp"])
+    jnp_calls = 2 if label == "rotary" else 1        # q, then k
+    assert called == (["pallas"] if want == {PALLAS: 1}
+                      else ["jnp"] * jnp_calls)
     assert choices(label) == want
