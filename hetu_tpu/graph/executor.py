@@ -50,6 +50,16 @@ def _changed_state_only(step_fn):
     return changed
 
 
+def _as_traced(value, dtype):
+    """A host feed as a numpy array in the dtype the step program was
+    traced with: the placeholder's, else the value's own, canonicalised as
+    ``jnp.asarray`` would (``float64`` becomes ``float32`` unless x64 is
+    on), so the cast happens once, on the host, and nothing retraces."""
+    value = np.asarray(value)
+    return value.astype(jax.dtypes.canonicalize_dtype(
+        value.dtype if dtype is None else dtype), copy=False)
+
+
 class SubExecutor:
     """One named subgraph compiled into a single jitted step function."""
 
@@ -137,6 +147,17 @@ class SubExecutor:
             "uploaded by the executor (size after the cast to the "
             "placeholder's dtype)",
             labels=("subgraph",)).labels(subgraph=name)
+        uploads = reg.counter(
+            "hetu_executor_feed_uploads_total",
+            "Feeds that arrived as host arrays, counted where the "
+            "executor uploads them, all of a step's in one device_put: "
+            "sharded under the step's in_shardings on the mesh, default "
+            "on the default device (no mesh)",
+            labels=("subgraph", "placed"))
+        self._m_uploads = {placed: uploads.labels(subgraph=name,
+                                                  placed=placed)
+                           for placed in ("sharded", "default")}
+        self._feed_sh = None   # the step's feed in_shardings (_build)
         self._m_multi = reg.counter(
             "hetu_executor_run_steps_calls_total",
             "run_steps() multi-step dispatches",
@@ -422,6 +443,9 @@ class SubExecutor:
                 single = functools.partial(step_fn, _stats=None)
                 stats_fn = functools.partial(step_fn, _stats="full")
         in_shardings = self.executor._input_shardings(self)
+        # where ``_upload`` puts the step's host feeds: the feed entry of
+        # the in_shardings the program is jitted with, None with no mesh
+        self._feed_sh = None if in_shardings is None else in_shardings[2]
         self._jitted_stats = None
         # A program that does not take its state donated would return
         # every leaf it only read as a fresh copy (jit does not forward an
@@ -481,18 +505,37 @@ class SubExecutor:
                 self._fast_feed = None
                 return None
             feeds[name] = v
+        host = {}
         for p, want in autos:
             # dataloader-fed: a device-prefetched batch in the declared
             # dtype passes straight through (no host round-trip); host
-            # batches get the one cast the slow path would do
+            # batches get the one cast and upload the slow path would do
             v = p.auto_feed(self.name)
             if not isinstance(v, jax.Array):
-                v = jnp.asarray(v, dtype=want)
-                self._m_h2d_bytes.inc(v.nbytes)
+                host[p.name] = _as_traced(v, want)
             elif want is not None and v.dtype != want:
                 v = jnp.asarray(v, dtype=want)
             feeds[p.name] = v
+        feeds.update(self._upload(host))
         return feeds
+
+    def _upload(self, host):
+        """The step's host feeds (numpy, already in the dtypes the program
+        was traced with) onto the device in ONE ``device_put``, each where
+        the jitted step's in_shardings wants it: under a mesh the call
+        then receives committed arrays that already have its input
+        shardings and moves nothing; an uncommitted array on one device
+        would be fetched back, cut up and uploaded again INSIDE the call,
+        a host round trip a feed with every device waiting.  With no mesh
+        the place is the default device."""
+        if not host:
+            return host
+        shardings, placed = None, "default"
+        if self._feed_sh is not None:
+            shardings, placed = {k: self._feed_sh[k] for k in host}, "sharded"
+        self._m_uploads[placed].inc(len(host))
+        self._m_h2d_bytes.inc(sum(v.nbytes for v in host.values()))
+        return jax.device_put(host, shardings)
 
     def _root_span(self):
         """The ``run`` root of one call: every phase span below is its
@@ -521,17 +564,21 @@ class SubExecutor:
             # lands in that step's dispatch/device residual.
             with self._tr.span("compile"):
                 self._build()
-        ex = self.executor
+        feeds, ps_ids = self._feeds(feed_dict)
+        return self._dispatch(self.executor, feeds, ps_ids,
+                              convert_to_numpy_ret_vals)
+
+    def _feeds(self, feed_dict):
+        """``(feeds, ps_ids)`` of one call, by the cached structure where
+        it is armed and still fits, else by the full walk."""
         # "h2d" phase: everything between entry and the jitted call —
         # feed canonicalization, casts, uploads, PS row gathers
         with self._tr.span("h2d"):
-            ps_ids = None
             feeds = (self._fast_resolve(feed_dict)
                      if self._fast_feed is not None else None)
-            if feeds is None:
-                feeds, ps_ids = self._slow_feeds(feed_dict)
-        return self._dispatch(ex, feeds, ps_ids,
-                              convert_to_numpy_ret_vals)
+            if feeds is not None:
+                return feeds, None
+            return self._slow_feeds(feed_dict)
 
     def _slow_feeds(self, feed_dict):
         """Full per-call feed canonicalization walk; returns
@@ -595,10 +642,11 @@ class SubExecutor:
         # jit pytree and break against in_shardings
         names = {p.name for p in self.placeholders}
         feeds = {k: v for k, v in feeds.items() if k in names}
-        # cast feeds to declared dtypes (reference DataloaderOp feeds float32)
+        # cast feeds to declared dtypes (reference DataloaderOp feeds
+        # float32): host arrays on the host, then up in one call
         all_device = True
         dtypes = {}
-        uploaded = 0
+        host = {}
         for p in self.placeholders:
             v = feeds[p.name]
             want = np.dtype(p.dtype) if p.dtype is not None else None
@@ -606,15 +654,14 @@ class SubExecutor:
             if not isinstance(v, jax.Array):
                 if p.name not in auto_names:
                     all_device = False
-                feeds[p.name] = jnp.asarray(v, dtype=p.dtype)
-                uploaded += feeds[p.name].nbytes
+                host[p.name] = _as_traced(v, want)
             elif want is not None and v.dtype != want:
                 # wrong-dtype DEVICE array: cast (device-side) instead of
                 # silently retracing a second program variant
                 if p.name not in auto_names:
                     all_device = False
                 feeds[p.name] = v.astype(want)
-        self._m_h2d_bytes.inc(uploaded)
+        feeds.update(self._upload(host))
         self._arm_fast(feed_dict, feeds, names, dtypes, auto_names,
                        all_device)
         return feeds, ps_ids
@@ -768,37 +815,7 @@ class SubExecutor:
             raise ValueError("run_steps: dataloader placeholders pull a "
                              "new batch per step; use run()")
         ex = self.executor
-        feeds = None
-        if self._fast_feed is not None and not self._fast_feed[1]:
-            # reuse the cached feed structure (run_steps never has
-            # dataloader autos — the guard above raised)
-            feeds = self._fast_resolve(feed_dict)
-        if feeds is None:
-            feeds = {}
-            for node, value in (feed_dict or {}).items():
-                name = node.name if isinstance(node, Op) else node
-                feeds[name] = value
-            names = {p.name for p in self.placeholders}
-            feeds = {k: v for k, v in feeds.items() if k in names}
-            missing = [p.name for p in self.placeholders
-                       if p.name not in feeds]
-            if missing:
-                raise ValueError(
-                    f"missing feeds for placeholders: {missing}")
-            all_device = True
-            dtypes = {}
-            for p in self.placeholders:
-                v = feeds[p.name]
-                want = np.dtype(p.dtype) if p.dtype is not None else None
-                dtypes[p.name] = want
-                if not isinstance(v, jax.Array) or (
-                        want is not None and v.dtype != want):
-                    all_device = False
-                    feeds[p.name] = jnp.asarray(v, dtype=p.dtype)
-                    if not isinstance(v, jax.Array):
-                        self._m_h2d_bytes.inc(feeds[p.name].nbytes)
-            self._arm_fast(feed_dict or {}, feeds, names, dtypes, set(),
-                           all_device)
+        feeds, _ = self._feeds(feed_dict)
         if self._multi_jitted is None:
             step_fn = self._step_fn
             donate = self._donate_argnums()
