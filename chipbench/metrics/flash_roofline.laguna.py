@@ -1,0 +1,7 @@
+"""``flash_roofline`` of the FULL layers (the window layers' kernels go by
+``hetu_swa_*`` and have ``window_attn_roofline``): the Ling-3.0 cell's reader,
+which requires each pass once a layer and step whether or not whole layers are
+recomputed; scores and values are both ``head_dim`` wide here."""
+from chipbench.run import reader
+
+read = reader("flash_roofline", "ling3")

@@ -46,7 +46,8 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              NemotronHForCausalLM, NEMOTRON_H_CONFIGS,
                              GraniteHybridConfig, GraniteHybridForCausalLM,
                              GRANITE_HYBRID_CONFIGS, OuroConfig,
-                             OuroForCausalLM, OURO_CONFIGS,
+                             OuroForCausalLM, OURO_CONFIGS, LagunaConfig,
+                             LagunaForCausalLM, LAGUNA_CONFIGS,
                              record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
@@ -57,7 +58,7 @@ def main():
                     choices=(list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS)
                              + list(NEMOTRON_H_CONFIGS)
                              + list(GRANITE_HYBRID_CONFIGS)
-                             + list(OURO_CONFIGS)))
+                             + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -97,6 +98,9 @@ def main():
               (OURO_CONFIGS, OuroConfig, OuroForCausalLM,
                "num_layers", "intermediate_size")
               if args.model in OURO_CONFIGS else
+              (LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in LAGUNA_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family

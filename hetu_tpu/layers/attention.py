@@ -20,9 +20,15 @@ query and key (Qwen3, Qwen3-Next).  ``head_dim`` sets the head size apart
 from ``hidden_size // num_heads`` (Qwen3-Next: 16 heads of 256 on a hidden
 size of 2,048), ``rotary_dim`` rotates only the first dimensions of a head,
 ``output_gate`` doubles the query projection and multiplies the context by
-the sigmoid of its second half, head by head.  ``scale`` is what the scores
-are multiplied by before the softmax where it is not ``head_dim ** -0.5``
-(Granite 4.0: ``attention_multiplier`` 1/64 on heads of 64).
+the sigmoid of its second half, head by head; ``output_gate="head"`` is one
+gate NUMBER a head and a token instead, ``sigmoid(x W_g)`` with ``W_g [hidden,
+heads]``, on that head's context before ``W_o`` (Laguna).  ``scale`` is what
+the scores are multiplied by before the softmax where it is not ``head_dim **
+-0.5`` (Granite 4.0: ``attention_multiplier`` 1/64 on heads of 64).
+``window`` keeps of a causal layer's keys the last ``window`` (the position's
+own among them); such a layer's block is ``hetu_window_attn`` (no scope name
+may lie inside another), not ``hetu_attn``.  ``rope_scaling`` is
+``ops/rotary.py yarn_scaling``'s tuple.
 """
 
 from __future__ import annotations
@@ -32,9 +38,23 @@ from ..graph.node import scope
 from .common import Linear, RMSNorm
 from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
+from ..ops.base import simple_op
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
                           repeat_kv_op, alibi_bias_op)
+
+
+def _gate_heads(ctx_, gate):
+    """The context ``[B, H, S, d]`` back as ``[B, S, H d]``, each head times
+    the sigmoid of its one gate number ``[B, S, H]``, in f32."""
+    import jax
+    import jax.numpy as jnp
+    o = (ctx_.transpose(0, 2, 1, 3).astype(jnp.float32)
+         * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None])
+    return o.astype(ctx_.dtype).reshape(o.shape[:2] + (-1,))
+
+
+gate_heads_op = simple_op(_gate_heads, "gate_heads")
 
 
 class MultiHeadAttention(BaseLayer):
@@ -44,8 +64,12 @@ class MultiHeadAttention(BaseLayer):
                  fused_head_projection=False, qk_norm=False,
                  qk_norm_eps=1e-5, head_dim=None, rotary_dim=None,
                  output_gate=False, qk_norm_zero_centered=False, scale=None,
-                 rope_tables=None, name=None):
+                 rope_tables=None, window=None, rope_scaling=None,
+                 name=None):
         assert head_dim is not None or hidden_size % num_heads == 0
+        assert output_gate in (False, True, "head"), output_gate
+        assert window is None or (causal_mask and not dropout_rate), (
+            "a window is causal and has no dropout on the probabilities")
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
         self.hidden_size = hidden_size
@@ -57,6 +81,8 @@ class MultiHeadAttention(BaseLayer):
         self.inner = self.num_heads * self.head_dim
         self.rotary_dim = rotary_dim
         self.output_gate = output_gate
+        self.window = window
+        self.rope_scaling = rope_scaling
         self.scale = scale
         self.sequence_length = sequence_length
         self.dropout_keep = 1.0 - dropout_rate
@@ -71,8 +97,11 @@ class MultiHeadAttention(BaseLayer):
         # with the output gate each head's query is followed by its gate:
         # [.., heads, 2 d] (HF Qwen3NextAttention), not two halves of the row
         self.q_proj = Linear(hidden_size,
-                             self.inner * (2 if output_gate else 1),
+                             self.inner * (2 if output_gate is True else 1),
                              bias=bias, name=f"{name}_q")
+        self.gate_proj = (Linear(hidden_size, num_heads, bias=False,
+                                 name=f"{name}_gate")
+                          if output_gate == "head" else None)
         self.k_proj = Linear(hidden_size, kv_dim, bias=bias,
                              name=f"{name}_k")
         self.v_proj = Linear(hidden_size, kv_dim, bias=bias,
@@ -128,7 +157,8 @@ class MultiHeadAttention(BaseLayer):
         supports cross-attention over a memory of different length
         (reference examples/nlp/hetu_transformer.py multihead_attention,
         decoder side)."""
-        with scope("hetu_attn"):
+        with scope("hetu_attn" if self.window is None
+                   else "hetu_window_attn"):
             return self._attend(query, key, value, attention_mask, seq_len,
                                 kv_seq_len)
 
@@ -159,7 +189,7 @@ class MultiHeadAttention(BaseLayer):
         if self.rope_theta is not None:
             # q and k together, on the projections' [B, S, heads * d]
             q, k = rotary_pair_op(q, k, self.rope_tables(
-                seq_len, self.head_dim, self.rope_theta))
+                seq_len, self.head_dim, self.rope_theta, self.rope_scaling))
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
         q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.inner))
                    for x, n in ((q, seq_len), (k, kv_seq_len),
@@ -167,7 +197,7 @@ class MultiHeadAttention(BaseLayer):
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
             scale=self.scale, dropout_keep=self.dropout_keep,
-            num_heads=self.num_heads)
+            num_heads=self.num_heads, window=self.window)
         return self.out_proj(ctx_)
 
     def _attend_bhsd(self, query, key, value, attention_mask, seq_len,
@@ -175,7 +205,7 @@ class MultiHeadAttention(BaseLayer):
         """The [B, heads, S, d] graph: heads split off and transposed
         before the op, the context transposed back."""
         gate = None
-        if self.output_gate:
+        if self.output_gate is True:
             # [B, S, heads, 2 d]: a head's query, then its gate
             qg = array_reshape_op(self.q_proj(query), output_shape=(
                 -1, seq_len, self.num_heads, 2 * self.head_dim))
@@ -196,6 +226,8 @@ class MultiHeadAttention(BaseLayer):
         if self.rope_theta is not None:
             kw = ({} if self.rotary_dim is None
                   else {"rotary_dim": self.rotary_dim})
+            if self.rope_scaling is not None:
+                kw["scaling"] = self.rope_scaling
             q = rotary_embedding_op(q, theta=self.rope_theta, **kw)
             k = rotary_embedding_op(k, theta=self.rope_theta, **kw)
         if self.num_kv_heads != self.num_heads:
@@ -208,7 +240,10 @@ class MultiHeadAttention(BaseLayer):
                               else attention_mask + bias)
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
-            scale=self.scale, dropout_keep=self.dropout_keep)
+            scale=self.scale, dropout_keep=self.dropout_keep,
+            window=self.window)
+        if self.gate_proj is not None:
+            return self.out_proj(gate_heads_op(ctx_, self.gate_proj(query)))
         ctx_ = transpose_op(ctx_, perm=(0, 2, 1, 3))
         ctx_ = array_reshape_op(ctx_,
                                 output_shape=(-1, seq_len, self.inner))

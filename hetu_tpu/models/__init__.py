@@ -19,6 +19,8 @@ from .ling3 import (Ling3Config, Ling3Model, Ling3ForCausalLM,
                     LING3_CONFIGS)
 from .ouro import (OuroConfig, OuroModel, OuroForCausalLM, OURO_CONFIGS,
                    record_exit_shares)
+from .laguna import (LagunaConfig, LagunaModel, LagunaForCausalLM,
+                     LAGUNA_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,

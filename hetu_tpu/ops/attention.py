@@ -16,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..graph.node import Op
 
 _FLASH_MIN_SEQ = 256  # below this the jnp path is faster (kernel overheads)
@@ -28,7 +29,7 @@ def _split_heads(x, num_heads):
         0, 2, 1, 3)
 
 
-def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None):
+def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
     """Decide how attention lowers for these operands: ``[B, H, S, D]``,
     or ``[B, S, H*D]`` with ``num_heads``, which is planned as the 4-D
     array it is a view of.
@@ -46,7 +47,7 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None):
         return f"platform:{dispatch.platform()}", (), ()
     if num_heads is not None:
         q, k, v = (heads_view(x, num_heads) for x in (q, k, v))
-    why = unsupported(q, k, v, mask, keep)
+    why = unsupported(q, k, v, mask, keep, window)
     if why is not None:
         return why, (), ()
     if q.shape[-2] < _FLASH_MIN_SEQ:
@@ -64,12 +65,26 @@ class ScaledDotProductAttentionOp(Op):
     """q, k, v ``[B, H, S, D]`` -> ``[B, H, S, D]``; with ``num_heads``,
     the projections' ``[B, S, H*D]`` -> ``[B, S, H*D]``: the flash kernel
     then reads and writes the heads in place, and the jnp composition goes
-    through the free ``[B, S, H, D]`` view."""
+    through the free ``[B, S, H, D]`` view.
+
+    ``window`` (``WindowAttentionOp``, causal): position ``i`` sees the keys
+    ``j`` with ``0 <= i - j < window``, its own among them (512 keys at 512,
+    not 513); the kernels then go by ``hetu_swa_fwd`` / ``hetu_swa_bwd`` and
+    skip the blocks outside the band.  With a window there is no dropout and
+    no ring: refused where the node is built or evaluated."""
+
+    #: the keys a position sees; None: all (that ``causal`` leaves)
+    window = None
 
     def __init__(self, q, k, v, mask=None, causal=False, scale=None,
                  dropout_keep=1.0, num_heads=None, name=None):
         inputs = [q, k, v] + ([mask] if mask is not None else [])
         super().__init__(*inputs, name=name)
+        telemetry.get_registry().counter(
+            "hetu_attn_layers_total",
+            "Attention nodes built, by kind (full: every key the mask "
+            "leaves; window: the last `window` keys)", labels=("kind",),
+        ).labels(kind="full" if self.window is None else "window").inc()
         self.has_mask = mask is not None
         self.num_heads = num_heads
         self.causal = causal
@@ -105,7 +120,7 @@ class ScaledDotProductAttentionOp(Op):
         if ctx.mesh is not None and ctx.mesh.shape.get("cp", 1) > 1:
             return False
         why, _, head_axes = _flash_plan(q, k, v, mask, self._keep(ctx),
-                                        ctx.mesh, self.num_heads)
+                                        ctx.mesh, self.num_heads, self.window)
         if why is not None:
             return True
         shards = 1
@@ -123,6 +138,11 @@ class ScaledDotProductAttentionOp(Op):
         # sequence dim is context-sharded — lower to flash ring attention
         # (K/V blocks rotate the ICI ring; parallel/context_parallel.py).
         # Dropout/masks stay on the single-device paths.
+        if (self.window is not None and ctx.mesh is not None
+                and ctx.mesh.shape.get("cp", 1) > 1):
+            raise NotImplementedError(
+                "attention with a window over a context-parallel mesh: the "
+                "ring's offsets are not built for it")
         if (ctx.mesh is not None and "cp" in ctx.mesh.shape
                 and ctx.mesh.shape["cp"] > 1 and mask is None
                 and self.dropout_keep >= 1.0 and q.ndim == 4
@@ -143,7 +163,7 @@ class ScaledDotProductAttentionOp(Op):
                                   scale=scale)
         keep = self._keep(ctx)
         why, batch_axes, head_axes = _flash_plan(q, k, v, mask, keep,
-                                                 ctx.mesh, heads)
+                                                 ctx.mesh, heads, self.window)
         from .pallas import dispatch
         if dispatch.record("flash_attention", why):
             from .pallas.flash_attention import (flash_attention,
@@ -153,7 +173,8 @@ class ScaledDotProductAttentionOp(Op):
                 seed = jax.random.bits(ctx.rng_for(self), (1,),
                                        "uint32").astype(jnp.int32)
             kw = dict(mask=mask, causal=self.causal, scale=scale,
-                      dropout_keep=keep, seed=seed, num_heads=heads)
+                      dropout_keep=keep, seed=seed, num_heads=heads,
+                      window=self.window)
             if batch_axes or head_axes:
                 return sharded_flash_attention(
                     ctx.mesh, q, k, v, batch_axes=batch_axes,
@@ -169,7 +190,10 @@ class ScaledDotProductAttentionOp(Op):
             s_q, s_k = scores.shape[-2], scores.shape[-1]
             iq = jnp.arange(s_q)[:, None]
             ik = jnp.arange(s_k)[None, :]
-            scores = jnp.where(iq >= ik - (s_k - s_q), scores, -1e9)
+            seen = iq >= ik - (s_k - s_q)
+            if self.window is not None:
+                seen = seen & (iq - ik + (s_k - s_q) < self.window)
+            scores = jnp.where(seen, scores, -1e9)
         if mask is not None:
             scores = scores + mask
         probs = jax.nn.softmax(scores, axis=-1)
@@ -182,9 +206,25 @@ class ScaledDotProductAttentionOp(Op):
         return out if heads is None else out.reshape(*out.shape[:2], -1)
 
 
+class WindowAttentionOp(ScaledDotProductAttentionOp):
+    """Causal attention over the last ``window`` keys: a node type of its own,
+    so that a reader of the graph tells the two kinds of layer apart as a
+    reader of the trace tells their kernels apart by name."""
+
+    def __init__(self, q, k, v, window, **kw):
+        assert kw.get("causal") and window >= 1, (
+            "a window is over the keys behind a position", window)
+        assert kw.get("dropout_keep", 1.0) >= 1.0, (
+            "dropout on the probabilities is not built with a window")
+        self.window = int(window)
+        super().__init__(q, k, v, **kw)
+
+
 def scaled_dot_product_attention_op(q, k, v, mask=None, causal=False,
                                     scale=None, dropout_keep=1.0,
-                                    num_heads=None, name=None):
-    return ScaledDotProductAttentionOp(q, k, v, mask=mask, causal=causal,
-                                       scale=scale, dropout_keep=dropout_keep,
-                                       num_heads=num_heads, name=name)
+                                    num_heads=None, window=None, name=None):
+    kw = dict(mask=mask, causal=causal, scale=scale,
+              dropout_keep=dropout_keep, num_heads=num_heads, name=name)
+    if window is not None:
+        return WindowAttentionOp(q, k, v, window, **kw)
+    return ScaledDotProductAttentionOp(q, k, v, **kw)

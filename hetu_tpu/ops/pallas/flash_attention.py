@@ -75,10 +75,14 @@ _NEG_INF = -1e30
 _LANES = 128
 
 
-def unsupported(q, k, v, mask=None, dropout_keep=1.0):
+def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None):
     """Why the kernel cannot take these ``[B, H, S, D]`` operands, or None
     when it can.  Callers that fall back to the jnp composition record this
-    string (ops/pallas/dispatch.py)."""
+    string (ops/pallas/dispatch.py).  ``window``: the keys a position sees,
+    its own among them (``_fwd``); with one, dropout inside the kernel is not
+    built."""
+    if window is not None and dropout_keep < 1.0:
+        return "window_with_dropout"
     if (q.ndim != 4 or k.shape != q.shape or v.ndim != 4
             or v.shape[:3] != q.shape[:3]):
         return "not_self_attention_4d"
@@ -236,8 +240,15 @@ def _tn(a, b):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 o_ref, lse_ref, *, scale, causal, block_k, q_len, k_len,
-                keep_prob, heads, group, empty_lse_neg=False):
-    """offs_ref (optional SMEM int32[2] = [q_off, k_off]): GLOBAL sequence
+                keep_prob, heads, group, empty_lse_neg=False, window=None,
+                back=0):
+    """``window`` (static; causal, no offsets): row ``i`` sees the keys ``j``
+    with ``0 <= i - j < window``.  The key loop then starts at the first block
+    that holds such a key, only the blocks that the window's edge or the
+    diagonal crosses are masked, and ``k_ref`` / ``v_ref`` hold the ``back``
+    rows before the query block and the block's own (``_fwd``), not all keys.
+
+    offs_ref (optional SMEM int32[2] = [q_off, k_off]): GLOBAL sequence
     offsets of the local q/k blocks — the ring-attention path attends a
     rotating remote K/V block, so causal masking compares global positions.
     ``empty_lse_neg``: blockwise callers need lse=-inf semantics for rows
@@ -263,14 +274,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         hi = (q_off + (qi + 1) * bq - 1 - k_off) // block_k + 1
         nk_causal = jax.lax.clamp(0, hi, nk) if offs_ref is not None \
             else jax.lax.min(nk, hi)
+    if window is not None:
+        # the first key block a row of this query block sees, the first that
+        # every row sees whole, and the block k_ref's first row lies in
+        j_lo = jax.lax.max(0, (qi * bq - window + 1) // block_k)
+        j_in = jax.lax.max(j_lo, -((window - (qi + 1) * bq) // block_k))
+        held_from = jax.lax.max(0, qi * bq - back) // block_k
 
     def make_body(q, h, masked):
         bh = b * heads + hg * group + h
 
         def body(j, carry):
             m, l, acc = carry
-            kb = k_ref[0, pl.ds(j * block_k, block_k), :]
-            vb = _only_head(v_ref[0, pl.ds(j * block_k, block_k), :],
+            def at():   # block j's first row in k_ref / v_ref
+                return (j if window is None else j - held_from) * block_k
+            kb = k_ref[0, pl.ds(at(), block_k), :]
+            vb = _only_head(v_ref[0, pl.ds(at(), block_k), :],
                             h, dim, group)
             # scores tracked in BASE-2 units (s2 = s * log2(e)): exp2 is
             # the VPU's native exponential; lse converts back to natural
@@ -285,7 +304,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 col = (k_off + j * block_k
                        + jax.lax.broadcasted_iota(jnp.int32,
                                                   (bq, block_k), 1))
-                s = jnp.where(row >= col, s, _NEG_INF)
+                seen = row >= col
+                if window is not None:
+                    seen = seen & (row - col < window)
+                s = jnp.where(seen, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1))
             p = jnp.exp2(s - m_new[:, None])
             alpha = jnp.exp2(m - m_new)
@@ -306,7 +328,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((bq,), jnp.float32)
         acc0 = jnp.zeros((bq, v_width), jnp.float32)
-        if causal and nk > 8:
+        if window is not None:
+            # [j_lo, inside): the window's edge crosses; [inside, diagonal):
+            # every pair is seen; [diagonal, nk_causal): the diagonal crosses
+            diagonal = jax.lax.clamp(j_lo, (qi * bq) // block_k, nk_causal)
+            inside = jax.lax.clamp(j_lo, j_in, diagonal)
+            carry = jax.lax.fori_loop(j_lo, inside, make_body(q, h, True),
+                                      (m0, l0, acc0))
+            carry = jax.lax.fori_loop(inside, diagonal,
+                                      make_body(q, h, False), carry)
+            m, l, acc = jax.lax.fori_loop(diagonal, nk_causal,
+                                          make_body(q, h, True), carry)
+        elif causal and nk > 8:
             # split loop: kv blocks fully below the diagonal need no mask —
             # the where+iota per tile is pure VPU overhead on ~(nk-1)/nk of
             # the causal work, alternating with the exp2 on the critical
@@ -388,28 +421,54 @@ def _compiler_params(resident_bytes):
 
 def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
          block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-         empty_lse_neg=False, num_heads=None):
+         empty_lse_neg=False, num_heads=None, window=None):
     """q: [b,h,sq,d]; k,v: [b,h,sk,d] (sq != sk in the blockwise/ring path,
     where ``offsets`` = int32[2] global [q_off, k_off]), or all three
-    [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq])."""
+    [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq]).
+
+    ``window`` (causal self-attention, no offsets, no dropout): the same body
+    under the name ``hetu_swa_fwd``; a program holds of K and V the rows its
+    query block can see, ``back`` rows before the block and the block's own,
+    cut out where they lie, unless that is all of them."""
     w, wv = _walk(q, num_heads), _walk(v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
     o_spec = pl.BlockSpec((1, block_q, wv.width), w.rows(lambda t: t))
-    k_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
-    v_spec = pl.BlockSpec((1, sk, wv.width), w.rows(lambda t: 0))
+    held, consts = sk, {}
+    if window is not None:
+        assert causal and offsets is None and keep_prob >= 1.0 and sq == sk
+        assert block_q % block_k == 0, (block_q, block_k)
+        back = -(-(window - 1) // block_k) * block_k
+        held = min(sk, back + block_q)
+        consts = dict(window=window, back=back if held < sk else sk)
+    if held < sk:
+        # addressed by element, not by block (Mosaic: all dimensions or
+        # none): the band starts where it starts
+        def band(width):
+            at = w.rows(lambda t: pl.multiple_of(
+                jnp.maximum(t * block_q - back, 0), block_k))
+
+            def index(b, hg, t):
+                lead, row, lane = at(b, hg, t)
+                return lead, row, lane * width
+            return pl.BlockSpec(tuple(map(pl.Element, (1, held, width))),
+                                index)
+        k_spec, v_spec = band(w.width), band(wv.width)
+    else:
+        k_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
+        v_spec = pl.BlockSpec((1, sk, wv.width), w.rows(lambda t: 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
     kern = _make_kern(_fwd_kernel, 3, mask is not None, keep_prob < 1.0,
                       offsets is not None,
                       scale=scale, causal=causal, block_k=block_k,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
                       heads=w.heads, group=w.group,
-                      empty_lse_neg=empty_lse_neg)
+                      empty_lse_neg=empty_lse_neg, **consts)
     groups = w.heads // w.group
     item = q.dtype.itemsize
     o, lse = pl.pallas_call(
         kern,
-        name="hetu_flash_fwd",
+        name="hetu_flash_fwd" if window is None else "hetu_swa_fwd",
         interpret=interpret(),
         grid=(w.batch, groups, sq // block_q),
         in_specs=[q_spec, k_spec, v_spec] + extra_specs,
@@ -425,7 +484,7 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
                                  jnp.float32),
         ],
         compiler_params=_compiler_params(
-            (block_q + sk) * (w.width + wv.width) * item),
+            (block_q + held) * (w.width + wv.width) * item),
     )(w.flat(q), w.flat(k), wv.flat(v), *extra_args)
     return wv.unflat(o), lse
 
@@ -435,9 +494,11 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 seed_ref, offs_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
                 scale, causal, block_q, q_len, k_len, keep_prob, heads,
-                group):
+                group, window=None):
     """One key block of one head group: every tile (query block i, this
-    key block) is formed once and feeds dV, dK and dQ[i]."""
+    key block) is formed once and feeds dV, dK and dQ[i].  ``window``
+    (``_fwd_kernel``): the query loop ends with the last block that holds a
+    row which sees one of these keys."""
     b, hg, kj = (pl.program_id(a) for a in range(3))
     bk, width = k_ref.shape[1], k_ref.shape[2]
     dim = width // group
@@ -478,7 +539,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 rr = (q_off + i * block_q
                       + jax.lax.broadcasted_iota(jnp.int32,
                                                  (block_q, bk), 0))
-                s = jnp.where(rr >= col, s, _NEG_INF)
+                seen = rr >= col
+                if window is not None:
+                    seen = seen & (rr - col < window)
+                s = jnp.where(seen, s, _NEG_INF)
             p = jnp.exp2(s - (lse * _LOG2E)[:, None])
             dp = _nt(doh, v)
             if keep_prob < 1.0:
@@ -510,7 +574,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
         # with offsets the bound is dynamic (global positions)
         lo = (k_off + kj * bk - q_off) // block_q
         i_start = jax.lax.clamp(0, lo, nq) if offs_ref is not None else lo
-    dk, dv = jax.lax.fori_loop(i_start, nq, body, (zeros, v_zeros))
+    i_end = nq
+    if window is not None:
+        i_end = jax.lax.min(nq, ((kj + 1) * bk + window - 2) // block_q + 1)
+    dk, dv = jax.lax.fori_loop(i_start, i_end, body, (zeros, v_zeros))
     dk_ref[0] = (dk * (scale / keep_prob)).astype(dk_ref.dtype)
     dv_ref[0] = (dv * (1.0 / keep_prob)).astype(dv_ref.dtype)
 
@@ -521,9 +588,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
 
 def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
               block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-              num_heads=None):
+              num_heads=None, window=None):
     """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
-    it."""
+    it.  With ``window`` the kernel is ``hetu_swa_bwd``."""
     w, wv = _walk(q, num_heads), _walk(v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
@@ -537,10 +604,11 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
                       offsets is not None,
                       scale=scale, causal=causal, block_q=block_q,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
-                      heads=w.heads, group=w.group)
+                      heads=w.heads, group=w.group, window=window)
     item = q.dtype.itemsize
     dq, dk, dv = pl.pallas_call(
-        kern, name="hetu_flash_bwd", interpret=interpret(),
+        kern, name="hetu_flash_bwd" if window is None else "hetu_swa_bwd",
+        interpret=interpret(),
         grid=(w.batch, w.heads // w.group, sk // block_k),
         in_specs=[whole_q, k_spec, v_spec, whole_o, whole_o, lse_spec]
         + extra_specs,
@@ -560,24 +628,34 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
 # the mask (None or [B,1,1,S]) and the dropout seed (a traced int32 tensor)
 # are operands with zero cotangent.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, mask, seed, causal, scale, keep_prob, block, num_heads):
+def _blocks(block, window):
+    """The keywords of ``_fwd`` / ``_bwd_impl`` that a call's static plan
+    sets: one block size for both sides, or with a window ``(block_q,
+    block_k)``."""
+    if window is None:
+        return dict(block_q=block, block_k=block)
+    return dict(block_q=block[0], block_k=block[1], window=window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, mask, seed, causal, scale, keep_prob, block, num_heads,
+           window=None):
     return _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                block_q=block, block_k=block, num_heads=num_heads)[0]
+                num_heads=num_heads, **_blocks(block, window))[0]
 
 
 def _flash_fwd(q, k, v, mask, seed, causal, scale, keep_prob, block,
-               num_heads):
+               num_heads, window=None):
     o, lse = _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                  block_q=block, block_k=block, num_heads=num_heads)
+                  num_heads=num_heads, **_blocks(block, window))
     return o, (q, k, v, mask, seed, o, lse)
 
 
-def _flash_bwd(causal, scale, keep_prob, block, num_heads, res, g):
+def _flash_bwd(causal, scale, keep_prob, block, num_heads, window, res, g):
     q, k, v, mask, seed, o, lse = res
     dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, g, causal, scale,
-                           keep_prob, seed, block_q=block, block_k=block,
-                           num_heads=num_heads)
+                           keep_prob, seed, num_heads=num_heads,
+                           **_blocks(block, window))
     # The additive mask is treated as NON-differentiable data (our graphs
     # build it from placeholder attention masks).  A learned attention bias
     # must use the jnp fallback path, which differentiates the bias.
@@ -590,7 +668,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # jitted, so that the layers of a model with one attention shape share one
 # trace of the two kernel bodies (pallas_call itself traces its kernel anew
 # at every call)
-_flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9))
+_flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9, 10))
 
 
 # -- blockwise API (ring / context parallelism) ----------------------------
@@ -646,17 +724,42 @@ def flash_attention_block_bwd(q, k, v, o, lse, dout, q_off, k_off, *,
                      block_k=bk, offsets=offsets)
 
 
-def _count_entry(walk, v_dim):
+#: (block_q, block_k) of a call with a window, by the window: the largest pair
+#: that divides the sequence is taken (``_window_plan``)
+WINDOW_BLOCKS = ((512, 512), (256, 256), (128, 128))
+
+
+def _window_plan(s_pad, window):
+    """``(block_q, block_k)`` of a call with a window over ``s_pad`` rows, and
+    the share of the causal plan's tile area (blocks of ``_pad_plan``) that
+    its key loop visits: the gauge ``hetu_attn_window_block_share``."""
+    bq, bk = next(b for b in WINDOW_BLOCKS if s_pad % b[0] == 0)
+    block = _pad_plan(s_pad)[1]
+    visited = sum((q_lo + bq - 1) // bk + 1 - max(0, (q_lo - window + 1) // bk)
+                  for q_lo in range(0, s_pad, bq)) * bq * bk
+    causal = sum((q_lo + block - 1) // block + 1
+                 for q_lo in range(0, s_pad, block)) * block * block
+    share = visited / causal
+    telemetry.get_registry().gauge(
+        "hetu_attn_window_block_share",
+        "Tile area the window attention kernel's key loop visits over the "
+        "causal flash plan's, at the last call planned").set(share)
+    return (bq, bk), share
+
+
+def _count_entry(walk, v_dim, window=None):
     """Trace-time count of the walk taken, beside ``dispatch.record``'s
     count of the kernel-versus-jnp choice.  Values narrower (or wider) than
-    the keys are the layout ``bhsd_v<head size of v>``."""
+    the keys are the layout ``bhsd_v<head size of v>``; a call with a window
+    adds ``_w<window>``."""
     telemetry.get_registry().counter(
         "hetu_flash_attention_entry_total",
         "Trace-time flash attention calls by operand layout and the heads "
         "one program takes",
         labels=("layout", "heads_per_program"),
     ).labels(layout=walk.layout + ("" if v_dim == walk.dim
-                                   else f"_v{v_dim}"),
+                                   else f"_v{v_dim}")
+             + ("" if window is None else f"_w{window}"),
              heads_per_program=str(walk.group)).inc()
 
 
@@ -668,7 +771,7 @@ def entries():
 
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
-                    dropout_keep=1.0, seed=None, num_heads=None):
+                    dropout_keep=1.0, seed=None, num_heads=None, window=None):
     """Fused attention; returns None when shapes are unsupported so the
     caller falls back to the jnp composition (ops/attention.py).
 
@@ -676,15 +779,23 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     ``num_heads`` (read, and the context written, in place); the latter
     needs ``heads_per_program(num_heads, D)`` to be non-zero.
     ``dropout_keep`` < 1 applies attention-prob dropout in-kernel (TPU
-    PRNG); ``seed`` must then be an int32/uint32 scalar array.
+    PRNG); ``seed`` must then be an int32/uint32 scalar array.  ``window``
+    (with ``causal``): position ``i`` sees the ``window`` keys ``i - window +
+    1 .. i``; the kernels then run as ``hetu_swa_fwd`` / ``hetu_swa_bwd`` and
+    skip the blocks outside the band; a window that holds every key is no
+    window.
     """
+    if window is not None:
+        assert causal and window >= 1, (causal, window)
+        if window >= (q.shape[1] if q.ndim == 3 else q.shape[2]):
+            window = None
     if q.ndim == 3:
         if not heads_per_program(num_heads, q.shape[-1] // num_heads):
             return None
         views = (heads_view(t, num_heads) for t in (q, k, v))
     else:
         views = (q, k, v)
-    if unsupported(*views, mask, dropout_keep) is not None:
+    if unsupported(*views, mask, dropout_keep, window) is not None:
         return None
     if dropout_keep < 1.0 and seed is None:
         raise ValueError(
@@ -695,7 +806,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     # two head sizes (latent attention: keys 192 wide, values 128) come as
     # [B, H, S, D]; heads read in place are one size
     assert dv == w.dim or q.ndim == 4, (q.shape, v.shape)
-    _count_entry(w, dv)
+    _count_entry(w, dv, window)
     s, d = w.seq(q), w.dim
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -723,8 +834,10 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
             mask = jnp.pad(base, ((0, 0), (0, 0), (0, 0), (0, s_pad - s)),
                            constant_values=_NEG_INF)
 
+    if window is not None:
+        block = _window_plan(s_pad, window)[0]
     out = _flash_call(q, k, v, mask, seed, causal, float(scale),
-                      float(dropout_keep), block, num_heads)
+                      float(dropout_keep), block, num_heads, window)
     if d_pad != d or dv_pad != dv or s_pad != s:
         out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :dv]
     return out

@@ -35,6 +35,8 @@ so HF Llama checkpoints import bit-tight (tests/test_torch_parity.py).
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 from .base import SimpleOp, simple_op
@@ -42,33 +44,70 @@ from .pallas import dispatch, rotary as kernels
 from ..graph.node import current_stage
 
 
-def _rope_tables(seq_len, dim, theta, pos_offset=0):
+def yarn_scaling(factor, original_max_position_embeddings, beta_fast=32.0,
+                 beta_slow=1.0, attention_factor=None):
+    """The ``scaling`` of the tables for YaRN as ``transformers`` computes it
+    (``rope_type`` "yarn"; arXiv:2309.00071): a hashable tuple.  The
+    frequencies whose wavelength the original context holds more than
+    ``beta_fast`` times stay, those it holds fewer than ``beta_slow`` times
+    are divided by ``factor``, a linear ramp blends between; ``cos`` and
+    ``sin`` are multiplied by ``attention_factor`` (default ``0.1 ln factor +
+    1``) at every length."""
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return ("yarn", float(factor), int(original_max_position_embeddings),
+            float(beta_fast), float(beta_slow), float(attention_factor))
+
+
+def _inverse_frequencies(dim, theta, scaling=None):
+    """``(inv [dim / 2], what cos and sin are multiplied by)``."""
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return inv, None
+    kind, factor, original, beta_fast, beta_slow, attention_factor = scaling
+    assert kind == "yarn", scaling
+
+    def correction(turns):    # the dimension that turns `turns` times
+        return (dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return (1.0 - ramp) * inv + ramp * inv / factor, attention_factor
+
+
+def _rope_tables(seq_len, dim, theta, pos_offset=0, scaling=None):
     # always f32 tables: bf16 positions past ~256 lose the low rotation
     # frequencies entirely
     pos = jnp.arange(pos_offset, pos_offset + seq_len, dtype=jnp.float32)
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inv, factor = _inverse_frequencies(dim, theta, scaling)
     freqs = jnp.outer(pos, inv)                       # [S, D/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)    # [S, D]
-    return jnp.cos(emb), jnp.sin(emb)
+    if factor is None:
+        return jnp.cos(emb), jnp.sin(emb)
+    return jnp.cos(emb) * factor, jnp.sin(emb) * factor
 
 
 def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
-            rotary_dim=None):
+            rotary_dim=None, scaling=None):
     """Apply RoPE to [B, H, S, D] (HF rotate_half convention), or, with
     ``seq_axis=1``, to the [B, S, H, D] view of a projection's output.
     ``rotary_dim`` rotates the first ``rotary_dim`` of the ``D`` dimensions
     (frequencies ``theta^(-2i / rotary_dim)``) and passes the rest through
-    (partial rotary: GPT-NeoX, Qwen3-Next)."""
+    (partial rotary: GPT-NeoX, Qwen3-Next).  ``scaling``: ``yarn_scaling``'s
+    tuple, over the dimensions that turn."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         assert 0 < rotary_dim < x.shape[-1] and rotary_dim % 2 == 0
+        more = {} if scaling is None else {"scaling": scaling}
         turned = _rotary(x[..., :rotary_dim], theta=theta,
-                         pos_offset=pos_offset, seq_axis=seq_axis)
+                         pos_offset=pos_offset, seq_axis=seq_axis, **more)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d, s = x.shape[-1], x.shape[seq_axis]
     along = [1] * x.ndim
     along[seq_axis], along[-1] = s, d
     cos, sin = (t.reshape(along)
-                for t in _rope_tables(s, d, theta, pos_offset))
+                for t in _rope_tables(s, d, theta, pos_offset, scaling))
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
@@ -78,11 +117,11 @@ def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
 rotary_embedding_op = simple_op(_rotary, "rotary_embedding")
 
 
-def _pair_tables(*, seq_len, dim, theta):
+def _pair_tables(*, seq_len, dim, theta, scaling=None):
     """``[2, S, D]`` f32: ``cos`` and ``sin±``, the sine with
     ``rotate_half``'s sign on it (``-sin`` on the first ``D / 2`` lanes), so
     that ``rotate_half(x) sin = roll(x, D / 2) sin±``."""
-    cos, sin = _rope_tables(seq_len, dim, theta)
+    cos, sin = _rope_tables(seq_len, dim, theta, scaling=scaling)
     return jnp.stack([cos, jnp.where(jnp.arange(dim) < dim // 2, -sin, sin)])
 
 
@@ -91,8 +130,8 @@ _pair_tables_op = simple_op(_pair_tables, "rope_tables")
 
 class RopeTables:
     """The ``_pair_tables`` nodes of one model: ONE a sequence length, head
-    size, base and pipeline stage, made for the layer that asks first and read
-    by every layer (and every application of a layer) after it, outside any
+    size, base, scaling (``yarn_scaling``; None: plain) and pipeline stage,
+    made for the layer that asks first and read by every layer (and every application of a layer) after it, outside any
     ``ht.remat()`` group, which then reads it as an input.  An attention layer
     has its own unless its model hands all its layers one
     (``models/llama.py``)."""
@@ -100,11 +139,13 @@ class RopeTables:
     def __init__(self):
         self.nodes = {}
 
-    def __call__(self, seq_len, dim, theta):
-        key = (seq_len, dim, float(theta), current_stage())
+    def __call__(self, seq_len, dim, theta, scaling=None):
+        key = (seq_len, dim, float(theta), current_stage()) + (
+            () if scaling is None else (scaling,))
         if key not in self.nodes:
             node = self.nodes[key] = _pair_tables_op(
-                seq_len=seq_len, dim=dim, theta=key[2])
+                seq_len=seq_len, dim=dim, theta=key[2],
+                **({} if scaling is None else {"scaling": scaling}))
             node.remat_scope = None
         return self.nodes[key]
 
@@ -134,7 +175,9 @@ def rotary_pair_op(q, k, tables):
     """The nodes of q and k ``[B, S, H d]`` rotated, both from one node;
     ``tables``: a ``RopeTables`` node of their sequence length, head size and
     base."""
-    pair = _rotary_pair_op(q, k, tables, theta=tables.attrs["theta"])
+    pair = _rotary_pair_op(q, k, tables, **{
+        key: tables.attrs[key] for key in ("theta", "scaling")
+        if key in tables.attrs})
     return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
 
 
