@@ -6,15 +6,22 @@ layer keeps the [B, S, H] layout end to end — projections are 3D matmuls XLA
 maps straight onto the MXU — and the core product is a single fused-attention
 op (ops/attention.py) lowered to Pallas flash attention on TPU, which reads
 the projections' [B, S, heads*d] and writes the context in place: the layer
-moves no heads.  Grouped-query and ALiBi layers (and the inference graphs'
-fused head projection) keep the [B, heads, S, d] graph.
+moves no heads.  ONE rule, on the layer's own arguments, says which graph a
+layer builds (``MultiHeadAttention.layout``, counted in
+``hetu_attn_layout_total{layout, reason}``): grouped queries (K and V stay
+``[B, S, kv_heads*d]``: the kernels read a query head's key head where it
+lies), a gate a head and a partial rotation go in place where a head is whole
+lane tiles (``head_dim % 128 == 0``); ALiBi, a norm a head, the elementwise
+gate and the inference graphs' fused head projection keep the [B, heads, S, d]
+graph.
 
 Position-encoding variants for the Llama/Baichuan model tier (reference
 tools/Hetu-Galvatron/galvatron/models/llama, models/baichuan): ``rope_theta``
 applies rotary embeddings to q/k before the attention product; ``alibi``
 adds the per-head linear bias instead; ``num_kv_heads`` < num_heads gives
-grouped-query attention (K/V projected to the smaller head count and
-broadcast back at the attention einsum); ``qk_norm`` applies an RMSNorm to
+grouped-query attention (K/V projected to the smaller head count; a query
+head reads its key head in place, or on ``[B, heads, S, d]`` K/V are repeated
+in front of the op); ``qk_norm`` applies an RMSNorm to
 the projected queries and keys (OLMoE), ``qk_norm="head"`` one to each head's
 query and key (Qwen3, Qwen3-Next).  ``head_dim`` sets the head size apart
 from ``hidden_size // num_heads`` (Qwen3-Next: 16 heads of 256 on a hidden
@@ -33,6 +40,10 @@ may lie inside another), not ``hetu_attn``.  ``rope_scaling`` is
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
+
+from .. import telemetry
 from .base import BaseLayer, fresh_name
 from ..graph.node import scope
 from .common import Linear, RMSNorm
@@ -40,6 +51,7 @@ from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
 from ..ops.base import simple_op
 from ..ops.attention import scaled_dot_product_attention_op
+from ..ops.pallas.common import PARTS, parts
 from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
                           repeat_kv_op, alibi_bias_op)
 
@@ -47,14 +59,72 @@ from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
 def _gate_heads(ctx_, gate):
     """The context ``[B, H, S, d]`` back as ``[B, S, H d]``, each head times
     the sigmoid of its one gate number ``[B, S, H]``, in f32."""
-    import jax
-    import jax.numpy as jnp
     o = (ctx_.transpose(0, 2, 1, 3).astype(jnp.float32)
          * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None])
     return o.astype(ctx_.dtype).reshape(o.shape[:2] + (-1,))
 
 
+def _spread(heads, d, times=1, dtype=jnp.bfloat16):
+    """``[times * heads, heads * d]`` of 0 and 1: row ``i`` holds 1 on the
+    ``d`` lanes of head ``i % heads``."""
+    return (jnp.arange(heads * d)[None, :] // d
+            == jnp.arange(times * heads)[:, None] % heads).astype(dtype)
+
+
+def _widen(sig, d):
+    """``sig [B, S, H]`` f32 -> f32 ``[B, S, H d]``, a head's number on each of
+    its ``d`` lanes, EXACTLY: the three bf16 parts of ``sig`` (all 24 bits of
+    it) side by side times ``_spread``, f32 sums of at most three terms that
+    are bits of one number.  A product on the matrix unit, because the other
+    way, ``sig[..., None]`` on an ``[.., H, d]`` view, is a pass over HBM in
+    f32 (PRs 41 and 48)."""
+    return jnp.matmul(jnp.concatenate(parts(sig), axis=-1),
+                      _spread(sig.shape[-1], d, PARTS),
+                      preferred_element_type=jnp.float32)
+
+
+def _sigmoid_wide(ctx_, gate):
+    """``(sigmoid(gate) [B, S, H], the same spread to ctx_'s [B, S, H d])``,
+    f32."""
+    sig = jax.nn.sigmoid(gate.astype(jnp.float32))
+    return sig, _widen(sig, ctx_.shape[-1] // gate.shape[-1])
+
+
+@jax.custom_vjp
+def _gate_heads_in_place(ctx_, gate):
+    """``_gate_heads`` on the context as the kernels leave it, ``[B, S, H
+    d]``: the same f32 product with one rounding and no view by heads.  Kept
+    for the backward pass: the two operands, nothing ``H d`` wide in f32."""
+    return (ctx_.astype(jnp.float32) * _sigmoid_wide(ctx_, gate)[1]
+            ).astype(ctx_.dtype)
+
+
+def _gate_in_place_fwd(ctx_, gate):
+    return _gate_heads_in_place(ctx_, gate), (ctx_, gate)
+
+
+def _gate_in_place_bwd(kept, g):
+    # behind a barrier, or XLA finds the forward pass's spread sigmoid to be
+    # this one and keeps its f32 [B, S, H d] from there to here
+    ctx_, gate = jax.lax.optimization_barrier(kept)
+    sig, wide = _sigmoid_wide(ctx_, gate)
+    g = g.astype(jnp.float32)
+    # a head's lanes of g * ctx added up: the spread's transpose, on an f32
+    # operand and 128 terms a sum, so at the highest precision
+    heads = gate.shape[-1]
+    d_sig = jnp.matmul(
+        g * ctx_.astype(jnp.float32),
+        _spread(heads, ctx_.shape[-1] // heads, dtype=jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST)
+    return ((g * wide).astype(ctx_.dtype),
+            (d_sig * sig * (1.0 - sig)).astype(gate.dtype))
+
+
+_gate_heads_in_place.defvjp(_gate_in_place_fwd, _gate_in_place_bwd)
+
 gate_heads_op = simple_op(_gate_heads, "gate_heads")
+gate_heads_in_place_op = simple_op(_gate_heads_in_place,
+                                   "gate_heads_in_place")
 
 
 class MultiHeadAttention(BaseLayer):
@@ -177,28 +247,57 @@ class MultiHeadAttention(BaseLayer):
                 "kv_seq_len != seq_len is only supported for non-causal, "
                 "non-rotary, non-alibi cross-attention")
         kv_seq_len = kv_seq_len or seq_len
-        if (self.fused_head_projection or self.alibi
-                or self.num_kv_heads != self.num_heads
-                or self.qk_norm_per_head or self.output_gate
-                or self.rotary_dim is not None):
+        layout, reason = self.layout()
+        telemetry.get_registry().counter(
+            "hetu_attn_layout_total",
+            "Attention layers built, by the graph they build (bshd: on the "
+            "projections' [B, S, heads*d] in place; bhsd: heads split off "
+            "and transposed) and why", labels=("layout", "reason"),
+        ).labels(layout=layout, reason=reason).inc()
+        if layout == "bhsd":
             return self._attend_bhsd(query, key, value, attention_mask,
                                      seq_len, kv_seq_len)
         q, k, v = self.q_proj(query), self.k_proj(key), self.v_proj(value)
         if self.q_norm is not None:
             q, k = self.q_norm(q), self.k_norm(k)
         if self.rope_theta is not None:
-            # q and k together, on the projections' [B, S, heads * d]
+            # q and k together, on the projections' [B, S, heads * d] and
+            # [B, S, kv_heads * d]
             q, k = rotary_pair_op(q, k, self.rope_tables(
-                seq_len, self.head_dim, self.rope_theta, self.rope_scaling))
+                seq_len, self.head_dim, self.rope_theta, self.rope_scaling,
+                **({} if self.rotary_dim is None
+                   else {"rotary_dim": self.rotary_dim})))
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
-        q, k, v = (array_reshape_op(x, output_shape=(-1, n, self.inner))
-                   for x, n in ((q, seq_len), (k, kv_seq_len),
-                                (v, kv_seq_len)))
+        kv_dim = self.num_kv_heads * self.head_dim
+        q, k, v = (array_reshape_op(x, output_shape=(-1, n, width))
+                   for x, n, width in ((q, seq_len, self.inner),
+                                       (k, kv_seq_len, kv_dim),
+                                       (v, kv_seq_len, kv_dim)))
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
             scale=self.scale, dropout_keep=self.dropout_keep,
             num_heads=self.num_heads, window=self.window)
+        if self.gate_proj is not None:
+            ctx_ = gate_heads_in_place_op(ctx_, self.gate_proj(query))
         return self.out_proj(ctx_)
+
+    def layout(self):
+        """``(layout, reason)``: ``("bshd", "in_place")`` where the layer
+        attends on the projections' ``[B, S, heads*d]``, else ``"bhsd"`` and
+        the first thing about the layer that the in-place kernels do not take.
+        Grouped queries, a gate a head and a partial rotation need heads of
+        whole lane tiles; without them any head size goes."""
+        tiles = (self.num_kv_heads != self.num_heads
+                 or self.output_gate == "head" or self.rotary_dim is not None)
+        for reason, holds in (
+                ("head_dim_not_128_aligned", tiles and self.head_dim % 128),
+                ("qk_norm_per_head", self.qk_norm_per_head),
+                ("gate_elementwise", self.output_gate is True),
+                ("alibi", self.alibi),
+                ("fused_head_projection", self.fused_head_projection)):
+            if holds:
+                return "bhsd", reason
+        return "bshd", "in_place"
 
     def _attend_bhsd(self, query, key, value, attention_mask, seq_len,
                      kv_seq_len):

@@ -149,7 +149,7 @@ LAGUNA_CONFIGS = {
 
 
 class LagunaDecoderLayer(BaseLayer):
-    def __init__(self, config, index, name):
+    def __init__(self, config, index, name, rope_tables=None):
         c = config
         self.kind = c.layer_types[index]
         window = c.sliding_window if self.kind == "sliding_attention" else None
@@ -158,7 +158,8 @@ class LagunaDecoderLayer(BaseLayer):
             sequence_length=c.seq_len, causal_mask=True,
             num_kv_heads=c.num_kv_heads, head_dim=c.head_dim, bias=False,
             output_gate="head" if c.gating else False, window=window,
-            name=f"{name}_attn", **c.rope[self.kind])
+            rope_tables=rope_tables, name=f"{name}_attn",
+            **c.rope[self.kind])
         self.dense = c.mlp_layer_types[index] == "dense"
         if self.dense:
             self.mlp = LlamaMLP(c.hidden_size, c.dense_intermediate_size,
@@ -192,7 +193,8 @@ class LagunaDecoderLayer(BaseLayer):
 
 class LagunaModel(LlamaModel):
     def _layer(self, i, name):
-        return LagunaDecoderLayer(self.config, i, name)
+        return LagunaDecoderLayer(self.config, i, name,
+                                  rope_tables=self.rope_tables)
 
 
 class LagunaForCausalLM(Ling3ForCausalLM):

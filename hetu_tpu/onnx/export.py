@@ -404,6 +404,11 @@ def _rotary_pair_exp(node, ctx):
     tables = ctx.shapes.get(node.inputs[2])
     if tables is None:
         raise NotImplementedError("rotary_pair export needs inferred shapes")
+    for what in ("rotary_dim", "scaling"):
+        if node.attrs.get(what) is not None:
+            raise NotImplementedError(
+                f"rotary_pair export with {what}={node.attrs[what]!r}: only "
+                "the plain rotation of a whole head is exported")
     s, d = (int(v) for v in tables[1:])
     out = []
     for i in range(2):
@@ -513,16 +518,31 @@ def _export_sdpa(node, ctx):
         d, s_q = qshape[-1] // heads, qshape[-2]
         s_k = ctx.shapes.get(k, qshape)[-2]
 
-        def split(x, tag, seq):
-            shp = ctx.const(f"{node.name}_{tag}_shape",
-                            np.asarray([-1, seq, heads, d], np.int64))
+        # k and v may hold fewer heads (grouped queries): each key head is
+        # tiled under its query heads, as ``repeat_kv`` exports
+        rep = qshape[-1] // ctx.shapes.get(k, qshape)[-1]
+
+        def split(x, tag, seq, rep=1):
+            shp = ctx.const(f"{node.name}_{tag}_shape", np.asarray(
+                [-1, seq, heads // rep] + [1] * (rep > 1) + [d], np.int64))
             rs, tr = (ctx.aux(f"{node.name}_{tag}_{part}")
                       for part in ("rs", "heads"))
             out.append(NodeIR("Reshape", [x.name, shp], [rs]))
+            if rep > 1:
+                tiled, flat = (ctx.aux(f"{node.name}_{tag}_{part}")
+                               for part in ("tile", "rep"))
+                out.append(NodeIR("Tile", [rs, ctx.const(
+                    f"{node.name}_{tag}_reps",
+                    np.asarray([1, 1, 1, rep, 1], np.int64))], [tiled]))
+                out.append(NodeIR("Reshape", [tiled, ctx.const(
+                    f"{node.name}_{tag}_all",
+                    np.asarray([-1, seq, heads, d], np.int64))], [flat]))
+                rs = flat
             out.append(NodeIR("Transpose", [rs], [tr],
                               {"perm": (0, 2, 1, 3)}))
             return tr
-        q, k, v = split(q, "q", s_q), split(k, "k", s_k), split(v, "v", s_k)
+        q, k, v = (split(q, "q", s_q), split(k, "k", s_k, rep),
+                   split(v, "v", s_k, rep))
     scale = node.scale if node.scale is not None else 1.0 / float(np.sqrt(d))
     kt = ctx.aux(f"{node.name}_kT")
     out.append(NodeIR("Transpose", [k], [kt], {"perm": (0, 1, 3, 2)}))

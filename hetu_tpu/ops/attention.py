@@ -22,17 +22,29 @@ from ..graph.node import Op
 _FLASH_MIN_SEQ = 256  # below this the jnp path is faster (kernel overheads)
 
 
-def _split_heads(x, num_heads):
-    """[B, S, H*D] -> [B, H, S, D]: a transpose, not a view."""
+def _head_view(x, num_heads, rep=1):
+    """[B, S, H/rep*D] -> [B, S, H, D]: a free view; with ``rep`` > 1 (keys
+    and values of grouped queries) a key head under each of its ``rep`` query
+    heads, as ``ops/rotary.py _repeat_kv`` on the other axis."""
     b, s, width = x.shape
-    return x.reshape(b, s, num_heads, width // num_heads).transpose(
-        0, 2, 1, 3)
+    kv = num_heads // rep
+    x = x.reshape(b, s, kv, width // kv)
+    if rep == 1:
+        return x
+    return jnp.broadcast_to(x[:, :, :, None, :], (b, s, kv, rep, x.shape[-1])
+                            ).reshape(b, s, num_heads, x.shape[-1])
+
+
+def _split_heads(x, num_heads, rep=1):
+    """[B, S, H/rep*D] -> [B, H, S, D]: a transpose, not a view."""
+    return _head_view(x, num_heads, rep).transpose(0, 2, 1, 3)
 
 
 def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
     """Decide how attention lowers for these operands: ``[B, H, S, D]``,
     or ``[B, S, H*D]`` with ``num_heads``, which is planned as the 4-D
-    array it is a view of.
+    array it is a view of; k and v may then be ``[B, S, KV*D]`` of fewer
+    heads (grouped queries), where a head is whole lane tiles.
 
     Returns ``(reason, batch_axes, head_axes)``: ``reason`` is None when
     the Pallas flash kernel runs (under ``shard_map`` over the named mesh
@@ -42,11 +54,13 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
     of at least ``_FLASH_MIN_SEQ`` and 8-aligned head sizes in
     [32, 512]."""
     from .pallas import dispatch
-    from .pallas.flash_attention import heads_view, unsupported
+    from .pallas.flash_attention import heads_views, unsupported
     if not dispatch.mosaic():
         return f"platform:{dispatch.platform()}", (), ()
     if num_heads is not None:
-        q, k, v = (heads_view(x, num_heads) for x in (q, k, v))
+        q, k, v = heads_views(q, k, v, num_heads)
+    elif k.ndim == 4 and k.shape[1] != q.shape[1]:
+        return "kv_heads_differ_4d", (), ()
     why = unsupported(q, k, v, mask, keep, window)
     if why is not None:
         return why, (), ()
@@ -55,9 +69,10 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
     if not all(32 <= d <= 512 and d % 8 == 0
                for d in (q.shape[-1], v.shape[-1])):
         return "head_dim_not_8_aligned_in_32_512", (), ()
-    # under a mesh: per shard, batch over 'dp' and heads over 'tp'
+    # under a mesh: per shard, batch over 'dp' and heads over 'tp' (the key
+    # heads, which divide the query heads)
     why, axes = dispatch.shard_axes(mesh, {"dp": q.shape[0],
-                                           "tp": q.shape[1]})
+                                           "tp": k.shape[1]})
     return why, axes["dp"], axes["tp"]
 
 
@@ -65,7 +80,10 @@ class ScaledDotProductAttentionOp(Op):
     """q, k, v ``[B, H, S, D]`` -> ``[B, H, S, D]``; with ``num_heads``,
     the projections' ``[B, S, H*D]`` -> ``[B, S, H*D]``: the flash kernel
     then reads and writes the heads in place, and the jnp composition goes
-    through the free ``[B, S, H, D]`` view.
+    through the free ``[B, S, H, D]`` view.  There k and v may be ``[B, S,
+    KV*D]`` of fewer heads (grouped queries; ``rep = H / KV`` is read from
+    the widths): the kernel reads a query head's key head where it lies, the
+    jnp composition repeats the key heads on the view.
 
     ``window`` (``WindowAttentionOp``, causal): position ``i`` sees the keys
     ``j`` with ``0 <= i - j < window``, its own among them (512 keys at 512,
@@ -105,8 +123,9 @@ class ScaledDotProductAttentionOp(Op):
             return self._attend(q, k, v, mask, ctx, heads)
         # the ring walks [B, H, S, D], and so does the kernel where a
         # shard's heads do not come in lane-aligned groups
-        out = self._attend(*(_split_heads(x, heads) for x in (q, k, v)),
-                           mask, ctx, None)
+        rep = q.shape[-1] // k.shape[-1]
+        out = self._attend(*(_split_heads(x, heads, n) for x, n in (
+            (q, 1), (k, rep), (v, rep))), mask, ctx, None)
         return out.transpose(0, 2, 1, 3).reshape(q.shape[:2] + (-1,))
 
     def _keep(self, ctx):
@@ -182,7 +201,9 @@ class ScaledDotProductAttentionOp(Op):
             return flash_attention(q, k, v, **kw)
         qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
         if heads is not None:
-            q, k, v = (x.reshape(*x.shape[:2], heads, -1) for x in (q, k, v))
+            rep = q.shape[-1] // k.shape[-1]
+            q, k, v = (_head_view(x, heads, n) for x, n in (
+                (q, 1), (k, rep), (v, rep)))
             qk, pv = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
         scores = jnp.einsum(qk, q, k,
                             preferred_element_type=jnp.float32) * scale

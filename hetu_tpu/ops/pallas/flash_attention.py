@@ -22,6 +22,15 @@ over 64 or an output 64 wide costs the same passes as one over 128, the
 zeros add nothing to the f32 sums, and the heads' outputs land in their own
 lanes of one tile, which is stored once.
 
+K and V may be NARROWER than q in place (grouped queries, heads of whole lane
+tiles): ``[B, S, KV*D]`` under q's ``[B, S, H*D]``, ``rep = H / KV`` read from
+the widths.  Program ``hg`` then reads the lane block ``hg // rep`` of K and V
+(``_Walk.kv_rows``: forward, the window's band, the backward pass's inputs):
+nothing repeats a key head in HBM.  The backward kernel writes dK and dV a
+QUERY head, as it does behind a ``repeat_kv``, and ``_group_sum`` adds each
+group up outside it (accumulating a group inside would fetch the whole q, o
+and dO again for every key block).  ``rep = 1`` is the program it was.
+
   forward : ``hetu_flash_fwd``, grid (B, H/g, Sq/block_q); the kv loop runs
             inside the kernel with running (m, l, acc) carries; saves the
             logsumexp ``[B, H/g, g, Sq]`` for the backward pass.
@@ -83,10 +92,15 @@ def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None):
     built."""
     if window is not None and dropout_keep < 1.0:
         return "window_with_dropout"
-    if (q.ndim != 4 or k.shape != q.shape or v.ndim != 4
-            or v.shape[:3] != q.shape[:3]):
+    # keys and values of fewer heads than the queries (grouped queries): only
+    # as the [B, S, KV*D] that ``heads_view`` is given, read in place
+    if (q.ndim != 4 or k.ndim != 4 or v.ndim != 4
+            or (k.shape[0],) + k.shape[2:] != (q.shape[0],) + q.shape[2:]
+            or q.shape[1] % k.shape[1] or v.shape[:3] != k.shape[:3]):
         return "not_self_attention_4d"
     b, h, s, d = q.shape
+    if k.shape[1] != h and d % _LANES:
+        return "grouped_head_dim_not_128_aligned"
     d = max(d, v.shape[3])
     # head dim is always the FULL last block dim, so Mosaic only needs it
     # 8-aligned (the wrapper pads to that); > 512 would blow VMEM tiles
@@ -111,6 +125,15 @@ def heads_view(x, num_heads):
                                 x.dtype)
 
 
+def heads_views(q, k, v, num_heads):
+    """``heads_view`` of q ``[B, S, H*D]`` and of k, v ``[B, S, KV*D]``: the
+    key heads are as many as k's width holds of q's ``H`` (``rep = H / KV``
+    is read from the widths)."""
+    kv_heads = num_heads // (q.shape[-1] // k.shape[-1])
+    return (heads_view(q, num_heads), heads_view(k, kv_heads),
+            heads_view(v, kv_heads))
+
+
 def heads_per_program(num_heads, head_dim):
     """Heads one program takes when ``[B, S, H*D]`` is read in place: as
     many as fill the 128 lanes of a block; 0 when the heads cannot be cut
@@ -130,12 +153,16 @@ class _Walk(NamedTuple):
     H*D]`` read in place, ``group`` heads a program; ``bhsd`` is ``[B, H, S,
     D]`` through its free ``[B*H, S, D]`` view, one head a program.  Either
     way a kernel sees blocks ``(1, rows, group*dim)`` on the grid ``(batch,
-    heads/group, row blocks)``."""
+    heads/group, row blocks)``.  ``rep`` query heads read one key head
+    (``bshd`` with heads of whole lane tiles): K and V are ``[B, S,
+    heads/rep*dim]`` and program ``hg`` reads their lane block ``hg // rep``
+    (``kv_rows``)."""
     layout: str
     batch: int
     heads: int
     dim: int
     group: int
+    rep: int = 1
 
     @property
     def width(self):
@@ -162,14 +189,33 @@ class _Walk(NamedTuple):
         heads = self.heads
         return lambda b, hg, t: (b * heads + hg, pick(t), 0)
 
+    def kv_rows(self, pick):
+        """``rows`` for a block of K or V: the key head of the program's
+        query head."""
+        if self.rep == 1:
+            return self.rows(pick)
+        rep = self.rep
+        return lambda b, hg, t: (b, pick(t), hg // rep)
 
-def _walk(q, num_heads):
+
+def _walk(q, num_heads, k=None):
+    """The walk of ``q``; with ``k``, of ``q`` over the keys ``k``, which may
+    be narrower."""
     if q.ndim == 4:
         b, h, _, d = q.shape
         return _Walk("bhsd", b, h, d, 1)
     b, _, width = q.shape
     d = width // num_heads
-    return _Walk("bshd", b, num_heads, d, heads_per_program(num_heads, d))
+    rep = 1 if k is None else width // k.shape[-1]
+    assert rep == 1 or d % _LANES == 0, (q.shape, k.shape, num_heads)
+    return _Walk("bshd", b, num_heads, d, heads_per_program(num_heads, d),
+                 rep)
+
+
+def _walks(q, k, v, num_heads):
+    """``(walk of q over k, walk of v)``: v's heads are k's."""
+    w = _walk(q, num_heads, k)
+    return w, _walk(v, num_heads and num_heads // w.rep)
 
 
 def _pad_plan(s):
@@ -430,7 +476,7 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
     under the name ``hetu_swa_fwd``; a program holds of K and V the rows its
     query block can see, ``back`` rows before the block and the block's own,
     cut out where they lie, unless that is all of them."""
-    w, wv = _walk(q, num_heads), _walk(v, num_heads)
+    w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
     o_spec = pl.BlockSpec((1, block_q, wv.width), w.rows(lambda t: t))
@@ -445,7 +491,7 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
         # addressed by element, not by block (Mosaic: all dimensions or
         # none): the band starts where it starts
         def band(width):
-            at = w.rows(lambda t: pl.multiple_of(
+            at = w.kv_rows(lambda t: pl.multiple_of(
                 jnp.maximum(t * block_q - back, 0), block_k))
 
             def index(b, hg, t):
@@ -455,8 +501,8 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
                                 index)
         k_spec, v_spec = band(w.width), band(wv.width)
     else:
-        k_spec = pl.BlockSpec((1, sk, w.width), w.rows(lambda t: 0))
-        v_spec = pl.BlockSpec((1, sk, wv.width), w.rows(lambda t: 0))
+        k_spec = pl.BlockSpec((1, sk, w.width), w.kv_rows(lambda t: 0))
+        v_spec = pl.BlockSpec((1, sk, wv.width), w.kv_rows(lambda t: 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
     kern = _make_kern(_fwd_kernel, 3, mask is not None, keep_prob < 1.0,
                       offsets is not None,
@@ -479,7 +525,8 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(
-                w.flat(q).shape[:-1] + wv.flat(v).shape[-1:], q.dtype),
+                w.flat(q).shape[:-1] + (w.rep * wv.flat(v).shape[-1],),
+                q.dtype),
             jax.ShapeDtypeStruct((w.batch, groups, w.group, sq),
                                  jnp.float32),
         ],
@@ -591,12 +638,17 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
               num_heads=None, window=None):
     """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
     it.  With ``window`` the kernel is ``hetu_swa_bwd``."""
-    w, wv = _walk(q, num_heads), _walk(v, num_heads)
+    w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
     whole_o = pl.BlockSpec((1, sq, wv.width), w.rows(lambda t: 0))
-    k_spec = pl.BlockSpec((1, block_k, w.width), w.rows(lambda t: t))
-    v_spec = pl.BlockSpec((1, block_k, wv.width), w.rows(lambda t: t))
+    # what is read of K and V lies at the key head, what is written of dK and
+    # dV at the query head: with ``rep`` > 1 the two results are a QUERY head
+    # wide and ``_flash_bwd`` sums each group
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, x.width), w.rows(lambda t: t))
+                      for x in (w, wv))
+    k_read, v_read = (pl.BlockSpec((1, block_k, x.width),
+                                   w.kv_rows(lambda t: t)) for x in (w, wv))
     lse_spec = pl.BlockSpec((1, 1, w.group, sq),
                             lambda b, hg, t: (b, hg, 0, 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
@@ -610,18 +662,36 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
         kern, name="hetu_flash_bwd" if window is None else "hetu_swa_bwd",
         interpret=interpret(),
         grid=(w.batch, w.heads // w.group, sk // block_k),
-        in_specs=[whole_q, k_spec, v_spec, whole_o, whole_o, lse_spec]
+        in_specs=[whole_q, k_read, v_read, whole_o, whole_o, lse_spec]
         + extra_specs,
         out_specs=[whole_q, k_spec, v_spec],
-        out_shape=[jax.ShapeDtypeStruct(x.flat(t).shape, t.dtype)
-                   for x, t in ((w, q), (w, k), (wv, v))],
+        out_shape=[jax.ShapeDtypeStruct(w.flat(q).shape, q.dtype)]
+        + [jax.ShapeDtypeStruct(
+            x.flat(t).shape[:-1] + (w.rep * x.flat(t).shape[-1],), t.dtype)
+           for x, t in ((w, k), (wv, v))],
         scratch_shapes=[pltpu.VMEM((sq, w.width), jnp.float32)],
         compiler_params=_compiler_params(
             2 * (sq + block_k) * (w.width + wv.width) * item
             + sq * w.width * 4),
     )(w.flat(q), w.flat(k), wv.flat(v), wv.flat(o), wv.flat(dout), lse,
       *extra_args)
-    return w.unflat(dq), w.unflat(dk), wv.unflat(dv)
+    return (w.unflat(dq), w.unflat(_group_sum(dk, w.rep, w.dim)),
+            wv.unflat(_group_sum(dv, w.rep, wv.dim)))
+
+
+def _group_sum(x, rep, dim):
+    """dK or dV a QUERY head ``[B, S, H*dim]`` -> a key head ``[B, S,
+    H/rep*dim]``: each key head's ``rep`` query heads added up in f32 with one
+    rounding (what the transpose of a ``repeat_kv`` in front of the kernels
+    would do), on slices of whole lane tiles: a view by heads would be a pass
+    over HBM of its own."""
+    if rep == 1:
+        return x
+    heads = [x[..., at:at + dim].astype(jnp.float32)
+             for at in range(0, x.shape[-1], dim)]
+    return jnp.concatenate(
+        [sum(heads[at + 1:at + rep], heads[at])
+         for at in range(0, len(heads), rep)], axis=-1).astype(x.dtype)
 
 
 # -- custom-vjp wrapper ----------------------------------------------------
@@ -751,7 +821,8 @@ def _count_entry(walk, v_dim, window=None):
     """Trace-time count of the walk taken, beside ``dispatch.record``'s
     count of the kernel-versus-jnp choice.  Values narrower (or wider) than
     the keys are the layout ``bhsd_v<head size of v>``; a call with a window
-    adds ``_w<window>``."""
+    adds ``_w<window>``, keys of fewer heads than the queries ``_kv<key
+    heads>``."""
     telemetry.get_registry().counter(
         "hetu_flash_attention_entry_total",
         "Trace-time flash attention calls by operand layout and the heads "
@@ -759,7 +830,8 @@ def _count_entry(walk, v_dim, window=None):
         labels=("layout", "heads_per_program"),
     ).labels(layout=walk.layout + ("" if v_dim == walk.dim
                                    else f"_v{v_dim}")
-             + ("" if window is None else f"_w{window}"),
+             + ("" if window is None else f"_w{window}")
+             + ("" if walk.rep == 1 else f"_kv{walk.heads // walk.rep}"),
              heads_per_program=str(walk.group)).inc()
 
 
@@ -792,7 +864,9 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     if q.ndim == 3:
         if not heads_per_program(num_heads, q.shape[-1] // num_heads):
             return None
-        views = (heads_view(t, num_heads) for t in (q, k, v))
+        views = heads_views(q, k, v, num_heads)
+    elif k.shape[1] != q.shape[1]:
+        return None         # grouped queries come as [B, S, H*D] alone
     else:
         views = (q, k, v)
     if unsupported(*views, mask, dropout_keep, window) is not None:
@@ -801,8 +875,8 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         raise ValueError(
             "flash_attention: dropout_keep < 1 requires seed= (an int32 "
             "scalar array; the per-tile dropout masks derive from it)")
-    w = _walk(q, num_heads)
-    dv = _walk(v, num_heads).dim
+    w, wv = _walks(q, k, v, num_heads)
+    dv = wv.dim
     # two head sizes (latent attention: keys 192 wide, values 128) come as
     # [B, H, S, D]; heads read in place are one size
     assert dv == w.dim or q.ndim == 4, (q.shape, v.shape)

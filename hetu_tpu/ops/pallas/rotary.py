@@ -25,11 +25,28 @@ tensors.
 ``hetu_rope_bwd``: the same body with ``sin±`` negated: the transpose of a
 rotation is the rotation by the opposite angle, ``dx_j = g_j cos_j - s_j
 g_(j ^ d/2) sin_j``.  Nothing is kept for the backward pass but the tables.
+
+``q`` and ``k`` have each their own width, any multiple of the head size
+(grouped queries: 64 query heads on 8 key heads); the grid then gets a third
+axis over the lanes, a key head and its query heads a program, and the body
+walks the ``width // d`` heads of its block of each tensor.  A PARTIAL rotation (the first ``r < d`` lanes of a head
+turn, with frequencies over ``r``; the rest pass) keeps whole 128-lane tiles
+too: ``rotate_half`` over ``r`` lanes is two rotations of the head's lanes,
+each with a sine table that is zero where the other one holds,
+
+    y = x cos + roll(x, r / 2) sA + roll(x, d - r / 2) sB
+
+``sA = +sin`` on the lanes ``[r / 2, r)``, ``sB = -sin`` on ``[0, r / 2)``,
+``cos = 1`` and both sines 0 from ``r`` on: tables ``[3, S, d]``.  At ``r = d``
+the two rotations coincide and ``sA + sB`` is ``sin±``, which is the ``[2, S,
+d]`` form above; only ``r < d`` carries the third table.  The backward pass
+negates both sines.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,84 +81,108 @@ def unsupported(q, k, *, head_dim):
         return f"dtype:{types.pop().name}"
     if q.shape[1] % ROWS:
         return f"seq_not_{ROWS}_aligned"
-    if q.shape != k.shape:
-        return "q_k_widths_differ"
     return None
 
 
-def _kernel(q_ref, k_ref, t_ref, qo_ref, ko_ref, *, chunk, backward):
+def _kernel(q_ref, k_ref, t_ref, qo_ref, ko_ref, *, chunk, backward,
+            shift=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    rows, lanes = q_ref.shape
+    rows = q_ref.shape[0]
     d = t_ref.shape[2]
+    # [2, S, d]: every lane of a head turns, one rotation by d / 2 and
+    # ``sin±``; [3, S, d]: the first 2 * shift lanes turn, two rotations
+    shifts = (d // 2,) if t_ref.shape[0] == 2 else (shift, d - shift)
 
     def step(i, carry):
         at = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
-        cos, sin = t_ref[0, at, :], t_ref[1, at, :]
+        cos = t_ref[0, at, :]
+        sines = [t_ref[1 + n, at, :] for n in range(len(shifts))]
         if backward:
-            sin = -sin
+            sines = [-sin for sin in sines]
         for ref, out in ((q_ref, qo_ref), (k_ref, ko_ref)):
-            for h in range(lanes // d):
+            for h in range(ref.shape[1] // d):
                 head = slice(h * d, (h + 1) * d)
                 x = ref[at, head].astype(_F32)
-                out[at, head] = (x * cos + pltpu.roll(x, d // 2, 1) * sin
-                                 ).astype(out.dtype)
+                y = x * cos
+                for by, sin in zip(shifts, sines):
+                    y = y + pltpu.roll(x, by, 1) * sin
+                out[at, head] = y.astype(out.dtype)
         return carry
     jax.lax.fori_loop(0, rows // chunk, step, 0)
 
 
-def _call(name, backward, q, k, tables, interpret, tile, chunk):
+def _call(name, backward, q, k, tables, interpret, tile, chunk, rotary_dim):
     import jax.experimental.pallas as pl
     B, S, W = q.shape
-    d = tables.shape[2]
-    ts = fit(S, max(tile // (W * q.dtype.itemsize), ROWS), ROWS)
-    block = pl.BlockSpec((None, ts, W), lambda s, b: (b, s, 0))
+    n, _, d = tables.shape
+    assert n == (2 if rotary_dim in (None, d) else 3), (n, d, rotary_dim)
+    more = {} if n == 2 else {"shift": rotary_dim // 2}
+    # q and k of different widths (grouped queries): a third grid axis over
+    # the lanes, as many programs as divide both head counts (a key head and
+    # its query heads each, where the key heads divide the query heads).  The
+    # body is unrolled over its block's heads, and one that walked the 64 + 8
+    # of a Laguna window layer took 3.6 s to trace
+    parts = math.gcd(W // d, k.shape[2] // d) if k.shape[2] != W else 1
+    widths = [x.shape[2] // parts for x in (q, k)]
+    ts = fit(S, max(tile // (widths[0] * q.dtype.itemsize), ROWS), ROWS)
+    # q's rows and k's, each as wide as it is; the tables' block stays the
+    # same over the batch and the lanes, so it is fetched once
+    blocks = [pl.BlockSpec((None, ts, w), lambda s, b, g=0: (b, s, g))
+              for w in widths]
     return pl.pallas_call(
         functools.partial(_kernel, chunk=fit(ts, max(chunk, ROWS), ROWS),
-                          backward=backward),
-        name=name, grid=(S // ts, B),
-        in_specs=[block, block,
-                  pl.BlockSpec((2, ts, d), lambda s, b: (0, s, 0))],
-        out_specs=[block, block],
+                          backward=backward, **more),
+        name=name, grid=(S // ts, B) + ((parts,) if parts > 1 else ()),
+        in_specs=blocks + [pl.BlockSpec((n, ts, d),
+                                        lambda s, b, g=0: (0, s, 0))],
+        out_specs=blocks,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype)],
-        compiler_params=params(interpret, ("parallel", "parallel")),
+        compiler_params=params(
+            interpret, ("parallel",) * (2 + (parts > 1))),
         interpret=interpret,
     )(q, k, tables)
 
 
-_STATIC = ("interpret", "tile", "chunk")
+_STATIC = ("interpret", "tile", "chunk", "rotary_dim")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def hetu_rope_fwd(q, k, tables, *, interpret, tile=TILE, chunk=CHUNK):
-    """``q``, ``k [B, S, H d]`` and ``tables [2, S, d]`` f32 (``cos``,
-    ``sin±``) -> the rotated ``(q, k)`` in their type."""
-    return _call("hetu_rope_fwd", False, q, k, tables, interpret, tile, chunk)
+def hetu_rope_fwd(q, k, tables, *, interpret, tile=TILE, chunk=CHUNK,
+                  rotary_dim=None):
+    """``q [B, S, H d]``, ``k [B, S, KV d]`` and the f32 ``tables [2, S, d]``
+    (``cos``, ``sin±``), or ``[3, S, d]`` (``cos``, ``sA``, ``sB``) where only
+    the first ``rotary_dim < d`` lanes of a head turn -> the rotated ``(q,
+    k)`` in their type."""
+    return _call("hetu_rope_fwd", False, q, k, tables, interpret, tile, chunk,
+                 rotary_dim)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def hetu_rope_bwd(dq, dk, tables, *, interpret, tile=TILE, chunk=CHUNK):
+def hetu_rope_bwd(dq, dk, tables, *, interpret, tile=TILE, chunk=CHUNK,
+                  rotary_dim=None):
     """The cotangents of ``hetu_rope_fwd``'s ``q``, ``k`` from its
     results'."""
     return _call("hetu_rope_bwd", True, dq, dk, tables, interpret, tile,
-                 chunk)
+                 chunk, rotary_dim)
 
 
-@jax.custom_vjp
-def rope(q, k, tables):
-    """The rotation through the kernel pair: ``q``, ``k [B, S, H d]``,
-    ``tables [2, S, d]`` -> ``(q, k)`` rotated."""
-    return tuple(hetu_rope_fwd(q, k, tables,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rope(q, k, tables, rotary_dim=None):
+    """The rotation through the kernel pair: ``q [B, S, H d]``, ``k [B, S,
+    KV d]``, ``tables`` (``hetu_rope_fwd``) -> ``(q, k)`` rotated."""
+    return tuple(hetu_rope_fwd(q, k, tables, rotary_dim=rotary_dim,
                                interpret=dispatch.interpret()))
 
 
-def _rope_fwd(q, k, tables):
-    return rope(q, k, tables), tables
+def _rope_fwd(q, k, tables, rotary_dim):
+    return rope(q, k, tables, rotary_dim), tables
 
 
-def _rope_bwd(tables, g):
-    dq, dk = hetu_rope_bwd(*g, tables, interpret=dispatch.interpret())
+def _rope_bwd(rotary_dim, tables, g):
+    dq, dk = hetu_rope_bwd(*g, tables, rotary_dim=rotary_dim,
+                           interpret=dispatch.interpret())
     return dq, dk, None                 # the tables: no gradient
 
 

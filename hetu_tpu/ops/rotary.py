@@ -14,20 +14,25 @@ d]`` view (``seq_axis=1``) the Ouro cell's traced step (ledger, PR 47) spent
 about 230 ms of 1,421 on it, 96 times a step: the f32 reshapes between ``[..,
 H d]`` and ``[.., H, d]`` are passes over HBM, the two halves of a 128-lane
 head are sliced, negated, concatenated and padded, and the tables are
-broadcast at every call.  So a multi-head attention layer hands q and k
-together to ``rotary_pair_op``, which on a TPU runs them through the kernel
-pair ``hetu_rope_fwd`` / ``hetu_rope_bwd`` (``ops/pallas/rotary.py``: on the
-flat ``[B, S, H d]``, one read and one write a tensor) where a head is whole
-lane tiles, q and k are both bf16 or both f32 and as wide as each other and the
-sequence is a multiple of 16, and reads the tables from ONE node a model,
-sequence length, head size and base (``RopeTables``).  Each call counts its choice
-in ``hetu_kernel_choice_total{kernel="rotary", impl, reason}``: ``pallas``, or
-``jnp`` with ``head_dim_not_128_aligned``, ``dtype:<name>``, ``dtype:mixed``,
-``seq_not_16_aligned`` or ``q_k_widths_differ``; what a mesh and a platform
-without Mosaic mean is ``dispatch.take``'s rule, and ``_rotary(seq_axis=1)``
-then runs on each tensor's view.  ``rotary_embedding_op`` (grouped-query and
-partial-rotary layers on ``[B, H, S, D]``, latent attention's ``_rope_last``)
-is ``_rotary`` everywhere.
+broadcast at every call.  So a multi-head attention layer on the flat path
+(``layers/attention.py MultiHeadAttention.layout``) hands q and k together to
+``rotary_pair_op``, which on a TPU runs them through the kernel pair
+``hetu_rope_fwd`` / ``hetu_rope_bwd`` (``ops/pallas/rotary.py``: on the flat
+``[B, S, H d]`` and ``[B, S, KV d]``, one read and one write a tensor) where a
+head is whole lane tiles, q and k are both bf16 or both f32 and the sequence
+is a multiple of 16, and reads the tables from ONE node a model, sequence
+length, head size, base, scaling and count of lanes that turn
+(``RopeTables``).  q and k have each their own width (grouped queries), and a
+partial rotation (``rotary_dim`` < head size) keeps the kernels: its tables are
+``[3, S, d]`` (``_pair_tables``).  Each call counts its choice in
+``hetu_kernel_choice_total{kernel="rotary", impl, reason}``: ``pallas``, or
+``jnp`` with ``head_dim_not_128_aligned``, ``dtype:<name>``, ``dtype:mixed`` or
+``seq_not_16_aligned``; what a mesh and a platform without Mosaic mean is
+``dispatch.take``'s rule, and ``_rotary(seq_axis=1)`` then runs on each
+tensor's view.  ``rotary_embedding_op`` (the layers that stay on ``[B, H, S,
+D]``: a norm a head, the elementwise gate, grouped queries on heads that are
+not whole lane tiles; latent attention's ``_rope_last``) is ``_rotary``
+everywhere.
 
 Conventions match huggingface's ``rotate_half`` (non-interleaved halves),
 so HF Llama checkpoints import bit-tight (tests/test_torch_parity.py).
@@ -117,12 +122,26 @@ def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
 rotary_embedding_op = simple_op(_rotary, "rotary_embedding")
 
 
-def _pair_tables(*, seq_len, dim, theta, scaling=None):
+def _pair_tables(*, seq_len, dim, theta, scaling=None, rotary_dim=None):
     """``[2, S, D]`` f32: ``cos`` and ``sin±``, the sine with
     ``rotate_half``'s sign on it (``-sin`` on the first ``D / 2`` lanes), so
-    that ``rotate_half(x) sin = roll(x, D / 2) sin±``."""
-    cos, sin = _rope_tables(seq_len, dim, theta, scaling=scaling)
-    return jnp.stack([cos, jnp.where(jnp.arange(dim) < dim // 2, -sin, sin)])
+    that ``rotate_half(x) sin = roll(x, D / 2) sin±``.  Where only the first
+    ``rotary_dim = r < D`` lanes turn (frequencies over ``r``), ``[3, S, D]``:
+    ``cos`` (1 from ``r`` on), ``sA`` (``+sin`` on ``[r / 2, r)``) and ``sB``
+    (``-sin`` on ``[0, r / 2)``), zero elsewhere: ``roll(x, r / 2) sA +
+    roll(x, D - r / 2) sB`` (``ops/pallas/rotary.py``)."""
+    r = rotary_dim or dim
+    cos, sin = _rope_tables(seq_len, r, theta, scaling=scaling)
+    if r == dim:
+        return jnp.stack(
+            [cos, jnp.where(jnp.arange(dim) < dim // 2, -sin, sin)])
+    assert 0 < r < dim and r % 2 == 0, (r, dim)
+    rest = ((0, 0), (0, dim - r))
+    lane = jnp.arange(r)
+    return jnp.stack(
+        [jnp.pad(cos, rest, constant_values=1.0),
+         jnp.pad(jnp.where(lane >= r // 2, sin, 0.0), rest),
+         jnp.pad(jnp.where(lane < r // 2, -sin, 0.0), rest)])
 
 
 _pair_tables_op = simple_op(_pair_tables, "rope_tables")
@@ -130,7 +149,8 @@ _pair_tables_op = simple_op(_pair_tables, "rope_tables")
 
 class RopeTables:
     """The ``_pair_tables`` nodes of one model: ONE a sequence length, head
-    size, base, scaling (``yarn_scaling``; None: plain) and pipeline stage,
+    size, base, scaling (``yarn_scaling``; None: plain), count of lanes that
+    turn (``rotary_dim``; None: all) and pipeline stage,
     made for the layer that asks first and read by every layer (and every application of a layer) after it, outside any
     ``ht.remat()`` group, which then reads it as an input.  An attention layer
     has its own unless its model hands all its layers one
@@ -139,13 +159,18 @@ class RopeTables:
     def __init__(self):
         self.nodes = {}
 
-    def __call__(self, seq_len, dim, theta, scaling=None):
-        key = (seq_len, dim, float(theta), current_stage()) + (
-            () if scaling is None else (scaling,))
+    def __call__(self, seq_len, dim, theta, scaling=None, rotary_dim=None):
+        # what a plain, whole rotation does not have is not among its
+        # node's attributes
+        more = {name: value for name, value in (
+            ("scaling", scaling),
+            ("rotary_dim", None if rotary_dim == dim else rotary_dim))
+            if value is not None}
+        key = (seq_len, dim, float(theta), current_stage()) + tuple(
+            more.items())
         if key not in self.nodes:
             node = self.nodes[key] = _pair_tables_op(
-                seq_len=seq_len, dim=dim, theta=key[2],
-                **({} if scaling is None else {"scaling": scaling}))
+                seq_len=seq_len, dim=dim, theta=key[2], **more)
             node.remat_scope = None
         return self.nodes[key]
 
@@ -161,7 +186,7 @@ class RotaryPairOp(SimpleOp):
         q, k = (x.reshape(-1, seq_len, x.shape[-1]) for x in (q, k))
         if dispatch.take("rotary", ctx.mesh,
                          kernels.unsupported(q, k, head_dim=d)):
-            return kernels.rope(q, k, tables)
+            return kernels.rope(q, k, tables, self.attrs.get("rotary_dim"))
         return tuple(
             self.impl(x.reshape(*x.shape[:2], -1, d), seq_axis=1,
                       **self.attrs).reshape(x.shape) for x in (q, k))
@@ -172,11 +197,11 @@ pair_item_op = simple_op(lambda pair, *, index: pair[index], "pair_item")
 
 
 def rotary_pair_op(q, k, tables):
-    """The nodes of q and k ``[B, S, H d]`` rotated, both from one node;
-    ``tables``: a ``RopeTables`` node of their sequence length, head size and
-    base."""
+    """The nodes of q ``[B, S, H d]`` and k ``[B, S, KV d]`` rotated, both
+    from one node; ``tables``: a ``RopeTables`` node of their sequence length,
+    head size, base, scaling and lanes that turn."""
     pair = _rotary_pair_op(q, k, tables, **{
-        key: tables.attrs[key] for key in ("theta", "scaling")
+        key: tables.attrs[key] for key in ("theta", "scaling", "rotary_dim")
         if key in tables.attrs})
     return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
 

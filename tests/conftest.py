@@ -139,6 +139,17 @@ def lowered_for_tpu(monkeypatch, build, debug_info=False):
         jax.clear_caches()
 
 
+def rotary_kernels_asked(monkeypatch):
+    """The ``rotary_pair`` node asks for its kernels as it does on a TPU, and
+    gets them in interpret mode (``dispatch.take(asked=True)``)."""
+    import types
+    from hetu_tpu.ops import rotary
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(rotary, "dispatch", types.SimpleNamespace(
+        take=lambda kernel, mesh, why:
+        dispatch.take(kernel, mesh, why, asked=True)))
+
+
 def without_locations(text):
     """A program's text with nothing left that a moved source line moves.
     Of a lowered program (``as_text()``, with or without ``debug_info``): the

@@ -867,6 +867,80 @@ def test_rotary_kernels_compile_for_v5e(v5e, as_on_tpu, shape, dtype):
         assert not re.findall(rf" = f32\[{B},{S},{W}\]\S* ", entry)
 
 
+@pytest.mark.parametrize("heads,kv,rotary_dim", [
+    (64, 8, None),          # Laguna's window layers: every lane turns
+    (48, 8, 64),            # its full layers: YaRN's tables on half a head
+])
+def test_grouped_rotary_kernels_compile_for_v5e(v5e, as_on_tpu, heads, kv,
+                                                rotary_dim):
+    """q ``[1, 8192, heads x 128]`` and k ``[1, 8192, 8 x 128]`` in one call,
+    forward and backward, each block as wide as its tensor; the partial
+    rotation with its third table; nothing by heads and nothing f32 of q's
+    shape in HBM around them."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops.pallas import rotary
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    q, k = sds((1, 8192, heads * 128)), sds((1, 8192, kv * 128))
+    assert rotary.unsupported(q, k, head_dim=128) is None
+
+    def loss(q, k, tables):
+        a, b = rotary.rope(q, k, tables, rotary_dim)
+        assert a.shape == q.shape and b.shape == k.shape
+        return (jnp.sum(a.astype(jnp.float32) ** 2)
+                + jnp.sum(b.astype(jnp.float32) ** 2))
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        q, k, sds((2 if rotary_dim is None else 3, 8192, 128), jnp.float32)
+    ).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert "hetu_rope_fwd" in kernels[0] and "hetu_rope_bwd" in kernels[1]
+    entry = hlo[hlo.index("\nENTRY "):]
+    assert not re.findall(r" = \w+\[1,8192,\d+,128\]\S* ", entry)
+    assert not re.findall(rf" = f32\[1,8192,{heads * 128}\]\S* ", entry)
+
+
+@pytest.mark.parametrize("heads,kv,window", [
+    (48, 8, None),          # Laguna's full layers
+    (64, 8, 512),           # its window layers: the band cut out by element
+    (32, 2, None),          # Nemotron-H's attention layer
+])
+def test_grouped_keys_in_place_compile_for_v5e(v5e, as_on_tpu, heads, kv,
+                                               window):
+    """K and V ``[1, 8192, kv x 128]`` read in place under ``heads`` query
+    heads: two kernels, dK and dV a query head out of the backward one and
+    each group added up on slices of whole lane tiles: no array by heads, no
+    K or V repeated in HBM before the kernels."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
+    q, k = sds(1, 8192, heads * 128), sds(1, 8192, kv * 128)
+    name = "hetu_flash" if window is None else "hetu_swa"
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, num_heads=heads,
+            window=window).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile()
+    hlo = compiled.as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert f"{name}_fwd" in kernels[0] and f"{name}_bwd" in kernels[1]
+    fwd_in = kernels[0].split("custom-call(")[1]
+    assert f"bf16[1,8192,{kv * 128}]" in fwd_in
+    entry = hlo[hlo.index("\nENTRY "):]
+    assert not re.findall(r" = \w+\[1,(?:8192,\d+|\d+,8192),128\]\S* ",
+                          entry)
+    # q, o, dO, dq, dK and dV a query head, K, V and their gradients
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * (
+        8192 * heads * 128 * 2)
+
+
 @pytest.mark.parametrize("dp", [1, 4])
 def test_dropout_mask_compiles_for_v5e_on_each_shard(v5e, as_on_tpu, dp):
     """BERT's hidden dropout, forward and backward, on one chip and under

@@ -281,7 +281,7 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
         setattr(node, held, lambda *a, **k: (
             called.append("jnp") or plain(*a, **k)))
         stub(monkeypatch, kernel, called, "pallas",
-             like=lambda: (lambda q, k, t: (q, k)) if label == "rotary"
+             like=lambda: (lambda q, k, t, r=None: (q, k)) if label == "rotary"
              else lambda o, *a, **k: o)
     elif scope == "hetu_kda_scan":      # the in-place entry: [B, S, H d]
         stub(monkeypatch, form, called, "jnp")

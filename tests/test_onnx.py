@@ -196,21 +196,23 @@ def test_onnx_bytes_roundtrip_seq2seq(rng):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("kv_heads,tiles", [
-    (2, 4),        # grouped-query: the [B, H, S, D] graph, K/V tiled
-    (4, None),     # multi-head: attention on [B, S, H*D], RoPE on its view
+@pytest.mark.parametrize("kv_heads,tiles,hidden", [
+    (2, 4, 16),     # grouped-query: the [B, H, S, D] graph, K/V tiled
+    (4, None, 16),  # multi-head: attention on [B, S, H*D], RoPE on its view
+    (2, 4, 512),    # grouped-query on heads of 128: [B, S, H*D] with K and
+                    # V [B, S, KV*D], tiled where the attention is exported
 ])
-def test_onnx_bytes_roundtrip_llama(rng, kv_heads, tiles):
+def test_onnx_bytes_roundtrip_llama(rng, kv_heads, tiles, hidden):
     """Llama tier through ModelProto bytes: RMSNorm, RoPE (constant
     cos/sin tables + Slice/Neg/Concat rotation), GQA repeat_kv
     (Reshape/Tile/Reshape), SwiGLU — all as standard opset ops, so any
     ONNX consumer can run the modern-LLM tier."""
     from hetu_tpu.models import LlamaConfig, LlamaForCausalLM
-    c = LlamaConfig(vocab_size=64, hidden_size=16, num_layers=2,
+    c = LlamaConfig(vocab_size=64, hidden_size=hidden, num_layers=2,
                     num_heads=4, num_kv_heads=kv_heads,
                     intermediate_size=32, seq_len=8)
     ids = ht.placeholder_op("llx_ids", (2, 8), dtype=np.int32)
-    logits = LlamaForCausalLM(c, name=f"llx{kv_heads}")(ids)
+    logits = LlamaForCausalLM(c, name=f"llx{kv_heads}_{hidden}")(ids)
     ex = ht.Executor({"inference": [logits]})
     model = hx.deserialize_model(
         hx.serialize_model(hx.hetu2onnx([logits], ex.params)))

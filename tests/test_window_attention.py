@@ -96,6 +96,57 @@ def test_in_place_heads_take_a_window_too():
     assert np.abs(got - flat(masked(q, k, v, 100))).max() < 2e-5
 
 
+@pytest.mark.parametrize("rep", [1, 6, 8])
+@pytest.mark.parametrize("seq,window", [(512, None), (2048, 512)])
+def test_key_heads_read_in_place_are_the_repeated_ones(live_registry, seq,
+                                                       window, rep):
+    """q ``[B, S, H d]`` on k, v ``[B, S, KV d]`` (two key heads of 128, each
+    under ``rep`` query heads), causal and under the published window (its
+    band cut out by element at the planned blocks), against the same kernels
+    on ``[B, H, S, D]`` behind ``repeat_kv``: the context and dq, dk, dv, the
+    last two summed over each group outside the backward kernel."""
+    kv, d = 2, 128
+    heads = kv * rep
+    r = np.random.default_rng(rep)
+    q = jnp.asarray(r.normal(0, 1, (1, heads, seq, d)), jnp.float32)
+    k, v = (jnp.asarray(r.normal(0, 1, (1, kv, seq, d)), jnp.float32)
+            for _ in range(2))
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(1, seq, -1)
+
+    def repeated(q, k, v):
+        return flat(fa.flash_attention(
+            q, rotary._repeat_kv(k, n_rep=rep), rotary._repeat_kv(v, n_rep=rep),
+            causal=True, window=window))
+
+    def in_place(q, k, v):
+        return fa.flash_attention(flat(q), flat(k), flat(v), causal=True,
+                                  window=window, num_heads=heads)
+    before = fa.entries()
+    want, got = repeated(q, k, v), in_place(q, k, v)
+    assert got.shape == (1, seq, heads * d)
+    assert np.abs(got - want).max() < 1e-6
+    layout = ("bshd" + ("" if window is None else f"_w{window}")
+              + ("" if rep == 1 else f"_kv{kv}"), 1)
+    assert fa.entries().get(layout, 0) == before.get(layout, 0) + 1
+    gw = jax.grad(lambda *a: weigh(repeated(*a)), (0, 1, 2))(q, k, v)
+    gg = jax.grad(lambda *a: weigh(in_place(*a)), (0, 1, 2))(q, k, v)
+    for what, a, b in zip(("dq", "dk", "dv"), gg, gw):
+        assert a.shape == b.shape, what
+        assert np.abs(a - b).max() < 2e-5 * max(1.0, np.abs(b).max()), what
+
+
+def test_grouped_keys_come_in_place_alone():
+    """``[B, H, S, D]`` callers (the ring, Galvatron) own one head count; key
+    heads narrower than whole lane tiles are not cut out in place."""
+    q = jnp.zeros((1, 4, 256, 64))
+    assert fa.flash_attention(q, q[:, :2], q[:, :2], causal=True) is None
+    flat = jnp.zeros((1, 256, 4 * 64))
+    assert fa.flash_attention(flat, flat[..., :128], flat[..., :128],
+                              causal=True, num_heads=4) is None
+    assert fa.flash_attention(flat, flat, flat, causal=True,
+                              num_heads=4) is not None
+
+
 def test_a_window_that_holds_every_key_is_no_window(live_registry):
     q, k, v = operands(2, 2, 256)
     before = fa.entries()
