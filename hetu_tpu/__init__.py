@@ -10,6 +10,9 @@ are Pallas.  See SURVEY.md for the reference structural map this follows.
 
 from __future__ import annotations
 
+import time as _time
+_import_began = _time.perf_counter()   # telemetry's hetu_import_seconds
+
 import numpy as np
 
 from .graph import (Op, PlaceholderOp, VariableOp, find_topo_sort,
@@ -38,6 +41,11 @@ from . import graphboard
 from .launcher import DistConfig, launch, launch_local, initialize_from_env
 
 __version__ = "0.1.0"
+
+#: seconds this package's import took (jax's own import included where
+#: this was the first to ask for it): ``hetu_import_seconds`` once
+#: ``telemetry.enable()`` runs
+import_seconds = _time.perf_counter() - _import_began
 
 
 def placeholder_op(name, shape=None, dtype=np.float32, trainable=False):

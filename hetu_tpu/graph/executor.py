@@ -32,6 +32,7 @@ from .node import (Op, PlaceholderOp, VariableOp, find_topo_sort,
                    graph_variables, named_scope)
 from .trace import TraceContext, evaluate
 from .. import telemetry as _telemetry
+from ..telemetry.steps import StepWatch
 
 
 def _changed_state_only(step_fn):
@@ -136,7 +137,8 @@ class SubExecutor:
             "steps)", labels=("subgraph",)).labels(subgraph=name)
         self._m_step_time = reg.histogram(
             "hetu_executor_step_seconds",
-            "Wall time of one run() call, the duration of its root span "
+            "Wall time of one run() or run_steps() call, the duration of "
+            "its root span "
             "(feed prep + dispatch + guard check, and the fetch when the "
             "caller asks for numpy values; without it device completion "
             "is asynchronous)",
@@ -173,6 +175,20 @@ class SubExecutor:
             "and optimiser state as donated arguments (updated in place, "
             "no fresh output buffers a step), 0 when it leaves them alive",
             labels=("subgraph",)).labels(subgraph=name)
+        self._m_stalls = reg.counter(
+            "hetu_executor_step_stalls_total",
+            "run roots that closed as a stall (telemetry/steps.py: over "
+            "the running median by a quarter of it and by 50 ms, or "
+            "carrying an XLA event once the median stands), by the phase "
+            "with the largest excess over its own median (h2d, dispatch, "
+            "fetch, run_self) and the cause (xla, paging, runq, host_cpu, "
+            "blocked)", labels=("subgraph", "phase", "cause"))
+        self._m_excess = reg.counter(
+            "hetu_executor_step_excess_seconds_total",
+            "Seconds of run roots over the running median of the last 64 "
+            "that carried no XLA event, every root",
+            labels=("subgraph",)).labels(subgraph=name)
+        self._watch = StepWatch()
         self._tr = _telemetry.get_tracer()
 
     def ps_synchronize(self):
@@ -537,31 +553,54 @@ class SubExecutor:
         self._m_h2d_bytes.inc(sum(v.nbytes for v in host.values()))
         return jax.device_put(host, shardings)
 
-    def _root_span(self):
-        """The ``run`` root of one call: every phase span below is its
-        child and carries its key, ``<subgraph>:<global step>``; in a
-        ``jax.profiler`` capture it is a step marker."""
+    def _rooted(self, impl, *args):
+        """``impl(*args)`` under the ``run`` root of one call: every phase
+        span below is its child and carries its key, ``<subgraph>:<global
+        step>``; in a ``jax.profiler`` capture it is a step marker.  The
+        root carries the thread's OS account and whatever XLA did beneath
+        it (``telemetry/tracing.py``); when it closes, its wall time goes
+        to the step histogram and to the subgraph's :class:`StepWatch`,
+        which names a stalled step."""
         step = self.executor._global_step
-        return self._tr.span("run", key=f"{self.name}:{step}", step=step)
+        root = self._tr.span("run", key=f"{self.name}:{step}", step=step,
+                             account=True)
+        try:
+            with root:
+                return impl(*args)
+        finally:
+            if root.kids is not None:   # not a tracer switched off since
+                self._close_root(root)
+
+    def _close_root(self, root):
+        self._m_step_time.observe(root.dur)
+        excess, stall = self._watch.close(root.dur, root.kids, root.extra)
+        if excess:
+            self._m_excess.inc(excess)
+        if stall is not None:
+            self._m_stalls.labels(subgraph=self.name, phase=stall["phase"],
+                                  cause=stall["cause"]).inc()
+            _telemetry.get_flight().record(
+                {"type": "step_stall", "subgraph": self.name,
+                 "key": root.key, "start_s": root.start,
+                 "wall_s": root.dur, **stall, "account": root.extra})
 
     def run(self, feed_dict=None, convert_to_numpy_ret_vals=False):
         if not self._tr.enabled:
             return self._run_impl(feed_dict, convert_to_numpy_ret_vals)
-        root = self._root_span()
         try:
-            with root:
-                return self._run_impl(feed_dict,
-                                      convert_to_numpy_ret_vals)
+            return self._rooted(self._run_impl, feed_dict,
+                                convert_to_numpy_ret_vals)
         finally:
             self._m_steps.inc()
-            self._m_step_time.observe(root.dur)
 
     def _run_impl(self, feed_dict, convert_to_numpy_ret_vals):
         if self._jitted is None:
-            # "compile" phase: program construction (graph walk + jit
-            # wrapper build) — the goodput ledger's compile bucket.
-            # XLA's lazy trace/compile on the first dispatch still
-            # lands in that step's dispatch/device residual.
+            # "compile" span: program construction alone (the graph walk
+            # and the jit wrapper, milliseconds).  XLA's trace, lowering
+            # and compile (or cache read) happen inside the first
+            # dispatch; jax.monitoring reports them and they land in this
+            # root's ``extra`` as xla_* (telemetry/tracing.py), from where
+            # the goodput ledger adds them to its compile bucket.
             with self._tr.span("compile"):
                 self._build()
         feeds, ps_ids = self._feeds(feed_dict)
@@ -800,9 +839,11 @@ class SubExecutor:
         body."""
         if n < 1:
             raise ValueError(f"run_steps needs n >= 1, got {n}")
-        with self._root_span():
+        if not self._tr.enabled:
             return self._run_steps_impl(feed_dict, n,
                                         convert_to_numpy_ret_vals)
+        return self._rooted(self._run_steps_impl, feed_dict, n,
+                            convert_to_numpy_ret_vals)
 
     def _run_steps_impl(self, feed_dict, n, convert_to_numpy_ret_vals):
         if self._jitted is None:
@@ -1035,6 +1076,17 @@ class Executor:
                  **kwargs):
         if isinstance(eval_node_dict, (list, tuple)):
             eval_node_dict = {"default": list(eval_node_dict)}
+        # set-up's other large part beside the first steps: the graph
+        # walk, parameter initialisation and upload, under one accounted
+        # root that also takes the XLA phases of the initialisers
+        with _telemetry.get_tracer().span(
+                "executor_init", key="+".join(eval_node_dict),
+                account=True):
+            self._init(eval_node_dict, ctx, seed, mesh, dist_strategy,
+                       comm_mode, compute_dtype, kwargs)
+
+    def _init(self, eval_node_dict, ctx, seed, mesh, dist_strategy,
+              comm_mode, compute_dtype, kwargs):
         self.eval_node_dict = {k: list(v) for k, v in eval_node_dict.items()}
         self.mesh = mesh
         self.comm_mode = comm_mode

@@ -39,8 +39,9 @@ from .registry import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                        JsonlWriter, MetricsRegistry, MetricsServer,
                        start_http_server)
 from .request_trace import EVENT_TYPES, RequestTrace
+from . import steps as _steps
 from .timeseries import TimeSeriesStore
-from .tracing import NULL_SPAN, SpanTracer
+from .tracing import NULL_SPAN, XLA_EVENTS, SpanTracer
 
 __all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram",
            "JsonlWriter", "MetricsServer", "SpanTracer", "NULL_SPAN",
@@ -56,7 +57,7 @@ __all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram",
            "get_flight", "get_hbm_ledger", "get_profiler",
            "get_timeseries", "get_alerts", "get_goodput",
            "enabled", "enable", "disable", "shutdown",
-           "report", "step_phase_report", "chrome_trace"]
+           "report", "step_phase_report", "step_report", "chrome_trace"]
 
 _registry = MetricsRegistry(enabled=False)
 _tracer = SpanTracer(capacity=65536, enabled=False)
@@ -147,6 +148,51 @@ def _slo_block():
     return control.slo_report()
 
 
+def _on_xla(event, value=1, **_):
+    """The one ``jax.monitoring`` listener (durations and plain events):
+    XLA's phases go under the root span open on the calling thread
+    (``SpanTracer.xla_event``) and into two counters for scrapes."""
+    heard = _tracer.xla_event(event, value)
+    if heard is None:
+        return
+    field, grew = heard
+    if field.endswith("_s"):
+        _registry.counter(
+            "hetu_xla_seconds_total",
+            "Seconds of XLA's own phases as jax.monitoring reports them, "
+            "inner events inside an outer one counted once: trace "
+            "(jaxpr), lower (to MLIR), compile (backend compile, or the "
+            "read from the persistent cache, with the executable's "
+            "load), cache_load (the cache's retrieval alone, inside "
+            "compile)", labels=("phase",)).labels(
+                phase=field[len("xla_"):-len("_s")]).inc(max(grew, 0.0))
+    else:
+        _registry.counter(
+            "hetu_xla_cache_total",
+            "Programs asked of jax's persistent compilation cache, by "
+            "result (hits, misses)", labels=("result",)).labels(
+                result=field[len("xla_cache_"):]).inc(grew)
+
+
+_listening = False
+
+
+def _listen(on):
+    """Register (or take away) the two ``jax.monitoring`` listeners;
+    enabling twice registers once, and nothing listens while disabled."""
+    global _listening
+    if on == _listening:
+        return
+    from jax import monitoring
+    if on:
+        monitoring.register_event_duration_secs_listener(_on_xla)
+        monitoring.register_event_listener(_on_xla)
+    else:
+        monitoring.unregister_event_duration_listener(_on_xla)
+        monitoring.unregister_event_listener(_on_xla)
+    _listening = on
+
+
 def enable(http_port=None, host="127.0.0.1", incident_dir=None):
     """Turn instruments live; optionally start the HTTP exporter
     (``http_port=0`` binds an ephemeral port) and point the flight
@@ -155,6 +201,13 @@ def enable(http_port=None, host="127.0.0.1", incident_dir=None):
     global _server
     _registry.enable()
     _tracer.enabled = True
+    _listen(True)
+    import hetu_tpu
+    _registry.gauge(
+        "hetu_import_seconds",
+        "Seconds `import hetu_tpu` took in this process (jax's import "
+        "included where the package was the first to ask for it)"
+        ).set(getattr(hetu_tpu, "import_seconds", 0.0))
     _request_trace.enabled = True
     _flight.enabled = True
     _timeseries.enabled = True
@@ -184,6 +237,7 @@ def disable():
     """Freeze instruments (references stay valid; state is retained)."""
     _registry.disable()
     _tracer.enabled = False
+    _listen(False)
     _request_trace.enabled = False
     _flight.enabled = False
     _timeseries.enabled = False
@@ -283,18 +337,32 @@ def step_phase_report(registry=None, tracer=None):
             "spans_dropped": tr.dropped}
 
 
+def step_report(subgraph=None, since=None, until=None, tracer=None,
+                say=None):
+    """What lay beneath the ``run`` roots of the process ring: steps and
+    their wall quantiles, the stalled steps with phase, cause and OS
+    account, the run second by second, the XLA phases of the first steps,
+    of ``executor_init`` and outside any span, the host.  See
+    :func:`hetu_tpu.telemetry.steps.step_report`; None where the ring
+    dropped spans."""
+    return _steps.step_report(tracer if tracer is not None else _tracer,
+                              subgraph=subgraph, since=since, until=until,
+                              say=say)
+
+
 def report(registry=None, tracer=None):
     """Everything ``--telemetry`` appends to a bench detail JSON: the
     registry snapshot (with ring-occupancy/drop gauges synced first),
-    the step-phase breakdown, the raw per-span aggregates (serving
-    phases etc. that aren't executor steps), and the request-trace /
-    incident summary."""
+    the step-phase breakdown, the steps' report (:func:`step_report`),
+    the raw per-span aggregates (serving phases etc. that aren't
+    executor steps), and the request-trace / incident summary."""
     reg = registry if registry is not None else _registry
     tr = tracer if tracer is not None else _tracer
     if reg is _registry:
         _sync_loss_gauges(reg, tr)
     return {"registry": reg.snapshot(),
             "phases": step_phase_report(reg, tr),
+            "steps": step_report(tracer=tr),
             "spans": {k: {"total_s": round(v["total_s"], 6),
                           "count": v["count"],
                           "mean_s": round(v["mean_s"], 9)}

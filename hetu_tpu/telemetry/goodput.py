@@ -14,7 +14,11 @@ Buckets (:data:`GOODPUT_BUCKETS`):
 * useful — ``useful_train`` (executor step time minus compile and
   guard-tripped steps), ``useful_prefill`` / ``useful_decode``
   (serving span time minus failover replay);
-* lost, by mechanism — ``compile`` (program build span),
+* lost, by mechanism — ``compile`` (the executor's ``compile`` span,
+  which is program construction alone, plus XLA's own trace, lowering
+  and compile or cache read as ``jax.monitoring`` reports them under
+  ``run`` roots, where they are taken out of the step time, and under
+  ``executor_init``: the roots' ``xla_s``, ``telemetry/tracing.py``),
   ``data_wait`` (input stall spans), ``checkpoint_save`` /
   ``checkpoint_restore`` (histograms), ``rollback`` (guard-tripped
   step time + the rollback-restore span), ``failover_replay``
@@ -118,6 +122,7 @@ class GoodputLedger:
     def _sinks(self):
         snap = self._registry.snapshot() if self._registry else {}
         agg = self._tracer.aggregate() if self._tracer else {}
+        xla = self._tracer.xla_seconds() if self._tracer else {}
 
         def span(n):
             return float(agg.get(n, {}).get("total_s", 0.0))
@@ -128,6 +133,10 @@ class GoodputLedger:
             "train_by": _by_label(snap, "hetu_executor_step_seconds",
                                   field="sum"),
             "compile": span("compile"),
+            # XLA's phases inside step roots (part of train_wall) and
+            # inside executor_init (part of no step)
+            "xla_run": float(xla.get("run", 0.0)),
+            "xla_init": float(xla.get("executor_init", 0.0)),
             "data_wait": span("data_wait") + span("prefetch_h2d"),
             "ckpt_save": _hsum(snap, "hetu_checkpoint_save_seconds"),
             "restore": _hsum(snap, "hetu_checkpoint_restore_seconds"),
@@ -195,11 +204,13 @@ class GoodputLedger:
         chips = self.chips if chips is None else int(chips)
         cap = wall * chips
 
-        # training: step wall minus the compile span it contains, minus
-        # guard-tripped steps (each trip wasted ~one mean step)
+        # training: step wall minus the compile span and the XLA phases
+        # it contains, minus guard-tripped steps (each trip wasted ~one
+        # mean step)
         mean_step = (d["train_wall"] / d["train_steps"]
                      if d["train_steps"] else 0.0)
-        train_pool = max(0.0, d["train_wall"] - d["compile"])
+        in_steps = d["compile"] + d["xla_run"]
+        train_pool = max(0.0, d["train_wall"] - in_steps)
         tripped = min(train_pool, d["guard_trips"] * mean_step)
         useful_train = train_pool - tripped
         # rollback = tripped step time + the measured restore span; the
@@ -226,7 +237,7 @@ class GoodputLedger:
             "useful_train": useful_train,
             "useful_prefill": useful_prefill,
             "useful_decode": useful_decode,
-            "compile": d["compile"],
+            "compile": in_steps + d["xla_init"],
             "data_wait": d["data_wait"],
             "checkpoint_save": ckpt_save,
             "checkpoint_restore": ckpt_restore,
