@@ -17,9 +17,13 @@ readers ``metrics/*.laguna.py``, ``metrics/window_attn_*.py``.
 
 Two kinds of attention layer, two head counts: the FULL layers' kernels go by
 ``hetu_flash_*`` and are what ``expected_kernel_shapes`` states
-(``flash_dims``, ``attention_layers``: the harness holds every flash event to
-one shape); the WINDOW layers' go by ``hetu_swa_*``, are held by name in
-``KERNELS`` and stated under ``window_dims`` for their own reader.
+(``flash_dims``, ``attention_passes``, ``attention_layers``: the harness holds
+every flash event to one shape, and the forward calls of a step between the
+required passes and a whole recomputation's); the WINDOW layers' go by
+``hetu_swa_*``, are held by name in ``KERNELS`` and stated under
+``window_dims`` for their own reader.  Both kernel pairs read the key heads in
+place on the projections' ``[B, S, H d]`` (since PR 52: nothing is repeated
+before a kernel).
 """
 
 from __future__ import annotations
@@ -198,13 +202,14 @@ class Program(Qwen3NextProgram):
 
     @property
     def forward_passes(self):
-        """Forward passes of a full layer a step: two where whole layers are
-        recomputed in the backward pass."""
+        """The most forward passes of a full layer a step: two where whole
+        layers are recomputed in the backward pass (a step that keeps the
+        kernel's output through the recomputation runs one)."""
         return 2 if self.config["job"]["remat"] == "layer" else 1
 
     @property
     def window_forward_passes(self):
-        """Forward passes of a window layer's attention a step."""
+        """The most forward passes of a window layer's attention a step."""
         return 2 if self.config["job"]["remat"] in ("layer", "window") else 1
 
     def heads_of(self, kind):
@@ -215,17 +220,20 @@ class Program(Qwen3NextProgram):
 
     def expected_kernel_shapes(self):
         """Flash attention's work is the FULL layers': batch x their query
-        heads (the key heads are repeated before the kernel) x positions x
-        head size, ``attention_layers`` their forward calls a step (a
+        heads (the kernel reads the key heads in place, one for each group of
+        query heads) x positions x head size; ``attention_passes`` the passes
+        a step REQUIRES (one forward and one backward a full layer),
+        ``attention_layers`` the MOST forward calls a step may make (a
         recomputed layer's twice).  The window layers' kernels go by another
         name and are stated beside them: ``window_dims``, ``window``,
-        ``window_layers`` (layers, not calls)."""
+        ``window_layers`` (layers, so the required passes; not calls)."""
         c = self.config
         (heads,), (w_heads,) = self.heads_of("full"), self.heads_of("window")
         d = c["head_dim"]
         return {"flash_dims": (self.batch, heads, self.seq, d),
                 "flash_elements": self.batch * heads * self.seq * d,
                 "flash_rows": self.batch * heads, "head_dim": d,
+                "attention_passes": self.model.layers_of(KINDS["full"]),
                 "attention_layers": (self.model.layers_of(KINDS["full"])
                                      * self.forward_passes),
                 "causal": True,

@@ -261,21 +261,25 @@ class Program(Qwen3NextProgram):
 
     @property
     def forward_passes(self):
-        """Forward passes of a layer a step: two where whole layers are
-        recomputed in the backward pass."""
+        """The most forward passes of a layer a step: two where whole layers
+        are recomputed in the backward pass (a step that keeps the kernel's
+        output through the recomputation runs one)."""
         return 2 if self.config["job"]["remat"] == "layer" else 1
 
     def expected_kernel_shapes(self):
         """Flash attention's work: the forward pass writes batch x heads x
-        positions x the VALUES' head size; ``attention_layers`` counts the
-        forward calls a step (a recomputed layer's twice); the scores' width
-        beside it for the roofline."""
+        positions x the VALUES' head size; ``attention_passes`` is the passes
+        a step REQUIRES (one forward and one backward an attention layer),
+        ``attention_layers`` the MOST forward calls a step may make (a
+        recomputed layer's twice); the scores' width beside them for the
+        roofline."""
         c = self.config
         heads, dv = c["num_attention_heads"], c["v_head_dim"]
         return {"flash_dims": (self.batch, heads, self.seq, dv),
                 "flash_elements": self.batch * heads * self.seq * dv,
                 "flash_rows": self.batch * heads, "head_dim": dv,
                 "score_dim": c["qk_nope_head_dim"] + c["qk_rope_head_dim"],
+                "attention_passes": self.model.attention_layers,
                 "attention_layers": (self.model.attention_layers
                                      * self.forward_passes),
                 "causal": True,

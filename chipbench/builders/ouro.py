@@ -251,19 +251,24 @@ class Program(LlamaProgram):
 
     @property
     def forward_passes(self):
-        """Forward passes of a layer application a step: two where whole
-        layers are recomputed in the backward pass."""
+        """The most forward passes of a layer application a step: two where
+        whole layers are recomputed in the backward pass (a step that keeps
+        the kernel's output through the recomputation runs one)."""
         return 2 if self.config["job"]["remat"] else 1
 
     def expected_kernel_shapes(self):
-        """Flash attention's work (batch, heads, positions, head size) and
-        its forward calls a step: ``P x k`` layer applications, each twice
-        where whole layers are recomputed; the rows of a loss kernel call."""
+        """Flash attention's work (batch, heads, positions, head size);
+        ``attention_passes``, the passes a step REQUIRES: one forward and one
+        backward for each of the ``P x k`` layer applications;
+        ``attention_layers``, the MOST forward calls a step may make: each
+        application's twice where whole layers are recomputed; the rows of a
+        loss kernel call."""
         c = self.config
         heads, hd = c["num_attention_heads"], c["head_dim"]
         return {"flash_dims": (self.batch, heads, self.seq, hd),
                 "flash_elements": self.batch * heads * self.seq * hd,
                 "flash_rows": self.batch * heads, "head_dim": hd,
+                "attention_passes": self.model.attention_layers,
                 "attention_layers": (self.model.attention_layers
                                      * self.forward_passes),
                 "causal": True,

@@ -38,6 +38,15 @@ FLASH_PASSES = {
                  "tensors": 8}}      # q, k, v, o, do read; dq, dk, dv written
 
 
+def flash_passes_a_step(want):
+    """The flash passes of each kind a step REQUIRES, one a layer
+    application, from a builder's ``expected_kernel_shapes()``:
+    ``attention_passes`` where the builder states it beside the most forward
+    calls a step may make (``attention_layers``: a recomputed layer's
+    twice), else ``attention_layers`` (nothing recomputed: one number)."""
+    return want.get("attention_passes", want["attention_layers"])
+
+
 def flash_pass(name, rows, seq, head_dim, itemsize=2):
     """``(operations, bytes)`` of one flash-attention pass over ``rows``
     (batch x heads) sequences, each tensor read or written once."""

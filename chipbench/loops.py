@@ -189,10 +189,13 @@ class TrainLoop:
     def trace_checks(self, reduced):
         """In a traced run: the kernels ran on the device as Mosaic calls,
         and flash attention did the configuration's work on every device:
-        both passes, on the local shard, once a layer and step.  Flash is
-        held by the pass its events name and the size of what the forward
-        pass writes, not by how many kernels carry a pass out or by the
-        order of their dimensions."""
+        both passes, on the local shard, and of forward calls no fewer than
+        a step REQUIRES (``flops.flash_passes_a_step``: one a layer
+        application) and no more than a step that recomputes every
+        ``ht.remat()`` group whole makes (``attention_layers``).  How many
+        it makes in between is the program's.  Flash is held by the pass its
+        events name and the size of what the forward pass writes, not by how
+        many kernels carry a pass out or by the order of their dimensions."""
         from math import prod
         from . import flops, trace_reduce as tr
         p = self.p
@@ -216,7 +219,10 @@ class TrainLoop:
                           f"{w[0]}{list(w[1])}" for w in results)
         steps = tr.count_spans(reduced["host"], STEP_SPANS)
         calls = {d: len(ev) for d, ev in fwd.items()}
-        due = want["attention_layers"] * steps
+        required = flops.flash_passes_a_step(want)
+        least, most = required * steps, want["attention_layers"] * steps
+        counts = set(calls.values())
+        a_pass = sorted(round(n / least, 4) for n in counts)
         return [(not missing, f"the device ran {p.KERNELS} (missing: "
                  f"{missing})"),
                 (bool(fwd) and not without,
@@ -228,10 +234,13 @@ class TrainLoop:
                  f"{want['flash_elements']} {dtype} elements in whatever "
                  f"order (saw {shown(wrote)}; not that: "
                  f"{shown(off_shard)})"),
-                (bool(calls) and set(calls.values()) == {due},
-                 f"flash forward calls on each device {calls}: "
-                 f"{want['attention_layers']} attention layer(s) x {steps} "
-                 f"traced steps = {due}")]
+                (len(counts) == 1 and least <= min(counts) <= most,
+                 f"flash forward calls on each device {calls}: the same on "
+                 f"every device, at least the {required} required pass(es) "
+                 f"x {steps} traced steps = {least} and at most a whole "
+                 f"recomputation's {want['attention_layers']} x {steps} = "
+                 f"{most}; forward calls a required pass: "
+                 f"{', '.join(map(repr, a_pass)) or 'none'}")]
 
 
 LOOPS = {"train_loop": TrainLoop}

@@ -8,6 +8,7 @@ A reader that finds nothing to read returns None."""
 from __future__ import annotations
 
 from chipbench import flops, trace_reduce as tr
+from chipbench.loops import STEP_SPANS
 
 
 def idle_share(ctx):
@@ -36,6 +37,19 @@ def kernel_events(ctx, names):
                         out[n].append(d)
                         break
     return out
+
+
+def passes_due(ctx, a_step, by_device):
+    """The passes of each kind the configuration REQUIRES in the traced
+    window: ``a_step`` (one a layer application, whatever the program
+    recomputes) x the steps of the window, as ``loops.trace_checks`` counts
+    them, x the devices on which an event of a pass ran (``by_device``:
+    ``{pass: tr.events_holding(..)}``).  Never a count of events: a program
+    that runs a forward kernel once where another runs it twice is due the
+    same work."""
+    steps = tr.count_spans(ctx["trace"]["reduced"]["host"], STEP_SPANS)
+    devices = {d for by in by_device.values() for d, ev in by.items() if ev}
+    return a_step * steps * len(devices)
 
 
 def roofline_share(ctx, names, call):

@@ -26,6 +26,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 REHEARSAL_TAG = "[REHEARSAL cpu toy-size] "
 IMPORTED_AT = time.perf_counter()
+#: Host memory the TPU client pins for transfers when it starts.  libtpu's
+#: own 4 GiB took 7.3-12.2 s of ``jax.devices()`` on the chip machine, in two
+#: modes 3.7 s apart (12% of a 30 s set-up: the check of PR 54 read medians
+#: of 27.88 and 32.31 s from one tree), 1 GiB 3.5-3.9 s and 256 MiB 1.9-2.1 s
+#: (calls 54.7, 54.8).  No cell's step moves more than 2.6 MB to the chip;
+#: a transfer larger than the buffer still goes, unpinned (256 MiB up in
+#: 1.44 s where 0.43).  A traffic file that needs more says
+#: ``premapped_buffer_bytes`` (Ouro's: its comparison reads 6.4 GB of logits
+#: back); a value the machine sets is left alone.
+PREMAPPED_BUFFER_BYTES = 256 << 20
 
 
 def process_age():
@@ -157,6 +167,8 @@ def main(argv=None, rehearsal=False):
         config = merge(config, config["toy"])
         mix = merge(mix, mix["toy"])
 
+    os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE", str(int(mix.get(
+        "premapped_buffer_bytes", PREMAPPED_BUFFER_BYTES))))
     import jax
     devices = jax.devices()
     d0 = devices[0]
@@ -175,7 +187,8 @@ def main(argv=None, rehearsal=False):
     from hetu_tpu import telemetry
     from hetu_tpu.platform import enable_compile_cache
     from . import loops, peaks as peaks_mod, trace_reduce
-    say(f"compile cache: {enable_compile_cache()}")
+    say(f"compile cache: {enable_compile_cache()}; premapped host buffer "
+        f"{os.environ['TPU_PREMAPPED_BUFFER_SIZE']} bytes")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     telemetry.enable()     # the registry counts retraces and kernel choices

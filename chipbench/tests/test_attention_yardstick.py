@@ -36,12 +36,15 @@ def program(**over):
 
 
 def synth(fwd="bf16_768_512_64", cut=("_dq", "_dkv"), layers=12, steps=2,
-          devices=(0,), bare=(), extra=()):
+          devices=(0,), bare=(), extra=(), forwards=None):
     """A reduced trace of ``steps`` steps on ``devices``: per layer one
     forward event writing ``fwd`` and the backward pass cut into the
     kernels ``cut`` (their times sum to ``BWD_NS`` whatever the cut), the
     loss kernels once a step, and ``extra`` ``(key, ns)`` events a step.
-    Devices in ``bare`` run everything but flash attention."""
+    Devices in ``bare`` run everything but flash attention.
+    ``forwards(device, step)``: the forward events of a step where they are
+    not one a layer (a recomputed layer's two)."""
+    forwards = forwards or (lambda d, i: layers)
     host, events = [], {d: [] for d in devices}
     for i in range(steps):
         t = 1e9 + i * STEP_NS
@@ -54,7 +57,7 @@ def synth(fwd="bf16_768_512_64", cut=("_dq", "_dkv"), layers=12, steps=2,
                 ev.append((at, ns, key))
                 at += ns + 1e3
             put("jvp_hetu_softmax_ce_fwd__f32_8192", 1e6)
-            for _ in range(layers if d not in bare else 0):
+            for _ in range(forwards(d, i) if d not in bare else 0):
                 put(f"jvp_hetu_flash_fwd__{fwd}_f32_768_1_512", FWD_NS)
             for key, ns in extra:
                 put(key, ns)
@@ -89,6 +92,68 @@ def by_hand(causal=False):
 def test_any_layout_and_cut_passes_the_trace_checks(layout, cut):
     for ok, what in checks(synth(LAYOUTS[layout], CUTS[cut])):
         assert ok, what
+
+
+#: a program that recomputes every layer whole states the twelve passes a
+#: step REQUIRES beside the most forward calls it may make, two a pass
+RECOMPUTED = {"attention_passes": 12, "attention_layers": 24}
+FORWARDS = {"twice_a_pass": 24, "once_a_pass": 12, "in_between": 18}
+
+
+@pytest.mark.parametrize("calls", FORWARDS)
+@pytest.mark.parametrize("devices", [(0,), (0, 1, 2, 3)])
+def test_a_recomputing_program_may_run_its_forward_once_or_twice(calls,
+                                                                 devices):
+    """How many forward calls get a required pass done is the program's,
+    between one and a whole recomputation's two; the check's line says how
+    many it was."""
+    reduced = synth(forwards=lambda d, i: FORWARDS[calls], devices=devices)
+    results = checks(reduced, program(**RECOMPUTED))
+    for ok, what in results:
+        assert ok, what
+    a_pass = FORWARDS[calls] / 12
+    assert f"forward calls a required pass: {a_pass!r}" in results[3][1]
+
+
+def test_a_program_that_states_no_required_passes_reads_as_before():
+    """The five builders that recompute no attention state
+    ``attention_layers`` alone: required and most are the same number."""
+    ok, what = checks(synth())[3]
+    assert ok and "forward calls a required pass: 1.0" in what
+    ok, what = checks(synth(forwards=lambda d, i: 13))[3]
+    assert not ok and "forward calls" in what
+
+
+def ling3_share(forwards, steps=2, devices=(0,), **shapes):
+    said = []
+    ctx = selfcheck.trace_ctx(
+        synth(forwards=lambda d, i: forwards, steps=steps, devices=devices),
+        program(causal=True, **shapes), KIND, said.append)
+    return run.reader("flash_roofline", "ling3")(ctx), said[0]
+
+
+@pytest.mark.parametrize("devices", [(0,), (0, 1, 2, 3)])
+@pytest.mark.parametrize("steps", [2, 3])
+def test_the_recomputing_cells_reader_credits_the_required_passes(steps,
+                                                                  devices):
+    """The Ling-3.0 cell's reader (Ouro's and Laguna's too): the least time
+    is the configuration's passes x steps x devices whatever the forward
+    events seen, so a step that keeps the kernel's output reads higher by
+    exactly the forward time that is gone."""
+    n = steps * len(devices)
+    least = by_hand(causal=True) / 100.0 * (FWD_NS + BWD_NS) * 1e-9 * 12 * n
+    twice, said_twice = ling3_share(24, steps, devices, **RECOMPUTED)
+    once, said_once = ling3_share(12, steps, devices, **RECOMPUTED)
+    measured = 12 * n * (2 * FWD_NS + BWD_NS) * 1e-9
+    assert twice == pytest.approx(100.0 * least / measured, rel=1e-12)
+    assert once == pytest.approx(
+        100.0 * least / (measured - 12 * n * FWD_NS * 1e-9), rel=1e-12)
+    assert once == pytest.approx(by_hand(causal=True), rel=1e-12)
+    for said in (said_twice, said_once):
+        assert f"{12 * n} passes required (12 a step)" in said
+    # a program that states no required passes: its forward calls a step
+    plain, _ = ling3_share(12, steps, devices)
+    assert plain == once
 
 
 @pytest.mark.parametrize("reader, causal", [("flash_roofline", False),
@@ -127,6 +192,13 @@ WRONG = {
                                     "local shard"),
     "forward_in_f32": (dict(fwd="f32_768_512_64"), "local shard"),
     "eleven_calls_where_twelve_layers": (dict(layers=11), "forward calls"),
+    "recomputed_one_call_short_of_required": (
+        dict(forwards=lambda d, i: 12 - i, over=RECOMPUTED), "forward calls"),
+    "recomputed_one_call_over_a_whole_recomputation": (
+        dict(forwards=lambda d, i: 24 + i, over=RECOMPUTED), "forward calls"),
+    "recomputed_devices_that_differ": (
+        dict(forwards=lambda d, i: 24 - 12 * d, devices=(0, 1),
+             over=RECOMPUTED), "the same on every device"),
     "a_device_without_flash": (dict(devices=(0, 1), bare=(1,)),
                                "without one: [1]"),
     "no_flash_at_all": (dict(bare=(0,)), "without one: [0]"),
@@ -136,7 +208,9 @@ WRONG = {
 @pytest.mark.parametrize("case", WRONG)
 def test_wrong_traces_fail_the_check_that_names_the_fault(case):
     kwargs, names_it = WRONG[case]
-    failed = [what for ok, what in checks(synth(**kwargs)) if not ok]
+    kwargs = dict(kwargs)
+    prog = program(**kwargs.pop("over", {}))
+    failed = [what for ok, what in checks(synth(**kwargs), prog) if not ok]
     assert failed and any(names_it in what for what in failed), failed
 
 

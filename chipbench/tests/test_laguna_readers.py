@@ -15,7 +15,7 @@ import importlib
 import pytest
 
 import hetu_tpu as ht
-from chipbench import flops, flops_laguna as fl, peaks, run, selfcheck
+from chipbench import flops, flops_laguna as fl, loops, peaks, run, selfcheck
 from chipbench import trace_reduce as tr
 from chipbench.metrics import _blocks, _moe
 
@@ -26,6 +26,12 @@ STEPS, STEP_NS = 2, 80e6
 #: pass once (nothing recomputed), or each forward pass twice
 FULL = (("hetu_flash_fwd.1", 4e5), ("hetu_flash_bwd.1", 9e5))
 WINDOW = (("hetu_swa_fwd.1", 2e5), ("hetu_swa_bwd.1", 5e5))
+#: what the two readers read of ``kernel_events(passes)`` at the parent of
+#: PR 54, where they took the required passes from the forward events seen
+AT_PR53 = {"flash_roofline": {1: 0.013849535080304308,
+                              2: 0.010590820943762118},
+           "window_attn_roofline": {1: 0.021433804290947146,
+                                    2: 0.01667073667073667}}
 
 
 def kernel_events(passes):
@@ -118,7 +124,10 @@ def test_window_attn_roofline_credits_the_band_once_a_layer(traced):
     del said[:]
     got = run.reader("window_attn_roofline")(ctx)
     assert got == pytest.approx(100.0 * least / measured, rel=1e-9)
+    assert got == pytest.approx(AT_PR53["window_attn_roofline"][passes],
+                                abs=1e-9)
     assert "3 heads" not in said[0] and "8 heads on 2 key heads" in said[0]
+    assert f"{3 * STEPS} passes required (3 a step)" in said[0]
 
 
 def test_window_attn_roofline_cannot_pass_100(traced):
@@ -165,21 +174,53 @@ def test_flash_roofline_reads_the_full_layers_alone(traced):
     want = prog.expected_kernel_shapes()
     assert want["flash_dims"] == (1, 6, 64, 16)
     assert want["attention_layers"] == 2 * passes == 2 * prog.forward_passes
+    assert want["attention_passes"] == 2
     pk = peaks.peaks_for(KIND)
     least = 0.0
     for name in ("forward", "backward"):
         ops, nbytes = flops.flash_pass(name, 6, 64, 16)
         least += flops.roofline_seconds(ops / 2, nbytes, pk)[0] * 2 * STEPS
     measured = STEPS * 2 * (passes * FULL[0][1] + FULL[1][1]) * 1e-9
-    assert run.reader("flash_roofline")(ctx) == pytest.approx(
-        100.0 * least / measured, rel=1e-9)
-    loop = importlib.import_module("chipbench.loops").TrainLoop(
-        prog, None, 0, None, None)
-    checks = loop.trace_checks(ctx["trace"]["reduced"])
+    got = run.reader("flash_roofline")(ctx)
+    assert got == pytest.approx(100.0 * least / measured, rel=1e-9)
+    assert got == pytest.approx(AT_PR53["flash_roofline"][passes], abs=1e-9)
+    checks = flash_checks(prog, ctx["trace"]["reduced"])
     # the CPU's step has neither the loss kernels nor the grouped products,
     # and the synthetic events carry no shapes: the COUNT is what is held
     assert checks[3][0], checks[3][1]
+    assert f"forward calls a required pass: {passes:.1f}" in checks[3][1]
     assert "hetu_swa_fwd" not in checks[0][1].split("missing:")[1]
+
+
+def flash_checks(prog, reduced):
+    return loops.TrainLoop(prog, None, 0, None, None).trace_checks(reduced)
+
+
+def test_every_second_forward_call_gone(traced):
+    """A program that recomputes whole layers and keeps the kernels' outputs
+    through the recomputation: the readers credit it the work of the trace
+    that runs each forward pass twice and read what a step that recomputes
+    nothing reads, and the flash check passes.  Where nothing is recomputed
+    the same cut leaves fewer forward calls than the configuration requires:
+    refused."""
+    ctx, _, _, passes = traced
+    seen, kept = {}, []
+    for e in ctx["trace"]["reduced"]["devices"][0]:
+        if e[2] in (FULL[0][0], WINDOW[0][0]):
+            seen[e[2]] = seen.get(e[2], 0) + 1
+            if seen[e[2]] % 2 == 0:
+                continue
+        kept.append(e)
+    reduced = dict(ctx["trace"]["reduced"], devices={0: kept})
+    cut = dict(ctx, trace=dict(ctx["trace"], reduced=reduced))
+    ok, what = flash_checks(ctx["program"], reduced)[3]
+    if passes == 1:
+        assert not ok and "forward calls" in what
+        return
+    assert ok and "forward calls a required pass: 1.0" in what, what
+    for name, at_parent in AT_PR53.items():
+        assert run.reader(name)(cut) == pytest.approx(at_parent[1], rel=1e-9)
+        assert run.reader(name)(ctx) == pytest.approx(at_parent[2], rel=1e-9)
 
 
 def test_mfu_credits_the_band_and_nothing_recomputed(traced):

@@ -130,25 +130,46 @@ def test_kda_scan_roofline_credits_six_layers_once(hybrid):
                                                                  rel=1e-9)
 
 
-def test_flash_roofline_credits_a_recomputed_layer_once(hybrid):
-    """Two forward events and one backward a step: ONE pass each is
-    required, at scores 48 and values 32 wide (the toy's), causal."""
+#: what the reader read of ``FLASH`` at the parent of PR 54, where it took the
+#: required passes from the forward events seen
+FLASH_ROOFLINE_AT_PR53 = 0.017651368239603532
+
+
+@pytest.mark.parametrize("kept", [False, True])
+def test_flash_roofline_credits_a_recomputed_layer_once(hybrid, kept):
+    """Two forward events and one backward a step, or ONE forward where the
+    kernel's output is kept through the recomputation: ONE pass each is
+    required (the configuration's ``attention_passes``), at scores 48 and
+    values 32 wide (the toy's), causal."""
     ctx, _, _ = hybrid
     prog = ctx["program"]
     want = prog.expected_kernel_shapes()
     assert prog.forward_passes == 2 and want["score_dim"] == 48
+    assert (want["attention_passes"], want["attention_layers"]) == (1, 2)
+    flash = [e for e in FLASH if not (kept and e[0] == "hetu_flash_fwd.2")]
+    if kept:
+        reduced = ctx["trace"]["reduced"]
+        reduced = dict(reduced, devices={0: [
+            e for e in reduced["devices"][0] if e[2] != "hetu_flash_fwd.2"]})
+        ctx = dict(ctx, trace=dict(ctx["trace"], reduced=reduced))
     pk = peaks.peaks_for(KIND)
     least = 0.0
     for name in ("forward", "backward"):
         ops, nbytes = fl.flash_pass(name, want["flash_rows"], prog.seq, 48,
                                     want["head_dim"])
         least += flops.roofline_seconds(ops / 2, nbytes, pk)[0] * STEPS
-    measured = STEPS * sum(ns for _, ns in FLASH) * 1e-9
+    measured = STEPS * sum(ns for _, ns in flash) * 1e-9
     got = run.reader("flash_roofline")(ctx)
     assert got == pytest.approx(100.0 * least / measured, rel=1e-9)
-    # the accepted reader would credit every forward event a backward pass
-    other = dict(ctx, config=dict(ctx["config"], builder="qwen3_next"))
-    assert run.reader("flash_roofline")(other) > 1.5 * got
+    if kept:
+        assert got == pytest.approx(FLASH_ROOFLINE_AT_PR53 * 17 / 13,
+                                    rel=1e-9)
+    else:
+        assert got == pytest.approx(FLASH_ROOFLINE_AT_PR53, abs=1e-9)
+        # the accepted reader would credit every forward event a backward
+        # pass
+        other = dict(ctx, config=dict(ctx["config"], builder="qwen3_next"))
+        assert run.reader("flash_roofline")(other) > 1.5 * got
 
 
 def test_mfu_credits_the_models_operations_and_nothing_recomputed(hybrid):

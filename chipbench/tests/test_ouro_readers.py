@@ -18,7 +18,7 @@ import importlib
 
 import pytest
 
-from chipbench import flops, flops_ouro as fo, peaks, run, selfcheck
+from chipbench import flops, flops_ouro as fo, loops, peaks, run, selfcheck
 from chipbench import trace_reduce as tr
 from chipbench.metrics import _blocks, _scopes
 
@@ -127,20 +127,29 @@ def test_mfu_credits_the_models_operations_and_nothing_recomputed(looped):
     assert run.reader_path("mfu", "ouro").endswith("mfu.ouro.py")
 
 
-def test_flash_roofline_credits_each_pass_once_a_layer_application():
+#: what the reader read of the sixteen-call trace below at the parent of PR 54,
+#: where it took the required passes from the forward events seen
+FLASH_ROOFLINE_AT_PR53 = 0.013336589336589336
+
+
+@pytest.mark.parametrize("forwards", [16, 8])
+def test_flash_roofline_credits_each_pass_once_a_layer_application(forwards):
     """Sixteen forward events a step (eight applications, each recomputed)
-    and eight backward: the roofline credits eight of each, causal."""
+    or eight (the kernel's output kept through the recomputation), and eight
+    backward: the roofline credits eight of each, causal, from the
+    configuration's ``attention_passes``, and the flash check passes both."""
     prog, config, _ = build()
     try:
         want = prog.expected_kernel_shapes()
         assert want["attention_layers"] == 16 and prog.forward_passes == 2
+        assert want["attention_passes"] == 8
         b, h, s, d = want["flash_dims"]
         host, events = [], []
         for step in range(2):
             t = 1e9 + step * 1e8
             host.append((t, 1e8 - 1e3, "executor_run"))
             at = t + 1e3
-            for i in range(16):
+            for i in range(forwards):
                 events.append((at, 2e5, f"jvp_hetu_flash_fwd__bf16_{b}_{s}"
                                         f"_{h * d}_f32"))
                 at += 3e5
@@ -157,9 +166,17 @@ def test_flash_roofline_credits_each_pass_once_a_layer_application():
             flops.flash_pass(name, b * h, s, d)[0] / 2.0,
             flops.flash_pass(name, b * h, s, d)[1], pk)[0]
             for name in ("forward", "backward")) * 16
-        measured = 2 * (16 * 2e5 + 8 * 5e5) * 1e-9
+        measured = 2 * (forwards * 2e5 + 8 * 5e5) * 1e-9
         got = run.reader("flash_roofline")(ctx)
         assert got == pytest.approx(100.0 * least / measured, rel=1e-9)
+        if forwards == 16:
+            assert got == pytest.approx(FLASH_ROOFLINE_AT_PR53, abs=1e-9)
+        else:
+            assert got > FLASH_ROOFLINE_AT_PR53
+        ok, what = loops.TrainLoop(prog, None, 0, None,
+                                   None).trace_checks(reduced)[3]
+        assert ok and (f"forward calls a required pass: {forwards / 8!r}"
+                       in what), what
     finally:
         prog.close()
 
