@@ -66,7 +66,8 @@ class GradientsBundleOp(Op):
         def f(x_vals):
             inner = TraceContext(key=ctx.key, training=ctx.training,
                                  mesh=ctx.mesh,
-                                 master_params=ctx.master_params)
+                                 master_params=ctx.master_params,
+                                 differentiated=True)
             bind = {n: env[n] for n in leaves if n in env}
             bind.update(dict(zip(self.xs, x_vals)))
             (loss_val,), _ = evaluate([self.loss], bind, inner)

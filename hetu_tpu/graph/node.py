@@ -80,7 +80,8 @@ def _remat_stack():
 class remat:
     """Rematerialization scope: ops created inside form one
     `jax.checkpoint` group — their activations are NOT saved for the
-    backward pass; the group recomputes during the vjp instead.
+    backward pass; the group recomputes during the vjp instead, but for
+    the kernel residuals named below.
 
     The graph-API face of the reference's memory planner (SURVEY §2.2
     P10: memory_pool.py / swap — on TPU the trade is FLOPs-for-HBM via
@@ -88,6 +89,17 @@ class remat:
 
         with ht.remat():
             x = layer(x, ...)
+
+    What a group keeps (PR 55): its inputs and, for every flash attention
+    call it holds (the window kernels too), the kernel's context, ``B x S x
+    heads x d`` of the compute type, and log-sum-exp, ``B x heads x S`` f32
+    (34.1 MB a call at 1 x 8,192 x 2,048 bf16): the kernel is the dearest
+    thing of a layer to make again, so the backward pass reads what the
+    forward pass wrote and runs no second forward kernel.  The names are one
+    table beside the kernels' dispatch (``ops/pallas/dispatch.py KEPT``,
+    given INSIDE the kernel's forward rule); a group with no such call keeps
+    its inputs alone.  ``hetu_remat_kept_total{kernel}`` and
+    ``hetu_remat_kept_bytes`` say how often and how much.
 
     Stateful ops (batchnorm update, assign) must stay outside — the
     recompute would replay their side effects; `evaluate` raises.

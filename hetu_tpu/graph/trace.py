@@ -34,10 +34,13 @@ class TraceContext:
     """
 
     def __init__(self, key=None, training=True, mesh=None,
-                 master_params=None, cp_impl="ring"):
+                 master_params=None, cp_impl="ring", differentiated=False):
         self.key = key
         self.training = training
         self.mesh = mesh
+        # the trace is the primal of a ``jax.vjp`` (``GradientsBundleOp``):
+        # what a recomputed group keeps is kept for a backward pass
+        self.differentiated = differentiated
         # long-context lowering flavor over a 'cp' mesh axis: 'ring'
         # (K/V rotate the ICI ring) or 'ulysses' (all-to-all head
         # parallelism); Executor(cp_impl=...) selects it
@@ -117,7 +120,9 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
         stack.extend(i for i in n.inputs if i not in env)
     # -- remat groups: ops created under `with ht.remat():` evaluate as
     # one jax.checkpoint'ed function (their activations recompute in the
-    # vjp instead of being saved — the FLOPs-for-HBM memory planner)
+    # vjp instead of being saved — the FLOPs-for-HBM memory planner), but
+    # for the kernel residuals `dispatch.KEPT` names: the policy keeps
+    # those, so the vjp makes no kernel's output again that it held
     remat_groups = {}
     if _remat:
         for node in topo:
@@ -141,6 +146,8 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
                         if isinstance(i, VariableOp)}:
                 uses[var] = uses.get(var, 0) + 1
         shared = {var: env[var] for var, n in uses.items() if n > 1}
+    from ..ops.pallas import dispatch
+    kept = []               # (kernel, bytes) a kernel call the groups keep
     if remat_groups:
         eval_ids = {n.id for n in eval_nodes}
         consumed_outside = {}
@@ -193,7 +200,9 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
             # bind ONLY the group's external inputs: everything the group
             # needs flows through the checkpoint boundary as an argument
             # (no closure captures), so the vjp recomputes exactly the
-            # group's interior and saves only `ins`
+            # group's interior and saves only `ins` and the named kernel
+            # residuals (a flash call: its context, B x S x heads x d of
+            # the compute type, and log-sum-exp, B x heads x S f32)
             before = dict(ctx.updates)
             vals, _ = evaluate(outs, dict(zip(ins, in_vals)), ctx,
                                _remat=False)
@@ -210,8 +219,10 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
         for i in ins:
             if i in shared:
                 shared[i] = _grad_link(shared[i])
-        out_vals, new_vals = jax.checkpoint(f)(
-            *[shared[i] if i in shared else env[i] for i in ins])
+        with dispatch.keeping(kept if ctx.differentiated else None):
+            out_vals, new_vals = jax.checkpoint(
+                f, policy=dispatch.KEEP_POLICY)(
+                *[shared[i] if i in shared else env[i] for i in ins])
         for n, v in zip(outs, out_vals):
             env[n] = v
         for var, val in zip(updated, new_vals):
@@ -243,6 +254,8 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
             sh = NamedSharding(ctx.mesh,
                                node.dist_state.to_pspec(env[node].ndim))
             env[node] = jax.lax.with_sharding_constraint(env[node], sh)
+    if _remat and ctx.differentiated:
+        dispatch.record_kept(kept)
     return [env[n] for n in eval_nodes], env
 
 

@@ -18,9 +18,12 @@ step.
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import threading
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 from ... import telemetry
 
@@ -111,6 +114,71 @@ def take(kernel, mesh, reason=None, asked=False):
     elif not there:
         reason = f"platform:{platform()}"
     return record(kernel, reason)
+
+
+#: what every ``ht.remat()`` group keeps beside its arguments, by kernel: the
+#: names its forward rule gives the residuals that are dearest to make again
+#: (``named``, INSIDE the ``custom_vjp``'s forward rule: the backward rule
+#: reads the residual, so a name on the node's output keeps a copy and still
+#: runs the kernel again).  Flash attention's context ``B x S x heads x d`` of
+#: the compute type and log-sum-exp ``B x heads x S`` f32 a call; the scans'
+#: outputs are not in it yet (PERF.md section 7).  The window kernels share
+#: the rule and the names, which hold no kernel's name (a reader of the device
+#: trace finds a kernel by its name anywhere in an event's)
+KEPT = {"flash": ("attention_context", "attention_lse")}
+
+
+def named(kernel, *residuals):
+    """``residuals`` under ``KEPT[kernel]``'s names, in order; outside a
+    ``jax.checkpoint`` a name is the identity and lowers to nothing."""
+    return tuple(checkpoint_name(r, name) for r, name
+                 in zip(residuals, KEPT[kernel], strict=True))
+
+
+#: the ``jax.checkpoint`` policy of every recomputed group (``graph/trace.py``):
+#: its arguments and every name of ``KEPT``.  ONE object: jax caches a group's
+#: partial evaluation by the policy's identity, and a policy made anew a group
+#: lowers every jitted function of the group a second time under another name
+KEEP_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *(name for names in KEPT.values() for name in names))
+
+
+_group = threading.local()
+
+
+@contextlib.contextmanager
+def keeping(tally):
+    """While a recomputed group that has a backward pass is traced:
+    ``kept`` appends to ``tally``."""
+    before, _group.tally = getattr(_group, "tally", None), tally
+    try:
+        yield
+    finally:
+        _group.tally = before
+
+
+def kept(kernel, nbytes):
+    """One kernel call that named ``nbytes`` of residuals: counted where a
+    group's policy keeps them, nothing elsewhere."""
+    if getattr(_group, "tally", None) is not None:
+        _group.tally.append((kernel, int(nbytes)))
+
+
+def record_kept(tally):
+    """A traced step's tally into the registry:
+    ``hetu_remat_kept_total{kernel}`` once a call, ``hetu_remat_kept_bytes``
+    the step's sum."""
+    registry = telemetry.get_registry()
+    calls = registry.counter(
+        "hetu_remat_kept_total",
+        "Trace-time kernel calls whose named residuals a recomputed "
+        "group's policy keeps for the backward pass", labels=("kernel",))
+    for kernel, _ in tally:
+        calls.labels(kernel=kernel).inc()
+    registry.gauge(
+        "hetu_remat_kept_bytes",
+        "Bytes of named kernel residuals the recomputed groups of the last "
+        "traced step keep").set(sum(n for _, n in tally))
 
 
 def counted(name):

@@ -73,7 +73,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ... import telemetry
-from .dispatch import counted, interpret
+from .dispatch import counted, interpret, kept, named
 
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
@@ -716,8 +716,12 @@ def _flash(q, k, v, mask, seed, causal, scale, keep_prob, block, num_heads,
 
 def _flash_fwd(q, k, v, mask, seed, causal, scale, keep_prob, block,
                num_heads, window=None):
-    o, lse = _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                  num_heads=num_heads, **_blocks(block, window))
+    # named HERE, on the values the backward rule reads: a recomputed group
+    # keeps them (``dispatch.KEPT``) and its backward pass runs no second
+    # forward kernel
+    o, lse = named("flash", *_fwd(q, k, v, mask, causal, scale, keep_prob,
+                                  seed, num_heads=num_heads,
+                                  **_blocks(block, window)))
     return o, (q, k, v, mask, seed, o, lse)
 
 
@@ -912,6 +916,9 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         block = _window_plan(s_pad, window)[0]
     out = _flash_call(q, k, v, mask, seed, causal, float(scale),
                       float(dropout_keep), block, num_heads, window)
+    # the context and the f32 log-sum-exp, one value a row and head
+    kept("flash" if window is None else "swa",
+         out.size * out.dtype.itemsize + out.size // dv_pad * 4)
     if d_pad != d or dv_pad != dv or s_pad != s:
         out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :dv]
     return out

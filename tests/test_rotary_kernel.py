@@ -368,9 +368,12 @@ def test_a_models_layers_share_one_tables_node_outside_every_remat_group():
 def test_a_recomputed_layer_lowers_to_two_forward_kernels_and_one_backward(
         monkeypatch):
     """Lowered for a TPU (nothing compiled or run): under ``ht.remat()`` the
-    forward kernel stands in the forward pass and in its recomputation, the
-    backward kernel once, and no f32 array nor a view by heads of q or k
-    stands between the projections and the flash kernels."""
+    rotary forward kernel stands in the forward pass and in its
+    recomputation, the backward kernel once; the flash kernel pair stands
+    ONCE each (the group keeps the forward kernel's context and log-sum-exp,
+    PR 55), and no f32 array nor a view by heads of q or k stands between the
+    projections and the flash kernels."""
+    from conftest import kernel_calls
     monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
     jax.clear_caches()
     try:
@@ -387,7 +390,8 @@ def test_a_recomputed_layer_lowers_to_two_forward_kernels_and_one_backward(
     calls = re.findall(r"call @(hetu_rope_\w+?)(?:_\d+)?\(", text)
     assert sorted(calls) == ["hetu_rope_bwd"] + ["hetu_rope_fwd"] * 2, calls
     assert 'kernel_name = "hetu_rope_fwd"' in text
-    assert 'kernel_name = "hetu_flash_fwd"' in text
+    assert kernel_calls(text, "hetu_flash_fwd") == 1
+    assert kernel_calls(text, "hetu_flash_bwd") == 1
     # the kernels read and write the projections' type; no view by heads
     assert len(re.findall(
         r"call @hetu_rope_\w+\(.*\) : \(tensor<2x256x256xbf16>, "
