@@ -25,6 +25,14 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            MEMEM*EME, four Mamba-2 mixers, four expert layers with 8 of 128
            relu2 experts held and the shared expert, one attention layer, an
            eighth of the vocabulary)
+       python examples/nlp/train_llama.py --model xing4.0-29b-a4b \
+           --layers 5 --experts-held 0:8 --vocab-rows 16384 \
+           --seq-len 4096 --batch-size 1     (Xing4.0 at one chip's share of
+           an 8-way expert-parallel job: four residual streams mixed by
+           hyper-connections, the model's first five layers (two dense, three
+           with 8 of 64 experts held and the shared expert), the
+           multi-token-prediction depth behind them, an eighth of the
+           vocabulary)
 """
 
 import os
@@ -47,7 +55,8 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              GraniteHybridConfig, GraniteHybridForCausalLM,
                              GRANITE_HYBRID_CONFIGS, OuroConfig,
                              OuroForCausalLM, OURO_CONFIGS, LagunaConfig,
-                             LagunaForCausalLM, LAGUNA_CONFIGS,
+                             LagunaForCausalLM, LAGUNA_CONFIGS, Xing4Config,
+                             Xing4ForCausalLM, XING4_CONFIGS,
                              record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
@@ -58,7 +67,8 @@ def main():
                     choices=(list(LLAMA_CONFIGS) + list(QWEN3_NEXT_CONFIGS)
                              + list(NEMOTRON_H_CONFIGS)
                              + list(GRANITE_HYBRID_CONFIGS)
-                             + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)))
+                             + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)
+                             + list(XING4_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -72,8 +82,8 @@ def main():
                          "it is a slice of the published vocabulary, ids, "
                          "logits and the loss are over the slice")
     ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
-                    help="qwen3-next, nemotron: the experts of each layer "
-                         "this chip holds, of the router's full width")
+                    help="qwen3-next, nemotron, xing4: the experts of each "
+                         "layer this chip holds, of the router's full width")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--tp", type=int, default=1)
@@ -101,6 +111,9 @@ def main():
               (LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in LAGUNA_CONFIGS else
+              (XING4_CONFIGS, Xing4Config, Xing4ForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in XING4_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family

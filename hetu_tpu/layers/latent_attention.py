@@ -1,9 +1,10 @@
 """Multi-head latent attention, the training half (DeepSeek-V2,
-arXiv:2405.04434; the softmax layers of Ling-3.0): keys and values come out
-of one low-rank latent, a head's query and key are a part without position
-and a rotary part, and the scores are wider than the values.
+arXiv:2405.04434; the softmax layers of Ling-3.0 and Xing4.0): keys and values
+come out of one low-rank latent, a head's query and key are a part without
+position and a rotary part, and the scores are wider than the values.
 
-    [q_h^nope (d_n) | q_h^rope (d_r)] = (x W_q)_h          no low-rank query
+    [q_h^nope (d_n) | q_h^rope (d_r)] = (x W_q)_h          full-rank query, or
+        c_q = RMSNorm_rq(x W_qa);  (c_q W_qb)_h            ``q_lora_rank``
     [c (r) | k^rope (d_r)] = x W_kva ;  c = RMSNorm_r(c)
     [k_h^nope (d_n) | v_h (d_v)] = (c W_kvb)_h
     q_h = RMSNorm(q_h),  k_h = RMSNorm([k_h^nope | k^rope])  over d_n + d_r
@@ -11,6 +12,13 @@ and a rotary part, and the scores are wider than the values.
                      rotary on the last d_r of both (HF's half-split form)
     o_h = softmax_causal(q_h k_h^T / sqrt(d_n + d_r)) v_h
     y = [ o_h * sigmoid(x w_gate)_h ] W_o                  one gate a head
+
+``rope_scaling`` is ``ops/rotary.py yarn_scaling``'s tuple over the ``d_r``
+rotary dimensions; ``softmax_scale_mult`` multiplies the scores' scale
+(DeepSeek-V3's ``mscale``: where ``mscale == mscale_all_dim`` the tables carry
+a factor of 1 and the scale ``(0.1 mscale_all_dim ln factor + 1)^2``).  A
+layer built without ``q_lora_rank``, ``rope_scaling`` and
+``softmax_scale_mult`` builds the graph it built before they existed.
 
 The core product is the one fused-attention op (``ops/attention.py``): on a
 TPU the flash kernels over ``[B, H, S, d_n + d_r]`` queries and keys and
@@ -38,13 +46,15 @@ def _rms(x, w, eps):
     return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope_last(x, d_rope, theta):
+def _rope_last(x, d_rope, theta, scaling=None):
     """Rotary on the last ``d_rope`` of ``x [B, S, H, d]``."""
     import jax.numpy as jnp
     from ..ops.rotary import _rotary
     keep = x.shape[-1] - d_rope
+    more = {} if scaling is None else {"scaling": scaling}
     return jnp.concatenate(
-        [x[..., :keep], _rotary(x[..., keep:], theta=theta, seq_axis=1)], -1)
+        [x[..., :keep],
+         _rotary(x[..., keep:], theta=theta, seq_axis=1, **more)], -1)
 
 
 def _latent(kva, w_norm, *, rank, eps):
@@ -52,17 +62,18 @@ def _latent(kva, w_norm, *, rank, eps):
     return _rms(kva[..., :rank], w_norm, eps)
 
 
-def _queries(q, *w_norm, heads, d_rope, theta, eps):
+def _queries(q, *w_norm, heads, d_rope, theta, eps, scaling=None):
     """``x W_q [B, S, H (d_n + d_r)]`` -> ``[B, H, S, d_n + d_r]``; ``w_norm``:
     the norm's weight where the layer has one."""
     B, S, _ = q.shape
     q = q.reshape(B, S, heads, -1)
     if w_norm:
         q = _rms(q, w_norm[0], eps)
-    return _rope_last(q, d_rope, theta).transpose(0, 2, 1, 3)
+    return _rope_last(q, d_rope, theta, scaling).transpose(0, 2, 1, 3)
 
 
-def _keys(kvb, kva, *w_norm, heads, d_nope, d_rope, rank, theta, eps):
+def _keys(kvb, kva, *w_norm, heads, d_nope, d_rope, rank, theta, eps,
+          scaling=None):
     """A head's key: its own part of ``c W_kvb`` beside the one rotary part
     all heads share: ``[B, H, S, d_n + d_r]``."""
     import jax.numpy as jnp
@@ -72,7 +83,7 @@ def _keys(kvb, kva, *w_norm, heads, d_nope, d_rope, rank, theta, eps):
     k = jnp.concatenate([nope, rope], -1)
     if w_norm:
         k = _rms(k, w_norm[0], eps)
-    return _rope_last(k, d_rope, theta).transpose(0, 2, 1, 3)
+    return _rope_last(k, d_rope, theta, scaling).transpose(0, 2, 1, 3)
 
 
 def _values(kvb, *, heads, d_nope):
@@ -97,9 +108,14 @@ class LatentAttention(BaseLayer):
     def __init__(self, hidden_size, num_heads, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                  rope_theta=10000.0, qk_norm=True, head_gate=True, eps=1e-6,
+                 q_lora_rank=None, rope_scaling=None, softmax_scale_mult=None,
                  name=None):
         name = fresh_name(name or "mla")
         self.num_heads = num_heads
+        self.rope_scaling = rope_scaling
+        self.scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5
+        if softmax_scale_mult is not None:
+            self.scale *= softmax_scale_mult
         self.d_nope, self.d_rope, self.d_v = (qk_nope_head_dim,
                                               qk_rope_head_dim, v_head_dim)
         self.rank, self.theta, self.eps = kv_lora_rank, rope_theta, eps
@@ -109,7 +125,14 @@ class LatentAttention(BaseLayer):
         def var(n, shape, how=None):
             return VariableOp(f"{name}_{n}", shape,
                               how or init.xavier_normal())
-        self.q_proj = var("q_weight", (hidden_size, num_heads * d_qk))
+        #: the low-rank query path: ``W_qa``, the norm's weight, then
+        #: ``q_proj`` is ``W_qb``
+        self.qa_proj = self.qa_norm = None
+        if q_lora_rank:
+            self.qa_proj = var("qa_weight", (hidden_size, q_lora_rank))
+            self.qa_norm = var("qa_norm_scale", (q_lora_rank,), init.ones())
+        self.q_proj = var("q_weight",
+                          (q_lora_rank or hidden_size, num_heads * d_qk))
         self.kva_proj = var("kva_weight",
                             (hidden_size, kv_lora_rank + qk_rope_head_dim))
         self.kv_norm = var("kv_norm_scale", (kv_lora_rank,), init.ones())
@@ -129,18 +152,22 @@ class LatentAttention(BaseLayer):
         S = lambda fn, *a, **kw: _Scoped(fn, _SCOPE, *a, **kw)
         rot = dict(heads=self.num_heads, d_rope=self.d_rope,
                    theta=self.theta, eps=self.eps)
+        if self.rope_scaling is not None:
+            rot["scaling"] = self.rope_scaling
         kva = S(project, x, self.kva_proj)
         c = S(_latent, kva, self.kv_norm, rank=self.rank, eps=self.eps)
         kvb = S(project, c, self.kvb_proj)
         normed = self.q_norm is not None
-        q = S(_queries, S(project, x, self.q_proj),
+        xq = x if self.qa_proj is None else S(
+            _rms, S(project, x, self.qa_proj), self.qa_norm, eps=self.eps)
+        q = S(_queries, S(project, xq, self.q_proj),
               *([self.q_norm] if normed else []), **rot)
         k = S(_keys, kvb, kva, *([self.k_norm] if normed else []),
               d_nope=self.d_nope, rank=self.rank, **rot)
         v = S(_values, kvb, heads=self.num_heads, d_nope=self.d_nope)
         with scope(_SCOPE):
             ctx_ = scaled_dot_product_attention_op(
-                q, k, v, causal=True, scale=self.d_qk ** -0.5)
+                q, k, v, causal=True, scale=self.scale)
         gate = ([] if self.gate_proj is None
                 else [S(project, x, self.gate_proj)])
         return S(_out, ctx_, self.out_proj, *gate)

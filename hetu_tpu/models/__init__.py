@@ -21,6 +21,8 @@ from .ouro import (OuroConfig, OuroModel, OuroForCausalLM, OURO_CONFIGS,
                    record_exit_shares)
 from .laguna import (LagunaConfig, LagunaModel, LagunaForCausalLM,
                      LAGUNA_CONFIGS)
+from .xing4 import (Xing4Config, Xing4Model, Xing4ForCausalLM,
+                    XING4_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,

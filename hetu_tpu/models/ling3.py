@@ -67,7 +67,6 @@ class Ling3Config:
                  share_expert_swiglu_limit_list=None,
                  router_bias_update_rate=1e-3, seq_len=2048,
                  experts_held=None, remat="layer"):
-        assert q_lora_rank is None, "a low-rank query path is not modelled"
         assert score_function == "sigmoid", score_function
         assert kda_safe_gate, "the chunked rule needs the bounded gate"
         for name, limits in (
@@ -101,6 +100,7 @@ class Ling3Config:
         self.moe_renorm_topk = norm_topk_prob
         self.routed_scaling_factor = routed_scaling_factor
         self.kv_lora_rank = kv_lora_rank
+        self.q_lora_rank = q_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
@@ -134,7 +134,8 @@ class Ling3DecoderLayer(BaseLayer):
                 c.hidden_size, c.num_heads, c.kv_lora_rank,
                 c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
                 rope_theta=c.rope_theta, qk_norm=c.use_qk_norm,
-                head_gate=True, eps=c.rms_eps, name=f"{name}_mla")
+                head_gate=True, eps=c.rms_eps, q_lora_rank=c.q_lora_rank,
+                name=f"{name}_mla")
         else:
             self.mixer = KimiDeltaAttention(
                 c.hidden_size, c.num_heads, c.head_dim,

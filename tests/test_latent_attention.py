@@ -1,6 +1,8 @@
 """Latent attention's training half: the layer against a plain softmax with
-two head sizes, the head-wise gate, rotary on the rope part alone; flash
-attention with keys wider than values against ``jax.numpy``."""
+two head sizes, the head-wise gate, rotary on the rope part alone, the
+low-rank query path, YaRN on the rope part and the scores' multiplier; the
+Ling path's graph and loss as they were before those; flash attention with
+keys wider than values against ``jax.numpy``."""
 
 import jax
 import jax.numpy as jnp
@@ -15,14 +17,33 @@ from hetu_tpu.ops.pallas.flash_attention import (entries, flash_attention,
 H, DN, DR, DV, RANK, HID, S = 2, 32, 16, 24, 20, 40, 48
 
 
-def plain(p, x, name, gated=True, normed=True, theta=1e4):
+QRANK = 12
+YARN = dict(factor=64.0, original=16, beta_fast=32.0, beta_slow=1.0)
+
+
+def yarn_inverse_frequencies(theta):
+    """transformers' YaRN over the ``DR`` rotary dimensions, by hand."""
+    inv = 1.0 / theta ** (np.arange(0, DR, 2) / DR)
+
+    def dimension(turns):
+        return (DR * np.log(YARN["original"] / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+    low = max(np.floor(dimension(YARN["beta_fast"])), 0)
+    high = min(np.ceil(dimension(YARN["beta_slow"])), DR - 1)
+    ramp = np.clip((np.arange(DR // 2) - low) / max(high - low, 0.001), 0, 1)
+    return inv * (1 - ramp) + inv / YARN["factor"] * ramp
+
+
+def plain(p, x, name, gated=True, normed=True, theta=1e4, low_rank=False,
+          yarn=False, scale_mult=1.0):
     """The layer's equations in plain ``jax.numpy`` (the docstring of
     ``layers/latent_attention.py``)."""
     w = lambda n: jnp.asarray(p[f"{name}_{n}"])
     rms = lambda t, s: t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True)
                                          + 1e-6) * s
     B = x.shape[0]
-    q = (x @ w("q_weight")).reshape(B, S, H, DN + DR)
+    xq = rms(x @ w("qa_weight"), w("qa_norm_scale")) if low_rank else x
+    q = (xq @ w("q_weight")).reshape(B, S, H, DN + DR)
     kva = x @ w("kva_weight")
     c = rms(kva[..., :RANK], w("kv_norm_scale"))
     kvb = (c @ w("kvb_weight")).reshape(B, S, H, DN + DV)
@@ -33,7 +54,8 @@ def plain(p, x, name, gated=True, normed=True, theta=1e4):
         q, k = rms(q, w("q_norm_scale")), rms(k, w("k_norm_scale"))
 
     def rope(t):
-        inv = 1.0 / theta ** (jnp.arange(0, DR, 2) / DR)
+        inv = (jnp.asarray(yarn_inverse_frequencies(theta)) if yarn
+               else 1.0 / theta ** (jnp.arange(0, DR, 2) / DR))
         ang = jnp.arange(S)[:, None] * inv[None, :]
         cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None]
         sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None]
@@ -41,7 +63,7 @@ def plain(p, x, name, gated=True, normed=True, theta=1e4):
         turned = jnp.concatenate([-r[..., DR // 2:], r[..., :DR // 2]], -1)
         return jnp.concatenate([keep, r * cos + turned * sin], -1)
     q, k = rope(q), rope(k)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(DN + DR)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(DN + DR) * scale_mult
     i = jnp.arange(S)
     s = jnp.where(i[:, None] >= i[None, :], s, -jnp.inf)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
@@ -64,6 +86,63 @@ def test_layer_is_a_plain_softmax_with_two_head_sizes(gated, normed):
     assert got.shape == (2, S, HID)
     want = plain(ex.params, jnp.asarray(xv), name, gated, normed)
     assert np.abs(got - np.asarray(want)).max() < 2e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("low_rank,yarn,mult", [
+    (True, False, None), (False, True, None), (False, False, 2.0047),
+    (True, True, 2.0047)])
+def test_low_rank_queries_yarn_and_the_scores_multiplier(low_rank, yarn,
+                                                         mult):
+    """Xing4.0's mixer: ``c_q = N(x W_qa)`` in front of ``W_qb``, YaRN's
+    frequencies on the rope part (tables times 1), the scores' scale times
+    ``m^2``; no QK-norm, no gate."""
+    from hetu_tpu.ops.rotary import yarn_scaling
+    name = f"mla_x_{int(low_rank)}{int(yarn)}{int(bool(mult))}"
+    layer = LatentAttention(
+        HID, H, RANK, DN, DR, DV, rope_theta=1e4, qk_norm=False,
+        head_gate=False, q_lora_rank=QRANK if low_rank else None,
+        rope_scaling=yarn_scaling(YARN["factor"], YARN["original"],
+                                  YARN["beta_fast"], YARN["beta_slow"], 1.0)
+        if yarn else None, softmax_scale_mult=mult, name=name)
+    assert layer.q_proj.shape == ((QRANK if low_rank else HID), H * (DN + DR))
+    assert (layer.qa_proj is not None) == low_rank
+    x = ht.placeholder_op(f"{name}_x", (2, S, HID))
+    ex = ht.Executor([layer(x)], seed=2)
+    if low_rank:
+        ex.params[f"{name}_qa_norm_scale"] = ex.params[
+            f"{name}_qa_norm_scale"] * 1.3
+    xv = np.random.default_rng(2).standard_normal((2, S, HID)).astype(
+        np.float32)
+    (got,) = ex.run(feed_dict={x: xv}, convert_to_numpy_ret_vals=True)
+    kw = dict(gated=False, normed=False, low_rank=low_rank, yarn=yarn,
+              scale_mult=mult or 1.0)
+    want = plain(ex.params, jnp.asarray(xv), name, **kw)
+    assert np.abs(got - np.asarray(want)).max() < 2e-5 * np.abs(want).max()
+    # each of the three is seen: the plain form without it is far off
+    for off in ("low_rank", "yarn", "scale_mult"):
+        if kw[off] in (False, 1.0) or off == "low_rank":
+            continue
+        other = plain(ex.params, jnp.asarray(xv), name,
+                      **dict(kw, **{off: False if off == "yarn" else 1.0}))
+        assert np.abs(got - np.asarray(other)).max() > 1e-3 * np.abs(
+            want).max(), off
+
+
+def test_the_ling_path_builds_the_graph_and_the_loss_it_had():
+    """``q_lora_rank=None``, no scaling, no multiplier: the Ling toy program
+    (``tests/test_ling3_reference.py build``) has the node count and, to the
+    bit, the loss and the logits' sum it had at the parent of PR 56 (read
+    there: 325 and 337 nodes, loss 0x1.6459d4p+2)."""
+    import test_ling3_reference as ling
+    model, ex, variables, feed = ling.build(name="lingpin")
+    out = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    assert len(ex.subexecutor["forward"].topo) == 325
+    assert len(ex.subexecutor["grads"].topo) == 337
+    assert float(out[1]).hex() == "0x1.6459d40000000p+2"
+    assert float(np.float64(out[0]).sum()).hex() == "-0x1.243e79034bd00p+3"
+    mla = model.model.layers[5].mixer
+    assert mla.qa_proj is None and mla.rope_scaling is None
+    assert mla.scale == (32 + 16) ** -0.5
 
 
 def test_the_gate_is_one_number_a_head_and_rotary_spares_the_nope_part():

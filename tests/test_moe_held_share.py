@@ -617,3 +617,78 @@ def test_the_kernel_stands_in_a_held_layers_step_and_in_no_other(
         assert rows_choices() == before
     finally:
         jax.clear_caches()
+
+
+def test_two_shares_of_a_hyper_connected_ffn_add_up_to_the_uncut_sublayer():
+    """Xing4.0's FFN sublayer at its toy size: the shares ``experts_held [0,
+    8]`` and ``[8, 8]`` of a 16-expert layer behind the same hyper-connection,
+    the shared expert in the first alone and the streams' own part ``Hres X``
+    counted once, add up to the uncut reference's output of the whole
+    hyper-connected sublayer (``chipbench/reference/xing4.py``)."""
+    from hetu_tpu.layers import RMSNorm
+    from hetu_tpu.layers.hyper_connection import HyperConnection
+    from chipbench.reference import xing4 as ref_xing4
+    C, Fx, Ex, n, Tx = 64, 32, 16, 4, 40
+    c = {"num_experts_per_tok": 2, "n_group": 1, "topk_group": 1,
+         "norm_topk_prob": True, "routed_scaling_factor": 2.0,
+         "hc_eps": 1e-6, "hc_sinkhorn_iters": 8, "mhc_h_res_clamp_min": -30,
+         "mhc_h_res_clamp_max": 30, "rms_norm_eps": 1e-6}
+    norm = RMSNorm(C, eps=1e-6, name="hcshare_norm")
+    x = ht.placeholder_op("hcshare_x", (1, Tx, n * C))
+    hcs, moes, outs = [], [], []
+    for j, shared in enumerate((Fx, None)):
+        hcs.append(HyperConnection(C, n, 8, 1e-6, (-30, 30),
+                                   name=f"hcshare_hc{j}"))
+        moes.append(MoELayer(
+            C, Fx, Ex, k=2, capacity_factor=None, expert_act="swiglu",
+            renorm_topk=True, held=(8 * j, 8), shared_width=shared,
+            shared_gate=False, router_score="sigmoid", router_scale=2.0,
+            router_groups=(1, 1), name=f"hcshare_moe{j}"))
+        outs.append(hcs[j].sublayer(x, norm, moes[j]))
+    ex = ht.Executor({"f": outs + [hcs[0].hres]}, seed=13)
+    r = np.random.default_rng(13)
+    w = {"router": r.normal(0, 0.5, (C, Ex)), "router_bias": r.normal(
+            0, 0.1, (Ex,)),
+         "w_gate": r.normal(0, 0.1, (Ex, C, Fx)), "w_up": r.normal(
+             0, 0.1, (Ex, C, Fx)), "w_down": r.normal(0, 0.1, (Ex, Fx, C)),
+         "shared_gate": r.normal(0, 0.1, (C, Fx)), "shared_up": r.normal(
+             0, 0.1, (C, Fx)), "shared_down": r.normal(0, 0.1, (Fx, C))}
+    w = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
+    maps = (r.normal(0, (n * C) ** -0.5, (n * C, 2 * n + n * n)),
+            r.normal(0, 0.5, 2 * n + n * n), r.uniform(0.5, 1.0, 3))
+    maps = tuple(jnp.asarray(m, jnp.float32) for m in maps)
+    scale = jnp.asarray(r.normal(1, 0.2, (C,)), jnp.float32)
+    ex.params[norm.scale.name] = scale
+    for j, (hc, moe) in enumerate(zip(hcs, moes)):
+        for var, value in zip((hc.phi, hc.b, hc.alpha), maps):
+            ex.params[var.name] = value
+        ex.params[moe.gate.wg.name] = w["router"]
+        ex.params[moe.gate.bias.name] = w["router_bias"]
+        for var, key in ((moe.w1, "w_gate"), (moe.w3, "w_up"),
+                         (moe.w2, "w_down")):
+            ex.params[var.name] = w[key][8 * j:8 * j + 8]
+    for var, key in zip(moes[0].shared, ("shared_gate", "shared_up",
+                                         "shared_down")):
+        ex.params[var.name] = w[key]
+    X = r.normal(0, 1, (1, Tx, n, C)).astype(np.float32)
+    first, second, hres = ex.run(
+        "f", feed_dict={x: X.reshape(1, Tx, n * C)},
+        convert_to_numpy_ret_vals=True)
+    mm = lambda a, b: a @ b
+
+    def ffn(u):
+        h = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True)
+                              + 1e-6) * scale
+        return ref_xing4.expert_block(h.reshape(Tx, C), w, c,
+                                      mm)[0].reshape(1, Tx, C)
+    with jax.default_matmul_precision("highest"):
+        whole, res = ref_xing4.hyper_connection(jnp.asarray(X), maps, c, mm,
+                                                ffn)
+    np.testing.assert_allclose(hres, np.asarray(res), atol=2e-6)
+    own = np.einsum("bsij,bsjc->bsic", hres, X).reshape(1, Tx, n * C)
+    total = first + second - own
+    np.testing.assert_allclose(total.reshape(1, Tx, n, C), np.asarray(whole),
+                               atol=2e-5)
+    # neither share is the whole: the sum needs both
+    assert np.abs(first.reshape(whole.shape) - np.asarray(whole)).max() > 1e-2
+    assert np.abs(second - own).max() > 1e-2
