@@ -30,11 +30,20 @@ row), ``b`` one ``[2 n + n^2]`` and ``alpha`` the three gains.  Initial values
 is): while the streams are equal, as ``expand`` makes them, a fresh model is
 the plain pre-norm residual ``x + F(norm(x))`` on every stream.
 
-Every op stands under the one scope ``hetu_hc`` (maps, Sinkhorn, both mixes;
-``jax.numpy``, forward and backward).  ``hetu_hc_entry_total{path}`` counts
-the sublayers built by what runs the mixes (``xla``: no Pallas kernel is
-written; the readers' bytes due are stated once a sublayer application
-whatever implements it, ``chipbench/flops_xing4.py``).
+Every op stands under the one scope ``hetu_hc`` (maps, Sinkhorn, both mixes,
+forward and backward).  What runs them: on a TPU two Pallas kernel pairs
+(``ops/pallas/hyper_connection.py``, PR 57: ``hetu_hc_pre_fwd`` / ``_bwd``
+before the sublayer's function, the maps, ``u`` and ``R = Hres X`` from one
+read of the streams; ``hetu_hc_mix_fwd`` / ``_bwd`` behind it, ``X' = R +
+Hpost^T y``), where ``dispatch.take("hc_mix", ..)`` and the kernels'
+``unsupported`` say so (a stream of whole lane tiles, bf16 or f32, no mesh);
+everywhere else the ``jax.numpy`` functions of this file (``_maps``, ``_pre``,
+``_mix``), which are also what the tests hold the kernels to.  Each choice is
+counted at trace time in ``hetu_kernel_choice_total{kernel="hc_mix"}``;
+``hetu_hc_entry_total{path}`` counts the sublayers built by what the layer's
+own arguments and the platform say will run the mixes (``pallas`` / ``xla``).
+The readers' bytes due are stated once a sublayer application whatever
+implements it (``chipbench/flops_xing4.py``).
 """
 
 from __future__ import annotations
@@ -45,6 +54,7 @@ from .base import BaseLayer, fresh_name
 from .. import initializers as init, telemetry
 from ..graph.node import VariableOp
 from ..ops.base import ScopedOp as _Scoped
+from ..ops.pallas import dispatch, hyper_connection as kernels
 
 _SCOPE = "hetu_hc"
 
@@ -115,7 +125,36 @@ def _mix(x, maps, y, *, n):
 
 def _res(maps, *, n):
     """``Hres [B, S, n, n]`` out of the maps (a comparison fetches it)."""
-    return maps[..., 2 * n:].reshape(maps.shape[:-1] + (n, n))
+    return maps[..., 2 * n:2 * n + n * n].reshape(maps.shape[:-1] + (n, n))
+
+
+class _Before(_Scoped):
+    """``(x, phi, b, alpha) -> (u, maps, R)``: what stands before the
+    sublayer's function.  Through the kernels ``maps`` is as wide as they keep
+    it (the layer's maps its first lanes) and ``R = Hres X`` is what
+    ``_Behind`` adds to; in the ``jax.numpy`` form ``R`` is None and
+    ``_Behind`` mixes ``x`` itself."""
+
+    def _compute(self, input_vals, ctx):
+        x, phi, b, alpha = input_vals
+        if dispatch.take("hc_mix", ctx.mesh,
+                         kernels.unsupported(x, self.attrs["n"])):
+            return kernels.pre(x, phi, b, alpha, **self.attrs)
+        maps = _maps(x, phi, b, alpha, **self.attrs)
+        return _pre(x, maps, n=self.attrs["n"]), maps, None
+
+
+def _item(before, *, index):
+    return before[index]
+
+
+def _behind(before, x, y, *, n):
+    """``X'`` from what ``_Before`` left, the streams and the function's
+    output."""
+    _, maps, r = before
+    if r is None:
+        return _mix(x, maps, y, n=n)
+    return kernels.mix(r, maps, y, n=n)
 
 
 def _initial_bias(n):
@@ -145,6 +184,7 @@ class HyperConnection(BaseLayer):
                  clamp=(-30.0, 30.0), name=None):
         name = fresh_name(name or "hc")
         self.n, self.iters, self.eps = n, iters, eps
+        self.hidden_size = hidden_size
         self.clamp = (float(clamp[0]), float(clamp[1]))
         width = 2 * n + n * n
         self.phi = VariableOp(f"{name}_phi", (n * hidden_size, width),
@@ -159,18 +199,30 @@ class HyperConnection(BaseLayer):
     def collapse(self, x):
         return collapse(x, self.n)
 
+    def path(self):
+        """What will run a sublayer's mixes, as far as the platform and the
+        layer's own arguments say (the streams' type and a mesh are the
+        trace's to see: ``hetu_kernel_choice_total`` has those)."""
+        import jax
+        import jax.numpy as jnp
+        streams = jax.ShapeDtypeStruct((self.n * self.hidden_size,),
+                                       jnp.bfloat16)
+        took = dispatch.mosaic() and kernels.unsupported(
+            streams, self.n) is None
+        return "pallas" if took else "xla"
+
     def sublayer(self, x, norm, f):
         from ..graph.node import scope
         telemetry.get_registry().counter(
             "hetu_hc_entry_total",
             "Hyper-connected sublayers built, by what runs the two mixes "
             "(xla: the jax.numpy form; pallas: a kernel)", labels=("path",),
-        ).labels(path="xla").inc()
-        maps = _Scoped(_maps, _SCOPE, x, self.phi, self.b, self.alpha,
-                       n=self.n, iters=self.iters, eps=self.eps,
-                       clamp=self.clamp)
-        self.hres = _Scoped(_res, _SCOPE, maps, n=self.n)
-        u = _Scoped(_pre, _SCOPE, x, maps, n=self.n)
+        ).labels(path=self.path()).inc()
+        before = _Before(None, _SCOPE, x, self.phi, self.b, self.alpha,
+                         n=self.n, iters=self.iters, eps=self.eps,
+                         clamp=self.clamp)
+        self.hres = _Scoped(_res, _SCOPE, _Scoped(_item, _SCOPE, before,
+                                                  index=1), n=self.n)
         with scope("hetu_norm"):
-            h = norm(u)
-        return _Scoped(_mix, _SCOPE, x, maps, f(h), n=self.n)
+            h = norm(_Scoped(_item, _SCOPE, before, index=0))
+        return _Scoped(_behind, _SCOPE, before, x, f(h), n=self.n)

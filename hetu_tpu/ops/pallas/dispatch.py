@@ -92,7 +92,8 @@ MESH = "mesh"
 #: there unless the caller asked for the kernels.  Any other label says
 #: ``platform:<name>``, as the kernels with a plan do.
 NO_CHOICE_OFF_TPU = frozenset({"gated_delta", "ssd", "kda", "causal_conv",
-                               "gated_norm", "moe_rows", "rotary"})
+                               "gated_norm", "moe_rows", "rotary",
+                               "hc_mix"})
 
 
 def take(kernel, mesh, reason=None, asked=False):

@@ -1092,3 +1092,81 @@ def test_kda_scan_node_compiles_for_v5e_in_place(v5e, as_on_tpu, dtype):
     assert not re.findall(r" = \w+\[[\d,]*,32,128\]\S* ", entry)
     if dtype == jnp.bfloat16:
         assert not re.findall(r" = f32\[1,8192,\d+\]\S* ", entry)
+
+
+def test_xing4_toy_step_compiles_for_v5e_on_the_hyper_connection_kernels(
+        v5e, as_on_tpu):
+    """The Xing4.0 cell's builder at toy size with streams of one whole lane
+    tile (hidden 128), bf16, whole layers recomputed: the train step compiles
+    for a v5e with ``hetu_hc_pre_fwd``, ``hetu_hc_mix_fwd`` and their backward
+    kernels as Mosaic calls, every one under the block ``hetu_hc``; over the
+    six sublayers a backward kernel runs once each, ``mix`` forward once and
+    once more where a recomputed layer's second sublayer reads it (XLA drops
+    the recomputed layer's last ``mix``: nothing reads it), ``pre`` forward at
+    least twice.  Then one sublayer at the cell's size ``[1, 4096, 4 x
+    3584]``, forward and backward: the four kernels under their scoped VMEM,
+    and XLA's own count of the bytes it holds (printed: ``PERF.md``)."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from chipbench import run
+    from chipbench.builders import xing4 as builder
+    from hetu_tpu.ops.pallas import hyper_connection as kernels
+    one = SingleDeviceSharding(v5e.devices[0])
+    _, _, config, mix = run.load_cell("xing4.0-29b-a4b.b1-s4096")
+    config = run.merge(run.merge(config, config["toy"]), {
+        "hidden_size": 128,
+        "job": {"remat": "layer", "compute_dtype": "bfloat16"}})
+    jax.clear_caches()
+    prog = builder.build(config, run.merge(mix, mix["toy"]), 2 ** 31 + 3,
+                         lambda msg: None)
+    try:
+        sub = prog.ex.subexecutor["train"]
+        if sub._jitted is None:
+            sub._build()
+        args = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            sub._abstract_args(None))
+        hlo = sub._jitted.lower(*args).compile().as_text()
+    finally:
+        prog.close()
+        jax.clear_caches()
+    calls = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "hetu_hc_" in ln]
+    counts = {}
+    for ln in calls:
+        op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "hetu_hc)" in op_name or "hetu_hc/" in op_name, op_name
+        name = re.search(r"/(hetu_hc_\w+?)/pallas_call", op_name).group(1)
+        counts[name] = counts.get(name, 0) + 1
+    assert counts.pop("hetu_hc_pre_fwd") >= 12
+    assert counts == {"hetu_hc_mix_fwd": 9, "hetu_hc_mix_bwd": 6,
+                      "hetu_hc_pre_bwd": 6}
+
+    n, c, tokens = 4, 3584, 4096
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    how = dict(n=n, iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+
+    def loss(x, phi, b, alpha, w):
+        u, maps, r = kernels.pre(x, phi, b, alpha, **how)
+        return jnp.sum(kernels.mix(r, maps, u * w, n=n).astype(
+            jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((1, tokens, n * c), jnp.bfloat16),
+        sds((n * c, kernels.width(n)), jnp.bfloat16),
+        sds((kernels.width(n),), jnp.float32), sds((3,), jnp.float32),
+        sds((c,), jnp.bfloat16)).compile()
+    hlo = compiled.as_text()
+    names = [re.search(r"/(hetu_hc_\w+?)/pallas_call", ln).group(1)
+             for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert names == ["hetu_hc_pre_fwd", "hetu_hc_mix_fwd", "hetu_hc_mix_bwd",
+                     "hetu_hc_pre_bwd"]
+    entry = hlo[hlo.index("\nENTRY "):]            # what reaches HBM
+    assert not re.findall(rf" = f32\[1,{tokens},\d+\]\S* ", entry)
+    stats = compiled.memory_analysis()
+    print(f"one hyper-connected sublayer at [1, {tokens}, {n} x {c}] bf16, "
+          f"forward and backward, compiled for a v5e: arguments "
+          f"{stats.argument_size_in_bytes} B, outputs "
+          f"{stats.output_size_in_bytes} B, temporaries "
+          f"{stats.temp_size_in_bytes} B")
+    assert stats.temp_size_in_bytes < 16 * tokens * n * c   # 8 streams' bytes

@@ -13,10 +13,11 @@ import jax.numpy as jnp
 
 import hetu_tpu as ht
 from hetu_tpu.graph.node import graph_variables
-from hetu_tpu.layers import RMSNorm
+from hetu_tpu.layers import RMSNorm, hyper_connection as forms
 from hetu_tpu.layers.hyper_connection import (HyperConnection, collapse,
                                               expand, sinkhorn)
 from hetu_tpu.models.llama import LlamaMLP, residual_sublayer
+from hetu_tpu.ops.pallas import dispatch, hyper_connection as kernels
 
 B, S, C, N, ITERS, EPS = 2, 12, 16, 4, 20, 1e-6
 CLAMP = (-30.0, 30.0)
@@ -200,3 +201,197 @@ def test_the_entry_counter_counts_a_sublayer_built():
         assert "hetu_hc" in ht.scopes()
     finally:
         telemetry.disable()
+
+
+# -- the Pallas kernel pairs (``ops/pallas/hyper_connection.py``) ------------------
+# In interpret mode on the CPU, against ``_maps`` / ``_pre`` / ``_mix``: what
+# Mosaic makes of them is compiled in ``tests/test_flash_attention.py``.
+
+#: fewer rounds than the layer's 20 where a case does not need them: a round
+#: is 80 traced operations and their transposes, in interpret mode
+FEW = 4
+
+
+def operands(n, c, tokens, dtype, seed=0, b_res=None):
+    """``x [2, tokens / 2, n c]``, ``phi``, ``b``, ``alpha``, ``y``."""
+    r = np.random.default_rng(seed)
+    k = 2 * n + n * n
+    b = r.normal(0, 0.5, k)
+    if b_res is not None:
+        b[2 * n:] = b_res
+    return (jnp.asarray(r.normal(0, 1, (2, tokens // 2, n * c)), dtype),
+            jnp.asarray(r.normal(0, (n * c) ** -0.5, (n * c, k)), dtype),
+            jnp.asarray(b, jnp.float32),
+            jnp.asarray(r.uniform(0.5, 1.0, 3), jnp.float32),
+            jnp.asarray(r.normal(0, 1, (2, tokens // 2, c)), dtype))
+
+
+def by_forms(x, phi, b, alpha, y, n, iters):
+    maps = forms._maps(x, phi, b, alpha, n=n, iters=iters, eps=EPS,
+                       clamp=CLAMP)
+    u = forms._pre(x, maps, n=n)
+    return u, maps, forms._mix(x, maps, y + u, n=n)
+
+
+def by_kernels(x, phi, b, alpha, y, n, iters):
+    u, maps, r = kernels.pre(x, phi, b, alpha, n=n, iters=iters, eps=EPS,
+                             clamp=CLAMP)
+    return u, maps[..., :2 * n + n * n], kernels.mix(r, maps, y + u, n=n)
+
+
+def values_and_cotangents(f, args, n, iters, seed=1):
+    """``f``'s three outputs and the gradient, by every operand, of their
+    inner product with fixed draws."""
+    r = np.random.default_rng(seed)
+    shapes = jax.eval_shape(lambda *a: f(*a, n, iters), *args)
+    draws = [jnp.asarray(r.normal(0, 1, s.shape), jnp.float32) for s in shapes]
+
+    def loss(*a):
+        return sum(jnp.sum(o.astype(jnp.float32) * d)
+                   for o, d in zip(f(*a, n, iters), draws))
+    with jax.default_matmul_precision("highest"):
+        return (f(*args, n, iters),
+                jax.grad(loss, argnums=tuple(range(5)))(*args))
+
+
+def gap(got, want):
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    return np.abs(got - want).max() / max(np.abs(want).max(), 1e-30)
+
+
+@pytest.mark.parametrize("n,c,tokens,dtype,iters,tol", [
+    (4, 128, 200, "float32", ITERS, 2e-5),  # a tile and 72 tokens of the next
+    (1, 256, 128, "float32", ITERS, 2e-5),  # one stream of two lane tiles
+    (2, 128, 24, "float32", ITERS, 2e-5),   # fewer tokens than a tile
+    (4, 256, 256, "bfloat16", FEW, 2e-2),   # the cell's type, two tiles
+])
+def test_the_kernel_pairs_are_the_jnp_forms(n, c, tokens, dtype, iters, tol):
+    """``u``, the maps and ``X'``, and ``dX``, ``dphi``, ``db``, ``dalpha``,
+    ``dy``: the kernels against the layer's own functions."""
+    args = operands(n, c, tokens, jnp.dtype(dtype))
+    want, dwant = values_and_cotangents(by_forms, args, n, iters)
+    got, dgot = values_and_cotangents(by_kernels, args, n, iters)
+    for name, g, w in zip(("u", "maps", "out"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert gap(g, w) < tol, (name, gap(g, w))
+    for name, g, w in zip(("dx", "dphi", "db", "dalpha", "dy"), dgot, dwant):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert np.abs(np.asarray(w, np.float32)).max() > 0, name
+        assert gap(g, w) < tol, (name, gap(g, w))
+
+
+@pytest.mark.parametrize("at", [100.0, -100.0])
+def test_the_kernels_clamp_holds_at_logits_of_a_hundred(at):
+    """One logit of ``Hres`` at +-100 and ``phi = 0``: the kernels' maps are
+    the clamped logits' Sinkhorn, and every value and cotangent is finite and
+    the forms'."""
+    b_res = np.zeros(N * N)
+    b_res[5] = at
+    x, phi, b, alpha, y = operands(N, 128, 128, jnp.float32, b_res=b_res)
+    args = (x, jnp.zeros_like(phi), b, alpha, y)
+    want, dwant = values_and_cotangents(by_forms, args, N, FEW)
+    got, dgot = values_and_cotangents(by_kernels, args, N, FEW)
+    clamped = jnp.asarray(np.clip(b_res, *CLAMP).reshape(N, N), jnp.float32)
+    hres = np.asarray(got[1])[0, 0, 2 * N:].reshape(N, N)
+    assert np.abs(hres - np.asarray(sinkhorn(clamped, FEW, EPS,
+                                             (-1e9, 1e9)))).max() < 1e-6
+    for g, w in zip(got + dgot, want + dwant):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        assert np.abs(np.asarray(g, np.float32)
+                      - np.asarray(w, np.float32)).max() < 2e-5 * max(
+                          1.0, np.abs(np.asarray(w, np.float32)).max())
+
+
+@pytest.mark.parametrize("shape,dtype,n,why", [
+    ((1, 8, 512), "bfloat16", 4, None),
+    ((1, 8, 14336), "bfloat16", 4, None),               # the cell's
+    ((1, 8, 64), "float32", 4, "stream_not_whole_lane_tiles"),
+    ((1, 8, 384), "float32", 2, "stream_not_whole_lane_tiles"),   # 192 a stream
+    ((1, 8, 512), "float16", 4, "dtype:float16"),
+    ((1, 8, 1280), "float32", 10, "maps_wider_than_a_lane_tile"),
+    ((1, 8, 4 * 16384), "float32", 4, "rows_exceed_vmem"),
+])
+def test_unsupported_says_why(shape, dtype, n, why):
+    assert kernels.unsupported(
+        jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)), n) == why
+
+
+@pytest.fixture
+def asked(monkeypatch):
+    """The layer asks for its kernels as it does on a TPU, and gets them in
+    interpret mode (``dispatch.take(asked=True)``)."""
+    import types
+    monkeypatch.setattr(forms, "dispatch", types.SimpleNamespace(
+        mosaic=lambda: True,
+        take=lambda kernel, mesh, why:
+        dispatch.take(kernel, mesh, why, asked=True)))
+
+
+def wide_program(name, c=128):
+    """``program`` at a stream of one whole lane tile, with its feed."""
+    hc = HyperConnection(c, N, FEW, EPS, CLAMP, name=f"{name}_hc")
+    norm = RMSNorm(c, eps=EPS, name=f"{name}_norm")
+    w = ht.Variable(f"{name}_w", shape=(c, c),
+                    initializer=ht.init.normal(0.0, 0.1))
+    x = ht.placeholder_op(f"{name}_x", (B, S, N * c))
+    y = hc.sublayer(x, norm, lambda h: ht.matmul_op(h, w))
+    loss = ht.reduce_sum_op(y * y, axes=None)
+    variables = [hc.phi, hc.b, hc.alpha, norm.scale, w]
+    ex = ht.Executor({"forward": [y, hc.hres],
+                      "grads": [loss] + ht.gradients(loss, variables)},
+                     seed=0)
+    r = np.random.default_rng(7)
+    ex.params[hc.phi.name] = jnp.asarray(
+        r.normal(0, (N * c) ** -0.5, hc.phi.shape), jnp.float32)
+    ex.params[hc.b.name] = jnp.asarray(r.normal(0, 0.5, hc.b.shape),
+                                       jnp.float32)
+    ex.params[hc.alpha.name] = jnp.asarray(r.uniform(0.5, 1.0, 3),
+                                           jnp.float32)
+    ex.params[w.name] = jnp.asarray(r.normal(0, 0.1, (c, c)), jnp.float32)
+    return ex, {x: r.normal(0, 1, (B, S, N * c)).astype(np.float32)}
+
+
+def test_the_layer_through_the_kernels_is_the_layer(asked, live_registry):
+    """One sublayer through the executor, forward and every gradient, with
+    the kernels asked for and without: the same numbers; one ``pallas`` choice
+    a traced program, ``path="pallas"`` once a sublayer built."""
+    def counts():
+        return (dict((lab["path"], n) for lab, n in dispatch.counted(
+            "hetu_hc_entry_total")),
+            {k[1:]: n for k, n in dispatch.choices().items()
+             if k[0] == "hc_mix"})
+    built, chosen = counts()
+    ex, feed = wide_program("hc_asked")
+    got = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    dgot = ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    after, taken = counts()
+    assert after.get("pallas", 0) == built.get("pallas", 0) + 1
+    assert after.get("xla", 0) == built.get("xla", 0)
+    assert taken.get(("pallas", ""), 0) >= chosen.get(("pallas", ""), 0) + 2
+    assert set(taken) == {("pallas", "")} | set(chosen)
+    forms.dispatch = dispatch             # the fixture puts its own back
+    ex, feed = wide_program("hc_unasked")
+    want = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    dwant = ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    assert counts()[1] == taken           # off a TPU, unasked: nothing recorded
+    assert counts()[0].get("xla", 0) == built.get("xla", 0) + 1
+    assert got[1].shape == (B, S, N, N)
+    for g, w in zip(got + dgot, want + dwant):
+        assert np.abs(g - w).max() < 2e-5 * max(1.0, np.abs(w).max())
+
+
+def test_a_narrow_stream_asked_for_says_why_and_runs_the_forms(asked,
+                                                               live_registry):
+    """A stream of 16 lanes: the layer is built ``xla``, and asked for the
+    kernels at trace time it records the refusal and runs ``_maps``, ``_pre``
+    and ``_mix``."""
+    before = dispatch.choices().get(
+        ("hc_mix", "jnp", "stream_not_whole_lane_tiles"), 0)
+    ex, x, _, (_, _, _, X, _, _) = loaded("hc_narrow")
+    got, _ = ex.run("forward", feed_dict={x: X.reshape(B, S, -1)},
+                    convert_to_numpy_ret_vals=True)
+    assert np.isfinite(got).all()
+    assert dispatch.choices()[
+        ("hc_mix", "jnp", "stream_not_whole_lane_tiles")] > before
+    assert HyperConnection(16, N, name="hc_narrow_path").path() == "xla"
+    assert HyperConnection(128, N, name="hc_wide_path").path() == "pallas"

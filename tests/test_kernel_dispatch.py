@@ -109,6 +109,7 @@ TABLE = {
     "gated_norm": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_rows": (PALLAS, MESH, NOTHING, NOTHING),
     "rotary": (PALLAS, MESH, NOTHING, NOTHING),
+    "hc_mix": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_gmm": (PALLAS, MESH, CPU, MESH),
 }
 
