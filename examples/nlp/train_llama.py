@@ -33,6 +33,13 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            with 8 of 64 experts held and the shared expert), the
            multi-token-prediction depth behind them, an eighth of the
            vocabulary)
+       python examples/nlp/train_llama.py --model zaya1-8b \
+           --layers 5 --experts-held 0:8 --vocab-rows 32784 \
+           --seq-len 8192 --batch-size 1     (ZAYA1-8B at one chip's share of
+           a two-way expert-parallel job: five layers of compressed
+           convolutional attention and top-1 experts behind the router that
+           carries its state down the depth, 8 of 16 experts held, an eighth
+           of the tied vocabulary)
 """
 
 import os
@@ -56,7 +63,8 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              GRANITE_HYBRID_CONFIGS, OuroConfig,
                              OuroForCausalLM, OURO_CONFIGS, LagunaConfig,
                              LagunaForCausalLM, LAGUNA_CONFIGS, Xing4Config,
-                             Xing4ForCausalLM, XING4_CONFIGS,
+                             Xing4ForCausalLM, XING4_CONFIGS, Zaya1Config,
+                             Zaya1ForCausalLM, ZAYA1_CONFIGS,
                              record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
@@ -68,7 +76,7 @@ def main():
                              + list(NEMOTRON_H_CONFIGS)
                              + list(GRANITE_HYBRID_CONFIGS)
                              + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)
-                             + list(XING4_CONFIGS)))
+                             + list(XING4_CONFIGS) + list(ZAYA1_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -82,7 +90,7 @@ def main():
                          "it is a slice of the published vocabulary, ids, "
                          "logits and the loss are over the slice")
     ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
-                    help="qwen3-next, nemotron, xing4: the experts of each "
+                    help="qwen3-next, nemotron, xing4, zaya1: the experts of each "
                          "layer this chip holds, of the router's full width")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -114,6 +122,9 @@ def main():
               (XING4_CONFIGS, Xing4Config, Xing4ForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in XING4_CONFIGS else
+              (ZAYA1_CONFIGS, Zaya1Config, Zaya1ForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in ZAYA1_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family

@@ -817,8 +817,19 @@ class SubExecutor:
 
     def _fetch(self, vals):
         """``fetch`` phase: the step's one synchronisation point — the
-        wait for the device and the copy of the results to the host."""
+        wait for the device and the copy of the results to the host.
+        Where a step hands out several values, every copy is asked for
+        before the first is waited for, so the copies follow the step's
+        program back to back and the host sleeps once, not once a value
+        with the device idle between.  One value is simply waited for: a
+        copy asked for ahead of the program's end is one more hand-over
+        between the runtime's threads, which several values repay and one
+        does not."""
         with self._tr.span("fetch"):
+            ahead = [v for v in vals if hasattr(v, "copy_to_host_async")]
+            if len(ahead) > 1:
+                for v in ahead:
+                    v.copy_to_host_async()
             return [None if v is None else np.asarray(v) for v in vals]
 
     def run_steps(self, feed_dict, n, convert_to_numpy_ret_vals=False):

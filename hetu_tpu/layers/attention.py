@@ -127,6 +127,16 @@ gate_heads_in_place_op = simple_op(_gate_heads_in_place,
                                    "gate_heads_in_place")
 
 
+def count_layout(layout, reason):
+    """One more attention layer built, in ``hetu_attn_layout_total``."""
+    telemetry.get_registry().counter(
+        "hetu_attn_layout_total",
+        "Attention layers built, by the graph they build (bshd: on the "
+        "projections' [B, S, heads*d] in place; bhsd: heads split off "
+        "and transposed) and why", labels=("layout", "reason"),
+    ).labels(layout=layout, reason=reason).inc()
+
+
 class MultiHeadAttention(BaseLayer):
     def __init__(self, hidden_size, num_heads, sequence_length=None,
                  dropout_rate=0.0, causal_mask=False, num_kv_heads=None,
@@ -248,12 +258,7 @@ class MultiHeadAttention(BaseLayer):
                 "non-rotary, non-alibi cross-attention")
         kv_seq_len = kv_seq_len or seq_len
         layout, reason = self.layout()
-        telemetry.get_registry().counter(
-            "hetu_attn_layout_total",
-            "Attention layers built, by the graph they build (bshd: on the "
-            "projections' [B, S, heads*d] in place; bhsd: heads split off "
-            "and transposed) and why", labels=("layout", "reason"),
-        ).labels(layout=layout, reason=reason).inc()
+        count_layout(layout, reason)
         if layout == "bhsd":
             return self._attend_bhsd(query, key, value, attention_mask,
                                      seq_len, kv_seq_len)

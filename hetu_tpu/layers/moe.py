@@ -172,23 +172,116 @@ class BalanceGate(BaseLayer):
         return base_balance_gating(tokens @ wg, capacity)
 
 
+def _state_router(u, w_down, b_down, norm_w, w1, b1, w2, b2, w3, *rest,
+                  eps):
+    """``(logits [T, E + skip], state [.., R])``, both f32: the equations of
+    ``StateRouter``; ``rest`` is ``(gamma, prev)`` where a state comes in."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+
+    def mm(a, w):
+        return jnp.matmul(a, w.astype(f32),
+                          precision=jax.lax.Precision.HIGHEST)
+    r = mm(u.astype(f32), w_down) + b_down.astype(f32)
+    if rest:
+        gamma, prev = rest
+        r = r + gamma.astype(f32) * prev.astype(f32)
+    h = r * jax.lax.rsqrt(jnp.mean(r * r, -1, keepdims=True) + eps)
+    h = h * norm_w.astype(f32)
+    h = jax.nn.gelu(mm(h, w1) + b1.astype(f32), approximate=False)
+    h = jax.nn.gelu(mm(h, w2) + b2.astype(f32), approximate=False)
+    logits = mm(h, w3)
+    return logits.reshape(-1, logits.shape[-1]), r
+
+
+class StateRouter(BaseLayer):
+    """The ZAYA1 router (arXiv:2511.17127): an MLP of width ``width`` on a
+    down-projection of the token that carries its own state down the depth.
+    Called ``(u, prev_state) -> (logits [T, E + skip], state [.., R])`` on the
+    normed hidden states ``u [.., C]`` and the state the layer above handed on
+    (None in the first layer), under ``hetu_moe_route``, in f32 at full
+    matmul precision whatever the compute type::
+
+        r = u W_d + b_d  (+ gamma * prev_state)          the state handed on
+        logits = W_3 gelu(W_2 gelu(W_1 N_w(r) + b_1) + b_2)
+
+    The softmax over the ``num_experts + skip`` logits, the choice by ``p +
+    bias`` and the gate ``p_e`` are the layer's (``route``: ``ops/moe.py
+    top_k_route(score="softmax", bias=)``), so that one function routes every
+    dropless layer; the last ``skip`` choices are no experts
+    (``MoELayer(router=)``).  ``bias [E + skip]`` is no weight: no
+    gradient, no optimizer state, moved inside the step by ``bias_rate *
+    sign(mean(load) - load)`` like ``TopKGate``'s.  ``gamma`` (ones at the
+    start) enters the graph only where a state comes in.  GELU is the exact
+    one (assumed)."""
+
+    def __init__(self, hidden_size, num_experts, width, skip=1, eps=1e-5,
+                 bias_rate=None, name=None):
+        name = fresh_name(name or "state_router")
+        self.num_experts, self.width, self.skip = num_experts, width, skip
+        self.eps, self.bias_rate = eps, bias_rate
+        out = num_experts + skip
+
+        def var(n, shape, how):
+            return VariableOp(f"{name}_{n}", shape, how)
+        self.down = var("down_weight", (hidden_size, width),
+                        init.xavier_uniform())
+        self.down_bias = var("down_bias", (width,), init.zeros())
+        self.gamma = var("eda_scale", (width,), init.ones())
+        self.norm = var("norm_scale", (width,), init.ones())
+        self.w1, self.w2 = (var(f"mlp{i}_weight", (width, width),
+                                init.xavier_uniform()) for i in (1, 2))
+        self.b1, self.b2 = (var(f"mlp{i}_bias", (width,), init.zeros())
+                            for i in (1, 2))
+        self.w3 = var("out_weight", (width, out), init.xavier_uniform())
+        self.bias = VariableOp(f"{name}_bias", (out,), init.zeros(),
+                               trainable=False)
+        #: a gate's weight that ``_MoEOp`` would multiply the tokens by: none
+        self.wg = None
+
+    def __call__(self, u, prev_state=None):
+        from ..ops.base import ScopedOp
+        from ..ops.rotary import pair_item_op
+        more = () if prev_state is None else (self.gamma, prev_state)
+        with scope("hetu_moe_route"):
+            both = ScopedOp(_state_router, "hetu_moe_route", u, self.down,
+                            self.down_bias, self.norm, self.w1, self.b1,
+                            self.w2, self.b2, self.w3, *more, eps=self.eps)
+            return pair_item_op(both, index=0), pair_item_op(both, index=1)
+
+    def route(self, tokens, logits, k, bias=None):
+        """``(logits, idx, gate, probs)`` as ``TopKGate.route``: the softmax,
+        the ``k`` largest of ``p + bias`` and their ``p``, not renormalised."""
+        from ..ops.moe import top_k_route
+        return (logits,) + top_k_route(logits, k, renorm=False,
+                                       score="softmax", bias=bias)
+
+
 class _MoEOp(Op):
     """Fused gate+dispatch+experts+combine (single graph node so the EP
     sharding annotations stay local to the op)."""
 
     def __init__(self, x, gate, w1, b1, w2, b2, num_experts, capacity_factor,
                  k, ep_axis=None, ids=None, sparse=True, w3=None,
-                 load_var=None, held=None, name=None):
+                 load_var=None, held=None, scores=None, state=None, skip=0,
+                 name=None):
         # swiglu experts are biasless: b1/b2 are None and stay out of the
         # graph entirely (no dead optimizer state / checkpoint entries)
         inputs = [x, w1, w2] if b1 is None else [x, w1, b1, w2, b2]
         self.has_biases = b1 is not None
         if w3 is not None:                    # swiglu experts: up proj
             inputs.append(w3)
-        if gate.wg is not None:
-            inputs.append(gate.wg)
+        # what the router hands the op: a gate's weight, or the logits of a
+        # router that is a layer of its own (``StateRouter``)
+        self.router_in = gate.wg if scores is None else scores
+        if self.router_in is not None:
+            inputs.append(self.router_in)
         if ids is not None:
             inputs.append(ids)
+        self._state_at = len(inputs) if state is not None else None
+        if state is not None:        # read for its RMS alone (the load's row)
+            inputs.append(state)
         self.bias_var = getattr(gate, "bias", None)
         self._bias_at = len(inputs)
         if self.bias_var is not None:
@@ -208,6 +301,10 @@ class _MoEOp(Op):
         self.has_ids = ids is not None
         self.load_var = load_var
         self.held = held
+        #: the router's last ``skip`` choices are no experts
+        self.skip = skip
+        assert not skip or (held is not None and held[1] >= 2), (
+            "a skip choice is laid out as a pair held nowhere (held=)")
         assert held is None or capacity_factor is None, (
             "a share of the experts (held=) is laid out by the dropless path")
         if capacity_factor is None:
@@ -234,7 +331,7 @@ class _MoEOp(Op):
             b1 = b2 = None
             rest = list(input_vals[3:])
         w3 = rest.pop(0) if self.has_w3 else None
-        wg = rest.pop(0) if self.gate.wg is not None else None
+        wg = rest.pop(0) if self.router_in is not None else None
         ids = rest.pop(0) if self.has_ids else None
         return x, w1, b1, w2, b2, w3, wg, ids
 
@@ -278,7 +375,8 @@ class _MoEOp(Op):
             return
         rate = self.gate.bias_rate
         with named_scope("hetu_moe_route"):
-            load = expert_load(idx, self.num_experts).astype(jnp.float32)
+            load = expert_load(idx, self.num_experts + self.skip).astype(
+                jnp.float32)
             bias = self._bias(input_vals, ctx).astype(jnp.float32)
             ctx.record_update(self.bias_var, bias + rate * jnp.sign(
                 jnp.mean(load) - load))
@@ -288,7 +386,8 @@ class _MoEOp(Op):
         with ``held`` a third row whose first entry is the pairs routed to
         experts held elsewhere, and a fourth of the pairs computed by a pass
         after the first) to the executor's state (``MoELayer.load()`` fetches
-        them beside the loss)."""
+        them beside the loss; with skip choices a fifth: the pairs that
+        chose none of the experts, and the carried router state's RMS)."""
         import jax.numpy as jnp
         if self.load_var is not None:
             ctx.record_update(self.load_var, jnp.stack(
@@ -321,10 +420,21 @@ class _MoEOp(Op):
                 tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
                 held=self.held,
                 rows=held_rows(T * self.k, self.num_experts, count))
-            self._record_load(
-                ctx, lay["load"], lay["computed"],
-                jnp.zeros((count,), jnp.int32).at[0].set(lay["elsewhere"]),
-                lay["computed"] - lay["kept"])
+            none = jnp.zeros((count,), jnp.int32)
+            rows = [lay["load"], lay["computed"],
+                    none.at[0].set(lay["elsewhere"]),
+                    lay["computed"] - lay["kept"]]
+            if self.skip:
+                # a pair whose choice is no expert has no row and gives zero;
+                # the layout counted it among those held elsewhere
+                skipped = jnp.sum(idx >= self.num_experts)
+                rows[2] = none.at[0].set(lay["elsewhere"] - skipped)
+                last = none.astype(jnp.float32).at[0].set(skipped)
+                if self._state_at is not None:
+                    r = input_vals[self._state_at].astype(jnp.float32)
+                    last = last.at[1].set(jnp.sqrt(jnp.mean(r * r)))
+                rows.append(last)
+            self._record_load(ctx, *rows)
             return y.reshape(orig_shape)
         C = self._capacity(T)
 
@@ -534,6 +644,14 @@ class MoELayer(BaseLayer):
     The load is ``[4, count]`` then: routed here, computed (the same),
     in ``[2, 0]`` the pairs routed elsewhere, and the pairs that took a pass
     after the first.
+    ``router=`` is a router that is a layer of its own (``StateRouter``):
+    the op takes its logits where it took the gate's weight, selects on
+    ``softmax + bias`` and hands the router's state on (``__call__(x,
+    state=)``, ``self.state``).  Its last ``router.skip`` outputs are no
+    experts: a pair that chooses one goes to no expert, gives zero, and is
+    counted apart in a fifth row of the load (``[4, 0]``; ``[4, 1]`` is the
+    RMS of the router state); the layer is laid out as a held one (all its
+    experts where ``held`` is None).
     ``shared_width`` adds a shared expert of that width and of the experts'
     kind (swiglu or relu2), computed for every token, and ``shared_gate``
     says whether the sigmoid of a one-column gate scales it
@@ -545,8 +663,20 @@ class MoELayer(BaseLayer):
                  renorm_topk=True, track_load=False, held=None,
                  shared_width=None, shared_gate=True, router_score="softmax",
                  router_scale=None, router_bias_rate=None,
-                 router_groups=None, name=None):
+                 router_groups=None, router=None, name=None):
         name = fresh_name(name or "moe")
+        self.router, self.skip = router, getattr(router, "skip", 0)
+        #: the router's state node of the last call (``router=``)
+        self.state = None
+        if router is not None:
+            assert capacity_factor is None and gate == "top", (
+                "a router layer routes the dropless path")
+            assert router.num_experts == num_experts, (
+                "the router's width is the layer's")
+            gate = router
+        if self.skip and held is None:
+            # a pair that chose no expert is laid out as one held nowhere
+            held = (0, num_experts)
         self.held = held
         n_held = num_experts
         if held is not None:
@@ -623,7 +753,8 @@ class MoELayer(BaseLayer):
         self.sparse = sparse
         self.load_var = VariableOp(
             f"{name}_load",
-            (2, num_experts) if held is None else (4, n_held), init.zeros(),
+            (2, num_experts) if held is None
+            else (5 if self.skip else 4, n_held), init.zeros(),
             trainable=False) if track_load else None
         if ep_axis is not None:
             ep_vars = [v for v in (self.w1, self.b1, self.w2, self.b2,
@@ -633,8 +764,14 @@ class MoELayer(BaseLayer):
                 v.dist_state = DistState({0: ep_axis})
         self.last_op = None
 
-    def __call__(self, x, ids=None):
-        if self.gate.wg is None and ids is None:
+    def __call__(self, x, ids=None, state=None):
+        """``state``: the router state the layer above handed on
+        (``router=``; this layer's is ``self.state`` afterwards)."""
+        more = {}
+        if self.router is not None:
+            scores, self.state = self.router(x, state)
+            more = dict(scores=scores, state=self.state, skip=self.skip)
+        elif self.gate.wg is None and ids is None:
             raise ValueError(
                 "hash-gated MoELayer requires token ids: moe(x, ids=...)")
         # what of the block lies outside its five regions (the reshapes,
@@ -647,7 +784,8 @@ class MoELayer(BaseLayer):
                                   self.capacity_factor, self.k,
                                   ep_axis=self.ep_axis, ids=ids,
                                   sparse=self.sparse, w3=self.w3,
-                                  load_var=self.load_var, held=self.held)
+                                  load_var=self.load_var, held=self.held,
+                                  **more)
             if self.shared is not None:
                 return self.last_op + shared_expert_op(x, *self.shared,
                                                        **self.shared_kind)
@@ -701,6 +839,10 @@ def record_moe_load(layer, load, bias=None):
     router sent to experts this device does not hold, and
     ``hetu_moe_pairs_over_bound_total{layer}`` those of the held experts'
     pairs that one pass's rows did not hold and a further pass computed.
+    From a layer with skip choices (``[5, count]``)
+    ``hetu_moe_pairs_skipped_total{layer}`` counts the pairs that chose no
+    expert and ``hetu_moe_router_state_rms{layer}`` is the RMS of the router
+    state the layer handed on, last step.
 
     The registry counts nothing while telemetry is disabled."""
     from .. import telemetry
@@ -721,6 +863,13 @@ def record_moe_load(layer, load, bias=None):
                     "Pairs on held experts computed by a pass after the first",
                     labels=("layer",)).labels(layer=layer).inc(
                         load[3].sum() if len(load) > 3 else 0)
+    if len(load) > 4 and total + load[2, 0] + load[4, 0] > 0:
+        reg.counter("hetu_moe_pairs_skipped_total",
+                    "Routed pairs whose choice is no expert (computed by none)",
+                    labels=("layer",)).labels(layer=layer).inc(load[4, 0])
+        reg.gauge("hetu_moe_router_state_rms",
+                  "RMS of the router state a layer handed on, last step",
+                  labels=("layer",)).labels(layer=layer).set(load[4, 1])
     if total <= 0:          # the state's initial zeros: no step has run
         return
     reg.counter("hetu_moe_pairs_routed_total",

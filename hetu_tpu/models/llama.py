@@ -19,11 +19,12 @@ from operator import add
 
 import numpy as np
 
-from ..graph.node import remat, scope, stage, scoped_init
+from ..graph.node import VariableOp, remat, scope, stage, scoped_init
 from .. import initializers as init
 from ..layers import Embedding, Linear, RMSNorm
-from ..layers.base import BaseLayer
+from ..layers.base import BaseLayer, fresh_name
 from ..layers.attention import MultiHeadAttention
+from ..ops.base import ScopedOp
 from ..ops.rotary import RopeTables
 from ..ops import (array_reshape_op, matmul_op, silu_op,
                    softmax_cross_entropy_sparse_op)
@@ -144,8 +145,39 @@ class LlamaMLP(BaseLayer):
             return self.down(silu_op(self.gate(x)) * self.up(x))
 
 
+def _merge(x, y, s_r, b_r, s_f, b_f):
+    """``s_r (x + b_r) + s_f (y + b_f)`` in f32, one rounding to ``x``'s
+    type."""
+    import jax.numpy as jnp
+    out = x.dtype
+    x, y, s_r, b_r, s_f, b_f = (a.astype(jnp.float32)
+                                for a in (x, y, s_r, b_r, s_f, b_f))
+    return (s_r * (x + b_r) + s_f * (y + b_f)).astype(out)
+
+
+class ResidualMerge(BaseLayer):
+    """The four ``hidden``-vectors of a residual-scaled sublayer (ZAYA1's
+    ``scale_residual_merge``): ``x' = s_r (x + b_r) + s_f (F(N(x)) + b_f)``,
+    the scales ones and the biases zeros at the start (the plain residual),
+    bias before scale (assumed).  One node under ``hetu_norm``, f32 inside,
+    the stream's type out."""
+
+    def __init__(self, hidden_size, name=None):
+        name = fresh_name(name or "merge")
+        self.s_r, self.b_r, self.s_f, self.b_f = (
+            VariableOp(f"{name}_{n}", (hidden_size,), how)
+            for n, how in (("res_scale", init.ones()),
+                           ("res_bias", init.zeros()),
+                           ("out_scale", init.ones()),
+                           ("out_bias", init.zeros())))
+
+    def __call__(self, x, y):
+        return ScopedOp(_merge, "hetu_norm", x, y, self.s_r, self.b_r,
+                        self.s_f, self.b_f)
+
+
 def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
-                      seq_len=None, post_norm=None):
+                      seq_len=None, post_norm=None, merge=None):
     """One pre-norm residual sublayer, ``x + sublayer(norm(x))`` (``* scale``
     where a family multiplies its residual branches): the norm and the sum
     under the block `hetu_norm`, the sublayer under the names it gives
@@ -155,7 +187,8 @@ def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
     pass keeps of it is what enters it, the residual stream alone.
     ``post_norm`` is a second norm BEHIND the sublayer (sandwich),
     ``x + post_norm(sublayer(norm(x)))``: it and the sum then stand inside
-    the recomputed group too."""
+    the recomputed group too.  ``merge`` (a ``ResidualMerge``) stands where
+    the sum stands: ``s_r (x + b_r) + s_f (sublayer(norm(x)) + b_f)``."""
     with (remat() if recompute else nullcontext()):
         with scope("hetu_norm"):
             h = norm(x)
@@ -165,6 +198,9 @@ def residual_sublayer(x, norm, sublayer, recompute=False, scale=None,
             with scope("hetu_norm"):
                 y = post_norm(y)
                 return x + (y if scale is None else y * scale)
+    if merge is not None:
+        assert scale is None and post_norm is None
+        return merge(x, y)
     with scope("hetu_norm"):
         return x + (y if scale is None else y * scale)
 

@@ -381,7 +381,9 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
     lower expert index; ``renorm`` rescales them to sum to 1 (Mixtral), the
     default uses them as they are (OLMoE's ``norm_topk_prob: false``).
     Everything in f32 whatever the logits' type, so that routing does not
-    depend on the compute type.
+    depend on the compute type.  With a ``bias`` the ``k`` experts are the
+    largest of ``p + bias`` and the gates the chosen ``p`` themselves (the
+    ZAYA1 router, ``layers/moe.py StateRouter``).
 
     ``score="sigmoid"`` is the router of DeepSeek-V3 (arXiv:2412.19437) and
     Nemotron-H: the scores are ``s = sigmoid(logits)``, the ``k`` experts are
@@ -397,7 +399,6 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
     lower group) and the ``k`` experts chosen among theirs alone.  One group
     is the ungrouped router, bit for bit."""
     assert score in ("softmax", "sigmoid"), score
-    assert bias is None or score == "sigmoid", "the bias selects by sigmoid"
     if score == "softmax":
         scores = probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     else:

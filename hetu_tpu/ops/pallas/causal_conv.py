@@ -66,9 +66,11 @@ LANES, TILE, CHUNK = 512, 2 ** 20, 32 * 512
 _F32 = jnp.float32
 
 
-def unsupported(x, w, b=None, window=None):
+def unsupported(x, w, b=None, window=None, act="silu"):
     """Why the kernels do not take ``causal_conv``'s operands, or None when
     they do."""
+    if act != "silu":                   # SiLU is inside both kernels
+        return f"act:{str(act).lower()}"
     lo, hi = window or (0, x.shape[-1])
     if lo % 128 or (hi - lo) % 128:
         return "channels_not_128_aligned"

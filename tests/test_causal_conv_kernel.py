@@ -304,3 +304,72 @@ def test_layer_through_the_kernels_is_the_layer(kind, monkeypatch):
     for a, b in zip(g2, g1):
         assert a.shape == b.shape and np.abs(b).max() > 0
         assert np.abs(a - b).max() < 1e-4 * np.abs(b).max()
+
+
+# -- no activation (compressed convolutional attention's depthwise taps) ------
+
+@pytest.mark.parametrize("K", [2, 4])
+@pytest.mark.parametrize("bias", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_without_an_activation_the_form_is_the_shifted_sum(dtype, bias, K):
+    """``act=None``: ``y_t = sum_j w_j x_(t - K + 1 + j) + b`` in f32, one
+    cast; its gradients are the shifted sum's."""
+    x, w, b, dy, _ = conv_inputs(2, 48, 128, K=K, bias=bias, dtype=dtype)
+    f32 = jnp.float32
+
+    def plain(x, w, b):
+        xp = jnp.pad(x.astype(f32), ((0, 0), (K - 1, 0), (0, 0)))
+        y = sum(xp[:, j:j + 48] * w[j].astype(f32) for j in range(K))
+        return (y if b is None else y + b.astype(f32)).astype(x.dtype)
+    got = causal_conv_jnp(x, w, b, None, None)
+    np.testing.assert_array_equal(got, plain(x, w, b))
+    assert np.abs(np.asarray(got - causal_conv_jnp(x, w, b), f32)).max() > 0.1
+    mine = grads(lambda x, w, b, window: causal_conv_jnp(x, w, b, window,
+                                                         None), x, w, b, dy,
+                 None)
+    want = grads(lambda x, w, b, window: plain(x, w, b), x, w, b, dy, None)
+    for g, wnt in zip(mine, want):
+        close(g, wnt, dtype, "act=None")
+
+
+def test_the_kernels_answer_act_none_and_the_form_runs_with_that_reason(
+        conv_choices, monkeypatch):
+    """The kernel pair applies SiLU: without an activation it answers
+    ``act:none`` whatever the operands, and on a TPU ``causal_conv`` then runs
+    the ``jax.numpy`` form with that reason recorded, one sample a call."""
+    assert kernels.unsupported(sds((1, 8192, 1280)), sds((2, 1280)),
+                               sds((1280,)), None, None) == "act:none"
+    assert kernels.unsupported(sds((1, 8192, 1280)), sds((2, 1280)),
+                               sds((1280,)), None, "silu") is None
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    monkeypatch.setattr(kernels, "conv", None)                # never reached
+    x, w, b, _, _ = conv_inputs(1, 64, 128, K=2)
+    np.testing.assert_array_equal(causal_conv(x, w, b, act=None),
+                                  causal_conv_jnp(x, w, b, None, None))
+    assert conv_choices() == {("jnp", "act:none"): 1}
+
+
+def test_a_node_with_silu_is_the_node_it_was():
+    """``ConvOp(scope, x, w, b, window=)`` builds the node it built before
+    ``act`` existed (the four cells that call it: no new attribute), and
+    computes what it computed, to the bit; ``act=None`` is one more
+    attribute."""
+    import hetu_tpu as ht
+    x_v, w_v, b_v, _, window = conv_inputs(1, 32, 128, wide=128)
+    x = ht.placeholder_op("conv_node_x", x_v.shape)
+    w = ht.Variable("conv_node_w", shape=w_v.shape,
+                    initializer=ht.init.zeros())
+    b = ht.Variable("conv_node_b", shape=b_v.shape,
+                    initializer=ht.init.zeros())
+    silu = op.ConvOp("hetu_gdn_conv", x, w, b, window=window)
+    bare = op.ConvOp("hetu_gdn_conv", x, w, b, window=window, act=None)
+    assert silu.attrs == {"window": window}
+    assert bare.attrs == {"window": window, "act": None}
+    ex = ht.Executor({"forward": [silu, bare]}, seed=0)
+    ex.params[w.name], ex.params[b.name] = w_v, b_v
+    got = ex.run("forward", feed_dict={x: np.asarray(x_v)},
+                 convert_to_numpy_ret_vals=True)
+    np.testing.assert_allclose(got[0], causal_conv_jnp(x_v, w_v, b_v, window),
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        got[1], causal_conv_jnp(x_v, w_v, b_v, window, None), atol=1e-6)

@@ -692,3 +692,87 @@ def test_two_shares_of_a_hyper_connected_ffn_add_up_to_the_uncut_sublayer():
     # neither share is the whole: the sum needs both
     assert np.abs(first.reshape(whole.shape) - np.asarray(whole)).max() > 1e-2
     assert np.abs(second - own).max() > 1e-2
+
+
+def test_two_shares_behind_a_state_router_add_up_to_the_uncut_sublayer():
+    """ZAYA1's expert sublayer at its toy size: the shares ``experts_held [0,
+    4]`` and ``[4, 4]`` of an 8-expert layer behind the same router (9
+    outputs: the last computes nothing) and the same scaled residual, the
+    skipped tokens' zero and the residual's own part ``s_r (x + b_r) + s_f
+    b_f`` counted once, add up to the uncut reference's output of the whole
+    expert sublayer (``chipbench/reference/zaya1.py``)."""
+    from hetu_tpu.layers import RMSNorm
+    from hetu_tpu.layers.moe import StateRouter
+    from hetu_tpu.models.llama import ResidualMerge, residual_sublayer
+    from chipbench.reference import zaya1 as ref_zaya1
+    C, Fx, Ex, R, Tx = 64, 32, 8, 16, 128
+    c = {"rms_norm_eps": 1e-5}
+    norm = RMSNorm(C, eps=1e-5, name="srshare_norm")
+    x = ht.placeholder_op("srshare_x", (1, Tx, C))
+    routers, moes, merges, outs = [], [], [], []
+    for j in range(2):
+        routers.append(StateRouter(C, Ex, R, skip=1, name=f"srshare_r{j}"))
+        moes.append(MoELayer(C, Fx, Ex, k=1, capacity_factor=None,
+                             expert_act="swiglu", renorm_topk=False,
+                             track_load=True, held=(4 * j, 4),
+                             router=routers[j],
+                             name=f"srshare_moe{j}"))
+        merges.append(ResidualMerge(C, name=f"srshare_merge{j}"))
+        outs.append(residual_sublayer(x, norm, moes[j], merge=merges[j]))
+    ex = ht.Executor({"f": outs + [m.load() for m in moes]}, seed=17)
+    r = np.random.default_rng(17)
+    w = {"router.down": r.normal(0, C ** -0.5, (C, R)),
+         "router.down_bias": r.normal(0, 0.1, R),
+         "router.norm": r.normal(1, 0.2, R),
+         "router.w1": r.normal(0, R ** -0.5, (R, R)),
+         "router.b1": r.normal(0, 0.1, R),
+         "router.w2": r.normal(0, R ** -0.5, (R, R)),
+         "router.b2": r.normal(0, 0.1, R),
+         "router.w3": r.normal(0, 2 * R ** -0.5, (R, Ex + 1)),
+         "router.bias": r.normal(0, 0.02, Ex + 1),
+         "w_gate": r.normal(0, 0.1, (Ex, C, Fx)),
+         "w_up": r.normal(0, 0.1, (Ex, C, Fx)),
+         "w_down": r.normal(0, 0.1, (Ex, Fx, C)),
+         "mlp_merge.s_r": r.uniform(0.5, 1.5, C),
+         "mlp_merge.b_r": r.normal(0, 0.1, C),
+         "mlp_merge.s_f": r.uniform(0.5, 1.5, C),
+         "mlp_merge.b_f": r.normal(0, 0.1, C)}
+    w = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
+    scale = jnp.asarray(r.normal(1, 0.2, (C,)), jnp.float32)
+    ex.params[norm.scale.name] = scale
+    for j, (rt, moe, mg) in enumerate(zip(routers, moes, merges)):
+        for var, key in ((rt.down, "down"), (rt.down_bias, "down_bias"),
+                         (rt.norm, "norm"), (rt.w1, "w1"), (rt.b1, "b1"),
+                         (rt.w2, "w2"), (rt.b2, "b2"), (rt.w3, "w3"),
+                         (rt.bias, "bias")):
+            ex.params[var.name] = w[f"router.{key}"]
+        for var, key in ((moe.w1, "w_gate"), (moe.w3, "w_up"),
+                         (moe.w2, "w_down")):
+            ex.params[var.name] = w[key][4 * j:4 * j + 4]
+        for var, key in ((mg.s_r, "s_r"), (mg.b_r, "b_r"), (mg.s_f, "s_f"),
+                         (mg.b_f, "b_f")):
+            ex.params[var.name] = w[f"mlp_merge.{key}"]
+    X = r.normal(0, 1, (1, Tx, C)).astype(np.float32)
+    first, second, load0, load1 = ex.run(
+        "f", feed_dict={x: X}, convert_to_numpy_ret_vals=True)
+    with jax.default_matmul_precision("highest"):
+        u = ref_zaya1._norm(jnp.asarray(X), scale, 1e-5)
+        y, chosen, _ = ref_zaya1.experts(u, w, None, c,
+                                         ref_zaya1._Products(None))
+        whole = ref_zaya1.merge(jnp.asarray(X), y, w, "mlp_merge")
+    own = np.asarray(w["mlp_merge.s_r"] * (X + w["mlp_merge.b_r"])
+                     + w["mlp_merge.s_f"] * w["mlp_merge.b_f"])
+    np.testing.assert_allclose(first + second - own, np.asarray(whole),
+                               atol=2e-5)
+    # a token that chose no expert is its scaled residual and b_f, in both
+    skipped = np.asarray(chosen) == Ex
+    assert 0 < skipped.sum() < Tx
+    np.testing.assert_allclose(first[0][skipped], own[0][skipped], atol=1e-6)
+    np.testing.assert_allclose(second[0][skipped], own[0][skipped],
+                               atol=1e-6)
+    # neither share is the whole, and the counters add up to every token once
+    assert np.abs(first - np.asarray(whole)).max() > 1e-3
+    assert np.abs(second - np.asarray(whole)).max() > 1e-3
+    assert load0[4, 0] == load1[4, 0] == skipped.sum()
+    assert load0[0].sum() + load1[0].sum() + skipped.sum() == Tx
+    assert load0[2, 0] == load1[0].sum() and load1[2, 0] == load0[0].sum()
