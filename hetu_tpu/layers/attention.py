@@ -121,6 +121,9 @@ def _gate_in_place_bwd(kept, g):
 
 
 _gate_heads_in_place.defvjp(_gate_in_place_fwd, _gate_in_place_bwd)
+#: the function itself, for a layer whose node calls it on the context the
+#: kernels left (``layers/latent_attention.py _out``)
+gate_heads_in_place = _gate_heads_in_place
 
 gate_heads_op = simple_op(_gate_heads, "gate_heads")
 gate_heads_in_place_op = simple_op(_gate_heads_in_place,

@@ -20,20 +20,35 @@ a factor of 1 and the scale ``(0.1 mscale_all_dim ln factor + 1)^2``).  A
 layer built without ``q_lora_rank``, ``rope_scaling`` and
 ``softmax_scale_mult`` builds the graph it built before they existed.
 
-The core product is the one fused-attention op (``ops/attention.py``): on a
-TPU the flash kernels over ``[B, H, S, d_n + d_r]`` queries and keys and
-``[B, H, S, d_v]`` values, no operand padded to the other's width.  What a
-serving cache would hold (the latent ``c`` and ``k^rope``, and the absorbed
-decode that reads them) is not here (ROADMAP Queue 2, M7).
+The core product is the one fused-attention op (``ops/attention.py``).  What
+stands between the projections and it is ONE node (``_Heads``), which chooses
+by what it can see when it is traced (``dispatch.take("mla_pack", ..)``, counted
+in ``hetu_attn_layout_total``).  Heads of 128 + 64 over values of whole lane
+tiles, on a TPU with no mesh: the kernel pairs of ``ops/pallas/mla_pack.py``
+lay q and k out at a stride of 256 lanes a head on ``[B, S, H x 256]`` (norm
+and rotation in that pass, 64 exact zeros behind a head's 192) and cut the
+values out of ``c W_kvb``; the flash kernels read all three in place (``bshd``
+with two head sizes, entry ``bshd_v128``) and write the context ``[B, S, H
+d_v]``, which the gate a head and ``W_o`` read as it lies: no view by heads
+and no transpose of anything.  Everywhere else (the CPU, a mesh, other head
+sizes): ``_queries`` / ``_keys`` / ``_values`` on the views by heads and the
+flash kernels (or the ``jax.numpy`` composition) over ``[B, H, S, d_n + d_r]``
+queries and keys and ``[B, H, S, d_v]`` values, no operand padded to the
+other's width.  What a serving cache would hold (the latent ``c`` and
+``k^rope``, and the absorbed decode that reads them) is not here (ROADMAP
+Queue 2, M7).
 """
 
 from __future__ import annotations
 
+from .attention import count_layout, gate_heads_in_place
 from .base import BaseLayer, fresh_name, project
 from .. import initializers as init
 from ..graph.node import VariableOp, scope
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.base import ScopedOp as _Scoped
+from ..ops.pallas import dispatch, mla_pack as kernels
+from ..ops.rotary import pair_item_op
 
 _SCOPE = "hetu_attn"
 
@@ -91,11 +106,61 @@ def _values(kvb, *, heads, d_nope):
     return kvb.reshape(B, S, heads, -1)[..., d_nope:].transpose(0, 2, 1, 3)
 
 
+def _by_heads(q, kvb, kva, *w_norm, d_nope, rank, **rot):
+    """``(q, k, v)`` on the views by heads, ``[B, H, S, .]``."""
+    return (_queries(q, *w_norm[:1], **rot),
+            _keys(kvb, kva, *w_norm[1:], d_nope=d_nope, rank=rank, **rot),
+            _values(kvb, heads=rot["heads"], d_nope=d_nope))
+
+
+def _in_place(q, kvb, kva, *w_norm, heads, d_nope, d_rope, rank, theta, eps,
+              scaling=None):
+    """``(q^ [B, S, H x 256], k^ [B, S, H x 256], v [B, S, H d_v])`` through
+    the kernel pairs; the tables are a rotation of the first ``d_rope`` lanes
+    of one lane tile (``ops/pallas/rotary.py``'s three-table form)."""
+    from ..ops.rotary import _pair_tables
+    more = {} if scaling is None else {"scaling": scaling}
+    tables = kernels.tables(_pair_tables(
+        seq_len=q.shape[1], dim=kernels.LANES, theta=theta, rotary_dim=d_rope,
+        **more))
+    wq, wk = w_norm or (None, None)
+    k, v = kernels.keys_values(kvb, kva[..., rank:], tables, wk, heads, eps)
+    return kernels.queries(q, tables, wq, heads, eps), k, v
+
+
+class _Heads(_Scoped):
+    """``(x W_q, c W_kvb, x W_kva) -> (q, k, v)``, with the two norms' weights
+    ``(x W_q, w_q, c W_kvb, x W_kva, w_k)`` (the order in which the three
+    nodes this one stands for read them): at a stride of 256 lanes a head
+    through the kernels where ``dispatch.take`` says so (the one thing the
+    functions cannot see, a mesh, is the node's), else on the views by
+    heads."""
+
+    def _compute(self, input_vals, ctx):
+        if len(input_vals) == 5:
+            q, wq, kvb, kva, wk = input_vals
+            input_vals = (q, kvb, kva, wq, wk)
+        q, kvb = input_vals[:2]
+        a = self.attrs
+        why = kernels.unsupported(
+            q, heads=a["heads"], d_nope=a["d_nope"], d_rope=a["d_rope"],
+            d_v=kvb.shape[-1] // a["heads"] - a["d_nope"])
+        if dispatch.take("mla_pack", ctx.mesh, why):
+            count_layout("bshd", "latent_in_place")
+            return _in_place(*input_vals, **a)
+        count_layout("bhsd", "latent_" + (why or (
+            "no_mosaic" if ctx.mesh is None else "under_a_mesh")))
+        return _by_heads(*input_vals, **a)
+
+
 def _out(ctx_, w_out, *gate):
-    """``[B, H, S, d_v]`` -> ``[B, S, H d_v]``, each head times the sigmoid
-    of its one gate number (where the layer has a gate), then ``W_o``."""
+    """The context times ``W_o``, each head first times the sigmoid of its
+    one gate number (where the layer has a gate): ``[B, S, H d_v]`` as the
+    in-place kernels leave it, or ``[B, H, S, d_v]`` -> ``[B, S, H d_v]``."""
     import jax
     import jax.numpy as jnp
+    if ctx_.ndim == 3:
+        return (gate_heads_in_place(ctx_, gate[0]) if gate else ctx_) @ w_out
     o = ctx_.transpose(0, 2, 1, 3)
     if gate:
         o = (o.astype(jnp.float32)
@@ -160,14 +225,16 @@ class LatentAttention(BaseLayer):
         normed = self.q_norm is not None
         xq = x if self.qa_proj is None else S(
             _rms, S(project, x, self.qa_proj), self.qa_norm, eps=self.eps)
-        q = S(_queries, S(project, xq, self.q_proj),
-              *([self.q_norm] if normed else []), **rot)
-        k = S(_keys, kvb, kva, *([self.k_norm] if normed else []),
-              d_nope=self.d_nope, rank=self.rank, **rot)
-        v = S(_values, kvb, heads=self.num_heads, d_nope=self.d_nope)
+        heads = _Heads(None, _SCOPE, S(project, xq, self.q_proj),
+                       *([self.q_norm] if normed else []), kvb, kva,
+                       *([self.k_norm] if normed else []),
+                       d_nope=self.d_nope, rank=self.rank, **rot)
         with scope(_SCOPE):
+            q, k, v = (pair_item_op(heads, index=i) for i in range(3))
+            # ``num_heads`` is read where q, k and v come flat
             ctx_ = scaled_dot_product_attention_op(
-                q, k, v, causal=True, scale=self.scale)
+                q, k, v, causal=True, scale=self.scale,
+                num_heads=self.num_heads)
         gate = ([] if self.gate_proj is None
                 else [S(project, x, self.gate_proj)])
         return S(_out, ctx_, self.out_proj, *gate)

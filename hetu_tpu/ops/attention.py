@@ -64,6 +64,9 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
     why = unsupported(q, k, v, mask, keep, window)
     if why is not None:
         return why, (), ()
+    if (num_heads is not None and v.shape[-1] != q.shape[-1]
+            and (q.shape[-1] % 128 or v.shape[-1] % 128)):
+        return "two_head_sizes_not_128_aligned", (), ()
     if q.shape[-2] < _FLASH_MIN_SEQ:
         return f"seq<{_FLASH_MIN_SEQ}", (), ()
     if not all(32 <= d <= 512 and d % 8 == 0
@@ -83,7 +86,11 @@ class ScaledDotProductAttentionOp(Op):
     through the free ``[B, S, H, D]`` view.  There k and v may be ``[B, S,
     KV*D]`` of fewer heads (grouped queries; ``rep = H / KV`` is read from
     the widths): the kernel reads a query head's key head where it lies, the
-    jnp composition repeats the key heads on the view.
+    jnp composition repeats the key heads on the view.  V's heads may be of
+    another size than q's and k's (latent attention: 256 / 128), whole lane
+    tiles each.  A node built with ``num_heads`` whose operands come ``[B, H,
+    S, D]`` all the same (a layer that chooses its layout when it is traced)
+    attends them as that.
 
     ``window`` (``WindowAttentionOp``, causal): position ``i`` sees the keys
     ``j`` with ``0 <= i - j < window``, its own among them (512 keys at 512,
@@ -117,7 +124,7 @@ class ScaledDotProductAttentionOp(Op):
         q, k, v = input_vals[:3]
         mask = input_vals[3] if self.has_mask else None
         heads = self.num_heads
-        if heads is None:
+        if heads is None or q.ndim == 4:
             return self._attend(q, k, v, mask, ctx, None)
         if self._stays_in_place(q, k, v, mask, ctx):
             return self._attend(q, k, v, mask, ctx, heads)

@@ -31,6 +31,12 @@ QUERY head, as it does behind a ``repeat_kv``, and ``_group_sum`` adds each
 group up outside it (accumulating a group inside would fetch the whole q, o
 and dO again for every key block).  ``rep = 1`` is the program it was.
 
+V (and the context) may have ANOTHER head size than q and k in either layout:
+``[B, H, S, D]`` of any two sizes, or in place where both are whole lane tiles
+and a program takes one head (latent attention: ``[B, S, H x 256]`` over ``[B,
+S, H x 128]``, the entry ``bshd_v128``); the two walks (``_walks``) then
+differ in their width alone.
+
   forward : ``hetu_flash_fwd``, grid (B, H/g, Sq/block_q); the kv loop runs
             inside the kernel with running (m, l, acc) carries; saves the
             logsumexp ``[B, H/g, g, Sq]`` for the backward pass.
@@ -881,9 +887,10 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
             "scalar array; the per-tile dropout masks derive from it)")
     w, wv = _walks(q, k, v, num_heads)
     dv = wv.dim
-    # two head sizes (latent attention: keys 192 wide, values 128) come as
-    # [B, H, S, D]; heads read in place are one size
-    assert dv == w.dim or q.ndim == 4, (q.shape, v.shape)
+    # two head sizes (latent attention: keys wider than values) come as
+    # [B, H, S, D], or in place as whole lane tiles each, one head a program
+    assert dv == w.dim or q.ndim == 4 or w.group == wv.group == 1, (
+        q.shape, v.shape)
     _count_entry(w, dv, window)
     s, d = w.seq(q), w.dim
     if scale is None:

@@ -31,7 +31,8 @@ partial rotation (``rotary_dim`` < head size) keeps the kernels: its tables are
 ``dispatch.take``'s rule, and ``_rotary(seq_axis=1)`` then runs on each
 tensor's view.  ``rotary_embedding_op`` (the layers that stay on ``[B, H, S,
 D]``: a norm a head, the elementwise gate, grouped queries on heads that are
-not whole lane tiles; latent attention's ``_rope_last``) is ``_rotary``
+not whole lane tiles; latent attention's ``_rope_last`` wherever its own
+kernels, ``ops/pallas/mla_pack.py``, do not run) is ``_rotary``
 everywhere.
 
 Conventions match huggingface's ``rotate_half`` (non-interleaved halves),

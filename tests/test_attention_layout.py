@@ -7,7 +7,9 @@ gate a head in place against the one on ``[B, H, S, d]``; what the toy train
 steps of the cells that bypass the rule lower to (a stored hash: ``rep = 1``
 and the layers the rule leaves on ``[B, H, S, D]`` build what they built before
 PR 52); and the Laguna and Nemotron-H toys with heads of 128, which take the
-flat path, through the harness's own check of the kernels chosen."""
+flat path, through the harness's own check of the kernels chosen; and a latent
+layer, which chooses when it is traced: in place on a TPU, by heads under a
+mesh."""
 
 import hashlib
 import re
@@ -276,3 +278,52 @@ def test_toys_with_heads_of_128_take_the_flat_path(live_registry, cell,
         assert fallbacks == []
     finally:
         program.close()
+
+
+# -- the latent layer chooses when it is traced (PR 59) --------------------------
+
+@pytest.mark.parametrize("platform,mesh,layout,entry,choice", [
+    ("tpu", False, ("bshd", "latent_in_place"), ("bshd_v128", 1),
+     ("pallas", "")),
+    ("tpu", True, ("bhsd", "latent_under_a_mesh"), ("bhsd_v128", 1),
+     ("jnp", "mesh")),
+    ("cpu", False, ("bhsd", "latent_no_mosaic"), None, None),
+])
+def test_a_latent_layer_is_in_place_on_a_tpu_and_by_heads_under_a_mesh(
+        live_registry, monkeypatch, platform, mesh, layout, entry, choice):
+    """Heads of 128 + 64 over values of 128 (both cells' sizes), traced
+    abstractly: on a TPU with no mesh the heads node takes the pack pairs and
+    the flash entry is ``bshd_v128``, one head a program; under a mesh it
+    counts ``mesh`` and every node is the one it was (``bhsd_v128``, per shard);
+    off a TPU nothing is recorded and flash is not reached."""
+    from jax.sharding import Mesh
+    from hetu_tpu.graph.node import VariableOp
+    from hetu_tpu.graph.trace import TraceContext, evaluate
+    from hetu_tpu.layers.latent_attention import LatentAttention
+    from hetu_tpu.ops.pallas import flash_attention as flash
+    monkeypatch.setattr(dispatch, "platform", lambda: platform)
+    name = f"mla_traced_{platform}_{int(mesh)}"
+    x = ht.placeholder_op(f"{name}_x", (2, 256, 64))
+    out = LatentAttention(64, 4, 32, 128, 64, 128, name=name)(x)
+    variables = [n for n in find_topo_sort([out])
+                 if isinstance(n, VariableOp)]
+    ctx = TraceContext(training=True, mesh=Mesh(
+        np.array(jax.devices()[:2]), ("dp",)) if mesh else None)
+    sds = lambda node: jax.ShapeDtypeStruct(node.shape, jnp.bfloat16)
+
+    def traced(*values):
+        return evaluate([out], dict(zip([x] + variables, values)), ctx)[0][0]
+
+    def counts():
+        return (layouts_built(), flash.entries(),
+                {k[1:]: n for k, n in dispatch.choices().items()
+                 if k[0] == "mla_pack"})
+    before = counts()
+    got = jax.eval_shape(traced, *map(sds, [x] + variables))
+    assert got.shape == (2, 256, 64)
+    built, entries, chosen = ({k: n - b.get(k, 0) for k, n in a.items()
+                               if n > b.get(k, 0)}
+                              for a, b in zip(counts(), before))
+    assert built == {layout: 1}
+    assert entries == ({} if entry is None else {entry: 1})
+    assert chosen == ({} if choice is None else {choice: 1})

@@ -720,6 +720,54 @@ def test_keys_wider_than_values_compile_for_v5e(v5e, as_on_tpu):
     assert "bf16[32,8192,128]" in kernels[1].split(" custom-call")[0]
 
 
+@pytest.mark.parametrize("seq,ling", [(8192, True), (4096, False)])
+def test_latent_heads_in_place_compile_for_v5e(v5e, as_on_tpu, seq, ling):
+    """A latent layer's attention at the two cells' sizes, from the
+    projections' outputs to ``W_o``, forward and backward: the pack pairs
+    (``hetu_mla_q_fwd`` / ``_bwd``, ``hetu_mla_k_fwd`` / ``_bwd``: q and k
+    ``[1, S, 32 x 256]``, the values ``[1, S, 32 x 128]``) and the two flash
+    kernels on those in place (``bshd`` at 256 / 128), each once, under their
+    scoped VMEM; Ling-3.0's form with the norm a head and the gate a head on
+    the flat context, Xing4.0's with neither; and no copy or transpose of an
+    array by heads anywhere."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.layers import latent_attention as layer
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    H, rank = 32, 512
+    attrs = dict(heads=H, d_nope=128, d_rope=64, rank=rank, theta=1e4,
+                 eps=1e-6)
+    norms = [sds((192,)), sds((192,))] if ling else []
+    gate = [sds((1, seq, H))] if ling else []
+
+    def loss(q, kvb, kva, w_out, *rest):
+        rest = list(rest)
+        w_norm = [rest.pop(0), rest.pop(0)] if ling else []
+        q, k, v = layer._in_place(q, kvb, kva, *w_norm, **attrs)
+        ctx_ = flash_attention(q, k, v, causal=True, scale=192 ** -0.5,
+                               num_heads=H)
+        return jnp.sum(layer._out(ctx_, w_out, *rest).astype(jnp.float32)
+                       ** 2)
+
+    operands = [sds((1, seq, H * 192)), sds((1, seq, H * 256)),
+                sds((1, seq, rank + 64)), sds((H * 128, 2048))] + norms + gate
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(len(operands))))).lower(
+        *operands).compile().as_text()
+    kernels = [re.search(r"%(hetu_\w+?)[.\d]* = ", ln).group(1)
+               for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert sorted(kernels) == sorted([
+        "hetu_mla_q_fwd", "hetu_mla_k_fwd", "hetu_flash_fwd",
+        "hetu_flash_bwd", "hetu_mla_k_bwd", "hetu_mla_q_bwd"]), kernels
+    flash = [ln.split(" custom-call")[0] for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "hetu_flash" in ln]
+    assert f"bf16[1,{seq},4096]" in flash[0]
+    assert flash[1].count(f"bf16[1,{seq},8192]") == 2
+    moved = re.findall(r" = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", hlo)
+    assert not [m for m in moved if "32," in m + ","
+                and any(d in m.split(",") for d in ("192", "256"))], moved
+
+
 def test_in_place_kernels_compile_per_shard_on_four_chips(v5e, as_on_tpu):
     """DataParallel(4)'s BERT shard under shard_map: the kernels see the
     local [64, 512, 768], and nothing is transposed or copied around them."""

@@ -6,7 +6,7 @@ pinned three ways.
    ``dispatch.py`` alone, ``dispatch.record`` is called there and by the three
    ops that have a per-shard plan alone, and no module of ``ops/pallas/``,
    ``layers/`` or ``models/`` imports an underscore name from a sibling.
-2. The function itself: for each of the eleven kernel labels, on ``tpu`` and
+2. The function itself: for each of the kernel labels, on ``tpu`` and
    ``cpu``, with and without a mesh, the key it records is the one in the
    table below, written out from what the call sites recorded before there
    was one function.
@@ -110,6 +110,7 @@ TABLE = {
     "moe_rows": (PALLAS, MESH, NOTHING, NOTHING),
     "rotary": (PALLAS, MESH, NOTHING, NOTHING),
     "hc_mix": (PALLAS, MESH, NOTHING, NOTHING),
+    "mla_pack": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_gmm": (PALLAS, MESH, CPU, MESH),
 }
 

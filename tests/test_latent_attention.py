@@ -132,12 +132,16 @@ def test_the_ling_path_builds_the_graph_and_the_loss_it_had():
     """``q_lora_rank=None``, no scaling, no multiplier: the Ling toy program
     (``tests/test_ling3_reference.py build``) has the node count and, to the
     bit, the loss and the logits' sum it had at the parent of PR 56 (read
-    there: 325 and 337 nodes, loss 0x1.6459d4p+2)."""
+    there: 325 and 337 nodes, loss 0x1.6459d4p+2).  Since PR 59 a latent
+    layer has one node more: the node that chooses the heads' layout and its
+    three items where ``_queries``, ``_keys`` and ``_values`` stood."""
     import test_ling3_reference as ling
     model, ex, variables, feed = ling.build(name="lingpin")
     out = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
-    assert len(ex.subexecutor["forward"].topo) == 325
-    assert len(ex.subexecutor["grads"].topo) == 337
+    for graph, nodes in (("forward", 325), ("grads", 337)):
+        topo = ex.subexecutor[graph].topo
+        latent = sum(type(node).__name__ == "_Heads" for node in topo)
+        assert latent and len(topo) == nodes + latent
     assert float(out[1]).hex() == "0x1.6459d40000000p+2"
     assert float(np.float64(out[0]).sum()).hex() == "-0x1.243e79034bd00p+3"
     mla = model.model.layers[5].mixer
@@ -208,3 +212,205 @@ def test_the_entry_counter_names_the_two_widths():
         assert entries().get(("bhsd_v128", 1), 0) == before + 1
     finally:
         telemetry.disable()
+
+
+# -- the heads in place (PR 59): ``ops/pallas/mla_pack.py`` behind ``_Heads`` ----
+
+#: lane-real widths (128 / 64 / 128: what the kernels take) at a small size
+WH, WS, WHID, WRANK, WQRANK = 4, 256, 64, 32, 48
+
+
+def forms_of(cell):
+    """The layer's arguments in the two cells' forms: Ling-3.0's (a norm a
+    head, a gate a head, a full-rank query) and Xing4.0's (a low-rank query,
+    YaRN's tables, the scores' multiplier; no norm, no gate)."""
+    from hetu_tpu.ops.rotary import yarn_scaling
+    return {"ling": dict(qk_norm=True, head_gate=True),
+            "xing4": dict(qk_norm=False, head_gate=False, q_lora_rank=WQRANK,
+                          rope_scaling=yarn_scaling(64.0, 16, 32.0, 1.0, 1.0),
+                          softmax_scale_mult=2.0047)}[cell]
+
+
+@pytest.fixture
+def asked(monkeypatch):
+    """The layer asks for its kernels as it does on a TPU, and gets them in
+    interpret mode (``dispatch.take(asked=True)``)."""
+    import types
+    from hetu_tpu.layers import latent_attention as forms
+    from hetu_tpu.ops.pallas import dispatch
+    monkeypatch.setattr(forms, "dispatch", types.SimpleNamespace(
+        take=lambda kernel, mesh, why:
+        dispatch.take(kernel, mesh, why, asked=True)))
+    return lambda: monkeypatch.setattr(forms, "dispatch", dispatch)
+
+
+def wide_program(name, cell, dims=(128, 64, 128)):
+    """The layer, a loss over its output and every gradient, the weights
+    drawn by their names' ends (two programs of two names hold the same)."""
+    import zlib
+    from hetu_tpu.graph.node import VariableOp, find_topo_sort
+    layer = LatentAttention(WHID, WH, WRANK, *dims, rope_theta=1e4, name=name,
+                            **forms_of(cell))
+    x = ht.placeholder_op(f"{name}_x", (1, WS, WHID))
+    y = layer(x)
+    loss = ht.reduce_sum_op(y * y, axes=None)
+    variables = [n for n in find_topo_sort([loss])
+                 if isinstance(n, VariableOp)]
+    ex = ht.Executor({"forward": [y],
+                      "grads": [loss] + ht.gradients(loss, variables)},
+                     seed=0)
+    for key, value in list(ex.params.items()):
+        r = np.random.default_rng(zlib.crc32(key[len(name):].encode()))
+        scale = 0.2 if key.endswith("_scale") else value.shape[0] ** -0.5
+        ex.params[key] = jnp.asarray(
+            key.endswith("_scale") + scale * r.normal(size=value.shape),
+            value.dtype)
+    feed = {x: np.random.default_rng(3).normal(size=(1, WS, WHID)).astype(
+        np.float32)}
+    return ex, feed, [v.name[len(name):] for v in variables]
+
+
+def pack_choices():
+    from hetu_tpu.ops.pallas import dispatch
+    return {k[1:]: n for k, n in dispatch.choices().items()
+            if k[0] == "mla_pack"}
+
+
+def layouts():
+    from test_attention_layout import layouts_built
+    return layouts_built()
+
+
+@pytest.mark.parametrize("cell", ["ling", "xing4"])
+def test_the_layer_in_place_is_the_layer_by_heads(asked, live_registry, cell):
+    """The layer through the executor, its output and every weight's
+    gradient, with the kernel pairs asked for (interpret mode: q and k at a
+    stride of 256 lanes a head, the attention op on ``[B, S, H x 256]`` and
+    ``[B, S, H x 128]``, the context flat into the gate and ``W_o``) and on
+    the views by heads: the same numbers."""
+    chosen, built = pack_choices(), layouts()
+    ex, feed, names = wide_program(f"mla_in_{cell}", cell)
+    got = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    got += ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    taken = pack_choices()
+    assert set(taken) == {("pallas", "")} | set(chosen)
+    assert taken[("pallas", "")] >= chosen.get(("pallas", ""), 0) + 2
+    assert layouts().get(("bshd", "latent_in_place"), 0) >= built.get(
+        ("bshd", "latent_in_place"), 0) + 2
+    asked()                              # the real ``dispatch`` back
+    ex, feed, _ = wide_program(f"mla_by_{cell}", cell)
+    want = ex.run("forward", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    want += ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    assert pack_choices() == taken       # off a TPU, unasked: nothing recorded
+    assert layouts()[("bhsd", "latent_no_mosaic")] >= 2
+    assert len(got) == len(want) == 2 + len(names)
+    for name, g, w in zip(["y", "loss"] + names, got, want):
+        assert np.abs(w).max() > 0, name
+        assert np.abs(g - w).max() < 2e-5 * max(1.0, np.abs(w).max()), name
+
+
+@pytest.mark.parametrize("cell", ["ling", "xing4"])
+def test_other_head_sizes_asked_for_say_why_and_run_by_heads(
+        asked, live_registry, cell):
+    """Where the path is not taken (a toy's heads of 32 + 16 over values of
+    24) the layer is, to the bit, the layer it was: asked for the kernels it
+    records the refusal and runs ``_queries``, ``_keys`` and ``_values``."""
+    before = pack_choices().get(("jnp", "nope_dim_not_128"), 0)
+    ex, feed, _ = wide_program(f"mla_toy_{cell}", cell, (DN, DR, DV))
+    got = ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    assert pack_choices()[("jnp", "nope_dim_not_128")] > before
+    assert layouts()[("bhsd", "latent_nope_dim_not_128")] >= 1
+    asked()
+    ex, feed, _ = wide_program(f"mla_toy_un_{cell}", cell, (DN, DR, DV))
+    want = ex.run("grads", feed_dict=feed, convert_to_numpy_ret_vals=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert float(got[0]).hex() == float(want[0]).hex()
+
+
+@pytest.mark.parametrize("normed", [True, False])
+def test_the_spare_lanes_are_exact_zeros_both_ways(normed):
+    """``q^`` and ``k^`` hold a head's 192 and 64 exact zeros, the values are
+    ``c W_kvb``'s lanes, and what flash hands back for ``q^`` and ``k^`` (read
+    in place: ``bshd`` at 256 / 128) is zero on the same lanes; the context
+    and the three gradients are those of the call by heads at 192 / 128."""
+    from hetu_tpu import telemetry
+    from hetu_tpu.ops.pallas import mla_pack
+    from hetu_tpu.ops.rotary import _pair_tables
+    r = np.random.default_rng(5)
+    x = jnp.asarray(r.normal(size=(1, WS, WH * 192)), jnp.float32)
+    kvb = jnp.asarray(r.normal(size=(1, WS, WH * 256)), jnp.float32)
+    rope = jnp.asarray(r.normal(size=(1, WS, 64)), jnp.float32)
+    w = jnp.asarray(1 + 0.2 * r.normal(size=(192,)), jnp.float32)
+    tables = mla_pack.tables(_pair_tables(seq_len=WS, dim=128, theta=1e4,
+                                          rotary_dim=64))
+    q = mla_pack.queries(x, tables, w if normed else None, WH, 1e-6)
+    k, v = mla_pack.keys_values(kvb, rope, tables, w if normed else None, WH,
+                                1e-6)
+    by_heads = lambda t: t.reshape(1, WS, WH, -1)
+    for t in (q, k):
+        assert t.shape == (1, WS, WH * 256)
+        assert (np.asarray(by_heads(t))[..., 192:] == 0).all()
+        assert np.abs(np.asarray(by_heads(t))[..., :192]).min() > 0
+    np.testing.assert_array_equal(np.asarray(by_heads(v)),
+                                  np.asarray(by_heads(kvb))[..., 128:])
+    g = jnp.asarray(r.normal(size=v.shape), jnp.float32)
+    flat = lambda *a: jnp.sum(flash_attention(
+        *a, causal=True, scale=192 ** -0.5, num_heads=WH) * g)
+    telemetry.enable()
+    try:
+        before = entries().get(("bshd_v128", 1), 0)
+        out = flash_attention(q, k, v, causal=True, scale=192 ** -0.5,
+                              num_heads=WH)
+        assert entries().get(("bshd_v128", 1), 0) == before + 1
+    finally:
+        telemetry.disable()
+    got = jax.grad(flat, argnums=(0, 1, 2))(q, k, v)
+    for t in got[:2]:
+        assert (np.asarray(by_heads(t))[..., 192:] == 0).all()
+    split = lambda t, d: by_heads(t)[..., :d].transpose(0, 2, 1, 3)
+    q4, k4, v4 = split(q, 192), split(k, 192), split(v, 128)
+    g4 = split(g, 128)
+    want_out = flash_attention(q4, k4, v4, causal=True, scale=192 ** -0.5)
+    want = jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, causal=True, scale=192 ** -0.5) * g4), argnums=(0, 1, 2))(
+            q4, k4, v4)
+    assert np.abs(np.asarray(split(out, 128) - want_out)).max() < 1e-6
+    for a, b, d in zip(got, want, (192, 192, 128)):
+        assert np.abs(np.asarray(split(a, d) - b)).max() < 1e-5
+
+
+@pytest.mark.parametrize("cell,layers_", [
+    ("ling-3.0-flash-vl.b1-s8192", 1), ("xing4.0-29b-a4b.b1-s4096", 3)])
+def test_the_cells_toys_at_heads_of_192_over_128_go_in_place(
+        asked, live_registry, cell, layers_):
+    """The Ling-3.0 toy (one latent layer behind a KDA layer, whole layers
+    recomputed) and the Xing4.0 toy (two layers and the MTP depth's, low-rank
+    queries, YaRN, hyper-connected streams) at the cells' head sizes, 128 +
+    64 over 128: every latent layer is traced in place through the kernel
+    pairs, the program is as near its cell's plain reference as the toy's
+    limits ask, a train step runs, and the harness's own reading of the
+    kernels chosen finds no ``jax.numpy`` form the platform does not
+    explain."""
+    from test_attention_layout import toy
+    built = layouts()
+    program, mix = toy(cell, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                       v_head_dim=128)
+    try:
+        feed, = program.make_batches(59, 1)
+        want = program.reference_loss(feed, int(mix["reference_chunk"]))
+        got = program.eval_loss(feed)
+        for term, limit in mix["reference_tolerance"].items():
+            assert abs(got[term] - want[term]) < limit, (term, got, want)
+        assert np.isfinite(program.step(feed))
+        _, fallbacks = program.kernel_choices()
+        assert fallbacks == []
+        now = layouts()
+        assert now.get(("bshd", "latent_in_place"), 0) >= built.get(
+            ("bshd", "latent_in_place"), 0) + 2 * layers_
+        assert {k: n for k, n in now.items() if k[0] == "bhsd"
+                and k[1].startswith("latent")} == {
+                    k: n for k, n in built.items() if k[0] == "bhsd"
+                    and k[1].startswith("latent")}
+    finally:
+        program.close()
