@@ -5,12 +5,18 @@ reshape → gate → layout_transform → alltoall → expert FFNs → alltoall 
 reverse_layout_transform (:60-88); BASE-layer variant (:90) with balance
 assignment; gates in layers/{TopGate,KTop1Gate,HashGate,SAMGate,BalanceGate}.
 
-TPU redesign: gating + dispatch are dense einsums (ops/moe.py); the expert
-dim of dispatched activations and of expert weights carries an 'ep' mesh-axis
-annotation, so GSPMD inserts the all-to-all pair the reference ran as
-explicit AllToAllOps (for multi-node topologies,
-parallel/collectives.hierarchical_all_to_all composes the DCN×ICI staging
-explicitly inside shard_map).
+TPU redesign: one graph node a layer, of one of two regimes that share no
+line (``MoELayer`` chooses by ``capacity_factor is None``).  Dropless
+(``_DroplessOp``; every cell with experts): the pairs sorted by expert and
+the experts as grouped products over the ragged groups (``ops/moe.py
+dropless_moe``), all of them or one device's share (``held=``).  Capacity
+(``_CapacityOp``; the reference's gates): ``[E, C, H]`` expert inputs gathered
+by the gate's routing choices (``sparse_dispatch`` / ``sparse_combine``; the
+dense one-hot einsums for a gate without a choices form), whose expert
+dimension and the expert weights' carry an 'ep' mesh-axis annotation, so
+GSPMD inserts the all-to-all pair the reference ran as explicit AllToAllOps
+(for multi-node topologies, parallel/collectives.hierarchical_all_to_all
+composes the DCN×ICI staging explicitly inside shard_map).
 """
 
 from __future__ import annotations
@@ -258,111 +264,88 @@ class StateRouter(BaseLayer):
                                        score="softmax", bias=bias)
 
 
+#: the rows of a layer's load (``MoELayer.load()``), an entry an expert the
+#: layer holds: the pairs routed and those of them computed; of a share of
+#: the experts (``held=``) also ``[ELSEWHERE, 0]``, the pairs routed to the
+#: others, and the pairs a pass after the first computed; with skip choices
+#: ``[SKIPPED, 0]``, the pairs that chose no expert, and ``[SKIPPED, 1]``,
+#: the RMS of the router state handed on
+ROUTED, COMPUTED, ELSEWHERE, LATER, SKIPPED = range(5)
+
+
 class _MoEOp(Op):
-    """Fused gate+dispatch+experts+combine (single graph node so the EP
-    sharding annotations stay local to the op)."""
+    """Gate, dispatch, experts and combine as ONE graph node (so the EP
+    sharding annotations stay local to the op): what the op of either regime
+    is.  The inputs go by NAME (``at``: name -> place in ``inputs``, of the
+    nodes that are not None in the order given; ``read``), the loss-side
+    nodes' too: they are built from ``op.inputs``."""
 
-    def __init__(self, x, gate, w1, b1, w2, b2, num_experts, capacity_factor,
-                 k, ep_axis=None, ids=None, sparse=True, w3=None,
-                 load_var=None, held=None, scores=None, state=None, skip=0,
-                 name=None):
-        # swiglu experts are biasless: b1/b2 are None and stay out of the
-        # graph entirely (no dead optimizer state / checkpoint entries)
-        inputs = [x, w1, w2] if b1 is None else [x, w1, b1, w2, b2]
-        self.has_biases = b1 is not None
-        if w3 is not None:                    # swiglu experts: up proj
-            inputs.append(w3)
-        # what the router hands the op: a gate's weight, or the logits of a
-        # router that is a layer of its own (``StateRouter``)
-        self.router_in = gate.wg if scores is None else scores
-        if self.router_in is not None:
-            inputs.append(self.router_in)
-        if ids is not None:
-            inputs.append(ids)
-        self._state_at = len(inputs) if state is not None else None
-        if state is not None:        # read for its RMS alone (the load's row)
-            inputs.append(state)
-        self.bias_var = getattr(gate, "bias", None)
-        self._bias_at = len(inputs)
-        if self.bias_var is not None:
-            inputs.append(self.bias_var)
+    def __init__(self, gate, k, num_experts, load_var, name, **named):
+        named = {n: node for n, node in named.items() if node is not None}
         if load_var is not None:
-            # last input, read by nobody: it puts the variable into every
-            # program that runs this op, so its update has a state to go to
-            inputs.append(load_var)
-        super().__init__(*inputs, name=name or "moe")
-        self.gate = gate
-        self.num_experts = num_experts
-        self.capacity_factor = capacity_factor
-        self.k = k
-        self.ep_axis = ep_axis
-        self.sparse = sparse
-        self.has_w3 = w3 is not None
-        self.has_ids = ids is not None
+            # read by nobody: it puts the variable into every program that
+            # runs this op, so its update has a state to go to
+            named["load"] = load_var
+        super().__init__(*named.values(), name=name or "moe")
+        self.at = {n: i for i, n in enumerate(named)}
+        self.gate, self.k, self.num_experts = gate, k, num_experts
         self.load_var = load_var
-        self.held = held
-        #: the router's last ``skip`` choices are no experts
-        self.skip = skip
-        assert not skip or (held is not None and held[1] >= 2), (
+
+    def read(self, input_vals, name):
+        """The value of the input ``name``; None where the op has none."""
+        return input_vals[self.at[name]] if name in self.at else None
+
+    def _record_load(self, ctx, *rows):
+        """Hand this step's counts (the rows ``ROUTED``, ``COMPUTED``, ..) to
+        the executor's state (``MoELayer.load()`` fetches them)."""
+        import jax.numpy as jnp
+        if self.load_var is not None:
+            ctx.record_update(self.load_var, jnp.stack(
+                rows).astype(jnp.float32))
+
+
+class _DroplessOp(_MoEOp):
+    """No capacity: every (token, choice) pair routed to an expert this
+    layer holds is computed (``ops/moe.py dropless_moe``).  ``gate`` routes
+    (``route``): a ``TopKGate`` on its weight, or a ``StateRouter`` on the
+    logits ``scores`` it made, with the ``state`` it hands on (read for its
+    RMS alone); ``w3=None`` is an expert that is not gated (relu2: ``w1`` is
+    its up projection); the gate's last ``skip`` choices are no experts."""
+
+    def __init__(self, x, gate, w1, w2, w3, k, num_experts, held=None,
+                 scores=None, state=None, load_var=None, name=None):
+        assert hasattr(gate, "route"), "the dropless op routes by gate.route"
+        self.held, self.skip = held, getattr(gate, "skip", 0)
+        assert not self.skip or (held is not None and held[1] >= 2), (
             "a skip choice is laid out as a pair held nowhere (held=)")
-        assert held is None or capacity_factor is None, (
-            "a share of the experts (held=) is laid out by the dropless path")
-        if capacity_factor is None:
-            assert b1 is None and hasattr(gate, "route"), (
-                "dropless routing (capacity_factor=None) runs experts "
-                "without biases (swiglu, relu2) behind a TopKGate")
-        else:
-            assert self.bias_var is None and (w3 is not None
-                                              or b1 is not None), (
-                "a sigmoid-scored router and relu2 experts are laid out by "
-                "the dropless path (capacity_factor=None)")
-
-    @property
-    def dropless(self):
-        return self.capacity_factor is None
-
-    def _unpack(self, input_vals):
-        """Input layout shared with MoEAuxLossOp (same inputs list)."""
-        if self.has_biases:
-            x, w1, b1, w2, b2 = input_vals[:5]
-            rest = list(input_vals[5:])
-        else:
-            x, w1, w2 = input_vals[:3]
-            b1 = b2 = None
-            rest = list(input_vals[3:])
-        w3 = rest.pop(0) if self.has_w3 else None
-        wg = rest.pop(0) if self.router_in is not None else None
-        ids = rest.pop(0) if self.has_ids else None
-        return x, w1, b1, w2, b2, w3, wg, ids
+        self.bias_var = getattr(gate, "bias", None)
+        super().__init__(gate, k, num_experts, load_var, name, x=x, w1=w1,
+                         w2=w2, w3=w3,
+                         router=gate.wg if scores is None else scores,
+                         state=state, bias=self.bias_var)
 
     def _bias(self, input_vals, ctx):
         """The router's selection bias in f32: under a lower compute type
         the f32 master, as an optimizer reads a weight (a bias of 0.5 moved
         by 0.001 in bf16 would not move)."""
-        if self.bias_var is None:
-            return None
-        if ctx.master_params is not None:
+        if self.bias_var is not None and ctx.master_params is not None:
             return ctx.master_params[self.bias_var.name]
-        return input_vals[self._bias_at]
-
-    def _capacity(self, T):
-        return max(int(np.ceil(self.capacity_factor * T * self.k
-                               / self.num_experts)), 1)
+        return self.read(input_vals, "bias")
 
     def routing(self, input_vals, ctx):
-        """The dropless routing of this op's tokens, traced once per trace:
-        the loss terms (``MoEAuxLossOp``, ``MoEZLossOp``) read what the layer
-        itself routed by.  Keyed by the identity of ``x``, so a node
-        evaluated in another trace (a remat body, a second program) routes
-        afresh."""
-        x, wg = input_vals[0], self._unpack(input_vals)[6]
+        """The routing of this op's tokens, traced once per trace: the loss
+        terms (``MoEAuxLossOp``, ``MoEZLossOp``) read what the layer itself
+        routed by.  Keyed by the identity of ``x``, so a node evaluated in
+        another trace (a remat body, a second program) routes afresh."""
+        x = self.read(input_vals, "x")
         memo = ctx.__dict__.setdefault("_moe_routing", {})
         if self.id not in memo or memo[self.id][0] is not x:
             extra = (() if self.bias_var is None
                      else (self._bias(input_vals, ctx),))
             with named_scope("hetu_moe_route"):
                 memo[self.id] = (x, self.gate.route(
-                    x.reshape(-1, x.shape[-1]), wg, self.k, *extra))
+                    x.reshape(-1, x.shape[-1]),
+                    self.read(input_vals, "router"), self.k, *extra))
         return memo[self.id][1]
 
     def _move_bias(self, input_vals, idx, ctx):
@@ -381,103 +364,121 @@ class _MoEOp(Op):
             ctx.record_update(self.bias_var, bias + rate * jnp.sign(
                 jnp.mean(load) - load))
 
-    def _record_load(self, ctx, *rows):
-        """Hand the per-expert pair counts of this step (routed, computed;
-        with ``held`` a third row whose first entry is the pairs routed to
-        experts held elsewhere, and a fourth of the pairs computed by a pass
-        after the first) to the executor's state (``MoELayer.load()`` fetches
-        them beside the loss; with skip choices a fifth: the pairs that
-        chose none of the experts, and the carried router state's RMS)."""
+    def _load_rows(self, input_vals, idx, counts):
+        """The load's rows from ``dropless_moe``'s ``counts``."""
         import jax.numpy as jnp
-        if self.load_var is not None:
-            ctx.record_update(self.load_var, jnp.stack(
-                rows).astype(jnp.float32))
+        rows = [counts["load"], counts["computed"]]
+        if self.held is None:
+            return rows
+        none = jnp.zeros((self.held[1],), jnp.int32)
+        if not self.skip:
+            return rows + [none.at[0].set(counts["elsewhere"]),
+                           counts["computed"] - counts["kept"]]
+        later = counts["computed"] - counts["kept"]   # (the program's order)
+        # a pair whose choice is no expert has no row and gives zero; the
+        # layout counted it among those held elsewhere
+        skipped = jnp.sum(idx >= self.num_experts)
+        rows += [none.at[0].set(counts["elsewhere"] - skipped), later]
+        last = none.astype(jnp.float32).at[0].set(skipped)
+        state = self.read(input_vals, "state")
+        if state is not None:
+            r = state.astype(jnp.float32)
+            last = last.at[1].set(jnp.sqrt(jnp.mean(r * r)))
+        return rows + [last]
+
+    def _compute(self, input_vals, ctx):
+        from ..ops.moe import dropless_moe, held_rows
+        x, w1, w2, w3 = (self.read(input_vals, n)
+                         for n in ("x", "w1", "w2", "w3"))
+        tokens = x.reshape(-1, x.shape[-1])
+        _, idx, gate, _ = self.routing(input_vals, ctx)
+        self._move_bias(input_vals, idx, ctx)
+        w_gate, w_up = (None, w1) if w3 is None else (w1, w3)
+        y, counts = dropless_moe(
+            tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
+            held=self.held, rows=self.held and held_rows(
+                idx.size, self.num_experts, self.held[1]))
+        self._record_load(ctx, *self._load_rows(input_vals, idx, counts))
+        return y.reshape(x.shape)
+
+    def aux(self, input_vals, ctx):
+        """The balance loss over top-k counts, from the routing the layer
+        itself ran by (``ops/moe.py load_balancing_loss``)."""
+        from ..ops.moe import load_balancing_loss, expert_load
+        _, idx, _, probs = self.routing(input_vals, ctx)
+        load = expert_load(idx, self.num_experts)
+        if getattr(self.gate, "score", "softmax") == "sigmoid":
+            # the share of the pairs, DeepSeek-V3's f_i
+            return load_balancing_loss(probs, load, pairs=idx.size)
+        return load_balancing_loss(probs, load)
+
+
+class _CapacityOp(_MoEOp):
+    """``[E, C, H]`` expert inputs with ``C`` from ``capacity_factor``; a
+    pair over an expert's capacity is dropped (the reference's regime).
+    Experts with biases (gelu) or gated without (swiglu: ``w3``); ``ep_axis``
+    shards the expert dimension; ``ids`` is what a hash gate routes by."""
+
+    def __init__(self, x, gate, w1, b1, w2, b2, w3, k, num_experts,
+                 capacity_factor, ep_axis=None, ids=None, load_var=None,
+                 name=None):
+        assert hasattr(gate, "gating"), "the capacity op routes by gate.gating"
+        assert (b1 is None) != (w3 is None), (
+            "experts with biases (gelu) or gated ones without (swiglu)")
+        self.capacity_factor, self.ep_axis = capacity_factor, ep_axis
+        super().__init__(gate, k, num_experts, load_var, name, x=x, w1=w1,
+                         b1=b1, w2=w2, b2=b2, w3=w3, router=gate.wg, ids=ids)
+
+    def _capacity(self, T):
+        return max(int(np.ceil(self.capacity_factor * T * self.k
+                               / self.num_experts)), 1)
+
+    def _on_ep(self, a, ctx):
+        """``a [E, C, ..]`` with its expert dimension on ``ep_axis``."""
+        import jax
+        if self.ep_axis is None or ctx.mesh is None:
+            return a
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.lax.with_sharding_constraint(
+            a, NamedSharding(ctx.mesh, P(self.ep_axis, None, None)))
 
     def _compute(self, input_vals, ctx):
         import jax
         import jax.numpy as jnp
-        from ..ops.moe import sparse_dispatch, sparse_combine, dropless_moe
-        x, w1, b1, w2, b2, w3, wg, ids = self._unpack(input_vals)
-
-        orig_shape = x.shape
-        h = x.shape[-1]
-        tokens = x.reshape(-1, h)
-        T = tokens.shape[0]
-        if self.dropless:
-            _, idx, gate, _ = self.routing(input_vals, ctx)
-            self._move_bias(input_vals, idx, ctx)
-            # an expert that is not gated (relu2) has no w3: w1 is its up
-            # projection
-            w_gate, w_up = (w1, w3) if self.has_w3 else (None, w1)
-            if self.held is None:
-                y, load = dropless_moe(tokens, idx, gate, w_gate, w_up, w2,
-                                       mesh=ctx.mesh)
-                self._record_load(ctx, load, load)
-                return y.reshape(orig_shape)
-            from ..ops.moe import held_rows
-            count = self.held[1]
-            y, lay = dropless_moe(
-                tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
-                held=self.held,
-                rows=held_rows(T * self.k, self.num_experts, count))
-            none = jnp.zeros((count,), jnp.int32)
-            rows = [lay["load"], lay["computed"],
-                    none.at[0].set(lay["elsewhere"]),
-                    lay["computed"] - lay["kept"]]
-            if self.skip:
-                # a pair whose choice is no expert has no row and gives zero;
-                # the layout counted it among those held elsewhere
-                skipped = jnp.sum(idx >= self.num_experts)
-                rows[2] = none.at[0].set(lay["elsewhere"] - skipped)
-                last = none.astype(jnp.float32).at[0].set(skipped)
-                if self._state_at is not None:
-                    r = input_vals[self._state_at].astype(jnp.float32)
-                    last = last.at[1].set(jnp.sqrt(jnp.mean(r * r)))
-                rows.append(last)
-            self._record_load(ctx, *rows)
-            return y.reshape(orig_shape)
-        C = self._capacity(T)
+        from ..ops.moe import sparse_dispatch, sparse_combine
+        x, w1, b1, w2, b2, w3, wg, ids = (
+            self.read(input_vals, n) for n in
+            ("x", "w1", "b1", "w2", "b2", "w3", "router", "ids"))
+        tokens = x.reshape(-1, x.shape[-1])
+        E, C = self.num_experts, self._capacity(tokens.shape[0])
 
         # scatter-style dispatch (reference LayoutTransform.cu) when the
-        # gate exposes routing CHOICES: memory is O(T·H + E·C·H), never
-        # the O(T·E·C) one-hot tensors of the dense einsum form — at real
-        # T·E·C those are the memory wall (SURVEY §2.1 N3).  Gates
-        # without a choices form (BASE auction) keep the dense path.
-        sparse = self.sparse and hasattr(self.gate, "gating_choices")
+        # gate exposes routing CHOICES: memory is O(T·H + E·C·H), never the
+        # O(T·E·C) one-hot tensors of the dense einsum form, the memory wall
+        # at real T·E·C (SURVEY §2.1 N3).  Gates without a choices form
+        # (BASE auction; the tests' oracle) keep the dense einsums.
+        sparse = hasattr(self.gate, "gating_choices")
         if sparse:
-            choices, aux = self.gate.gating_choices(tokens, wg, ids,
-                                                    self.k, C)
-            # pallas_call does not partition under GSPMD: inside ANY
-            # meshed program (ep-sharded or just dp) the gather lowers
-            # via XLA instead; row_gather records which form ran
-            # (pallas/dispatch.py)
-            pallas_ok = ctx.mesh is None
-            expert_in = sparse_dispatch(tokens, choices,
-                                        self.num_experts, C,
-                                        use_pallas=pallas_ok)
+            choices, _ = self.gate.gating_choices(tokens, wg, ids, self.k, C)
+            expert_in = sparse_dispatch(tokens, choices, E, C)
             if self.load_var is not None:
-                hot = [jax.nn.one_hot(i, self.num_experts,
-                                      dtype=jnp.float32)
+                hot = [jax.nn.one_hot(i, E, dtype=jnp.float32)
                        for i, _, _ in choices]
                 self._record_load(
                     ctx, sum(o.sum(0) for o in hot),
                     sum((o * (p < C)[:, None]).sum(0)
                         for o, (_, _, p) in zip(hot, choices)))
         else:
-            dispatch, combine, aux = self.gate.gating(tokens, wg, ids,
-                                                      self.k, C)
+            dispatch, combine, _ = self.gate.gating(tokens, wg, ids,
+                                                    self.k, C)
             expert_in = jnp.einsum("tec,th->ech", dispatch, tokens)
             # a gate without a choices form does not say what it routed
             # beyond capacity: only the kept pairs are known
             kept = jnp.sum(dispatch, axis=(0, 2))
             self._record_load(ctx, kept, kept)
-        if self.ep_axis is not None and ctx.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            expert_in = jax.lax.with_sharding_constraint(
-                expert_in, NamedSharding(ctx.mesh,
-                                         P(self.ep_axis, None, None)))
+        expert_in = self._on_ep(expert_in, ctx)
         # per-expert FFN: [E, C, H] @ [E, H, F] -> [E, C, F]
-        if self.has_w3:
+        if w3 is not None:
             # swiglu experts (Mixtral-style): silu(x@w1) * (x@w3) @ w2
             a = (jax.nn.silu(jnp.einsum("ech,ehf->ecf", expert_in, w1))
                  * jnp.einsum("ech,ehf->ecf", expert_in, w3))
@@ -486,56 +487,46 @@ class _MoEOp(Op):
             a = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", expert_in, w1)
                             + b1[:, None, :])
             out = jnp.einsum("ecf,efh->ech", a, w2) + b2[:, None, :]
-        if self.ep_axis is not None and ctx.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            out = jax.lax.with_sharding_constraint(
-                out, NamedSharding(ctx.mesh, P(self.ep_axis, None, None)))
+        out = self._on_ep(out, ctx)
         if sparse:
-            combined = sparse_combine(out, choices,
-                                      use_pallas=pallas_ok)
+            combined = sparse_combine(out, choices)
         else:
             combined = jnp.einsum("ech,tec->th", out, combine)
-        return combined.reshape(orig_shape)
+        return combined.reshape(x.shape)
+
+    def aux(self, input_vals, ctx):
+        """The gate's balance term by its aux-only form: O(T·E) logits work,
+        never the [T,E,C] dispatch/combine tensors (an aux evaluated in another
+        program than the MoE op must not pay the dispatch again)."""
+        import jax.numpy as jnp
+        x, wg, ids = (self.read(input_vals, n)
+                      for n in ("x", "router", "ids"))
+        if not getattr(self.gate, "has_aux", True):
+            # hash/balance gates have identically-zero aux: skip the
+            # dispatch recompute entirely
+            return jnp.asarray(0.0, x.dtype)
+        tokens = x.reshape(-1, x.shape[-1])
+        if hasattr(self.gate, "aux"):
+            aux = self.gate.aux(tokens, wg, ids, self.k)
+        else:
+            # caller-built gate without the aux-only fast path: fall back
+            # to full gating (CSE removes the cost when jitted with the
+            # MoE op)
+            _, _, aux = self.gate.gating(tokens, wg, ids, self.k,
+                                         self._capacity(tokens.shape[0]))
+        return jnp.asarray(aux, x.dtype)
 
 
 class MoEAuxLossOp(Op):
+    """The balance term of an MoE op's regime (``aux``); like the two nodes
+    below it is built from the op's inputs, so the op's names find them."""
+
     def __init__(self, moe_op):
         super().__init__(*moe_op.inputs, name=f"{moe_op.name}_aux")
         self.moe = moe_op
 
     def _compute(self, input_vals, ctx):
-        # aux-only gate path: O(T·E) logits work, never the [T,E,C]
-        # dispatch/combine tensors — an aux evaluated in a separate
-        # subexecutor from the MoE op must not pay the full dispatch
-        # recompute (in the same jitted program, CSE merges it anyway)
-        import jax.numpy as jnp
-        x, _, _, _, _, _, wg, ids = self.moe._unpack(input_vals)
-        if self.moe.dropless:
-            # balance loss over top-k counts, from the routing the layer
-            # itself ran by (ops/moe.py load_balancing_loss)
-            from ..ops.moe import load_balancing_loss, expert_load
-            _, idx, _, probs = self.moe.routing(input_vals, ctx)
-            load = expert_load(idx, self.moe.num_experts)
-            if getattr(self.moe.gate, "score", "softmax") == "sigmoid":
-                # the share of the pairs, DeepSeek-V3's f_i
-                return load_balancing_loss(probs, load, pairs=idx.size)
-            return load_balancing_loss(probs, load)
-        if not getattr(self.moe.gate, "has_aux", True):
-            # hash/balance gates have identically-zero aux: skip the
-            # dispatch recompute entirely
-            return jnp.asarray(0.0, x.dtype)
-        tokens = x.reshape(-1, x.shape[-1])
-        aux_fn = getattr(self.moe.gate, "aux", None)
-        if aux_fn is not None:
-            aux = aux_fn(tokens, wg, ids, self.moe.k)
-        else:
-            # caller-built gate without the aux-only fast path: fall back
-            # to full gating (CSE removes the cost when jitted with the
-            # MoE op)
-            _, _, aux = self.moe.gate.gating(
-                tokens, wg, ids, self.moe.k,
-                self.moe._capacity(tokens.shape[0]))
-        return jnp.asarray(aux, x.dtype)
+        return self.moe.aux(input_vals, ctx)
 
 
 class MoEZLossOp(Op):
@@ -543,7 +534,8 @@ class MoEZLossOp(Op):
     in f32 (ST-MoE; OLMoE trains with 0.001 of it a layer)."""
 
     def __init__(self, moe_op):
-        assert moe_op.dropless, "the z-loss reads the dropless routing"
+        assert isinstance(moe_op, _DroplessOp), (
+            "the z-loss reads the dropless routing")
         super().__init__(*moe_op.inputs, name=f"{moe_op.name}_zloss")
         self.moe = moe_op
 
@@ -557,7 +549,7 @@ class MoEChosenOp(Op):
     largest weight first (for checks against a reference's routing)."""
 
     def __init__(self, moe_op):
-        assert moe_op.dropless
+        assert isinstance(moe_op, _DroplessOp)
         super().__init__(*moe_op.inputs, name=f"{moe_op.name}_chosen")
         self.moe = moe_op
 
@@ -615,23 +607,41 @@ class MoELoadOp(Op):
         return input_vals[0]
 
 
+#: the keywords of ``MoELayer`` that belong to ONE regime: (keyword, the
+#: regime, the values that ask for it).  Every other keyword is the layer's
+#: under either regime.
+_ONE_REGIME_ALONE = (
+    ("held", "dropless", lambda v: v is not None),
+    ("router", "dropless", lambda v: v is not None),
+    ("router_groups", "dropless", lambda v: v is not None),
+    ("router_score", "dropless", lambda v: v == "sigmoid"),
+    ("expert_act", "dropless", lambda v: v == "relu2"),
+    ("expert_act", "capacity", lambda v: v == "gelu"),    # with biases
+    # a gate without ``route``: hash, ktop1, sam (``num_groups``), balance,
+    # or a caller-built one; a caller-built gate with a selection bias
+    ("gate", "capacity", lambda v: v != "top" and not hasattr(v, "route")),
+    ("gate", "dropless", lambda v: getattr(v, "bias", None) is not None),
+)
+
+
 class MoELayer(BaseLayer):
     """Expert-parallel FFN block (drop-in for TransformerFFN).
 
-    ``capacity_factor=None`` is the dropless path (ops/moe.py
+    ``capacity_factor=None`` is the dropless regime (ops/moe.py
     ``dropless_moe``): every (token, choice) pair is computed, by grouped
     products over the pairs sorted by expert; it needs the ``top`` gate and
     experts without biases: ``expert_act="swiglu"`` (``silu(x W1) * (x W3)``
     then ``W2``, three grouped products a pass) or ``"relu2"`` (``relu(x
-    W1)^2`` then ``W2``, two; no ``w3``).  ``"gelu"`` experts have biases and
-    run behind a capacity; ``"relu2"`` runs on the dropless path alone.
+    W1)^2`` then ``W2``, two; no ``w3``).  A capacity factor is the
+    reference's regime: any gate, ``"gelu"`` experts (with biases) or
+    ``"swiglu"``.  ``_ONE_REGIME_ALONE`` says which keyword is which
+    regime's alone; one of the other regime is refused by name.
     ``renorm_topk`` is the gate's ``renorm``; ``router_score="sigmoid"``,
     ``router_scale`` and ``router_bias_rate`` are the gate's ``score``,
-    ``scale`` and ``bias_rate``, ``router_groups`` its ``groups`` (``TopKGate``;
-    dropless path alone), and
-    ``router_bias()`` fetches the bias as ``load()`` fetches the load.
-    ``track_load`` adds a ``[2, E]`` state variable of per-expert pair
-    counts (routed, kept) that ``load()`` fetches.
+    ``scale`` and ``bias_rate``, ``router_groups`` its ``groups``
+    (``TopKGate``), and ``router_bias()`` fetches the bias as ``load()``
+    fetches the load.  ``track_load`` adds a ``[2, E]`` state variable of
+    per-expert pair counts (routed, kept) that ``load()`` fetches.
 
     ``held=(first, count)`` is one device's share of an expert-parallel
     layer without the other devices: the router keeps its ``num_experts``
@@ -641,17 +651,14 @@ class MoELayer(BaseLayer):
     nothing stands in for it.  One pass lays out rows for twice the mean
     share (``ops/moe.py held_rows``); pairs a batch routes here over that
     bound are computed by further passes (``dropless_moe``), none is dropped.
-    The load is ``[4, count]`` then: routed here, computed (the same),
-    in ``[2, 0]`` the pairs routed elsewhere, and the pairs that took a pass
-    after the first.
+    The load is ``[4, count]`` then (``ROUTED`` .. ``LATER``).
     ``router=`` is a router that is a layer of its own (``StateRouter``):
     the op takes its logits where it took the gate's weight, selects on
     ``softmax + bias`` and hands the router's state on (``__call__(x,
     state=)``, ``self.state``).  Its last ``router.skip`` outputs are no
     experts: a pair that chooses one goes to no expert, gives zero, and is
-    counted apart in a fifth row of the load (``[4, 0]``; ``[4, 1]`` is the
-    RMS of the router state); the layer is laid out as a held one (all its
-    experts where ``held`` is None).
+    counted apart in a fifth row of the load (``SKIPPED``); the layer is
+    laid out as a held one (all its experts where ``held`` is None).
     ``shared_width`` adds a shared expert of that width and of the experts'
     kind (swiglu or relu2), computed for every token, and ``shared_gate``
     says whether the sigmoid of a one-column gate scales it
@@ -659,18 +666,28 @@ class MoELayer(BaseLayer):
 
     def __init__(self, hidden_size, intermediate_size, num_experts, k=2,
                  capacity_factor=1.25, gate="top", ep_axis=None,
-                 num_groups=None, sparse=True, expert_act="gelu",
-                 renorm_topk=True, track_load=False, held=None,
-                 shared_width=None, shared_gate=True, router_score="softmax",
-                 router_scale=None, router_bias_rate=None,
-                 router_groups=None, router=None, name=None):
+                 num_groups=None, expert_act="gelu", renorm_topk=True,
+                 track_load=False, held=None, shared_width=None,
+                 shared_gate=True, router_score="softmax", router_scale=None,
+                 router_bias_rate=None, router_groups=None, router=None,
+                 name=None):
         name = fresh_name(name or "moe")
+        assert expert_act in ("gelu", "swiglu", "relu2"), expert_act
+        # the regime is chosen HERE; a keyword of the other is refused by name
+        self.capacity_factor = capacity_factor
+        regime = "dropless" if capacity_factor is None else "capacity"
+        given = locals()
+        for keyword, its, belongs in _ONE_REGIME_ALONE:
+            if its != regime and belongs(given[keyword]):
+                raise ValueError(
+                    f"{keyword}={given[keyword]!r} belongs to the {its} "
+                    f"regime (capacity_factor"
+                    f"{'=' if its == 'dropless' else ' is not '}None); "
+                    f"this layer is {regime}")
         self.router, self.skip = router, getattr(router, "skip", 0)
         #: the router's state node of the last call (``router=``)
         self.state = None
         if router is not None:
-            assert capacity_factor is None and gate == "top", (
-                "a router layer routes the dropless path")
             assert router.num_experts == num_experts, (
                 "the router's width is the layer's")
             gate = router
@@ -691,8 +708,6 @@ class MoELayer(BaseLayer):
                                  score=router_score, scale=router_scale,
                                  bias_rate=router_bias_rate,
                                  groups=router_groups)
-            assert router_groups is None or capacity_factor is None, (
-                "group-limited selection is the dropless path's")
         elif gate == "hash":
             self.gate = HashGate(num_experts)
         elif gate == "ktop1":
@@ -704,31 +719,19 @@ class MoELayer(BaseLayer):
             self.gate = BalanceGate(hidden_size, num_experts, name=name)
         else:
             raise ValueError(gate)
-        assert expert_act in ("gelu", "swiglu", "relu2"), expert_act
-        assert router_score == "softmax" or gate == "top", (
-            "the sigmoid-scored router is the top gate's")
-        assert capacity_factor is None or (
-            expert_act != "relu2" and router_score == "softmax"), (
-            "relu2 experts and the sigmoid-scored router run on the "
-            "dropless path (capacity_factor=None)")
         self.expert_act = expert_act
-        self.w1 = VariableOp(f"{name}_w1",
-                             (n_held, hidden_size, intermediate_size),
-                             init.xavier_uniform())
-        self.b1 = VariableOp(f"{name}_b1", (n_held, intermediate_size),
-                             init.zeros()) \
-            if expert_act == "gelu" else None
-        self.w2 = VariableOp(f"{name}_w2",
-                             (n_held, intermediate_size, hidden_size),
-                             init.xavier_uniform())
-        self.b2 = VariableOp(f"{name}_b2", (n_held, hidden_size),
-                             init.zeros()) \
-            if expert_act == "gelu" else None
+
+        def var(n, *shape, how=init.xavier_uniform):
+            return VariableOp(f"{name}_{n}", (n_held,) + shape, how())
+        biased = expert_act == "gelu"
+        self.w1 = var("w1", hidden_size, intermediate_size)
+        self.b1 = var("b1", intermediate_size,
+                      how=init.zeros) if biased else None
+        self.w2 = var("w2", intermediate_size, hidden_size)
+        self.b2 = var("b2", hidden_size, how=init.zeros) if biased else None
         # swiglu experts (Mixtral-style, reference-beyond): gated FFN
         # silu(x@w1) * (x@w3) @ w2, no biases
-        self.w3 = VariableOp(f"{name}_w3",
-                             (n_held, hidden_size, intermediate_size),
-                             init.xavier_uniform()) \
+        self.w3 = var("w3", hidden_size, intermediate_size) \
             if expert_act == "swiglu" else None
         self.shared = None
         self.shared_kind = dict(act=expert_act, gated=bool(shared_gate))
@@ -743,34 +746,36 @@ class MoELayer(BaseLayer):
             self.shared = tuple(
                 VariableOp(f"{name}_shared_{n}", shape, init.xavier_uniform())
                 for n, shape in parts)
-        self.num_experts = num_experts
-        self.capacity_factor = capacity_factor
-        self.k = k
-        self.ep_axis = ep_axis
-        # sparse=False forces the dense one-hot einsum dispatch (debug /
-        # exactness oracle); sparse routing needs a gate with a choices
-        # form and is the default memory-safe path
-        self.sparse = sparse
+        self.num_experts, self.k, self.ep_axis = num_experts, k, ep_axis
         self.load_var = VariableOp(
             f"{name}_load",
             (2, num_experts) if held is None
             else (5 if self.skip else 4, n_held), init.zeros(),
             trainable=False) if track_load else None
         if ep_axis is not None:
-            ep_vars = [v for v in (self.w1, self.b1, self.w2, self.b2,
-                                   self.w3) if v is not None]
-            for v in ep_vars:
-                from ..parallel.mesh import DistState
-                v.dist_state = DistState({0: ep_axis})
+            from ..parallel.mesh import DistState
+            for v in (self.w1, self.b1, self.w2, self.b2, self.w3):
+                if v is not None:
+                    v.dist_state = DistState({0: ep_axis})
         self.last_op = None
+        #: the op of this layer's regime on ``x``
+        if regime == "dropless":
+            self._op = lambda x, ids, scores: _DroplessOp(
+                x, self.gate, self.w1, self.w2, self.w3, self.k,
+                self.num_experts, held=self.held, scores=scores,
+                state=self.state, load_var=self.load_var)
+        else:
+            self._op = lambda x, ids, scores: _CapacityOp(
+                x, self.gate, self.w1, self.b1, self.w2, self.b2, self.w3,
+                self.k, self.num_experts, self.capacity_factor,
+                ep_axis=self.ep_axis, ids=ids, load_var=self.load_var)
 
     def __call__(self, x, ids=None, state=None):
         """``state``: the router state the layer above handed on
         (``router=``; this layer's is ``self.state`` afterwards)."""
-        more = {}
+        scores = None
         if self.router is not None:
             scores, self.state = self.router(x, state)
-            more = dict(scores=scores, state=self.state, skip=self.skip)
         elif self.gate.wg is None and ids is None:
             raise ValueError(
                 "hash-gated MoELayer requires token ids: moe(x, ids=...)")
@@ -779,13 +784,7 @@ class MoELayer(BaseLayer):
         # shared expert) has a name of its own: the regions' names are what
         # the moe_block metrics read, and hold what they held
         with scope("hetu_moe_other"):
-            self.last_op = _MoEOp(x, self.gate, self.w1, self.b1, self.w2,
-                                  self.b2, self.num_experts,
-                                  self.capacity_factor, self.k,
-                                  ep_axis=self.ep_axis, ids=ids,
-                                  sparse=self.sparse, w3=self.w3,
-                                  load_var=self.load_var, held=self.held,
-                                  **more)
+            self.last_op = self._op(x, ids, scores)
             if self.shared is not None:
                 return self.last_op + shared_expert_op(x, *self.shared,
                                                        **self.shared_kind)
@@ -847,39 +846,39 @@ def record_moe_load(layer, load, bias=None):
     The registry counts nothing while telemetry is disabled."""
     from .. import telemetry
     reg = telemetry.get_registry()
+
+    def metric(kind, name, text):
+        return getattr(reg, kind)(name, text, labels=("layer",)).labels(
+            layer=layer)
     if bias is not None:
-        reg.gauge("hetu_moe_router_bias_max_abs",
-                  "Largest |selection bias| of the router, last step",
-                  labels=("layer",)).labels(layer=layer).set(
-                      float(np.abs(np.asarray(bias, np.float64)).max()))
+        metric("gauge", "hetu_moe_router_bias_max_abs",
+               "Largest |selection bias| of the router, last step").set(
+                   float(np.abs(np.asarray(bias, np.float64)).max()))
     load = np.asarray(load, np.float64)
-    routed, kept = load[:2]
+    routed, kept = load[ROUTED], load[COMPUTED]
     total = routed.sum()
-    if len(load) > 2 and total + load[2, 0] > 0:
-        reg.counter("hetu_moe_pairs_elsewhere_total",
-                    "Routed pairs whose expert another device holds",
-                    labels=("layer",)).labels(layer=layer).inc(load[2, 0])
-        reg.counter("hetu_moe_pairs_over_bound_total",
-                    "Pairs on held experts computed by a pass after the first",
-                    labels=("layer",)).labels(layer=layer).inc(
-                        load[3].sum() if len(load) > 3 else 0)
-    if len(load) > 4 and total + load[2, 0] + load[4, 0] > 0:
-        reg.counter("hetu_moe_pairs_skipped_total",
-                    "Routed pairs whose choice is no expert (computed by none)",
-                    labels=("layer",)).labels(layer=layer).inc(load[4, 0])
-        reg.gauge("hetu_moe_router_state_rms",
-                  "RMS of the router state a layer handed on, last step",
-                  labels=("layer",)).labels(layer=layer).set(load[4, 1])
+    if len(load) > ELSEWHERE and total + load[ELSEWHERE, 0] > 0:
+        metric("counter", "hetu_moe_pairs_elsewhere_total",
+               "Routed pairs whose expert another device holds").inc(
+                   load[ELSEWHERE, 0])
+        metric("counter", "hetu_moe_pairs_over_bound_total",
+               "Pairs on held experts computed by a pass after the first"
+               ).inc(load[LATER].sum() if len(load) > LATER else 0)
+    if len(load) > SKIPPED and (total + load[ELSEWHERE, 0]
+                                + load[SKIPPED, 0] > 0):
+        metric("counter", "hetu_moe_pairs_skipped_total",
+               "Routed pairs whose choice is no expert (computed by none)"
+               ).inc(load[SKIPPED, 0])
+        metric("gauge", "hetu_moe_router_state_rms",
+               "RMS of the router state a layer handed on, last step").set(
+                   load[SKIPPED, 1])
     if total <= 0:          # the state's initial zeros: no step has run
         return
-    reg.counter("hetu_moe_pairs_routed_total",
-                "(token, choice) pairs the router sent to an expert",
-                labels=("layer",)).labels(layer=layer).inc(total)
-    reg.counter("hetu_moe_pairs_dropped_total",
-                "Routed pairs no expert computed (capacity overflow)",
-                labels=("layer",)).labels(layer=layer).inc(
-                    total - kept.sum())
-    reg.gauge("hetu_moe_expert_load_max_over_mean",
-              "Pairs at the fullest expert over the mean, last step",
-              labels=("layer",)).labels(layer=layer).set(
-                  routed.max() * routed.size / total)
+    metric("counter", "hetu_moe_pairs_routed_total",
+           "(token, choice) pairs the router sent to an expert").inc(total)
+    metric("counter", "hetu_moe_pairs_dropped_total",
+           "Routed pairs no expert computed (capacity overflow)").inc(
+               total - kept.sum())
+    metric("gauge", "hetu_moe_expert_load_max_over_mean",
+           "Pairs at the fullest expert over the mean, last step").set(
+               routed.max() * routed.size / total)

@@ -100,13 +100,14 @@ class _PackedLookupOp(_Op):
     embedding dims whose vjp needs no XLA scatter).  The Pallas write
     kernel engages only off-mesh on TPU; the jnp fallback is
     numerically identical (CPU tests, sharded programs), and
-    ``pack_write`` records which of the two ran (pallas/dispatch.py)."""
+    ``dispatch.take`` records which of the two ran, under ``packed_lookup``
+    (pallas/dispatch.py)."""
 
     def _compute(self, input_vals, ctx):
         from .pallas.sparse_densify import packed_lookup
         table, ids = input_vals
-        use_pallas = ctx is None or ctx.mesh is None
-        return packed_lookup(table, ids, self.attrs["dim"], use_pallas)
+        return packed_lookup(table, ids, self.attrs["dim"],
+                             None if ctx is None else ctx.mesh)
 
 
 def packed_embedding_lookup_op(table, ids, dim, name=None):

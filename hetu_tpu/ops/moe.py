@@ -6,10 +6,12 @@ gpu_ops/{LayoutTransform,ReverseLayoutTransform,TopKIdx,BalanceAssignment,
 Sample,Scatter1D}.py — scatter tokens into (expert, capacity) buffers before
 the all-to-all and back after.
 
-TPU redesign: dispatch is expressed densely (GShard-style one-hot
-dispatch/combine einsums) so it is MXU work with static shapes instead of
-data-dependent scatters; capacity overflow drops match the reference's
-LayoutTransform semantics.  The EP all-to-all is inserted by GSPMD from the
+TPU redesign: behind a capacity, dispatch and combine are row gathers by the
+gate's routing choices (``sparse_dispatch`` / ``sparse_combine``; the
+GShard-style one-hot einsums stay for a gate without a choices form and as
+the tests' oracle), static shapes either way; capacity overflow drops match
+the reference's LayoutTransform semantics; without one, ``dropless_moe``
+(below).  The EP all-to-all is inserted by GSPMD from the
 expert-dim shardings (layers/moe.py), or composed explicitly with
 parallel/collectives.hierarchical_all_to_all for DCN×ICI topologies.
 """
@@ -49,8 +51,8 @@ def top_k_gating_choices(logits, k, capacity, *, second_renorm=True,
                          noise_rng=None, noise_eps=0.0):
     """``top_k_gating`` in CHOICES form — [(expert_idx, gate, pos)] per
     routing choice plus the aux loss, never materializing the [T, E, C]
-    dispatch/combine tensors (the sparse dispatch path feeds these to
-    ops/pallas/moe_dispatch.row_gather)."""
+    dispatch/combine tensors (``sparse_dispatch`` and ``sparse_combine``
+    gather rows by them)."""
     T, E = logits.shape
     if not 1 <= k <= E:
         raise ValueError(f"top_k_gating needs 1 <= k <= {E} experts, got "
@@ -84,15 +86,13 @@ def top_k_gating_choices(logits, k, capacity, *, second_renorm=True,
     return choices, aux
 
 
-def sparse_dispatch(tokens, choices, num_experts, capacity,
-                    use_pallas=True):
+def sparse_dispatch(tokens, choices, num_experts, capacity):
     """[E, C, H] expert inputs straight from routing choices (reference
     LayoutTransform.cu) — a row gather by the slot→token inverse map; the
     O(T·E·C) one-hot tensors never exist."""
-    from .pallas.moe_dispatch import row_gather
     T, H = tokens.shape
     S = num_experts * capacity
-    slot_tok = jnp.full((S,), -1, jnp.int32)
+    slot_tok = jnp.full((S,), T, jnp.int32)     # past the end: an empty slot
     for idx, gate, pos in choices:
         keep = (pos < capacity) & (gate > 0)
         slot = jnp.where(keep,
@@ -101,15 +101,13 @@ def sparse_dispatch(tokens, choices, num_experts, capacity,
         slot_tok = slot_tok.at[slot].set(
             jnp.arange(T, dtype=jnp.int32), mode="drop",
             unique_indices=True)
-    return row_gather(tokens, slot_tok, use_pallas).reshape(
-        num_experts, capacity, H)
+    return _take_rows(tokens, slot_tok).reshape(num_experts, capacity, H)
 
 
-def sparse_combine(expert_out, choices, use_pallas=True):
+def sparse_combine(expert_out, choices):
     """[T, H] outputs from [E, C, H] expert results + routing choices
     (reference ReverseLayoutTransform.cu): per choice, gather the token's
-    slot row and scale by its gate."""
-    from .pallas.moe_dispatch import row_gather
+    slot row (zeros for a pair that was dropped) and scale by its gate."""
     E, C, H = expert_out.shape
     flat = expert_out.reshape(E * C, H)
     out = None
@@ -117,9 +115,8 @@ def sparse_combine(expert_out, choices, use_pallas=True):
         keep = (pos < C) & (gate > 0)
         slot = jnp.where(keep,
                          idx.astype(jnp.int32) * C
-                         + pos.astype(jnp.int32), -1)
-        term = (row_gather(flat, slot, use_pallas)
-                * gate[:, None].astype(flat.dtype))
+                         + pos.astype(jnp.int32), E * C)
+        term = _take_rows(flat, slot) * gate[:, None].astype(flat.dtype)
         out = term if out is None else out + term
     return out
 
@@ -914,10 +911,11 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
     """``y[t] = sum_c gate[t, c] * W_down,e( silu(W_gate,e x_t) * W_up,e
     x_t )`` with ``e = idx[t, c]``; no pair is dropped.  ``tokens [T, H]``,
     ``idx, gate [T, k]``, weights ``[E, H, F]``, ``[E, H, F]``,
-    ``[E, F, H]``.  Returns ``(y [T, H], load [E])``.  ``w_gate=None`` is an
-    expert that is not gated, ``W_down,e relu(W_up,e x_t)^2`` (Nemotron-H's
-    ``relu2``): two grouped products a pass where the gated expert runs
-    three.
+    ``[E, F, H]``.  Returns ``(y [T, H], counts)``, ``counts`` a dict of
+    ``load`` and ``computed`` ``[E]``: the pairs routed to each expert and
+    those of them computed (the same).  ``w_gate=None`` is an expert that is
+    not gated, ``W_down,e relu(W_up,e x_t)^2`` (Nemotron-H's ``relu2``): two
+    grouped products a pass where the gated expert runs three.
 
     ``held=(first, count)``: the weights are those of ``count`` experts of
     the ``num_experts`` that ``idx`` ranges over, and ``y`` is their part of
@@ -925,9 +923,10 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
     (``grouped_layout``).  ``rows`` bounds the pairs ONE pass lays out
     (``held_rows``); what a batch routes here beyond it is computed by
     further passes over the same rows (``_every_window``), so no pair is
-    dropped here either.  Returns ``(y, lay)`` then, with the layout's
-    ``load`` and ``elsewhere``, ``kept``, the pairs the first pass held, and
-    ``computed``, the pairs all passes held (``load``, counted)."""
+    dropped here either.  ``counts`` is over the held experts then
+    (``computed``: the pairs all passes held, ``load`` counted) and holds
+    the layout's ``elsewhere``, ``total`` and ``kept``, the pairs the first
+    pass held (``computed - kept``: the pairs a further pass took), too."""
     T, H = tokens.shape
     k = idx.shape[1]
     E, _, F = w_up.shape
@@ -948,7 +947,7 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
                                  lay["slot_of_pair"])
             y = jnp.sum(by_pair.reshape(T, k, H).astype(jnp.float32)
                         * gate[:, :, None], axis=1).astype(tokens.dtype)
-        return y, lay["load"]
+        return y, {"load": lay["load"], "computed": lay["load"]}
 
     one_pass = functools.partial(
         _held_pass, k=k, held=tuple(held), rows=rows, tile=tile, how=how,

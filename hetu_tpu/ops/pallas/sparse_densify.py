@@ -34,9 +34,9 @@ DMAs share a target line); invalid lanes (padding / merged duplicates)
 are skipped under ``pl.when``.
 
 pallas_call does not partition under GSPMD, so callers inside a
-sharded program have two options: pass ``use_pallas=False`` (the jnp
-fallback is numerically identical), or call
-:func:`sharded_packed_lookup`, which wraps the lookup in the
+sharded program have two options: hand the ``mesh`` they see (the jnp
+form is numerically identical; ``dispatch.take`` records the reason), or
+call :func:`sharded_packed_lookup`, which wraps the lookup in the
 ``jax.shard_map`` — the id batch splits over a mesh axis,
 the packed table rides replicated into every shard, and each device
 runs the SAME kernel on its local slice.
@@ -70,13 +70,10 @@ def packed_rows(num_rows, dim):
     return (num_rows + q - 1) // q
 
 
-def _unsupported(dtype, use_pallas):
-    """Why the row-write kernel does not run, or None when it does (the
-    DMA form needs Mosaic, so there is no interpret-mode twin)."""
-    if not use_pallas:
-        return "caller:use_pallas=False"
-    if not dispatch.mosaic():
-        return f"platform:{dispatch.platform()}"
+def _unsupported(dtype):
+    """Why the row-write kernel refuses its operands, or None (the DMA form
+    needs Mosaic and has no interpret-mode twin: ``dispatch.take`` says
+    ``platform:<name>`` off a TPU)."""
     if dtype not in (jnp.float32, np.float32):
         return f"dtype:{jnp.dtype(dtype).name}"
     return None
@@ -128,15 +125,22 @@ def _merge_duplicate_lines(pack, rows):
             jnp.where(last[:, None], totals, 0.0))
 
 
-def pack_write(pack_ids, lines, p_rows, use_pallas=True):
+def pack_write(pack_ids, lines, p_rows, mesh=None):
     """Write-only densify: out[pack_ids[i]] = lines[i] summed over
     duplicates (negative ids ignored), everything else zero.  Shapes:
-    pack_ids [M] int, lines [M, 128] -> [p_rows, 128]."""
+    pack_ids [M] int, lines [M, 128] -> [p_rows, 128].  ``mesh``: the one
+    the caller sees, or None."""
+    return _write(pack_ids, lines, p_rows, dispatch.take(
+        "pack_write", mesh, _unsupported(lines.dtype)))
+
+
+def _write(pack_ids, lines, p_rows, kernel):
+    """``pack_write`` by the row-write kernel or, without ``kernel``, by a
+    scatter-add."""
     pack_ids = pack_ids.reshape(-1).astype(jnp.int32)
     m = pack_ids.shape[0]
     lines = lines.reshape(m, 128)
-    if not dispatch.record("packed_embedding_write",
-                           _unsupported(lines.dtype, use_pallas)):
+    if not kernel:
         safe = jnp.where(pack_ids >= 0, pack_ids, p_rows)
         z = jnp.zeros((p_rows + 1, 128), lines.dtype)
         return z.at[safe].add(lines)[:p_rows]
@@ -186,11 +190,12 @@ def _position_lines(ids, g, q, dim):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def packed_lookup(table, ids, dim, use_pallas=True):
+def packed_lookup(table, ids, dim, mesh=None):
     """Row lookup from a PACKED [p_rows, 128] table: returns
     [..., dim] rows for integer ``ids`` (shape-preserving like
     jnp.take).  The vjp produces the packed dense gradient through
-    ``pack_write`` — no XLA scatter anywhere."""
+    ``pack_write`` — no XLA scatter anywhere; ``mesh`` (the one the caller
+    sees, or None) is for it."""
     q = 128 // dim
     flat = ids.reshape(-1).astype(jnp.int32)
     # negative (padding) ids clamp to logical row 0, matching the
@@ -211,25 +216,24 @@ def packed_lookup(table, ids, dim, use_pallas=True):
     return rows.reshape(ids.shape + (dim,))
 
 
-def _packed_lookup_fwd(table, ids, dim, use_pallas):
-    return packed_lookup(table, ids, dim, use_pallas), \
-        (ids, table.shape[0])
+def _packed_lookup_fwd(table, ids, dim, mesh):
+    return packed_lookup(table, ids, dim, mesh), (ids, table.shape[0])
 
 
-def _packed_lookup_bwd(dim, use_pallas, res, g):
+def _packed_lookup_bwd(dim, mesh, res, g):
     ids, p_rows = res
     q = 128 // dim
     flat = ids.reshape(-1).astype(jnp.int32)
     lines = _position_lines(flat, g.reshape(-1, dim), q, dim)
-    grad = pack_write(flat // q, lines, p_rows, use_pallas=use_pallas)
+    grad = _write(flat // q, lines, p_rows, dispatch.take(
+        "packed_lookup", mesh, _unsupported(lines.dtype)))
     return grad, np.zeros(ids.shape, jax.dtypes.float0)
 
 
 packed_lookup.defvjp(_packed_lookup_fwd, _packed_lookup_bwd)
 
 
-def sharded_packed_lookup(mesh, table, ids, dim, axis="model",
-                          use_pallas=True):
+def sharded_packed_lookup(mesh, table, ids, dim, axis="model"):
     """:func:`packed_lookup` inside a GSPMD mesh program.
 
     ``pallas_call`` does not partition, so the lookup runs under
@@ -251,7 +255,7 @@ def sharded_packed_lookup(mesh, table, ids, dim, axis="model",
             f"{axis!r} (size {n_shards})")
 
     def local(tbl, local_ids):
-        return packed_lookup(tbl, local_ids, dim, use_pallas)
+        return packed_lookup(tbl, local_ids, dim)   # per shard: no mesh
 
     spec = P(axis) if ids.ndim == 1 else P(*((axis,) + (None,) *
                                              (ids.ndim - 1)))

@@ -139,7 +139,7 @@ class EmbeddingServer:
                  max_queue=None, low_watermark=None,
                  shed_policy="reject_newest", watchdog=True, clock=None,
                  instance=None, latency_buckets=None, device=None,
-                 name=None, own_host_table=None, use_pallas=True):
+                 name=None, own_host_table=None):
         self.params = executor.params
         self.model = model
         self.instance = None if instance is None else str(instance)
@@ -155,7 +155,6 @@ class EmbeddingServer:
             self.instance or "embed")
         self.watchdog = bool(watchdog)
         self._clock = clock if clock is not None else time.perf_counter
-        self.use_pallas = bool(use_pallas)
         if host_table is None:
             # spill the trained in-graph table to host RAM: the device
             # never holds the full table again, exactly the
@@ -251,13 +250,13 @@ class EmbeddingServer:
         shape = (self.n_slots, self.num_sparse, self.dim, self.num_dense,
                  None if self.hot is None else self.hot.padded_rows)
         return (type(self.model).__name__, self._names, shape, mode,
-                self.use_pallas, jax.default_backend())
+                jax.default_backend())
 
     def _build(self):
         score, self._names = make_wdl_scorer(self.model)
         entry = self._PROGRAMS.get(self._program_key())
         if entry is None:
-            dim, use_pallas = self.dim, self.use_pallas
+            dim = self.dim
             p_rows = None if self.hot is None else self.hot.p_rows
             from ... import telemetry as _tel
             retrace = _tel.get_registry().counter(
@@ -274,8 +273,7 @@ class EmbeddingServer:
                     traces[mode] += 1   # host-side retrace witness
                     retrace.labels(program=mode).inc()
                     packed = table_dev.reshape(p_rows, 128)
-                    rows = packed_lookup(packed, slot_ids, dim,
-                                         use_pallas)
+                    rows = packed_lookup(packed, slot_ids, dim)
                     logits = score(params, rows, dense)
                     ok = jnp.isfinite(logits)
                     return jnp.where(active, logits, 0.0), ok

@@ -116,6 +116,19 @@ def clone_params_into(ex, prev):
     return {k: np.asarray(v) for k, v in ex.params.items()}
 
 
+def dense_twin_gate(gate):
+    """``gate`` (a ``layers/moe.py`` gate with a choices form) as a
+    caller-built gate that exposes ``gating`` and ``aux`` alone: a capacity
+    ``MoELayer`` handed it runs the dense one-hot einsums on the same
+    routing, the oracle of the scatter-style dispatch."""
+    from hetu_tpu.layers.base import BaseLayer
+
+    class Dense(BaseLayer):
+        def __init__(self):
+            self.wg, self.gating, self.aux = gate.wg, gate.gating, gate.aux
+    return Dense()
+
+
 def lowered_for_tpu(monkeypatch, build, debug_info=False):
     """The text of the train step of the benchmark program ``build()`` makes,
     lowered for a TPU (nothing is compiled or run) with the platform read as

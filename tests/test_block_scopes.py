@@ -25,7 +25,7 @@ from hetu_tpu.optim import optimizer
 #: every Mosaic kernel's name: they stand in ``op_name`` too
 #: (``pallas_call[name=...]``), so no block's name may lie inside one
 KERNELS = ("hetu_dropout_mask", "hetu_flash_fwd", "hetu_flash_bwd",
-           "hetu_gdn_fwd", "hetu_gdn_bwd", "hetu_moe_row_gather",
+           "hetu_gdn_fwd", "hetu_gdn_bwd",
            "hetu_moe_gmm_fwd", "hetu_moe_gmm_dw", "hetu_moe_gmm_dx",
            "hetu_softmax_ce_fwd", "hetu_softmax_ce_bwd",
            "hetu_packed_embedding_write", "hetu_ssd_fwd", "hetu_ssd_bwd",

@@ -35,10 +35,6 @@ HETU_ROOT = os.path.join(os.path.dirname(__file__), "..", "hetu_tpu")
 #: the ops whose kernels run per shard under a mesh: each has a plan that
 #: reads the mesh (``dispatch.shard_axes``) and hands ``record`` its reason
 PLANNED = {"ops/attention.py", "ops/losses.py", "ops/nn.py"}
-#: until ROADMAP's D10 is paid (``row_gather``, ``use_pallas=`` and
-#: ``MoELayer(sparse=)`` go together): the caller computes ``mesh is None``
-#: and the kernel file records for itself
-D10 = {"ops/pallas/moe_dispatch.py", "ops/pallas/sparse_densify.py"}
 
 
 def modules(*packages):
@@ -78,7 +74,7 @@ def test_record_is_called_by_dispatch_and_the_plans_alone():
                 yield node.lineno
     where = {rel for rel, tree in modules("ops", "layers")
              if list(records(tree))}
-    assert where - D10 == PLANNED | {"ops/pallas/dispatch.py"}
+    assert where == PLANNED | {"ops/pallas/dispatch.py"}
 
 
 def test_no_module_imports_a_siblings_private_name():
@@ -112,6 +108,9 @@ TABLE = {
     "hc_mix": (PALLAS, MESH, NOTHING, NOTHING),
     "mla_pack": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_gmm": (PALLAS, MESH, CPU, MESH),
+    # the packed table's row-write kernel, by the entry that reached it
+    "packed_lookup": (PALLAS, MESH, CPU, MESH),
+    "pack_write": (PALLAS, MESH, CPU, MESH),
 }
 
 

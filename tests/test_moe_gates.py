@@ -125,18 +125,23 @@ def test_moe_layer_trains_with_gate(gate, kw, rng):
 @pytest.mark.parametrize("gate_kind", ["ktop1", "sam"])
 def test_sparse_path_matches_dense_for_ktop1_and_sam(rng, gate_kind):
     """KTop1/SAM gates also expose the CHOICES form: the sparse
-    scatter-dispatch MoELayer matches a dense-forced twin end to end."""
+    scatter-dispatch MoELayer matches a twin on the dense einsums (the same
+    gate without its choices form) end to end."""
+    from conftest import dense_twin_gate
     from hetu_tpu.layers import MoELayer
+    from hetu_tpu.layers.moe import KTop1Gate, SAMGate
 
     B, S, H = 4, 8, 16
     X = rng.standard_normal((B, S, H)).astype(np.float32)
     Y = np.zeros_like(X)
     losses, prev = {}, None
     for mode in ("sparse", "dense"):
-        kw = dict(num_groups=2) if gate_kind == "sam" else {}
+        name = f"ks_{gate_kind}_{mode}"
+        gate = (SAMGate(H, 4, 2, name=name) if gate_kind == "sam"
+                else KTop1Gate(H, 4, name=name))
         moe = MoELayer(H, 32, num_experts=4, k=2, capacity_factor=2.0,
-                       gate=gate_kind, sparse=(mode == "sparse"),
-                       name=f"ks_{gate_kind}_{mode}", **kw)
+                       gate=gate if mode == "sparse"
+                       else dense_twin_gate(gate), name=name)
         x = ht.placeholder_op(f"ksx_{gate_kind}_{mode}", X.shape)
         y = ht.placeholder_op(f"ksy_{gate_kind}_{mode}", X.shape)
         loss = ht.mse_loss_op(moe(x), y) + 0.01 * moe.aux_loss()

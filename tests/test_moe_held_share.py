@@ -412,12 +412,51 @@ def test_the_router_bias_moves_by_its_rule_and_is_no_weight():
                               for path, _ in states)
 
 
-def test_relu2_and_sigmoid_run_on_the_dropless_path_alone():
-    with pytest.raises(AssertionError, match="dropless"):
-        MoELayer(H, F, E, k=K, expert_act="relu2", name="bad1")
-    with pytest.raises(AssertionError, match="dropless"):
-        MoELayer(H, F, E, k=K, expert_act="swiglu", router_score="sigmoid",
-                 name="bad2")
+def caller_built(kind):
+    from hetu_tpu.layers.moe import KTop1Gate, StateRouter, TopKGate
+    return {"sigmoid_gate": lambda: TopKGate(H, E, score="sigmoid"),
+            "ktop1_gate": lambda: KTop1Gate(H, E),
+            "router": lambda: StateRouter(H, E, 8)}[kind]()
+
+
+@pytest.mark.parametrize("keyword,value,regime", [
+    ("held", (0, 4), "dropless"),
+    ("router", "router", "dropless"),
+    ("router_groups", (2, 1), "dropless"),
+    ("router_score", "sigmoid", "dropless"),
+    ("expert_act", "relu2", "dropless"),
+    ("gate", "sigmoid_gate", "dropless"),
+    ("expert_act", "gelu", "capacity"),
+    ("gate", "hash", "capacity"),
+    ("gate", "ktop1", "capacity"),
+    ("gate", "sam", "capacity"),
+    ("gate", "balance", "capacity"),
+    ("gate", "ktop1_gate", "capacity"),
+])
+def test_a_keyword_of_the_other_regime_is_refused_by_name(keyword, value,
+                                                          regime):
+    """``layers/moe.py _ONE_REGIME_ALONE``: a layer of one regime
+    (``capacity_factor is None`` or not) refuses what belongs to the other
+    alone, and says which keyword and which regime; under its own regime the
+    same keyword builds."""
+    if value in ("router", "sigmoid_gate", "ktop1_gate"):
+        value = caller_built(value)
+    kw = {"expert_act": "swiglu", keyword: value}
+    if value == "sam":
+        kw["num_groups"] = 2
+    own, other = (None, 1.25) if regime == "dropless" else (1.25, None)
+    with pytest.raises(ValueError, match=rf"{keyword}=.* belongs to the "
+                                         rf"{regime} regime"):
+        MoELayer(H, F, E, k=K, capacity_factor=other, **kw)
+    MoELayer(H, F, E, k=K, capacity_factor=own, **kw)
+
+
+@pytest.mark.parametrize("capacity_factor", [None, 1.25])
+def test_the_layers_own_keywords_build_under_either_regime(capacity_factor):
+    layer = MoELayer(H, F, E, k=K, capacity_factor=capacity_factor,
+                     expert_act="swiglu", shared_width=8, shared_gate=False,
+                     ep_axis="ep", track_load=True, renorm_topk=False)
+    assert len(layer.shared) == 3 and layer.load_var.shape == (2, E)
 
 
 # -- rows to tokens: the sum over token tiles against the one-hot product -----

@@ -41,7 +41,7 @@ def plain_router(u, p, name, prev=None):
     return h @ p[f"{name}_out_weight"], r
 
 
-def stack(name, held=None, rate=None, layers=3):
+def stack(name, held=None, rate=None, layers=3, skip=1):
     """Three expert layers behind their routers, each handed the state of the
     one above: ``y = sum of the layers' outputs``."""
     x = ht.placeholder_op(f"{name}_x", (1, T, C))
@@ -50,7 +50,7 @@ def stack(name, held=None, rate=None, layers=3):
         moe = MoELayer(C, F, num_experts=E, k=1, capacity_factor=None,
                        expert_act="swiglu", renorm_topk=False, track_load=True,
                        held=held,
-                       router=StateRouter(C, E, R, skip=1, bias_rate=rate,
+                       router=StateRouter(C, E, R, skip=skip, bias_rate=rate,
                                           name=f"{name}_r{i}"),
                        name=f"{name}_moe{i}")
         ys.append(moe(x, state=state))
@@ -72,7 +72,7 @@ def perturb(ex, seed=5, bias_spread=0.05):
 def test_the_state_is_a_sum_down_three_layers():
     x, moes, ys = stack("sr_sum")
     ex = ht.Executor({"forward": [m.state for m in moes]
-                      + [m.last_op.router_in for m in moes]}, seed=1)
+                      + [m.last_op.inputs[m.last_op.at["router"]] for m in moes]}, seed=1)
     perturb(ex)
     out = ex.run("forward", feed_dict={x: X}, convert_to_numpy_ret_vals=True)
     p = {k: jnp.asarray(v) for k, v in ex.params.items()}
@@ -99,7 +99,8 @@ def test_top1_weight_zeros_of_the_skip_choice_and_the_count():
     x, moes, ys = stack("sr_top1", layers=1)
     moe = moes[0]
     ex = ht.Executor({"forward": [ys[0], moe.chosen(), moe.load(), moe.state,
-                                  moe.last_op.router_in]}, seed=2)
+                                  moe.last_op.inputs[
+                                      moe.last_op.at["router"]]]}, seed=2)
     perturb(ex, bias_spread=0.1)
     y, chosen, load, state, logits = ex.run(
         "forward", feed_dict={x: X}, convert_to_numpy_ret_vals=True)
@@ -227,6 +228,37 @@ def test_the_counters_of_a_layer_with_a_skip_choice():
         assert "hetu_moe_router_state_rms" not in snap
     finally:
         telemetry.shutdown()
+
+
+def test_the_loss_side_nodes_read_the_ops_inputs_by_name():
+    """A layer whose op has a state, a bias and the load's variable among its
+    inputs at once (the second of a stack): the balance loss, the z-loss and
+    the chosen experts are those of the logits and the bias the op's names
+    find, whatever stands between them in the list."""
+    from hetu_tpu.ops.moe import expert_load, load_balancing_loss
+    x, moes, ys = stack("sr_names", layers=2, skip=0)
+    moe = moes[1]
+    op = moe.last_op
+    assert list(op.at) == ["x", "w1", "w2", "w3", "router", "state", "bias",
+                           "load"]
+    named = {n: op.inputs[i] for n, i in op.at.items()}
+    assert named["state"] is moe.state and named["bias"] is moe.gate.bias
+    assert named["load"] is moe.load_var
+    nodes = [moe.aux_loss(), moe.z_loss(), moe.chosen()]
+    assert all(n.inputs == op.inputs for n in nodes)
+    ex = ht.Executor({"forward": nodes + [named["router"], ys[1]]}, seed=3)
+    perturb(ex, bias_spread=0.2)
+    aux, z, chosen, logits, _ = ex.run("forward", feed_dict={x: X},
+                                       convert_to_numpy_ret_vals=True)
+    probs = jax.nn.softmax(jnp.asarray(logits), -1)
+    want = np.argmax(np.asarray(probs) + np.asarray(
+        ex.params["sr_names_r1_bias"]), -1)
+    assert (want != np.argmax(logits, -1)).any()        # the bias was read
+    np.testing.assert_array_equal(chosen[:, 0], want)
+    lse = jax.nn.logsumexp(jnp.asarray(logits), -1)
+    np.testing.assert_allclose(z, jnp.mean(lse * lse), rtol=1e-6)
+    np.testing.assert_allclose(aux, load_balancing_loss(
+        probs, expert_load(jnp.asarray(want), E)), rtol=1e-6)
 
 
 #: read at the parent of PR 58 (commit aa26f6b) from the toy programs of

@@ -105,7 +105,7 @@ def test_pack_write_fallback_semantics(rng):
     ids = np.array([3, 3, 7, -1, 0], np.int32)      # dup + invalid
     lines = rng.standard_normal((5, 128)).astype(np.float32)
     out = np.asarray(pack_write(jnp.asarray(ids), jnp.asarray(lines),
-                                p_rows, use_pallas=False))
+                                p_rows))
     ref = np.zeros((p_rows, 128), np.float32)
     for i, r in zip(ids, lines):
         if i >= 0:

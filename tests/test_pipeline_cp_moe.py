@@ -425,25 +425,6 @@ def test_ring_attention_dp_cp_mesh():
 
 # -- sparse (scatter-style) MoE dispatch (reference LayoutTransform.cu) ----
 
-def test_row_gather_matches_take(rng):
-    from hetu_tpu.ops.pallas.moe_dispatch import row_gather
-    src = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
-    idx = jnp.asarray([3, 0, 15, -1, 7, 30, 2, 2], jnp.int32)
-    got = row_gather(src, idx)
-    want = np.where((np.asarray(idx) >= 0)[:, None]
-                    & (np.asarray(idx) < 16)[:, None],
-                    np.asarray(src)[np.clip(np.asarray(idx), 0, 15)], 0)
-    np.testing.assert_allclose(np.asarray(got), want)
-    # vjp: scatter-add back (duplicate index 2 accumulates)
-    f = lambda s: jnp.sum(row_gather(s, idx) * 2.0)
-    g = jax.grad(f)(src)
-    expect = np.zeros((16, 8), np.float32)
-    for j in np.asarray(idx):
-        if 0 <= j < 16:
-            expect[j] += 2.0
-    np.testing.assert_allclose(np.asarray(g), expect)
-
-
 @pytest.mark.parametrize("k", [1, 2])
 def test_sparse_dispatch_matches_dense_einsum(rng, k):
     """The scatter-style layout transform is EXACT vs the one-hot einsum
@@ -489,10 +470,13 @@ def test_sparse_dispatch_matches_dense_einsum(rng, k):
 
 
 def test_moe_layer_sparse_matches_dense_and_memory_sweep(rng):
-    """MoELayer end-to-end on the sparse path == a dense-forced run, and
-    the compiled program's footprint no longer scales with E at fixed
-    E*C*H (the [T,E,C] wall moved; sweep over experts)."""
+    """MoELayer end-to-end on the sparse path == a run on a gate without a
+    choices form (the dense einsums), and the compiled program's footprint
+    no longer scales with E at fixed E*C*H (the [T,E,C] wall moved; sweep
+    over experts)."""
+    from conftest import dense_twin_gate
     from hetu_tpu.layers import MoELayer
+    from hetu_tpu.layers.moe import TopKGate
 
     B, S, H = 4, 8, 16
     X = rng.standard_normal((B, S, H)).astype(np.float32)
@@ -500,8 +484,10 @@ def test_moe_layer_sparse_matches_dense_and_memory_sweep(rng):
 
     losses, prev = {}, None
     for mode in ("sparse", "dense"):
+        gate = TopKGate(H, 4, name=f"sdm_{mode}")
         moe = MoELayer(H, 32, num_experts=4, k=2, capacity_factor=2.0,
-                       sparse=(mode == "sparse"), name=f"sdm_{mode}")
+                       gate=gate if mode == "sparse"
+                       else dense_twin_gate(gate), name=f"sdm_{mode}")
         x = ht.placeholder_op(f"sdx_{mode}", X.shape)
         y = ht.placeholder_op(f"sdy_{mode}", X.shape)
         loss = ht.mse_loss_op(moe(x), y) + 0.01 * moe.aux_loss()
