@@ -82,10 +82,12 @@ class TopKGate(BaseLayer):
         return top_k_gating_choices(tokens @ wg, k, capacity,
                                     second_renorm=self.renorm)
 
-    def route(self, tokens, wg, k, bias=None):
+    def route(self, tokens, wg, k, bias=None, mesh=None):
         """Dropless routing: ``(logits, idx, gate, probs)``, the logits and
         everything after them in f32 at full matmul precision, so that which
-        experts a token takes does not depend on the compute type."""
+        experts a token takes does not depend on the compute type.  ``mesh``:
+        the node's, for the kernel behind the choice (``ops/moe.py
+        select_k``)."""
         import jax
         import jax.numpy as jnp
         from ..ops.moe import top_k_route
@@ -94,7 +96,8 @@ class TopKGate(BaseLayer):
                             precision=jax.lax.Precision.HIGHEST)
         return (logits,) + top_k_route(logits, k, renorm=self.renorm,
                                        score=self.score, bias=bias,
-                                       scale=self.scale, groups=self.groups)
+                                       scale=self.scale, groups=self.groups,
+                                       mesh=mesh)
 
     def aux(self, tokens, wg, ids, k):
         return top_k_balance_aux(tokens @ wg)
@@ -256,12 +259,12 @@ class StateRouter(BaseLayer):
                             self.w2, self.b2, self.w3, *more, eps=self.eps)
             return pair_item_op(both, index=0), pair_item_op(both, index=1)
 
-    def route(self, tokens, logits, k, bias=None):
+    def route(self, tokens, logits, k, bias=None, mesh=None):
         """``(logits, idx, gate, probs)`` as ``TopKGate.route``: the softmax,
         the ``k`` largest of ``p + bias`` and their ``p``, not renormalised."""
         from ..ops.moe import top_k_route
         return (logits,) + top_k_route(logits, k, renorm=False,
-                                       score="softmax", bias=bias)
+                                       score="softmax", bias=bias, mesh=mesh)
 
 
 #: the rows of a layer's load (``MoELayer.load()``), an entry an expert the
@@ -327,7 +330,7 @@ class _DroplessOp(_MoEOp):
     def _bias(self, input_vals, ctx):
         """The router's selection bias in f32: under a lower compute type
         the f32 master, as an optimizer reads a weight (a bias of 0.5 moved
-        by 0.001 in bf16 would not move)."""
+        by 0.001 in bf16 would not move); None where the gate has none."""
         if self.bias_var is not None and ctx.master_params is not None:
             return ctx.master_params[self.bias_var.name]
         return self.read(input_vals, "bias")
@@ -340,12 +343,11 @@ class _DroplessOp(_MoEOp):
         x = self.read(input_vals, "x")
         memo = ctx.__dict__.setdefault("_moe_routing", {})
         if self.id not in memo or memo[self.id][0] is not x:
-            extra = (() if self.bias_var is None
-                     else (self._bias(input_vals, ctx),))
             with named_scope("hetu_moe_route"):
                 memo[self.id] = (x, self.gate.route(
                     x.reshape(-1, x.shape[-1]),
-                    self.read(input_vals, "router"), self.k, *extra))
+                    self.read(input_vals, "router"), self.k,
+                    bias=self._bias(input_vals, ctx), mesh=ctx.mesh))
         return memo[self.id][1]
 
     def _move_bias(self, input_vals, idx, ctx):

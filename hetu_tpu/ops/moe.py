@@ -371,8 +371,40 @@ def sam_group_sum(x, group_idx, num_groups):
 # rows of a capacity buffer (at the no-drop capacity factor E/k that is E/k
 # times the expert work).
 
+def select_k(x, k, mesh=None, asked=False):
+    """``jax.lax.top_k(x, k)[1]`` as int32 for ``x [T, E]`` f32: the ``k``
+    largest of a row, largest first, ties to the lower index, ``-inf``
+    entries last.  By selection where that is cheaper than the full sort of
+    every row ``top_k`` lowers to on a TPU, chosen by what the call can see:
+    the first maximum at ``k == 1`` (the algorithm, no kernel: nothing is
+    recorded); ``hetu_moe_select`` (``ops/pallas/moe_select.py``: ``k``
+    masked maxima over a tile in VMEM) on a TPU without a ``mesh``, recorded
+    in ``dispatch.choices()`` under ``moe_select``; ``top_k`` itself
+    elsewhere (``asked``: the kernel in interpret mode)."""
+    from .pallas import dispatch, moe_select
+    if k == 1:
+        return jnp.argmax(x, axis=-1).astype(jnp.int32)[:, None]
+    if dispatch.take("moe_select", mesh,
+                     moe_select.unsupported(x.shape[-1], k, x.dtype),
+                     asked=asked):
+        # the choice carries no gradient: nothing to differentiate through
+        return moe_select.select(jax.lax.stop_gradient(x), k)
+    return jax.lax.top_k(x, k)[1].astype(jnp.int32)
+
+
+def _two_largest_sum(x):
+    """``jnp.sum(jax.lax.top_k(x, 2)[0], -1)`` by three reductions and no
+    sort: the maximum ``m1``, and ``m2``, the maximum once the first
+    position of ``m1`` is taken out (``m1`` again where it stands twice)."""
+    m1 = jnp.max(x, axis=-1, keepdims=True)
+    twice = jnp.sum((x == m1).astype(jnp.int32), axis=-1) > 1
+    below = jnp.max(jnp.where(x < m1, x, -jnp.inf), axis=-1)
+    m1 = m1[..., 0]
+    return m1 + jnp.where(twice, m1, below)
+
+
 def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
-                scale=None, groups=None):
+                scale=None, groups=None, mesh=None):
     """``(idx [T, k] int32, gate [T, k] f32, probs [T, E] f32)``: the ``k``
     largest softmax probabilities of each token, largest first, ties to the
     lower expert index; ``renorm`` rescales them to sum to 1 (Mixtral), the
@@ -394,7 +426,10 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
     in ``n_group`` groups of neighbours, a group scored by the sum of its two
     largest ``s + bias``, the ``topk_group`` best groups kept (ties to the
     lower group) and the ``k`` experts chosen among theirs alone.  One group
-    is the ungrouped router, bit for bit."""
+    is the ungrouped router, bit for bit.
+
+    Every choice is ``select_k``'s (``mesh``: the mesh the calling node sees,
+    which a ``pallas_call`` does not partition under)."""
     assert score in ("softmax", "sigmoid"), score
     if score == "softmax":
         scores = probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -408,13 +443,12 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
         T, E = chosen_by.shape
         assert E % n_group == 0 and k <= topk_group * (E // n_group), groups
         by_group = chosen_by.reshape(T, n_group, E // n_group)
-        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
-        _, best = jax.lax.top_k(group_score, topk_group)
+        best = select_k(_two_largest_sum(by_group), topk_group, mesh)
         kept = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32),
                        axis=1) > 0                           # [T, n_group]
         chosen_by = jnp.where(kept[:, :, None], by_group,
                               -jnp.inf).reshape(T, E)
-    _, idx = jax.lax.top_k(chosen_by, k)
+    idx = select_k(chosen_by, k, mesh)
     # the gates by a one-hot product, not top_k's values: its backward
     # pass is then a product too and not a scatter-add into [T, E]
     gate = jnp.sum(jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)
@@ -423,7 +457,7 @@ def top_k_route(logits, k, renorm=False, score="softmax", bias=None,
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     if scale is not None:
         gate = gate * scale
-    return idx.astype(jnp.int32), gate, probs
+    return idx, gate, probs
 
 
 def expert_load(idx, num_experts):

@@ -211,16 +211,18 @@ def test_the_gate_a_head_in_place_is_the_gate_by_heads(dtype):
 #: cell -> sha256 of ``without_locations`` of its toy train step lowered for a
 #: TPU (without the results' paths, which hold names the process numbers:
 #: the optimizer's, a fresh variable's), taken on the tree before PR 52
-#: (8d0d00d) and equal on this one
+#: (8d0d00d) and equal on this one; OLMoE's and Qwen3-Next's since PR 61, whose
+#: routers choose their experts in ``hetu_moe_select`` (the three steps
+#: without a router kept theirs)
 TOY_STEPS = {
     "bert-base.b64-s512":
         "fef11c9a04527e1704fa1b7730bef180fb2aae5027eeee745929dd16f4e31407",
     "olmoe-1b-7b.b2-s4096":
-        "c75c3dee79f111df33c42fb5effb93563db492699c546bbf10fa082454540c8b",
+        "b624dcca179f629ffb969b5fdd888686184eaffad14989d9a38bc8941834ff1a",
     "ouro-2.6b.b1-s8192":
         "6c3754eb9f8eb2ff37b5f3c719410662b5bc365c495d35292bea1b7c1555f428",
     "qwen3-next-80b-a3b.b1-s8192":
-        "59882ada7f3aeef3332f0b23fc07603a0f05d5fdf618f798bc2550ad63973b12",
+        "6279b5a9f01d4e8d15673fde6e3b5f4d7012bbec0f92400a26b2ae06d0dd36f6",
     "granite-4.0-h-micro.b1-s8192":
         "baabcf14ca313dcafc00896ad55f739a9f0c28691a203ad6c26c68e4ee8275cb",
 }

@@ -1076,6 +1076,37 @@ def test_held_relu2_experts_compile_for_v5e_at_a_width_off_128(v5e, as_on_tpu):
     assert "ragged-dot" not in hlo
 
 
+@pytest.mark.parametrize("experts,k,groups", [
+    (512, 8, (8, 4)),       # Ling-3.0: the groups kept, then the experts
+    (512, 10, None),        # Qwen3-Next
+    (256, 8, None), (128, 6, None), (64, 8, None), (64, 4, None),
+    (17, 1, None),          # ZAYA1: one choice, the first maximum, no kernel
+])
+def test_the_routers_choice_compiles_for_v5e(v5e, as_on_tpu, experts, k,
+                                             groups):
+    """Every expert cell's router over 8,192 tokens under ``jax.grad``: the
+    choice is ``hetu_moe_select`` (twice with groups, never at ``k = 1``) and
+    no sort of the scores is left in the compiled program."""
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops import moe as moe_ops
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+
+    def loss(x, wr, bias):
+        idx, gate, probs = moe_ops.top_k_route(
+            x @ wr, k, renorm=True, score="sigmoid", bias=bias,
+            groups=groups)
+        return jnp.sum(gate * idx) + jnp.sum(probs[:, 0])
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sds((8192, 256), jnp.float32), sds((256, experts), jnp.float32),
+        sds((experts,), jnp.float32)).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == (0 if k == 1 else 2 if groups else 1)
+    assert all("hetu_moe_select" in ln for ln in kernels)
+    assert " sort(" not in hlo
+
+
 def test_mamba2_scan_node_compiles_for_v5e(v5e, as_on_tpu):
     """The Nemotron-H cell's ``hetu_ssm_scan`` node (64 heads of 64, state
     128, 8 groups, 8,192 positions in chunks of 128, bf16), forward and

@@ -107,6 +107,7 @@ TABLE = {
     "rotary": (PALLAS, MESH, NOTHING, NOTHING),
     "hc_mix": (PALLAS, MESH, NOTHING, NOTHING),
     "mla_pack": (PALLAS, MESH, NOTHING, NOTHING),
+    "moe_select": (PALLAS, MESH, NOTHING, NOTHING),
     "moe_gmm": (PALLAS, MESH, CPU, MESH),
     # the packed table's row-write kernel, by the entry that reached it
     "packed_lookup": (PALLAS, MESH, CPU, MESH),
