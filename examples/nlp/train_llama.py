@@ -40,6 +40,17 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            convolutional attention and top-1 experts behind the router that
            carries its state down the depth, 8 of 16 experts held, an eighth
            of the tied vocabulary)
+       python examples/nlp/train_llama.py --model sdar-30b-a3b-chat \
+           --layers 6 --experts-held 0:16 --vocab-rows 18992 \
+           --seq-len 8192 --batch-size 1     (SDAR-30B-A3B-Chat's
+           block-diffusion training at one chip's share of an 8-way
+           expert-parallel job: every batch goes through
+           hetu_tpu.dataloader.block_diffusion_noise (a level a block of 4, a
+           draw a token, the mask token), the model walks the clean and the
+           noised copy of a sequence in one pass under the block-diffusion
+           mask and the loss is the 1/t-weighted cross-entropy on the masked
+           positions; six layers, 16 of 128 experts held, an eighth of the
+           vocabulary, the mask token its last row)
 """
 
 import os
@@ -64,7 +75,8 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              OuroForCausalLM, OURO_CONFIGS, LagunaConfig,
                              LagunaForCausalLM, LAGUNA_CONFIGS, Xing4Config,
                              Xing4ForCausalLM, XING4_CONFIGS, Zaya1Config,
-                             Zaya1ForCausalLM, ZAYA1_CONFIGS,
+                             Zaya1ForCausalLM, ZAYA1_CONFIGS, SdarMoeConfig,
+                             SdarMoeForCausalLM, SDAR_CONFIGS,
                              record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
@@ -76,7 +88,8 @@ def main():
                              + list(NEMOTRON_H_CONFIGS)
                              + list(GRANITE_HYBRID_CONFIGS)
                              + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)
-                             + list(XING4_CONFIGS) + list(ZAYA1_CONFIGS)))
+                             + list(XING4_CONFIGS) + list(ZAYA1_CONFIGS)
+                             + list(SDAR_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -90,7 +103,7 @@ def main():
                          "it is a slice of the published vocabulary, ids, "
                          "logits and the loss are over the slice")
     ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
-                    help="qwen3-next, nemotron, xing4, zaya1: the experts of each "
+                    help="qwen3-next, nemotron, xing4, zaya1, sdar: the experts of each "
                          "layer this chip holds, of the router's full width")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -125,6 +138,9 @@ def main():
               (ZAYA1_CONFIGS, Zaya1Config, Zaya1ForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in ZAYA1_CONFIGS else
+              (SDAR_CONFIGS, SdarMoeConfig, SdarMoeForCausalLM,
+               "num_hidden_layers", "moe_intermediate_size")
+              if args.model in SDAR_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
@@ -145,14 +161,24 @@ def main():
     if args.experts_held:
         base["experts_held"] = tuple(
             int(n) for n in args.experts_held.split(":"))
+    diffusion = args.model in SDAR_CONFIGS
+    if diffusion and args.vocab:
+        # the mask token is an ordinary row of the slice: its last
+        base["mask_token_id"] = args.vocab - 1
     c = config_cls(seq_len=args.seq_len, **base)
     rng = np.random.default_rng(0)
     B, S = args.batch_size, args.seq_len
 
-    ids = ht.placeholder_op("ids", (B, S), dtype=np.int32)
+    # block diffusion walks a clean and a noised copy of a sequence in one
+    # pass and weighs each masked position's loss
+    ids = ht.placeholder_op("ids", (B, 2 * S if diffusion else S),
+                            dtype=np.int32)
     labels = ht.placeholder_op("labels", (B, S), dtype=np.int32)
+    weights = (ht.placeholder_op("weights", (B, S), dtype=np.float32)
+               if diffusion else None)
     model = model_cls(c, pipeline_stages=args.pp or None)
-    loss = model.loss(ids, labels)
+    loss = (model.loss(ids, labels, weights) if diffusion
+            else model.loss(ids, labels))
     opt = ht.AdamWOptimizer(learning_rate=args.lr, weight_decay=0.01)
 
     kwargs = dict(compute_dtype=jnp.bfloat16)
@@ -183,8 +209,14 @@ def main():
         print(f"imported weights from {args.hf_import}")
 
     for step in range(args.steps):
-        tok = rng.integers(0, c.vocab_size, (B, S + 1))
-        feed = {ids: tok[:, :-1], labels: tok[:, 1:]}
+        if diffusion:
+            tok = rng.integers(0, c.vocab_size - 1, (B, S))
+            tok = tok + (tok >= c.mask_token_id)    # never the mask token
+            feed = dict(zip((ids, labels, weights), ht.block_diffusion_noise(
+                tok, c.block_length, c.mask_token_id, rng)))
+        else:
+            tok = rng.integers(0, c.vocab_size, (B, S + 1))
+            feed = {ids: tok[:, :-1], labels: tok[:, 1:]}
         out = ex.run("train", feed_dict=feed,
                      convert_to_numpy_ret_vals=True)
         for i, load in enumerate(out[2:2 + len(loads)]):

@@ -30,7 +30,8 @@ from .resilience import (CheckpointError, GuardTripped,
                          RollingCheckpointManager, StepGuard, retry)
 from . import metrics
 from . import telemetry
-from .dataloader import Dataloader, DataloaderOp, dataloader_op
+from .dataloader import (Dataloader, DataloaderOp, dataloader_op,
+                         block_diffusion_noise)
 from .datasets.prefetch import DevicePrefetcher, prefetch_feeds
 from .logger import HetuLogger, WandbLogger
 from .profiler import HetuProfiler, HetuSimulator

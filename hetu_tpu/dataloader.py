@@ -31,6 +31,39 @@ import numpy as np
 from .graph.node import PlaceholderOp
 
 
+def block_diffusion_noise(ids, block, mask_id, rng, eps=1e-3):
+    """The noising step of block diffusion's data path (SDAR, arXiv:2510.06303;
+    the linear schedule of MDLM and LLaDA) on a host batch ``ids [B, L]``: for
+    each sequence and block of ``block`` tokens a level ``t ~ U[eps, 1]``, for
+    each token a draw ``m ~ Bernoulli(t of its block)``, and a masked token
+    becomes ``mask_id``.  Returns ``(input_ids [B, 2L], labels [B, L], weights
+    [B, L] f32)``: the clean copy and then the noised one, a masked position's
+    token (-1 elsewhere) and ``1 / t`` of a position's block, the weight the
+    masked-diffusion bound gives its cross-entropy.  ``rng`` is a
+    ``numpy.random.Generator``: the same state gives the same batch.  Counts
+    the positions it masked and kept in
+    ``hetu_diffusion_positions_total{state}``."""
+    from . import telemetry
+    ids = np.asarray(ids)
+    B, L = ids.shape
+    assert L % block == 0, (L, block)
+    t = rng.uniform(eps, 1.0, (B, L // block))
+    level = np.repeat(t, block, axis=1)
+    masked = rng.random((B, L)) < level
+    noised = np.where(masked, np.asarray(mask_id, ids.dtype), ids)
+    counts = telemetry.get_registry().counter(
+        "hetu_diffusion_positions_total",
+        "Noised positions block_diffusion_noise made, by state (masked: "
+        "replaced by the mask token and labelled; kept: left as they were)",
+        labels=("state",))
+    n_masked = int(masked.sum())
+    counts.labels(state="masked").inc(n_masked)
+    counts.labels(state="kept").inc(B * L - n_masked)
+    return (np.concatenate([ids, noised], axis=1),
+            np.where(masked, ids, -1).astype(ids.dtype),
+            (1.0 / level).astype(np.float32))
+
+
 def _mp_worker(worker_id, num_workers, start, stop, data_shm_name,
                data_shape, data_dtype, out_shm_name, out_shape, out_dtype,
                slots, empty_sems, filled_sems, batch_size, num_batches,

@@ -35,7 +35,10 @@ the scores are multiplied by before the softmax where it is not ``head_dim **
 ``window`` keeps of a causal layer's keys the last ``window`` (the position's
 own among them); such a layer's block is ``hetu_window_attn`` (no scope name
 may lie inside another), not ``hetu_attn``.  ``rope_scaling`` is
-``ops/rotary.py yarn_scaling``'s tuple.
+``ops/rotary.py yarn_scaling``'s tuple.  ``block_diffusion=K`` (not causal) is
+block diffusion's training pass: the sequence is a clean copy of ``L`` tokens
+and then their noised copy, token ``i`` of each rotates at position ``i``, and
+a position sees what ``ops/attention.py block_diffusion_mask`` shows.
 """
 
 from __future__ import annotations
@@ -148,11 +151,15 @@ class MultiHeadAttention(BaseLayer):
                  qk_norm_eps=1e-5, head_dim=None, rotary_dim=None,
                  output_gate=False, qk_norm_zero_centered=False, scale=None,
                  rope_tables=None, window=None, rope_scaling=None,
-                 name=None):
+                 block_diffusion=None, name=None):
         assert head_dim is not None or hidden_size % num_heads == 0
         assert output_gate in (False, True, "head"), output_gate
         assert window is None or (causal_mask and not dropout_rate), (
             "a window is causal and has no dropout on the probabilities")
+        assert block_diffusion is None or not (
+            causal_mask or dropout_rate or window or alibi), (
+            "the block-diffusion mask stands alone")
+        self.block_diffusion = block_diffusion
         self.fused_head_projection = fused_head_projection
         name = fresh_name(name or "attn")
         self.hidden_size = hidden_size
@@ -274,7 +281,8 @@ class MultiHeadAttention(BaseLayer):
             q, k = rotary_pair_op(q, k, self.rope_tables(
                 seq_len, self.head_dim, self.rope_theta, self.rope_scaling,
                 **({} if self.rotary_dim is None
-                   else {"rotary_dim": self.rotary_dim})))
+                   else {"rotary_dim": self.rotary_dim}),
+                **self._copies()))
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
         kv_dim = self.num_kv_heads * self.head_dim
         q, k, v = (array_reshape_op(x, output_shape=(-1, n, width))
@@ -284,10 +292,16 @@ class MultiHeadAttention(BaseLayer):
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
             scale=self.scale, dropout_keep=self.dropout_keep,
-            num_heads=self.num_heads, window=self.window)
+            num_heads=self.num_heads, window=self.window,
+            block_diffusion=self.block_diffusion)
         if self.gate_proj is not None:
             ctx_ = gate_heads_in_place_op(ctx_, self.gate_proj(query))
         return self.out_proj(ctx_)
+
+    def _copies(self):
+        """The rotary tables' ``copies``: two under the block-diffusion mask
+        (a clean and a noised copy of the same positions)."""
+        return {} if self.block_diffusion is None else {"copies": 2}
 
     def layout(self):
         """``(layout, reason)``: ``("bshd", "in_place")`` where the layer
@@ -335,6 +349,7 @@ class MultiHeadAttention(BaseLayer):
                   else {"rotary_dim": self.rotary_dim})
             if self.rope_scaling is not None:
                 kw["scaling"] = self.rope_scaling
+            kw.update(self._copies())
             q = rotary_embedding_op(q, theta=self.rope_theta, **kw)
             k = rotary_embedding_op(k, theta=self.rope_theta, **kw)
         if self.num_kv_heads != self.num_heads:
@@ -348,7 +363,7 @@ class MultiHeadAttention(BaseLayer):
         ctx_ = scaled_dot_product_attention_op(
             q, k, v, mask=attention_mask, causal=self.causal,
             scale=self.scale, dropout_keep=self.dropout_keep,
-            window=self.window)
+            window=self.window, block_diffusion=self.block_diffusion)
         if self.gate_proj is not None:
             return self.out_proj(gate_heads_op(ctx_, self.gate_proj(query)))
         ctx_ = transpose_op(ctx_, perm=(0, 2, 1, 3))

@@ -40,7 +40,24 @@ def _split_heads(x, num_heads, rep=1):
     return _head_view(x, num_heads, rep).transpose(0, 2, 1, 3)
 
 
-def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
+def block_diffusion_mask(positions, block):
+    """``[2L, 2L]`` bool, query on key, over a clean copy of ``L = positions
+    / 2`` tokens and then their noised copy, blocks of ``block`` tokens (``b(i)
+    = i // block`` on a token's index in its half): clean on clean ``b(j) <=
+    b(i)``, noised on clean ``b(j) < b(i)``, noised on noised ``b(j) ==
+    b(i)``, clean on noised never.  The noised block ``b`` sees the clean
+    blocks before it and itself, which is what generation sees when it
+    denoises block ``b``."""
+    half = positions // 2
+    at = jnp.arange(positions)
+    noised, b = at >= half, (at % half) // block
+    qn, kn, qb, kb = noised[:, None], noised[None, :], b[:, None], b[None, :]
+    return jnp.where(kn, qn & (kb == qb),
+                     jnp.where(qn, kb < qb, kb <= qb))
+
+
+def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None,
+                block_diffusion=None):
     """Decide how attention lowers for these operands: ``[B, H, S, D]``,
     or ``[B, S, H*D]`` with ``num_heads``, which is planned as the 4-D
     array it is a view of; k and v may then be ``[B, S, KV*D]`` of fewer
@@ -61,7 +78,7 @@ def _flash_plan(q, k, v, mask, keep, mesh, num_heads=None, window=None):
         q, k, v = heads_views(q, k, v, num_heads)
     elif k.ndim == 4 and k.shape[1] != q.shape[1]:
         return "kv_heads_differ_4d", (), ()
-    why = unsupported(q, k, v, mask, keep, window)
+    why = unsupported(q, k, v, mask, keep, window, block_diffusion)
     if why is not None:
         return why, (), ()
     if (num_heads is not None and v.shape[-1] != q.shape[-1]
@@ -96,20 +113,47 @@ class ScaledDotProductAttentionOp(Op):
     ``j`` with ``0 <= i - j < window``, its own among them (512 keys at 512,
     not 513); the kernels then go by ``hetu_swa_fwd`` / ``hetu_swa_bwd`` and
     skip the blocks outside the band.  With a window there is no dropout and
-    no ring: refused where the node is built or evaluated."""
+    no ring: refused where the node is built or evaluated.
+
+    ``block_diffusion=K`` (not causal, no mask, dropout or ring): the sequence
+    is a clean copy of ``L`` tokens and then their noised copy, and a position
+    sees what ``block_diffusion_mask`` shows; the kernels then go by
+    ``hetu_flash_fwd_bd`` / ``hetu_flash_bwd_bd`` and walk the tiles that hold
+    a visible pair.  The node's type is this one's (the flash passes' events
+    are counted on the nodes of this type: ``chipbench/tests/
+    test_attention_yardstick.py``); its ``kind`` says which mask it has."""
 
     #: the keys a position sees; None: all (that ``causal`` leaves)
     window = None
+    #: the block length of the block-diffusion mask; None: no such mask
+    block_diffusion = None
+
+    @property
+    def kind(self):
+        """``full``, ``window`` or ``block_diffusion``: the label of
+        ``hetu_attn_layers_total``."""
+        if self.block_diffusion is not None:
+            return "block_diffusion"
+        return "full" if self.window is None else "window"
 
     def __init__(self, q, k, v, mask=None, causal=False, scale=None,
-                 dropout_keep=1.0, num_heads=None, name=None):
+                 dropout_keep=1.0, num_heads=None, block_diffusion=None,
+                 name=None):
         inputs = [q, k, v] + ([mask] if mask is not None else [])
         super().__init__(*inputs, name=name)
+        if block_diffusion is not None:
+            assert (not causal and mask is None and dropout_keep >= 1.0
+                    and self.window is None and block_diffusion >= 1), (
+                "the block-diffusion mask stands alone: no causal flag, key "
+                "mask, dropout on the probabilities or window beside it")
+            self.block_diffusion = int(block_diffusion)
         telemetry.get_registry().counter(
             "hetu_attn_layers_total",
             "Attention nodes built, by kind (full: every key the mask "
-            "leaves; window: the last `window` keys)", labels=("kind",),
-        ).labels(kind="full" if self.window is None else "window").inc()
+            "leaves; window: the last `window` keys; block_diffusion: a clean "
+            "and a noised copy under the block-diffusion mask)",
+            labels=("kind",),
+        ).labels(kind=self.kind).inc()
         self.has_mask = mask is not None
         self.num_heads = num_heads
         self.causal = causal
@@ -146,7 +190,8 @@ class ScaledDotProductAttentionOp(Op):
         if ctx.mesh is not None and ctx.mesh.shape.get("cp", 1) > 1:
             return False
         why, _, head_axes = _flash_plan(q, k, v, mask, self._keep(ctx),
-                                        ctx.mesh, self.num_heads, self.window)
+                                        ctx.mesh, self.num_heads, self.window,
+                                        self.block_diffusion)
         if why is not None:
             return True
         shards = 1
@@ -164,11 +209,13 @@ class ScaledDotProductAttentionOp(Op):
         # sequence dim is context-sharded — lower to flash ring attention
         # (K/V blocks rotate the ICI ring; parallel/context_parallel.py).
         # Dropout/masks stay on the single-device paths.
-        if (self.window is not None and ctx.mesh is not None
+        if ((self.window is not None or self.block_diffusion is not None)
+                and ctx.mesh is not None
                 and ctx.mesh.shape.get("cp", 1) > 1):
             raise NotImplementedError(
-                "attention with a window over a context-parallel mesh: the "
-                "ring's offsets are not built for it")
+                "attention with a window or the block-diffusion mask over a "
+                "context-parallel mesh: the ring's offsets are not built for "
+                "it")
         if (ctx.mesh is not None and "cp" in ctx.mesh.shape
                 and ctx.mesh.shape["cp"] > 1 and mask is None
                 and self.dropout_keep >= 1.0 and q.ndim == 4
@@ -188,8 +235,9 @@ class ScaledDotProductAttentionOp(Op):
             return ring_attention(ctx.mesh, q, k, v, causal=self.causal,
                                   scale=scale)
         keep = self._keep(ctx)
-        why, batch_axes, head_axes = _flash_plan(q, k, v, mask, keep,
-                                                 ctx.mesh, heads, self.window)
+        why, batch_axes, head_axes = _flash_plan(
+            q, k, v, mask, keep, ctx.mesh, heads, self.window,
+            self.block_diffusion)
         from .pallas import dispatch
         if dispatch.record("flash_attention", why):
             from .pallas.flash_attention import (flash_attention,
@@ -201,6 +249,8 @@ class ScaledDotProductAttentionOp(Op):
             kw = dict(mask=mask, causal=self.causal, scale=scale,
                       dropout_keep=keep, seed=seed, num_heads=heads,
                       window=self.window)
+            if self.block_diffusion is not None:
+                kw["block_diffusion"] = self.block_diffusion
             if batch_axes or head_axes:
                 return sharded_flash_attention(
                     ctx.mesh, q, k, v, batch_axes=batch_axes,
@@ -222,6 +272,10 @@ class ScaledDotProductAttentionOp(Op):
             if self.window is not None:
                 seen = seen & (iq - ik + (s_k - s_q) < self.window)
             scores = jnp.where(seen, scores, -1e9)
+        if self.block_diffusion is not None:
+            scores = jnp.where(
+                block_diffusion_mask(scores.shape[-1], self.block_diffusion),
+                scores, -1e9)
         if mask is not None:
             scores = scores + mask
         probs = jax.nn.softmax(scores, axis=-1)
@@ -250,9 +304,13 @@ class WindowAttentionOp(ScaledDotProductAttentionOp):
 
 def scaled_dot_product_attention_op(q, k, v, mask=None, causal=False,
                                     scale=None, dropout_keep=1.0,
-                                    num_heads=None, window=None, name=None):
+                                    num_heads=None, window=None,
+                                    block_diffusion=None, name=None):
     kw = dict(mask=mask, causal=causal, scale=scale,
               dropout_keep=dropout_keep, num_heads=num_heads, name=name)
     if window is not None:
+        assert block_diffusion is None, "a window or the block mask, not both"
         return WindowAttentionOp(q, k, v, window, **kw)
+    if block_diffusion is not None:
+        kw["block_diffusion"] = block_diffusion
     return ScaledDotProductAttentionOp(q, k, v, **kw)

@@ -90,14 +90,29 @@ _NEG_INF = -1e30
 _LANES = 128
 
 
-def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None):
+def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None,
+                block_diffusion=None):
     """Why the kernel cannot take these ``[B, H, S, D]`` operands, or None
     when it can.  Callers that fall back to the jnp composition record this
     string (ops/pallas/dispatch.py).  ``window``: the keys a position sees,
     its own among them (``_fwd``); with one, dropout inside the kernel is not
-    built."""
+    built.  ``block_diffusion``: the block length of the block-diffusion mask
+    (``_fwd_kernel``'s ``bd``); with it neither dropout nor a window nor a key
+    mask is built, a block is a power of two (its index is a shift) of at
+    most half the smallest tile, and a half is at least one tile of rows."""
     if window is not None and dropout_keep < 1.0:
         return "window_with_dropout"
+    if block_diffusion is not None:
+        for reason, holds in (
+                ("block_diffusion_with_dropout", dropout_keep < 1.0),
+                ("block_diffusion_with_window", window is not None),
+                ("block_diffusion_with_mask", mask is not None),
+                ("block_not_a_power_of_two",
+                 block_diffusion & (block_diffusion - 1)),
+                ("block>64", block_diffusion > 64),
+                ("half<128", q.ndim == 4 and q.shape[2] < 256)):
+            if holds:
+                return reason
     # keys and values of fewer heads than the queries (grouped queries): only
     # as the [B, S, KV*D] that ``heads_view`` is given, read in place
     if (q.ndim != 4 or k.ndim != 4 or v.ndim != 4
@@ -288,13 +303,64 @@ def _tn(a, b):
                                preferred_element_type=jnp.float32)
 
 
+# -- the block-diffusion mask ----------------------------------------------
+
+_FAR = 1 << 30
+
+
+def _bd_edges(block, rows, cols):
+    """``edge(strict, wide)`` for a tile of ``rows x cols`` that starts at a
+    multiple of the block in both directions (the mask's tiles under an edge
+    all lie on a half's diagonal): the pairs whose key's block comes before
+    the query's (``strict`` 1) or not after it (0), all of them (``wide`` 1)
+    or those of the query's own block alone (0).  ``strict`` and ``wide`` may
+    be traced scalars; the block is a power of two."""
+    shift = block.bit_length() - 1
+    rb, cb = (jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, cols), axis), shift)
+        for axis in (0, 1))
+
+    def edge(strict, wide):
+        return (cb + strict <= rb) & (cb + wide * _FAR >= rb)
+    return edge
+
+
+def _bd_tiles(nh):
+    """``{pass: (walked, visible)}`` of the block-diffusion mask over ``2 nh``
+    square tiles a side, a head: the tiles the forward kernel's key loops and
+    the backward kernel's query loops walk (the loops of ``_fwd_kernel`` and
+    ``_bwd_kernel``, counted as they run) against the tiles that hold a
+    visible pair (the four rules on whole tiles: a tile holds at least two
+    whole blocks, ``unsupported``)."""
+    def visible(qi, kj):
+        (qn, qt), (kn, kt) = divmod(qi, nh), divmod(kj, nh)
+        if kn:                  # noised keys: their own noised queries
+            return bool(qn) and qt == kt
+        return kt <= qt         # clean keys: a tile holds whole blocks
+    seen = sum(visible(qi, kj) for qi in range(2 * nh)
+               for kj in range(2 * nh))
+    forward = sum(t + 1 + noised for noised in (0, 1) for t in range(nh))
+    backward = sum(2 * (nh - t - 1) + 2 for t in range(nh)) + nh
+    return {"forward": (forward, seen), "backward": (backward, seen)}
+
+
 # -- forward ---------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 o_ref, lse_ref, *, scale, causal, block_k, q_len, k_len,
                 keep_prob, heads, group, empty_lse_neg=False, window=None,
-                back=0):
-    """``window`` (static; causal, no offsets): row ``i`` sees the keys ``j``
+                back=0, bd=None):
+    """``bd = (K, nh)`` (static; not causal, no mask, no offsets): the
+    block-diffusion mask over ``2 nh`` square tiles of rows, a clean copy of a
+    sequence in the first ``nh`` and its noised copy in the rest, blocks of
+    ``K`` tokens (``b(i) = i // K`` on a token's index in its half): clean on
+    clean ``b(j) <= b(i)``, noised on clean ``b(j) < b(i)``, noised on noised
+    ``b(j) == b(i)``, clean on noised never.  Query tile ``t`` of either half
+    walks the clean key tiles ``0 .. t - 1`` whole, the clean tile ``t`` under
+    its edge and, noised, its own noised tile under the block diagonal: no
+    tile without a visible pair (``_bd_tiles``).
+
+    ``window`` (static; causal, no offsets): row ``i`` sees the keys ``j``
     with ``0 <= i - j < window``.  The key loop then starts at the first block
     that holds such a key, only the blocks that the window's edge or the
     diagonal crosses are masked, and ``k_ref`` / ``v_ref`` hold the ``back``
@@ -333,7 +399,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         j_in = jax.lax.max(j_lo, -((window - (qi + 1) * bq) // block_k))
         held_from = jax.lax.max(0, qi * bq - back) // block_k
 
-    def make_body(q, h, masked):
+    def make_body(q, h, masked, see=None):
         bh = b * heads + hg * group + h
 
         def body(j, carry):
@@ -360,6 +426,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 if window is not None:
                     seen = seen & (row - col < window)
                 s = jnp.where(seen, s, _NEG_INF)
+            elif see is not None:
+                s = jnp.where(see, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1))
             p = jnp.exp2(s - m_new[:, None])
             alpha = jnp.exp2(m - m_new)
@@ -380,7 +448,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((bq,), jnp.float32)
         acc0 = jnp.zeros((bq, v_width), jnp.float32)
-        if window is not None:
+        if bd is not None:
+            # the clean tiles before this one whole; then the clean tile t
+            # under its edge and, for a noised query tile, its own noised
+            # tile under the block diagonal, LAST: a row of the first block
+            # sees nothing of the clean tile, and what that tile left in its
+            # sums is scaled to nothing by the first key it does see
+            nh = bd[1]
+            noised = (qi >= nh).astype(jnp.int32)
+            t = qi - noised * nh
+            edge = _bd_edges(bd[0], bq, block_k)
+
+            def under_edge(r, carry):
+                own = r * noised
+                return make_body(q, h, False, edge(noised - own, 1 - own))(
+                    t + r * nh, carry)
+            carry = jax.lax.fori_loop(0, t, make_body(q, h, False),
+                                      (m0, l0, acc0))
+            m, l, acc = jax.lax.fori_loop(0, 1 + noised, under_edge, carry)
+        elif window is not None:
             # [j_lo, inside): the window's edge crosses; [inside, diagonal):
             # every pair is seen; [diagonal, nk_causal): the diagonal crosses
             diagonal = jax.lax.clamp(j_lo, (qi * bq) // block_k, nk_causal)
@@ -471,9 +557,18 @@ def _compiler_params(resident_bytes):
         min(100 << 20, (24 << 20) + 2 * resident_bytes)))
 
 
+def _kernel_name(which, window, bd):
+    """The pass's kernel: a name of its own with a window (its events are not
+    the flash passes'), the pass's name and ``_bd`` under the block-diffusion
+    mask (its events are)."""
+    if window is not None:
+        return f"hetu_swa_{which}"
+    return f"hetu_flash_{which}" + ("" if bd is None else "_bd")
+
+
 def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
          block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-         empty_lse_neg=False, num_heads=None, window=None):
+         empty_lse_neg=False, num_heads=None, window=None, bd=None):
     """q: [b,h,sq,d]; k,v: [b,h,sk,d] (sq != sk in the blockwise/ring path,
     where ``offsets`` = int32[2] global [q_off, k_off]), or all three
     [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq]).
@@ -481,7 +576,11 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
     ``window`` (causal self-attention, no offsets, no dropout): the same body
     under the name ``hetu_swa_fwd``; a program holds of K and V the rows its
     query block can see, ``back`` rows before the block and the block's own,
-    cut out where they lie, unless that is all of them."""
+    cut out where they lie, unless that is all of them.
+
+    ``bd`` (``_fwd_kernel``; self-attention over both halves, square tiles,
+    no mask, dropout, offsets or window): the same body under the name
+    ``hetu_flash_fwd_bd``."""
     w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
@@ -493,6 +592,12 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
         back = -(-(window - 1) // block_k) * block_k
         held = min(sk, back + block_q)
         consts = dict(window=window, back=back if held < sk else sk)
+    if bd is not None:
+        assert (not causal and window is None and offsets is None
+                and mask is None and keep_prob >= 1.0 and sq == sk
+                and block_q == block_k and sq == 2 * bd[1] * block_q), (
+            bd, sq, sk, block_q, block_k)
+        consts = dict(bd=bd)
     if held < sk:
         # addressed by element, not by block (Mosaic: all dimensions or
         # none): the band starts where it starts
@@ -520,7 +625,7 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
     item = q.dtype.itemsize
     o, lse = pl.pallas_call(
         kern,
-        name="hetu_flash_fwd" if window is None else "hetu_swa_fwd",
+        name=_kernel_name("fwd", window, bd),
         interpret=interpret(),
         grid=(w.batch, groups, sq // block_q),
         in_specs=[q_spec, k_spec, v_spec] + extra_specs,
@@ -547,11 +652,13 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 seed_ref, offs_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
                 scale, causal, block_q, q_len, k_len, keep_prob, heads,
-                group, window=None):
+                group, window=None, bd=None):
     """One key block of one head group: every tile (query block i, this
     key block) is formed once and feeds dV, dK and dQ[i].  ``window``
     (``_fwd_kernel``): the query loop ends with the last block that holds a
-    row which sees one of these keys."""
+    row which sees one of these keys.  ``bd`` (``_fwd_kernel``): a clean key
+    tile ``t`` walks the query tiles ``t`` of both halves under their edges
+    and the tiles behind them whole, a noised one its own query tile."""
     b, hg, kj = (pl.program_id(a) for a in range(3))
     bk, width = k_ref.shape[1], k_ref.shape[2]
     dim = width // group
@@ -571,7 +678,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(i, carry):
+    def body(i, carry, see=None):
         dk, dv = carry
         rows = pl.ds(i * block_q, block_q)
         qb = q_ref[0, rows, :]
@@ -596,6 +703,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 if window is not None:
                     seen = seen & (rr - col < window)
                 s = jnp.where(seen, s, _NEG_INF)
+            elif see is not None:
+                s = jnp.where(see, s, _NEG_INF)
             p = jnp.exp2(s - (lse * _LOG2E)[:, None])
             dp = _nt(doh, v)
             if keep_prob < 1.0:
@@ -630,7 +739,28 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
     i_end = nq
     if window is not None:
         i_end = jax.lax.min(nq, ((kj + 1) * bk + window - 2) // block_q + 1)
-    dk, dv = jax.lax.fori_loop(i_start, i_end, body, (zeros, v_zeros))
+    if bd is not None:
+        nh = bd[1]
+        clean = (kj < nh).astype(jnp.int32)
+        t = kj - (1 - clean) * nh
+        behind = nh - t - 1
+        edge = _bd_edges(bd[0], block_q, bk)
+
+        def whole(u, carry):
+            # the query tiles behind t, of the clean half and then of the
+            # noised one
+            return body(u + t + 1 + (u >= behind) * (t + 1), carry)
+
+        def under_edge(r, carry):
+            # a clean key tile: the clean query tile t (not strict), then the
+            # noised one (strict); a noised key tile: its own query tile
+            return body(t + nh * (r + 1 - clean), carry,
+                        edge(r * clean, clean))
+        carry = jax.lax.fori_loop(0, 2 * behind * clean, whole,
+                                  (zeros, v_zeros))
+        dk, dv = jax.lax.fori_loop(0, 1 + clean, under_edge, carry)
+    else:
+        dk, dv = jax.lax.fori_loop(i_start, i_end, body, (zeros, v_zeros))
     dk_ref[0] = (dk * (scale / keep_prob)).astype(dk_ref.dtype)
     dv_ref[0] = (dv * (1.0 / keep_prob)).astype(dv_ref.dtype)
 
@@ -641,9 +771,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
 
 def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
               block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-              num_heads=None, window=None):
+              num_heads=None, window=None, bd=None):
     """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
-    it.  With ``window`` the kernel is ``hetu_swa_bwd``."""
+    it.  With ``window`` the kernel is ``hetu_swa_bwd``, with ``bd``
+    ``hetu_flash_bwd_bd``."""
     w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
@@ -662,10 +793,10 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
                       offsets is not None,
                       scale=scale, causal=causal, block_q=block_q,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
-                      heads=w.heads, group=w.group, window=window)
+                      heads=w.heads, group=w.group, window=window, bd=bd)
     item = q.dtype.itemsize
     dq, dk, dv = pl.pallas_call(
-        kern, name="hetu_flash_bwd" if window is None else "hetu_swa_bwd",
+        kern, name=_kernel_name("bwd", window, bd),
         interpret=interpret(),
         grid=(w.batch, w.heads // w.group, sk // block_k),
         in_specs=[whole_q, k_read, v_read, whole_o, whole_o, lse_spec]
@@ -704,38 +835,41 @@ def _group_sum(x, rep, dim):
 # the mask (None or [B,1,1,S]) and the dropout seed (a traced int32 tensor)
 # are operands with zero cotangent.
 
-def _blocks(block, window):
+def _blocks(block, window, bd=None):
     """The keywords of ``_fwd`` / ``_bwd_impl`` that a call's static plan
     sets: one block size for both sides, or with a window ``(block_q,
-    block_k)``."""
+    block_k)``; under the block-diffusion mask ``bd`` too."""
+    if bd is not None:
+        return dict(block_q=block, block_k=block, bd=bd)
     if window is None:
         return dict(block_q=block, block_k=block)
     return dict(block_q=block[0], block_k=block[1], window=window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, mask, seed, causal, scale, keep_prob, block, num_heads,
-           window=None):
+           window=None, bd=None):
     return _fwd(q, k, v, mask, causal, scale, keep_prob, seed,
-                num_heads=num_heads, **_blocks(block, window))[0]
+                num_heads=num_heads, **_blocks(block, window, bd))[0]
 
 
 def _flash_fwd(q, k, v, mask, seed, causal, scale, keep_prob, block,
-               num_heads, window=None):
+               num_heads, window=None, bd=None):
     # named HERE, on the values the backward rule reads: a recomputed group
     # keeps them (``dispatch.KEPT``) and its backward pass runs no second
     # forward kernel
     o, lse = named("flash", *_fwd(q, k, v, mask, causal, scale, keep_prob,
                                   seed, num_heads=num_heads,
-                                  **_blocks(block, window)))
+                                  **_blocks(block, window, bd)))
     return o, (q, k, v, mask, seed, o, lse)
 
 
-def _flash_bwd(causal, scale, keep_prob, block, num_heads, window, res, g):
+def _flash_bwd(causal, scale, keep_prob, block, num_heads, window, bd, res,
+               g):
     q, k, v, mask, seed, o, lse = res
     dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, g, causal, scale,
                            keep_prob, seed, num_heads=num_heads,
-                           **_blocks(block, window))
+                           **_blocks(block, window, bd))
     # The additive mask is treated as NON-differentiable data (our graphs
     # build it from placeholder attention masks).  A learned attention bias
     # must use the jnp fallback path, which differentiates the bias.
@@ -748,7 +882,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # jitted, so that the layers of a model with one attention shape share one
 # trace of the two kernel bodies (pallas_call itself traces its kernel anew
 # at every call)
-_flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9, 10))
+_flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9, 10, 11))
 
 
 # -- blockwise API (ring / context parallelism) ----------------------------
@@ -827,12 +961,50 @@ def _window_plan(s_pad, window):
     return (bq, bk), share
 
 
-def _count_entry(walk, v_dim, window=None):
+def _halves(x, axis, rows):
+    """``x`` with each half of its ``axis`` (a clean and a noised copy, one
+    behind the other) padded with zeros, or cut back, to ``rows`` rows."""
+    half = x.shape[axis] // 2
+    if half == rows:
+        return x
+    two = x.reshape(x.shape[:axis] + (2, half) + x.shape[axis + 1:])
+    if rows < half:
+        two = jax.lax.slice_in_dim(two, 0, rows, axis=axis + 1)
+    else:
+        pad = [(0, 0)] * two.ndim
+        pad[axis + 1] = (0, rows - half)
+        two = jnp.pad(two, pad)
+    return two.reshape(x.shape[:axis] + (2 * rows,) + x.shape[axis + 1:])
+
+
+def _bd_plan(half, block_diffusion):
+    """``(rows a half is padded to, tile, bd)`` of a call under the
+    block-diffusion mask, and the gauge ``hetu_flash_tiles{mask, pass,
+    tiles}``: the tiles a head's loops walk and the tiles that hold a visible
+    pair (``_bd_tiles``), at the last call planned."""
+    assert half % block_diffusion == 0, (
+        "a half is whole blocks: a padded key then lies in a block behind "
+        "every real query's", half, block_diffusion)
+    rows, tile = _pad_plan(half)
+    gauge = telemetry.get_registry().gauge(
+        "hetu_flash_tiles",
+        "Tiles a head's loops walk in a flash kernel under a mask that is "
+        "neither causal nor a window (walked) and tiles that hold a visible "
+        "pair (visible), by pass, at the last call planned",
+        labels=("mask", "pass", "tiles"))
+    for which, counts in _bd_tiles(rows // tile).items():
+        for tiles, n in zip(("walked", "visible"), counts):
+            gauge.labels(**{"mask": "block_diffusion", "pass": which,
+                            "tiles": tiles}).set(n)
+    return rows, tile, (int(block_diffusion), rows // tile)
+
+
+def _count_entry(walk, v_dim, window=None, block_diffusion=None):
     """Trace-time count of the walk taken, beside ``dispatch.record``'s
     count of the kernel-versus-jnp choice.  Values narrower (or wider) than
     the keys are the layout ``bhsd_v<head size of v>``; a call with a window
-    adds ``_w<window>``, keys of fewer heads than the queries ``_kv<key
-    heads>``."""
+    adds ``_w<window>``, the block-diffusion mask ``_bd<block>``, keys of fewer
+    heads than the queries ``_kv<key heads>``."""
     telemetry.get_registry().counter(
         "hetu_flash_attention_entry_total",
         "Trace-time flash attention calls by operand layout and the heads "
@@ -841,6 +1013,7 @@ def _count_entry(walk, v_dim, window=None):
     ).labels(layout=walk.layout + ("" if v_dim == walk.dim
                                    else f"_v{v_dim}")
              + ("" if window is None else f"_w{window}")
+             + ("" if block_diffusion is None else f"_bd{block_diffusion}")
              + ("" if walk.rep == 1 else f"_kv{walk.heads // walk.rep}"),
              heads_per_program=str(walk.group)).inc()
 
@@ -853,7 +1026,8 @@ def entries():
 
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
-                    dropout_keep=1.0, seed=None, num_heads=None, window=None):
+                    dropout_keep=1.0, seed=None, num_heads=None, window=None,
+                    block_diffusion=None):
     """Fused attention; returns None when shapes are unsupported so the
     caller falls back to the jnp composition (ops/attention.py).
 
@@ -865,7 +1039,11 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     (with ``causal``): position ``i`` sees the ``window`` keys ``i - window +
     1 .. i``; the kernels then run as ``hetu_swa_fwd`` / ``hetu_swa_bwd`` and
     skip the blocks outside the band; a window that holds every key is no
-    window.
+    window.  ``block_diffusion`` (not ``causal``): the sequence is a clean copy
+    of ``L`` tokens and then their noised copy, blocks of ``block_diffusion``
+    tokens, under ``_fwd_kernel``'s ``bd`` mask; the kernels then run as
+    ``hetu_flash_fwd_bd`` / ``hetu_flash_bwd_bd`` and walk the tiles that hold
+    a visible pair alone.
     """
     if window is not None:
         assert causal and window >= 1, (causal, window)
@@ -879,7 +1057,8 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         return None         # grouped queries come as [B, S, H*D] alone
     else:
         views = (q, k, v)
-    if unsupported(*views, mask, dropout_keep, window) is not None:
+    if unsupported(*views, mask, dropout_keep, window,
+                   block_diffusion) is not None:
         return None
     if dropout_keep < 1.0 and seed is None:
         raise ValueError(
@@ -891,7 +1070,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     # [B, H, S, D], or in place as whole lane tiles each, one head a program
     assert dv == w.dim or q.ndim == 4 or w.group == wv.group == 1, (
         q.shape, v.shape)
-    _count_entry(w, dv, window)
+    _count_entry(w, dv, window, block_diffusion)
     s, d = w.seq(q), w.dim
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -904,6 +1083,16 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     # 32, 64 or a multiple of 128 wide: never padded
     d_pad, dv_pad = (max(32, -(-x // 8) * 8) for x in (d, dv))
     s_pad, block = _pad_plan(s)
+    bd = tokens = None
+    if block_diffusion is not None:
+        # each half padded by itself, so a padded key's block lies behind
+        # every real query's and needs no mask; from here on ``s`` is the
+        # padded length and the generic padding below sees nothing to do
+        assert not causal and s % 2 == 0, (causal, s)
+        tokens, axis = s // 2, 1 if q.ndim == 3 else 2
+        rows, block, bd = _bd_plan(tokens, block_diffusion)
+        q, k, v = (_halves(t, axis, rows) for t in (q, k, v))
+        s = s_pad = 2 * rows
     if d_pad != d or dv_pad != dv or s_pad != s:
         pad = [(0, 0)] * q.ndim
         pad[1 if q.ndim == 3 else 2] = (0, s_pad - s)
@@ -922,12 +1111,14 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     if window is not None:
         block = _window_plan(s_pad, window)[0]
     out = _flash_call(q, k, v, mask, seed, causal, float(scale),
-                      float(dropout_keep), block, num_heads, window)
+                      float(dropout_keep), block, num_heads, window, bd)
     # the context and the f32 log-sum-exp, one value a row and head
-    kept("flash" if window is None else "swa",
+    kept("bd" if bd else "flash" if window is None else "swa",
          out.size * out.dtype.itemsize + out.size // dv_pad * 4)
     if d_pad != d or dv_pad != dv or s_pad != s:
         out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :dv]
+    if bd:
+        out = _halves(out, 1 if q.ndim == 3 else 2, tokens)
     return out
 
 

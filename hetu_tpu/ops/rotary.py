@@ -83,10 +83,16 @@ def _inverse_frequencies(dim, theta, scaling=None):
     return (1.0 - ramp) * inv + ramp * inv / factor, attention_factor
 
 
-def _rope_tables(seq_len, dim, theta, pos_offset=0, scaling=None):
+def _rope_tables(seq_len, dim, theta, pos_offset=0, scaling=None, copies=1):
     # always f32 tables: bf16 positions past ~256 lose the low rotation
-    # frequencies entirely
-    pos = jnp.arange(pos_offset, pos_offset + seq_len, dtype=jnp.float32)
+    # frequencies entirely.  ``copies``: the sequence is that many copies of
+    # ``seq_len / copies`` tokens one behind the other (block diffusion's
+    # clean and noised copy), and token i of each turns at position i
+    assert seq_len % copies == 0, (seq_len, copies)
+    pos = jnp.arange(pos_offset, pos_offset + seq_len // copies,
+                     dtype=jnp.float32)
+    if copies > 1:
+        pos = jnp.tile(pos, copies)
     inv, factor = _inverse_frequencies(dim, theta, scaling)
     freqs = jnp.outer(pos, inv)                       # [S, D/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)    # [S, D]
@@ -96,16 +102,18 @@ def _rope_tables(seq_len, dim, theta, pos_offset=0, scaling=None):
 
 
 def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
-            rotary_dim=None, scaling=None):
+            rotary_dim=None, scaling=None, copies=1):
     """Apply RoPE to [B, H, S, D] (HF rotate_half convention), or, with
     ``seq_axis=1``, to the [B, S, H, D] view of a projection's output.
     ``rotary_dim`` rotates the first ``rotary_dim`` of the ``D`` dimensions
     (frequencies ``theta^(-2i / rotary_dim)``) and passes the rest through
     (partial rotary: GPT-NeoX, Qwen3-Next).  ``scaling``: ``yarn_scaling``'s
-    tuple, over the dimensions that turn."""
+    tuple, over the dimensions that turn.  ``copies``: ``_rope_tables``'s."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         assert 0 < rotary_dim < x.shape[-1] and rotary_dim % 2 == 0
         more = {} if scaling is None else {"scaling": scaling}
+        if copies != 1:
+            more["copies"] = copies
         turned = _rotary(x[..., :rotary_dim], theta=theta,
                          pos_offset=pos_offset, seq_axis=seq_axis, **more)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
@@ -113,7 +121,8 @@ def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
     along = [1] * x.ndim
     along[seq_axis], along[-1] = s, d
     cos, sin = (t.reshape(along)
-                for t in _rope_tables(s, d, theta, pos_offset, scaling))
+                for t in _rope_tables(s, d, theta, pos_offset, scaling,
+                                      copies))
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
@@ -123,7 +132,8 @@ def _rotary(x, *, theta=10000.0, pos_offset=0, seq_axis=-2,
 rotary_embedding_op = simple_op(_rotary, "rotary_embedding")
 
 
-def _pair_tables(*, seq_len, dim, theta, scaling=None, rotary_dim=None):
+def _pair_tables(*, seq_len, dim, theta, scaling=None, rotary_dim=None,
+                 copies=1):
     """``[2, S, D]`` f32: ``cos`` and ``sin±``, the sine with
     ``rotate_half``'s sign on it (``-sin`` on the first ``D / 2`` lanes), so
     that ``rotate_half(x) sin = roll(x, D / 2) sin±``.  Where only the first
@@ -132,7 +142,8 @@ def _pair_tables(*, seq_len, dim, theta, scaling=None, rotary_dim=None):
     (``-sin`` on ``[0, r / 2)``), zero elsewhere: ``roll(x, r / 2) sA +
     roll(x, D - r / 2) sB`` (``ops/pallas/rotary.py``)."""
     r = rotary_dim or dim
-    cos, sin = _rope_tables(seq_len, r, theta, scaling=scaling)
+    cos, sin = _rope_tables(seq_len, r, theta, scaling=scaling,
+                            copies=copies)
     if r == dim:
         return jnp.stack(
             [cos, jnp.where(jnp.arange(dim) < dim // 2, -sin, sin)])
@@ -151,7 +162,8 @@ _pair_tables_op = simple_op(_pair_tables, "rope_tables")
 class RopeTables:
     """The ``_pair_tables`` nodes of one model: ONE a sequence length, head
     size, base, scaling (``yarn_scaling``; None: plain), count of lanes that
-    turn (``rotary_dim``; None: all) and pipeline stage,
+    turn (``rotary_dim``; None: all), count of copies of the tokens the
+    sequence holds (``copies``; 1: positions ``0 .. S - 1``) and pipeline stage,
     made for the layer that asks first and read by every layer (and every application of a layer) after it, outside any
     ``ht.remat()`` group, which then reads it as an input.  An attention layer
     has its own unless its model hands all its layers one
@@ -160,12 +172,14 @@ class RopeTables:
     def __init__(self):
         self.nodes = {}
 
-    def __call__(self, seq_len, dim, theta, scaling=None, rotary_dim=None):
+    def __call__(self, seq_len, dim, theta, scaling=None, rotary_dim=None,
+                 copies=1):
         # what a plain, whole rotation does not have is not among its
         # node's attributes
         more = {name: value for name, value in (
             ("scaling", scaling),
-            ("rotary_dim", None if rotary_dim == dim else rotary_dim))
+            ("rotary_dim", None if rotary_dim == dim else rotary_dim),
+            ("copies", None if copies == 1 else copies))
             if value is not None}
         key = (seq_len, dim, float(theta), current_stage()) + tuple(
             more.items())
@@ -202,7 +216,8 @@ def rotary_pair_op(q, k, tables):
     from one node; ``tables``: a ``RopeTables`` node of their sequence length,
     head size, base, scaling and lanes that turn."""
     pair = _rotary_pair_op(q, k, tables, **{
-        key: tables.attrs[key] for key in ("theta", "scaling", "rotary_dim")
+        key: tables.attrs[key]
+        for key in ("theta", "scaling", "rotary_dim", "copies")
         if key in tables.attrs})
     return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
 

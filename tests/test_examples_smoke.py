@@ -68,6 +68,7 @@ def test_ps_scale_bench_smoke():
 @pytest.mark.parametrize("model, layers", [
     ("olmoe-1b-7b", "1"),               # softmax router: loads, no bias
     ("nemotron-3-nano-30b-a3b", "2"),   # sigmoid router: loads and biases
+    ("sdar-30b-a3b-chat", "2"),         # block diffusion: the noised batch
 ])
 def test_train_llama_example_fetches_an_moe_models_loads(model, layers):
     """The example's fetch list (loss, update, a load a layer and, for the

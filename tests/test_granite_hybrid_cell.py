@@ -13,6 +13,16 @@ import numpy as np
 from chipbench import flops_granitehybrid as fg, flops_nemotronh as fn, run
 
 CELL = "granite-4.0-h-micro.b1-s8192"
+#: the quantities the cell reports
+QUANTITIES = (
+    "attn_block_device_ms_per_step", "attn_layout_copy_ms_per_step",
+    "device_idle_share", "flash_roofline", "head_loss_device_ms_per_step",
+    "idle_dispatch_ms_per_step", "idle_fetch_ms_per_step",
+    "idle_h2d_ms_per_step", "idle_outside_run_ms_per_step",
+    "idle_run_self_ms_per_step", "mfu", "mlp_block_device_ms_per_step",
+    "optim_device_ms_per_step", "peak_hbm_share", "softmax_ce_roofline",
+    "ssd_scan_roofline", "ssm_block_device_ms_per_step",
+    "step_unscoped_device_share")
 KINDS = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
 
 #: the catalog row's ``config`` (model-configs guide, architectures.jsonl,
@@ -64,10 +74,12 @@ def test_configuration_file_holds_the_published_keys():
     assert entry["source"] == config["source"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "b1-s8192-granite"
+    # by QUANTITY: some entry of each lists this cell (its own entry today,
+    # a folded one's list tomorrow), each moving the training throughput
     per_layer = [m for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert len(per_layer) == 18
-    assert all(m["name"].endswith(".granite") and m["workloads"] == [CELL]
-               and m["moves"] == "train_tokens_per_s" for m in per_layer)
+    assert sorted(m["name"].split(".")[0] for m in per_layer) == sorted(
+        QUANTITIES)
+    assert all(m["moves"] == "train_tokens_per_s" for m in per_layer)
 
 
 def test_parameter_count_at_the_published_widths():

@@ -63,13 +63,14 @@ def test_benchmark_entries():
                  if c["name"] == "laguna-xs.2-pretrain")
     assert sorted(entry["reduced"]) == sorted(REDUCED)
     assert len(bench["per_layer"]) <= 128
-    mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
+    # by QUANTITY: some entry of each lists this cell
+    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
+            if CELL in m["workloads"]]
     assert len(mine) == 23
     for name in ("flash_roofline", "mfu", "moe_experts_roofline",
-                 "attn_block_device_ms_per_step.laguna",
-                 "window_attn_roofline",
+                 "attn_block_device_ms_per_step", "window_attn_roofline",
                  "window_attn_block_device_ms_per_step"):
-        assert name in mine
+        assert mine.count(name) == 1, name
     for key in ("window", "no_qk_norm", "gate_a_head", "rotary", "router",
                 "shared_expert", "loss", "job", "remat"):
         assert key in config["assumed"], key

@@ -48,11 +48,13 @@ def test_benchmark_entries():
                  if c["name"] == "ling-3.0-flash-vl-pretrain")
     assert sorted(entry["reduced"]) == sorted(REDUCED)
     assert len(bench["per_layer"]) <= 128
-    mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
+    # by QUANTITY: some entry of each lists this cell
+    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
+            if CELL in m["workloads"]]
     for name in ("kda_scan_roofline", "kda_block_device_ms_per_step",
-                 "flash_roofline.ling", "mfu.ling", "peak_hbm_share.ling",
-                 "moe_experts_roofline.ling", "softmax_ce_roofline.ling"):
-        assert name in mine
+                 "flash_roofline", "mfu", "peak_hbm_share",
+                 "moe_experts_roofline", "softmax_ce_roofline"):
+        assert mine.count(name) == 1, name
     for key in ("kda_gate", "kda_projections", "attention_gate", "qk_norm",
                 "kda_heads", "head", "router", "rotary", "remat"):
         assert key in config["assumed"], key

@@ -22,11 +22,11 @@ JOINED = ("flash_roofline", "softmax_ce_roofline", "mfu", "peak_hbm_share",
           "idle_dispatch_ms_per_step", "idle_fetch_ms_per_step",
           "idle_run_self_ms_per_step", "idle_outside_run_ms_per_step",
           "attn_layout_copy_ms_per_step")
-DECLARED = ("attn_block_device_ms_per_step.ouro",
-            "mlp_block_device_ms_per_step.ouro",
-            "head_loss_device_ms_per_step.ouro",
-            "optim_device_ms_per_step.ouro",
-            "step_unscoped_device_share.ouro",
+#: quantities the cell declared for itself (an entry of its own today; a
+#: folded file lists the cell in the quantity's one entry)
+DECLARED = ("attn_block_device_ms_per_step", "mlp_block_device_ms_per_step",
+            "head_loss_device_ms_per_step", "optim_device_ms_per_step",
+            "step_unscoped_device_share",
             "exit_block_device_ms_per_step", "loop_recompute_device_share")
 
 
@@ -88,17 +88,18 @@ def test_benchmark_entries():
     felt, = (m for m in bench["end_to_end"]
              if m["name"] == "train_tokens_per_s")
     assert CELL in felt["workloads"]
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in JOINED:
-        assert CELL in by_name[name]["workloads"], name
+    # by QUANTITY: ONE entry of each lists this cell, whatever its name
+    by_quantity = {}
+    for m in bench["per_layer"]:
+        if CELL in m.get("workloads", ()):
+            by_quantity.setdefault(m["name"].split(".")[0], []).append(m)
+    assert sorted(by_quantity) == sorted(JOINED + DECLARED)
+    assert all(len(entries) == 1 for entries in by_quantity.values())
     for name in DECLARED:
-        assert by_name[name]["workloads"] == [CELL], name
-        assert by_name[name]["moves"] == "train_tokens_per_s"
-    assert (by_name["loop_recompute_device_share"]["unit"],
-            by_name["exit_block_device_ms_per_step"]["unit"]) == ("%", "ms")
-    mine = [m["name"] for m in bench["per_layer"]
-            if CELL in m.get("workloads", ())]
-    assert sorted(mine) == sorted(JOINED + DECLARED)
+        assert by_quantity[name][0]["moves"] == "train_tokens_per_s"
+    assert (by_quantity["loop_recompute_device_share"][0]["unit"],
+            by_quantity["exit_block_device_ms_per_step"][0]["unit"]) == (
+        "%", "ms")
     assert len(bench["per_layer"]) <= 128
 
 

@@ -62,13 +62,15 @@ def test_benchmark_entries():
                  if c["name"] == "xing4.0-29b-a4b-pretrain")
     assert sorted(entry["reduced"]) == sorted(REDUCED)
     assert len(bench["per_layer"]) <= 128
-    mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
+    # by QUANTITY: some entry of each lists this cell
+    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
+            if CELL in m["workloads"]]
     assert len(mine) == 24
     for name in ("flash_roofline", "mfu", "moe_experts_roofline",
-                 "softmax_ce_roofline", "attn_block_device_ms_per_step.xing4",
+                 "softmax_ce_roofline", "attn_block_device_ms_per_step",
                  "hc_block_device_ms_per_step", "hc_mix_roofline",
                  "mtp_block_device_ms_per_step"):
-        assert name in mine
+        assert mine.count(name) == 1, name
     for key in ("streams", "hc_norm", "hc_maps", "sinkhorn_order",
                 "hc_initial_values", "mla", "yarn", "router",
                 "shared_expert", "mtp", "dense_layers"):
