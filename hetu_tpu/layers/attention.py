@@ -10,10 +10,15 @@ moves no heads.  ONE rule, on the layer's own arguments, says which graph a
 layer builds (``MultiHeadAttention.layout``, counted in
 ``hetu_attn_layout_total{layout, reason}``): grouped queries (K and V stay
 ``[B, S, kv_heads*d]``: the kernels read a query head's key head where it
-lies), a gate a head and a partial rotation go in place where a head is whole
-lane tiles (``head_dim % 128 == 0``); ALiBi, a norm a head, the elementwise
-gate and the inference graphs' fused head projection keep the [B, heads, S, d]
-graph.
+lies), a gate a head, a partial rotation and a norm a head go in place where a
+head is whole lane tiles (``head_dim % 128 == 0``); the norm a head is part of
+the pass that rotates (``ops/rotary.py qk_norm_rotary_pair_op``: SDAR, Qwen3).
+What keeps the [B, heads, S, d] graph: ALiBi (its bias is built by heads), the
+elementwise gate (a head's query and its gate lie side by side in ONE
+projection, which the flash kernels would have to read at a stride of ``2 d``:
+Qwen3-Next), the inference graphs' fused head projection, heads that are not
+whole lane tiles under any of the above (Granite's 64), and a norm a head that
+no rotation follows (no cell's layer).
 
 Position-encoding variants for the Llama/Baichuan model tier (reference
 tools/Hetu-Galvatron/galvatron/models/llama, models/baichuan): ``rope_theta``
@@ -56,7 +61,7 @@ from ..ops.base import simple_op
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.pallas.common import PARTS, parts
 from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
-                          repeat_kv_op, alibi_bias_op)
+                          qk_norm_rotary_pair_op, repeat_kv_op, alibi_bias_op)
 
 
 def _gate_heads(ctx_, gate):
@@ -273,16 +278,25 @@ class MultiHeadAttention(BaseLayer):
             return self._attend_bhsd(query, key, value, attention_mask,
                                      seq_len, kv_seq_len)
         q, k, v = self.q_proj(query), self.k_proj(key), self.v_proj(value)
-        if self.q_norm is not None:
+        if self.q_norm is not None and not self.qk_norm_per_head:
             q, k = self.q_norm(q), self.k_norm(k)
         if self.rope_theta is not None:
             # q and k together, on the projections' [B, S, heads * d] and
             # [B, S, kv_heads * d]
-            q, k = rotary_pair_op(q, k, self.rope_tables(
+            tables = self.rope_tables(
                 seq_len, self.head_dim, self.rope_theta, self.rope_scaling,
                 **({} if self.rotary_dim is None
                    else {"rotary_dim": self.rotary_dim}),
-                **self._copies()))
+                **self._copies())
+            if self.qk_norm_per_head:
+                # the norm a head in the same pass (layout(): never without
+                # a rotation here)
+                q, k = qk_norm_rotary_pair_op(
+                    q, k, self.q_norm.scale, self.k_norm.scale, tables,
+                    eps=self.q_norm.eps,
+                    zero_centered=self.q_norm.zero_centered)
+            else:
+                q, k = rotary_pair_op(q, k, tables)
         # [B, S, H] as it comes (a no-op), or a caller's [B*S, H]
         kv_dim = self.num_kv_heads * self.head_dim
         q, k, v = (array_reshape_op(x, output_shape=(-1, n, width))
@@ -307,13 +321,18 @@ class MultiHeadAttention(BaseLayer):
         """``(layout, reason)``: ``("bshd", "in_place")`` where the layer
         attends on the projections' ``[B, S, heads*d]``, else ``"bhsd"`` and
         the first thing about the layer that the in-place kernels do not take.
-        Grouped queries, a gate a head and a partial rotation need heads of
-        whole lane tiles; without them any head size goes."""
+        Grouped queries, a gate a head, a partial rotation and a norm a head
+        need heads of whole lane tiles; without them any head size goes.  A
+        norm a head goes in place in the pass that rotates (``ops/rotary.py
+        qk_norm_rotary_pair_op``); one that NO rotation follows is no cell's
+        layer and keeps ``[B, heads, S, d]`` under ``qk_norm_per_head``."""
         tiles = (self.num_kv_heads != self.num_heads
-                 or self.output_gate == "head" or self.rotary_dim is not None)
+                 or self.output_gate == "head" or self.rotary_dim is not None
+                 or self.qk_norm_per_head)
         for reason, holds in (
                 ("head_dim_not_128_aligned", tiles and self.head_dim % 128),
-                ("qk_norm_per_head", self.qk_norm_per_head),
+                ("qk_norm_per_head",
+                 self.qk_norm_per_head and self.rope_theta is None),
                 ("gate_elementwise", self.output_gate is True),
                 ("alibi", self.alibi),
                 ("fused_head_projection", self.fused_head_projection)):

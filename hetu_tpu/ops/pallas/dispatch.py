@@ -93,7 +93,8 @@ MESH = "mesh"
 #: ``platform:<name>``, as the kernels with a plan do.
 NO_CHOICE_OFF_TPU = frozenset({"gated_delta", "ssd", "kda", "causal_conv",
                                "gated_norm", "moe_rows", "rotary",
-                               "hc_mix", "mla_pack", "moe_select"})
+                               "hc_mix", "mla_pack", "moe_select",
+                               "qk_norm_rope"})
 
 
 def take(kernel, mesh, reason=None, asked=False):

@@ -29,11 +29,17 @@ partial rotation (``rotary_dim`` < head size) keeps the kernels: its tables are
 ``jnp`` with ``head_dim_not_128_aligned``, ``dtype:<name>``, ``dtype:mixed`` or
 ``seq_not_16_aligned``; what a mesh and a platform without Mosaic mean is
 ``dispatch.take``'s rule, and ``_rotary(seq_axis=1)`` then runs on each
-tensor's view.  ``rotary_embedding_op`` (the layers that stay on ``[B, H, S,
-D]``: a norm a head, the elementwise gate, grouped queries on heads that are
-not whole lane tiles; latent attention's ``_rope_last`` wherever its own
-kernels, ``ops/pallas/mla_pack.py``, do not run) is ``_rotary``
-everywhere.
+tensor's view.  A layer that norms each head before it rotates
+(``qk_norm="head"``: SDAR, Qwen3) hands q, k and the two norms' scales to
+``qk_norm_rotary_pair_op`` instead: ONE pass of a second kernel pair
+(``hetu_qk_norm_rope_fwd`` / ``_bwd``, counted under ``qk_norm_rope``) with
+``ops/nn.py _rms_norm``'s own roundings, and where that pair refuses
+(``zero_centered``, ``partial_rotation``, and the first pair's reasons)
+``_rms_norm`` and ``_rotary`` on the same flat operands' view: such a layer
+never goes back to ``[B, H, S, D]`` for its norm.  ``rotary_embedding_op`` (the
+layers that stay on ``[B, H, S, D]``: the elementwise gate, grouped queries or
+a norm a head on heads that are not whole lane tiles; latent attention's ``_rope_last`` wherever its own kernels,
+``ops/pallas/mla_pack.py``, do not run) is ``_rotary`` everywhere.
 
 Conventions match huggingface's ``rotate_half`` (non-interleaved halves),
 so HF Llama checkpoints import bit-tight (tests/test_torch_parity.py).
@@ -46,6 +52,7 @@ import math
 import jax.numpy as jnp
 
 from .base import SimpleOp, simple_op
+from .nn import _rms_norm
 from .pallas import dispatch, rotary as kernels
 from ..graph.node import current_stage
 
@@ -211,14 +218,62 @@ _rotary_pair_op = simple_op(_rotary, "rotary_pair", node_cls=RotaryPairOp)
 pair_item_op = simple_op(lambda pair, *, index: pair[index], "pair_item")
 
 
+def _turn_attrs(tables):
+    """What a pair node's ``jax.numpy`` form needs of its tables' node."""
+    return {key: tables.attrs[key]
+            for key in ("theta", "scaling", "rotary_dim", "copies")
+            if key in tables.attrs}
+
+
 def rotary_pair_op(q, k, tables):
     """The nodes of q ``[B, S, H d]`` and k ``[B, S, KV d]`` rotated, both
     from one node; ``tables``: a ``RopeTables`` node of their sequence length,
     head size, base, scaling and lanes that turn."""
-    pair = _rotary_pair_op(q, k, tables, **{
-        key: tables.attrs[key]
-        for key in ("theta", "scaling", "rotary_dim", "copies")
-        if key in tables.attrs})
+    pair = _rotary_pair_op(q, k, tables, **_turn_attrs(tables))
+    return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
+
+
+class NormRotaryPairOp(SimpleOp):
+    """``(q, k, wq, wk, tables) -> (q', k')`` on ``[B, S, H d]``: a norm a
+    head (``ops/nn.py _rms_norm`` under ``wq``, ``wk [d]``) and then the
+    rotation, ONE pass of the kernel pair ``hetu_qk_norm_rope_fwd`` / ``_bwd``
+    where ``dispatch.take`` says so (label ``qk_norm_rope``), else ``impl``
+    (``_rms_norm``, then ``_rotary``) on each tensor's view by heads: what the
+    kernels refuse stays on the flat path too."""
+
+    def _compute(self, input_vals, ctx):
+        q, k, wq, wk, tables = input_vals
+        seq_len, d = tables.shape[1:]
+        q, k = (x.reshape(-1, seq_len, x.shape[-1]) for x in (q, k))
+        attrs = self.attrs
+        if dispatch.take("qk_norm_rope", ctx.mesh, kernels.norm_unsupported(
+                q, k, wq, wk, head_dim=d, rotary_dim=attrs.get("rotary_dim"),
+                zero_centered=attrs["zero_centered"])):
+            return kernels.norm_rope(q, k, wq, wk, tables, attrs["eps"])
+        return tuple(
+            self.impl(x.reshape(*x.shape[:2], -1, d), w, **attrs
+                      ).reshape(x.shape) for x, w in ((q, wq), (k, wk)))
+
+
+def _norm_rotary(view, scale, *, eps, zero_centered, **turn):
+    """A norm a head and the rotation on ``[B, S, H, d]``, ``jax.numpy``."""
+    return _rotary(_rms_norm(view, scale, eps, zero_centered), seq_axis=1,
+                   **turn)
+
+
+_norm_rotary_pair_op = simple_op(_norm_rotary, "qk_norm_rotary_pair",
+                                 node_cls=NormRotaryPairOp)
+
+
+def qk_norm_rotary_pair_op(q, k, q_scale, k_scale, tables, *, eps,
+                           zero_centered=False):
+    """``rotary_pair_op`` behind a norm a head: the nodes of q ``[B, S, H
+    d]`` and k ``[B, S, KV d]``, each head normed under ``q_scale`` /
+    ``k_scale [d]`` (``_rms_norm(eps, zero_centered)``) and rotated, both from
+    one node."""
+    pair = _norm_rotary_pair_op(q, k, q_scale, k_scale, tables, eps=eps,
+                                zero_centered=zero_centered,
+                                **_turn_attrs(tables))
     return pair_item_op(pair, index=0), pair_item_op(pair, index=1)
 
 

@@ -163,6 +163,40 @@ def rotary_kernels_asked(monkeypatch):
         dispatch.take(kernel, mesh, why, asked=True)))
 
 
+def ulps(got, want):
+    """The largest gap in units of ``want``'s last place (bf16: 8 bits)."""
+    import numpy as np
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    place = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    return float((np.abs(got - want) / place).max())
+
+
+def close(got, want, dtype, what):
+    """A rotary kernel's result against its ``jax.numpy`` form's: f32 to
+    rounding, bf16 within one place."""
+    import jax.numpy as jnp
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype, what
+    if dtype == jnp.float32:
+        gap = float(jnp.abs(got - want).max())
+        assert gap < 1e-6 * max(1.0, float(jnp.abs(want).max())), (what, gap)
+    else:
+        assert ulps(got, want) <= 1.0, (what, ulps(got, want))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """The sharding of one chip of a described v5e: a test compiles for it
+    (Mosaic and all) without one."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - whatever the describing raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
 def without_locations(text):
     """A program's text with nothing left that a moved source line moves.
     Of a lowered program (``as_text()``, with or without ``debug_info``): the

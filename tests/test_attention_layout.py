@@ -1,13 +1,14 @@
 """Which graph an attention layer builds (``layers/attention.py
 MultiHeadAttention.layout``, ``hetu_attn_layout_total{layout, reason}``): the
 rule a case a reason; a layer with grouped queries, a gate a head, a partial
-rotation and a window on the projections' ``[B, S, H d]`` against
-``_attend_bhsd`` on the same weights, values and every weight's gradient; the
-gate a head in place against the one on ``[B, H, S, d]``; what the toy train
+rotation and a window, or with a norm a head under the block-diffusion and
+the causal mask, on the projections' ``[B, S, H d]`` against ``_attend_bhsd``
+on the same weights, values and every weight's gradient; the gate a head in
+place against the one on ``[B, H, S, d]``; what the toy train
 steps of the cells that bypass the rule lower to (a stored hash: ``rep = 1``
 and the layers the rule leaves on ``[B, H, S, D]`` build what they built before
-PR 52); and the Laguna and Nemotron-H toys with heads of 128, which take the
-flat path, through the harness's own check of the kernels chosen; and a latent
+PR 52); and the Laguna, Nemotron-H and SDAR toys with heads of 128, which
+take the flat path, through the harness's own check of the kernels chosen; and a latent
 layer, which chooses when it is traced: in place on a TPU, by heads under a
 mesh."""
 
@@ -68,7 +69,15 @@ def since(before):
      ("bhsd", "head_dim_not_128_aligned")),
     (dict(head_dim=256, num_kv_heads=2, qk_norm="head", output_gate=True,
           rope_theta=1e4, rotary_dim=64),                     # Qwen3-Next
-     ("bhsd", "qk_norm_per_head")),
+     ("bhsd", "gate_elementwise")),
+    (dict(head_dim=128, num_kv_heads=2, qk_norm="head", rope_theta=1e6,
+          block_diffusion=4), ("bshd", "in_place")),          # SDAR
+    (dict(head_dim=128, qk_norm="head", rope_theta=1e4, causal_mask=True),
+     ("bshd", "in_place")),                                   # Qwen3's
+    (dict(head_dim=64, qk_norm="head", rope_theta=1e4),
+     ("bhsd", "head_dim_not_128_aligned")),
+    # a norm a head that no rotation follows: no cell's layer
+    (dict(head_dim=128, qk_norm="head"), ("bhsd", "qk_norm_per_head")),
     (dict(head_dim=128, output_gate=True), ("bhsd", "gate_elementwise")),
     (dict(head_dim=128, num_kv_heads=2, alibi=True), ("bhsd", "alibi")),
     (dict(head_dim=128, num_kv_heads=2, fused_head_projection=True),
@@ -99,7 +108,8 @@ def both_graphs(name, dtype=None, **kw):
     """One layer's variables under both graphs: the executor of each graph's
     loss and of every weight's gradient, and the feed."""
     S, hidden = 32, 64
-    layer = MultiHeadAttention(hidden, 4, sequence_length=S, causal_mask=True,
+    kw = {"causal_mask": True, **kw}
+    layer = MultiHeadAttention(hidden, 4, sequence_length=S,
                                head_dim=128, num_kv_heads=2, bias=False,
                                name=name, **kw)
     assert layer.layout() == ("bshd", "in_place")
@@ -110,16 +120,18 @@ def both_graphs(name, dtype=None, **kw):
     weights = [p.weight for p in (layer.q_proj, layer.k_proj, layer.v_proj,
                                   layer.out_proj, layer.gate_proj)
                if p is not None]
+    scales = [n.scale for n in (layer.q_norm, layer.k_norm) if n is not None]
     graphs = {}
     for key, y in (("flat", flat), ("bhsd", by_heads)):
         loss = ht.reduce_sum_op(ht.sin_op(y), axes=[0, 1, 2])
-        graphs[key] = [loss, y] + ht.gradients(loss, weights)
+        graphs[key] = [loss, y] + ht.gradients(loss, weights + scales)
     ex = ht.Executor(graphs, seed=3, **(
         {} if dtype is None else {"compute_dtype": dtype}))
     r = np.random.default_rng(7)
-    for var in weights:
+    for var in weights + scales:
         ex.params[var.name] = jnp.asarray(
-            r.normal(0.0, 0.2, var.shape), ex.params[var.name].dtype)
+            r.normal(float(var in scales), 0.2, var.shape),
+            ex.params[var.name].dtype)
     feed = {x: r.normal(size=(2, S, hidden)).astype(np.float32)}
     return ex, feed, flat, by_heads
 
@@ -154,6 +166,37 @@ def test_the_flat_path_is_the_graph_by_heads(monkeypatch, kw,
     got, want = (ex.run(key, feed_dict=feed, convert_to_numpy_ret_vals=True)
                  for key in ("flat", "bhsd"))
     ex.close()
+    assert abs(float(got[0] - want[0])) < 1e-5 * abs(float(want[0]))
+    for a, b in zip(got[1:], want[1:]):
+        assert a.shape == b.shape and np.abs(b).max() > 0
+        assert np.abs(a - b).max() < 2e-5 * np.abs(b).max()
+
+
+@pytest.mark.parametrize("through_the_kernels", [False, True])
+@pytest.mark.parametrize("mask", [dict(causal_mask=False, block_diffusion=4),
+                                  dict(causal_mask=True)],
+                         ids=["block_diffusion", "causal"])
+def test_a_norm_a_head_in_place_is_the_graph_by_heads(monkeypatch, mask,
+                                                      through_the_kernels):
+    """Four heads of 128 on two key heads, a norm a head and a whole rotation,
+    under the block-diffusion mask (SDAR: a clean and a noised copy, rotated
+    alike) and under the causal one: loss, output and the gradient of every
+    weight, the two norms' scales among them, f32, against ``_attend_bhsd`` on
+    the same variables; with the kernel pair too."""
+    if through_the_kernels:
+        asked(monkeypatch)
+    name = f"al_norm{int(through_the_kernels)}{len(mask)}"
+    ex, feed, flat, by_heads = both_graphs(name, rope_theta=1e6,
+                                           qk_norm="head", **mask)
+    kinds = lambda y: sorted(k for k in kinds_of(y) if k.startswith(
+        ("rotary", "repeat", "rms_norm", "qk_norm")))
+    assert kinds(by_heads) == (["repeat_kv"] * 2 + ["rms_norm"] * 2
+                               + ["rotary_embedding"] * 2)
+    assert kinds(flat) == ["qk_norm_rotary_pair"]
+    got, want = (ex.run(key, feed_dict=feed, convert_to_numpy_ret_vals=True)
+                 for key in ("flat", "bhsd"))
+    ex.close()
+    assert len(got) == 2 + 4 + 2
     assert abs(float(got[0] - want[0])) < 1e-5 * abs(float(want[0]))
     for a, b in zip(got[1:], want[1:]):
         assert a.shape == b.shape and np.abs(b).max() > 0
@@ -257,12 +300,14 @@ def test_toy_train_steps_lower_to_what_they_lowered_to(monkeypatch, cell):
 @pytest.mark.parametrize("cell,widths,layers_", [
     ("laguna-xs.2.b1-s8192", dict(head_dim=128), 5),
     ("nemotron-3-nano-30b-a3b.b1-s8192", dict(head_dim=128), 1),
+    ("sdar-30b-a3b.b1-s8192", dict(head_dim=128, num_hidden_layers=6), 6),
 ])
 def test_toys_with_heads_of_128_take_the_flat_path(live_registry, cell,
                                                    widths, layers_):
     """The Laguna toy (grouped queries, a gate a head, a window, YaRN on half
-    a head) and the Nemotron-H toy (grouped queries, no rotary) at heads of
-    128: every attention layer is built in place, the program is as near its
+    a head), the Nemotron-H toy (grouped queries, no rotary) and the SDAR toy
+    (grouped queries, a norm a head, the block-diffusion mask; six layers, as
+    its cell has) at heads of 128: every attention layer is built in place, the program is as near its
     cell's plain reference as the toy's limits ask, a train step runs, and the
     harness's own reading of the kernels chosen finds no ``jax.numpy`` form
     that the platform does not explain."""

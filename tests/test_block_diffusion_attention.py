@@ -242,27 +242,16 @@ def test_the_layer_sees_a_copy_of_a_token_where_the_token_is(head_dim,
 
 # -- Mosaic takes the kernels at the cell's shape ------------------------------
 
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:      # noqa: BLE001 - whatever the describing raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 @pytest.mark.parametrize("shape, heads", [
-    ((1, 32, 16384, 128), None),            # the SDAR cell: [B, H, 2L, d]
-    ((1, 2048, 1024), 8)])                  # in place, grouped queries 8:1
+    ((1, 32, 16384, 128), None),            # [B, H, 2L, d]: the cell to PR 62
+    ((1, 2048, 1024), 8),                   # in place, grouped queries 8:1
+    ((1, 16384, 4096), 32)])                # the SDAR cell, in place (PR 63)
 def test_mosaic_compiles_both_kernels(one_chip, monkeypatch, shape, heads):
     from hetu_tpu.ops.pallas import dispatch
     monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
     monkeypatch.setattr(fa, "interpret", lambda: False)
     jax.clear_caches()
-    k_shape = shape if heads is None else shape[:2] + (128,)
+    k_shape = shape if heads is None else shape[:2] + (shape[2] // 8,)
     q, k = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
             for s in (shape, k_shape))
 

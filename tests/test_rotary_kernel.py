@@ -24,6 +24,8 @@ from hetu_tpu.layers.attention import MultiHeadAttention
 from hetu_tpu.ops import rotary as op
 from hetu_tpu.ops.pallas import dispatch, rotary as kernels
 
+from conftest import close, ulps, rotary_kernels_asked as asked
+
 D, THETA = 128, 10000.0
 
 
@@ -43,22 +45,6 @@ def by_heads(x, d=D):
     B, S, W = x.shape
     return op._rotary(x.reshape(B, S, W // d, d), theta=THETA,
                       seq_axis=1).reshape(B, S, W)
-
-
-def ulps(got, want):
-    """The largest gap in units of ``want``'s last place (bf16: 8 bits)."""
-    got, want = (np.asarray(t, np.float32) for t in (got, want))
-    place = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
-    return float((np.abs(got - want) / place).max())
-
-
-def close(got, want, dtype, what):
-    assert got.shape == want.shape and got.dtype == want.dtype == dtype, what
-    if dtype == jnp.float32:
-        gap = float(jnp.abs(got - want).max())
-        assert gap < 1e-6 * max(1.0, float(jnp.abs(want).max())), (what, gap)
-    else:
-        assert ulps(got, want) <= 1.0, (what, ulps(got, want))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -209,9 +195,6 @@ def choices(live_registry):
                 for key, n in dispatch.choices().items()
                 if key[0] == "rotary" and n > before.get(key, 0)}
     return since
-
-
-from conftest import rotary_kernels_asked as asked  # noqa: E402
 
 
 def pair_node(q_shape, k_shape, d, name, seq_len=None):
