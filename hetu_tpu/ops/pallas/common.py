@@ -79,6 +79,22 @@ def dot32(a, b, dims):
     return out
 
 
+def dot32_stacked(a, b, dims):
+    """``dot32`` as ONE product: the parts of each operand put one behind the
+    other along the contraction, ``[a0 a0 a1 a0 a1 a2] . [b2; b1; b1; b0; b0;
+    b0]`` for two f32 operands, so the same pairs of parts are multiplied,
+    least terms first, and the matrix unit adds them up in f32 where
+    ``dot32`` pops each pair's product and adds on the vector unit.  For a
+    contraction that is whole lane tiles or runs down the rows (``TN``):
+    stacking the parts then moves no data."""
+    ap, bp = parts(a), parts(b)
+    pairs = [(i, j) for i in reversed(range(len(ap)))
+             for j in reversed(range(len(bp))) if i + j < PARTS]
+    return dot(jnp.concatenate([ap[i] for i, _ in pairs], axis=dims[0][0]),
+               jnp.concatenate([bp[j] for _, j in pairs], axis=dims[1][0]),
+               dims)
+
+
 def iotas():
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)

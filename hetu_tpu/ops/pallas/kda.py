@@ -48,6 +48,52 @@ the blocks below from one product a column sub-chunk (rows decayed from its
 end, clamped at one where they lie before it; columns decayed to it).
 ``g >= -5`` a position keeps every factor within ``exp(+-40)``.
 
+What a chunk of one head asks of the matrix unit (``chunk_products``: the
+``dot_general``s of ``_chunks`` and of ``jax.vjp`` of it; ``passes``: one a
+product and 128 of its contraction; ``tests/test_kda_passes.py`` holds both
+and the gauge ``hetu_kda_chunk_passes{kernel}`` shows the passes).  A product
+at f32 precision multiplies all three bf16 parts of an f32 operand: three
+``dot_general``s where the other operand is bf16, six where both are f32
+(``dot32``), or one over the parts stacked along the contraction
+(``dot32_stacked``).  PR 64 changed two things and no part of any operand:
+``u = T beta (v - (k e^G) S)`` is one product behind the inverse where ``T
+beta v - (T beta (k e^G)) S`` was two in a chain, and the f32 products whose
+contraction is whole lane tiles or runs down the rows are one product each:
+
+    stage                            forward          backward kernel
+                                     products passes  products  passes
+    G = triangle of ones x g          3 ->  3  3 ->  3   12 ->  5  12 ->  11
+    the pair matrices (_pair)         8     8  8     8   24    24  24     24
+    L^T (through the matrix unit)     3     3  3     3    3     3   3      3
+    the inverse's two merges         24    24 24    24   24    24  24     24
+    the inverse's cotangent           -     -  -     -   12     7  12      9
+    products with S                  12     2 12    12   36     6  36     30
+    products with T beta              9     6  9     6   30     8  30     15
+    P u                               1     1  1     1    3     3   3      3
+    the next state                    6     1  6     3   18     3  18     15
+    a chunk                          66    48 66    60  162    83 162    134
+
+The time does not follow the passes: the body of the chunk walk is bound
+by the vector unit (splitting f32 operands into parts, adding partial
+products, the masks of the inverse and of the pair matrices: the forward
+body of four heads compiled for a v5e is 18,500 vector operations, 4,600
+bundles' worth on four slots, in the 6,600 bundles the scheduler packs, a
+count and not a time), so a product's sum moved into the matrix unit is
+worth more than a pass saved.  The pair alone at the Ling-3.0
+layer's shape (``[1, 8192, 32 x 128]`` bf16, ``in_place``; my chip run, PR
+64, call 64.1, ten calls in a row by the host's clock; ms a forward and a
+backward call): as PR 41 left them 4.87 and 10.25; ``u`` from one product
+with ``T beta`` 4.87 and 9.83; with ``q e^G`` and ``k e^G`` stacked by rows
+into one product with ``S`` 4.89 and 9.37 (a pass of 128 rows costs the
+forward kernel two of 64; the backward one gains where ONE contraction down
+128 rows stands for two down 64) and with ``[k; q]`` through one ``_pair``
+4.94 and 9.26; the state products in a stage of their own before the
+inverses 5.09 and 9.92 (slower forward: they stay in ``_close``); ``u`` from
+one product and the stacked contraction, as this file has them, **4.51 and
+9.18**, and then the products of 128 rows are worth nothing more (4.65 and
+9.30 with the one with ``S``, 4.57 and 9.03 with ``_pair``'s: under 1% of
+the two kernels, so absent).  ``plain``: 4.73 and 9.50 -> 4.39 and 8.42.
+
 A chunk is one chain of dependent steps and Mosaic's scheduler stays close
 to program order, so a program's heads run their chains in step as the
 scalar rule's do (``together``: ``_open`` and ``_close`` are generators that
@@ -62,12 +108,14 @@ gates and re-tilings around them, ``in_place`` 4.75 and 10.05 with 3.7.
 
 Precision as ``ops/pallas/gated_delta.py``: the state, the decays, ``T`` and
 every operand of a product with them are f32, multiplied as bf16 passes
-over their three bf16 parts (``dot32``); the pair matrices and ``P u`` take
+over their three bf16 parts (``dot32``, ``dot32_stacked``: the same pairs of
+parts, f32 sums); the pair matrices and ``P u`` take
 their operands in the compute type.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -77,8 +125,8 @@ import jax.numpy as jnp
 from . import dispatch
 from ... import telemetry
 from .common import (C, CHUNKS, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot,
-                     dot32, head_lanes, iotas, params, pick, put, to_col,
-                     together, unit_lower_inverse, walk)
+                     dot32, dot32_stacked, head_lanes, iotas, params, pick,
+                     put, to_col, together, unit_lower_inverse, walk)
 from ..kda import SUB
 
 _F32 = jnp.float32
@@ -100,10 +148,16 @@ _TRANSPOSED = {
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _mm(a, b, dims, hi):
     """``a . b`` contracting ``dims`` with f32 sums; ``hi``: at f32 precision
-    (``dot32``), else operands as they are (the compute type).  Its
+    (all parts of an f32 operand: ``dot32``, and as ONE product over the
+    parts put one behind the other, ``dot32_stacked``, where the contraction
+    is whole lane tiles or runs down the rows, so the parts are stacked
+    without a copy), else operands as they are (the compute type).  Its
     cotangents are products of the same three forms, so no transposition
     reaches Mosaic."""
-    return (dot32 if hi else dot)(a, b, dims)
+    if not hi:
+        return dot(a, b, dims)
+    whole = dims == TN or a.shape[dims[0][0]] % 128 == 0
+    return (dot32_stacked if whole else dot32)(a, b, dims)
 
 
 def _mm_fwd(a, b, dims, hi):
@@ -237,15 +291,15 @@ def _close(c, T, v, beta_row, S, norm=None):
     d_v] f32, eps)``: ``o`` is then the head's gated RMS norm of it, ``o
     rsqrt(mean(o^2) + eps) scale sigmoid(z)``, taken on the f32 chunk."""
     ct, G, kf = v.dtype, c["G"], c["kf"]
-    Tb = T * beta_row
     eG = jnp.exp(G)
-    Vp = _mm(Tb, v, NN, True)
-    W = _mm(Tb, kf * eG, NN, True)
+    # u = T beta (v - (k e^G) S): one product behind T, where T beta v - (T
+    # beta (k e^G)) S was two in a chain; what reads S waits for G alone
+    X = v.astype(_F32) - _mm(kf * eG, S, NN, True)
+    QS = _mm(c["qf"] * eG, S, NN, True)
     yield
-    u = Vp - _mm(W, S, NN, True)
+    u = _mm(T * beta_row, X, NN, True)
     yield
-    o = _mm(c["qf"] * eG, S, NN, True) + _mm(c["P"], u.astype(ct), NN,
-                                               False)
+    o = QS + _mm(c["P"], u.astype(ct), NN, False)
     yield
     if norm is not None:
         z, scale, eps = norm
@@ -516,16 +570,79 @@ def unsupported(q, k, v, g, chunk):
     return None
 
 
+def _products(jaxpr, into):
+    """Count the ``dot_general``s of ``jaxpr`` and of every jaxpr under it
+    ``into`` a counter, by ``(rows, contraction, columns)``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (ca, cb), _ = eqn.params["dimension_numbers"]
+            a, b = (v.aval.shape for v in eqn.invars)
+            into[a[1 - ca[0]], a[ca[0]], b[1 - cb[0]]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _products(sub, into)
+    return into
+
+
+@functools.lru_cache(maxsize=None)
+def chunk_products(form):
+    """The products of one chunk of one head (of 128 channels, bf16
+    operands), traced and not run: ``{"fwd": counter, "bwd": counter}`` of
+    the ``dot_general``s of ``_chunks`` and of ``jax.vjp`` of it as
+    ``_bwd_kernel`` takes it (the forward again, then its transposition),
+    each keyed ``(rows, contraction, columns)``.  ``form``: ``plain`` or
+    ``in_place``.  The module's docstring has the table this is held to
+    (``tests/test_kda_passes.py``)."""
+    d = 128
+    of = lambda rows, cols, t: jax.ShapeDtypeStruct((rows, cols), t)
+    wide, state = of(C, d, _BF16), of(d, d, _F32)
+    head = (wide, wide, wide, of(C, d, _F32), of(1, C, _F32), state)
+    gate = None
+    if form == "in_place":
+        small = of(1, d, _F32)
+        head = (wide,) * 4 + head[4:] + (wide, small, small, small)
+        gate = (-5.0, 1e-6)
+    chunks = functools.partial(_chunks, gate=gate)
+
+    def pulled(heads, cotangents):
+        return jax.vjp(chunks, heads)[1](cotangents)
+    traced = dict(
+        fwd=jax.make_jaxpr(chunks)((head,)),
+        bwd=jax.make_jaxpr(pulled)((head,), ((of(C, d, _F32), state),)))
+    return {kernel: _products(closed.jaxpr, collections.Counter())
+            for kernel, closed in traced.items()}
+
+
+def passes(products):
+    """The passes of the matrix unit behind a counter of products: one for
+    each 128 of a product's contraction (its operands are bf16 and no wider
+    than 128 columns)."""
+    return sum(n * -(-contraction // 128)
+               for (_, contraction, _), n in products.items())
+
+
 def _count_entry(form):
     """Trace-time count of the entry taken, beside ``dispatch.record``'s
-    count of the kernel-versus-jnp choice."""
-    telemetry.get_registry().counter(
+    count of the kernel-versus-jnp choice, and what a chunk of it asks of the
+    matrix unit (``passes`` of ``chunk_products``: a gauge, traced only while
+    telemetry is on)."""
+    registry = telemetry.get_registry()
+    registry.counter(
         "hetu_kda_entry_total",
         "Trace-time calls of the delta rule's kernels by what they are "
         "handed: the layer's arrays read in place with its norms and gates "
         "in the kernel, or q, k, v and g",
         labels=("form",),
     ).labels(form=form).inc()
+    if telemetry.enabled():
+        gauge = registry.gauge(
+            "hetu_kda_chunk_passes",
+            "Passes of the matrix unit (one a product and 128 of its "
+            "contraction) in one chunk of one head of the delta rule's "
+            "forward kernel and of its backward kernel (the forward again, "
+            "then its transposition), at the last call traced",
+            labels=("kernel",))
+        for kernel, products in chunk_products(form).items():
+            gauge.labels(kernel=kernel).set(passes(products))
 
 
 def entries():
