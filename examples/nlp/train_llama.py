@@ -51,6 +51,16 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            mask and the loss is the 1/t-weighted cross-entropy on the masked
            positions; six layers, 16 of 128 experts held, an eighth of the
            vocabulary, the mask token its last row)
+       python examples/nlp/train_llama.py --model phi-4-mini-flash-reasoning \
+           --layers 6 --first-layer 14 --vocab-rows 25008 \
+           --seq-len 16384 --batch-size 1     (Phi-4-mini-flash-reasoning at
+           the pipeline stage across the boundary of its two decoders: the
+           model's layers 14-19 under their published indices, Mamba-1,
+           differential attention over a window of 512, the Mamba layer that
+           hands out its scan output, differential attention over all keys
+           that hands out its K and V, a Gated Memory Unit and a
+           cross-attention layer that read them; an eighth of the tied
+           vocabulary)
 """
 
 import os
@@ -77,7 +87,8 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              Xing4ForCausalLM, XING4_CONFIGS, Zaya1Config,
                              Zaya1ForCausalLM, ZAYA1_CONFIGS, SdarMoeConfig,
                              SdarMoeForCausalLM, SDAR_CONFIGS,
-                             record_exit_shares, load_hf_llama_weights,
+                             Phi4FlashConfig, Phi4FlashForCausalLM,
+                             PHI4FLASH_CONFIGS, record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
 
@@ -89,7 +100,8 @@ def main():
                              + list(GRANITE_HYBRID_CONFIGS)
                              + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)
                              + list(XING4_CONFIGS) + list(ZAYA1_CONFIGS)
-                             + list(SDAR_CONFIGS)))
+                             + list(SDAR_CONFIGS)
+                             + list(PHI4FLASH_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -105,6 +117,13 @@ def main():
     ap.add_argument("--experts-held", default=None, metavar="FIRST:COUNT",
                     help="qwen3-next, nemotron, xing4, zaya1, sdar: the experts of each "
                          "layer this chip holds, of the router's full width")
+    ap.add_argument("--first-layer", type=int, default=0,
+                    help="phi-4-mini-flash-reasoning: the published index of "
+                         "the first of --layers consecutive layers (a "
+                         "layer's kind follows from its index)")
+    ap.add_argument("--heads", default=None, metavar="QUERY:KEY",
+                    help="phi-4-mini-flash-reasoning: query and key heads "
+                         "(beside --hidden at a toy size)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--tp", type=int, default=1)
@@ -141,6 +160,9 @@ def main():
               (SDAR_CONFIGS, SdarMoeConfig, SdarMoeForCausalLM,
                "num_hidden_layers", "moe_intermediate_size")
               if args.model in SDAR_CONFIGS else
+              (PHI4FLASH_CONFIGS, Phi4FlashConfig, Phi4FlashForCausalLM,
+               "num_hidden_layers", "intermediate_size")
+              if args.model in PHI4FLASH_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
@@ -161,6 +183,14 @@ def main():
     if args.experts_held:
         base["experts_held"] = tuple(
             int(n) for n in args.experts_held.split(":"))
+    if args.model in PHI4FLASH_CONFIGS:
+        # a cut keeps the published indices: the model's own depth says
+        # where its two decoders meet
+        base.update(first_layer_index=args.first_layer, remat="layer",
+                    published_layers=Phi4FlashConfig().num_layers)
+        if args.heads:
+            base["num_attention_heads"], base["num_key_value_heads"] = (
+                int(n) for n in args.heads.split(":"))
     diffusion = args.model in SDAR_CONFIGS
     if diffusion and args.vocab:
         # the mask token is an ordinary row of the slice: its last

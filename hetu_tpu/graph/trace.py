@@ -150,6 +150,14 @@ def evaluate(eval_nodes, bindings, ctx: TraceContext, topo=None,
     kept = []               # (kernel, bytes) a kernel call the groups keep
     if remat_groups:
         eval_ids = {n.id for n in eval_nodes}
+        # what a group hands on: every node of it that a node OUTSIDE it
+        # reads, a later group among them (a layer that hands its keys and
+        # values, or its scan's output, to layers behind it:
+        # `models/phi4flash.py`).  Such a value is a RESULT of its group's
+        # checkpointed function and an ARGUMENT of each reader's: it is kept,
+        # no reader makes it again, and jax sums the readers' cotangents
+        # before the group's own backward pass runs
+        # (`tests/test_phi4flash_reference.py`)
         consumed_outside = {}
         for n in topo:
             scope = getattr(n, "remat_scope", None)

@@ -48,18 +48,22 @@ a position sees what ``ops/attention.py block_diffusion_mask`` shows.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from .. import initializers as init
 from .. import telemetry
 from .base import BaseLayer, fresh_name
-from ..graph.node import scope
+from ..graph.node import VariableOp, scope
 from .common import Linear, RMSNorm
 from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
-from ..ops.base import simple_op
+from ..ops.base import ScopedOp, simple_op
 from ..ops.attention import scaled_dot_product_attention_op
 from ..ops.pallas.common import PARTS, parts
+from ..ops.pallas.flash_attention import pair_view_unsupported
 from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
                           qk_norm_rotary_pair_op, repeat_kv_op, alibi_bias_op)
 
@@ -391,3 +395,150 @@ class MultiHeadAttention(BaseLayer):
         if gate is not None:
             ctx_ = ctx_ * sigmoid_op(gate)
         return self.out_proj(ctx_)
+
+
+# -- differential attention (the SambaY decoders) ------------------------------
+
+def lambda_init(layer_index):
+    """The constant a differential layer starts its ``lambda`` at, from the
+    layer's PUBLISHED index (Differential Transformer, arXiv:2410.05258)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+def _pair_heads(q, *, half):
+    """``q [B, S, P x 2 half]`` (pair ``i`` is ``q1_i | q2_i``) -> ``[B, S, 2 P x
+    2 half]``: head ``2 i`` is ``q1_i | 0`` and head ``2 i + 1`` is ``0 | q2_i``,
+    so a head's scores on the key pair ``k1 | k2`` are ``q1 . k1`` and ``q2 .
+    k2``: two softmaxes over ONE value of ``2 half`` in kernels that know one
+    head size.  A zero half costs the matrix unit nothing it would not spend
+    on a contraction of 64 (its tiles are 128 deep)."""
+    width = 2 * half
+    low = jnp.arange(width) < half
+    zero = jnp.zeros((), q.dtype)
+    out = []
+    for i in range(q.shape[-1] // width):
+        pair = q[..., i * width:(i + 1) * width]
+        out += [jnp.where(low, pair, zero), jnp.where(low, zero, pair)]
+    return jnp.concatenate(out, axis=-1)
+
+
+def _lambda(lq1, lk1, lq2, lk2, lam_init):
+    f32 = jnp.float32
+    return (jnp.exp(jnp.sum(lq1.astype(f32) * lk1.astype(f32)))
+            - jnp.exp(jnp.sum(lq2.astype(f32) * lk2.astype(f32))) + lam_init)
+
+
+def _differ(ctx_, lq1, lk1, lq2, lk2, gamma, *, width, lam_init, eps):
+    """The heads' contexts ``[B, S, 2 P x width]`` (``A1_i``, then ``A2_i``)
+    -> ``[B, S, P x width]``: ``(1 - lambda_init) RMSNorm(A1_i - lambda A2_i;
+    gamma)`` a pair, f32 inside, one rounding."""
+    f32 = jnp.float32
+    lam = _lambda(lq1, lk1, lq2, lk2, lam_init)
+    g = gamma.astype(f32)
+    out = []
+    for i in range(ctx_.shape[-1] // (2 * width)):
+        at = 2 * i * width
+        d = (ctx_[..., at:at + width].astype(f32)
+             - lam * ctx_[..., at + width:at + 2 * width].astype(f32))
+        d = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + eps) * g
+        out.append(((1.0 - lam_init) * d).astype(ctx_.dtype))
+    return jnp.concatenate(out, axis=-1)
+
+
+def _lanes(x, *, lo, hi):
+    return x[..., lo:hi]
+
+
+class DifferentialAttention(BaseLayer):
+    """Differential attention as the SambaY decoders have it: ``num_heads``
+    query heads and ``num_kv_heads`` key heads of ``head_dim``; query heads ``(2
+    i, 2 i + 1)`` are the pair ``(q1_i, q2_i)``, key heads ``(2 j, 2 j + 1)``
+    the pair ``(k1_j, k2_j)``, ``V_j = [v_2j | v_(2j+1)]`` (``2 head_dim``
+    wide), pair ``i`` reads key pair ``j = i // (num_heads / num_kv_heads)``::
+
+        A^c_i = softmax(q^c_i (k^c_j)^T / sqrt(head_dim) + mask) V_j   c = 1, 2
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init(layer_index)
+        O_i = (1 - lambda_init) RMSNorm(A^1_i - lambda A^2_i; gamma)
+        out = [O_0 | ..] W_o + b_o
+
+    ``[q | k | v] = u W_qkv + b`` is ONE projection; ``cross=True`` builds the
+    query projection alone and the call takes another layer's ``keys`` and
+    ``values`` nodes (that layer's ``self.keys`` / ``self.values``, after its
+    projection).  The mask is causal, with ``window`` the last ``window`` keys
+    (the block is then ``hetu_window_attn``).  No position encoding, no
+    dropout, no key mask.
+
+    The pairs stay on the projections' ``[B, S, heads * head_dim]``: a pair is
+    one lane tile where ``head_dim`` is 64, and the attention node sees ``2
+    P`` heads of ``2 head_dim`` whose other half is zero (``_pair_heads``) on
+    ``num_kv_heads / 2`` key heads of the same size, which the flash and the
+    window kernels read in place as grouped queries (``pair_view_unsupported``
+    in ``ops/pallas/flash_attention.py`` says when they do).  Counted in
+    ``hetu_attn_layout_total`` as ``bshd`` / ``differential_pairs`` then, and
+    in ``hetu_attn_layers_total`` under ``differential_full`` / ``_window`` /
+    ``_cross``; ``hetu_diff_attn_lambda{layer}`` is set by ``lambdas()``'s
+    reader (``models/phi4flash.py``)."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, layer_index,
+                 sequence_length, window=None, cross=False, eps=1e-5,
+                 name=None):
+        name = fresh_name(name or "diff_attn")
+        d = hidden_size // num_heads
+        assert num_heads % 2 == 0 and num_kv_heads % 2 == 0, (
+            "differential attention pairs its heads", num_heads, num_kv_heads)
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.head_dim, self.num_heads, self.num_kv_heads = (
+            d, num_heads, num_kv_heads)
+        self.inner, self.kv_dim = num_heads * d, num_kv_heads * d
+        self.layer_index, self.lambda_init = layer_index, lambda_init(
+            layer_index)
+        self.window, self.cross, self.eps = window, cross, eps
+        self.sequence_length = sequence_length
+        self.pair_view = pair_view_unsupported(num_heads, num_kv_heads, d)
+        normal = init.normal(0.0, 0.02)
+        self.qkv_proj = Linear(
+            hidden_size, self.inner + (0 if cross else 2 * self.kv_dim),
+            bias=True, initializer=normal, name=f"{name}_q" if cross
+            else f"{name}_qkv")
+        self.out_proj = Linear(self.inner, hidden_size, bias=True,
+                               initializer=normal, name=f"{name}_out")
+        self.lambdas = tuple(
+            VariableOp(f"{name}_lambda_{n}", (d,), init.normal(0.0, 0.1))
+            for n in ("q1", "k1", "q2", "k2"))
+        self.sub_norm = VariableOp(f"{name}_subln_scale", (2 * d,),
+                                   init.ones())
+        #: the projected keys and values of the last call (not a cross layer)
+        self.keys = self.values = None
+
+    def __call__(self, u, keys=None, values=None):
+        block = "hetu_attn" if self.window is None else "hetu_window_attn"
+        assert (keys is not None) == self.cross, (
+            "a cross layer is called with another layer's keys and values")
+        count_layout("bshd" if self.pair_view is None else "bhsd",
+                     self.pair_view or "differential_pairs")
+        with scope(block):
+            qkv = self.qkv_proj(u)
+            q = qkv
+            if not self.cross:
+                q, keys, values = (
+                    ScopedOp(_lanes, block, qkv, lo=lo, hi=hi)
+                    for lo, hi in ((0, self.inner),
+                                   (self.inner, self.inner + self.kv_dim),
+                                   (self.inner + self.kv_dim,
+                                    self.inner + 2 * self.kv_dim)))
+                self.keys, self.values = keys, values
+            heads = ScopedOp(_pair_heads, block, q, half=self.head_dim)
+            ctx_ = scaled_dot_product_attention_op(
+                heads, keys, values, causal=True,
+                scale=self.head_dim ** -0.5, num_heads=self.num_heads,
+                window=self.window,
+                form="cross" if self.cross else "differential")
+            out = ScopedOp(_differ, block, ctx_, *self.lambdas,
+                           self.sub_norm, width=2 * self.head_dim,
+                           lam_init=self.lambda_init, eps=self.eps)
+            return self.out_proj(out)
+
+    def lambda_value(self, params):
+        """``lambda`` of this layer under ``params`` (``{name: array}``)."""
+        return float(_lambda(*(params[v.name] for v in self.lambdas),
+                             self.lambda_init))

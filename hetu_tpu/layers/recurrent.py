@@ -1,5 +1,5 @@
 """The initialisers the recurrent mixers (``gated_delta_net.py``,
-``mamba2.py``, ``kda.py``) share, of their decays and steps.  Their nodes are
+``mamba2.py``, ``mamba1.py``, ``kda.py``) share, of their decays and steps.  Their nodes are
 shared too: a projection is ``ScopedOp(project, ..)`` (``base.py``), the scan
 ``ops/base.py KernelOp``, the convolution ``ops/causal_conv.py ConvOp``, the
 scalar mixers' output ``ops/gated_norm.py OutOp``; the data flow between the
@@ -18,6 +18,15 @@ def log_uniform(lo, hi):
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, lo, hi)
                        ).astype(dtype)
     return draw
+
+
+def log_arange(key, shape, dtype=np.float32):
+    """``log(1 .. N)`` along the last axis, the same a row: Mamba-1's initial
+    ``A_log [channels, N]`` (S4D-real)."""
+    import jax.numpy as jnp
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[-1] + 1,
+                                               dtype=jnp.float32)),
+                            shape).astype(dtype)
 
 
 def dt_bias(dt_min, dt_max, floor):

@@ -127,20 +127,32 @@ class ScaledDotProductAttentionOp(Op):
     window = None
     #: the block length of the block-diffusion mask; None: no such mask
     block_diffusion = None
+    #: ``differential``: the heads are the two halves of query pairs on one
+    #: value (``layers/attention.py DifferentialAttention``); ``cross`` with
+    #: it: the keys and values are another layer's nodes
+    form = None
 
     @property
     def kind(self):
-        """``full``, ``window`` or ``block_diffusion``: the label of
-        ``hetu_attn_layers_total``."""
+        """``full``, ``window`` or ``block_diffusion``, behind
+        ``differential_`` where the node is a differential layer's
+        (``differential_cross`` where its keys and values are another
+        layer's): the label of ``hetu_attn_layers_total``."""
         if self.block_diffusion is not None:
             return "block_diffusion"
-        return "full" if self.window is None else "window"
+        kind = "full" if self.window is None else "window"
+        if self.form is None:
+            return kind
+        return "differential_" + ("cross" if self.form == "cross" else kind)
 
     def __init__(self, q, k, v, mask=None, causal=False, scale=None,
                  dropout_keep=1.0, num_heads=None, block_diffusion=None,
-                 name=None):
+                 form=None, name=None):
         inputs = [q, k, v] + ([mask] if mask is not None else [])
         super().__init__(*inputs, name=name)
+        if form is not None:
+            assert form in ("differential", "cross"), form
+            self.form = form
         if block_diffusion is not None:
             assert (not causal and mask is None and dropout_keep >= 1.0
                     and self.window is None and block_diffusion >= 1), (
@@ -151,7 +163,9 @@ class ScaledDotProductAttentionOp(Op):
             "hetu_attn_layers_total",
             "Attention nodes built, by kind (full: every key the mask "
             "leaves; window: the last `window` keys; block_diffusion: a clean "
-            "and a noised copy under the block-diffusion mask)",
+            "and a noised copy under the block-diffusion mask; differential_"
+            "full / _window / _cross: the halves of query pairs as heads on "
+            "one value, on the layer's own keys or on another layer's)",
             labels=("kind",),
         ).labels(kind=self.kind).inc()
         self.has_mask = mask is not None
@@ -305,9 +319,12 @@ class WindowAttentionOp(ScaledDotProductAttentionOp):
 def scaled_dot_product_attention_op(q, k, v, mask=None, causal=False,
                                     scale=None, dropout_keep=1.0,
                                     num_heads=None, window=None,
-                                    block_diffusion=None, name=None):
+                                    block_diffusion=None, form=None,
+                                    name=None):
     kw = dict(mask=mask, causal=causal, scale=scale,
               dropout_keep=dropout_keep, num_heads=num_heads, name=name)
+    if form is not None:
+        kw["form"] = form
     if window is not None:
         assert block_diffusion is None, "a window or the block mask, not both"
         return WindowAttentionOp(q, k, v, window, **kw)

@@ -94,7 +94,7 @@ MESH = "mesh"
 NO_CHOICE_OFF_TPU = frozenset({"gated_delta", "ssd", "kda", "causal_conv",
                                "gated_norm", "moe_rows", "rotary",
                                "hc_mix", "mla_pack", "moe_select",
-                               "qk_norm_rope"})
+                               "qk_norm_rope", "selective_scan"})
 
 
 def take(kernel, mesh, reason=None, asked=False):

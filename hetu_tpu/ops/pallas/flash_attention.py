@@ -138,6 +138,27 @@ def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None,
     return None
 
 
+def pair_view_unsupported(num_heads, num_kv_heads, head_dim,
+                          dropout_keep=1.0, mask=None):
+    """Why a differential layer's PAIRS (``layers/attention.py
+    DifferentialAttention``: query heads ``(2 i, 2 i + 1)`` side by side on one
+    value of ``2 head_dim``) are not read in place as heads of ``2 head_dim``
+    on ``num_kv_heads / 2`` key heads, or None when they are: what that view
+    really asks is a head of 64 or a multiple (a pair is then whole lane
+    tiles, which ``unsupported`` asks of grouped queries:
+    ``grouped_head_dim_not_128_aligned``), an even number of query and of key
+    heads, no dropout on the probabilities and no key mask (neither is built
+    into the pair's combination)."""
+    for reason, holds in (
+            ("pair_heads_odd", num_heads % 2 or num_kv_heads % 2),
+            ("pair_head_dim_not_64_aligned", head_dim % (_LANES // 2)),
+            ("pair_with_dropout", dropout_keep < 1.0),
+            ("pair_with_key_mask", mask is not None)):
+        if holds:
+            return reason
+    return None
+
+
 def heads_view(x, num_heads):
     """The shape ``[B, H, S, D]`` of which ``x`` ``[B, S, H*D]`` is a view
     (no array: what ``unsupported`` and the mesh plan read)."""

@@ -81,6 +81,26 @@ def test_train_llama_example_fetches_an_moe_models_loads(model, layers):
     assert "step    1  loss" in r.stdout
 
 
+def test_train_llama_example_trains_a_cut_across_two_decoders():
+    """``--model phi-4-mini-flash-reasoning --layers 6 --first-layer 14``:
+    the model's layers 14-19 under their published indices (every kind of
+    layer; a Gated Memory Unit and a cross-attention layer reading what two
+    recomputed layers before them kept) build and train at a toy size."""
+    r = _run(["examples/nlp/train_llama.py", "--model",
+              "phi-4-mini-flash-reasoning", "--layers", "6", "--first-layer",
+              "14", "--hidden", "64", "--heads", "4:2", "--intermediate",
+              "32", "--vocab", "128", "--seq-len", "64", "--batch-size", "1",
+              "--steps", "2"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "step    1  loss" in r.stdout
+    # a run of layers that leaves out what a reader reads is refused
+    r2 = _run(["examples/nlp/train_llama.py", "--model",
+               "phi-4-mini-flash-reasoning", "--layers", "2",
+               "--first-layer", "18", "--hidden", "64", "--heads", "4:2",
+               "--steps", "1"])
+    assert r2.returncode != 0 and "reads layer 16" in r2.stderr
+
+
 def test_ctr_sparse_opt_example_smoke():
     """train_ctr --sparse-opt (lazy in-graph table updates) runs."""
     r = _run(["examples/ctr/train_ctr.py", "--model", "wdl", "--steps",

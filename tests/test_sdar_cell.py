@@ -89,7 +89,7 @@ def test_benchmark_entries():
     assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
     felt, = (m for m in bench["end_to_end"]
              if m["name"] == "train_tokens_per_s")
-    assert felt["workloads"][-1] == CELL
+    assert CELL in felt["workloads"]
     assert len(bench["per_layer"]) <= 128
     # by QUANTITY: ONE entry of each lists this cell, whatever its name
     mine = [m["name"].split(".")[0] for m in bench["per_layer"]
