@@ -56,7 +56,8 @@ benchmark's long-memory probe calls (``chipbench/builders/qwen3_next.py``
 ``delta_rule_gap``).  On a TPU it runs as two Pallas kernels,
 ``hetu_gdn_fwd`` and ``hetu_gdn_bwd`` (``ops/pallas/gated_delta.py``, a
 ``jax.custom_vjp``: one walk over chunk states in VMEM each way; the
-backward keeps the chunk-start states and rebuilds everything else), where
+backward keeps the chunk-start states and the chunks' triangular inverses
+and rebuilds everything else), where
 it can read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk``
 64, q, k and v all bf16 or all f32; any ``T``, ``B`` and ``H``.  Each call
 counts its choice at trace time in ``hetu_kernel_choice_total{kernel=

@@ -56,7 +56,8 @@ What runs where.  ``chunk_kda`` is what the benchmark's probe calls and what
 the layer's ``hetu_kda_scan`` node (``layers/kda.py``) calls wherever the
 kernels do not run.  On a TPU it runs as two Pallas kernels, ``hetu_kda_fwd``
 and ``hetu_kda_bwd`` (``ops/pallas/kda.py``, a ``jax.custom_vjp``; the
-backward keeps the chunk-start states and rebuilds the rest), where it can
+backward keeps the chunk-start states and the chunks' triangular inverses
+and rebuilds the rest), where it can
 read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk`` 64, q,
 k and v all bf16 or all f32, ``g`` f32.  Each call counts its choice at
 trace time in ``hetu_kernel_choice_total{kernel="kda", impl, reason}``:

@@ -154,7 +154,9 @@ def _merge_right(T, TB):
 
 def unit_lower_inverse(L, LT):
     """``(I + L)^-1`` for a strictly lower triangular ``L [C, C]`` given with
-    its transpose; see the module's docstring.  (Walking the rows by sublane
+    its transpose; see the module's docstring.  The delta rules' forward
+    kernels call it and write the result out; their backward kernels read
+    that (PR 66) and do not come here.  (Walking the rows by sublane
     tiles of 8, so that a step touches only the tiles it reads and writes,
     was 0.8% of the cell's step faster and seven times the stages.)"""
     A, ATn, rel, pick = _solve_open(L, LT)

@@ -84,6 +84,21 @@ def record(kernel, reason=None):
     return reason is None
 
 
+def count_inverse(rule, source):
+    """Count one traced call of a delta rule's kernel (``rule``: ``gdn`` or
+    ``kda``) by where it has its chunks' ``(I + L)^-1`` from: ``solved`` by
+    substitution (the forward kernels) or ``kept``, read as the forward
+    kernel wrote it (the backward kernels)."""
+    telemetry.get_registry().counter(
+        "hetu_delta_inverse_total",
+        "Trace-time calls of the delta rules' kernels by where a chunk's "
+        "triangular inverse comes from: solved by substitution (a forward "
+        "kernel) or kept, read as the forward kernel wrote it (a backward "
+        "kernel)",
+        labels=("rule", "source"),
+    ).labels(rule=rule, source=source).inc()
+
+
 #: the reason of a kernel that has no per-shard form, under a mesh
 MESH = "mesh"
 

@@ -10,23 +10,41 @@ lane offset ``h * d``: no ``[B, H, N, C, d]`` copy exists), g and beta from
 ``[B, H, T / (n C), n, C]``, and for each chunk forms in VMEM the running sum
 ``G``, the masked decays, ``K K^T``, ``L``, ``T = (I + L)^-1``, ``V' = T (beta
 V)``, ``W = T (beta exp(G) K)``, ``u = V' - W S``, ``o = (q exp(G)) S + P u``
-and the next state; it writes ``o`` once and the state each chunk starts
-from.
+and the next state; it writes ``o`` once, the state each chunk starts from
+and the chunk's ``T``.
 
 ``hetu_gdn_bwd``: the same grid with the chunks in reverse and ``dS [d_k,
-d_v]`` f32 in VMEM scratch.  A program rebuilds its chunk's ``T, W, V', u,
-P`` from q, k, v, g, beta and the kept chunk-start state, and writes dq, dk,
-dv, dg and dbeta once.  One kernel: the reverse walk and the gradients inside
-a chunk share every rebuilt matrix.
+d_v]`` f32 in VMEM scratch.  A program reads its chunk's ``T`` as the forward
+kernel wrote it, rebuilds ``W, V', u, P`` from it, q, k, v, g, beta and the
+kept chunk-start state, and writes dq, dk, dv, dg and dbeta once.  One
+kernel: the reverse walk and the gradients inside a chunk share every
+rebuilt matrix.  Nothing is solved here: the triangle ``L``, its transpose
+and the substitution are the forward kernel's alone
+(``hetu_delta_inverse_total{rule="gdn", source}`` counts a forward call
+traced as ``solved`` and a backward one as ``kept``).
 
 What the backward keeps: the chunk-start states (``d_k x d_v`` f32 a chunk
-and head, 268 MB for a layer of the Qwen3-Next cell, alive only while that
-layer's backward pass runs since the mixer is recomputed), and nothing else:
-no ``[.., 64, 64]`` matrix, no ``W``, ``V'`` or ``u`` reaches HBM.  Writing
-them costs the forward kernel nothing that a run can see (2.437 ms a layer
-with them, 2.438 without: PERF.md, PR 32), so there is one forward kernel
-and a call that wants no gradient drops them; walking the chunks of a
-program again in the backward pass would add two state products a chunk.
+and head, 268 MB for a layer of the Qwen3-Next cell) and the chunks'
+inverses ``T [B, H, T / (n C), n, 64, 64]`` f32, exactly the value
+``unit_lower_inverse`` returned (16 KiB a chunk and head, 64 MiB a layer of
+that cell and 128 MiB in HBM, whose tiles are 128 lanes wide), both alive
+only while that layer's backward pass runs since the mixer is recomputed;
+no ``W``, ``V'`` or ``u`` reaches HBM.  A step used to solve every chunk's
+system three times (forward, recomputed forward, and again inside the
+backward kernel, 37% of that kernel's bundles: 6.08 -> 3.88 ms a call on a
+v5e; PERF.md, PR 66).  The tiles stay half empty: a program's heads side by
+side in whole ``[64, 128]`` tiles halve the kept bytes but the odd heads then
+sit at lane offset 64 (the backward body 9% more bundles with a load there,
+1.6% with an aligned load and a roll; in the Qwen3-Next step 0.05 ms a step
+more for each kernel and the same peak, since that cell's peak lies
+elsewhere; KDA's forward body 1.2% more bundles, over the 1% a forward
+kernel was allowed), and the padding costs 0.78 points of HBM where a
+mixer's backward pass is the peak (Ling-3.0: 86.67 -> 87.45%).  Writing the
+states and ``T`` costs the forward kernel nothing that a run can see (2.437
+ms a layer with the states, 2.438 without: PERF.md, PR 32; 20.88 -> 20.91 ms
+a step in six calls with ``T``, PR 66), so there is one forward kernel and a
+call that wants no gradient drops them; walking the chunks of a program
+again in the backward pass would add two state products a chunk.
 
 A chunk is one chain of dependent steps (the solve, ``T rhs``, ``W S``, the
 state), and Mosaic's scheduler stays close to program order.  So a program
@@ -89,10 +107,11 @@ _BF16 = jnp.bfloat16
 
 
 
-@jax.jit
-def _chunk_open(k, g_row, beta_row):
-    """The running sum ``G``, the masked decays, ``K_beta`` and the triangle
-    ``L = strict(K_beta K^T D)`` with its transpose."""
+@functools.partial(jax.jit, static_argnames="solve")
+def _chunk_open(k, g_row, beta_row, *, solve):
+    """The running sum ``G``, the masked decays, ``K_beta`` and ``K_beta
+    K^T``; ``solve``: also the triangle ``L = strict(K_beta K^T D)`` with its
+    transpose, which the inverse alone reads."""
     row, col = iotas()
     eye, lower = row == col, row >= col
     G = jnp.sum(jnp.where(lower, jnp.broadcast_to(g_row, (C, C)), 0.0),
@@ -102,12 +121,14 @@ def _chunk_open(k, g_row, beta_row):
     diff = G - G_row                                         # G_t - G_s
     # masked before the exp: above the diagonal the difference is positive
     D = jnp.exp(jnp.where(lower, diff, -jnp.inf))
-    DT = jnp.exp(jnp.where(row <= col, -diff, -jnp.inf))
     kb = (k.astype(_F32) * beta).astype(k.dtype)
     KK = dot(kb, k, NT)
-    return dict(G=G, G_row=G_row, beta=beta, D=D, kb=kb, KK=KK,
-                L=jnp.where(row > col, KK * D, 0.0),
-                LT=jnp.where(row < col, dot(k, kb, NT) * DT, 0.0))
+    c = dict(G=G, G_row=G_row, beta=beta, D=D, kb=kb, KK=KK)
+    if solve:
+        DT = jnp.exp(jnp.where(row <= col, -diff, -jnp.inf))
+        c.update(L=jnp.where(row > col, KK * D, 0.0),
+                 LT=jnp.where(row < col, dot(k, kb, NT) * DT, 0.0))
+    return c
 
 
 @jax.jit
@@ -128,14 +149,16 @@ def _chunk_close(q, k, G, D):
                 eG=jnp.exp(G), e_end=jnp.exp(G_end - G), a=jnp.exp(G_end))
 
 
-def _chunk(q, k, v, g_row, beta_row):
+def _chunk(q, k, v, g_row, beta_row, T=None):
     """What a chunk's forward and backward passes share and the state does
-    not enter.  A generator, as the three functions around it: it yields
-    between stages that depend on each other and returns its value at the
-    end (``together``)."""
-    c = _chunk_open(k, g_row, beta_row)
+    not enter; ``T``: the chunk's kept inverse, or None to solve for it.  A
+    generator, as the three functions around it: it yields between stages
+    that depend on each other and returns its value at the end
+    (``together``)."""
+    c = _chunk_open(k, g_row, beta_row, solve=T is None)
     yield
-    T = yield from unit_lower_inverse(c["L"], c["LT"])
+    if T is None:
+        T = yield from unit_lower_inverse(c["L"], c["LT"])
     Vp, W = _chunk_rhs(T, k, v, beta_row, c["G_row"])
     yield
     return dict({n: c[n] for n in ("beta", "D", "kb", "KK")}, T=T, Vp=Vp,
@@ -154,11 +177,12 @@ def _fwd_close(q, k, S, u, c):
 
 
 def _chunk_fwd(q, k, v, g_row, beta_row, S):
-    """One chunk from the state ``S`` it starts at: ``(o f32, next state)``."""
+    """One chunk from the state ``S`` it starts at: ``(o f32, next state,
+    the chunk's inverse T)``."""
     c = yield from _chunk(q, k, v, g_row, beta_row)
     u = _u(c["Vp"], c["W"], S)
     yield
-    return _fwd_close(q, k, S, u, c)
+    return _fwd_close(q, k, S, u, c) + (c["T"],)
 
 
 # o = (q exp(G)) S + P u;  S_next = a S + (k e_end)^T u;  u = V' - W S
@@ -225,12 +249,13 @@ def _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c):
     return dq, dk, dv, dg, to_row(dbeta, eye)
 
 
-def _chunk_bwd(q, k, v, g_row, beta_row, S, do, dS):
+def _chunk_bwd(q, k, v, g_row, beta_row, S, T, do, dS):
     """One chunk's gradients from ``do`` and the gradient ``dS`` of the state
-    it ends at: ``(dq, dk, dv f32 [C, d]; dg, dbeta [1, C]; the gradient of
-    the state it starts at)``."""
-    c = yield from _chunk(q, k, v, g_row, beta_row)
-    T, Vp, W = c["T"], c["Vp"], c["W"]
+    it ends at, with the chunk-start state ``S`` and the inverse ``T`` its
+    forward pass kept: ``(dq, dk, dv f32 [C, d]; dg, dbeta [1, C]; the
+    gradient of the state it starts at)``."""
+    c = yield from _chunk(q, k, v, g_row, beta_row, T)
+    Vp, W = c["Vp"], c["W"]
     u = _u(Vp, W, S)
     yield
     dP, du, dk_end = _bwd_u(k, do, dS, u, c)
@@ -245,7 +270,7 @@ def _chunk_bwd(q, k, v, g_row, beta_row, S, do, dS):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
-                s_ref, *, nc, hb, dk, dv):
+                t_ref, s_ref, *, nc, hb, dk, dv):
     import jax.experimental.pallas as pl
     i = pl.program_id(2)
     lanes = head_lanes(hb, dk, dv)
@@ -262,9 +287,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
             _chunk_fwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
                        pick(g_ref[h], j), pick(b_ref[h], j), s_ref[h])
             for h, (kl, vl) in enumerate(lanes))
-        for h, (o, S) in enumerate(outs):
+        for h, (o, S, T) in enumerate(outs):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
+            t_ref[h, j] = T
     walk(nc, body)
 
     @pl.when(i == pl.num_programs(2) - 1)
@@ -272,9 +298,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
         last_ref[...] = s_ref[...]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, nc, hb, dk,
-                dv):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, t_ref, do_ref,
+                dlast_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *,
+                nc, hb, dk, dv):
     import jax.experimental.pallas as pl
     lanes = head_lanes(hb, dk, dv)
 
@@ -288,7 +314,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
         outs = together(
             _chunk_bwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
                        pick(g_ref[h], j), pick(b_ref[h], j), s0_ref[h, j],
-                       do_ref[rows, vl], ds_ref[h])
+                       t_ref[h, j], do_ref[rows, vl], ds_ref[h])
             for h, (kl, vl) in enumerate(lanes))
         for h, (dq, dk_, dv_, dg, dbeta, dS) in enumerate(outs):
             kl, vl = lanes[h]
@@ -304,8 +330,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dlast_ref,
 
 def _plan(g, q, v, reverse):
     """Grid, the kernels' static sizes and the block specs of q / k, v / o,
-    g / beta, the kept states and a state; ``reverse``: the blocks of chunks
-    from the last to the first."""
+    g / beta, the kept states, the kept inverses and a state; ``reverse``:
+    the blocks of chunks from the last to the first."""
     import jax.experimental.pallas as pl
     B, H, groups, nc, _ = g.shape
     dk, dv = q.shape[2] // H, v.shape[2] // H
@@ -315,30 +341,34 @@ def _plan(g, q, v, reverse):
                                  lambda b, h, i: (b, at(i), h))
     gate = pl.BlockSpec((None, hb, None, nc, C),
                         lambda b, h, i: (b, h, at(i), 0, 0))
-    kept = pl.BlockSpec((None, hb, None, nc, dk, dv),
-                        lambda b, h, i: (b, h, at(i), 0, 0, 0))
+    kept = lambda rows, cols: pl.BlockSpec(
+        (None, hb, None, nc, rows, cols),
+        lambda b, h, i: (b, h, at(i), 0, 0, 0))
     state = pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0))
     return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv),
-            (seq(dk), seq(dv), gate, kept, state))
+            (seq(dk), seq(dv), gate, kept(dk, dv), kept(C, C), state))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fwd_call(q, k, v, g, beta, *, interpret):
     """``q, k [B, T, H dk]``, ``v [B, T, H dv]``, ``g, beta [B, H, T / (n C),
     n, C]`` f32 (``n`` chunks a program): ``(o [B, T, H dv], last state [B, H,
-    dk, dv], chunk-start states [B, H, T / (n C), n, dk, dv])``."""
+    dk, dv], chunk-start states [B, H, T / (n C), n, dk, dv], the chunks'
+    inverses [B, H, T / (n C), n, C, C] f32)``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     B, H, groups, nc, _ = g.shape
-    grid, dims, (qk, vo, gate, kept, state) = _plan(g, q, v, False)
+    grid, dims, (qk, vo, gate, kept, inverse, state) = _plan(g, q, v, False)
     dk, dv, hb = dims["dk"], dims["dv"], dims["hb"]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **dims),
         name="hetu_gdn_fwd", grid=grid,
-        in_specs=[qk, qk, vo, gate, gate], out_specs=[vo, state, kept],
+        in_specs=[qk, qk, vo, gate, gate],
+        out_specs=[vo, state, kept, inverse],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
-                   jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32)],
+                   jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32),
+                   jax.ShapeDtypeStruct((B, H, groups, nc, C, C), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
@@ -346,14 +376,14 @@ def _fwd_call(q, k, v, g, beta, *, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _bwd_call(q, k, v, g, beta, states, do, dlast, *, interpret):
+def _bwd_call(q, k, v, g, beta, states, inverses, do, dlast, *, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (qk, vo, gate, kept, state) = _plan(g, q, v, True)
+    grid, dims, (qk, vo, gate, kept, inverse, state) = _plan(g, q, v, True)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, **dims),
         name="hetu_gdn_bwd", grid=grid,
-        in_specs=[qk, qk, vo, gate, gate, kept, vo, state],
+        in_specs=[qk, qk, vo, gate, gate, kept, inverse, vo, state],
         out_specs=[qk, qk, vo, gate, gate],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -364,7 +394,7 @@ def _bwd_call(q, k, v, g, beta, states, do, dlast, *, interpret):
                                    _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v, g, beta, states, do, dlast)
+    )(q, k, v, g, beta, states, inverses, do, dlast)
 
 
 @jax.custom_vjp
@@ -373,13 +403,15 @@ def _rule(q, k, v, g, beta):
 
 
 def _rule_fwd(q, k, v, g, beta):
-    o, last, states = _fwd_call(q, k, v, g, beta,
-                                interpret=dispatch.interpret())
-    return (o, last), (q, k, v, g, beta, states)
+    dispatch.count_inverse("gdn", "solved")
+    o, last, states, inverses = _fwd_call(q, k, v, g, beta,
+                                          interpret=dispatch.interpret())
+    return (o, last), (q, k, v, g, beta, states, inverses)
 
 
 def _rule_bwd(res, grads):
     do, dlast = grads
+    dispatch.count_inverse("gdn", "kept")
     return tuple(_bwd_call(*res, do, dlast, interpret=dispatch.interpret()))
 
 

@@ -13,14 +13,25 @@ VMEM (``_chunks``) and writes ``o`` once and the state each chunk starts
 from.
 
 ``hetu_kda_bwd``: the same grid with the chunks in reverse and ``dS`` in
-VMEM scratch.  A program rebuilds its chunks from its operands and the kept
-chunk-start states and pulls ``do`` and ``dS`` back through them: the
-backward pass of a chunk is ``jax.vjp`` of ``_chunks``, traced into the
-kernel.  Two rules are given by hand so that what Mosaic is handed are the
-three forms of product it lowers without a transposition (``_mm``) and no
-walk back through the substitution (``_inverses``: ``dL = -T^T dT T^T``).
-What the backward keeps is the operands, the chunk-start states and nothing
-else.
+VMEM scratch.  A program rebuilds its chunks from its operands, the kept
+chunk-start states and the kept inverses and pulls ``do`` and ``dS`` back
+through them: the backward pass of a chunk is ``jax.vjp`` of ``_chunks``,
+traced into the kernel.  Two rules are given by hand so that what Mosaic is
+handed are the three forms of product it lowers without a transposition
+(``_mm``) and no walk back through the substitution (``_inverses``: ``dL =
+-T^T dT T^T``).  The backward kernel does not solve at all: ``_chunks`` takes
+the heads' ``T`` as the forward kernel wrote them (``_kept_inverses``: the
+kept value, ``_inverses``' cotangent), so neither the substitution, its two
+merges nor the product that forms ``L^T`` is traced there
+(``hetu_delta_inverse_total{rule="kda", source}`` counts a forward call
+traced as ``solved`` and a backward one as ``kept``).  What the backward
+keeps is the operands, the chunk-start states and the chunks' inverses ``T
+[B, H, T / (n C), n, 64, 64]`` f32, exactly what ``unit_lower_inverse``
+returned (16 KiB a chunk and head; 64 MiB a layer of the Ling-3.0 cell and
+128 MiB in HBM, whose tiles are 128 lanes wide; alive, as the states, only
+while that layer's backward pass runs; ``gated_delta.py`` has why the tiles
+stay half empty; ``hetu_kda_bwd`` 9.07 -> 6.70 ms a call on a v5e, PERF.md,
+PR 66).
 
 Two entries of the one kernel pair, by what the caller holds (counted in
 ``hetu_kda_entry_total{form}``, ``entries()``).  ``kda`` (``plain``) takes
@@ -39,7 +50,7 @@ from the same ``jax.vjp``: the kernel writes ``dq~, dk~, dv, df, dz`` in the
 compute type and the small parameters' as f32 partial sums a batch row and
 head (output blocks resident over the sequence; XLA adds them).  No f32 ``[B,
 T, H d]`` array reaches HBM in either pass, and the residuals are ``mixed``,
-``proj``, ``beta`` and the chunk-start states.
+``proj``, ``beta``, the chunk-start states and the chunks' inverses.
 
 Inside a chunk the decays are taken sub-chunk by sub-chunk of 16 positions
 (``_pair``): the diagonal ``[16, 16]`` blocks from one ``[64, 64]`` product
@@ -64,14 +75,18 @@ contraction is whole lane tiles or runs down the rows are one product each:
                                      products passes  products  passes
     G = triangle of ones x g          3 ->  3  3 ->  3   12 ->  5  12 ->  11
     the pair matrices (_pair)         8     8  8     8   24    24  24     24
-    L^T (through the matrix unit)     3     3  3     3    3     3   3      3
-    the inverse's two merges         24    24 24    24   24    24  24     24
+    L^T (through the matrix unit)     3     3  3     3    3     -   3      -
+    the inverse's two merges         24    24 24    24   24     -  24      -
     the inverse's cotangent           -     -  -     -   12     7  12      9
     products with S                  12     2 12    12   36     6  36     30
     products with T beta              9     6  9     6   30     8  30     15
     P u                               1     1  1     1    3     3   3      3
     the next state                    6     1  6     3   18     3  18     15
-    a chunk                          66    48 66    60  162    83 162    134
+    a chunk                          66    48 66    60  162    56 162    107
+
+(The backward columns' right-hand sides were 83 products and 134 passes until
+PR 66: the kernel solved for the inverse again, ``L^T`` and the two merges,
+where it now reads the forward kernel's.)
 
 The time does not follow the passes: the body of the chunk walk is bound
 by the vector unit (splitting f32 operands into parts, adding partial
@@ -239,6 +254,25 @@ def _inverses_bwd(Ts, dTs):
 _inverses.defvjp(_inverses_fwd, _inverses_bwd)
 
 
+@jax.custom_vjp
+def _kept_inverses(Ls, Ts):
+    """``_inverses(Ls)`` where the forward kernel kept them: ``Ts`` as they
+    are, with ``_inverses``' cotangent for ``Ls``; no substitution and no
+    ``L^T`` is traced."""
+    return Ts
+
+
+def _kept_inverses_fwd(Ls, Ts):
+    return Ts, Ts
+
+
+def _kept_inverses_bwd(Ts, dTs):
+    return _inverses_bwd(Ts, dTs) + (tuple(jnp.zeros_like(T) for T in Ts),)
+
+
+_kept_inverses.defvjp(_kept_inverses_fwd, _kept_inverses_bwd)
+
+
 def _unit(x):
     """The rows of ``x [C, d]`` over their norms, f32 (the layer's
     ``l2norm``: 1e-6 under the root)."""
@@ -316,16 +350,18 @@ def _close(c, T, v, beta_row, S, norm=None):
     return o, S_next
 
 
-def _chunks(heads, gate=None):
+def _chunks(heads, gate=None, kept=None):
     """One chunk of each of a program's heads, their chains in step: ``heads``
     a tuple of ``(q, k, v, g, beta_row, S)`` (``q, k [C, d_k]``, ``v [C,
     d_v]`` in the compute type, ``g [C, d_k]`` f32, ``beta_row [1, C]`` f32,
-    ``S [d_k, d_v]`` f32) -> a tuple of ``(o f32 [C, d_v], the next state)``.
-    ``gate = (lower_bound, eps)`` (static): a head is ``(q~, k~, v, f,
-    beta_row, S, z, rate, bias, scale)`` as the layer has them and ``o`` what
-    its output product reads (``_open``, ``_close``).  Its backward pass is
-    ``jax.vjp`` of it, whose order is this one's reversed: interleaved as
-    well."""
+    ``S [d_k, d_v]`` f32) -> a tuple of ``(o f32 [C, d_v], the next state)``
+    and, beside it, the heads' inverses ``T [C, C]`` f32 (for ``jax.vjp``,
+    ``has_aux``).  ``gate = (lower_bound, eps)`` (static): a head is ``(q~,
+    k~, v, f, beta_row, S, z, rate, bias, scale)`` as the layer has them and
+    ``o`` what its output product reads (``_open``, ``_close``).  ``kept``:
+    the heads' inverses as the forward kernel wrote them, or None to solve
+    for them.  Its backward pass is ``jax.vjp`` of it, whose order is this
+    one's reversed: interleaved as well."""
     def small(h):
         if gate is None:
             return None, None
@@ -333,10 +369,11 @@ def _chunks(heads, gate=None):
         return (rate, bias, gate[0]), (z, scale, gate[1])
     opened = together(_open(h[0], h[1], h[3], h[4], small(h)[0])
                        for h in heads)
-    Ts = _inverses(tuple(c["L"] for c in opened))
+    Ls = tuple(c["L"] for c in opened)
+    Ts = _inverses(Ls) if kept is None else _kept_inverses(Ls, kept)
     return tuple(together(
         _close(c, T, h[2], h[4], h[5], small(h)[1])
-        for c, T, h in zip(opened, Ts, heads)))
+        for c, T, h in zip(opened, Ts, heads))), Ts
 
 
 def _head(ins, rows, j, h, kl, vl, S):
@@ -361,7 +398,7 @@ def _inputs(gate):
 def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
     import jax.experimental.pallas as pl
     n = _inputs(gate)
-    ins, (o_ref, last_ref, s0_ref, s_ref) = refs[:n], refs[n:]
+    ins, (o_ref, last_ref, s0_ref, t_ref, s_ref) = refs[:n], refs[n:]
     i = pl.program_id(2)
     lanes = head_lanes(hb, dk, dv)
 
@@ -373,11 +410,12 @@ def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
         rows = chunk_rows(j, C)
         for h in range(hb):
             s0_ref[h, j] = s_ref[h]
-        outs = _chunks(tuple(_head(ins, rows, j, h, kl, vl, s_ref[h])
-                             for h, (kl, vl) in enumerate(lanes)), gate)
-        for h, (o, S) in enumerate(outs):
+        outs, Ts = _chunks(tuple(_head(ins, rows, j, h, kl, vl, s_ref[h])
+                                 for h, (kl, vl) in enumerate(lanes)), gate)
+        for h, ((o, S), T) in enumerate(zip(outs, Ts)):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
+            t_ref[h, j] = T
     walk(nc, body)
 
     @pl.when(i == pl.num_programs(2) - 1)
@@ -388,9 +426,9 @@ def _fwd_kernel(*refs, nc, hb, dk, dv, gate):
 def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
     import jax.experimental.pallas as pl
     n = _inputs(gate)
-    ins, (s0_ref, do_ref, dlast_ref) = refs[:n], refs[n:n + 3]
+    ins, (s0_ref, t_ref, do_ref, dlast_ref) = refs[:n], refs[n:n + 4]
     (dq_ref, dk_ref, dv_ref, dg_ref, db_ref, *dsmall), ds_ref = (
-        refs[n + 3:-1], refs[-1])
+        refs[n + 4:-1], refs[-1])
     lanes = head_lanes(hb, dk, dv)
 
     @pl.when(pl.program_id(2) == 0)
@@ -402,9 +440,11 @@ def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
     def body(m):
         j = nc - 1 - m
         rows = chunk_rows(j, C)
-        _, pull = jax.vjp(functools.partial(_chunks, gate=gate), tuple(
-            _head(ins, rows, j, h, kl, vl, s0_ref[h, j])
-            for h, (kl, vl) in enumerate(lanes)))
+        kept = tuple(t_ref[h, j] for h in range(hb))
+        _, pull, _ = jax.vjp(
+            functools.partial(_chunks, gate=gate, kept=kept),
+            tuple(_head(ins, rows, j, h, kl, vl, s0_ref[h, j])
+                  for h, (kl, vl) in enumerate(lanes)), has_aux=True)
         (grads,) = pull(tuple(
             (do_ref[rows, vl].astype(_F32), ds_ref[h])
             for h, (_, vl) in enumerate(lanes)))
@@ -427,7 +467,8 @@ def _bwd_kernel(*refs, nc, hb, dk, dv, gate):
 def _plan(ops, gate, reverse):
     """Grid, the kernels' static sizes, their inputs with a block spec each,
     and the block specs by name of a ``[B, T, H d]`` array (``qk``, ``vo``),
-    beta, the kept states, a state and a small parameter's partial sums;
+    beta, the kept states, the kept inverses, a state and a small parameter's
+    partial sums;
     ``reverse``: the blocks of chunks from the last to the first.  ``ops``:
     ``q, k, v, g, beta``, or with a ``gate`` ``mixed, proj, beta, rate, bias,
     scale``: ``mixed [B, T, 3 H d]`` is then read as its three and ``proj
@@ -447,10 +488,12 @@ def _plan(ops, gate, reverse):
         lambda b, h, i: (b, at(i), window * (H // hb) + h))
     rows = pl.BlockSpec((None, hb, None, nc, C),
                         lambda b, h, i: (b, h, at(i), 0, 0))
+    kept = lambda rows, cols: pl.BlockSpec(
+        (None, hb, None, nc, rows, cols),
+        lambda b, h, i: (b, h, at(i), 0, 0, 0))
     names = dict(
-        qk=seq(dk), vo=seq(dv), rows=rows,
-        kept=pl.BlockSpec((None, hb, None, nc, dk, dv),
-                          lambda b, h, i: (b, h, at(i), 0, 0, 0)),
+        qk=seq(dk), vo=seq(dv), rows=rows, kept=kept(dk, dv),
+        inverse=kept(C, C),
         state=pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0)),
         sums=pl.BlockSpec((None, 1, hb * dk), lambda b, h, i: (b, 0, h)))
     if gate is None:
@@ -471,7 +514,8 @@ def _fwd_call(*ops, gate, interpret):
     """``q, k, g [B, T, H dk]``, ``v [B, T, H dv]``, ``beta [B, H, T / (n C),
     n, C]`` f32 (``n`` chunks a program), or with a ``gate`` the operands
     ``_plan`` names: ``(o [B, T, H dv], last state [B, H, dk, dv],
-    chunk-start states [B, H, T / (n C), n, dk, dv])``."""
+    chunk-start states [B, H, T / (n C), n, dk, dv], the chunks' inverses
+    [B, H, T / (n C), n, C, C] f32)``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     grid, dims, args, specs, at = _plan(ops, gate, False)
@@ -480,11 +524,12 @@ def _fwd_call(*ops, gate, interpret):
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **dims),
         name="hetu_kda_fwd", grid=grid, in_specs=specs,
-        out_specs=[at["vo"], at["state"], at["kept"]],
+        out_specs=[at["vo"], at["state"], at["kept"], at["inverse"]],
         out_shape=[jax.ShapeDtypeStruct(args[0].shape[:2] + (H * dv,),
                                         args[2].dtype),
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
-                   jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32)],
+                   jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32),
+                   jax.ShapeDtypeStruct((B, H, groups, nc, C, C), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
@@ -493,14 +538,14 @@ def _fwd_call(*ops, gate, interpret):
 
 @functools.partial(jax.jit, static_argnames=("gate", "interpret"))
 def _bwd_call(*ops, gate, interpret):
-    """``_fwd_call``'s operands, the kept states, ``do`` and the last
-    state's cotangent: ``dq, dk, dv, dg, dbeta`` and, with a ``gate`` (where
+    """``_fwd_call``'s operands, the kept states and inverses, ``do`` and the
+    last state's cotangent: ``dq, dk, dv, dg, dbeta`` and, with a ``gate`` (where
     they are ``dq~, dk~, dv, df``, all ``[B, T, H d]`` in the compute type),
     ``dz`` and the partial sums ``[B, 1, H d]`` f32 of ``rate``'s, ``bias``'s
     and, a head, ``scale``'s."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    *ops, states, do, dlast = ops
+    *ops, states, inverses, do, dlast = ops
     grid, dims, args, specs, at = _plan(ops, gate, True)
     qk, vo = at["qk"], at["vo"]
     dk, dv, H = dims["dk"], dims["dv"], states.shape[1]
@@ -511,7 +556,7 @@ def _bwd_call(*ops, gate, interpret):
     return pl.pallas_call(
         functools.partial(_bwd_kernel, **dims),
         name="hetu_kda_bwd", grid=grid,
-        in_specs=specs + [at["kept"], vo, at["state"]],
+        in_specs=specs + [at["kept"], at["inverse"], vo, at["state"]],
         out_specs=[qk, qk, vo, qk, at["rows"]] + (
             [] if gate is None else [vo] + [at["sums"]] * 3),
         out_shape=[like(q, dk), like(k, dk), like(v, dv),
@@ -521,7 +566,7 @@ def _bwd_call(*ops, gate, interpret):
         scratch_shapes=[pltpu.VMEM((dims["hb"], dk, dv), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(*args, states, do, dlast)
+    )(*args, states, inverses, do, dlast)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -530,12 +575,14 @@ def _rule(gate, *ops):
 
 
 def _rule_fwd(gate, *ops):
-    o, last, states = _fwd_call(*ops, gate=gate,
-                                interpret=dispatch.interpret())
-    return (o, last), ops + (states,)
+    dispatch.count_inverse("kda", "solved")
+    o, last, states, inverses = _fwd_call(*ops, gate=gate,
+                                          interpret=dispatch.interpret())
+    return (o, last), ops + (states, inverses)
 
 
 def _rule_bwd(gate, res, grads):
+    dispatch.count_inverse("kda", "kept")
     out = _bwd_call(*res, *grads, gate=gate, interpret=dispatch.interpret())
     if gate is None:
         return tuple(out)
@@ -588,8 +635,8 @@ def chunk_products(form):
     """The products of one chunk of one head (of 128 channels, bf16
     operands), traced and not run: ``{"fwd": counter, "bwd": counter}`` of
     the ``dot_general``s of ``_chunks`` and of ``jax.vjp`` of it as
-    ``_bwd_kernel`` takes it (the forward again, then its transposition),
-    each keyed ``(rows, contraction, columns)``.  ``form``: ``plain`` or
+    ``_bwd_kernel`` takes it (the forward again with the inverse kept, then
+    its transposition), each keyed ``(rows, contraction, columns)``.  ``form``: ``plain`` or
     ``in_place``.  The module's docstring has the table this is held to
     (``tests/test_kda_passes.py``)."""
     d = 128
@@ -601,13 +648,13 @@ def chunk_products(form):
         small = of(1, d, _F32)
         head = (wide,) * 4 + head[4:] + (wide, small, small, small)
         gate = (-5.0, 1e-6)
-    chunks = functools.partial(_chunks, gate=gate)
-
-    def pulled(heads, cotangents):
-        return jax.vjp(chunks, heads)[1](cotangents)
+    def pulled(heads, kept, cotangents):
+        chunks = functools.partial(_chunks, gate=gate, kept=kept)
+        return jax.vjp(chunks, heads, has_aux=True)[1](cotangents)
     traced = dict(
-        fwd=jax.make_jaxpr(chunks)((head,)),
-        bwd=jax.make_jaxpr(pulled)((head,), ((of(C, d, _F32), state),)))
+        fwd=jax.make_jaxpr(functools.partial(_chunks, gate=gate))((head,)),
+        bwd=jax.make_jaxpr(pulled)((head,), (of(C, C, _F32),),
+                                   ((of(C, d, _F32), state),)))
     return {kernel: _products(closed.jaxpr, collections.Counter())
             for kernel, closed in traced.items()}
 
@@ -638,8 +685,9 @@ def _count_entry(form):
             "hetu_kda_chunk_passes",
             "Passes of the matrix unit (one a product and 128 of its "
             "contraction) in one chunk of one head of the delta rule's "
-            "forward kernel and of its backward kernel (the forward again, "
-            "then its transposition), at the last call traced",
+            "forward kernel and of its backward kernel (the forward again "
+            "with the inverse kept, then its transposition), at the last "
+            "call traced",
             labels=("kernel",))
         for kernel, products in chunk_products(form).items():
             gauge.labels(kernel=kernel).set(passes(products))

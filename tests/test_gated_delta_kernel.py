@@ -21,6 +21,9 @@ from hetu_tpu.ops.gated_delta import (chunk_gated_delta_rule,
 from hetu_tpu.ops.pallas import common, dispatch, gated_delta as kernels
 
 D = 128                       # the published head size, keys and values
+#: the kept inverses of a layer of 32 heads over 8,192 positions, eight
+#: chunks a program, as an HLO shape
+KEPT = "1,32,16,8,64,64"
 
 
 def delta_inputs(T, B=1, H=1, dtype=jnp.float32, seed=0, rate=None):
@@ -284,8 +287,9 @@ def test_layer_step_compiles_for_v5e(v5e, gdn_choices, monkeypatch):
     """The Qwen3-Next cell's mixer (16 key heads, 32 value heads of 128,
     8,192 positions, bf16), forward and backward from the node's inputs:
     ``hetu_gdn_fwd`` and ``hetu_gdn_bwd`` and nothing else of the rule's: no
-    ``triangular_solve`` custom call, no ``while``, no ``[.., 64, 64]`` f32
-    array in HBM; the kernels read and write ``[1, 8192, 4096]`` in place."""
+    ``triangular_solve`` custom call, no ``while``, and of ``[.., 64, 64]``
+    f32 arrays in HBM the chunks' kept inverses alone (PR 66); the kernels
+    read and write ``[1, 8192, 4096]`` in place."""
     import re
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu.layers.gated_delta_net import _scan
@@ -310,15 +314,16 @@ def test_layer_step_compiles_for_v5e(v5e, gdn_choices, monkeypatch):
     assert all("bf16[1,8192,4096]" in ln for ln in kernels_)
     assert "triangular_solve" not in hlo.lower()
     assert not re.findall(r"\bwhile\(", hlo)
-    assert not re.findall(r" = f32\[[\d,]*64,64\]\S* ", hlo)
+    assert set(re.findall(r" = f32\[([\d,]*64,64)\]\S* ", hlo)) == {KEPT}
 
 
 def test_kda_layer_step_compiles_for_v5e(v5e, monkeypatch):
     """The Ling-3.0 cell's KDA mixer (32 heads of 128, 8,192 positions, bf16,
     a decay a channel in f32), forward and backward from the scan node's
     inputs: ``hetu_kda_fwd`` and ``hetu_kda_bwd`` and nothing else of the
-    rule's: no ``triangular_solve``, no ``while``, no ``[.., 64, 64]`` f32
-    array in HBM; the kernels write ``[1, 8192, 4096]`` as the output product
+    rule's: no ``triangular_solve``, no ``while``, of ``[.., 64, 64]`` f32
+    arrays in HBM the chunks' kept inverses alone; the kernels write ``[1,
+    8192, 4096]`` as the output product
     reads it (``hetu_gdn_*``'s helpers inside: the Qwen3-Next case above is
     what holds those to what they were; what the in-place entry reads and
     leaves out of HBM is held in ``tests/test_flash_attention.py``)."""
@@ -360,4 +365,4 @@ def test_kda_layer_step_compiles_for_v5e(v5e, monkeypatch):
     assert all("bf16[1,8192,4096]" in ln for ln in kernels_)
     assert "triangular_solve" not in hlo.lower()
     assert not re.findall(r"\bwhile\(", hlo)
-    assert not re.findall(r" = f32\[[\d,]*64,64\]\S* ", hlo)
+    assert set(re.findall(r" = f32\[([\d,]*64,64)\]\S* ", hlo)) == {KEPT}

@@ -224,13 +224,14 @@ def layer_arrays(seed, B, T, dtype, at_bound=False, H=HEADS):
             a_log, dt_bias, scale)
 
 
-def mixer_jnp(mixed, proj, beta_lin, a_log, dt_bias, scale, H=HEADS):
+def mixer_jnp(mixed, proj, beta_lin, a_log, dt_bias, scale, H=HEADS,
+              rule=kda.chunk_kda_jnp):
     """The layer's ``jax.numpy`` form between the convolution and the output
-    product: ``_scan`` around ``chunk_kda_jnp``, then ``_out``'s norm and
-    gate (its product taken with the identity)."""
+    product: ``_scan`` around ``rule`` (``chunk_kda_jnp``), then ``_out``'s
+    norm and gate (its product taken with the identity)."""
     from hetu_tpu.layers.kda import _out, _scan
     o = _scan(proj, mixed, beta_lin, a_log, dt_bias, scale, heads=H, d=D,
-              lower_bound=-5.0, eps=1e-6, rule=kda.chunk_kda_jnp)
+              lower_bound=-5.0, eps=1e-6, rule=rule)
     assert o.ndim == 4
     return _out(o, proj, scale, jnp.eye(H * D, dtype=o.dtype), eps=1e-6)
 
