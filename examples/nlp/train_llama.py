@@ -61,6 +61,13 @@ Usage: python examples/nlp/train_llama.py [--model llama-7b --layers 2]
            that hands out its K and V, a Gated Memory Unit and a
            cross-attention layer that read them; an eighth of the tied
            vocabulary)
+       python examples/nlp/train_llama.py --model evabyte --layers 4 \
+           --seq-len 8192 --batch-size 1     (EvaByte at one pipeline stage of
+           eight: four layers of EVA attention (exact softmax inside an
+           aligned window of 2,048 bytes, one learned summary a chunk of 16
+           for everything before it), eight next-byte heads over the 320
+           rows, whole layers recomputed; labels are [B, S, 8], head i's the
+           bytes shifted by 1 + i)
 """
 
 import os
@@ -88,7 +95,9 @@ from hetu_tpu.models import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS,
                              Zaya1ForCausalLM, ZAYA1_CONFIGS, SdarMoeConfig,
                              SdarMoeForCausalLM, SDAR_CONFIGS,
                              Phi4FlashConfig, Phi4FlashForCausalLM,
-                             PHI4FLASH_CONFIGS, record_exit_shares, load_hf_llama_weights,
+                             PHI4FLASH_CONFIGS, EvaByteConfig,
+                             EvaByteForCausalLM, EVABYTE_CONFIGS,
+                             record_exit_shares, load_hf_llama_weights,
                              load_hf_granite_hybrid_weights)
 
 
@@ -101,7 +110,8 @@ def main():
                              + list(OURO_CONFIGS) + list(LAGUNA_CONFIGS)
                              + list(XING4_CONFIGS) + list(ZAYA1_CONFIGS)
                              + list(SDAR_CONFIGS)
-                             + list(PHI4FLASH_CONFIGS)))
+                             + list(PHI4FLASH_CONFIGS)
+                             + list(EVABYTE_CONFIGS)))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--layers", type=int, default=0,
@@ -122,8 +132,11 @@ def main():
                          "the first of --layers consecutive layers (a "
                          "layer's kind follows from its index)")
     ap.add_argument("--heads", default=None, metavar="QUERY:KEY",
-                    help="phi-4-mini-flash-reasoning: query and key heads "
-                         "(beside --hidden at a toy size)")
+                    help="phi-4-mini-flash-reasoning, evabyte: query and key "
+                         "heads (beside --hidden at a toy size)")
+    ap.add_argument("--window", default=None, metavar="WINDOW:CHUNK",
+                    help="evabyte: the keys of an aligned window and of a "
+                         "chunk (at a toy size)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--tp", type=int, default=1)
@@ -163,6 +176,9 @@ def main():
               (PHI4FLASH_CONFIGS, Phi4FlashConfig, Phi4FlashForCausalLM,
                "num_hidden_layers", "intermediate_size")
               if args.model in PHI4FLASH_CONFIGS else
+              (EVABYTE_CONFIGS, EvaByteConfig, EvaByteForCausalLM,
+               "num_hidden_layers", "intermediate_size")
+              if args.model in EVABYTE_CONFIGS else
               (LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
                "num_layers", "intermediate_size"))
     configs, config_cls, model_cls, depth, width = family
@@ -188,9 +204,14 @@ def main():
         # where its two decoders meet
         base.update(first_layer_index=args.first_layer, remat="layer",
                     published_layers=Phi4FlashConfig().num_layers)
-        if args.heads:
-            base["num_attention_heads"], base["num_key_value_heads"] = (
-                int(n) for n in args.heads.split(":"))
+    if args.model in EVABYTE_CONFIGS:
+        base["remat"] = "layer"
+        if args.window:
+            base["window_size"], base["chunk_size"] = (
+                int(n) for n in args.window.split(":"))
+    if args.heads and args.model in (*PHI4FLASH_CONFIGS, *EVABYTE_CONFIGS):
+        base["num_attention_heads"], base["num_key_value_heads"] = (
+            int(n) for n in args.heads.split(":"))
     diffusion = args.model in SDAR_CONFIGS
     if diffusion and args.vocab:
         # the mask token is an ordinary row of the slice: its last
@@ -203,7 +224,10 @@ def main():
     # pass and weighs each masked position's loss
     ids = ht.placeholder_op("ids", (B, 2 * S if diffusion else S),
                             dtype=np.int32)
-    labels = ht.placeholder_op("labels", (B, S), dtype=np.int32)
+    # a byte-level model with several heads labels each position once a head
+    P = getattr(c, "num_pred_heads", 0)
+    labels = ht.placeholder_op("labels", (B, S, P) if P else (B, S),
+                               dtype=np.int32)
     weights = (ht.placeholder_op("weights", (B, S), dtype=np.float32)
                if diffusion else None)
     model = model_cls(c, pipeline_stages=args.pp or None)
@@ -244,6 +268,10 @@ def main():
             tok = tok + (tok >= c.mask_token_id)    # never the mask token
             feed = dict(zip((ids, labels, weights), ht.block_diffusion_noise(
                 tok, c.block_length, c.mask_token_id, rng)))
+        elif P:
+            tok = rng.integers(0, c.vocab_size, (B, S + P))
+            feed = {ids: tok[:, :S], labels: np.stack(
+                [tok[:, 1 + i:1 + i + S] for i in range(P)], axis=-1)}
         else:
             tok = rng.integers(0, c.vocab_size, (B, S + 1))
             feed = {ids: tok[:, :-1], labels: tok[:, 1:]}

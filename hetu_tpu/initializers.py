@@ -56,12 +56,14 @@ class NormalInit(Initializer):
 
 
 class TruncatedNormalInit(Initializer):
-    def __init__(self, mean=0.0, stddev=1.0):
-        self.mean, self.stddev = mean, stddev
+    """Drawn again outside ``cutoff`` deviations of the mean."""
+
+    def __init__(self, mean=0.0, stddev=1.0, cutoff=2.0):
+        self.mean, self.stddev, self.cutoff = mean, stddev, cutoff
 
     def __call__(self, key, shape, dtype=jnp.float32):
         return self.mean + self.stddev * jax.random.truncated_normal(
-            key, -2.0, 2.0, shape, dtype=dtype)
+            key, -self.cutoff, self.cutoff, shape, dtype=dtype)
 
 
 def _fans(shape):
@@ -135,7 +137,7 @@ def ones(): return OnesInit()
 def constant(c=0.0): return ConstantInit(c)
 def uniform(low=-1.0, high=1.0): return UniformInit(low, high)
 def normal(mean=0.0, stddev=1.0): return NormalInit(mean, stddev)
-def truncated_normal(mean=0.0, stddev=1.0): return TruncatedNormalInit(mean, stddev)
+def truncated_normal(mean=0.0, stddev=1.0, cutoff=2.0): return TruncatedNormalInit(mean, stddev, cutoff)
 def xavier_normal(gain=1.0): return XavierNormalInit(gain)
 def xavier_uniform(gain=1.0): return XavierUniformInit(gain)
 def he_normal(): return HeNormalInit()

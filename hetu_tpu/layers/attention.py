@@ -62,7 +62,7 @@ from ..ops import (array_reshape_op, transpose_op, head_split_linear_op,
                    split_op, sigmoid_op)
 from ..ops.base import ScopedOp, simple_op
 from ..ops.attention import scaled_dot_product_attention_op
-from ..ops.pallas.common import PARTS, parts
+from ..ops.pallas.common import spread as _spread, widen as _widen
 from ..ops.pallas.flash_attention import pair_view_unsupported
 from ..ops.rotary import (RopeTables, rotary_embedding_op, rotary_pair_op,
                           qk_norm_rotary_pair_op, repeat_kv_op, alibi_bias_op)
@@ -74,25 +74,6 @@ def _gate_heads(ctx_, gate):
     o = (ctx_.transpose(0, 2, 1, 3).astype(jnp.float32)
          * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None])
     return o.astype(ctx_.dtype).reshape(o.shape[:2] + (-1,))
-
-
-def _spread(heads, d, times=1, dtype=jnp.bfloat16):
-    """``[times * heads, heads * d]`` of 0 and 1: row ``i`` holds 1 on the
-    ``d`` lanes of head ``i % heads``."""
-    return (jnp.arange(heads * d)[None, :] // d
-            == jnp.arange(times * heads)[:, None] % heads).astype(dtype)
-
-
-def _widen(sig, d):
-    """``sig [B, S, H]`` f32 -> f32 ``[B, S, H d]``, a head's number on each of
-    its ``d`` lanes, EXACTLY: the three bf16 parts of ``sig`` (all 24 bits of
-    it) side by side times ``_spread``, f32 sums of at most three terms that
-    are bits of one number.  A product on the matrix unit, because the other
-    way, ``sig[..., None]`` on an ``[.., H, d]`` view, is a pass over HBM in
-    f32 (PRs 41 and 48)."""
-    return jnp.matmul(jnp.concatenate(parts(sig), axis=-1),
-                      _spread(sig.shape[-1], d, PARTS),
-                      preferred_element_type=jnp.float32)
 
 
 def _sigmoid_wide(ctx_, gate):

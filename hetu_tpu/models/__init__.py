@@ -30,6 +30,8 @@ from .sdar import (SdarMoeConfig, SdarMoeModel, SdarMoeForCausalLM,
 from .phi4flash import (Phi4FlashConfig, Phi4FlashDecoderLayer,
                         Phi4FlashModel, Phi4FlashForCausalLM,
                         PHI4FLASH_CONFIGS)
+from .evabyte import (EvaByteConfig, EvaByteDecoderLayer, EvaByteModel,
+                      EvaByteForCausalLM, EVABYTE_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate
 from .hf_import import (load_hf_bert_weights, load_hf_gpt2_weights,
                         load_hf_llama_weights, export_hf_llama_weights,

@@ -121,7 +121,15 @@ class ScaledDotProductAttentionOp(Op):
     ``hetu_flash_fwd_bd`` / ``hetu_flash_bwd_bd`` and walk the tiles that hold
     a visible pair.  The node's type is this one's (the flash passes' events
     are counted on the nodes of this type: ``chipbench/tests/
-    test_attention_yardstick.py``); its ``kind`` says which mask it has."""
+    test_attention_yardstick.py``); its ``kind`` says which mask it has.
+
+    ``eva=(window, chunk)`` with ``summaries=(ks, vs)`` (causal, ``[B, S,
+    H*D]`` with ``num_heads``, no mask or dropout): EVA attention
+    (``ops/eva.py``): the keys of a position's own aligned window exactly and
+    one summary a chunk for the windows before it under one softmax; the
+    kernels then go by ``hetu_eva_fwd`` / ``hetu_eva_bwd``.  The node's type is
+    this one's too (a model whose every layer is of this kind has these for
+    its attention passes); its ``kind`` is ``eva``."""
 
     #: the keys a position sees; None: all (that ``causal`` leaves)
     window = None
@@ -131,6 +139,9 @@ class ScaledDotProductAttentionOp(Op):
     #: value (``layers/attention.py DifferentialAttention``); ``cross`` with
     #: it: the keys and values are another layer's nodes
     form = None
+    #: ``(window, chunk)`` of EVA attention; the summaries are then the node's
+    #: last two inputs
+    eva = None
 
     @property
     def kind(self):
@@ -140,6 +151,8 @@ class ScaledDotProductAttentionOp(Op):
         layer's): the label of ``hetu_attn_layers_total``."""
         if self.block_diffusion is not None:
             return "block_diffusion"
+        if self.eva is not None:
+            return "eva"
         kind = "full" if self.window is None else "window"
         if self.form is None:
             return kind
@@ -147,9 +160,16 @@ class ScaledDotProductAttentionOp(Op):
 
     def __init__(self, q, k, v, mask=None, causal=False, scale=None,
                  dropout_keep=1.0, num_heads=None, block_diffusion=None,
-                 form=None, name=None):
+                 form=None, eva=None, summaries=(), name=None):
         inputs = [q, k, v] + ([mask] if mask is not None else [])
-        super().__init__(*inputs, name=name)
+        super().__init__(*inputs, *summaries, name=name)
+        if eva is not None:
+            assert (causal and mask is None and dropout_keep >= 1.0
+                    and num_heads and self.window is None
+                    and block_diffusion is None and len(summaries) == 2), (
+                "EVA attention is causal on [B, S, H*D], with its two "
+                "summaries and no key mask, dropout or window beside it")
+            self.eva = tuple(map(int, eva))
         if form is not None:
             assert form in ("differential", "cross"), form
             self.form = form
@@ -165,7 +185,9 @@ class ScaledDotProductAttentionOp(Op):
             "leaves; window: the last `window` keys; block_diffusion: a clean "
             "and a noised copy under the block-diffusion mask; differential_"
             "full / _window / _cross: the halves of query pairs as heads on "
-            "one value, on the layer's own keys or on another layer's)",
+            "one value, on the layer's own keys or on another layer's; eva: "
+            "exact keys inside an aligned window and one summary a chunk "
+            "before it)",
             labels=("kind",),
         ).labels(kind=self.kind).inc()
         self.has_mask = mask is not None
@@ -182,6 +204,12 @@ class ScaledDotProductAttentionOp(Op):
         q, k, v = input_vals[:3]
         mask = input_vals[3] if self.has_mask else None
         heads = self.num_heads
+        if self.eva is not None:
+            from .eva import eva_attention
+            return eva_attention(q, k, v, *input_vals[-2:],
+                                 window=self.eva[0], chunk=self.eva[1],
+                                 num_heads=heads, scale=self.scale,
+                                 mesh=ctx.mesh)
         if heads is None or q.ndim == 4:
             return self._attend(q, k, v, mask, ctx, None)
         if self._stays_in_place(q, k, v, mask, ctx):
@@ -320,11 +348,14 @@ def scaled_dot_product_attention_op(q, k, v, mask=None, causal=False,
                                     scale=None, dropout_keep=1.0,
                                     num_heads=None, window=None,
                                     block_diffusion=None, form=None,
-                                    name=None):
+                                    eva=None, summaries=(), name=None):
     kw = dict(mask=mask, causal=causal, scale=scale,
               dropout_keep=dropout_keep, num_heads=num_heads, name=name)
     if form is not None:
         kw["form"] = form
+    if eva is not None:
+        assert window is None, "a window or summaries behind one, not both"
+        kw.update(eva=eva, summaries=summaries)
     if window is not None:
         assert block_diffusion is None, "a window or the block mask, not both"
         return WindowAttentionOp(q, k, v, window, **kw)

@@ -63,6 +63,25 @@ def parts(x):
     return out + [x.astype(_BF16)]
 
 
+def spread(heads, d, times=1, dtype=_BF16):
+    """``[times * heads, heads * d]`` of 0 and 1: row ``i`` holds 1 on the
+    ``d`` lanes of head ``i % heads``."""
+    return (jnp.arange(heads * d)[None, :] // d
+            == jnp.arange(times * heads)[:, None] % heads).astype(dtype)
+
+
+def widen(x, d):
+    """``x [B, S, H]`` f32 -> f32 ``[B, S, H d]``, a head's number on each of
+    its ``d`` lanes, EXACTLY: the three bf16 parts of ``x`` (all 24 bits of
+    it) side by side times ``spread``, f32 sums of at most three terms that
+    are bits of one number.  A product on the matrix unit, because the other
+    way, ``x[..., None]`` on an ``[.., H, d]`` view, is a pass over HBM in
+    f32 (PRs 41 and 48)."""
+    return jnp.matmul(jnp.concatenate(parts(x), axis=-1),
+                      spread(x.shape[-1], d, PARTS),
+                      preferred_element_type=_F32)
+
+
 def dot32(a, b, dims):
     """A product with f32 operands on the matrix unit, at f32 precision: bf16
     passes over the pairs of parts whose indices add up to less than

@@ -56,6 +56,14 @@ differ in their width alone.
             exactly.  The 1/keep factor is applied to the (rows, g*D)
             results, not to the (block_q, block_k) tiles.
 
+Three static plans beside the causal and the full walk, each the same two
+bodies under other loop bounds: ``window`` (a band of keys behind a position,
+``hetu_swa_*``), ``bd`` (the block-diffusion mask over a clean and a noised
+copy, ``hetu_flash_*_bd``) and ``eva`` (exact keys inside an aligned window
+and one summary a chunk of keys for every window before it, a second pooled
+operand whose gradients the backward kernel hands out, ``hetu_eva_*``;
+``ops/eva.py``).
+
 Supported: additive key mask [B, 1, 1, S] (BERT padding masks), causal,
 any head dim ≤ 512 and any seq ≥ 128: the wrapper pads seq up to a block
 multiple with -inf key-column masking and, in the 4-D layout, zero-pads d
@@ -91,7 +99,7 @@ _LANES = 128
 
 
 def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None,
-                block_diffusion=None):
+                block_diffusion=None, eva=None):
     """Why the kernel cannot take these ``[B, H, S, D]`` operands, or None
     when it can.  Callers that fall back to the jnp composition record this
     string (ops/pallas/dispatch.py).  ``window``: the keys a position sees,
@@ -99,9 +107,28 @@ def unsupported(q, k, v, mask=None, dropout_keep=1.0, window=None,
     built.  ``block_diffusion``: the block length of the block-diffusion mask
     (``_fwd_kernel``'s ``bd``); with it neither dropout nor a window nor a key
     mask is built, a block is a power of two (its index is a shift) of at
-    most half the smallest tile, and a half is at least one tile of rows."""
+    most half the smallest tile, and a half is at least one tile of rows.
+    ``eva = (window, chunk)`` (``_fwd_kernel``'s ``eva``): exact keys inside an
+    aligned window, one summary a chunk for the windows before it; with it no
+    dropout, key mask or grouped queries are built, the window is whole tiles
+    of 128 rows, a chunk divides 128 and a window's summaries are whole
+    packed sublane tiles (16 rows)."""
     if window is not None and dropout_keep < 1.0:
         return "window_with_dropout"
+    if eva is not None:
+        for reason, holds in (
+                ("eva_with_dropout", dropout_keep < 1.0),
+                ("eva_with_mask", mask is not None),
+                ("eva_with_window", window is not None
+                 or block_diffusion is not None),
+                ("eva_window_not_128_aligned", eva[0] % _LANES),
+                ("eva_chunk_not_dividing_128", _LANES % eva[1]),
+                ("eva_window_summaries_not_16_aligned",
+                 eva[0] // eva[1] % 16),
+                ("eva_grouped_queries",
+                 q.ndim == 4 and k.ndim == 4 and k.shape[1] != q.shape[1])):
+            if holds:
+                return reason
     if block_diffusion is not None:
         for reason, holds in (
                 ("block_diffusion_with_dropout", dropout_keep < 1.0),
@@ -370,8 +397,17 @@ def _bd_tiles(nh):
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
                 o_ref, lse_ref, *, scale, causal, block_k, q_len, k_len,
                 keep_prob, heads, group, empty_lse_neg=False, window=None,
-                back=0, bd=None):
-    """``bd = (K, nh)`` (static; not causal, no mask, no offsets): the
+                back=0, bd=None, eva=None, pooled=None):
+    """``eva = (W, n, rows)`` (static; causal, square tiles, no mask, offsets,
+    dropout or window) with ``pooled = (ks_ref, vs_ref)``: windows of ``W``
+    keys aligned at multiples of ``W``, ``n`` summaries a window (one a chunk
+    of ``W / n`` keys), read ``rows`` at a time.  Query tile ``qi`` of window
+    ``w`` walks the summaries of the windows before ``w`` whole, then its own
+    window's key tiles up to the diagonal whole and the diagonal tile under
+    its edge, all under ONE running maximum and sum: no tile of the walk is
+    masked but the diagonal one (``_eva_tiles``).
+
+    ``bd = (K, nh)`` (static; not causal, no mask, no offsets): the
     block-diffusion mask over ``2 nh`` square tiles of rows, a clean copy of a
     sequence in the first ``nh`` and its noised copy in the rest, blocks of
     ``K`` tokens (``b(i) = i // K`` on a token's index in its half): clean on
@@ -420,16 +456,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
         j_in = jax.lax.max(j_lo, -((window - (qi + 1) * bq) // block_k))
         held_from = jax.lax.max(0, qi * bq - back) // block_k
 
-    def make_body(q, h, masked, see=None):
+    def make_body(q, h, masked, see=None, summaries=0):
+        """``summaries``: the rows of a tile of ``pooled``'s refs, which the
+        body then reads in place of K's and V's."""
         bh = b * heads + hg * group + h
 
         def body(j, carry):
             m, l, acc = carry
             def at():   # block j's first row in k_ref / v_ref
                 return (j if window is None else j - held_from) * block_k
-            kb = k_ref[0, pl.ds(at(), block_k), :]
-            vb = _only_head(v_ref[0, pl.ds(at(), block_k), :],
-                            h, dim, group)
+            if summaries:
+                rows = pl.ds(j * summaries, summaries)
+                kb = pooled[0][0, rows, :]
+                vb = _only_head(pooled[1][0, rows, :], h, dim, group)
+            else:
+                kb = k_ref[0, pl.ds(at(), block_k), :]
+                vb = _only_head(v_ref[0, pl.ds(at(), block_k), :],
+                                h, dim, group)
             # scores tracked in BASE-2 units (s2 = s * log2(e)): exp2 is
             # the VPU's native exponential; lse converts back to natural
             # units at the end so the backward's exp(s - lse) contract is
@@ -487,6 +530,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, offs_ref,
             carry = jax.lax.fori_loop(0, t, make_body(q, h, False),
                                       (m0, l0, acc0))
             m, l, acc = jax.lax.fori_loop(0, 1 + noised, under_edge, carry)
+        elif eva is not None:
+            # the summaries of the windows before this one, then this
+            # window's key tiles before the diagonal, then the diagonal's
+            span, n, rows = eva
+            w = (qi * bq) // span
+            carry = jax.lax.fori_loop(
+                0, w * (n // rows),
+                make_body(q, h, False, summaries=rows), (m0, l0, acc0))
+            carry = jax.lax.fori_loop(w * (span // block_k), qi,
+                                      make_body(q, h, False), carry)
+            m, l, acc = make_body(q, h, True)(qi, carry)
         elif window is not None:
             # [j_lo, inside): the window's edge crosses; [inside, diagonal):
             # every pair is seen; [diagonal, nk_causal): the diagonal crosses
@@ -578,18 +632,27 @@ def _compiler_params(resident_bytes):
         min(100 << 20, (24 << 20) + 2 * resident_bytes)))
 
 
-def _kernel_name(which, window, bd):
-    """The pass's kernel: a name of its own with a window (its events are not
-    the flash passes'), the pass's name and ``_bd`` under the block-diffusion
-    mask (its events are)."""
+def _kernel_name(which, window, bd, eva=None):
+    """The pass's kernel: a name of its own with a window or with summaries
+    (their events are not the flash passes'), the pass's name and ``_bd``
+    under the block-diffusion mask (its events are)."""
+    if eva is not None:
+        return f"hetu_eva_{which}"
     if window is not None:
         return f"hetu_swa_{which}"
     return f"hetu_flash_{which}" + ("" if bd is None else "_bd")
 
 
+def _eva_fwd_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, *rest, **consts):
+    """``_fwd_kernel`` with the summaries' two refs behind K's and V's."""
+    _fwd_kernel(q_ref, k_ref, v_ref, *rest, pooled=(ks_ref, vs_ref),
+                **consts)
+
+
 def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
          block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-         empty_lse_neg=False, num_heads=None, window=None, bd=None):
+         empty_lse_neg=False, num_heads=None, window=None, bd=None,
+         eva=None, pooled=()):
     """q: [b,h,sq,d]; k,v: [b,h,sk,d] (sq != sk in the blockwise/ring path,
     where ``offsets`` = int32[2] global [q_off, k_off]), or all three
     [b,s,h*d] with ``num_heads``.  Returns (o, lse [b, h/g, g, sq]).
@@ -601,7 +664,11 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
 
     ``bd`` (``_fwd_kernel``; self-attention over both halves, square tiles,
     no mask, dropout, offsets or window): the same body under the name
-    ``hetu_flash_fwd_bd``."""
+    ``hetu_flash_fwd_bd``.
+
+    ``eva`` (``_fwd_kernel``) with ``pooled = (ks, vs)``, the chunks'
+    summaries in the layout of k and v, whole in a program: the same body
+    under the name ``hetu_eva_fwd``."""
     w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     q_spec = pl.BlockSpec((1, block_q, w.width), w.rows(lambda t: t))
@@ -619,6 +686,17 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
                 and block_q == block_k and sq == 2 * bd[1] * block_q), (
             bd, sq, sk, block_q, block_k)
         consts = dict(bd=bd)
+    pooled_specs = []
+    if eva is not None:
+        assert (causal and window is None and bd is None and offsets is None
+                and mask is None and keep_prob >= 1.0 and sq == sk
+                and block_q == block_k and w.rep == 1
+                and eva[0] % block_k == 0 and eva[1] % eva[2] == 0), (
+            eva, sq, sk, block_q, block_k)
+        consts = dict(eva=eva)
+        pooled_specs = [pl.BlockSpec((1, w.seq(x), x_walk.width),
+                                     w.rows(lambda t: 0))
+                        for x, x_walk in zip(pooled, (w, wv))]
     if held < sk:
         # addressed by element, not by block (Mosaic: all dimensions or
         # none): the band starts where it starts
@@ -636,7 +714,8 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
         k_spec = pl.BlockSpec((1, sk, w.width), w.kv_rows(lambda t: 0))
         v_spec = pl.BlockSpec((1, sk, wv.width), w.kv_rows(lambda t: 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
-    kern = _make_kern(_fwd_kernel, 3, mask is not None, keep_prob < 1.0,
+    kern = _make_kern(_fwd_kernel if eva is None else _eva_fwd_kernel,
+                      3 + len(pooled), mask is not None, keep_prob < 1.0,
                       offsets is not None,
                       scale=scale, causal=causal, block_k=block_k,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
@@ -646,10 +725,10 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
     item = q.dtype.itemsize
     o, lse = pl.pallas_call(
         kern,
-        name=_kernel_name("fwd", window, bd),
+        name=_kernel_name("fwd", window, bd, eva),
         interpret=interpret(),
         grid=(w.batch, groups, sq // block_q),
-        in_specs=[q_spec, k_spec, v_spec] + extra_specs,
+        in_specs=[q_spec, k_spec, v_spec] + pooled_specs + extra_specs,
         out_specs=[
             o_spec,
             pl.BlockSpec((1, 1, w.group, block_q),
@@ -663,8 +742,10 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
                                  jnp.float32),
         ],
         compiler_params=_compiler_params(
-            (block_q + held) * (w.width + wv.width) * item),
-    )(w.flat(q), w.flat(k), wv.flat(v), *extra_args)
+            (block_q + held + sum(w.seq(x) for x in pooled[:1]))
+            * (w.width + wv.width) * item),
+    )(w.flat(q), w.flat(k), wv.flat(v),
+      *(x_walk.flat(x) for x, x_walk in zip(pooled, (w, wv))), *extra_args)
     return wv.unflat(o), lse
 
 
@@ -673,9 +754,15 @@ def _fwd(q, k, v, mask, causal, scale, keep_prob=1.0, seed=None,
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 seed_ref, offs_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
                 scale, causal, block_q, q_len, k_len, keep_prob, heads,
-                group, window=None, bd=None):
+                group, window=None, bd=None, eva=None, pooled=None):
     """One key block of one head group: every tile (query block i, this
-    key block) is formed once and feeds dV, dK and dQ[i].  ``window``
+    key block) is formed once and feeds dV, dK and dQ[i].  ``eva``
+    (``_fwd_kernel``; the caller passes ``causal`` False and the diagonal
+    tile gets its edge here) with ``pooled = (ks_ref, vs_ref, dks_ref,
+    dvs_ref)``: the grid's last axis holds the key tiles and BEHIND them the
+    summaries' tiles; a key tile walks its own query tile under the edge and
+    the rest of its window whole, a summaries' tile the query tiles of every
+    later window whole, and writes ``d k^`` and ``d v^``.  ``window``
     (``_fwd_kernel``): the query loop ends with the last block that holds a
     row which sees one of these keys.  ``bd`` (``_fwd_kernel``): a clean key
     tile ``t`` walks the query tiles ``t`` of both halves under their edges
@@ -684,6 +771,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
     bk, width = k_ref.shape[1], k_ref.shape[2]
     dim = width // group
     nq, nk = q_len // block_q, k_len // bk
+    if eva is not None:
+        return _eva_bwd_tiles(
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref,
+            dv_ref, dq_acc, pooled, scale=scale, block_q=block_q, nq=nq,
+            nk=nk, group=group, eva=eva)
     k = k_ref[0]
     v = v_ref[0]
     k_heads = [_only_head(k, h, dim, group) for h in range(group)]
@@ -790,12 +882,100 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
         dq_ref[0] = (dq_acc[...] * (scale / keep_prob)).astype(dq_ref.dtype)
 
 
+def _eva_bwd_tiles(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+                   dk_ref, dv_ref, dq_acc, pooled, *, scale, block_q, nq, nk,
+                   group, eva):
+    """``_bwd_kernel`` under ``eva``: program ``kj < nk`` is key tile ``kj``,
+    program ``nk + t`` the summaries' tile ``t``.  The transpose of the
+    forward walk: what a tile of keys or of summaries feeds is dQ of the query
+    tiles that walked it."""
+    kj = pl.program_id(2)
+    width = k_ref.shape[2]
+    dim = width // group
+    span, n, rows = eva
+    ks_ref, vs_ref, dks_ref, dvs_ref = pooled
+    last = pl.num_programs(2) - 1
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def walk(k, v, first, stop, edge):
+        """(dk, dv) of the keys ``k``, ``v`` over the query tiles ``[first,
+        stop)``, the first of them under the diagonal's edge where ``edge``."""
+        k_heads = [_only_head(k, h, dim, group) for h in range(group)]
+        cols = k.shape[0]
+
+        def body(i, carry, see=None):
+            dk, dv = carry
+            at = pl.ds(i * block_q, block_q)
+            qb, dob = q_ref[0, at, :], do_ref[0, at, :]
+            od = dob.astype(jnp.float32) * o_ref[0, at, :].astype(jnp.float32)
+            dq = jnp.zeros((block_q, width), jnp.float32)
+            for h in range(group):
+                qh = _only_head(qb, h, dim, group)
+                doh = _only_head(dob, h, dim, group)
+                dsum = jnp.sum(_only_head(od, h, dim, group), axis=1)
+                s = _nt(qh, k) * (scale * _LOG2E)
+                if see is not None:
+                    s = jnp.where(see, s, _NEG_INF)
+                p = jnp.exp2(s - (lse_ref[0, 0, h, at] * _LOG2E)[:, None])
+                dp = _nt(doh, v)
+                ds = (p * (dp - dsum[:, None])).astype(qb.dtype)
+                dv = dv + _tn(p.astype(dob.dtype), doh)
+                dk = dk + _tn(ds, qh)
+                dq = dq + _nn(ds, k_heads[h])
+            dq_acc[at, :] += dq
+            return dk, dv
+
+        zeros = (jnp.zeros((cols, width), jnp.float32),
+                 jnp.zeros((cols, v.shape[1]), jnp.float32))
+        if edge:
+            seen = (jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 0)
+                    >= jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 1))
+            zeros = body(first, zeros, seen)
+            first = first + 1
+        return jax.lax.fori_loop(first, stop, body, zeros)
+
+    @pl.when(kj < nk)
+    def _():
+        # the key tile's own query tile, then the rest of its window
+        stop = jax.lax.min(nq, ((kj * block_q) // span + 1)
+                           * (span // block_q))
+        dk, dv = walk(k_ref[0], v_ref[0], kj, stop, True)
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kj >= nk)
+    def _():
+        # the window these summaries are of; every query tile behind it
+        first = (((kj - nk) * rows) // n + 1) * (span // block_q)
+        dk, dv = walk(ks_ref[0], vs_ref[0], first, nq, False)
+        dks_ref[0] = (dk * scale).astype(dks_ref.dtype)
+        dvs_ref[0] = dv.astype(dvs_ref.dtype)
+
+    @pl.when(kj == last)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _eva_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, ks_ref,
+                    vs_ref, mask_ref, seed_ref, offs_ref, dq_ref, dk_ref,
+                    dv_ref, dks_ref, dvs_ref, dq_acc, **consts):
+    """``_bwd_kernel`` with the summaries' refs behind the inputs and their
+    gradients' behind the outputs."""
+    _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
+                seed_ref, offs_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                pooled=(ks_ref, vs_ref, dks_ref, dvs_ref), **consts)
+
+
 def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
               block_q=_BLOCK_Q, block_k=_BLOCK_K, offsets=None,
-              num_heads=None, window=None, bd=None):
+              num_heads=None, window=None, bd=None, eva=None, pooled=()):
     """(dq, dk, dv) in the operands' layout; ``lse`` as ``_fwd`` returns
     it.  With ``window`` the kernel is ``hetu_swa_bwd``, with ``bd``
-    ``hetu_flash_bwd_bd``."""
+    ``hetu_flash_bwd_bd``, with ``eva`` and ``pooled = (ks, vs)``
+    ``hetu_eva_bwd``, which hands ``(dq, dk, dv, dks, dvs)`` out."""
     w, wv = _walks(q, k, v, num_heads)
     sq, sk = w.seq(q), w.seq(k)
     whole_q = pl.BlockSpec((1, sq, w.width), w.rows(lambda t: 0))
@@ -803,38 +983,62 @@ def _bwd_impl(q, k, v, mask, o, lse, dout, causal, scale, keep_prob, seed,
     # what is read of K and V lies at the key head, what is written of dK and
     # dV at the query head: with ``rep`` > 1 the two results are a QUERY head
     # wide and ``_flash_bwd`` sums each group
-    k_spec, v_spec = (pl.BlockSpec((1, block_k, x.width), w.rows(lambda t: t))
+    def tile(t):
+        return t
+    tiles, pooled_specs, pooled_shapes = sk // block_k, [], []
+    if eva is not None:
+        assert (block_q == block_k and w.rep == 1 and offsets is None
+                and mask is None and keep_prob >= 1.0), (eva, block_q, block_k)
+        # the summaries' tiles stand behind the keys' on the grid's last axis:
+        # a program of either kind leaves the other kind's blocks where they
+        # were (an output block is written back when its index changes)
+        nk, rows = tiles, eva[2]
+
+        def tile(t):
+            return jnp.minimum(t, nk - 1)
+        pooled_specs = [pl.BlockSpec((1, rows, x.width), w.rows(
+            lambda t: jnp.maximum(t - nk, 0))) for x in (w, wv)]
+        pooled_shapes = [jax.ShapeDtypeStruct(x_walk.flat(x).shape, x.dtype)
+                         for x, x_walk in zip(pooled, (w, wv))]
+        tiles += w.seq(pooled[0]) // rows
+        causal = False
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, x.width), w.rows(tile))
                       for x in (w, wv))
     k_read, v_read = (pl.BlockSpec((1, block_k, x.width),
-                                   w.kv_rows(lambda t: t)) for x in (w, wv))
+                                   w.kv_rows(tile)) for x in (w, wv))
     lse_spec = pl.BlockSpec((1, 1, w.group, sq),
                             lambda b, hg, t: (b, hg, 0, 0))
     extra_args, extra_specs = _extras(w, mask, keep_prob, seed, offsets, sk)
-    kern = _make_kern(_bwd_kernel, 6, mask is not None, keep_prob < 1.0,
+    kern = _make_kern(_bwd_kernel if eva is None else _eva_bwd_kernel,
+                      6 + len(pooled), mask is not None, keep_prob < 1.0,
                       offsets is not None,
                       scale=scale, causal=causal, block_q=block_q,
                       q_len=sq, k_len=sk, keep_prob=keep_prob,
-                      heads=w.heads, group=w.group, window=window, bd=bd)
+                      heads=w.heads, group=w.group, window=window, bd=bd,
+                      **({} if eva is None else {"eva": eva}))
     item = q.dtype.itemsize
-    dq, dk, dv = pl.pallas_call(
-        kern, name=_kernel_name("bwd", window, bd),
+    dq, dk, dv, *d_pooled = pl.pallas_call(
+        kern, name=_kernel_name("bwd", window, bd, eva),
         interpret=interpret(),
-        grid=(w.batch, w.heads // w.group, sk // block_k),
+        grid=(w.batch, w.heads // w.group, tiles),
         in_specs=[whole_q, k_read, v_read, whole_o, whole_o, lse_spec]
-        + extra_specs,
-        out_specs=[whole_q, k_spec, v_spec],
+        + pooled_specs + extra_specs,
+        out_specs=[whole_q, k_spec, v_spec] + pooled_specs,
         out_shape=[jax.ShapeDtypeStruct(w.flat(q).shape, q.dtype)]
         + [jax.ShapeDtypeStruct(
             x.flat(t).shape[:-1] + (w.rep * x.flat(t).shape[-1],), t.dtype)
-           for x, t in ((w, k), (wv, v))],
+           for x, t in ((w, k), (wv, v))] + pooled_shapes,
         scratch_shapes=[pltpu.VMEM((sq, w.width), jnp.float32)],
         compiler_params=_compiler_params(
             2 * (sq + block_k) * (w.width + wv.width) * item
             + sq * w.width * 4),
     )(w.flat(q), w.flat(k), wv.flat(v), wv.flat(o), wv.flat(dout), lse,
-      *extra_args)
-    return (w.unflat(dq), w.unflat(_group_sum(dk, w.rep, w.dim)),
-            wv.unflat(_group_sum(dv, w.rep, wv.dim)))
+      *(x_walk.flat(x) for x, x_walk in zip(pooled, (w, wv))), *extra_args)
+    grads = (w.unflat(dq), w.unflat(_group_sum(dk, w.rep, w.dim)),
+             wv.unflat(_group_sum(dv, w.rep, wv.dim)))
+    if eva is None:
+        return grads
+    return grads + tuple(x.unflat(t) for x, t in zip((w, wv), d_pooled))
 
 
 def _group_sum(x, rep, dim):
@@ -904,6 +1108,92 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # trace of the two kernel bodies (pallas_call itself traces its kernel anew
 # at every call)
 _flash_call = jax.jit(_flash, static_argnums=(5, 6, 7, 8, 9, 10, 11))
+
+
+# -- summaries behind a window (EVA) ---------------------------------------
+# q, k, v and the chunks' summaries (ks, vs: one row a chunk of every window
+# but the last) are all differentiated; the plan is static.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _eva(q, k, v, ks, vs, scale, block, num_heads, eva):
+    return _fwd(q, k, v, None, True, scale, num_heads=num_heads,
+                block_q=block, block_k=block, eva=eva, pooled=(ks, vs))[0]
+
+
+def _eva_fwd(q, k, v, ks, vs, scale, block, num_heads, eva):
+    # named as the flash kernels' are: a recomputed group keeps them
+    o, lse = named("flash", *_fwd(q, k, v, None, True, scale,
+                                  num_heads=num_heads, block_q=block,
+                                  block_k=block, eva=eva, pooled=(ks, vs)))
+    return o, (q, k, v, ks, vs, o, lse)
+
+
+def _eva_bwd(scale, block, num_heads, eva, res, g):
+    q, k, v, ks, vs, o, lse = res
+    return _bwd_impl(q, k, v, None, o, lse, g, True, scale, 1.0, None,
+                     block_q=block, block_k=block, num_heads=num_heads,
+                     eva=eva, pooled=(ks, vs))
+
+
+_eva.defvjp(_eva_fwd, _eva_bwd)
+_eva_call = jax.jit(_eva, static_argnums=(5, 6, 7, 8))
+
+
+def _eva_tiles(nq, span, n, rows):
+    """``{pass: (whole, edge)}`` of the plan ``eva = (span, n, rows)`` in
+    tiles (``span``: a window's key tiles; ``n`` summaries a window, ``rows``
+    a tile of them) over ``nq`` square tiles of rows, as the kernels' loops
+    run: the (query tile, key tile) pairs walked whole, keys ``("k", j)`` and
+    summaries ``("s", j)``, and those walked under the diagonal's edge."""
+    fwd_whole, fwd_edge, bwd_whole, bwd_edge = [], [], [], []
+    for qi in range(nq):                          # _fwd_kernel
+        w = qi // span
+        fwd_whole += [(qi, ("s", j)) for j in range(w * (n // rows))]
+        fwd_whole += [(qi, ("k", j)) for j in range(w * span, qi)]
+        fwd_edge.append((qi, ("k", qi)))
+    for kj in range(nq):                          # _eva_bwd_tiles, keys
+        bwd_edge.append((kj, ("k", kj)))
+        bwd_whole += [(i, ("k", kj)) for i in range(
+            kj + 1, min(nq, (kj // span + 1) * span))]
+    for t in range((nq - 1) // span * (n // rows)):     # and summaries
+        bwd_whole += [(i, ("s", t)) for i in range(
+            (t * rows // n + 1) * span, nq)]
+    return {"forward": (fwd_whole, fwd_edge),
+            "backward": (bwd_whole, bwd_edge)}
+
+
+def eva_pairs(seq, window, chunk):
+    """``(local, remote)``: the (query, key) and the (query, summary) pairs a
+    head attends to over ``seq`` positions: position ``t`` of window ``w = t
+    // window`` sees the keys ``window w .. t`` and the ``window / chunk``
+    summaries of each window before ``w``."""
+    full, rest = divmod(seq, window)
+    local = full * window * (window + 1) // 2 + rest * (rest + 1) // 2
+    remote = (window // chunk) * (window * full * (full - 1) // 2
+                                  + rest * full)
+    return local, remote
+
+
+def _eva_plan(s, s_pad, window, chunk):
+    """``(tile, eva, summaries read)`` of a call with ``eva = (window,
+    chunk)`` over ``s`` positions padded to ``s_pad``: the largest tile that
+    divides both the padded sequence and the window, the kernels' static
+    ``(window, summaries a window, rows of a tile of them)``, and how many
+    summaries the last window's queries see (the windows before it, whole).
+    Counts the plan's pairs in ``hetu_eva_pairs_total{part}``."""
+    tile = next(b for b in (512, 256, 128)
+                if s_pad % b == 0 and window % b == 0)
+    n = window // chunk
+    rows = next(r for r in (512, 256, 128, 64, 32, 16) if n % r == 0)
+    pairs = telemetry.get_registry().counter(
+        "hetu_eva_pairs_total",
+        "Trace-time (query, key) pairs a head attends to in the EVA calls "
+        "planned, by part: local (exact keys of the query's own window) and "
+        "remote (one summary a chunk of every window before it)",
+        labels=("part",))
+    for part, count in zip(("local", "remote"), eva_pairs(s, window, chunk)):
+        pairs.labels(part=part).inc(count)
+    return tile, (window, n, rows), (s_pad - 1) // window * n
 
 
 # -- blockwise API (ring / context parallelism) ----------------------------
@@ -1020,12 +1310,13 @@ def _bd_plan(half, block_diffusion):
     return rows, tile, (int(block_diffusion), rows // tile)
 
 
-def _count_entry(walk, v_dim, window=None, block_diffusion=None):
+def _count_entry(walk, v_dim, window=None, block_diffusion=None, eva=None):
     """Trace-time count of the walk taken, beside ``dispatch.record``'s
     count of the kernel-versus-jnp choice.  Values narrower (or wider) than
     the keys are the layout ``bhsd_v<head size of v>``; a call with a window
-    adds ``_w<window>``, the block-diffusion mask ``_bd<block>``, keys of fewer
-    heads than the queries ``_kv<key heads>``."""
+    adds ``_w<window>``, the block-diffusion mask ``_bd<block>``, summaries
+    behind a window ``_eva<window>c<chunk>``, keys of fewer heads than the
+    queries ``_kv<key heads>``."""
     telemetry.get_registry().counter(
         "hetu_flash_attention_entry_total",
         "Trace-time flash attention calls by operand layout and the heads "
@@ -1035,6 +1326,7 @@ def _count_entry(walk, v_dim, window=None, block_diffusion=None):
                                    else f"_v{v_dim}")
              + ("" if window is None else f"_w{window}")
              + ("" if block_diffusion is None else f"_bd{block_diffusion}")
+             + ("" if eva is None else "_eva{}c{}".format(*eva))
              + ("" if walk.rep == 1 else f"_kv{walk.heads // walk.rep}"),
              heads_per_program=str(walk.group)).inc()
 
@@ -1048,7 +1340,7 @@ def entries():
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
                     dropout_keep=1.0, seed=None, num_heads=None, window=None,
-                    block_diffusion=None):
+                    block_diffusion=None, eva=None, summaries=None):
     """Fused attention; returns None when shapes are unsupported so the
     caller falls back to the jnp composition (ops/attention.py).
 
@@ -1064,12 +1356,21 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     of ``L`` tokens and then their noised copy, blocks of ``block_diffusion``
     tokens, under ``_fwd_kernel``'s ``bd`` mask; the kernels then run as
     ``hetu_flash_fwd_bd`` / ``hetu_flash_bwd_bd`` and walk the tiles that hold
-    a visible pair alone.
+    a visible pair alone.  ``eva = (window, chunk)`` (with ``causal``) and
+    ``summaries = (ks, vs)``, one row a chunk in the layout of k and v:
+    position ``i`` of window ``w = i // window`` sees the keys ``window w ..
+    i`` and the summaries of the chunks of every window before ``w``, under
+    one softmax; the kernels then run as ``hetu_eva_fwd`` / ``hetu_eva_bwd``;
+    one window that holds every key is causal attention and reads no summary.
     """
     if window is not None:
         assert causal and window >= 1, (causal, window)
         if window >= (q.shape[1] if q.ndim == 3 else q.shape[2]):
             window = None
+    if eva is not None:
+        assert causal and summaries is not None, (causal, eva)
+        if eva[0] >= (q.shape[1] if q.ndim == 3 else q.shape[2]):
+            eva = None
     if q.ndim == 3:
         if not heads_per_program(num_heads, q.shape[-1] // num_heads):
             return None
@@ -1079,7 +1380,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     else:
         views = (q, k, v)
     if unsupported(*views, mask, dropout_keep, window,
-                   block_diffusion) is not None:
+                   block_diffusion, eva) is not None:
         return None
     if dropout_keep < 1.0 and seed is None:
         raise ValueError(
@@ -1091,7 +1392,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     # [B, H, S, D], or in place as whole lane tiles each, one head a program
     assert dv == w.dim or q.ndim == 4 or w.group == wv.group == 1, (
         q.shape, v.shape)
-    _count_entry(w, dv, window, block_diffusion)
+    _count_entry(w, dv, window, block_diffusion, eva)
     s, d = w.seq(q), w.dim
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -1131,10 +1432,23 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
 
     if window is not None:
         block = _window_plan(s_pad, window)[0]
-    out = _flash_call(q, k, v, mask, seed, causal, float(scale),
-                      float(dropout_keep), block, num_heads, window, bd)
+    if eva is not None:
+        # the summaries the last window's queries see, cut and padded as k
+        # and v are: outside the custom_vjp, so the rest get zeros back
+        block, plan, seen = _eva_plan(s, s_pad, *eva)
+        ks, vs = (jax.lax.slice_in_dim(x, 0, seen, axis=q.ndim - 2)
+                  for x in summaries)
+        if q.ndim == 4:         # heads in place are never padded
+            ks, vs = (jnp.pad(x, [(0, 0)] * 3 + [(0, width - x.shape[-1])])
+                      for x, width in ((ks, d_pad), (vs, dv_pad)))
+        out = _eva_call(q, k, v, ks, vs, float(scale), block, num_heads,
+                        plan)
+    else:
+        out = _flash_call(q, k, v, mask, seed, causal, float(scale),
+                          float(dropout_keep), block, num_heads, window, bd)
     # the context and the f32 log-sum-exp, one value a row and head
-    kept("bd" if bd else "flash" if window is None else "swa",
+    kept("bd" if bd else "eva" if eva else "flash" if window is None
+         else "swa",
          out.size * out.dtype.itemsize + out.size // dv_pad * 4)
     if d_pad != d or dv_pad != dv or s_pad != s:
         out = out[:, :s] if q.ndim == 3 else out[:, :, :s, :dv]

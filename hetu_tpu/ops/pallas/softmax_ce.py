@@ -34,7 +34,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .dispatch import interpret
 
 _BN = 256     # rows per program
-_BV = 2048    # vocab lanes per chunk
+_BV = 2048    # vocab lanes per chunk, at most
+#: rows from which a vocabulary under 1,024 classes takes the kernel too
+_NARROW_ROWS = 16384
 _NEG = -1e30
 
 
@@ -101,8 +103,9 @@ def _fwd(logits, labels, ignored):
     if npad != n:
         logits = jnp.pad(logits, ((0, npad - n), (0, 0)))
         labels = jnp.pad(labels, (0, npad - n), constant_values=ignored)
-    nv = -(-v // _BV)
-    kern = functools.partial(_fwd_kernel, v=v, bv=_BV, nv=nv,
+    bv = _chunk(v)
+    nv = -(-v // bv)
+    kern = functools.partial(_fwd_kernel, v=v, bv=bv, nv=nv,
                              ignored=ignored)
     loss, lse = pl.pallas_call(
         kern,
@@ -110,7 +113,7 @@ def _fwd(logits, labels, ignored):
         interpret=interpret(),
         grid=(npad // _BN, nv),
         in_specs=[
-            pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
+            pl.BlockSpec((_BN, bv), lambda i, j: (i, j)),
             _row_spec(),
         ],
         out_specs=[_row_spec(), _row_spec()],
@@ -134,18 +137,19 @@ def _bwd(logits, labels, lse, g, ignored):
         labels = jnp.pad(labels, (0, npad - n), constant_values=ignored)
         lse = jnp.pad(lse, (0, npad - n))
         g = jnp.pad(g, (0, npad - n))
-    nv = -(-v // _BV)
-    kern = functools.partial(_bwd_kernel, v=v, bv=_BV, ignored=ignored)
+    bv = _chunk(v)
+    nv = -(-v // bv)
+    kern = functools.partial(_bwd_kernel, v=v, bv=bv, ignored=ignored)
     dx = pl.pallas_call(
         kern,
         name="hetu_softmax_ce_bwd",
         interpret=interpret(),
         grid=(npad // _BN, nv),
         in_specs=[
-            pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
+            pl.BlockSpec((_BN, bv), lambda i, j: (i, j)),
             _row_spec(), _row_spec(), _row_spec(),
         ],
-        out_specs=pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((_BN, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((npad, v), logits.dtype),
     )(logits, labels.astype(jnp.int32).reshape(npad, 1),
       lse.reshape(npad, 1), g.reshape(npad, 1))
@@ -171,13 +175,22 @@ def _ce_bwd(ignored, res, g):
 _ce.defvjp(_ce_fwd, _ce_bwd)
 
 
+def _chunk(v):
+    """Vocabulary lanes a program reads: ``_BV``, or the whole of a narrower
+    vocabulary in lane tiles (a byte-level model's 320 classes: 384 lanes,
+    not 2,048 of which 84% would be padding)."""
+    return min(_BV, -(-v // 128) * 128)
+
+
 def unsupported(y):
     """Why the kernel does not take logits of this shape, or None when it
     does: below 1024 classes or 8 rows the jnp form is one small fusion
-    and a kernel launch buys nothing."""
+    and a kernel launch buys nothing, unless the rows are many (a byte-level
+    model's eight heads: 65,536 rows of 320 classes, 84 MB of f32 logits)."""
     if y.ndim < 2:
         return "rank<2"
-    if y.shape[-1] < 1024:
+    if y.shape[-1] < 1024 and (y.shape[-1] < 128 or int(
+            np.prod(y.shape[:-1])) < _NARROW_ROWS):
         return "vocab<1024"
     if int(np.prod(y.shape[:-1])) < 8:
         return "rows<8"

@@ -256,7 +256,8 @@ def test_the_gate_a_head_in_place_is_the_gate_by_heads(dtype):
 #: the optimizer's, a fresh variable's), taken on the tree before PR 52
 #: (8d0d00d) and equal on this one; OLMoE's and Qwen3-Next's since PR 61, whose
 #: routers choose their experts in ``hetu_moe_select`` (the three steps
-#: without a router kept theirs)
+#: without a router kept theirs); EvaByte's since PR 67, which brought it (its
+#: toy's heads of 32 take the ``jax.numpy`` forms)
 TOY_STEPS = {
     "bert-base.b64-s512":
         "fef11c9a04527e1704fa1b7730bef180fb2aae5027eeee745929dd16f4e31407",
@@ -268,6 +269,8 @@ TOY_STEPS = {
         "6279b5a9f01d4e8d15673fde6e3b5f4d7012bbec0f92400a26b2ae06d0dd36f6",
     "granite-4.0-h-micro.b1-s8192":
         "baabcf14ca313dcafc00896ad55f739a9f0c28691a203ad6c26c68e4ee8275cb",
+    "evabyte-6.5b.b1-s8192":
+        "05424cb015755648532ddb85848efe69f8de1a07324069a564549250beafc8ee",
 }
 
 

@@ -101,6 +101,18 @@ def test_train_llama_example_trains_a_cut_across_two_decoders():
     assert r2.returncode != 0 and "reads layer 16" in r2.stderr
 
 
+def test_train_llama_example_trains_a_byte_level_model_with_eight_heads():
+    """``--model evabyte``: EVA attention (two windows of 32, chunks of 4),
+    labels ``[B, S, 8]``, whole layers recomputed, builds and trains at a toy
+    size."""
+    r = _run(["examples/nlp/train_llama.py", "--model", "evabyte", "--layers",
+              "2", "--hidden", "64", "--heads", "2:2", "--intermediate", "32",
+              "--window", "32:4", "--seq-len", "64", "--batch-size", "1",
+              "--steps", "2"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "step    1  loss" in r.stdout
+
+
 def test_ctr_sparse_opt_example_smoke():
     """train_ctr --sparse-opt (lazy in-graph table updates) runs."""
     r = _run(["examples/ctr/train_ctr.py", "--model", "wdl", "--steps",
