@@ -17,8 +17,10 @@ Four graph nodes, each under a ``jax.named_scope`` that the device trace's
 readers find in the compiled step's ``op_name``: ``hetu_gdn_proj`` (the two
 projections and the split), ``hetu_gdn_conv`` (``ops/causal_conv.py ConvOp``:
 on a TPU the Pallas kernels ``hetu_conv_fwd`` and ``hetu_conv_bwd``),
-``hetu_gdn_scan`` (gates,
-normalisation and the chunked delta rule) and ``hetu_gdn_out`` (the gated
+``hetu_gdn_scan`` (the gates and the chunked delta rule, whose kernels read
+``q~ | k~ | v`` where the convolution wrote them and normalise in VMEM,
+``ops/gated_delta.py chunk_gated_delta_rule_in_place``; where that refuses,
+the normalisation and the copies a value head in ``jax.numpy`` as well) and ``hetu_gdn_out`` (the gated
 norm and the output projection: ``ops/gated_norm.py OutOp``, which reads ``z``
 out of ``qkvz`` itself; on a TPU the norm and the gate are the Pallas kernels
 ``hetu_gated_norm_fwd`` and ``hetu_gated_norm_bwd`` on the scan's ``[B, S,
@@ -54,16 +56,25 @@ def _mixed(qkvz, *, key_heads, dk, dv, rep):
 
 
 def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
+    """Gates and the rule: from ``mixed`` as the convolution wrote it where
+    the rule's kernels read it in place (``rule`` None and
+    ``chunk_gated_delta_rule_in_place`` takes the operands), else the norms
+    and the copies a value head here, around ``chunk_gated_delta_rule``."""
     import jax
     import jax.numpy as jnp
     B, S, _ = mixed.shape
     f32 = jnp.float32
-    kd = key_heads * dk
-    q, k, v = (mixed[..., :kd], mixed[..., kd:2 * kd], mixed[..., 2 * kd:])
     b, a = jnp.split(ba.reshape(B, S, key_heads, 2 * rep), 2, axis=-1)
     beta = jax.nn.sigmoid(b.reshape(B, S, -1).astype(f32))
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         a.reshape(B, S, -1).astype(f32) + dt_bias.astype(f32))
+    if rule is None:
+        o = gated_delta.chunk_gated_delta_rule_in_place(
+            mixed, g, beta, key_heads=key_heads, dk=dk, dv=dv, rep=rep)
+        if o is not None:
+            return o
+    kd = key_heads * dk
+    q, k, v = (mixed[..., :kd], mixed[..., kd:2 * kd], mixed[..., 2 * kd:])
 
     def unit(t):            # L2 norm over a head, then one copy a value head
         t = t.reshape(B, S, key_heads, dk).astype(f32)

@@ -48,12 +48,29 @@ and head).
 Shapes: ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``g, beta [B, T, H]``
 (``H`` value heads; q and k already repeated for them, normalised and
 scaled by the caller); returns ``o [B, T, H, d_v]`` in ``v``'s type and
-the final state ``[B, H, d_k, d_v]`` f32.
+the final state ``[B, H, d_k, d_v]`` f32.  ``chunk_gated_delta_rule_in_place``
+takes the layer's arrays instead: ``mixed [B, T, 2 key_dim + value_dim]``
+(``q~ | k~ | v`` as the convolution wrote them, ``H / rep`` key heads) and
+``g, beta``; returns ``o [B, T, H d_v]``, or None.
 
-What runs where.  ``chunk_gated_delta_rule`` is what the layer's
-``hetu_gdn_scan`` node calls (``layers/gated_delta_net.py``) and what the
-benchmark's long-memory probe calls (``chipbench/builders/qwen3_next.py``
-``delta_rule_gap``).  On a TPU it runs as two Pallas kernels,
+What runs where.  The layer's ``hetu_gdn_scan`` node
+(``layers/gated_delta_net.py``) asks ``chunk_gated_delta_rule_in_place``
+first (PR 69): on a TPU, outside a mesh, under the rule below, where a
+program's four value heads are whole key heads' (``gcd(H, 4) % rep == 0``,
+else ``key_head_split_across_programs``) and v's window starts at a block
+of a program's v (else ``value_window_not_block_aligned``), the kernel pair
+reads ``mixed`` in place, a key head once for its value heads, and takes the
+L2 norms on the chunk in VMEM; what stands between the convolution's output
+and the gated norm is then the gates (``[B, T, H]``, XLA's), the two kernels
+and one concatenation of ``d mixed``.  Where it returns None the node runs
+its ``jax.numpy`` prologue around ``chunk_gated_delta_rule``, which is also
+what the benchmark's long-memory probe calls
+(``chipbench/builders/qwen3_next.py`` ``delta_rule_gap``).  Either way a
+recomputed group keeps what the forward kernel wrote (``ops/pallas/dispatch.py
+KEPT["gdn"]``): the output and, f32 a chunk and head, the chunk-start state
+and the chunk's inverse, ``B T H (d_v itemsize + (d_k d_v + 64 x 64) / 16)``
+bytes a call (466 MB a layer of the Qwen3-Next cell as HBM tiles them).  On
+a TPU ``chunk_gated_delta_rule`` runs as two Pallas kernels,
 ``hetu_gdn_fwd`` and ``hetu_gdn_bwd`` (``ops/pallas/gated_delta.py``, a
 ``jax.custom_vjp``: one walk over chunk states in VMEM each way; the
 backward keeps the chunk-start states and the chunks' triangular inverses
@@ -63,7 +80,9 @@ it can read that they apply: ``d_k`` and ``d_v`` multiples of 128, ``chunk``
 counts its choice at trace time in ``hetu_kernel_choice_total{kernel=
 "gated_delta", impl, reason}``: ``pallas``, or ``jnp`` with
 ``head_dim_not_128_aligned``, ``chunk!=64``, ``dtype:<name>`` or
-``dtype:mixed``.  What a mesh (which the scan node sees, ``ops/base.py
+``dtype:mixed``; the in-place entry counts ``pallas`` where it engages and
+``jnp`` with one of its own two reasons where it alone refuses (the prologue
+is then ``jax.numpy`` and the plain entry counts its ``pallas`` beside it).  What a mesh (which the scan node sees, ``ops/base.py
 KernelOp``) and a platform without Mosaic mean is ``dispatch.take``'s rule;
 ``chunk_gated_delta_rule_jnp`` then runs, bit for bit what this function was
 before it had kernels.  The kernels themselves run anywhere when called
@@ -112,6 +131,33 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
                      kernels.unsupported(q, k, v, chunk)):
         return kernels.gated_delta_rule(q, k, v, g, beta)
     return chunk_gated_delta_rule_jnp(q, k, v, g, beta, chunk)
+
+
+def chunk_gated_delta_rule_in_place(mixed, g, beta, *, key_heads, dk, dv,
+                                    rep):
+    """The rule from the convolution's output where its kernels read that in
+    place, else None (the caller then runs its ``jax.numpy`` prologue around
+    ``chunk_gated_delta_rule``): on a TPU, under the rule
+    ``chunk_gated_delta_rule`` reads, where a program's value heads are whole
+    key heads' and v's window starts at a block, from ``mixed [B, T, 2
+    key_dim + value_dim]`` (``q~ | k~ | v``) and ``g, beta [B, T, value
+    heads]`` to ``o [B, T, value heads x dv]`` (``ops/pallas/gated_delta.py
+    gated_delta_rule_in_place``: a key head read once for its ``rep`` value
+    heads, its L2 norms taken on the chunk in VMEM)."""
+    from .pallas import dispatch, gated_delta as kernels
+    B, T, _ = mixed.shape
+    head = lambda d: jax.ShapeDtypeStruct((B, T, key_heads * rep, d),
+                                          mixed.dtype)
+    # that refusal is ``chunk_gated_delta_rule``'s to count, when the caller
+    # falls back on it; this entry's own is counted here
+    if kernels.unsupported(head(dk), head(dk), head(dv), CHUNK) is not None:
+        return None
+    if not dispatch.take("gated_delta", None,
+                         kernels.in_place_unsupported(key_heads, dk, dv,
+                                                      rep)):
+        return None
+    return kernels.gated_delta_rule_in_place(mixed, g, beta, dk=dk, dv=dv,
+                                             rep=rep)
 
 
 def chunk_gated_delta_rule_jnp(q, k, v, g, beta, chunk=CHUNK):

@@ -138,11 +138,18 @@ def take(kernel, mesh, reason=None, asked=False):
 #: (``named``, INSIDE the ``custom_vjp``'s forward rule: the backward rule
 #: reads the residual, so a name on the node's output keeps a copy and still
 #: runs the kernel again).  Flash attention's context ``B x S x heads x d`` of
-#: the compute type and log-sum-exp ``B x heads x S`` f32 a call; the scans'
-#: outputs are not in it yet (PERF.md section 7).  The window kernels share
-#: the rule and the names, which hold no kernel's name (a reader of the device
-#: trace finds a kernel by its name anywhere in an event's)
-KEPT = {"flash": ("attention_context", "attention_lse")}
+#: the compute type and log-sum-exp ``B x heads x S`` f32 a call.  The window
+#: kernels share the rule and the names, which hold no kernel's name (a reader
+#: of the device trace finds a kernel by its name anywhere in an event's).
+#: The gated delta rule's output ``B x S x heads x d_v`` of the compute type
+#: and, f32 a chunk of 64 and head, its chunk-start state ``d_k x d_v`` and
+#: the chunk's inverse ``64 x 64`` (a layer of the Qwen3-Next cell: 64 + 268 +
+#: 67 MB, 134 for the last in HBM's tiles of 128 lanes; ``hetu_gdn_fwd`` then
+#: runs once a layer application).  The other scans' are not in it: their
+#: cells have no memory for them (PERF.md section 7)
+KEPT = {"flash": ("attention_context", "attention_lse"),
+        "gdn": ("delta_rule_output", "delta_rule_states",
+                "delta_rule_inverses")}
 
 
 def named(kernel, *residuals):
@@ -174,10 +181,16 @@ def keeping(tally):
         _group.tally = before
 
 
+def keeps():
+    """Whether a recomputed group that has a backward pass is being traced:
+    its policy keeps what ``named`` names."""
+    return getattr(_group, "tally", None) is not None
+
+
 def kept(kernel, nbytes):
     """One kernel call that named ``nbytes`` of residuals: counted where a
     group's policy keeps them, nothing elsewhere."""
-    if getattr(_group, "tally", None) is not None:
+    if keeps():
         _group.tally.append((kernel, int(nbytes)))
 
 

@@ -7,7 +7,21 @@ last axis sequential.  The states ``S [d_k, d_v]`` f32 of a program's
 program walks ``CHUNKS`` chunks of 64 positions: it reads their q, k, v rows
 in place from the ``[B, T, H * d]`` view (a ``[rows, HEADS * d]`` block at
 lane offset ``h * d``: no ``[B, H, N, C, d]`` copy exists), g and beta from
-``[B, H, T / (n C), n, C]``, and for each chunk forms in VMEM the running sum
+``[B, H, T / (n C), n, C]``.  Two entries hand it its operands.
+``gated_delta_rule`` takes q and k normalised, scaled and repeated a value
+head by the caller.  ``gated_delta_rule_in_place`` (PR 69, what the layer
+runs) takes ``mixed [B, T, 2 key_dim + value_dim]`` as the convolution wrote
+it: the three windows ``q~ | k~ | v`` are blocks of the one array whose lane
+index starts at the window's, a program's four value heads read their two
+KEY heads' ``[rows, 2 d_k]`` once (``rep`` value heads a key head: half the
+q and k bytes at 2), and the kernel forms ``q = round(l2norm(q~) d_k^-1/2)``,
+``k = round(l2norm(k~))`` on the ``[64, 128]`` chunk in VMEM (``_operands``:
+f32 sum of squares, 1e-6 under the root, rounded to the compute type where
+the layer's ``jax.numpy`` prologue rounds, so every product takes the passes
+it took and the values are that prologue's but for the order of a 128-lane
+sum).  No normalised or repeated ``[B, T, H d_k]`` reaches HBM, forward,
+recomputed or as a cotangent.  For each chunk the kernel forms in VMEM the
+running sum
 ``G``, the masked decays, ``K K^T``, ``L``, ``T = (I + L)^-1``, ``V' = T (beta
 V)``, ``W = T (beta exp(G) K)``, ``u = V' - W S``, ``o = (q exp(G)) S + P u``
 and the next state; it writes ``o`` once, the state each chunk starts from
@@ -16,8 +30,12 @@ and the chunk's ``T``.
 ``hetu_gdn_bwd``: the same grid with the chunks in reverse and ``dS [d_k,
 d_v]`` f32 in VMEM scratch.  A program reads its chunk's ``T`` as the forward
 kernel wrote it, rebuilds ``W, V', u, P`` from it, q, k, v, g, beta and the
-kept chunk-start state, and writes dq, dk, dv, dg and dbeta once.  One
-kernel: the reverse walk and the gradients inside a chunk share every
+kept chunk-start state, and writes dq, dk, dv, dg and dbeta once; in place
+it sums a key head's dq and dk over its value heads in f32, takes them
+through the scale and the norm by hand (``_unit_bwd``: ``dt~ = r (dt^ - t^
+sum(dt^ t^))``, one lane sum a row) and writes ``dq~, dk~ [B, T, key_dim]``:
+half the cotangent bytes, and ``d mixed`` is one concatenation with ``dv``.
+One kernel: the reverse walk and the gradients inside a chunk share every
 rebuilt matrix.  Nothing is solved here: the triangle ``L``, its transpose
 and the substitution are the forward kernel's alone
 (``hetu_delta_inverse_total{rule="gdn", source}`` counts a forward call
@@ -27,9 +45,13 @@ What the backward keeps: the chunk-start states (``d_k x d_v`` f32 a chunk
 and head, 268 MB for a layer of the Qwen3-Next cell) and the chunks'
 inverses ``T [B, H, T / (n C), n, 64, 64]`` f32, exactly the value
 ``unit_lower_inverse`` returned (16 KiB a chunk and head, 64 MiB a layer of
-that cell and 128 MiB in HBM, whose tiles are 128 lanes wide), both alive
-only while that layer's backward pass runs since the mixer is recomputed;
-no ``W``, ``V'`` or ``u`` reaches HBM.  A step used to solve every chunk's
+that cell and 128 MiB in HBM, whose tiles are 128 lanes wide); no ``W``,
+``V'`` or ``u`` reaches HBM.  Since PR 69 ``_rule_fwd`` names both and the
+output (``dispatch.KEPT["gdn"]``: 64 MB of bf16 output a layer of that cell,
+466 MB with them), so a recomputed mixer keeps what its first forward call
+wrote, its backward pass runs no second ``hetu_gdn_fwd`` (three a step where
+six ran) and all three layers' residuals are alive from a layer's forward
+pass to its backward pass (``peak_hbm_share`` 69 -> 77%).  A step used to solve every chunk's
 system three times (forward, recomputed forward, and again inside the
 backward kernel, 37% of that kernel's bundles: 6.08 -> 3.88 ms a call on a
 v5e; PERF.md, PR 66).  The tiles stay half empty: a program's heads side by
@@ -78,6 +100,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -269,8 +292,42 @@ def _chunk_bwd(q, k, v, g_row, beta_row, S, T, do, dS):
     return _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c) + (dS0,)
 
 
+@jax.jit
+def _unit(t):
+    """The rows of ``t [C, d]`` over their norms, f32, and the norms' inverses
+    ``[C, 1]`` (the layer's ``l2norm``: 1e-6 under the root)."""
+    t = t.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(t * t, axis=1, keepdims=True) + 1e-6)
+    return t * r, r
+
+
+@jax.jit
+def _unit_bwd(dt, t, r):
+    """``_unit``'s cotangent from that of its rows ``dt``, the rows ``t`` and
+    the norms' inverses ``r``: one lane sum a row."""
+    return r * (dt - t * jnp.sum(dt * t, axis=1, keepdims=True))
+
+
+def _operands(q_ref, k_ref, rows, hb, dk, rep):
+    """``(q, k)`` in the compute type of each of a program's value heads at a
+    chunk's ``rows``.  ``rep`` None: a head's lanes of both blocks as they
+    are.  Else the blocks hold the convolution's ``q~, k~`` a KEY head, read
+    once for its ``rep`` value heads: ``q = round(l2norm(q~) dk^-1/2)``, ``k =
+    round(l2norm(k~))`` formed here, rounded where the layer's ``jax.numpy``
+    form rounds them; beside them, a key head, ``(q^, r_q, k^, r_k)`` f32 for
+    the norms' backward pass."""
+    if rep is None:
+        return [(q_ref[rows, kl], k_ref[rows, kl])
+                for kl, _ in head_lanes(hb, dk, dk)], None
+    ct = q_ref.dtype
+    norms = [_unit(q_ref[rows, kl]) + _unit(k_ref[rows, kl])
+             for kl, _ in head_lanes(hb // rep, dk, dk)]
+    return [((qn * dk ** -0.5).astype(ct), kn.astype(ct))
+            for qn, _, kn, _ in norms for _ in range(rep)], norms
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
-                t_ref, s_ref, *, nc, hb, dk, dv):
+                t_ref, s_ref, *, nc, hb, dk, dv, rep):
     import jax.experimental.pallas as pl
     i = pl.program_id(2)
     lanes = head_lanes(hb, dk, dv)
@@ -283,10 +340,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
         rows = chunk_rows(j, C)
         for h in range(hb):
             s0_ref[h, j] = s_ref[h]
+        qk, _ = _operands(q_ref, k_ref, rows, hb, dk, rep)
         outs = together(
-            _chunk_fwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-                       pick(g_ref[h], j), pick(b_ref[h], j), s_ref[h])
-            for h, (kl, vl) in enumerate(lanes))
+            _chunk_fwd(q, k, v_ref[rows, vl], pick(g_ref[h], j),
+                       pick(b_ref[h], j), s_ref[h])
+            for h, ((q, k), (_, vl)) in enumerate(zip(qk, lanes)))
         for h, (o, S, T) in enumerate(outs):
             o_ref[rows, lanes[h][1]] = o.astype(o_ref.dtype)
             s_ref[h] = S
@@ -300,7 +358,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, last_ref, s0_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, t_ref, do_ref,
                 dlast_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *,
-                nc, hb, dk, dv):
+                nc, hb, dk, dv, rep):
     import jax.experimental.pallas as pl
     lanes = head_lanes(hb, dk, dv)
 
@@ -311,108 +369,173 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, t_ref, do_ref,
     def body(n):
         j = nc - 1 - n
         rows = chunk_rows(j, C)
+        qk, norms = _operands(q_ref, k_ref, rows, hb, dk, rep)
         outs = together(
-            _chunk_bwd(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
-                       pick(g_ref[h], j), pick(b_ref[h], j), s0_ref[h, j],
-                       t_ref[h, j], do_ref[rows, vl], ds_ref[h])
-            for h, (kl, vl) in enumerate(lanes))
+            _chunk_bwd(q, k, v_ref[rows, vl], pick(g_ref[h], j),
+                       pick(b_ref[h], j), s0_ref[h, j], t_ref[h, j],
+                       do_ref[rows, vl], ds_ref[h])
+            for h, ((q, k), (_, vl)) in enumerate(zip(qk, lanes)))
         for h, (dq, dk_, dv_, dg, dbeta, dS) in enumerate(outs):
             kl, vl = lanes[h]
-            dq_ref[rows, kl] = dq.astype(dq_ref.dtype)
-            dk_ref[rows, kl] = dk_.astype(dk_ref.dtype)
+            if rep is None:
+                dq_ref[rows, kl] = dq.astype(dq_ref.dtype)
+                dk_ref[rows, kl] = dk_.astype(dk_ref.dtype)
             dv_ref[rows, vl] = dv_.astype(dv_ref.dtype)
             put(dg_ref.at[h], j, dg)
             put(db_ref.at[h], j, dbeta)
             ds_ref[h] = dS
+        # a key head's dq, dk summed over its value heads in f32, then
+        # through the scale and the norm: dq~, dk~ a key head
+        for n_, (qn, rq, kn, rk) in enumerate(norms or ()):
+            kl = lanes[n_][0]
+            mine = outs[n_ * rep:(n_ + 1) * rep]
+            dq, dk_ = (functools.reduce(operator.add, (o[n] for o in mine))
+                       for n in range(2))
+            dq_ref[rows, kl] = _unit_bwd(dq * dk ** -0.5, qn,
+                                         rq).astype(dq_ref.dtype)
+            dk_ref[rows, kl] = _unit_bwd(dk_, kn, rk).astype(dk_ref.dtype)
     walk(nc, body)
 
 
-
-def _plan(g, q, v, reverse):
-    """Grid, the kernels' static sizes and the block specs of q / k, v / o,
-    g / beta, the kept states, the kept inverses and a state; ``reverse``:
-    the blocks of chunks from the last to the first."""
+def _plan(g, widths, reverse):
+    """Grid, the kernels' static sizes and the block specs by name: ``q``
+    (and ``dq``, ``dk`` as the backward kernel writes them), ``k``, ``v`` as
+    the kernels read them, ``o`` (and ``do``, ``dv``), ``gate`` (g and beta),
+    the kept states, the kept inverses and a state; ``reverse``: the blocks
+    of chunks from the last to the first.  ``widths = (dk, dv, rep)``.
+    ``rep`` None: q, k ``[B, T, H dk]`` and v ``[B, T, H dv]`` are arrays of
+    their own, a value head's lanes each.  Else all three are windows of
+    ``mixed [B, T, 2 key_dim + value_dim]``, each a block whose lane index
+    starts at the window's: a program's ``hb`` value heads read their ``hb /
+    rep`` key heads' ``q~`` and ``k~`` once, and ``dq~``, ``dk~`` are ``[B,
+    T, key_dim]``, blocked as ``q~`` is in its window."""
     import jax.experimental.pallas as pl
     B, H, groups, nc, _ = g.shape
-    dk, dv = q.shape[2] // H, v.shape[2] // H
+    dk, dv, rep = widths
     hb = math.gcd(H, HEADS)
+    keys = hb * dk // (rep or 1)        # lanes of a program's q and k blocks
     at = (lambda i: groups - 1 - i) if reverse else (lambda i: i)
-    seq = lambda d: pl.BlockSpec((None, nc * C, hb * d),
-                                 lambda b, h, i: (b, at(i), h))
-    gate = pl.BlockSpec((None, hb, None, nc, C),
-                        lambda b, h, i: (b, h, at(i), 0, 0))
+    seq = lambda lanes, first=0: pl.BlockSpec(
+        (None, nc * C, lanes), lambda b, h, i: (b, at(i), first + h))
     kept = lambda rows, cols: pl.BlockSpec(
         (None, hb, None, nc, rows, cols),
         lambda b, h, i: (b, h, at(i), 0, 0, 0))
-    state = pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0))
-    return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv),
-            (seq(dk), seq(dv), gate, kept(dk, dv), kept(C, C), state))
+    # the windows' first blocks: k~ behind the H / hb blocks of q~, v behind
+    # both (``in_place_unsupported`` has refused a v that starts inside one)
+    k_at, v_at = (0, 0) if rep is None else (
+        H // hb, 2 * (H // hb) * keys // (hb * dv))
+    return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv, rep=rep),
+            dict(q=seq(keys), k=seq(keys, k_at), v=seq(hb * dv, v_at),
+                 o=seq(hb * dv),
+                 gate=pl.BlockSpec((None, hb, None, nc, C),
+                                   lambda b, h, i: (b, h, at(i), 0, 0)),
+                 kept=kept(dk, dv), inverse=kept(C, C),
+                 state=pl.BlockSpec((None, hb, dk, dv),
+                                    lambda b, h, i: (b, h, 0, 0))))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fwd_call(q, k, v, g, beta, *, interpret):
+def _read(ops, widths):
+    """``(q, k, v, g, beta)`` as the kernels are handed them and ``widths``
+    whole: ``ops`` is those five, or with ``widths`` ``(mixed, g, beta)``,
+    ``mixed`` then read three times."""
+    if widths is None:
+        q, _, v, g, _ = ops
+        H = g.shape[1]
+        return ops, (q.shape[2] // H, v.shape[2] // H, None)
+    mixed, g, beta = ops
+    return (mixed, mixed, mixed, g, beta), widths
+
+
+@functools.partial(jax.jit, static_argnames=("widths", "interpret"))
+def _fwd_call(*ops, interpret, widths=None):
     """``q, k [B, T, H dk]``, ``v [B, T, H dv]``, ``g, beta [B, H, T / (n C),
-    n, C]`` f32 (``n`` chunks a program): ``(o [B, T, H dv], last state [B, H,
-    dk, dv], chunk-start states [B, H, T / (n C), n, dk, dv], the chunks'
-    inverses [B, H, T / (n C), n, C, C] f32)``."""
+    n, C]`` f32 (``n`` chunks a program), or with ``widths = (dk, dv, rep)``
+    ``mixed [B, T, 2 key_dim + value_dim]``, ``g, beta``: ``(o [B, T, H dv],
+    last state [B, H, dk, dv], chunk-start states [B, H, T / (n C), n, dk,
+    dv], the chunks' inverses [B, H, T / (n C), n, C, C] f32)``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    ops, widths = _read(ops, widths)
+    g = ops[3]
     B, H, groups, nc, _ = g.shape
-    grid, dims, (qk, vo, gate, kept, inverse, state) = _plan(g, q, v, False)
+    grid, dims, at = _plan(g, widths, False)
     dk, dv, hb = dims["dk"], dims["dv"], dims["hb"]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **dims),
         name="hetu_gdn_fwd", grid=grid,
-        in_specs=[qk, qk, vo, gate, gate],
-        out_specs=[vo, state, kept, inverse],
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+        in_specs=[at["q"], at["k"], at["v"], at["gate"], at["gate"]],
+        out_specs=[at["o"], at["state"], at["kept"], at["inverse"]],
+        out_shape=[jax.ShapeDtypeStruct(ops[2].shape[:2] + (H * dv,),
+                                        ops[2].dtype),
                    jax.ShapeDtypeStruct((B, H, dk, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, groups, nc, dk, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, groups, nc, C, C), _F32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v, g, beta)
+    )(*ops)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _bwd_call(q, k, v, g, beta, states, inverses, do, dlast, *, interpret):
+@functools.partial(jax.jit, static_argnames=("widths", "interpret"))
+def _bwd_call(*ops, interpret, widths=None):
+    """``_fwd_call``'s operands, the kept states and inverses, ``do`` and the
+    last state's cotangent: ``dq, dk, dv, dg, dbeta``; with ``widths`` the
+    first two are ``dq~, dk~ [B, T, key_dim]``, a key head's summed over its
+    value heads and taken through its norm."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (qk, vo, gate, kept, inverse, state) = _plan(g, q, v, True)
+    *ops, states, inverses, do, dlast = ops
+    ops, widths = _read(ops, widths)
+    q, _, _, g, _ = ops
+    grid, dims, at = _plan(g, widths, True)
+    dk, dv, rep = widths
+    keys = jax.ShapeDtypeStruct(
+        q.shape[:2] + (g.shape[1] * dk // (rep or 1),), q.dtype)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, **dims),
         name="hetu_gdn_bwd", grid=grid,
-        in_specs=[qk, qk, vo, gate, gate, kept, inverse, vo, state],
-        out_specs=[qk, qk, vo, gate, gate],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+        in_specs=[at["q"], at["k"], at["v"], at["gate"], at["gate"],
+                  at["kept"], at["inverse"], at["o"], at["state"]],
+        out_specs=[at["q"], at["q"], at["o"], at["gate"], at["gate"]],
+        out_shape=[keys, keys, jax.ShapeDtypeStruct(do.shape, q.dtype),
                    jax.ShapeDtypeStruct(g.shape, _F32),
                    jax.ShapeDtypeStruct(g.shape, _F32)],
-        scratch_shapes=[pltpu.VMEM((dims["hb"], dims["dk"], dims["dv"]),
-                                   _F32)],
+        scratch_shapes=[pltpu.VMEM((dims["hb"], dk, dv), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v, g, beta, states, inverses, do, dlast)
+    )(*ops, states, inverses, do, dlast)
 
 
-@jax.custom_vjp
-def _rule(q, k, v, g, beta):
-    return _rule_fwd(q, k, v, g, beta)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rule(widths, *ops):
+    # traced wherever the call is: a program that differentiates it lowers
+    # the forward rule's trace and drops this one, and where a group keeps
+    # that rule's names (``dispatch.keeps``) no second kernel stands for it
+    return _forward(widths, ops, count=not dispatch.keeps())[:2]
 
 
-def _rule_fwd(q, k, v, g, beta):
-    dispatch.count_inverse("gdn", "solved")
-    o, last, states, inverses = _fwd_call(q, k, v, g, beta,
-                                          interpret=dispatch.interpret())
-    return (o, last), (q, k, v, g, beta, states, inverses)
+def _forward(widths, ops, count=True):
+    if count:
+        dispatch.count_inverse("gdn", "solved")
+    return _fwd_call(*ops, widths=widths, interpret=dispatch.interpret())
 
 
-def _rule_bwd(res, grads):
-    do, dlast = grads
+def _rule_fwd(widths, *ops):
+    o, last, states, inverses = _forward(widths, ops)
+    # named HERE, on the values the backward rule and the mixer's norm read: a
+    # recomputed group keeps them (``dispatch.KEPT``) and its backward pass
+    # runs no second forward kernel
+    o, states, inverses = dispatch.named("gdn", o, states, inverses)
+    return (o, last), ops + (states, inverses)
+
+
+def _rule_bwd(widths, res, grads):
     dispatch.count_inverse("gdn", "kept")
-    return tuple(_bwd_call(*res, do, dlast, interpret=dispatch.interpret()))
+    dq, dk, dv, dg, dbeta = _bwd_call(*res, *grads, widths=widths,
+                                      interpret=dispatch.interpret())
+    if widths is None:
+        return dq, dk, dv, dg, dbeta
+    return jnp.concatenate([dq, dk, dv], -1), dg, dbeta
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -432,26 +555,73 @@ def unsupported(q, k, v, chunk):
     return None
 
 
+def in_place_unsupported(key_heads, dk, dv, rep):
+    """Why ``gated_delta_rule_in_place`` does not read ``mixed`` where the
+    kernels take its heads (``unsupported``), or None when it does: a
+    program's value heads must be whole key heads', and the window of v must
+    start at a block of a program's v."""
+    hb = math.gcd(key_heads * rep, HEADS)
+    if hb % rep:
+        return "key_head_split_across_programs"
+    if 2 * key_heads * dk % (hb * dv):
+        return "value_window_not_block_aligned"
+    return None
+
+
+def _cut(T):
+    """Chunks a program, programs along the sequence and the positions of
+    padding behind ``T``: those write nothing (beta 0), decay nothing (g 0)
+    and their outputs are cut off."""
+    nc = min(CHUNKS, -(-T // C))
+    groups = -(-T // (nc * C))
+    return nc, groups, groups * nc * C - T
+
+
+def _rows(x, pad):
+    """``[B, T, ..] -> [B, T', lanes]``."""
+    x = x.reshape(x.shape[:2] + (-1,))
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+
+def _gates(x, nc, groups, pad):
+    """``[B, T, H] -> [B, H, T' / (n C), n, C]`` f32."""
+    B, _, H = x.shape
+    return jnp.moveaxis(_rows(x.astype(_F32), pad), 2, 1).reshape(
+        B, H, groups, nc, C)
+
+
+def _kept(o, dk, dv):
+    """Tell the group that recomputes this call (``dispatch.kept``) what
+    ``_rule_fwd`` named of it: ``o [B, T', H dv]`` and, f32 a chunk and head,
+    the start state ``[dk, dv]`` and the inverse ``[C, C]``."""
+    chunk_heads = o.size // (C * dv)
+    dispatch.kept("gdn", o.size * o.dtype.itemsize
+                  + 4 * chunk_heads * (dk * dv + C * C))
+
+
 def gated_delta_rule(q, k, v, g, beta):
     """``chunk_gated_delta_rule`` at chunk 64 through the kernel pair: ``q, k
     [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``g, beta [B, T, H]`` -> ``(o [B,
     T, H, d_v]`` in ``v``'s type, the last state ``[B, H, d_k, d_v]`` f32)``.
-    Any ``T``: positions of padding write nothing (beta 0), decay nothing (g
-    0) and their outputs are cut off."""
+    Any ``T`` (``_cut``)."""
     B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    nc = min(CHUNKS, -(-T // C))
-    groups = -(-T // (nc * C))
-    pad = groups * nc * C - T
+    cut = _cut(T)
+    o, last = _rule(None, *(_rows(x, cut[2]) for x in (q, k, v)),
+                    _gates(g, *cut), _gates(beta, *cut))
+    _kept(o, dk, v.shape[-1])
+    return o[:, :T].reshape(B, T, H, v.shape[-1]), last
 
-    def rows(x):                       # [B, T, H, d] -> [B, T', H d]
-        x = x.reshape(B, T, -1)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
-    def gates(x):                      # [B, T, H] -> [B, H, T' / (n C), n, C]
-        x = x.astype(_F32)
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        return jnp.moveaxis(x, 2, 1).reshape(B, H, groups, nc, C)
-    o, last = _rule(rows(q), rows(k), rows(v), gates(g), gates(beta))
-    return o[:, :T].reshape(B, T, H, dv), last
+def gated_delta_rule_in_place(mixed, g, beta, *, dk, dv, rep):
+    """The rule from the convolution's output: ``mixed [B, T, 2 key_dim +
+    value_dim]`` (``q~ | k~ | v``, a key head's ``rep`` value heads side by
+    side), ``g, beta [B, T, H]`` -> ``o [B, T, H d_v]`` in ``mixed``'s type,
+    what ``gated_delta_rule`` gives on ``q = round(l2norm(q~) d_k^-1/2)``,
+    ``k = round(l2norm(k~))`` each repeated for its value heads, neither of
+    which reaches HBM; the cotangent of ``mixed`` comes back whole."""
+    T = mixed.shape[1]
+    cut = _cut(T)
+    o, _ = _rule((dk, dv, rep), _rows(mixed, cut[2]), _gates(g, *cut),
+                 _gates(beta, *cut))
+    _kept(o, dk, dv)
+    return o[:, :T]

@@ -257,7 +257,10 @@ def test_the_gate_a_head_in_place_is_the_gate_by_heads(dtype):
 #: (8d0d00d) and equal on this one; OLMoE's and Qwen3-Next's since PR 61, whose
 #: routers choose their experts in ``hetu_moe_select`` (the three steps
 #: without a router kept theirs); EvaByte's since PR 67, which brought it (its
-#: toy's heads of 32 take the ``jax.numpy`` forms)
+#: toy's heads of 32 take the ``jax.numpy`` forms); Qwen3-Next's since PR 69,
+#: whose scan node forms the gates before it slices ``mixed`` (its toy's heads
+#: of 16 take the ``jax.numpy`` prologue and form: the parent's 5,325 lines in
+#: another order, line for line but for a private function's number)
 TOY_STEPS = {
     "bert-base.b64-s512":
         "fef11c9a04527e1704fa1b7730bef180fb2aae5027eeee745929dd16f4e31407",
@@ -266,7 +269,7 @@ TOY_STEPS = {
     "ouro-2.6b.b1-s8192":
         "6c3754eb9f8eb2ff37b5f3c719410662b5bc365c495d35292bea1b7c1555f428",
     "qwen3-next-80b-a3b.b1-s8192":
-        "6279b5a9f01d4e8d15673fde6e3b5f4d7012bbec0f92400a26b2ae06d0dd36f6",
+        "5fb0f01223d80a4f8de7a41ea6bba8507f4cb0baa977d92af2e470180ebd4ac2",
     "granite-4.0-h-micro.b1-s8192":
         "baabcf14ca313dcafc00896ad55f739a9f0c28691a203ad6c26c68e4ee8275cb",
     "evabyte-6.5b.b1-s8192":

@@ -23,12 +23,15 @@ from hetu_tpu.ops.pallas import common, dispatch
 from hetu_tpu.ops.pallas import gated_delta as gdn_kernels
 from hetu_tpu.ops.pallas import kda as kda_kernels
 from hetu_tpu.ops.pallas.common import C
-from test_gated_delta_kernel import delta_inputs
+from test_gated_delta_kernel import delta_inputs, mixed_inputs
 from test_kda import (D, draw, layer_arrays, mixer_in_place, mixer_jnp,
                       rel)
 
 T, H = 600, 2
 ENTRIES = ("gdn", "plain", "in_place")
+#: the scalar rule's entry that reads the convolution's output (PR 69): one
+#: key head under two value heads
+GDN_IN_PLACE = "gdn_in_place"
 DTYPES = ("float32", "bfloat16")
 LOWER = -5.0                         # the gate's bound, as ``mixer_in_place``'s
 
@@ -46,6 +49,10 @@ def case(entry, dtype):
                 f32(gdn_kernels.gated_delta_rule),
                 f32(gated_delta.chunk_gated_delta_rule_jnp),
                 f32(gated_delta.recurrent_gated_delta_rule))
+    if entry == GDN_IN_PLACE:
+        return (mixed_inputs(T, 1, H, dtype, seed=1), ("o",),
+                lambda *a: (gdn_kernels.gated_delta_rule_in_place(
+                    *a, dk=D, dv=D, rep=H).astype(jnp.float32),), None, None)
     if entry == "plain":
         return (draw(64, T, H=H, dtype=dtype),
                 ("o", "s", "dq", "dk", "dv", "dg", "dbeta"),
@@ -120,7 +127,7 @@ def kda_inverse(q, k, g, beta_row, small, gate):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("entry", ENTRIES)
+@pytest.mark.parametrize("entry", ENTRIES + (GDN_IN_PLACE,))
 def test_the_forward_kernel_writes_the_inverse_it_solved_for(entry, dtype,
                                                             monkeypatch):
     """Every chunk and head of 600 positions (ten chunks, the last of 24
@@ -129,10 +136,10 @@ def test_the_forward_kernel_writes_the_inverse_it_solved_for(entry, dtype,
     ``unit_lower_inverse`` of that chunk's ``L``, formed from the kernel's
     own operands with the kernel's own stages, bit for bit."""
     x, _, kernels, _, _ = case(entry, dtype)
-    module = gdn_kernels if entry == "gdn" else kda_kernels
+    module = gdn_kernels if "gdn" in entry else kda_kernels
     ops, outs = forward_call(module, monkeypatch, kernels, x)
     kept = np.asarray(outs[3])
-    beta = ops[2 if entry == "in_place" else 4]
+    beta = ops[4 if len(ops) == 5 else 2]
     B, heads, groups, nc, _ = beta.shape
     assert kept.shape == (B, heads, groups, nc, C, C)
     assert kept.dtype == np.float32 and (groups, nc) == (2, 8)
@@ -144,6 +151,13 @@ def test_the_forward_kernel_writes_the_inverse_it_solved_for(entry, dtype,
             if entry == "gdn":
                 _, k, _, g, _ = ops
                 L, want = gdn_inverse(lanes(k, h)[rows], row(g), row(beta))
+            elif entry == GDN_IN_PLACE:
+                # the one key head's k~ (the second window of ``mixed``)
+                # through the kernel's own norm, rounded as the kernel does
+                mixed, g, _ = ops
+                k = gdn_kernels._unit(mixed[0, rows, D:2 * D])[0]
+                L, want = gdn_inverse(k.astype(mixed.dtype), row(g),
+                                      row(beta))
             elif entry == "plain":
                 q, k, _, g, _ = ops
                 L, want = kda_inverse(lanes(q, h)[rows], lanes(k, h)[rows],
@@ -283,13 +297,13 @@ def test_gdns_backward_kernel_has_no_merge():
 
 # -- (d) the counter ----------------------------------------------------------
 
-@pytest.mark.parametrize("entry", ENTRIES)
+@pytest.mark.parametrize("entry", ENTRIES + (GDN_IN_PLACE,))
 def test_a_forward_kernel_counts_solved_and_a_backward_kernel_kept(entry):
     """One traced ``jax.grad`` of a rule: its forward kernel ``solved`` once,
     its backward kernel ``kept`` once, and nothing else (a backward kernel
     that solved would be a second ``solved``)."""
     x, _, kernels, _, _ = case(entry, "bfloat16")
-    rule = "gdn" if entry == "gdn" else "kda"
+    rule = "gdn" if "gdn" in entry else "kda"
     telemetry.enable()
     try:
         telemetry.get_registry().reset()
@@ -304,3 +318,31 @@ def test_a_forward_kernel_counts_solved_and_a_backward_kernel_kept(entry):
     finally:
         telemetry.get_registry().reset()
         telemetry.disable()
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_delta_net_layer_solves_once_recomputed_or_not(remat, monkeypatch):
+    """A ``GatedDeltaNet``'s loss and gradients traced and lowered as on a
+    TPU: ``hetu_gdn_fwd`` and ``hetu_gdn_bwd`` once each, ``solved`` once and
+    ``kept`` once, whether the layer stands in an ``ht.remat()`` group or not
+    (the parent's group ran, and counted, a second forward kernel: since PR
+    69 the group keeps what the first wrote, ``dispatch.KEPT``)."""
+    from conftest import kernel_calls
+    from test_remat_kept import delta_net_layer, traced
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    jax.clear_caches()
+    telemetry.enable()
+    try:
+        telemetry.get_registry().reset()
+        ex, _ = delta_net_layer(f"dik_layer{int(remat)}", remat)
+        text = traced(ex).lower(lowering_platforms=("tpu",)).as_text()
+        ex.close()
+        assert sorted((lab["rule"], lab["source"], n) for lab, n in
+                      dispatch.counted("hetu_delta_inverse_total")) == [
+                          ("gdn", "kept", 1), ("gdn", "solved", 1)]
+    finally:
+        telemetry.get_registry().reset()
+        telemetry.disable()
+        jax.clear_caches()
+    assert kernel_calls(text, "hetu_gdn_fwd") == 1
+    assert kernel_calls(text, "hetu_gdn_bwd") == 1

@@ -225,6 +225,172 @@ def test_rule_reads_its_operands_as_on_tpu(gdn_choices, monkeypatch, why,
         assert not taken and gdn_choices() == {("jnp", why): 1}
 
 
+# -- the rule from the convolution's output (PR 69) ----------------------------
+
+def mixed_inputs(T, key_heads, rep, dtype, B=1, seed=0):
+    """``mixed [B, T, 2 key_dim + value_dim]`` as a convolution might leave
+    it (``q~ | k~ | v``, no row of unit length), ``g`` and ``beta`` a value
+    head."""
+    r = np.random.default_rng(seed)
+    H = key_heads * rep
+    mixed = r.normal(size=(B, T, (2 * key_heads + H) * D)) * 0.7
+    g = -np.exp(r.normal(size=(B, T, H))) * 0.3
+    beta = 1 / (1 + np.exp(-r.normal(size=(B, T, H))))
+    return (jnp.asarray(mixed, dtype), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32))
+
+
+def from_mixed(rule, mixed, g, beta, *, key_heads, rep):
+    """The layer's ``jax.numpy`` prologue (``layers/gated_delta_net.py
+    _scan``) around ``rule``: q and k L2-normalised over a head in f32, q
+    scaled, one copy a value head, rounded to the compute type."""
+    B, T, _ = mixed.shape
+    kd = key_heads * D
+
+    def unit(t):
+        t = t.reshape(B, T, key_heads, D).astype(jnp.float32)
+        t = t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        return jnp.repeat(t, rep, axis=2)
+    q = (unit(mixed[..., :kd]) * D ** -0.5).astype(mixed.dtype)
+    k = unit(mixed[..., kd:2 * kd]).astype(mixed.dtype)
+    v = mixed[..., 2 * kd:].reshape(B, T, key_heads * rep, D)
+    return rule(q, k, v, g, beta)[0].reshape(B, T, -1)
+
+
+@pytest.mark.parametrize("T,B,key_heads,rep,dtype", [
+    (100, 1, 2, 2, "float32"), (600, 1, 2, 2, "bfloat16"),
+    (64, 2, 2, 1, "bfloat16"), (130, 1, 1, 2, "float32"),
+    (70, 1, 2, 4, "bfloat16"), (128, 1, 4, 1, "float32")])
+def test_in_place_is_the_prologue_around_the_chunked_form(T, B, key_heads,
+                                                          rep, dtype):
+    """``gated_delta_rule_in_place`` on ``mixed`` against today's prologue
+    around ``chunk_gated_delta_rule_jnp``: the output and the gradients of
+    ``mixed`` (all three windows), g and beta; one and two value heads a key
+    head and four (one key head a program), one and two programs of heads,
+    lengths that are no multiple of a program's 512 rows or of a chunk; f32 at
+    the tolerance the plain entry is held to; bf16 within a step or two of
+    the largest (the two round q and k alike but for the order of a 128-lane
+    sum, and a flipped rounding of q or k is a bf16 step in dg and dbeta) and
+    no farther than 1.5 times the prologue's form from that form on the operands in
+    f32 (``dq~`` and ``dk~`` are nearer: a key head's value heads are summed
+    before they are rounded)."""
+    x = mixed_inputs(T, key_heads, rep, jnp.dtype(dtype), B, seed=T)
+    dims = dict(key_heads=key_heads, rep=rep)
+    want_fn = lambda *a: from_mixed(chunk_gated_delta_rule_jnp, *a, **dims)
+    got_fn = lambda *a: kernels.gated_delta_rule_in_place(
+        *a, dk=D, dv=D, rep=rep)
+
+    def both(fn, x):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True)(*x)
+        return (o,) + grads
+    form = jax.jit(both, static_argnums=0)
+    got, want = both(got_fn, x), form(want_fn, x)
+    f32 = dtype == "float32"
+    exact = want if f32 else form(
+        want_fn, (x[0].astype(jnp.float32),) + x[1:])
+    kd = key_heads * D
+    for name, a, b, c in zip(("o", "dmixed", "dg", "dbeta"), got, want,
+                             exact):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b, c = (np.asarray(t, np.float32) for t in (a, b, c))
+        windows = ((slice(0, kd), slice(kd, 2 * kd), slice(2 * kd, None))
+                   if name == "dmixed" else (slice(None),))
+        for w in windows:
+            a_, b_, c_ = (t[..., w] for t in (a, b, c))
+            top = np.abs(c_).max()
+            assert top > 0
+            gap = lambda s, t: np.abs(s - t).max() / top
+            assert gap(a_, b_) <= (2e-5 if f32 else 1.5e-2), (name, w)
+            assert gap(a_, c_) <= 1.5 * gap(b_, c_) + 1e-4, (name, w)
+
+
+@pytest.mark.parametrize("why,dims", [
+    (None, dict(key_heads=16, rep=2)),
+    (None, dict(key_heads=2, rep=1)),
+    (None, dict(key_heads=2, rep=4)),
+    ("key_head_split_across_programs", dict(key_heads=1, rep=3)),
+    ("key_head_split_across_programs", dict(key_heads=2, rep=8)),
+    ("value_window_not_block_aligned", dict(key_heads=1, rep=4)),
+    ("head_dim_not_128_aligned", dict(key_heads=2, rep=2, dk=64)),
+    ("dtype:float16", dict(key_heads=2, rep=2, dtype=jnp.float16)),
+])
+def test_scan_reads_mixed_in_place_where_a_program_holds_whole_key_heads(
+        gdn_choices, monkeypatch, why, dims):
+    """``_scan`` with the platform patched to ``tpu``: the in-place entry
+    where the kernels take the heads, a program's value heads are whole key
+    heads' and v's window starts at a block, counted ``pallas`` once; a
+    refusal of its own is counted with its reason and the prologue runs
+    around the plain kernels (``pallas`` once more); where the kernels do not
+    take the heads the plain entry counts why, as it did."""
+    from hetu_tpu.layers.gated_delta_net import _scan
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    dims = dict(dict(dk=D, dv=D), **dims)
+    dtype = dims.pop("dtype", jnp.bfloat16)
+    H = dims["key_heads"] * dims["rep"]
+    taken = []
+    monkeypatch.setattr(
+        kernels, "gated_delta_rule_in_place",
+        lambda mixed, g, beta, **kw: taken.append("in_place") or
+        jnp.zeros(mixed.shape[:2] + (H * kw["dv"],), mixed.dtype))
+    monkeypatch.setattr(kernels, "gated_delta_rule",
+                        lambda *a: taken.append("plain") or
+                        chunk_gated_delta_rule_jnp(*a))
+    sds = jax.ShapeDtypeStruct
+    o = jax.eval_shape(
+        lambda *a: _scan(*a, **dims),
+        sds((1, 64, 2 * dims["key_heads"] * dims["dk"] + H * dims["dv"]),
+            dtype),
+        sds((1, 64, 2 * H), dtype), sds((H,), jnp.float32),
+        sds((H,), jnp.float32))
+    assert o.shape == (1, 64, H * dims["dv"]) and o.dtype == dtype
+    if why is None:
+        assert taken == ["in_place"]
+        assert gdn_choices() == {("pallas", ""): 1}
+    elif "128" in why or "dtype" in why:
+        assert taken == [] and gdn_choices() == {("jnp", why): 1}
+    else:
+        assert taken == ["plain"]
+        assert gdn_choices() == {("jnp", why): 1, ("pallas", ""): 1}
+
+
+def test_scan_through_the_in_place_kernels_is_the_scan(monkeypatch):
+    """The scan node's function as it runs on a TPU (interpret mode: Mosaic
+    read as there, the platform the CPU's) against itself around the
+    ``jax.numpy`` form, f32, two value heads a key head over 100 positions:
+    the output and the gradient of ``mixed``, ``ba``, ``A_log`` and
+    ``dt_bias``."""
+    from hetu_tpu.layers.gated_delta_net import _scan
+    dims = dict(key_heads=2, dk=D, dv=D, rep=2)
+    r = np.random.default_rng(4)
+    x = (jnp.asarray(r.normal(size=(1, 100, 8 * D)), jnp.float32),
+         jnp.asarray(r.normal(size=(1, 100, 8)), jnp.float32),
+         jnp.asarray(r.normal(size=(4,)), jnp.float32),
+         jnp.asarray(r.normal(1.0, 0.1, size=(4,)), jnp.float32))
+
+    def both(rule):
+        def loss(*a):
+            o = _scan(*a, rule=rule, **dims)
+            return jnp.sum(jnp.sin(o)), o
+        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                           has_aux=True)(*x)
+        return (o,) + grads
+    want = both(chunk_gated_delta_rule_jnp)
+    monkeypatch.setattr(dispatch, "mosaic", lambda: True)
+    seen = []
+    real = kernels.gated_delta_rule_in_place
+    monkeypatch.setattr(kernels, "gated_delta_rule_in_place",
+                        lambda *a, **kw: seen.append(kw) or real(*a, **kw))
+    got = both(None)
+    assert seen == [dict(dk=D, dv=D, rep=2)]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and np.abs(b).max() > 0
+        assert np.abs(a - b).max() < 2e-5 * np.abs(b).max()
+
+
 # -- the layer through the kernels ---------------------------------------------
 
 def layer_loss_and_grads(through_kernels, monkeypatch):

@@ -214,7 +214,8 @@ def f32(*shape):
 
 #: scope -> (label, the kernel's entry, its ``jax.numpy`` form, operands)
 NODES = {
-    "hetu_gdn_scan": ("gated_delta", "pallas.gated_delta:gated_delta_rule",
+    "hetu_gdn_scan": ("gated_delta",
+                      "pallas.gated_delta:gated_delta_rule_in_place",
                       "gated_delta:chunk_gated_delta_rule_jnp",
                       [bf16(1, 64, 4 * D), bf16(1, 64, 4), f32(2), f32(2)]),
     "hetu_ssm_scan": ("ssd", "pallas.ssd:ssd", "ssd:chunk_ssd_jnp",
@@ -285,7 +286,7 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
         stub(monkeypatch, kernel, called, "pallas",
              like=lambda: (lambda q, k, t, r=None: (q, k)) if label == "rotary"
              else lambda o, *a, **k: o)
-    elif scope == "hetu_kda_scan":      # the in-place entry: [B, S, H d]
+    elif "_in_place" in kernel:         # from ``mixed`` to [B, S, H d]
         stub(monkeypatch, form, called, "jnp")
         stub(monkeypatch, kernel, called, "pallas",
              like=lambda: lambda mixed, *a, **k: mixed[..., :2 * D])

@@ -2,7 +2,9 @@
 kernel's context and log-sum-exp, named INSIDE the kernel's forward rule
 (``ops/pallas/dispatch.py KEPT``, ``named``) and saved by the group's policy
 (``graph/trace.py``), so the backward pass of a recomputed layer runs the
-forward kernel no second time.
+forward kernel no second time; since PR 69 the gated delta rule's output,
+chunk-start states and chunks' inverses as well (``"gdn"``), so a recomputed
+DeltaNet mixer runs ``hetu_gdn_fwd`` once.
 
 Lowered for a TPU (nothing compiled or run) the cells' toys at heads of the
 kernels' width run as many flash forward calls as layer applications; a group
@@ -28,8 +30,9 @@ from chipbench import run
 from conftest import kernel_calls, lowered_for_tpu, without_locations
 from test_rotary_kernel import layer_grads
 
-OURO, LING, LAGUNA = ("ouro-2.6b.b1-s8192", "ling-3.0-flash-vl.b1-s8192",
-                      "laguna-xs.2.b1-s8192")
+OURO, LING, LAGUNA, QWEN = (
+    "ouro-2.6b.b1-s8192", "ling-3.0-flash-vl.b1-s8192",
+    "laguna-xs.2.b1-s8192", "qwen3-next-80b-a3b.b1-s8192")
 D, S = 128, 256
 
 
@@ -79,6 +82,21 @@ def laguna_toy():
     return toy(LAGUNA, sliding_window=128, job={"remat": "layer"})
 
 
+def qwen_toy():
+    # the published period, D D D A, the DeltaNet mixers recomputed as in the
+    # cell: two key heads, four value heads of 128 (the attention layer is
+    # in no group)
+    return toy(QWEN, num_hidden_layers=4, full_attention_interval=4,
+               linear_key_head_dim=D, linear_value_head_dim=D)
+
+
+def delta_words(batch, heads, seq, itemsize, dk=D, dv=D):
+    """Bytes of a delta rule call's output and, f32 a chunk of 64 and head,
+    its chunk-start state and the chunk's inverse."""
+    return (batch * seq * heads * dv * itemsize
+            + 4 * batch * heads * seq // 64 * (dk * dv + 64 * 64))
+
+
 def words(batch, heads, seq, width, itemsize):
     """Bytes of a flash call's context and f32 log-sum-exp."""
     return batch * seq * heads * width * itemsize + batch * heads * seq * 4
@@ -88,12 +106,14 @@ def words(batch, heads, seq, width, itemsize):
     (ouro_toy, {"hetu_flash": 2 * 4}, 8 * words(1, 2, S, D, 4)),
     (ling_toy, {"hetu_flash": 1}, None),
     (laguna_toy, {"hetu_flash": 2, "hetu_swa": 3}, None),
+    (qwen_toy, {"hetu_gdn": 3}, 3 * delta_words(1, 4, S, 4)),
 ])
 def test_a_toy_step_runs_one_forward_kernel_a_layer_application(
         monkeypatch, kept, build, calls, nbytes):
-    """Every attention layer in a recomputed group: as many forward calls as
-    layer applications (the parent ran twice as many), as many backward
-    calls, and the registry counted each once."""
+    """Every attention layer (Qwen3-Next: every DeltaNet mixer) in a
+    recomputed group: as many forward calls as layer applications (the
+    parent ran twice as many), as many backward calls, and the registry
+    counted each once."""
     text = lowered_for_tpu(monkeypatch, build)
     for kernel, n in calls.items():
         assert kernel_calls(text, kernel + "_fwd") == n, kernel
@@ -266,10 +286,69 @@ def test_a_program_with_no_backward_pass_counts_nothing(monkeypatch, kept):
     assert kept() == ({"flash": 1}, words(2, 2, S, D, 4))
 
 
+def delta_net_layer(name, remat):
+    """A ``GatedDeltaNet`` (two key heads, four value heads of 128) over ``[2,
+    100, 64]`` under ``ht.remat()`` (or not): the executor of its loss and of
+    every weight's gradient, and the feed."""
+    import contextlib
+    from hetu_tpu.graph.node import graph_variables
+    from hetu_tpu.layers.gated_delta_net import GatedDeltaNet
+    layer = GatedDeltaNet(64, 2, 4, D, D, name=name)
+    x = ht.placeholder_op(f"{name}_x", (2, 100, 64))
+    with ht.remat() if remat else contextlib.nullcontext():
+        y = layer(x)
+    loss = ht.reduce_sum_op(ht.sin_op(y), axes=[0, 1, 2])
+    variables = graph_variables([loss], trainable_only=True)
+    ex = ht.Executor({"grads": [loss] + ht.gradients(loss, variables)},
+                     seed=3)
+    r = np.random.default_rng(5)
+    for var in variables:
+        ex.params[var.name] = jnp.asarray(
+            r.normal(0.2 if var.shape == (4,) else 0.0, 0.1, var.shape),
+            ex.params[var.name].dtype)
+    return ex, {x: r.normal(size=(2, 100, 64)).astype(np.float32)}
+
+
+def test_gradients_of_a_recomputed_delta_net_are_the_layers_to_the_bit(
+        monkeypatch, kept):
+    """Through the rule's kernels (interpret mode, ``mixed`` read in place),
+    f32, 100 positions (28 of padding): the backward kernel reads the
+    states and the inverses, and the mixer's norm the output, that the
+    forward pass wrote where the parent's read a second evaluation of the
+    same kernel; the loss and all seven weights' gradients are the
+    un-recomputed layer's, bit for bit, and the group counted its one call
+    and the bytes its shapes say (the padded 128 rows)."""
+    through_the_kernels(monkeypatch)
+    outs = []
+    for remat in (False, True):
+        ex, feed = delta_net_layer(f"rkept_gdn{int(remat)}", remat)
+        outs.append(ex.run("grads", feed_dict=feed,
+                           convert_to_numpy_ret_vals=True))
+        assert kept()[0] == ({"gdn": 1} if remat else {})
+        ex.close()
+    assert kept()[1] == delta_words(2, 4, 128, 4)
+    assert len(outs[0]) == 8
+    for plain, recomputed in zip(*outs):
+        assert np.abs(plain).max() > 0
+        assert (np.asarray(plain) == np.asarray(recomputed)).all()
+
+
 def test_the_names_live_in_one_place():
-    """One table beside the kernels' dispatch: the forward rule names its two
+    """One table beside the kernels' dispatch: a forward rule names its
     residuals from it, the groups' policy saves exactly its names."""
-    assert dispatch.KEPT == {"flash": ("attention_context", "attention_lse")}
+    assert dispatch.KEPT == {
+        "flash": ("attention_context", "attention_lse"),
+        "gdn": ("delta_rule_output", "delta_rule_states",
+                "delta_rule_inverses")}
+    saved = str(jax.make_jaxpr(jax.checkpoint(
+        lambda *a: sum(jnp.sin(t).sum() for t in (
+            dispatch.named("flash", *a[:2]) + dispatch.named("gdn", *a[2:]))),
+        policy=dispatch.KEEP_POLICY))(*(jnp.ones(n) for n in range(2, 7))))
+    for names in dispatch.KEPT.values():
+        for name in names:
+            assert f"name={name}" in saved
+    with pytest.raises(ValueError):
+        dispatch.named("gdn", jnp.ones(2), jnp.ones(3))
     o, lse = dispatch.named("flash", jnp.ones(2), jnp.ones(3))
     assert o.shape == (2,) and lse.shape == (3,)
     with pytest.raises(ValueError):
