@@ -5,54 +5,44 @@ and 6,488 M parameters), the builder at toy size against the plain reference
 with and without whole layers recomputed, the cell's rehearsal through the
 harness, its controls (each fails its term), the toy's train step lowered for
 a TPU at a head of 128 (which kernels a step calls, and how often), and its
-readers (``chipbench/tests/test_evabyte_readers.py``, collected here)."""
+readers (``chipbench/tests/test_evabyte_readers.py``, collected here).
+
+**A new cell's test calls ``declared(bench, CELL, own=...)`` of
+``tests/cells.py`` inside its ``table_part(bench)`` and states nothing else
+about ``BENCHMARK.json``**: no count of entries, configurations or cells, no
+index into them, no list of the quantities the cell reports (that list is
+``chipbench/testdata/per_layer/<cell>.json``).
+``tests/test_cells_declared.py`` runs ``table_part`` on a folded table and on
+one that holds more."""
 
 import json
-import os
 
 import numpy as np
 import pytest
 
+import cells
 from chipbench import run
 from chipbench.tests.test_evabyte_readers import *  # noqa: F401,F403
 
 CELL = "evabyte-6.5b.b1-s8192"
-#: the lists this cell joined: one entry of each quantity lists it
-QUANTITIES = (
-    "softmax_ce_roofline", "mfu", "device_idle_share", "peak_hbm_share",
-    "idle_h2d_ms_per_step", "idle_dispatch_ms_per_step",
-    "idle_fetch_ms_per_step", "idle_run_self_ms_per_step",
-    "idle_outside_run_ms_per_step", "attn_layout_copy_ms_per_step",
-    "window_attn_roofline", "window_attn_block_device_ms_per_step",
-    "attn_block_device_ms_per_step", "head_loss_device_ms_per_step",
-    "optim_device_ms_per_step", "step_unscoped_device_share",
-    "mlp_block_device_ms_per_step")
+#: the family's own mechanism: EVA's kernel pair, read as a window's
+OWN = ("window_attn_roofline", "window_attn_block_device_ms_per_step")
 TERMS = {"ce", "logits_gap", "eva_gap", "eva_remote_gap", "summary_gap",
          "nodes"}
 
 
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
+    # no layer runs the flash kernel
+    assert "flash_roofline" not in mine
+
+
 def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = bench["configs"][-1]
-    assert entry["name"] == "evabyte-6.5b-pretrain"
-    assert entry["reduced"] == config["reduced"] == ["num_hidden_layers"]
-    assert entry["source"] == config["source"]
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, "evabyte-6.5b-pretrain", "b1-s8192-evabyte", 1)
-    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
-    assert (len(bench["configs"]), len(bench["workloads"])) == (13, 14)
-    felt, = (m for m in bench["end_to_end"]
-             if m["name"] == "train_tokens_per_s")
-    assert felt["workloads"][-1] == CELL
-    # the contract's most, and this cell declared none of them
-    assert len(bench["per_layer"]) == 128
-    mine = [m for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert sorted(m["name"].split(".")[0] for m in mine) == sorted(QUANTITIES)
-    assert all(m["workloads"][-1] == CELL for m in mine)
-    assert not any(CELL in m["workloads"] for m in bench["per_layer"]
-                   if m["name"].startswith("flash_roofline"))
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "evabyte-6.5b-pretrain", "b1-s8192-evabyte", 1)
+    assert config["reduced"] == ["num_hidden_layers"]
     assert set(mix["reference_tolerance"]) == TERMS
     for key, value in {"batch": 1, "seq": 8192, "ring": 8, "warm_steps": 3,
                        "strategy": None, "mask_fraction": 1.0,

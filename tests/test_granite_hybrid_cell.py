@@ -5,24 +5,14 @@ builder at a toy size with the cell's HYBRID period through the benchmark's
 own loop against the plain reference, the cell's rehearsal through the
 harness, and the f32 state-space state, which the loss alone does not hold."""
 
-import json
-import os
-
 import numpy as np
 
+import cells
 from chipbench import flops_granitehybrid as fg, flops_nemotronh as fn, run
 
 CELL = "granite-4.0-h-micro.b1-s8192"
-#: the quantities the cell reports
-QUANTITIES = (
-    "attn_block_device_ms_per_step", "attn_layout_copy_ms_per_step",
-    "device_idle_share", "flash_roofline", "head_loss_device_ms_per_step",
-    "idle_dispatch_ms_per_step", "idle_fetch_ms_per_step",
-    "idle_h2d_ms_per_step", "idle_outside_run_ms_per_step",
-    "idle_run_self_ms_per_step", "mfu", "mlp_block_device_ms_per_step",
-    "optim_device_ms_per_step", "peak_hbm_share", "softmax_ce_roofline",
-    "ssd_scan_roofline", "ssm_block_device_ms_per_step",
-    "step_unscoped_device_share")
+#: the family's own mechanism: the state-space mixers and their scan
+OWN = ("ssd_scan_roofline", "ssm_block_device_ms_per_step")
 KINDS = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
 
 #: the catalog row's ``config`` (model-configs guide, architectures.jsonl,
@@ -44,11 +34,16 @@ PUBLISHED = {
     "tie_word_embeddings": True, "vocab_size": 100352}
 
 
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
+    assert all(mine[name]["moves"] == "train_tokens_per_s" for name in OWN)
+
+
 def test_configuration_file_holds_the_published_keys():
     """Every published key unchanged but the three in ``reduced``, whose
     published values stand in the ``deployment`` group beside the cut: one
     whole period of the layer pattern, an eighth of the vocabulary."""
-    _, _, config, _ = run.load_cell(CELL)
+    bench, cell, config, _ = run.load_cell(CELL)
     reduced = {"num_hidden_layers": 10, "layer_types": KINDS,
                "vocab_size": 12544}
     assert sorted(config["reduced"]) == sorted(reduced)
@@ -67,19 +62,9 @@ def test_configuration_file_holds_the_published_keys():
     assert dep["chips_sharing_a_layer"] == 1
     assert config["job"]["remat"] == "mamba"
     assert config["job"]["scan_chunk"] == 128 != config["mamba_chunk_size"]
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "granite-4.0-h-micro-pretrain")
-    assert sorted(entry["reduced"]) == sorted(reduced)
-    assert entry["source"] == config["source"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell["chips"] == 1 and cell["traffic"] == "b1-s8192-granite"
-    # by QUANTITY: some entry of each lists this cell (its own entry today,
-    # a folded one's list tomorrow), each moving the training throughput
-    per_layer = [m for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert sorted(m["name"].split(".")[0] for m in per_layer) == sorted(
-        QUANTITIES)
-    assert all(m["moves"] == "train_tokens_per_s" for m in per_layer)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "granite-4.0-h-micro-pretrain", "b1-s8192-granite", 1)
+    table_part(bench)
 
 
 def test_parameter_count_at_the_published_widths():
@@ -136,8 +121,8 @@ def test_flops_of_the_cut_configuration():
 def hybrid_toy(say=lambda msg: None, seq=192, **widths):
     """The cell's program at toy widths with the cell's own period (nine
     Mamba-2 layers, one attention layer; the configuration's own ``toy`` is
-    all attention, see its ``why_all_attention``) over a whole chunk of 128
-    positions and half of a second."""
+    a hybrid of one layer of each kind, see its ``why_pattern``) over a whole
+    chunk of 128 positions and half of a second."""
     from chipbench.builders import granite_hybrid as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])

@@ -11,6 +11,7 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_laguna as fl, run
 from chipbench.tests.test_laguna_readers import *  # noqa: F401,F403
 
@@ -21,6 +22,8 @@ REDUCED = {"num_hidden_layers": 5, "num_experts": 32, "vocab_size": 12544,
            + ["full_attention"],
            "mlp_layer_types": ["dense"] + ["sparse"] * 4,
            "num_attention_heads_per_layer": [48, 64, 64, 64, 48]}
+#: the family's own mechanism: the window layers' kernel pair
+OWN = ("window_attn_roofline", "window_attn_block_device_ms_per_step")
 
 
 def published():
@@ -56,21 +59,15 @@ def test_configuration_file_holds_the_published_keys():
                                                8)
 
 
+def table_part(bench):
+    cells.declared(bench, CELL, own=OWN)
+
+
 def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "laguna-xs.2-pretrain")
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert len(bench["per_layer"]) <= 128
-    # by QUANTITY: some entry of each lists this cell
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    assert len(mine) == 23
-    for name in ("flash_roofline", "mfu", "moe_experts_roofline",
-                 "attn_block_device_ms_per_step", "window_attn_roofline",
-                 "window_attn_block_device_ms_per_step"):
-        assert mine.count(name) == 1, name
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert cell["config"] == "laguna-xs.2-pretrain"
+    assert sorted(config["reduced"]) == sorted(REDUCED)
     for key in ("window", "no_qk_norm", "gate_a_head", "rotary", "router",
                 "shared_expert", "loss", "job", "remat"):
         assert key in config["assumed"], key
@@ -170,9 +167,9 @@ def test_the_cells_builder_at_toy_size(remat):
         second = prog.step(feed)
         assert np.isfinite(second) and second != first
         shapes = prog.expected_kernel_shapes()
-        assert prog.forward_passes == (2 if remat == "layer" else 1)
-        assert prog.window_forward_passes == (1 if remat is None else 2)
-        assert shapes["attention_layers"] == 2 * prog.forward_passes
+        assert shapes["attention_passes"] == 2
+        assert shapes["window_layers"] == 3
+        assert shapes["attention_layers"] == (4 if remat == "layer" else 2)
         assert shapes["flash_dims"] == (1, 6, 64, 16)
         assert shapes["window_dims"] == (1, 8, 64, 16)
         assert prog.n_layers == 4 and prog.probed == (1, 4)

@@ -10,12 +10,15 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_ling3 as fl, run
 
 CELL = "ling-3.0-flash-vl.b1-s8192"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 7, "first_k_dense_replace": 1,
            "num_experts": 8, "vocab_size": 19648}
+#: the family's own mechanism: the delta rule with a decay a key channel
+OWN = ("kda_scan_roofline", "kda_block_device_ms_per_step")
 
 
 def published():
@@ -41,20 +44,15 @@ def test_configuration_file_holds_the_published_keys():
     assert entry["chips"] == 1
 
 
+def table_part(bench):
+    cells.declared(bench, CELL, own=OWN)
+
+
 def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "ling-3.0-flash-vl-pretrain")
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert len(bench["per_layer"]) <= 128
-    # by QUANTITY: some entry of each lists this cell
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    for name in ("kda_scan_roofline", "kda_block_device_ms_per_step",
-                 "flash_roofline", "mfu", "peak_hbm_share",
-                 "moe_experts_roofline", "softmax_ce_roofline"):
-        assert mine.count(name) == 1, name
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert cell["config"] == "ling-3.0-flash-vl-pretrain"
+    assert sorted(config["reduced"]) == sorted(REDUCED)
     for key in ("kda_gate", "kda_projections", "attention_gate", "qk_norm",
                 "kda_heads", "head", "router", "rotary", "remat"):
         assert key in config["assumed"], key
@@ -94,8 +92,8 @@ def test_flops_and_parameters_of_the_cut_configuration():
 def hybrid_toy(say=lambda msg: None, head_dim=32, **job):
     """The cell's program at toy widths with the published layer pattern
     behind one dense layer (K K K K K A K) and whole layers recomputed (the
-    configuration's own ``toy`` is all attention, see its
-    ``why_all_attention``)."""
+    configuration's own ``toy`` is a hybrid of one layer of each kind, see
+    its ``why_pattern``)."""
     from chipbench.builders import ling3 as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])
@@ -120,9 +118,8 @@ def test_the_cells_builder_at_a_hybrid_toy_size(remat):
         second = prog.step(feed)
         assert np.isfinite(second) and second != first
         shapes = prog.expected_kernel_shapes()
-        passes = 2 if remat == "layer" else 1
-        assert prog.forward_passes == passes
-        assert shapes["attention_layers"] == passes
+        assert shapes["attention_passes"] == 1
+        assert shapes["attention_layers"] == (2 if remat == "layer" else 1)
         assert shapes["flash_dims"] == (1, 2, 64, 32)
         assert shapes["score_dim"] == 48 and prog.n_layers == 6
     finally:

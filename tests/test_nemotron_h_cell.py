@@ -6,16 +6,16 @@ configuration states that the loss terms alone do not hold: dropless routing
 all through the window, the f32 state-space state, and the rule that moves
 the router's bias."""
 
-import json
-import os
-
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_nemotronh as fn, run
 
 CELL = "nemotron-3-nano-30b-a3b.b1-s8192"
 PATTERN = "MEMEM*EME"
+#: the family's own mechanism: the state-space mixers and their scan
+OWN = ("ssd_scan_roofline", "ssm_block_device_ms_per_step")
 
 #: the catalog row's ``config`` (model-configs guide, architectures.jsonl,
 #: NVIDIA-Nemotron-3-Nano-30B-A3B-BF16), every key of it
@@ -43,10 +43,14 @@ PUBLISHED = {
     "use_mamba_kernels": True, "vocab_size": 131072}
 
 
+def table_part(bench):
+    cells.declared(bench, CELL, own=OWN)
+
+
 def test_configuration_file_holds_the_published_keys():
     """Every published key unchanged but the four in ``reduced``, whose
     published values stand in the ``deployment`` group beside the cut."""
-    _, _, config, _ = run.load_cell(CELL)
+    bench, cell, config, _ = run.load_cell(CELL)
     reduced = {"num_hidden_layers": 9, "hybrid_override_pattern": PATTERN,
                "n_routed_experts": 8, "vocab_size": 16384}
     assert sorted(config["reduced"]) == sorted(reduced)
@@ -63,13 +67,9 @@ def test_configuration_file_holds_the_published_keys():
     assert (dep["pipeline_stages"] - 1) * dep["blocks_a_stage"] < 52 <= (
         dep["pipeline_stages"] * dep["blocks_a_stage"])
     assert dep["blocks_a_stage"] == config["num_hidden_layers"]
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "nemotron-3-nano-30b-a3b-pretrain")
-    assert sorted(entry["reduced"]) == sorted(reduced)
-    assert entry["source"] == config["source"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell["chips"] == 1 and cell["traffic"] == "b1-s8192-nemotron"
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "nemotron-3-nano-30b-a3b-pretrain", "b1-s8192-nemotron", 1)
+    table_part(bench)
 
 
 def test_flops_of_the_cut_configuration():
@@ -99,8 +99,8 @@ def test_flops_of_the_cut_configuration():
 def hybrid_toy(say=lambda msg: None, **widths):
     """The cell's program at toy widths with the cell's own pattern (four
     Mamba-2 mixers, four expert blocks, one attention block; the
-    configuration's own ``toy`` is all attention, see its
-    ``why_all_attention``)."""
+    configuration's own ``toy`` is a hybrid of one block of each kind, see
+    its ``why_pattern``)."""
     from chipbench.builders import nemotron_h as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])

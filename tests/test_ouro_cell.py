@@ -11,23 +11,15 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_ouro as fo, run
 
 CELL = "ouro-2.6b.b1-s8192"
 CONFIG = "ouro-2.6b-pretrain"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 8, "layer_types": ["full_attention"] * 8}
-JOINED = ("flash_roofline", "softmax_ce_roofline", "mfu", "peak_hbm_share",
-          "device_idle_share", "idle_h2d_ms_per_step",
-          "idle_dispatch_ms_per_step", "idle_fetch_ms_per_step",
-          "idle_run_self_ms_per_step", "idle_outside_run_ms_per_step",
-          "attn_layout_copy_ms_per_step")
-#: quantities the cell declared for itself (an entry of its own today; a
-#: folded file lists the cell in the quantity's one entry)
-DECLARED = ("attn_block_device_ms_per_step", "mlp_block_device_ms_per_step",
-            "head_loss_device_ms_per_step", "optim_device_ms_per_step",
-            "step_unscoped_device_share",
-            "exit_block_device_ms_per_step", "loop_recompute_device_share")
+#: the family's own mechanism: the exit gate and the loop's recomputation
+OWN = ("exit_block_device_ms_per_step", "loop_recompute_device_share")
 
 
 def published():
@@ -69,38 +61,21 @@ def test_configuration_file_holds_the_published_keys():
     assert toy["num_hidden_layers"] == 2 and "total_ut_steps" not in toy
 
 
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
+    assert all(mine[name]["moves"] == "train_tokens_per_s" for name in OWN)
+    assert (mine["loop_recompute_device_share"]["unit"],
+            mine["exit_block_device_ms_per_step"]["unit"]) == ("%", "ms")
+
+
 def test_benchmark_entries():
-    """One configuration, one cell on one chip, the cell's name in
-    ``train_tokens_per_s``'s list and in the eleven joined quantities', seven
-    entries of its own: eighteen that the cell reports.  Every entry is
-    found by its name: what a later PR appends moves nothing here."""
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    _, _, config, _ = run.load_cell(CELL)
-    entry, = (c for c in bench["configs"] if c["name"] == CONFIG)
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert entry["source"] == config["source"]
-    cell, = (w for w in bench["workloads"] if w["name"] == CELL)
+    """The cell on one chip; the exit gate's block and the loop's recomputed
+    share are entries that move the training throughput."""
+    bench, cell, config, _ = run.load_cell(CELL)
+    table_part(bench)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "b1-s8192-ouro", 1)
-    assert [w["name"] for w in bench["workloads"]
-            if w["config"] == CONFIG] == [CELL]
-    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
-    felt, = (m for m in bench["end_to_end"]
-             if m["name"] == "train_tokens_per_s")
-    assert CELL in felt["workloads"]
-    # by QUANTITY: ONE entry of each lists this cell, whatever its name
-    by_quantity = {}
-    for m in bench["per_layer"]:
-        if CELL in m.get("workloads", ()):
-            by_quantity.setdefault(m["name"].split(".")[0], []).append(m)
-    assert sorted(by_quantity) == sorted(JOINED + DECLARED)
-    assert all(len(entries) == 1 for entries in by_quantity.values())
-    for name in DECLARED:
-        assert by_quantity[name][0]["moves"] == "train_tokens_per_s"
-    assert (by_quantity["loop_recompute_device_share"][0]["unit"],
-            by_quantity["exit_block_device_ms_per_step"][0]["unit"]) == (
-        "%", "ms")
-    assert len(bench["per_layer"]) <= 128
+    assert sorted(config["reduced"]) == sorted(REDUCED)
 
 
 def test_parameter_count_at_the_published_widths():

@@ -7,54 +7,34 @@ calls, and how often), and its readers
 (``chipbench/tests/test_phi4flash_readers.py``, collected here)."""
 
 import json
-import os
 
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_phi4flash as fp, run
 from chipbench.tests.test_phi4flash_readers import *  # noqa: F401,F403
 
 CELL = "phi-4-mini-flash.b1-s16384"
-#: the lists this cell joined: one entry of each quantity lists it
-QUANTITIES = (
-    "flash_roofline", "softmax_ce_roofline", "mfu", "device_idle_share",
-    "peak_hbm_share", "idle_h2d_ms_per_step", "idle_dispatch_ms_per_step",
-    "idle_fetch_ms_per_step", "idle_run_self_ms_per_step",
-    "idle_outside_run_ms_per_step", "attn_layout_copy_ms_per_step",
-    "window_attn_roofline", "window_attn_block_device_ms_per_step",
-    "attn_block_device_ms_per_step", "head_loss_device_ms_per_step",
-    "optim_device_ms_per_step", "step_unscoped_device_share",
-    "ssm_block_device_ms_per_step", "mlp_block_device_ms_per_step",
-    "selective_scan_roofline")
+#: the family's own mechanism: the Mamba-1 scan's kernel pair
+OWN = ("selective_scan_roofline",)
 TERMS = {"ce", "logits_gap", "scan_gap", "scan_probe_gap", "window_gap",
          "window_edge", "attention_gap", "gmu_gap", "cross_gap", "nodes"}
 
 
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
+    scan = mine["selective_scan_roofline"]
+    assert (scan["unit"], scan["better"], scan["source"], scan["layer"],
+            scan["moves"]) == ("%", "higher", "device_trace", "kernels",
+                               "train_tokens_per_s")
+
+
 def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "phi-4-mini-flash-reasoning-train")
-    assert entry["reduced"] == config["reduced"]
-    assert entry["source"] == config["source"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "phi-4-mini-flash-reasoning-train", "b1-s16384-phi4flash", 1)
-    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
-    felt, = (m for m in bench["end_to_end"]
-             if m["name"] == "train_tokens_per_s")
-    assert CELL in felt["workloads"]
-    # the contract's most: every further entry waits for the fold
-    assert len(bench["per_layer"]) == 128
-    # by QUANTITY: ONE entry of each lists this cell, whatever its name
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    assert sorted(mine) == sorted(QUANTITIES)
-    assert bench["per_layer"][-1] == {
-        "name": "selective_scan_roofline", "unit": "%", "better": "higher",
-        "source": "device_trace", "layer": "kernels",
-        "moves": "train_tokens_per_s", "workloads": [CELL]}
     assert set(mix["reference_tolerance"]) == TERMS
     for key, value in {"batch": 1, "seq": 16384, "ring": 8, "warm_steps": 3,
                        "strategy": None, "mask_fraction": 1.0}.items():

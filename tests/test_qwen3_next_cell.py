@@ -5,15 +5,15 @@ rehearsal through the harness, and the two things the configuration states
 that the loss terms alone do not hold: dropless routing all through the
 window, and the f32 DeltaNet state."""
 
-import json
-import os
-
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_qwen3next as fq, run
 
 CELL = "qwen3-next-80b-a3b.b1-s8192"
+#: the family's own mechanism: the gated delta rule's mixers and their scan
+OWN = ("gdn_scan_roofline", "gdn_block_device_ms_per_step")
 
 #: the catalog row's ``config`` (model-configs guide, architectures.jsonl,
 #: Qwen3-Next-80B-A3B-Instruct), every number of it
@@ -33,10 +33,17 @@ PUBLISHED = {
     "use_sliding_window": False, "vocab_size": 151936}
 
 
+def table_part(bench):
+    cells.declared(bench, CELL, own=OWN)
+    # the contract's rule: four-chip cells are at most a quarter of the cells
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(bench["workloads"]) // 4)
+
+
 def test_configuration_file_holds_the_published_keys():
     """Every published key unchanged but the three in ``reduced``, whose
     published values stand in the ``deployment`` group beside the cut."""
-    _, _, config, _ = run.load_cell(CELL)
+    bench, cell, config, _ = run.load_cell(CELL)
     reduced = {"num_hidden_layers": 4, "num_experts": 32,
                "vocab_size": 18992}
     assert sorted(config["reduced"]) == sorted(reduced)
@@ -49,12 +56,8 @@ def test_configuration_file_holds_the_published_keys():
     assert dep["experts_held"] == [0, 32]
     assert dep["vocabulary_divided"] * config["vocab_size"] == 151936
     assert dep["pipeline_stages"] * config["num_hidden_layers"] == 48
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "qwen3-next-80b-a3b-pretrain")
-    assert sorted(entry["reduced"]) == sorted(reduced)
-    cells = [w for w in bench["workloads"] if w["chips"] == 4]
-    assert len(cells) <= max(1, len(bench["workloads"]) // 4)
+    assert cell["config"] == "qwen3-next-80b-a3b-pretrain"
+    table_part(bench)
 
 
 def test_flops_of_the_cut_configuration():
@@ -81,7 +84,7 @@ def test_flops_of_the_cut_configuration():
 def hybrid_toy(say=lambda msg: None, **widths):
     """The cell's program at toy widths with the published layer pattern
     (three DeltaNet layers, one attention layer; the configuration's own
-    ``toy`` is all attention, see its ``why_all_attention``)."""
+    ``toy`` is a hybrid of one layer of each kind, see its ``why_pattern``)."""
     from chipbench.builders import qwen3_next as builder
     _, _, config, mix = run.load_cell(CELL)
     config = run.merge(config, config["toy"])

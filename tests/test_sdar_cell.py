@@ -11,23 +11,15 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_sdar as fs, run
 from chipbench.tests.test_sdar_readers import *  # noqa: F401,F403
 
 CELL = "sdar-30b-a3b.b1-s8192"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 6, "num_experts": 16, "vocab_size": 18992}
-#: the lists this cell joined: one entry of each quantity lists it
-QUANTITIES = (
-    "flash_roofline", "softmax_ce_roofline", "mfu", "device_idle_share",
-    "peak_hbm_share", "idle_h2d_ms_per_step", "idle_dispatch_ms_per_step",
-    "idle_fetch_ms_per_step", "idle_run_self_ms_per_step",
-    "idle_outside_run_ms_per_step", "moe_block_device_ms_per_step",
-    "moe_experts_roofline", "moe_dropped_share", "moe_load_max_over_mean",
-    "attn_layout_copy_ms_per_step", "moe_held_pair_share",
-    "attn_block_device_ms_per_step", "head_loss_device_ms_per_step",
-    "optim_device_ms_per_step", "step_unscoped_device_share",
-    "diffusion_masked_share")
+#: the family's own mechanism: the masked share of a block-diffusion batch
+OWN = ("diffusion_masked_share",)
 
 
 def row():
@@ -76,33 +68,22 @@ def test_configuration_file_holds_the_published_keys():
         "generation", "packing", "exchange", "keys_unused"}
 
 
-def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "sdar-30b-a3b-chat-train")
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert entry["source"] == config["source"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "sdar-30b-a3b-chat-train", "b1-s8192-sdar", 1)
-    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
-    felt, = (m for m in bench["end_to_end"]
-             if m["name"] == "train_tokens_per_s")
-    assert CELL in felt["workloads"]
-    assert len(bench["per_layer"]) <= 128
-    # by QUANTITY: ONE entry of each lists this cell, whatever its name
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    assert sorted(mine) == sorted(QUANTITIES)
-    new, = (m for m in bench["per_layer"]
-            if m["name"] == "diffusion_masked_share")
-    assert new == {"name": "diffusion_masked_share", "unit": "%",
-                   "better": "higher", "source": "program_counter",
-                   "layer": "model step", "moves": "train_tokens_per_s",
-                   "workloads": [CELL]}
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
+    new = mine["diffusion_masked_share"]
+    assert (new["unit"], new["better"], new["source"], new["layer"],
+            new["moves"]) == ("%", "higher", "program_counter", "model step",
+                              "train_tokens_per_s")
     # no dense FFN: the row hetu_mlp would read nothing in this cell
     assert not [n for n in mine if n.startswith("mlp_block")]
+
+
+def test_benchmark_entries():
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "sdar-30b-a3b-chat-train", "b1-s8192-sdar", 1)
+    assert sorted(config["reduced"]) == sorted(REDUCED)
     assert set(mix["reference_tolerance"]) == {
         "ce", "ce_masked", "logits_gap", "attention_gap", "routing_mismatch",
         "dropped"}

@@ -11,6 +11,7 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_xing4 as fl, run
 from chipbench.tests.test_xing4_readers import *  # noqa: F401,F403
 
@@ -18,6 +19,9 @@ CELL = "xing4.0-29b-a4b.b1-s4096"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 5, "first_k_dense_replace": 1,
            "n_routed_experts": 8, "vocab_size": 16384}
+#: the family's own mechanism: hyper-connections and the MTP depth
+OWN = ("hc_block_device_ms_per_step", "hc_mix_roofline",
+       "mtp_block_device_ms_per_step")
 
 
 def published():
@@ -55,22 +59,15 @@ def test_configuration_file_holds_the_published_keys():
                                              64, 128, 4, 4, 20)
 
 
+def table_part(bench):
+    cells.declared(bench, CELL, own=OWN)
+
+
 def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "xing4.0-29b-a4b-pretrain")
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert len(bench["per_layer"]) <= 128
-    # by QUANTITY: some entry of each lists this cell
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    assert len(mine) == 24
-    for name in ("flash_roofline", "mfu", "moe_experts_roofline",
-                 "softmax_ce_roofline", "attn_block_device_ms_per_step",
-                 "hc_block_device_ms_per_step", "hc_mix_roofline",
-                 "mtp_block_device_ms_per_step"):
-        assert mine.count(name) == 1, name
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert cell["config"] == "xing4.0-29b-a4b-pretrain"
+    assert sorted(config["reduced"]) == sorted(REDUCED)
     for key in ("streams", "hc_norm", "hc_maps", "sinkhorn_order",
                 "hc_initial_values", "mla", "yarn", "router",
                 "shared_expert", "mtp", "dense_layers"):
@@ -159,10 +156,8 @@ def test_the_cells_builder_at_toy_size(remat):
         second = prog.step(feed)
         assert np.isfinite(second) and second != first
         shapes = prog.expected_kernel_shapes()
-        passes = 2 if remat == "layer" else 1
-        assert prog.forward_passes == passes
         assert shapes["attention_passes"] == 3
-        assert shapes["attention_layers"] == 3 * passes
+        assert shapes["attention_layers"] == (6 if remat == "layer" else 3)
         assert shapes["flash_dims"] == (1, 2, 64, 32)
         assert shapes["score_dim"] == 48 and shapes["hc_sublayers"] == 6
         assert prog.n_layers == 2 and prog.probed_layer == 1

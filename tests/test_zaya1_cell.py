@@ -11,6 +11,7 @@ import os
 import numpy as np
 import pytest
 
+import cells
 from chipbench import flops_zaya1 as fz, run
 from chipbench.tests.test_zaya1_readers import *  # noqa: F401,F403
 
@@ -19,6 +20,10 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 5, "layer_types": ["hybrid"] * 5,
            "num_experts": 8, "vocab_size": 32784}
 SIBLINGS = ("ZAYA1-base", "ZAYA1-VL-8B")
+#: the family's own mechanism: compressed convolutional attention, and the
+#: router's skip
+OWN = ("cca_block_device_ms_per_step", "cca_mix_roofline",
+       "moe_skipped_share")
 
 
 def rows():
@@ -74,24 +79,17 @@ def test_the_sibling_rows_carry_what_this_row_dropped():
         assert catalog[name]["config"]["cca"] is taken["cca"] is True
 
 
-def test_benchmark_entries():
-    _, _, config, mix = run.load_cell(CELL)
-    bench = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "zaya1-8b-pretrain")
-    assert sorted(entry["reduced"]) == sorted(REDUCED)
-    assert len(bench["per_layer"]) <= 128
-    # by QUANTITY: some entry of each lists this cell
-    mine = [m["name"].split(".")[0] for m in bench["per_layer"]
-            if CELL in m["workloads"]]
-    assert len(mine) == 23
-    for name in ("flash_roofline", "mfu", "moe_experts_roofline",
-                 "softmax_ce_roofline", "attn_block_device_ms_per_step",
-                 "cca_block_device_ms_per_step", "cca_mix_roofline",
-                 "moe_skipped_share", "moe_held_pair_share"):
-        assert mine.count(name) == 1, name
+def table_part(bench):
+    mine = cells.declared(bench, CELL, own=OWN)
     # no dense FFN: the row hetu_mlp would read nothing in this cell
     assert not [n for n in mine if n.startswith("mlp_block")]
+
+
+def test_benchmark_entries():
+    bench, cell, config, mix = run.load_cell(CELL)
+    table_part(bench)
+    assert cell["config"] == "zaya1-8b-pretrain"
+    assert sorted(config["reduced"]) == sorted(REDUCED)
     for key in ("zaya_use_eda", "zaya_use_mod", "scale_residual_merge",
                 "router_mlp", "router_bias", "value_halves", "temperature",
                 "rotary", "qk_mean", "convolutions", "initial_values"):
