@@ -28,12 +28,18 @@ reads ``z`` out of the projection's output itself; on a TPU the gate and the
 norm are the Pallas kernels ``hetu_gated_norm_fwd`` and ``hetu_gated_norm_bwd``
 over blocks of whole groups, and ``_out`` below is the ``jax.numpy`` form they
 are held to; under a mesh and on any other platform ``_out`` runs).  The scan is
-``ops/ssd.py chunk_ssd``: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
+``ops/ssd.py``'s: on a TPU the Pallas kernels ``hetu_ssd_fwd`` and
 ``hetu_ssd_bwd`` where their rule takes the operands (a group of more than
-eight heads as blocks of heads, ``ops/pallas/ssd.py``), under a mesh and on
-any other platform the ``jax.numpy`` form (the node is an ``ops/base.py
-KernelOp``); the softplus, ``-exp(A_log)`` and the skip stay XLA's under the
-same scope.  A decode step,
+eight heads as blocks of heads, ``ops/pallas/ssd.py``).  The node asks
+``chunk_ssd_in_place`` first: the kernels then read ``x``, ``B`` and ``C``
+out of ``xBC`` where the convolution wrote them and add the skip ``D x``
+themselves (no slice of ``xBC`` and no f32 pass behind the scan in HBM), and
+only the softplus, ``-exp(A_log)``, ``a = dt A`` and ``dt``'s turn to a chunk
+along the lanes stay XLA's under the same scope.  Where that entry's rule
+refuses (``B``'s window not at a whole block, a length that would be padded)
+``_scan`` slices and adds the skip around ``chunk_ssd`` and the same kernels;
+under a mesh and on any other platform around the ``jax.numpy`` form (the
+node is an ``ops/base.py KernelOp``).  A decode step,
 and the state ``[H, P, N]`` with the convolution's last ``K - 1`` inputs in a
 serving cache, are not here (ROADMAP Queue 2).
 """
@@ -58,17 +64,27 @@ def _part(zxbcdt, *, lo, hi):
 
 def _scan(xbc, dt, dt_bias, a_log, d_skip, *, heads, head_dim, groups,
           state, chunk, rule=None):
+    """Gates, the scan and the skip: from ``xbc`` as the convolution wrote it
+    where the scan's kernels read it in place and add the skip themselves
+    (``rule`` None and ``chunk_ssd_in_place`` takes the operands), else the
+    three slices and the skip here, around ``chunk_ssd``."""
     import jax
     import jax.numpy as jnp
     B, S, _ = xbc.shape
     f32 = jnp.float32
     d, gn = heads * head_dim, groups * state
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    A = -jnp.exp(a_log.astype(f32))
+    if rule is None:
+        y = ssd.chunk_ssd_in_place(xbc, dt, A, d_skip, heads=heads,
+                                   head_dim=head_dim, groups=groups,
+                                   state=state, chunk=chunk)
+        if y is not None:
+            return y
     x = xbc[..., :d].reshape(B, S, heads, head_dim)
     Bm = xbc[..., d:d + gn].reshape(B, S, groups, state)
     Cm = xbc[..., d + gn:].reshape(B, S, groups, state)
-    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-    y, _ = (rule or ssd.chunk_ssd)(x, dt, -jnp.exp(a_log.astype(f32)), Bm,
-                                   Cm, chunk=chunk)
+    y, _ = (rule or ssd.chunk_ssd)(x, dt, A, Bm, Cm, chunk=chunk)
     y = y.astype(f32) + d_skip.astype(f32)[:, None] * x.astype(f32)
     return y.astype(xbc.dtype).reshape(B, S, d)
 

@@ -41,7 +41,8 @@ operands in the compute type and adds in f32; the one rounding a product
 rebuilds its chunk's decays and scores from ``x, dt, a, B, C`` and the kept
 chunk-start state and writes ``dx``, the gradients of ``dt`` (where it scales
 a product's operand) and of ``a``, and ``dB`` and ``dC`` summed over the
-group's heads in VMEM, each once.  ``dA`` and the rest of ``ddt`` follow
+group's heads in VMEM, each once (in place also the skip's part of ``dx`` and
+``dD``'s partial sums, below).  ``dA`` and the rest of ``ddt`` follow
 from ``a = dt A`` in XLA.  The gradient of ``a`` is the reverse running sum,
 inside the chunk, of the gradient of its running sum, which is taken term by
 term from the decays it enters (the ``[128, 128]`` matrix as a row and as a
@@ -54,6 +55,36 @@ after they cancel was 3% off in ``dA`` where the ``jax.numpy`` form is
 0.08%.)  Every sum over a chunk's positions runs down the sublanes, after a
 transposition where it has to: a sum along the lanes costs a register's
 rotations, and took a third of the kernel's time.
+
+Two entries hand the pair its operands (as ``kda`` / ``kda_in_place`` and
+``gated_delta_rule`` / ``gated_delta_rule_in_place`` do), chosen by what the
+caller holds and counted in ``hetu_ssd_form_total{form}`` (``forms()``).
+``ssd`` (``plain``) takes ``x``, ``B`` and ``C`` as arrays of their own:
+``chunk_ssd``'s operands, the benchmark's probe.  ``ssd_in_place`` (PR 71,
+what the layer runs) takes ``xBC [b, T, d + 2 G N]`` as the convolution wrote
+it, three times: the three windows are blocks of the ONE array whose
+lane-block index starts at the window's (``_plan``): ``x`` blocks of ``R P``
+lanes at ``g``, ``B`` blocks of ``N`` lanes at ``d / N + g // wide`` and ``C``
+at ``d / N + G + g // wide`` (Granite's 4,352 lanes are eight and a half ``x``
+blocks: the half is never indexed), so no slice of ``xBC`` reaches HBM,
+forward, recomputed or backward (a Pallas operand is a whole array: XLA wrote
+each slice and the kernel read it back, 0.17 ms a call for ``x`` alone at
+8,192 x 4,096 bf16).  The rule that admits it is read from the shapes
+(``in_place_unsupported``: ``d`` whole blocks of ``N`` lanes, as it is of ``R
+P``; ``T`` whole blocks of ``CHUNKS x 128`` positions, or fewer chunks in one
+program, since padding ``xBC`` is the copy it saves); where it refuses the
+caller slices as before.  In place the skip ``D x`` is the kernels' too.
+Forward, on the chunk the program holds: ``y = round(y)``, then ``round(f32(y)
++ D_h f32(x))``, rounded where the layer's ``jax.numpy`` lines round, so the
+values are theirs bit for bit (v5e, my chip run, PR 71); ``D [H]`` enters f32
+on its heads' lanes, ``[G', 1, R P]``, a block a program.  Backward by hand:
+``dx += D_h dy`` in f32 before ``dx``'s one rounding, and ``dD`` as f32
+partial sums ``sum(dy x)`` over a program's positions in an output block ``[b,
+G', 1, R P]`` that stays in VMEM along the sequence axis; XLA adds the batch
+rows and a head's lanes up.  ``d xBC`` is ONE concatenation ``dx | dB | dC``
+by XLA.  (Slices, kernels and XLA's f32 skip 5.54 ms a mixer forward and
+backward at the Granite cell's shape, in place 3.70; 5.56 and 3.68 at the
+Nemotron-H cell's: v5e, my chip run, PR 71.)
 
 What the backward keeps: the chunk-start states (``N x P`` f32 a chunk and
 head, 134 MB for a mixer of the Nemotron-H cell, alive only while that
@@ -251,9 +282,11 @@ def _bwd_close(Bm, Cm, BT, CT, dCB, dBT, dCT, dcs, ddt, end, dt_r):
     return dBT.T, dCT.T, _sum_from(dcs - dt_r * ddt) + end
 
 
-def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
-                s_ref, *, nc, heads, p):
+def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, *refs, nc, heads, p,
+                skip):
     import jax.experimental.pallas as pl
+    d_ref = refs[0] if skip else None            # ``D`` on its heads' lanes
+    y_ref, last_ref, s0_ref, s_ref = refs[-4:]
     i = pl.program_id(2)
     lanes = [slice(h * p, (h + 1) * p) for h in range(heads)]
 
@@ -270,11 +303,16 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
         c["fs_c"] = jnp.exp(c["cs_c"])     # the decay from the chunk's start
         CS = _dot(Cm, ST.astype(Cm.dtype), NN)        # [L, R P]: every head's
         for h, at in enumerate(lanes):
+            x = x_ref[rows, at]
             y, S = _head_fwd(
-                x_ref[rows, at], c["CB"], CS[:, at], ST[:, at], c["BT"],
+                x, c["CB"], CS[:, at], ST[:, at], c["BT"],
                 *_of_head(c, h, ("cs_c", "cs_r", "dt_r", "te_r", "fs_c",
                                  "last")))
-            y_ref[rows, at] = y.astype(y_ref.dtype)
+            y = y.astype(y_ref.dtype)
+            if skip:        # rounded where the layer's jax.numpy lines round
+                y = (y.astype(_F32) + d_ref[:, at] * x.astype(_F32)
+                     ).astype(y_ref.dtype)
+            y_ref[rows, at] = y
             s_ref[:, at] = S
     walk(nc, body)
 
@@ -284,15 +322,21 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, last_ref, s0_ref,
 
 
 def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
-                dlast_ref, dx_ref, db_ref, dc_ref, ddt_ref, da_ref, ds_ref,
-                end_ref, *, nc, heads, p):
+                dlast_ref, *refs, nc, heads, p, skip):
     import jax.experimental.pallas as pl
+    if skip:            # ``D``'s row in, its gradient's partial sums out
+        d_ref, dx_ref, db_ref, dc_ref, ddt_ref, da_ref, dd_ref, ds_ref, \
+            end_ref = refs
+    else:
+        dx_ref, db_ref, dc_ref, ddt_ref, da_ref, ds_ref, end_ref = refs
     lanes = [slice(h * p, (h + 1) * p) for h in range(heads)]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = dlast_ref[...]
         end_ref[...] = _state_dot(dlast_ref[...], last_ref[...], heads=heads)
+        if skip:
+            dd_ref[...] = jnp.zeros_like(dd_ref)
 
     def body(n):
         j = nc - 1 - n
@@ -306,11 +350,14 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
         dcs = ddt = jnp.zeros((heads, L), _F32)
         dCB = dBT = dCT = 0.0
         for h, at in enumerate(lanes):
+            dy = dy_ref[rows, at]
             dx, dCB_h, dBT_h, dCT_h, ddt_h, dcs_h, dS0T = _head_bwd(
-                x_ref[rows, at], dy_ref[rows, at], c["CB"], S0T[:, at],
+                x_ref[rows, at], dy, c["CB"], S0T[:, at],
                 dST[:, at], c["BT"], CT,
                 *_of_head(c, h, ("cs_c", "cs_r", "dt_r", "te_r", "fs_r",
                                  "last")))
+            if skip:
+                dx = dx + d_ref[:, at] * dy.astype(_F32)
             dx_ref[rows, at] = dx.astype(dx_ref.dtype)
             ds_ref[:, at] = dS0T
             dCB, dBT, dCT = dCB + dCB_h, dBT + dBT_h, dCT + dCT_h
@@ -324,106 +371,162 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, s0_ref, last_ref, dy_ref,
         dc_ref[rows, :] = dC.astype(dc_ref.dtype)
         ddt_ref[j] = ddt
         da_ref[j] = da
+        if skip:    # dD: sum(dy x) over the chunk's rows, every head's lanes
+            dd_ref[...] += jnp.sum(
+                dy_ref[rows, :].astype(_F32) * x_ref[rows, :].astype(_F32),
+                axis=0, keepdims=True)
     walk(nc, body)
 
 
-def _plan(x, Bm, dt, reverse, wide):
-    """Grid, the kernels' static sizes and the block specs of x / y, B / C
-    (and dB / dC), dt / a, the kept states and a state; ``reverse``: the
-    blocks of chunks from the last to the first.  The grid's second axis is
-    over blocks of heads (``dt``'s second dimension), ``wide`` of them to a
-    group: they read the one ``B`` and ``C`` of their group and each writes
-    its own part of ``dB`` and ``dC``."""
+def _plan(dt, rp, N, reverse, wide, d=None):
+    """Grid, the kernels' static sizes and the block specs by name: ``x`` (and
+    y, dy, dx), ``B`` and ``C`` as the kernels read them, ``part`` (dB and dC
+    as the backward kernel writes them), ``gate`` (dt and a), the ``kept``
+    states, a ``state``, ``skip`` (``D`` on a program's lanes) and ``dskip``
+    (its gradient's partial sums, a row a sequence: the block stays where it
+    is along the grid's last axis); ``reverse``: the blocks of chunks from the
+    last to the first.  The grid's second axis is over blocks of heads
+    (``dt``'s second dimension), ``wide`` of them to a group: they read the
+    one ``B`` and ``C`` of their group and each writes its own part of ``dB``
+    and ``dC``.  ``d`` None: ``x [b, T, H P]`` and ``B``, ``C [b, T, G N]``
+    are arrays of their own.  Else all three are windows of ``xBC [b, T, d +
+    2 G N]``, each a block whose lane index starts at the window's: ``x``
+    blocks of ``R P`` lanes from 0, ``B`` blocks of ``N`` lanes from ``d / N``
+    and ``C`` behind its ``G`` blocks (``in_place_unsupported`` has refused a
+    ``d`` that is not whole blocks of ``N``; a last, partial block of ``R P``
+    lanes is never indexed)."""
     import jax.experimental.pallas as pl
     b, G, blocks, nc, R, _ = dt.shape
-    rp, N = x.shape[2] // G, Bm.shape[2] * wide // G
     at = (lambda i: blocks - 1 - i) if reverse else (lambda i: i)
-    seq = lambda d: pl.BlockSpec((None, nc * L, d),
-                                 lambda b, g, i: (b, at(i), g))
-    group = seq(N) if wide == 1 else pl.BlockSpec(
-        (None, nc * L, N), lambda b, g, i: (b, at(i), g // wide))
-    gate = pl.BlockSpec((None, None, None, nc, R, L),
-                        lambda b, g, i: (b, g, at(i), 0, 0, 0))
-    kept = pl.BlockSpec((None, None, None, nc, N, rp),
-                        lambda b, g, i: (b, g, at(i), 0, 0, 0))
-    state = pl.BlockSpec((None, None, N, rp), lambda b, g, i: (b, g, 0, 0))
+
+    def seq(lanes, first=0, shared=False):
+        return pl.BlockSpec(
+            (None, nc * L, lanes), lambda b, g, i: (
+                b, at(i), first + (g // wide if shared and wide > 1 else g)))
+
+    def six(rows, cols):
+        return pl.BlockSpec((None, None, None, nc, rows, cols),
+                            lambda b, g, i: (b, g, at(i), 0, 0, 0))
+    b_at, c_at = (0, 0) if d is None else (d // N, d // N + G // wide)
     return ((b, G, blocks), dict(nc=nc, heads=R, p=rp // R),
-            (seq(rp), group, seq(N), gate, kept, state))
+            dict(x=seq(rp), B=seq(N, b_at, True), C=seq(N, c_at, True),
+                 part=seq(N), gate=six(R, L), kept=six(N, rp),
+                 state=pl.BlockSpec((None, None, N, rp),
+                                    lambda b, g, i: (b, g, 0, 0)),
+                 skip=pl.BlockSpec((None, 1, rp), lambda b, g, i: (g, 0, 0)),
+                 dskip=pl.BlockSpec((None, None, 1, rp),
+                                    lambda b, g, i: (b, g, 0, 0))))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "wide"))
-def _fwd_call(x, dt, a, Bm, Cm, *, interpret, wide):
-    """``x [b, T, H P]``, ``B, C [b, T, G N]``, ``dt, a [b, G', T / (n L), n,
-    R, L]`` f32 (``n`` chunks a program; ``G' = wide G`` blocks of ``R`` heads,
-    ``wide`` to a group): ``(y [b, T, H P], last state^T [b, G', N, R P],
-    chunk-start states^T [b, G', T / (n L), n, N, R P])``."""
+def _read(ops, lanes):
+    """``(x, B, C, dt, a)`` as the kernels are handed them, the rest of
+    ``ops``, the lanes ``R P`` of a program's ``x`` block and ``d`` for
+    ``_plan``: ``ops`` starts with ``x, dt, a, B, C``, or with ``lanes = (P,
+    N)`` with ``xBC, dt, a, D``, ``xBC`` then read three times and ``D [H]``
+    f32 spread over its heads' lanes, ``[G', 1, R P]``, first of the rest."""
+    if lanes is None:
+        x, dt, a, Bm, Cm, *rest = ops
+        G = dt.shape[1]
+        return (x, Bm, Cm, dt, a), rest, x.shape[2] // G, None
+    xbc, dt, a, D, *rest = ops
+    G, R = dt.shape[1], dt.shape[4]
+    skip = jnp.repeat(D.astype(_F32), lanes[0]).reshape(G, 1, -1)
+    rp = R * lanes[0]
+    return (xbc, xbc, xbc, dt, a), [skip] + rest, rp, G * rp
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "wide", "lanes"))
+def _fwd_call(*ops, interpret, wide, lanes=None):
+    """``x [b, T, H P]``, ``dt, a [b, G', T / (n L), n, R, L]`` f32 (``n``
+    chunks a program; ``G' = wide G`` blocks of ``R`` heads, ``wide`` to a
+    group), ``B, C [b, T, G N]``; or with ``lanes = (P, N)`` ``xBC [b, T, H P
+    + 2 G N]``, ``dt, a``, ``D [H]``, and ``y`` is then ``round(round(y) + D
+    x)``: ``(y [b, T, H P], last state^T [b, G', N, R P], chunk-start
+    states^T [b, G', T / (n L), n, N, R P])``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    ops, skip, rp, d = _read(ops, lanes)
+    x, Bm, dt = ops[0], ops[1], ops[3]
     b, G, blocks, nc = dt.shape[:4]
-    grid, dims, (xy, bc, _, gate, kept, state) = _plan(x, Bm, dt, False, wide)
-    rp, N = x.shape[2] // G, Bm.shape[2] * wide // G
+    N = lanes[1] if lanes else Bm.shape[2] * wide // G
+    grid, dims, at = _plan(dt, rp, N, False, wide, d)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, **dims),
+        functools.partial(_fwd_kernel, skip=bool(lanes), **dims),
         name="hetu_ssd_fwd", grid=grid,
-        in_specs=[xy, bc, bc, gate, gate], out_specs=[xy, state, kept],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+        in_specs=[at["x"], at["B"], at["C"], at["gate"], at["gate"]]
+        + [at["skip"]] * len(skip),
+        out_specs=[at["x"], at["state"], at["kept"]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape[:2] + (G * rp,), x.dtype),
                    jax.ShapeDtypeStruct((b, G, N, rp), _F32),
                    jax.ShapeDtypeStruct((b, G, blocks, nc, N, rp), _F32)],
         scratch_shapes=[pltpu.VMEM((N, rp), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(x, Bm, Cm, dt, a)
+    )(*ops, *skip)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "wide"))
-def _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast, *, interpret,
-              wide):
-    """``dx``, ``dB`` and ``dC`` (``[b, T, G' N]``: a block of heads' own part,
-    which ``_scan_bwd`` adds up over a group's ``wide`` blocks), ``ddt`` and
-    ``da``."""
+@functools.partial(jax.jit, static_argnames=("interpret", "wide", "lanes"))
+def _bwd_call(*ops, interpret, wide, lanes=None):
+    """``_fwd_call``'s operands, the kept states, the last state, ``dy`` and
+    the last state's cotangent: ``dx``, ``dB`` and ``dC`` (``[b, T, G' N]``: a
+    block of heads' own part, which ``_scan_bwd`` adds up over a group's
+    ``wide`` blocks), ``ddt`` and ``da``; with ``lanes`` ``dx`` holds the
+    skip's ``D dy`` too, added in f32 before its one rounding, and a sixth
+    output is ``sum(dy x)`` over a program's positions, f32 ``[b, G', 1, R
+    P]``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    grid, dims, (xy, bc, dbc, gate, kept, state) = _plan(x, Bm, dt, True,
-                                                         wide)
-    parts = Bm.shape[:2] + (Bm.shape[2] * wide,)
+    ops, (*skip, states, last, dy, dlast), rp, d = _read(ops, lanes)
+    x, Bm, dt = ops[0], ops[1], ops[3]
+    b, G = dt.shape[:2]
+    N = states.shape[-2]
+    grid, dims, at = _plan(dt, rp, N, True, wide, d)
+    part = jax.ShapeDtypeStruct(x.shape[:2] + (G * N,), x.dtype)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, **dims),
+        functools.partial(_bwd_kernel, skip=bool(lanes), **dims),
         name="hetu_ssd_bwd", grid=grid,
-        in_specs=[xy, bc, bc, gate, gate, kept, state, xy, state],
-        out_specs=[xy, dbc, dbc, gate, gate],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(parts, Bm.dtype),
-                   jax.ShapeDtypeStruct(parts, Cm.dtype),
+        in_specs=[at["x"], at["B"], at["C"], at["gate"], at["gate"],
+                  at["kept"], at["state"], at["x"], at["state"]]
+        + [at["skip"]] * len(skip),
+        out_specs=[at["x"], at["part"], at["part"], at["gate"], at["gate"]]
+        + [at["dskip"]] * len(skip),
+        out_shape=[jax.ShapeDtypeStruct(dy.shape, x.dtype), part, part,
                    jax.ShapeDtypeStruct(dt.shape, _F32),
-                   jax.ShapeDtypeStruct(dt.shape, _F32)],
-        scratch_shapes=[pltpu.VMEM(states.shape[-2:], _F32),
+                   jax.ShapeDtypeStruct(dt.shape, _F32)]
+        + [jax.ShapeDtypeStruct((b, G, 1, rp), _F32)] * len(skip),
+        scratch_shapes=[pltpu.VMEM((N, rp), _F32),
                         pltpu.VMEM((dims["heads"], L), _F32)],
         compiler_params=params(interpret, WALK, VMEM_LIMIT),
         interpret=interpret,
-    )(x, Bm, Cm, dt, a, states, last, dy, dlast)
+    )(*ops, states, last, dy, dlast, *skip)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _scan(x, dt, a, Bm, Cm, wide):
-    return _scan_fwd(x, dt, a, Bm, Cm, wide)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _scan(wide, lanes, *ops):
+    return _scan_fwd(wide, lanes, *ops)[0]
 
 
-def _scan_fwd(x, dt, a, Bm, Cm, wide):
-    y, last, states = _fwd_call(x, dt, a, Bm, Cm, wide=wide,
+def _scan_fwd(wide, lanes, *ops):
+    y, last, states = _fwd_call(*ops, wide=wide, lanes=lanes,
                                 interpret=dispatch.interpret())
-    return (y, last), (x, dt, a, Bm, Cm, states, last)
+    return (y, last), ops + (states, last)
 
 
-def _scan_bwd(wide, res, grads):
-    x, dt, a, Bm, Cm, states, last = res
-    dy, dlast = grads
-    dx, dB, dC, ddt, da = _bwd_call(x, dt, a, Bm, Cm, states, last, dy, dlast,
-                                    wide=wide, interpret=dispatch.interpret())
+def _scan_bwd(wide, lanes, res, grads):
+    dx, dB, dC, ddt, da, *dD = _bwd_call(*res, *grads, wide=wide, lanes=lanes,
+                                         interpret=dispatch.interpret())
     if wide > 1:            # a group's dB, dC: the sum of its blocks' parts
-        N = Bm.shape[2] * wide // dt.shape[1]
-        dB, dC = (t.reshape(t.shape[:2] + (-1, wide, N)).sum(3, dtype=_F32)
-                  .astype(t.dtype).reshape(Bm.shape) for t in (dB, dC))
-    return dx, ddt, da, dB, dC
+        b, T, GN = dB.shape
+        N = res[-2].shape[-2]
+        dB, dC = (t.reshape(b, T, -1, wide, N).sum(3, dtype=_F32)
+                  .astype(t.dtype).reshape(b, T, GN // wide)
+                  for t in (dB, dC))
+    if lanes is None:
+        return dx, ddt, da, dB, dC
+    # d xBC is ONE concatenation; dD the batch rows' and a head's lanes' sum
+    D = res[3]
+    dD = dD[0].reshape(dx.shape[0], D.shape[0], lanes[0]).sum((0, 2))
+    return (jnp.concatenate([dx, dB, dC], -1), ddt, da, dD.astype(D.dtype))
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
@@ -470,15 +573,49 @@ def unsupported(x, Bm, Cm, chunk):
     return None
 
 
-def _count_entry(R, r):
-    """Trace-time count of the cut taken, beside ``dispatch.record``'s count
-    of the kernel-versus-jnp choice."""
-    telemetry.get_registry().counter(
+def _cut(T):
+    """Chunks a program, programs along the sequence and the positions of
+    padding behind ``T``: those write nothing and decay nothing (dt 0) and
+    their outputs are cut off."""
+    nc = min(CHUNKS, -(-T // L))
+    blocks = -(-T // (nc * L))
+    return nc, blocks, blocks * nc * L - T
+
+
+def in_place_unsupported(T, d, N):
+    """Why ``ssd_in_place`` does not read ``xBC [b, T, d + 2 G N]`` where the
+    kernels take its heads (``unsupported``), or None when it does: the
+    windows of ``B`` and ``C`` must start at whole blocks of ``N`` lanes
+    (``x``'s blocks of ``R P`` lanes start at 0, and ``d = H P`` is whole
+    blocks of them since ``R`` divides a group's heads), and ``T`` must be
+    whole blocks of a program's chunks, since padding ``xBC`` is the copy this
+    entry is there to save."""
+    if d % N:
+        return "bc_window_not_block_aligned"
+    if _cut(T)[2]:
+        return "positions_not_whole_blocks"
+    return None
+
+
+def _count_entry(R, r, form):
+    """Trace-time counts beside ``dispatch.record``'s count of the
+    kernel-versus-jnp choice: the cut taken, and the entry (``form``:
+    ``plain``, the caller's ``x``, ``B``, ``C``, or ``in_place``, the windows
+    of ``xBC`` and the skip)."""
+    registry = telemetry.get_registry()
+    registry.counter(
         "hetu_ssd_entry_total",
         "Trace-time calls of the state-space scan's kernels by the heads of "
         "a group and the heads one program holds",
         labels=("heads_a_group", "heads_a_program"),
     ).labels(heads_a_group=str(R), heads_a_program=str(r)).inc()
+    registry.counter(
+        "hetu_ssd_form_total",
+        "Trace-time calls of the state-space scan's kernels by entry: plain "
+        "(x, B and C arrays of their own, the skip the caller's) or in_place "
+        "(the three windows of the convolution's output read where they are, "
+        "the skip inside the kernels)",
+        labels=("form",)).labels(form=form).inc()
 
 
 def entries():
@@ -488,12 +625,30 @@ def entries():
             for lab, n in dispatch.counted("hetu_ssd_entry_total")}
 
 
+def forms():
+    """``{form: count}`` of the calls traced so far, ``form`` ``plain`` or
+    ``in_place`` (empty while telemetry is disabled)."""
+    return {lab["form"]: n
+            for lab, n in dispatch.counted("hetu_ssd_form_total")}
+
+
+def _gates(dt, A, G, R, cut):
+    """``dt [b, T, H]``, ``A [H]`` -> ``dt``, ``a = dt A`` as ``[b, G, T' / (n
+    L), n, R, L]`` f32: a chunk along the lanes."""
+    nc, blocks, pad = cut
+    dt = dt.astype(_F32)
+    if pad:
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+    dt = dt.reshape(dt.shape[0], blocks, nc, L, G, R).transpose(
+        0, 4, 1, 2, 5, 3)
+    return dt, dt * A.astype(_F32).reshape(G, 1, 1, R, 1)
+
+
 def ssd(x, dt, A, Bm, Cm):
     """``chunk_ssd`` at chunk 128 through the kernel pair: ``x [b, T, H,
     P]``, ``dt [b, T, H]``, ``A [H]``, ``B, C [b, T, G, N]`` -> ``(y [b, T, H,
-    P]`` in ``x``'s type, the last state ``[b, H, P, N]`` f32)``.  Any ``T``:
-    positions of padding write nothing and decay nothing (dt 0) and their
-    outputs are cut off.  A group of more than ``HEADS`` heads runs as
+    P]`` in ``x``'s type, the last state ``[b, H, P, N]`` f32)``.  Any ``T``
+    (``_cut``).  A group of more than ``HEADS`` heads runs as
     ``wide`` blocks of ``R`` heads, each a program of its own on the grid's
     second axis (below, ``G`` counts those blocks): a group's blocks read its
     ``B`` and ``C`` rows in place, once a block, and the group's ``dB`` and
@@ -502,19 +657,28 @@ def ssd(x, dt, A, Bm, Cm):
     N = Bm.shape[3]
     R = heads_a_program(H // Bm.shape[2], P)
     G, wide = H // R, H // Bm.shape[2] // R
-    _count_entry(wide * R, R)
-    nc = min(CHUNKS, -(-T // L))
-    blocks = -(-T // (nc * L))
-    pad = blocks * nc * L - T
+    _count_entry(wide * R, R, "plain")
+    cut = _cut(T)
 
     def rows(t):                       # [b, T, .., d] -> [b, T', .. d]
         t = t.reshape(b, T, -1)
-        return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+        return jnp.pad(t, ((0, 0), (0, cut[2]), (0, 0))) if cut[2] else t
 
-    # [b, T, H] -> [b, G, T' / (n L), n, R, L]: a chunk along the lanes
-    dt = rows(dt.astype(_F32)).reshape(b, blocks, nc, L, G, R)
-    dt = dt.transpose(0, 4, 1, 2, 5, 3)
-    a = dt * A.astype(_F32).reshape(G, 1, 1, R, 1)
-    y, last = _scan(rows(x), dt, a, rows(Bm), rows(Cm), wide)
+    dt, a = _gates(dt, A, G, R, cut)
+    y, last = _scan(wide, None, rows(x), dt, a, rows(Bm), rows(Cm))
     last = last.reshape(b, G, N, R, P).transpose(0, 1, 3, 4, 2)
     return y[:, :T].reshape(b, T, H, P), last.reshape(b, H, P, N)
+
+
+def ssd_in_place(xbc, dt, A, D, *, heads, head_dim, groups, state):
+    """The scan and its skip from the convolution's output: ``xBC [b, T, H P
+    + 2 G N]`` (``x | B | C``), ``dt [b, T, H]`` (after its softplus), ``A,
+    D [H]`` -> ``y [b, T, H P]`` in ``xBC``'s type, what ``ssd`` gives on the
+    three slices followed by ``round(f32(y) + D f32(x))``, none of which
+    reaches HBM; the cotangent of ``xBC`` comes back whole.  ``T`` whole
+    blocks of a program's chunks (``in_place_unsupported``)."""
+    R = heads_a_program(heads // groups, head_dim)
+    G, wide = heads // R, heads // groups // R
+    _count_entry(wide * R, R, "in_place")
+    dt, a = _gates(dt, A, G, R, _cut(xbc.shape[1]))
+    return _scan(wide, (head_dim, state), xbc, dt, a, D.astype(_F32))[0]

@@ -48,10 +48,19 @@ Shapes: ``x [b, T, H, P]``, ``dt [b, T, H]`` (after its softplus), ``A
 [H]``, ``B, C [b, T, G, N]``; returns ``y [b, T, H, P]`` in ``x``'s type and
 the last state ``[b, H, P, N]`` f32.
 
-What runs where.  ``chunk_ssd`` is what the layer's ``hetu_ssm_scan`` node
-calls (``layers/mamba2.py``) and what the benchmark's long-memory probe
-calls (``chipbench/builders/nemotron_h.py`` ``ssd_state_gap``).  On a TPU it
-runs as two Pallas kernels, ``hetu_ssd_fwd`` and ``hetu_ssd_bwd``
+What runs where.  ``chunk_ssd`` is what the benchmark's long-memory probe
+calls (``chipbench/builders/nemotron_h.py`` ``ssd_state_gap``) and what the
+layer's ``hetu_ssm_scan`` node (``layers/mamba2.py``) calls on its slices of
+``xBC`` where ``chunk_ssd_in_place`` hands it None.  That function is the
+node's first choice: the same kernel pair's second entry
+(``ops/pallas/ssd.py ssd_in_place``), which reads ``x | B | C`` where the
+convolution wrote them and adds the skip ``D x`` on the chunk in VMEM, under
+``chunk_ssd``'s rule and two conditions more, read from the shapes (``H P`` a
+multiple of ``N``, ``T`` whole blocks of 8 x 128 positions, or fewer chunks
+in one program); it counts the one choice of a call that it takes
+(``pallas``) and nothing of one it does not, and which entry ran is in
+``hetu_ssd_form_total{form}`` (``plain`` / ``in_place``).  On a TPU
+``chunk_ssd`` runs as two Pallas kernels, ``hetu_ssd_fwd`` and ``hetu_ssd_bwd``
 (``ops/pallas/ssd.py``, a ``jax.custom_vjp``: one walk over chunk states in
 VMEM each way, up to eight of a group's heads a program and a wider group
 as blocks of heads on the grid, each reading the group's ``B`` and ``C`` in
@@ -125,6 +134,31 @@ def chunk_ssd(x, dt, A, B, C, chunk=CHUNK):
     if dispatch.take("ssd", None, kernels.unsupported(x, B, C, chunk)):
         return kernels.ssd(x, dt, A, B, C)
     return chunk_ssd_jnp(x, dt, A, B, C, chunk)
+
+
+def chunk_ssd_in_place(xbc, dt, A, D, *, heads, head_dim, groups, state,
+                       chunk=CHUNK):
+    """The scan with its skip from the convolution's output where its kernels
+    read that in place, else None (the caller then slices ``x``, ``B``, ``C``
+    out and adds the skip around ``chunk_ssd``): on a TPU, under the rule
+    ``chunk_ssd`` reads, where the three windows start at whole blocks and
+    nothing is padded, from ``xbc [b, T, H P + 2 G N]`` (``x | B | C``), ``dt
+    [b, T, H]`` and ``A, D [H]`` to ``y [b, T, H P]`` with ``D x`` in it
+    (``ops/pallas/ssd.py ssd_in_place``).  One choice a call: this entry
+    counts ``pallas`` where it runs and nothing where it does not (the
+    slicing form's ``chunk_ssd`` then counts its own)."""
+    from .pallas import dispatch, ssd as kernels
+    b, T, _ = xbc.shape
+    x = jax.ShapeDtypeStruct((b, T, heads, head_dim), xbc.dtype)
+    bc = jax.ShapeDtypeStruct((b, T, groups, state), xbc.dtype)
+    if kernels.unsupported(x, bc, bc, chunk) is not None:
+        return None
+    if kernels.in_place_unsupported(T, heads * head_dim, state) is not None:
+        return None
+    if not dispatch.take("ssd", None):
+        return None
+    return kernels.ssd_in_place(xbc, dt, A, D, heads=heads,
+                                head_dim=head_dim, groups=groups, state=state)
 
 
 def chunk_ssd_jnp(x, dt, A, B, C, chunk=CHUNK):

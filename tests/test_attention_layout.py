@@ -260,7 +260,11 @@ def test_the_gate_a_head_in_place_is_the_gate_by_heads(dtype):
 #: toy's heads of 32 take the ``jax.numpy`` forms); Qwen3-Next's since PR 69,
 #: whose scan node forms the gates before it slices ``mixed`` (its toy's heads
 #: of 16 take the ``jax.numpy`` prologue and form: the parent's 5,325 lines in
-#: another order, line for line but for a private function's number)
+#: another order, line for line but for a private function's number);
+#: Granite's since PR 71, whose scan node forms ``dt`` and ``A`` before it
+#: slices ``xBC`` (its toy's heads of 16 take the slices, the skip and the
+#: ``jax.numpy`` form: the parent's 2,619 lines in another order, line for
+#: line but for the values' numbers)
 TOY_STEPS = {
     "bert-base.b64-s512":
         "fef11c9a04527e1704fa1b7730bef180fb2aae5027eeee745929dd16f4e31407",
@@ -271,7 +275,7 @@ TOY_STEPS = {
     "qwen3-next-80b-a3b.b1-s8192":
         "5fb0f01223d80a4f8de7a41ea6bba8507f4cb0baa977d92af2e470180ebd4ac2",
     "granite-4.0-h-micro.b1-s8192":
-        "baabcf14ca313dcafc00896ad55f739a9f0c28691a203ad6c26c68e4ee8275cb",
+        "baad6f4c94657bc655c804133dccac1e586a830cd8cf4bbdf36a1671c242d8c6",
     "evabyte-6.5b.b1-s8192":
         "05424cb015755648532ddb85848efe69f8de1a07324069a564549250beafc8ee",
 }

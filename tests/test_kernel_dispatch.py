@@ -218,7 +218,7 @@ NODES = {
                       "pallas.gated_delta:gated_delta_rule_in_place",
                       "gated_delta:chunk_gated_delta_rule_jnp",
                       [bf16(1, 64, 4 * D), bf16(1, 64, 4), f32(2), f32(2)]),
-    "hetu_ssm_scan": ("ssd", "pallas.ssd:ssd", "ssd:chunk_ssd_jnp",
+    "hetu_ssm_scan": ("ssd", "pallas.ssd:ssd_in_place", "ssd:chunk_ssd_jnp",
                       [bf16(1, 128, 4 * P + 2 * N), bf16(1, 128, 4), f32(4),
                        f32(4), f32(4)]),
     "hetu_kda_scan": ("kda", "pallas.kda:kda_in_place", "kda:chunk_kda_jnp",
@@ -287,7 +287,7 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
              like=lambda: (lambda q, k, t, r=None: (q, k)) if label == "rotary"
              else lambda o, *a, **k: o)
     elif "_in_place" in kernel:         # from ``mixed`` to [B, S, H d]
-        stub(monkeypatch, form, called, "jnp")
+        stub(monkeypatch, form, called, "jnp")      # (256 lanes: 2 D = 4 P)
         stub(monkeypatch, kernel, called, "pallas",
              like=lambda: lambda mixed, *a, **k: mixed[..., :2 * D])
     else:

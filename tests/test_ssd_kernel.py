@@ -7,7 +7,11 @@ where a bf16 state is seen and the kernels are not; the rule by which
 ``chunk_ssd`` takes them; the ``Mamba2`` layer through them against the same
 layer through the ``jax.numpy`` form; and the layer's train step compiled for
 a described v5e; and a group of more heads than a program holds (Granite
-4.0-H: 64 heads on one ``B`` and ``C``) cut into blocks of heads."""
+4.0-H: 64 heads on one ``B`` and ``C``) cut into blocks of heads; and the
+pair's second entry, ``ssd_in_place``, which reads ``x | B | C`` where the
+convolution wrote them and adds the skip itself, against the slices, the
+first entry and the layer's ``jax.numpy`` skip, with the rule that admits
+it."""
 
 
 import numpy as np
@@ -278,6 +282,178 @@ def test_rule_reads_its_operands_as_on_tpu(ssd_choices, monkeypatch, why, kw,
         assert not taken and ssd_choices() == {("jnp", why): 1}
 
 
+# -- the second entry: xBC read in place, the skip inside ----------------------
+
+def xbc_inputs(T, b, H, G, dtype, seed, exact):
+    """``xBC [b, T, H P + 2 G N]`` as the convolution writes it, ``dt``, ``A``
+    as ``ssd_inputs`` draws them, and ``D`` about one or, ``exact``, signed
+    powers of two (``D x`` is then exact and a fused multiply-add, which the
+    CPU's compiler makes of an interpret-mode body and not of the ``jax.numpy``
+    lines, rounds as they do)."""
+    x, dt, A, Bm, Cm = ssd_inputs(T, b, H, G, dtype, seed)
+    r = np.random.default_rng(seed + 1)
+    D = (2.0 ** r.integers(-2, 2, H) * r.choice([-1.0, 1.0], H) if exact
+         else r.normal(1.0, 0.3, H))
+    xbc = jnp.concatenate([t.reshape(b, T, -1) for t in (x, Bm, Cm)], -1)
+    return xbc, dt, A, jnp.asarray(D, jnp.float32)
+
+
+def from_slices(scan, xbc, dt, A, D, *, H, G):
+    """The layer's lines around ``scan`` (``layers/mamba2.py _scan`` before
+    the kernels had a second entry): three slices of ``xBC``, the scan, the
+    skip in f32 rounded to the compute type."""
+    b, T, _ = xbc.shape
+    d = H * P
+    x = xbc[..., :d].reshape(b, T, H, P)
+    Bm, Cm = (xbc[..., lo:lo + G * N].reshape(b, T, G, N)
+              for lo in (d, d + G * N))
+    y = scan(x, dt, A, Bm, Cm)[0].astype(jnp.float32)
+    y = y + D[:, None] * x.astype(jnp.float32)
+    return y.astype(xbc.dtype).reshape(b, T, d)
+
+
+def in_place(xbc, dt, A, D, *, H, G):
+    return kernels.ssd_in_place(xbc, dt, A, D, heads=H, head_dim=P,
+                                groups=G, state=N)
+
+
+def value_and_grads(fn, args, **dims):
+    def loss(*a):
+        y = fn(*a, **dims)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32))), y
+    (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                       has_aux=True)(*args)
+    return (y,) + grads
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("T,b,H,G", [
+    (256, 2, 16, 1),            # Granite's cut: one group, two blocks of heads
+    (1024, 1, 16, 2),           # Nemotron-H's: a group a program, two programs
+    (128, 1, 4, 1)])            #   a sequence; one chunk, four heads
+def test_in_place_is_the_slices_the_kernels_and_the_skip(T, b, H, G, dtype):
+    """``ssd_in_place`` on ``xBC`` against today's composition on the same
+    kernels: the values bit for bit where ``D x`` is exact (the two round
+    ``y`` and ``y + D x`` at the same places) and within a rounding of the
+    compute type for any ``D``; the gradients of ``xBC`` (each window), ``dt``,
+    ``A`` and ``D``: in f32 to rounding; in bf16 ``dx`` within a step of its
+    largest (the skip's ``D dy`` is added in f32 before ``dx``'s one rounding,
+    where the composition adds two rounded terms) and the rest, which the skip
+    does not enter, as they were where ``y`` is (``dD`` is f32 sums of the
+    same products in another order)."""
+    dims = dict(H=H, G=G)
+    f32 = dtype == "float32"
+    # not jitted whole: the kernels' calls then compile as they do under
+    # ``in_place``, each a program of its own
+    want_fn = lambda *a: value_and_grads(
+        lambda *x, **kw: from_slices(kernels.ssd, *x, **kw), a, **dims)
+    for exact in (True, False):
+        args = xbc_inputs(T, b, H, G, jnp.dtype(dtype), T + exact, exact)
+        got = value_and_grads(in_place, args, **dims)
+        want = want_fn(*args)
+        assert got[0].shape == (b, T, H * P) and got[0].dtype == args[0].dtype
+        if exact:
+            np.testing.assert_array_equal(got[0], want[0])
+        d, gn = H * P, G * N
+        for name, a, w in zip(("y", "dxbc", "ddt", "dA", "dD"), got, want):
+            assert a.shape == w.shape and a.dtype == w.dtype, name
+            a, w = (np.asarray(t, np.float64) for t in (a, w))
+            windows = ((slice(0, d), slice(d, d + gn), slice(d + gn, None))
+                       if name == "dxbc" else (slice(None),))
+            for at in windows:
+                top = np.abs(w[..., at]).max()
+                assert top > 0, (name, at)
+                gap = np.abs(a[..., at] - w[..., at]).max() / top
+                # the skip enters y and dx alone; in bf16 a y that rounds
+                # the other way (no exact ``D x``) is a step in what it feeds
+                rounded = name == "y" or (name == "dxbc" and at.start == 0
+                                          ) or not exact
+                assert gap <= (5e-6 if f32 else 8e-3 if rounded else 1e-5), (
+                    name, at, gap)
+
+
+@pytest.fixture
+def ssd_forms(live_registry):
+    """``{form: count}`` of the entries traced since the test began."""
+    before = kernels.forms()
+    return lambda: {k: n - before.get(k, 0)
+                    for k, n in kernels.forms().items()
+                    if n > before.get(k, 0)}
+
+
+@pytest.mark.parametrize("why,form,kw", [
+    (None, "in_place", dict(H=64, G=1, T=8192)),     # the Granite cell's mixer
+    (None, "in_place", dict(H=64, G=8, T=8192)),     # the Nemotron-H cell's
+    (None, "in_place", dict(H=4, G=1, T=384)),       # three chunks, one program
+    (None, "in_place", dict(H=6, G=3, T=128, p=128, dtype=jnp.float32)),
+    ("positions_not_whole_blocks", "plain", dict(H=8, G=1, T=1100)),
+    ("positions_not_whole_blocks", "plain", dict(H=8, G=1, T=1536)),
+    ("bc_window_not_block_aligned", "plain", dict(H=2, G=1, T=256, n=256)),
+    ("bc_window_not_block_aligned", "plain", dict(H=6, G=3, T=256, n=256)),
+    ("head_dim_not_64_aligned", None, dict(H=8, G=2, T=256, p=16, n=32)),
+    ("dtype:float16", None, dict(H=8, G=1, T=256, dtype=jnp.float16)),
+])
+def test_scan_reads_xbc_in_place_where_the_windows_are_whole_blocks(
+        as_on_tpu, ssd_choices, ssd_forms, why, form, kw):
+    """``layers/mamba2.py _scan`` with the platform read as ``tpu``: the
+    in-place entry where the kernels take the heads, ``B``'s window starts at
+    a whole block of ``N`` lanes and no position is padded; where its own rule
+    refuses, the slices, the first entry and the skip, as before.  ONE choice
+    a call either way, ``pallas``, and the entry's form counted beside it; where
+    the kernels do not take the heads, the ``jax.numpy`` form with
+    ``unsupported``'s reason and no entry at all."""
+    from hetu_tpu.layers.mamba2 import _scan
+    H, G, T = kw["H"], kw["G"], kw["T"]
+    p, n, dtype = kw.get("p", P), kw.get("n", N), kw.get("dtype", jnp.bfloat16)
+    if form is not None:
+        assert kernels.in_place_unsupported(T, H * p, n) == why
+    sds = jax.ShapeDtypeStruct
+    y = jax.eval_shape(
+        lambda *a: _scan(*a, heads=H, head_dim=p, groups=G, state=n,
+                         chunk=128),
+        sds((1, T, H * p + 2 * G * n), dtype), sds((1, T, H), dtype),
+        *(sds((H,), jnp.float32),) * 3)
+    assert y.shape == (1, T, H * p) and y.dtype == dtype
+    if form is None:
+        assert ssd_forms() == {} and ssd_choices() == {("jnp", why): 1}
+    else:
+        assert ssd_forms() == {form: 1}
+        assert ssd_choices() == {("pallas", ""): 1}
+
+
+def test_scan_through_the_in_place_kernels_is_the_scan(monkeypatch, ssd_forms):
+    """The scan node's function as it runs on a TPU (interpret mode: Mosaic
+    read as there, the platform the CPU's) against itself around the
+    ``jax.numpy`` form, f32, 8 heads in 2 groups over 256 positions: the
+    output and the gradient of ``xBC``, ``dt``, ``dt_bias``, ``A_log`` and
+    ``D``."""
+    from hetu_tpu.layers.mamba2 import _scan
+    dims = dict(heads=8, head_dim=P, groups=2, state=N, chunk=128)
+    r = np.random.default_rng(4)
+    x = (jnp.asarray(r.normal(size=(1, 256, 8 * P + 4 * N)) * 0.3,
+                     jnp.float32),
+         jnp.asarray(r.normal(size=(1, 256, 8)), jnp.float32),
+         jnp.asarray(r.normal(size=(8,)), jnp.float32),
+         jnp.asarray(r.normal(size=(8,)), jnp.float32),
+         jnp.asarray(r.normal(1.0, 0.3, size=(8,)), jnp.float32))
+
+    def both(rule):
+        def loss(*a):
+            y = _scan(*a, rule=rule, **dims)
+            return jnp.sum(jnp.sin(y)), y
+        (_, y), grads = jax.value_and_grad(loss, argnums=range(5),
+                                           has_aux=True)(*x)
+        return (y,) + grads
+    want = both(chunk_ssd_jnp)
+    assert ssd_forms() == {}
+    monkeypatch.setattr(dispatch, "mosaic", lambda: True)
+    got = both(None)
+    assert ssd_forms() == {"in_place": 1}
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and np.abs(b).max() > 0
+        assert np.abs(a - b).max() < 1e-4 * np.abs(b).max()
+
+
 # -- the layer through the kernels ---------------------------------------------
 
 def layer_loss_and_grads(through_kernels, monkeypatch):
@@ -347,13 +523,15 @@ def as_on_tpu(monkeypatch):
 
 @pytest.mark.parametrize("groups", [8, 1])
 def test_layer_train_step_compiles_for_v5e(v5e, as_on_tpu, ssd_choices,
-                                           groups):
+                                           ssd_forms, groups):
     """The Nemotron-H cell's mixer (64 heads of 64 in 8 groups, state 128,
     8,192 positions, bf16 over f32 masters) recomputed in the backward pass
     as the cell's are, with AdamW: ``hetu_ssd_fwd`` twice (forward and
     recomputed forward), ``hetu_ssd_bwd`` once and nothing else of the
     scan's: no ``while``, no ``[.., 128, 128]`` array in HBM; the kernels read
-    and write ``x [1, 8192, 4096]`` in place.  And the Granite cell's, all 64
+    ``x``, ``B`` and ``C`` out of the convolution's ``[1, 8192, 6144]`` (4,352
+    lanes at one group) where it lies, add the skip and write ``y [1, 8192,
+    4096]``.  And the Granite cell's, all 64
     heads in ONE group: held whole by a program that is 68 MiB of VMEM
     against the limit of 64 (``RESOURCE_EXHAUSTED`` before the group was cut
     into blocks of eight heads), the same three calls."""
@@ -376,12 +554,19 @@ def test_layer_train_step_compiles_for_v5e(v5e, as_on_tpu, ssd_choices,
         sub._abstract_args(None))
     hlo = sub._jitted.lower(*args).compile().as_text()
     assert ssd_choices() == {("pallas", ""): 1}
-    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert ssd_forms() == {"in_place": 1}
+    # a call by its own name, not its operands' (the scan reads the
+    # convolution's output where it is: ``custom-call(%hetu_conv_fwd.2, ..``)
+    calls = [re.sub(r"custom-call\([^)]*\)", "custom-call()", ln)
+             for ln in hlo.splitlines() if "tpu_custom_call" in ln]
     assert sum("hetu_ssd_fwd" in ln for ln in calls) == 2
     assert sum("hetu_ssd_bwd" in ln for ln in calls) == 1
     # (the layer's other kernels are the convolution's, hetu_conv_*)
     assert all("bf16[1,8192,4096]" in ln for ln in calls if "hetu_ssd" in ln)
     assert sum("hetu_conv_fwd" in ln for ln in calls) == 2
     assert sum("hetu_conv_bwd" in ln for ln in calls) == 1
+    # all three read x | B | C where the convolution's kernel wrote them
+    scans = [ln for ln in hlo.splitlines() if re.match(r"\s*%hetu_ssd", ln)]
+    assert [ln.count("%hetu_conv_fwd") for ln in scans] == [3, 3, 3]
     assert not re.findall(r"\bwhile\(", hlo)
     assert not re.findall(r" = \w+\[[\d,]*128,128\]\S* ", hlo)

@@ -4,7 +4,9 @@ the recurrence one position at a time, the program's own
 (``chipbench/reference/nemotron_h.py ssm_recurrence``): outputs, the last
 state and the gradient of every input, over several chunk counts with a
 ragged last chunk, in f32 and with bf16 inputs.  A state carried in bf16 must
-fail the tolerance the chunked form passes.
+fail the tolerance the chunked form passes.  And which function the
+``Mamba2`` layer's scan node calls: on a TPU the kernels' in-place entry off a
+mesh, the ``jax.numpy`` form under one.
 
 Decays: ``dt`` about 0.7 and ``A`` of 0.003 to 1, so that the slowest head
 forgets 0.2% a position and still holds the first position at the last."""
@@ -111,3 +113,48 @@ def test_segsum_is_a_sum_of_the_terms_between():
     # no cancellation: after a running sum of -2,000 a small step is exact
     big = jnp.asarray([-2000.0, -1e-3, -1e-3], jnp.float32)
     assert float(segsum(big)[2, 0]) == pytest.approx(-2e-3, rel=1e-6)
+
+
+# -- what the layer's scan node calls -------------------------------------------
+
+@pytest.mark.parametrize("platform,mesh,form,choice", [
+    ("tpu", None, {"in_place": 1}, {("pallas", ""): 1}),
+    ("tpu", "a mesh", {}, {("jnp", "mesh"): 1}),
+    ("cpu", None, {}, {}), ("cpu", "a mesh", {}, {})])
+def test_scan_node_takes_the_in_place_entry_off_a_mesh(
+        live_registry, monkeypatch, platform, mesh, form, choice):
+    """The ``hetu_ssm_scan`` node of a ``Mamba2`` at the published head and
+    state sizes (4 heads of 64, one group, state 128; 256 positions): on a
+    TPU off a mesh ``ssd_in_place`` (``hetu_ssd_form_total{form="in_place"}``)
+    counted ``pallas`` once; under a mesh the ``jax.numpy`` form around the
+    slices and the skip, counted ``mesh``, and no entry of the kernels;
+    without Mosaic that form and nothing counted."""
+    import types
+    import hetu_tpu as ht
+    from hetu_tpu.layers.mamba2 import Mamba2
+    from hetu_tpu.ops import ssd
+    from hetu_tpu.ops.pallas import dispatch, ssd as kernels
+    monkeypatch.setattr(dispatch, "platform", lambda: platform)
+    jax.clear_caches()            # ``kernels._dot`` reads the mode when traced
+    called = []
+    real = ssd.chunk_ssd_jnp
+    monkeypatch.setattr(ssd, "chunk_ssd_jnp",
+                        lambda *a, **k: called.append("jnp") or real(*a, **k))
+    x = ht.placeholder_op(f"ssn_{platform}_{mesh is None}_x", (1, 256, 256))
+    node = Mamba2(256, 4, 64, 1, 128,
+                  name=f"ssn_{platform}_{mesh is None}")(x).inputs[0]
+    assert node.scope == "hetu_ssm_scan"
+    forms, choices = kernels.forms(), dispatch.choices()
+    sds = jax.ShapeDtypeStruct
+    y = jax.eval_shape(
+        lambda *a: node._compute(list(a), types.SimpleNamespace(mesh=mesh)),
+        sds((1, 256, 512), jnp.bfloat16), sds((1, 256, 4), jnp.bfloat16),
+        *(sds((4,), jnp.float32),) * 3)
+    jax.clear_caches()
+    assert y.shape == (1, 256, 256) and y.dtype == jnp.bfloat16
+    assert called == ([] if form else ["jnp"])
+    assert {k: n - forms.get(k, 0) for k, n in kernels.forms().items()
+            if n > forms.get(k, 0)} == form
+    assert {k[1:]: n - choices.get(k, 0)
+            for k, n in dispatch.choices().items()
+            if k[0] == "ssd" and n > choices.get(k, 0)} == choice
