@@ -459,6 +459,8 @@ class SubExecutor:
                 single = functools.partial(step_fn, _stats=None)
                 stats_fn = functools.partial(step_fn, _stats="full")
         in_shardings = self.executor._input_shardings(self)
+        #: (params', optimiser state's) shardings under a mesh, else None
+        self._state_sh = None
         # where ``_upload`` puts the step's host feeds: the feed entry of
         # the in_shardings the program is jitted with, None with no mesh
         self._feed_sh = None if in_shardings is None else in_shardings[2]
@@ -468,11 +470,11 @@ class SubExecutor:
         # input to an output): a second whole state on the device for the
         # length of the call, which a state that fills half of HBM cannot
         # afford (OLMoE's validate program: 7.5 GB of state, PR 26).  It
-        # returns the leaves it changed and ``_dispatch`` merges them.
-        # Under a mesh the whole state still comes back: out_shardings are
-        # fixed before the trace says which leaves change.
-        self._returns_changed_only = (donate == (4,)
-                                      and in_shardings is None)
+        # returns the leaves it changed and ``_dispatch`` merges them
+        # (under a mesh their shardings are left to the compiler, since
+        # out_shardings are fixed before the trace says which leaves change,
+        # and ``_dispatch`` puts them where the next call takes them).
+        self._returns_changed_only = donate == (4,)
         if self._returns_changed_only:
             single = _changed_state_only(single)
             if stats_fn is not None:
@@ -488,7 +490,10 @@ class SubExecutor:
             from ..parallel.mesh import replicated
             rep = replicated(self.executor.mesh)
             param_sh, opt_sh, _, _, _ = in_shardings
-            out_shardings = (rep, param_sh, opt_sh, rep)
+            self._state_sh = (param_sh, opt_sh)
+            out_shardings = ((rep, None, None, rep)
+                             if self._returns_changed_only
+                             else (rep, param_sh, opt_sh, rep))
             self._jitted = jax.jit(single, donate_argnums=donate,
                                    in_shardings=in_shardings,
                                    out_shardings=out_shardings)
@@ -750,6 +755,11 @@ class SubExecutor:
                 ex.params, ex.opt_state, feeds, ex._base_key,
                 ex._step_arr)
         if self._returns_changed_only:
+            if self._state_sh is not None:
+                new_params, new_opt_state = (
+                    {k: jax.device_put(v, sh[k]) for k, v in new.items()}
+                    for new, sh in zip((new_params, new_opt_state),
+                                       self._state_sh))
             new_params = {**ex.params, **new_params}
             new_opt_state = {**ex.opt_state, **new_opt_state}
         ex.params = new_params
@@ -1214,8 +1224,13 @@ class Executor:
     def _place(self, var, value):
         if self.mesh is not None and var.dist_state is not None:
             from ..parallel.mesh import to_named_sharding
-            return jax.device_put(value, to_named_sharding(self.mesh,
-                                                           var.dist_state))
+            placed = jax.device_put(value, to_named_sharding(
+                self.mesh, var.dist_state))
+            # ``value`` lies whole on one device until the copy is done, and
+            # the initial values of the next variables are already being
+            # made there: a model no device holds whole would pile up on it
+            # (2.1 G parameters: 8.5 GB beside the shards; PERF.md, PR 72)
+            return jax.block_until_ready(placed)
         return value
 
     def _commit_state(self, shardings):

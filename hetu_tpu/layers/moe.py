@@ -316,9 +316,18 @@ class _DroplessOp(_MoEOp):
     its up projection); the gate's last ``skip`` choices are no experts."""
 
     def __init__(self, x, gate, w1, w2, w3, k, num_experts, held=None,
-                 scores=None, state=None, load_var=None, name=None):
+                 scores=None, state=None, load_var=None, ep_axis=None,
+                 name=None):
         assert hasattr(gate, "route"), "the dropless op routes by gate.route"
         self.held, self.skip = held, getattr(gate, "skip", 0)
+        self.ep_axis = ep_axis
+        assert ep_axis is None or (held is None and scores is None), (
+            "experts spread over an axis: all of them, routed by a gate's "
+            "weight")
+        #: bytes a device receives in the forward pass's all-gather and
+        #: reduce-scatter (``exchange_bytes``), known once the op is traced
+        #: under a mesh
+        self.exchange = None
         assert not self.skip or (held is not None and held[1] >= 2), (
             "a skip choice is laid out as a pair held nowhere (held=)")
         self.bias_var = getattr(gate, "bias", None)
@@ -343,12 +352,50 @@ class _DroplessOp(_MoEOp):
         x = self.read(input_vals, "x")
         memo = ctx.__dict__.setdefault("_moe_routing", {})
         if self.id not in memo or memo[self.id][0] is not x:
+            tokens = x.reshape(-1, x.shape[-1])
+            router = self.read(input_vals, "router")
+            bias = self._bias(input_vals, ctx)
             with named_scope("hetu_moe_route"):
-                memo[self.id] = (x, self.gate.route(
-                    x.reshape(-1, x.shape[-1]),
-                    self.read(input_vals, "router"), self.k,
-                    bias=self._bias(input_vals, ctx), mesh=ctx.mesh))
+                if self._axis_size(ctx, x) is None:
+                    routed = self.gate.route(tokens, router, self.k,
+                                             bias=bias, mesh=ctx.mesh)
+                else:
+                    # each device routes its own tokens: a kernel sees no
+                    # mesh inside (``select_k``)
+                    whole = (router,) + (() if bias is None else (bias,))
+                    routed = self._per_shard(
+                        ctx, lambda t, r, *b: self.gate.route(
+                            t, r, self.k, bias=b[0] if b else None),
+                        (tokens,) + whole,
+                        sharded=(True,) + (False,) * len(whole),
+                        out_sharded=(True,) * 4)
+                memo[self.id] = (x, routed)
         return memo[self.id][1]
+
+    def _axis_size(self, ctx, x):
+        """How many devices the experts are spread over (``ep_axis``), None
+        where they are not: no axis, no mesh, or an axis of one."""
+        if self.ep_axis is None or ctx.mesh is None:
+            return None
+        n = ctx.mesh.shape.get(self.ep_axis, 1)
+        assert x.shape[0] % n == 0, (
+            f"the batch {x.shape[0]} is divided over the {n} devices of "
+            f"{self.ep_axis!r}")
+        return n if n > 1 else None
+
+    def _per_shard(self, ctx, fn, args, sharded, out_sharded):
+        """``fn`` on each device of ``ep_axis`` under ``shard_map``: an
+        argument (a result) that is ``sharded`` has its dim 0 on the axis,
+        any other is whole on every device."""
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        on, whole = P(self.ep_axis), P()
+        # pallas out_shapes carry no varying-axes annotations
+        return shard_map(
+            fn, mesh=ctx.mesh, in_specs=tuple(on if s else whole
+                                              for s in sharded),
+            out_specs=tuple(on if s else whole for s in out_sharded),
+            check_vma=False)(*args)
 
     def _move_bias(self, input_vals, idx, ctx):
         """``bias += rate * sign(mean(load) - load)`` from this step's pair
@@ -370,6 +417,10 @@ class _DroplessOp(_MoEOp):
         """The load's rows from ``dropless_moe``'s ``counts``."""
         import jax.numpy as jnp
         rows = [counts["load"], counts["computed"]]
+        if self.ep_axis is not None:
+            # the host's counts over all experts: none is held elsewhere
+            later = counts.get("later", jnp.zeros_like(rows[0]))
+            return rows + [jnp.zeros_like(rows[0]), later]
         if self.held is None:
             return rows
         none = jnp.zeros((self.held[1],), jnp.int32)
@@ -396,12 +447,43 @@ class _DroplessOp(_MoEOp):
         _, idx, gate, _ = self.routing(input_vals, ctx)
         self._move_bias(input_vals, idx, ctx)
         w_gate, w_up = (None, w1) if w3 is None else (w1, w3)
-        y, counts = dropless_moe(
-            tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
-            held=self.held, rows=self.held and held_rows(
-                idx.size, self.num_experts, self.held[1]))
+        n = self._axis_size(ctx, x)
+        if n is None:
+            y, counts = dropless_moe(
+                tokens, idx, gate, w_gate, w_up, w2, mesh=ctx.mesh,
+                held=self.held, rows=self.held and held_rows(
+                    idx.size, self.num_experts, self.held[1]))
+        else:
+            y, counts = self._over_axis(ctx, n, tokens, idx, gate,
+                                        [w_gate, w_up, w2])
         self._record_load(ctx, *self._load_rows(input_vals, idx, counts))
         return y.reshape(x.shape)
+
+    def _over_axis(self, ctx, n, tokens, idx, gate, weights):
+        """``dropless_moe_over_axis`` on the ``n`` devices of ``ep_axis``:
+        the tokens' dim 0 and the expert stacks' dim 0 on the axis."""
+        from .. import telemetry
+        from ..ops.moe import dropless_moe_over_axis, exchange_bytes
+        names = ("load", "computed", "later")
+        gated = weights[0] is not None
+
+        def local(tokens, idx, gate, *w):
+            y, host = dropless_moe_over_axis(
+                tokens, idx, gate, *(w if gated else (None,) + w),
+                axis=self.ep_axis, num_experts=self.num_experts)
+            return (y,) + tuple(host[name] for name in names)
+        self.exchange = exchange_bytes(
+            tokens.shape[0] // n, tokens.shape[1], self.k, n,
+            tokens.dtype.itemsize)
+        telemetry.get_registry().gauge(
+            "hetu_moe_expert_axis_size",
+            "Devices the experts of the dropless layers traced last are "
+            "spread over (MoELayer(ep_axis=))").set(n)
+        w = tuple(a for a in weights if a is not None)
+        y, *counts = self._per_shard(
+            ctx, local, (tokens, idx, gate) + w, sharded=(True,) * (3 + len(w)),
+            out_sharded=(True, False, False, False))
+        return y, dict(zip(names, counts))
 
     def aux(self, input_vals, ctx):
         """The balance loss over top-k counts, from the routing the layer
@@ -749,9 +831,10 @@ class MoELayer(BaseLayer):
                 VariableOp(f"{name}_shared_{n}", shape, init.xavier_uniform())
                 for n, shape in parts)
         self.num_experts, self.k, self.ep_axis = num_experts, k, ep_axis
+        over_axis = regime == "dropless" and ep_axis is not None
         self.load_var = VariableOp(
             f"{name}_load",
-            (2, num_experts) if held is None
+            (4 if over_axis else 2, num_experts) if held is None
             else (5 if self.skip else 4, n_held), init.zeros(),
             trainable=False) if track_load else None
         if ep_axis is not None:
@@ -765,7 +848,8 @@ class MoELayer(BaseLayer):
             self._op = lambda x, ids, scores: _DroplessOp(
                 x, self.gate, self.w1, self.w2, self.w3, self.k,
                 self.num_experts, held=self.held, scores=scores,
-                state=self.state, load_var=self.load_var)
+                state=self.state, load_var=self.load_var,
+                ep_axis=self.ep_axis)
         else:
             self._op = lambda x, ids, scores: _CapacityOp(
                 x, self.gate, self.w1, self.b1, self.w2, self.b2, self.w3,
@@ -820,7 +904,7 @@ class MoELayer(BaseLayer):
             return MoELoadOp(self.gate.bias)
 
 
-def record_moe_load(layer, load, bias=None):
+def record_moe_load(layer, load, bias=None, exchange=None):
     """Count one step's per-expert load of MoE layer ``layer`` (a label) in
     the telemetry registry.  ``load`` is the fetched value of
     ``MoELayer.load()``, ``[2, E]``: pairs routed and pairs computed.
@@ -845,9 +929,28 @@ def record_moe_load(layer, load, bias=None):
     expert and ``hetu_moe_router_state_rms{layer}`` is the RMS of the router
     state the layer handed on, last step.
 
+    From a layer whose experts are spread over a mesh axis
+    (``MoELayer(ep_axis=)``, ``[4, num_experts]``) every count is the
+    host's, over all experts and all devices' tokens: ``elsewhere`` stays 0
+    and ``over_bound`` counts the pairs a device's further passes computed.
+    ``exchange`` (the bytes one device received in this step's all-gathers
+    and reduce-scatters: ``ops/moe.py exchange_bytes_a_step`` of the op's
+    ``exchange``, which is sized when the step is traced, and of the step's
+    forward passes, which whoever set the recomputation knows) is added to
+    ``hetu_moe_exchange_bytes_total{layer, direction}``.
+
     The registry counts nothing while telemetry is disabled."""
     from .. import telemetry
     reg = telemetry.get_registry()
+    for direction, nbytes in (exchange or {}).items():
+        reg.counter(
+            "hetu_moe_exchange_bytes_total",
+            "Bytes one device received in the experts' exchange, by the "
+            "traced step's shapes: its all-gathers (of tokens, and of the "
+            "sums' cotangent) and its reduce-scatters (of partial sums, and "
+            "of the tokens' cotangent), forward, recomputed and backward",
+            labels=("layer", "direction")).labels(
+                layer=layer, direction=direction).inc(nbytes)
 
     def metric(kind, name, text):
         return getattr(reg, kind)(name, text, labels=("layer",)).labels(

@@ -30,6 +30,7 @@ from .sdar import (SdarMoeConfig, SdarMoeModel, SdarMoeForCausalLM,
 from .phi4flash import (Phi4FlashConfig, Phi4FlashDecoderLayer,
                         Phi4FlashModel, Phi4FlashForCausalLM,
                         PHI4FLASH_CONFIGS)
+from .mellum import MellumConfig, MellumForCausalLM, MELLUM_CONFIGS
 from .evabyte import (EvaByteConfig, EvaByteDecoderLayer, EvaByteModel,
                       EvaByteForCausalLM, EVABYTE_CONFIGS)
 from .llama_decode import build_greedy_decode, greedy_generate

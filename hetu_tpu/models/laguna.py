@@ -67,7 +67,8 @@ class LagunaConfig:
                  partial_rotary_factor=0.5, mlp_layer_types=None,
                  moe_routed_scaling_factor=2.5,
                  num_attention_heads_per_layer=None, seq_len=2048,
-                 experts_held=None, remat=None):
+                 experts_held=None, remat=None, router_score="sigmoid",
+                 expert_axis=None):
         n = num_hidden_layers
         assert not attention_bias, "the attention layer is built without bias"
         assert not moe_apply_router_weight_on_input, (
@@ -88,6 +89,11 @@ class LagunaConfig:
         self.gating = gating
         self.sliding_window = sliding_window
         self.routed_scaling_factor = moe_routed_scaling_factor
+        #: how the router scores (``TopKGate(score=)``) and the mesh axis the
+        #: experts are spread over (``MoELayer(ep_axis=)``): a family's, no
+        #: published key of this one
+        self.router_score = router_score
+        self.expert_axis = expert_axis
         # a layer reads the three lists by its index: a model cut to its
         # first layers reads their first entries
         self.layer_types = tuple(
@@ -170,8 +176,9 @@ class LagunaDecoderLayer(BaseLayer):
                 num_experts=c.num_experts, k=c.moe_k, capacity_factor=None,
                 expert_act="swiglu", renorm_topk=True, track_load=True,
                 held=c.experts_held, shared_width=c.shared_width or None,
-                shared_gate=False, router_score="sigmoid",
-                router_scale=c.routed_scaling_factor, name=f"{name}_moe")
+                shared_gate=False, router_score=c.router_score,
+                router_scale=c.routed_scaling_factor, ep_axis=c.expert_axis,
+                name=f"{name}_moe")
         self.input_norm, self.post_norm = (
             RMSNorm(c.hidden_size, eps=c.rms_eps, name=f"{name}_{n}")
             for n in ("input_norm", "post_norm"))

@@ -844,7 +844,7 @@ def rows_impl(how, tile, mesh, tokens, hidden, dtype):
                                asked=how == "pallas") else None
 
 
-def _every_window(one_pass, step, tokens, idx, gate, weights):
+def _every_window(one_pass, step, tokens, idx, gate, weights, *, later):
     """``(y, lay)``: the sum of ``one_pass(tokens, idx, gate, weights,
     offset)[0]`` (``idx``: whatever says where the pairs go, integers, no
     gradient) over the windows ``offset = 0, step, 2 step, ..`` below the
@@ -854,9 +854,14 @@ def _every_window(one_pass, step, tokens, idx, gate, weights):
     only where a batch routes more pairs to the held experts than one window
     holds, as many as it takes, in a ``while`` loop over the same buffers;
     their backward pass is a loop too and computes each window again, so a
-    step keeps nothing of them whatever their number."""
+    step keeps nothing of them whatever their number.  ``later=(pass,
+    step)``: the windows after the first are that pass's, of its own number
+    of rows, at ``offset = step, step + later step, ..`` (the first's again
+    but under ``dropless_moe_over_axis``)."""
     def more(total):
         return lambda c: c[0] < total
+    first_step = step
+    one_later, step = later
 
     def fwd(tokens, idx, gate, weights):
         y, pull, lay = jax.vjp(
@@ -864,10 +869,10 @@ def _every_window(one_pass, step, tokens, idx, gate, weights):
             tokens, gate, weights, has_aux=True)
 
         def add(c):
-            y_here, here = one_pass(tokens, idx, gate, weights, c[0])
+            y_here, here = one_later(tokens, idx, gate, weights, c[0])
             return c[0] + step, c[1] + y_here, c[2] + here["kept"]
         _, y, computed = jax.lax.while_loop(
-            more(lay["total"]), add, (jnp.int32(step), y, lay["kept"]))
+            more(lay["total"]), add, (jnp.int32(first_step), y, lay["kept"]))
         return ((y, dict(lay, computed=computed)),
                 (pull, tokens, idx, gate, weights, lay["total"]))
 
@@ -877,12 +882,12 @@ def _every_window(one_pass, step, tokens, idx, gate, weights):
 
         def add(c):
             _, pull_here = jax.vjp(
-                lambda t, g, w: one_pass(t, idx, g, w, c[0])[0], tokens,
+                lambda t, g, w: one_later(t, idx, g, w, c[0])[0], tokens,
                 gate, weights)
             return c[0] + step, jax.tree_util.tree_map(
                 jnp.add, c[1], pull_here(dy))
         _, (d_tokens, d_gate, d_weights) = jax.lax.while_loop(
-            more(total), add, (jnp.int32(step), pull(dy)))
+            more(total), add, (jnp.int32(first_step), pull(dy)))
         return d_tokens, None, d_gate, d_weights
 
     run = jax.custom_vjp(lambda *a: fwd(*a)[0])
@@ -961,35 +966,130 @@ def dropless_moe(tokens, idx, gate, w_gate, w_up, w_down, *, mesh=None,
     (``computed``: the pairs all passes held, ``load`` counted) and holds
     the layout's ``elsewhere``, ``total`` and ``kept``, the pairs the first
     pass held (``computed - kept``: the pairs a further pass took), too."""
+    if held is not None:
+        return _held_moe(tokens, idx, gate, (w_gate, w_up, w_down), held,
+                         rows, rows, mesh, impl)
     T, H = tokens.shape
     k = idx.shape[1]
     E, _, F = w_up.shape
-    pairs, tile = T * k, None
-    if held is not None:
-        pairs = rows or pairs
-        tile = HELD_TILE if pairs // E >= HELD_TILE else 8
-        pairs = -(-pairs // tile) * tile       # the layout rounds up too
-    how, tile = grouped_impl(pairs, E, H, F, tokens.dtype, mesh, impl, tile)
-    if held is None:
-        with named_scope("hetu_moe_dispatch"):
-            lay = grouped_layout(idx, E, tile)
-            xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
-        with named_scope("hetu_moe_experts"):
-            out = _experts(xs, lay, w_gate, w_up, w_down, how, tile, None)
-        with named_scope("hetu_moe_combine"):
-            by_pair = _rows_back(out, lay["pair_of_slot"],
-                                 lay["slot_of_pair"])
-            y = jnp.sum(by_pair.reshape(T, k, H).astype(jnp.float32)
-                        * gate[:, :, None], axis=1).astype(tokens.dtype)
-        return y, {"load": lay["load"], "computed": lay["load"]}
+    how, tile = grouped_impl(T * k, E, H, F, tokens.dtype, mesh, impl, None)
+    with named_scope("hetu_moe_dispatch"):
+        lay = grouped_layout(idx, E, tile)
+        xs = _rows_out(tokens, lay["pair_of_slot"], lay["slot_of_pair"])
+    with named_scope("hetu_moe_experts"):
+        out = _experts(xs, lay, w_gate, w_up, w_down, how, tile, None)
+    with named_scope("hetu_moe_combine"):
+        by_pair = _rows_back(out, lay["pair_of_slot"], lay["slot_of_pair"])
+        y = jnp.sum(by_pair.reshape(T, k, H).astype(jnp.float32)
+                    * gate[:, :, None], axis=1).astype(tokens.dtype)
+    return y, {"load": lay["load"], "computed": lay["load"]}
 
-    one_pass = functools.partial(
-        _held_pass, k=k, held=tuple(held), rows=rows, tile=tile, how=how,
+
+def _held_moe(tokens, idx, gate, weights, held, rows, later_rows, mesh=None,
+              impl=None):
+    """``dropless_moe(held=, rows=)`` with ``weights = (w_gate, w_up,
+    w_down)``; a pass after the first lays out ``later_rows`` pairs."""
+    T, H = tokens.shape
+    k = idx.shape[1]
+    E, _, F = weights[1].shape
+    pairs = rows or T * k
+    tile = HELD_TILE if pairs // E >= HELD_TILE else 8
+    pairs = -(-pairs // tile) * tile           # the layout rounds up too
+    how, tile = grouped_impl(pairs, E, H, F, tokens.dtype, mesh, impl, tile)
+    a_pass = functools.partial(
+        _held_pass, k=k, held=tuple(held), tile=tile, how=how,
         tt=rows_impl(how, tile, mesh, T, H, tokens.dtype))
-    weights = (w_gate, w_up, w_down)
     with named_scope("hetu_moe_dispatch"):
         by_expert = sorted_pairs(idx, None, held)   # once for every window
     if rows is None:                   # rows for every pair: one window
-        return one_pass(tokens, by_expert, gate, weights, jnp.int32(0))
-    return _every_window(one_pass, rows if tile is None else pairs + E * tile,
-                         tokens, by_expert, gate, weights)
+        return a_pass(tokens, by_expert, gate, weights, jnp.int32(0),
+                      rows=None)
+
+    def window(n):
+        """A pass over ``n`` pairs and the rows it lays out
+        (``layout_window``'s ``M``)."""
+        return (functools.partial(a_pass, rows=n),
+                n if tile is None else -(-n // tile) * tile + E * tile)
+    return _every_window(*window(rows), tokens, by_expert, gate, weights,
+                         later=window(later_rows))
+
+
+# -- dropless experts over an expert axis --------------------------------------
+#
+# Inside ``shard_map`` over one mesh axis of ``n`` devices, each holding its own
+# tokens and ``E / n`` experts (the weights' dim 0 on the axis).  With ``k``
+# choices over ``n`` devices a token has a pair on a given device with
+# probability ``1 - (1 - 1/n)^k`` (0.90 at 8 over 4), so an all-to-all of pairs
+# would send ``k (n - 1) / n`` rows a token where an all-gather of tokens sends
+# ``n - 1``; and what a device then does with everyone's tokens is the held
+# pass above with ``first = count x axis_index``.  So: all-gather the tokens,
+# their choices and their weights; the held passes (twice the mean share a
+# pass, what a batch routes here beyond it by further passes, no pair
+# dropped); reduce-scatter the partial sums, each token's to the device it came
+# from.  The backward pass is the same pair transposed, by jax's own rules.
+
+#: a pass after a device's first lays out this share of the first's rows:
+#: with every device's tokens here the popular experts' device takes 2.1-2.7
+#: times the mean share at initial weights (v5e, four seeds: PERF.md, PR 72),
+#: what spills over twice the mean is a fraction of a window, and a window
+#: costs what it lays out (gathers of its rows whether live or not)
+LATER_SHARE = 4
+
+
+def exchange_bytes(tokens_local, hidden, k, n, itemsize):
+    """``{"gather": .., "scatter": ..}``: the bytes ONE device receives in
+    the forward pass's all-gather of ``tokens_local`` tokens a device (with
+    ``k`` int32 choices and f32 weights each) and in its reduce-scatter of
+    the partial sums, over ``n`` devices."""
+    rows = (n - 1) * tokens_local
+    return {"gather": rows * (hidden * itemsize + 8 * k),
+            "scatter": rows * hidden * itemsize}
+
+
+def exchange_bytes_a_step(forward, forward_passes=1):
+    """``forward`` (``exchange_bytes``) over ONE TRAINING STEP of a layer:
+    the bytes a device receives in all its all-gathers (``gather``) and in
+    all its reduce-scatters (``scatter``).  The backward pass is the forward
+    pair transposed: the sums' cotangent is gathered (the scatter's bytes)
+    and the tokens' and weights' cotangents are scattered (the choices have
+    none: half way between the two).  A layer recomputed in the backward pass
+    (``forward_passes`` 2) gathers once more and scatters nothing more, since
+    nothing reads the recomputed sums: three all-gathers and two
+    reduce-scatters a layer and step on the device's trace (PR 72)."""
+    gather, scatter = forward["gather"], forward["scatter"]
+    return {"gather": forward_passes * gather + scatter,
+            "scatter": scatter + (gather + scatter) // 2}
+
+
+def dropless_moe_over_axis(tokens, idx, gate, w_gate, w_up, w_down, *, axis,
+                           num_experts, impl=None):
+    """``dropless_moe`` of THIS device's ``tokens [T, H]`` (``idx, gate [T,
+    k]`` over all ``num_experts``) where the experts are spread over the mesh
+    axis ``axis``: the weights are this device's ``num_experts / n`` experts,
+    the ``axis_index``-th run of them.  Call inside ``shard_map`` over
+    ``axis`` (a kernel sees no mesh there: the grouped products, the row sums
+    run their Pallas forms on the shard).  Returns ``(y [T, H], counts)``:
+    ``load`` and ``computed`` ``[num_experts]`` over ALL devices' tokens, the
+    host's counts, the same on every device, and ``later`` ``[num_experts]``,
+    the pairs a pass after a device's first computed."""
+    n = jax.lax.axis_size(axis)
+    count = w_up.shape[0]
+    assert count * n == num_experts, (count, n, num_experts)
+    with named_scope("hetu_moe_exchange"):
+        everyone = [jax.lax.all_gather(a, axis, axis=0, tiled=True)
+                    for a in (tokens, idx, gate)]
+    tokens_all, idx_all, gate_all = everyone
+    # the experts here as 0 .. count - 1; every other index is held elsewhere
+    mine = idx_all - count * jax.lax.axis_index(axis).astype(idx_all.dtype)
+    rows = held_rows(idx_all.size, num_experts, count)
+    part, counts = _held_moe(
+        tokens_all, mine, gate_all, (w_gate, w_up, w_down), (0, count), rows,
+        rows // LATER_SHARE, impl=impl)
+    with named_scope("hetu_moe_exchange"):
+        y = jax.lax.psum_scatter(part, axis, scatter_dimension=0, tiled=True)
+        later = counts["computed"] - counts["kept"]
+        host = {name: jax.lax.all_gather(rows, axis, axis=0, tiled=True)
+                for name, rows in (("load", counts["load"]),
+                                   ("computed", counts["computed"]),
+                                   ("later", later))}
+    return y, host

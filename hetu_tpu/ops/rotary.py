@@ -29,7 +29,10 @@ partial rotation (``rotary_dim`` < head size) keeps the kernels: its tables are
 ``jnp`` with ``head_dim_not_128_aligned``, ``dtype:<name>``, ``dtype:mixed`` or
 ``seq_not_16_aligned``; what a mesh and a platform without Mosaic mean is
 ``dispatch.take``'s rule, and ``_rotary(seq_axis=1)`` then runs on each
-tensor's view.  A layer that norms each head before it rotates
+tensor's view; under a mesh whose only axis larger than 1 is a ``dp`` that
+divides the batch the pair runs per shard under ``shard_map``, each device on
+its own sequences (the plan flash attention, the window kernels and
+softmax-CE have).  A layer that norms each head before it rotates
 (``qk_norm="head"``: SDAR, Qwen3) hands q, k and the two norms' scales to
 ``qk_norm_rotary_pair_op`` instead: ONE pass of a second kernel pair
 (``hetu_qk_norm_rope_fwd`` / ``_bwd``, counted under ``qk_norm_rope``) with
@@ -54,6 +57,7 @@ import jax.numpy as jnp
 from .base import SimpleOp, simple_op
 from .nn import _rms_norm
 from .pallas import dispatch, rotary as kernels
+from .pallas.dispatch import shard_axes
 from ..graph.node import current_stage
 
 
@@ -206,12 +210,35 @@ class RotaryPairOp(SimpleOp):
         q, k, tables = input_vals
         seq_len, d = tables.shape[1:]
         q, k = (x.reshape(-1, seq_len, x.shape[-1]) for x in (q, k))
-        if dispatch.take("rotary", ctx.mesh,
+        turned = self.attrs.get("rotary_dim")
+        # under a mesh whose one axis splits the batch the kernels run on
+        # each device's own sequences and see no mesh; any other mesh is
+        # ``take``'s to refuse
+        over = _batch_axes(ctx.mesh, q.shape[0])
+        if dispatch.take("rotary", None if over else ctx.mesh,
                          kernels.unsupported(q, k, head_dim=d)):
-            return kernels.rope(q, k, tables, self.attrs.get("rotary_dim"))
+            if not over:
+                return kernels.rope(q, k, tables, turned)
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+            # pallas out_shapes carry no varying-axes annotations
+            return shard_map(
+                lambda q, k, t: kernels.rope(q, k, t, turned), mesh=ctx.mesh,
+                in_specs=(P(over), P(over), P()), out_specs=(P(over),) * 2,
+                check_vma=False)(q, k, tables)
         return tuple(
             self.impl(x.reshape(*x.shape[:2], -1, d), seq_axis=1,
                       **self.attrs).reshape(x.shape) for x in (q, k))
+
+
+def _batch_axes(mesh, batch):
+    """The mesh axes a per-shard rotation splits its batch over
+    (``dispatch.shard_axes``: ``dp`` where it divides the batch and no other
+    axis is larger than 1), ``()`` where there is none."""
+    if mesh is None:
+        return ()
+    why, axes = shard_axes(mesh, {"dp": batch})
+    return () if why is not None else axes["dp"]
 
 
 _rotary_pair_op = simple_op(_rotary, "rotary_pair", node_cls=RotaryPairOp)

@@ -123,6 +123,54 @@ class FSDP(Strategy):
         return self.mesh
 
 
+class ExpertParallel(Strategy):
+    """One mesh axis that is data-parallel for everything but the experts
+    and the vocabulary: the placeholders' batch on the axis; the ``[E, H,
+    F]`` expert stacks' dim 0 on it (``MoELayer(ep_axis=)``, which says so
+    itself: a device holds ``E / n`` experts with their optimizer state, and
+    the dropless op exchanges tokens under ``shard_map``:
+    ``ops/moe.py dropless_moe_over_axis``); the embedding table's and an
+    untied head's vocabulary dim on it where it divides (FSDP's way:
+    a device keeps ``V / n`` rows of the f32 master and of the moments, the
+    compute type's copy is gathered where it is used and the gradient
+    reduce-scattered, so the loss kernel sees whole rows of logits for the
+    device's own tokens); everything else replicated, its gradients
+    all-reduced by GSPMD as ``DataParallel``'s are.
+
+    The axis goes by ``dp`` because that is the name the per-shard kernel
+    plans split a batch over (``ops/pallas/dispatch.py shard_axes``)."""
+
+    #: embedding tables (``layers/common.py Embedding``) and a causal LM's
+    #: untied head (``models/llama.py``): name -> the vocabulary's dim
+    VOCAB = ((re.compile(r"_table$"), 0), (re.compile(r"_lm_head_weight$"), 1))
+
+    def __init__(self, mesh=None, ndev=None, axis="dp"):
+        self.mesh = mesh if mesh is not None else make_mesh(
+            {axis: ndev or _ndev()})
+        self.axis = axis
+
+    def annotate(self, eval_nodes):
+        size = self.mesh.shape[self.axis]
+        experts = 0
+        for n in find_topo_sort(eval_nodes):
+            if isinstance(n, PlaceholderOp):
+                n.dist_state = DistState({0: self.axis})
+            elif isinstance(n, VariableOp):
+                if n.dist_state is not None:
+                    experts += n.dist_state.splits.get(0) == self.axis
+                    continue
+                for pattern, dim in self.VOCAB:
+                    if pattern.search(n.name) and n.shape[dim] % size == 0:
+                        n.dist_state = DistState({dim: self.axis})
+        if size > 1 and not experts:
+            import warnings
+            warnings.warn(
+                f"ExpertParallel.annotate: no variable has its dim 0 on "
+                f"{self.axis!r}; build the expert layers with "
+                f"MoELayer(ep_axis={self.axis!r})", stacklevel=2)
+        return self.mesh
+
+
 class MegatronLM(Strategy):
     """2D dp×tp for transformer stacks (reference simple.py:174).
 

@@ -674,6 +674,55 @@ def test_data_parallel_donates_every_leaf_without_warning(tel):
     assert _donates_gauge("train") == 1
 
 
+def test_a_program_that_keeps_its_state_returns_what_it_changed_on_a_mesh(
+        tel):
+    """A program that does not take its state donated returns, under a mesh
+    too, only the leaves it replaced (a second whole state does not fit
+    beside a state that fills half a chip: PERF.md, PR 72), with shardings
+    the compiler chose; ``_dispatch`` places them where the next call's
+    ``in_shardings`` take them.  So: a program that changes nothing hands the
+    very same arrays on, a training program that keeps its state alive
+    replaces every leaf on the state's own shardings in one compilation, and
+    the steps are the one-device program's."""
+    import warnings
+    import jax
+    from hetu_tpu.parallel import DataParallel
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    one, feed_one = _donation_problem("keep1", donate_params=False)
+    ex, feed = _donation_problem("keep4", donate_params=False,
+                                 dist_strategy=DataParallel(ndev=4))
+    ex.run("validate", feed_dict=feed)       # _commit_state places the state
+    for name in ("dn_w", "dn_b"):
+        ex.params[f"{name}_keep4"] = jax.device_put(
+            np.asarray(one.params[f"{name}_keep1"]),
+            ex.params[f"{name}_keep4"].sharding)
+    held = _state_leaves(ex)
+    ex.run("validate", feed_dict=feed)
+    assert all(a is b for a, b in zip(held, _state_leaves(ex)))
+    sub = ex.subexecutor["train"]
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        for _ in range(3):
+            got = ex.run("train", feed_dict=feed,
+                         convert_to_numpy_ret_vals=True)[0]
+            want = one.run("train", feed_dict=feed_one,
+                           convert_to_numpy_ret_vals=True)[0]
+            assert abs(got - want) < 1e-5 * abs(want)
+            assert sub._returns_changed_only and sub._state_sh is not None
+            placed = jax.tree_util.tree_map(
+                lambda v, sh: v.sharding == sh, (ex.params, ex.opt_state),
+                sub._state_sh)
+            assert placed and all(jax.tree_util.tree_leaves(placed))
+        assert not any(v.is_deleted() for v in held)
+        assert not any(a is b for a, b in zip(held, _state_leaves(ex)))
+        # the placed leaves are what the program was compiled to take
+        assert sub._jitted._cache_size() == 1
+    assert not [str(w.message) for w in caught
+                if "donated buffers" in str(w.message)]
+    assert _donates_gauge("train") == 0
+
+
 def test_state_dict_survives_later_donation():
     """``state_dict`` hands out host arrays; the device buffers they were
     read from are donated by the next step.  On the CPU ``np.asarray`` of

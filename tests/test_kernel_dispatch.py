@@ -15,6 +15,11 @@ pinned three ways.
    to take; off a mesh on a TPU the kernel, counted ``pallas``; nothing is
    counted on a platform without Mosaic.  One parametrised test over (node,
    platform, mesh) on one stub of the kernel and of the ``jax.numpy`` form.
+4. The kernels that left the rule where a ``dp`` axis divides the batch (PR
+   72): the rotary pair runs per shard under ``shard_map``, and the experts'
+   three (``moe_gmm``, ``moe_rows``, ``moe_select``) are called inside the
+   expert layer's own ``shard_map`` over its axis, where they see no mesh:
+   each counts ``pallas`` and none ``mesh``.
 """
 
 import ast
@@ -294,9 +299,55 @@ def test_a_node_reads_the_mesh(choices, monkeypatch, scope, platform, mesh,
         real = stub(monkeypatch, form, called, "jnp")
         stub(monkeypatch, kernel, called, "pallas",
              like=lambda: lambda *a: real(*a))
+    if mesh is not None:        # one that divides no operand's batch of 1
+        mesh = types.SimpleNamespace(shape={"dp": 2})
     ctx = types.SimpleNamespace(mesh=mesh)
     jax.eval_shape(lambda *a: node._compute(list(a), ctx), *operands)
     jnp_calls = 2 if label == "rotary" else 1        # q, then k
     assert called == (["pallas"] if want == {PALLAS: 1}
                       else ["jnp"] * jnp_calls)
     assert choices(label) == want
+
+
+# -- 4. per shard where a dp axis divides the batch --------------------------------
+
+@pytest.fixture(scope="module")
+def dp4():
+    from hetu_tpu.parallel.mesh import make_mesh
+    return make_mesh({"dp": 4}, devices=jax.devices()[:4])
+
+
+def traced_under(dp4, label):
+    """Trace, on a TPU by name and under a mesh whose ``dp`` divides the
+    batch of 4, the node that stands in front of ``label``'s kernel."""
+    import hetu_tpu as ht
+    from hetu_tpu.graph.node import find_topo_sort
+    ctx = types.SimpleNamespace(mesh=dp4, master_params=None, training=False,
+                                record_update=lambda *a: None)
+    if label == "rotary":
+        from hetu_tpu.layers.attention import MultiHeadAttention
+        x = ht.placeholder_op("kd4_x", (4, 64, 256))
+        attn = MultiHeadAttention(256, 2, sequence_length=64, rope_theta=1e4,
+                                  name="kd4_attn")(x, x, x)
+        node = next(n for n in find_topo_sort([attn])
+                    if getattr(n, "op_kind", "") == "rotary_pair")
+        operands = [bf16(4, 64, 2 * D), bf16(4, 64, 2 * D), f32(2, 64, D)]
+    else:
+        from hetu_tpu.layers.moe import MoELayer
+        x = ht.placeholder_op("kd4_tokens", (4, 128, D))
+        layer = MoELayer(D, D, num_experts=16, k=4, capacity_factor=None,
+                         expert_act="swiglu", ep_axis="dp", name="kd4_moe")
+        node = layer(x)
+        shapes = {"x": bf16(4, 128, D), "router": bf16(D, 16)}
+        operands = [shapes.get(name, bf16(16, D, D)) for name in node.at]
+    jax.eval_shape(lambda *a: node._compute(list(a), ctx), *operands)
+
+
+@pytest.mark.parametrize("label", ["rotary", "moe_gmm", "moe_rows",
+                                   "moe_select"])
+def test_a_kernel_runs_per_shard_where_dp_divides_the_batch(
+        choices, monkeypatch, dp4, label):
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    traced_under(dp4, label)
+    got = choices(label)
+    assert got and set(got) == {PALLAS}, got

@@ -456,7 +456,10 @@ def test_the_layers_own_keywords_build_under_either_regime(capacity_factor):
     layer = MoELayer(H, F, E, k=K, capacity_factor=capacity_factor,
                      expert_act="swiglu", shared_width=8, shared_gate=False,
                      ep_axis="ep", track_load=True, renorm_topk=False)
-    assert len(layer.shared) == 3 and layer.load_var.shape == (2, E)
+    # dropless experts spread over an axis count the host's pairs in the
+    # four rows of a held layer (PR 72: ``elsewhere`` 0, the further passes')
+    rows = 4 if capacity_factor is None else 2
+    assert len(layer.shared) == 3 and layer.load_var.shape == (rows, E)
 
 
 # -- rows to tokens: the sum over token tiles against the one-hot product -----

@@ -226,8 +226,9 @@ def test_a_step_that_drops_a_pair_is_a_failed_step(monkeypatch):
     incorrect run."""
     from hetu_tpu.ops import moe as moe_ops
     monkeypatch.setattr(moe_ops, "held_rows", lambda pairs, E, count: 8)
-    monkeypatch.setattr(moe_ops, "_every_window",
-                        lambda one_pass, step, *args: one_pass(*args, 0))
+    monkeypatch.setattr(
+        moe_ops, "_every_window",
+        lambda one_pass, step, *args, later: one_pass(*args, 0))
     prog, mix = hybrid_toy()
     try:
         feed = prog.make_batches(2 ** 31 + 3, 1)[0]

@@ -165,8 +165,9 @@ def test_a_window_that_drops_a_pair_is_not_correct(monkeypatch, capsys):
     from hetu_tpu.ops import moe as moe_ops
     monkeypatch.setattr(moe_ops, "held_rows", lambda pairs, E, count: 8)
     # and a program that stops after the first pass over its rows
-    monkeypatch.setattr(moe_ops, "_every_window",
-                        lambda one_pass, step, *args: one_pass(*args, 0))
+    monkeypatch.setattr(
+        moe_ops, "_every_window",
+        lambda one_pass, step, *args, later: one_pass(*args, 0))
     prog, _ = hybrid_toy()
     try:
         feed = prog.make_batches(2 ** 31 + 3, 1)[0]
