@@ -99,6 +99,21 @@ def count_inverse(rule, source):
     ).labels(rule=rule, source=source).inc()
 
 
+def count_gmm_plan(kernel, k_blocks, row_passes):
+    """Count one traced grouped product (``kernel``: ``hetu_moe_gmm_fwd``,
+    ``_dx`` or ``_dw``) by its plan (``moe_gmm.gmm_plan`` / ``tgmm_plan``):
+    the blocks its contraction (``_dw``: its output's rows) is cut into, 1
+    where an expert's weights are fetched once, and the times it walks the
+    row tiles, 1 where every row tile is read once."""
+    telemetry.get_registry().counter(
+        "hetu_moe_gmm_plan_total",
+        "Trace-time grouped products by their blocks: k_blocks 1 fetches an "
+        "expert's weights once, row_passes 1 reads every row tile once",
+        labels=("kernel", "k_blocks", "row_passes"),
+    ).labels(kernel=kernel, k_blocks=str(k_blocks),
+             row_passes=str(row_passes)).inc()
+
+
 #: the reason of a kernel that has no per-shard form, under a mesh
 MESH = "mesh"
 

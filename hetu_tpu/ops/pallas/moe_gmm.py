@@ -22,6 +22,21 @@ Tiles from ``n_used`` on (the static row bound is never reached) are
 skipped: ``gmm`` writes zeros there, ``tgmm`` adds nothing.  The layout
 assigns them to the last expert, so ``tile_expert`` stays sorted.
 
+The blocks are the product's own widths (``gmm_plan``, ``tgmm_plan``:
+functions of ``(tm, k, n, dtype)`` and ``VMEM_LIMIT`` alone; no argument,
+environment variable or table decides one).  ``gmm`` takes the contraction
+WHOLE wherever its blocks fit ``BLOCK_BUDGET``: with one contraction step
+the weight block's index changes only where the expert does, so an expert's
+weights are fetched once; split over ``kk``, the innermost grid axis, they
+are fetched again for every row tile, live or skipped (PR 73: 2.0 MB a grid
+step under 1.3 us of product at Mellum2's 2,304).  Then the widest column
+block that still fits, the whole width first: each column pass reads every
+row tile again.  ``tgmm`` takes the blocks that walk the rows the fewest
+times, once where ``[K, N]`` fits: each ``(a, b)`` of its grid is a pass
+over ALL row tiles.  A product too wide for the budget gets the blocks of
+``fit128`` under the caps the kernels had before PR 73.  Every traced
+kernel counts its plan (``dispatch.count_gmm_plan``).
+
 Named ``hetu_moe_gmm_fwd`` / ``hetu_moe_gmm_dx`` / ``hetu_moe_gmm_dw`` in
 the device trace.
 """
@@ -29,6 +44,7 @@ the device trace.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +52,18 @@ import jax.numpy as jnp
 from . import dispatch
 from .common import VMEM_LIMIT, WALK, fit, params
 
-# (scoped VMEM: a [256, 2048] row tile, a [2048, 1024] weight block and the
-# f32 accumulator, double-buffered, are about 14 MiB of ``VMEM_LIMIT``'s 64;
-# Mosaic's default scoped limit is 16 MiB of the v5e's 128)
+# (scoped VMEM: the kernels ask for ``VMEM_LIMIT``, 64 MiB of the v5e's 128
+# where Mosaic's default is 16.  A plan sums its blocks: the two operands'
+# and the output's, each twice (Pallas double-buffers them), and the f32
+# accumulator once.  Whole, bf16, 128-row tiles: ``gmm`` 10 MB at Mellum2's
+# 2,304 x 896, 17 at Xing4.0's 3,584 x 1,024, 22 at Nemotron-H's 2,688 x
+# 1,856; ``tgmm`` 18, 31 and 42, and 38 at ZAYA1's 2,048 x 2,048 on 256-row
+# tiles.  The rest of the limit is the body's: the product's f32 value
+# before it is added, at most another accumulator)
+#: what a plan's blocks may sum to, of ``VMEM_LIMIT``
+BLOCK_BUDGET = VMEM_LIMIT * 3 // 4
 #: the widest dimension taken as one block where no multiple of 128 divides
-#: it: the default contraction block
+#: it, and the contraction block of a product the budget cannot take whole
 WHOLE_WIDTH = 2048
 
 
@@ -48,6 +71,55 @@ def fit128(dim, want):
     """The largest multiple of 128 that divides ``dim`` and is at most
     ``want`` (``dim`` itself when it is at most ``want`` or none does)."""
     return dim if dim <= want else fit(dim, want, 128) or dim
+
+
+def blocks(dim):
+    """The blocks ``dim`` can be cut into, widest first: itself, then every
+    multiple of 128 that divides it."""
+    return [dim] + [t for t in range(dim // 128 * 128, 0, -128)
+                    if t < dim and dim % t == 0]
+
+
+class Plan(NamedTuple):
+    """A grouped product's blocks: ``tk`` of ``k`` and ``tn`` of ``n``, the
+    ``k_blocks = k / tk`` and ``n_blocks = n / tn`` they make, and the
+    ``vmem`` bytes the kernel's blocks sum to."""
+    tk: int
+    tn: int
+    k_blocks: int
+    n_blocks: int
+    vmem: int
+
+
+def _plan(tm, k, n, dtype, tk, tn, acc_rows):
+    """The plan of blocks ``tk``, ``tn``: three double-buffered blocks of
+    the operands' type and an f32 accumulator ``[acc_rows, tn]``."""
+    size = jnp.dtype(dtype).itemsize
+    return Plan(tk, tn, k // tk, n // tn,
+                2 * size * (tm * tk + tk * tn + tm * tn) + 4 * acc_rows * tn)
+
+
+def gmm_plan(tm, k, n, dtype):
+    """``gmm``'s blocks for ``[tm, k]`` row tiles times ``[k, n]`` weights:
+    the contraction whole and the widest ``tn`` that fits ``BLOCK_BUDGET``
+    beside it; where no ``tn`` does, ``fit128`` under the old caps."""
+    return next(
+        (p for p in (_plan(tm, k, n, dtype, k, tn, tm) for tn in blocks(n))
+         if p.vmem <= BLOCK_BUDGET),
+        _plan(tm, k, n, dtype, fit128(k, WHOLE_WIDTH), fit128(n, 1024), tm))
+
+
+def tgmm_plan(tm, k, n, dtype):
+    """``tgmm``'s blocks for ``[tm, k]^T [tm, n]`` sums: of those that fit
+    ``BLOCK_BUDGET`` (of all, where none does: a width no multiple of 128
+    divides has one block) the fewest passes over the rows, ``k_blocks x
+    n_blocks``, then the fewest operand bytes read again (``x`` once a
+    column block, ``dy`` once a block of ``k``)."""
+    plans = [_plan(tm, k, n, dtype, tk, tn, tk)
+             for tk in blocks(k) for tn in blocks(n)]
+    return min([p for p in plans if p.vmem <= BLOCK_BUDGET] or plans,
+               key=lambda p: (p.k_blocks * p.n_blocks,
+                              p.n_blocks * k + p.k_blocks * n))
 
 
 def unsupported(m, k, n, tm, dtype):
@@ -80,18 +152,17 @@ def live(i, n_used):
 # rows call the same few (shapes, options), and each is traced and lowered
 # once a program, not once a call site (tracing a kernel is Python time).
 
-@functools.partial(jax.jit, static_argnames=("tm", "transpose_rhs", "tk",
-                                             "tn", "name"))
+@functools.partial(jax.jit, static_argnames=("tm", "transpose_rhs", "name"))
 def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
-        tk=2048, tn=1024, name="hetu_moe_gmm_fwd"):
+        name="hetu_moe_gmm_fwd"):
     """``out[tile i] = x[tile i] @ w[tile_expert[i]]`` (``w[...]^T`` with
     ``transpose_rhs``) for the first ``n_used`` row tiles, zeros after."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     m, k = x.shape
     n = w.shape[1] if transpose_rhs else w.shape[2]
-    tk, tn = fit128(k, tk), fit128(n, tn)
-    tiles_k = k // tk
+    tk, tn, tiles_k, passes, _ = gmm_plan(tm, k, n, x.dtype)
+    dispatch.count_gmm_plan(name, tiles_k, passes)
     contract = (((1,), (1,)), ((), ())) if transpose_rhs \
         else (((1,), (0,)), ((), ()))
 
@@ -140,16 +211,16 @@ def gmm(x, w, tile_expert, n_used, *, tm, transpose_rhs=False,
     )(tile_expert, n_used, x, w)
 
 
-@functools.partial(jax.jit, static_argnames=("num_experts", "tm", "tk",
-                                             "tn", "name"))
-def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm, tk=2048, tn=512,
+@functools.partial(jax.jit, static_argnames=("num_experts", "tm", "name"))
+def tgmm(x, dy, tile_expert, n_used, num_experts, *, tm,
          name="hetu_moe_gmm_dw"):
     """``dw[e] = sum over expert e's row tiles of x_tile^T @ dy_tile``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     m, k = x.shape
     n = dy.shape[1]
-    tk, tn = fit128(k, tk), fit128(n, tn)
+    tk, tn, tiles_k, tiles_n, _ = tgmm_plan(tm, k, n, x.dtype)
+    dispatch.count_gmm_plan(name, tiles_k, tiles_k * tiles_n)
     tiles_m = m // tm
 
     def kernel(te, nu, x_ref, dy_ref, o_ref, acc):

@@ -1076,6 +1076,39 @@ def test_held_relu2_experts_compile_for_v5e_at_a_width_off_128(v5e, as_on_tpu):
     assert "ragged-dot" not in hlo
 
 
+@pytest.mark.parametrize("hidden,width,tm", [
+    (2688, 1856, 128),      # Nemotron-H: the widest blocks, ``dw`` 42 MB
+    (3584, 1024, 128),      # Xing4.0: the widest contraction
+    (2048, 2048, 256),      # ZAYA1's widths on OLMoE's 256-row tiles
+    (2304, 896, 128),       # Mellum2: 896 = 7 x 128
+])
+def test_grouped_products_compile_whole_for_v5e(v5e, as_on_tpu, hidden, width,
+                                                tm):
+    """The three grouped products on the blocks their plans name, the whole
+    ``[hidden, width]`` of an expert (PR 73), fit the scoped VMEM the kernels
+    ask for: Mosaic takes each at 40 row tiles of 8 experts."""
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.ops.pallas import moe_gmm
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one)
+    E, m = 8, 40 * tm
+    te, nu = sds((40,), jnp.int32), sds((1,), jnp.int32)
+    assert moe_gmm.gmm_plan(tm, hidden, width, jnp.bfloat16)[:2] == (
+        hidden, width) == moe_gmm.tgmm_plan(tm, hidden, width,
+                                            jnp.bfloat16)[:2]
+    for fn, a, b in (
+            (lambda x, w, te, nu: moe_gmm.gmm(x, w, te, nu, tm=tm),
+             sds((m, hidden)), sds((E, hidden, width))),
+            (lambda dy, w, te, nu: moe_gmm.gmm(
+                dy, w, te, nu, tm=tm, transpose_rhs=True,
+                name="hetu_moe_gmm_dx"),
+             sds((m, width)), sds((E, hidden, width))),
+            (lambda x, dy, te, nu: moe_gmm.tgmm(x, dy, te, nu, E, tm=tm),
+             sds((m, hidden)), sds((m, width)))):
+        hlo = jax.jit(fn).lower(a, b, te, nu).compile().as_text()
+        assert sum("tpu_custom_call" in ln for ln in hlo.splitlines()) == 1
+
+
 @pytest.mark.parametrize("experts,k,groups", [
     (512, 8, (8, 4)),       # Ling-3.0: the groups kept, then the experts
     (512, 10, None),        # Qwen3-Next
