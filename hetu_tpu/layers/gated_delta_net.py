@@ -77,8 +77,7 @@ def _scan(mixed, ba, a_log, dt_bias, *, key_heads, dk, dv, rep, rule=None):
     q, k, v = (mixed[..., :kd], mixed[..., kd:2 * kd], mixed[..., 2 * kd:])
 
     def unit(t):            # L2 norm over a head, then one copy a value head
-        t = t.reshape(B, S, key_heads, dk).astype(f32)
-        t = t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        t = recurrent.unit_heads(t, key_heads)
         # the copies side by side along the lanes: [.., key heads, rep x dk]
         # is the layout of the [B, S, value heads x dk] the rule's kernels
         # read, where jnp.repeat's [.., key heads, rep, dk] is copied again

@@ -75,12 +75,9 @@ def _scan(proj, mixed, beta_lin, a_log, dt_bias, w_norm, *, heads, d,
                                    eps=eps)
         if y is not None:
             return y
-
-    def unit(t):
-        t = t.reshape(B, S, heads, d).astype(f32)
-        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-    q = (unit(mixed[..., :hd]) * d ** -0.5).astype(mixed.dtype)
-    k = unit(mixed[..., hd:2 * hd]).astype(mixed.dtype)
+    unit = recurrent.unit_heads
+    q = (unit(mixed[..., :hd], heads) * d ** -0.5).astype(mixed.dtype)
+    k = unit(mixed[..., hd:2 * hd], heads).astype(mixed.dtype)
     v = mixed[..., 2 * hd:].reshape(B, S, heads, d)
     g = gate(proj[..., 3 * hd:4 * hd].reshape(B, S, heads, d), a_log,
              dt_bias, lower_bound)

@@ -1,6 +1,7 @@
 """The initialisers the recurrent mixers (``gated_delta_net.py``,
-``mamba2.py``, ``mamba1.py``, ``kda.py``) share, of their decays and steps.  Their nodes are
-shared too: a projection is ``ScopedOp(project, ..)`` (``base.py``), the scan
+``mamba2.py``, ``mamba1.py``, ``kda.py``) share, of their decays and steps,
+and the delta rules' L2 norm a head (``unit_heads``).  Their nodes are shared
+too: a projection is ``ScopedOp(project, ..)`` (``base.py``), the scan
 ``ops/base.py KernelOp``, the convolution ``ops/causal_conv.py ConvOp``, the
 scalar mixers' output ``ops/gated_norm.py OutOp``; the data flow between the
 nodes is each layer's own."""
@@ -8,6 +9,17 @@ nodes is each layer's own."""
 from __future__ import annotations
 
 import numpy as np
+
+
+def unit_heads(t, heads):
+    """``t [B, S, heads d] -> [B, S, heads, d]`` f32, each head over its norm
+    (1e-6 under the root): q and k of the delta rules' ``jax.numpy`` prologue,
+    where their kernels do not take the convolution's output in place
+    (``ops/pallas/common.py unit`` is this on a chunk in VMEM)."""
+    import jax
+    import jax.numpy as jnp
+    t = t.reshape(t.shape[:2] + (heads, -1)).astype(jnp.float32)
+    return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
 
 
 def log_uniform(lo, hi):

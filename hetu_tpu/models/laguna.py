@@ -43,8 +43,8 @@ from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
 from ..layers.moe import MoELayer
 from ..ops.rotary import yarn_scaling
-from .ling3 import Ling3ForCausalLM
-from .llama import LlamaMLP, LlamaModel, residual_sublayer
+from .llama import (BiasBalanced, LlamaForCausalLM, LlamaMLP, LlamaModel,
+                    residual_sublayer)
 
 KINDS = ("full_attention", "sliding_attention")
 
@@ -204,10 +204,10 @@ class LagunaModel(LlamaModel):
                                   rope_tables=self.rope_tables)
 
 
-class LagunaForCausalLM(Ling3ForCausalLM):
-    """The loss is the cross-entropy alone and ``moe_loads`` is over the
-    expert layers (``[4, count]`` where a share of the experts is held), as
-    the Ling-3.0 model's, whose methods these are."""
+class LagunaForCausalLM(BiasBalanced, LlamaForCausalLM):
+    """The loss is the cross-entropy alone (``BiasBalanced``) and
+    ``moe_loads`` is over the expert layers (``[4, count]`` where a share of
+    the experts is held)."""
     model_cls = LagunaModel
 
     def __init__(self, config, name="laguna", pipeline_stages=None):

@@ -33,13 +33,13 @@ from __future__ import annotations
 
 from contextlib import nullcontext
 
-from ..graph.node import remat as remat_scope, scope
+from ..graph.node import remat as remat_scope
 from ..layers import RMSNorm
 from ..layers.base import BaseLayer
 from ..layers.kda import KimiDeltaAttention
 from ..layers.latent_attention import LatentAttention
 from ..layers.moe import MoELayer
-from .llama import (LlamaForCausalLM, LlamaMLP, LlamaModel,
+from .llama import (BiasBalanced, LlamaForCausalLM, LlamaMLP, LlamaModel,
                     residual_sublayer)
 
 
@@ -177,36 +177,14 @@ class Ling3Model(LlamaModel):
         return Ling3DecoderLayer(self.config, i, name)
 
 
-class Ling3ForCausalLM(LlamaForCausalLM):
+class Ling3ForCausalLM(BiasBalanced, LlamaForCausalLM):
     """``moe_loads`` is the base class's over the expert layers (``[4,
     count]`` where a share of the experts is held); the loss is the
-    cross-entropy alone."""
+    cross-entropy alone (``BiasBalanced``)."""
     model_cls = Ling3Model
 
     def __init__(self, config, name="ling3", pipeline_stages=None):
         super().__init__(config, name=name, pipeline_stages=pipeline_stages)
-
-    def moe_layers(self):
-        return [layer.mlp for layer in self.model.layers if not layer.dense]
-
-    def router_biases(self):
-        """One ``[num_experts]`` node an expert layer: the router's selection
-        bias as this step left it."""
-        return [m.router_bias() for m in self.moe_layers()]
-
-    def loss_terms(self, input_ids, labels, logits=None):
-        """``(loss, {"ce": ...})``: no balance term, the bias balances."""
-        from ..ops import (array_reshape_op,
-                           softmax_cross_entropy_sparse_op)
-        from .llama import MaskedMeanOp
-        if logits is None:
-            logits = self(input_ids)
-        with scope("hetu_loss"):
-            flat = array_reshape_op(labels, output_shape=(-1,))
-            ce = softmax_cross_entropy_sparse_op(logits, flat,
-                                                 ignored_index=-1)
-            loss = MaskedMeanOp(ce, flat)
-        return loss, {"ce": loss}
 
     @property
     def attention_layers(self):

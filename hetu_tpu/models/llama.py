@@ -24,6 +24,7 @@ from .. import initializers as init
 from ..layers import Embedding, Linear, RMSNorm
 from ..layers.base import BaseLayer, fresh_name
 from ..layers.attention import MultiHeadAttention
+from ..layers.moe import MoELayer
 from ..ops.base import ScopedOp
 from ..ops.rotary import RopeTables
 from ..ops import (array_reshape_op, matmul_op, silu_op,
@@ -217,7 +218,6 @@ class LlamaDecoderLayer(BaseLayer):
             qk_norm=c.qk_norm, qk_norm_eps=c.rms_eps,
             rope_tables=rope_tables, name=f"{name}_attn")
         if c.num_experts:
-            from ..layers.moe import MoELayer
             self.mlp = MoELayer(c.hidden_size, c.intermediate_size,
                                 num_experts=c.num_experts, k=c.moe_k,
                                 capacity_factor=c.moe_capacity_factor,
@@ -331,13 +331,10 @@ class LlamaForCausalLM:
         c = self.config
         if logits is None:
             logits = self(input_ids)
+        terms = {"ce": self.cross_entropy(logits, labels)}
+        loss = terms["ce"]
+        mlps = self.moe_layers() if c.num_experts else []
         with scope("hetu_loss"):
-            flat = array_reshape_op(labels, output_shape=(-1,))
-            ce = softmax_cross_entropy_sparse_op(logits, flat,
-                                                 ignored_index=-1)
-            terms = {"ce": MaskedMeanOp(ce, flat)}
-            loss = terms["ce"]
-            mlps = self.moe_layers() if c.num_experts else []
             if mlps:
                 terms["lbl"] = reduce(add, [m.aux_loss() for m in mlps])
                 loss = loss + c.moe_aux_coeff * terms["lbl"]
@@ -346,15 +343,44 @@ class LlamaForCausalLM:
                     loss = loss + c.moe_z_coeff * terms["z"]
             return loss, terms
 
+    def cross_entropy(self, logits, labels):
+        """The mean cross-entropy of ``logits [B S, V]`` over the labelled
+        positions of ``labels [B, S]`` (-1: ignored)."""
+        with scope("hetu_loss"):
+            flat = array_reshape_op(labels, output_shape=(-1,))
+            return MaskedMeanOp(softmax_cross_entropy_sparse_op(
+                logits, flat, ignored_index=-1), flat)
+
     def moe_layers(self):
-        """The model's sparse expert layers, in order (a family whose blocks
-        are not all mixer-then-FFN pairs overrides it)."""
-        return [layer.mlp for layer in self.model.layers]
+        """The model's sparse expert layers, in order: the ``mlp`` of every
+        layer that holds expert weights (not a dense layer's, and not the
+        None of a block that has no FFN)."""
+        return [layer.mlp for layer in self.model.layers
+                if isinstance(layer.mlp, MoELayer)]
 
     def moe_loads(self):
         """One ``[2, E]`` node a layer of (pairs routed, pairs computed) by
         expert, to fetch beside the loss (layers/moe.py ``MoELoadOp``)."""
         return [m.load() for m in self.moe_layers()]
+
+
+class BiasBalanced:
+    """A model whose routers are balanced by a selection bias that each step
+    updates and not by a loss; before ``LlamaForCausalLM`` in a family's
+    bases."""
+
+    def router_biases(self):
+        """One ``[num_experts]`` node an expert layer: the router's selection
+        bias as this step left it, to fetch beside the loads
+        (``layers/moe.py record_moe_load(bias=)``)."""
+        return [m.router_bias() for m in self.moe_layers()]
+
+    def loss_terms(self, input_ids, labels, logits=None):
+        """``(loss, {"ce": ...})``: no balance term, the bias balances."""
+        if logits is None:
+            logits = self(input_ids)
+        loss = self.cross_entropy(logits, labels)
+        return loss, {"ce": loss}
 
 
 def BaichuanForCausalLM(config, name="baichuan", pipeline_stages=None):

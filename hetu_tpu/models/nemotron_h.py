@@ -42,7 +42,8 @@ from ..layers.attention import MultiHeadAttention
 from ..layers.base import BaseLayer
 from ..layers.mamba2 import Mamba2
 from ..layers.moe import MoELayer
-from .llama import LlamaForCausalLM, LlamaModel, residual_sublayer
+from .llama import (BiasBalanced, LlamaForCausalLM, LlamaModel,
+                    residual_sublayer)
 
 #: the published pattern: 23 Mamba-2 mixers, 23 expert layers, 6 attention
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -159,24 +160,17 @@ class NemotronHModel(LlamaModel):
         return NemotronHBlock(self.config, self.config.pattern[i], name)
 
 
-class NemotronHForCausalLM(LlamaForCausalLM):
-    """``loss``, ``loss_terms`` and ``moe_loads`` are the base class's over
-    the expert blocks: the balance loss summed over them at
-    ``moe_aux_coeff``, one load node each (``[3, count]`` where a share of
-    the experts is held)."""
+class NemotronHForCausalLM(BiasBalanced, LlamaForCausalLM):
+    """``router_biases`` and ``moe_loads`` are over the expert blocks, one
+    node each (a load is ``[3, count]`` where a share of the experts is
+    held)."""
     model_cls = NemotronHModel
+    #: the bias balances AND the balance loss is kept: the base class's, summed
+    #: over the expert blocks at ``moe_aux_coeff``
+    loss_terms = LlamaForCausalLM.loss_terms
 
     def __init__(self, config, name="nemotronh", pipeline_stages=None):
         super().__init__(config, name=name, pipeline_stages=pipeline_stages)
-
-    def moe_layers(self):
-        return [b.mlp for b in self.model.layers if b.mlp is not None]
-
-    def router_biases(self):
-        """One ``[n_routed_experts]`` node an expert block: the router's
-        selection bias as this step left it, to fetch beside the loads
-        (``layers/moe.py record_moe_load(bias=)``)."""
-        return [m.router_bias() for m in self.moe_layers()]
 
     @property
     def attention_layers(self):

@@ -55,10 +55,10 @@ from ..layers.base import BaseLayer
 from ..layers.hyper_connection import HyperConnection, collapse, expand
 from ..layers.latent_attention import LatentAttention
 from ..layers.moe import MoELayer
-from ..ops import array_reshape_op, softmax_cross_entropy_sparse_op
+from ..ops import array_reshape_op
 from ..ops.base import ScopedOp, simple_op
 from ..ops.rotary import yarn_scaling
-from .llama import LlamaForCausalLM, LlamaMLP, LlamaModel, MaskedMeanOp
+from .llama import BiasBalanced, LlamaForCausalLM, LlamaMLP, LlamaModel
 
 
 class Xing4Config:
@@ -240,9 +240,10 @@ _next_ids = simple_op(_labelled, "mtp_ids")
 _shift_labels = simple_op(_shifted, "mtp_labels")
 
 
-class Xing4ForCausalLM(LlamaForCausalLM):
-    """``loss_terms`` returns ``(loss, {"ce", "mtp"})``; ``moe_layers`` are
-    the main stack's expert layers and then the MTP depth's."""
+class Xing4ForCausalLM(BiasBalanced, LlamaForCausalLM):
+    """``loss_terms`` returns ``(loss, {"ce", "mtp"})``; ``moe_layers`` (and
+    so ``router_biases``, ``moe_loads``) are the main stack's expert layers
+    and then the MTP depth's."""
     model_cls = Xing4Model
 
     @scoped_init
@@ -268,13 +269,9 @@ class Xing4ForCausalLM(LlamaForCausalLM):
                                     else [])
 
     def moe_layers(self):
+        # an override only to walk the MTP depth's layer behind the stack's
         return [layer.mlp for layer in self.decoder_layers()
-                if not layer.dense]
-
-    def router_biases(self):
-        """One ``[n_routed_experts]`` node an expert layer: the router's
-        selection bias as this step left it."""
-        return [m.router_bias() for m in self.moe_layers()]
+                if isinstance(layer.mlp, MoELayer)]
 
     def hc_maps(self):
         """One ``[B, S, n, n]`` node a hyper-connected sublayer, ``Hres`` as
@@ -300,21 +297,13 @@ class Xing4ForCausalLM(LlamaForCausalLM):
         """``(loss, {"ce": ..., "mtp": ...})``: the next token's mean
         cross-entropy and, weighted by ``mtp_loss_weight``, that of the token
         after it from the MTP depth; no balance term, the bias balances."""
-        if logits is None:
-            logits = self(input_ids)
-        with scope("hetu_loss"):
-            flat = array_reshape_op(labels, output_shape=(-1,))
-            ce = MaskedMeanOp(softmax_cross_entropy_sparse_op(
-                logits, flat, ignored_index=-1), flat)
+        ce, terms = super().loss_terms(input_ids, labels, logits)
         if self.mtp_layer is None:
-            return ce, {"ce": ce}
+            return ce, terms
         #: the depth's logits of the last call (a benchmark fetches them)
         self.mtp_out = self.mtp_logits(_next_ids(labels))
+        mtp = self.cross_entropy(self.mtp_out, _shift_labels(labels))
         with scope("hetu_loss"):
-            after = array_reshape_op(_shift_labels(labels),
-                                     output_shape=(-1,))
-            mtp = MaskedMeanOp(softmax_cross_entropy_sparse_op(
-                self.mtp_out, after, ignored_index=-1), after)
             return ce + mtp * self.config.mtp_loss_weight, {"ce": ce,
                                                             "mtp": mtp}
 

@@ -41,8 +41,8 @@ from ..layers import RMSNorm
 from ..layers.base import BaseLayer
 from ..layers.compressed_attention import CompressedConvAttention
 from ..layers.moe import MoELayer, StateRouter
-from .ling3 import Ling3ForCausalLM
-from .llama import LlamaModel, ResidualMerge, residual_sublayer
+from .llama import (BiasBalanced, LlamaForCausalLM, LlamaModel, ResidualMerge,
+                    residual_sublayer)
 
 
 class Zaya1Config:
@@ -113,9 +113,6 @@ ZAYA1_CONFIGS = {
 
 
 class Zaya1DecoderLayer(BaseLayer):
-    #: what ``Ling3ForCausalLM.moe_layers`` asks a layer
-    dense = False
-
     def __init__(self, config, name, rope_tables=None):
         c = config
         self.attn = CompressedConvAttention(
@@ -171,10 +168,10 @@ class Zaya1Model(LlamaModel):
             return self.norm(x)
 
 
-class Zaya1ForCausalLM(Ling3ForCausalLM):
-    """The loss is the cross-entropy alone; ``moe_loads`` and
-    ``router_biases`` are the Ling-3.0 model's over every layer (a load is
-    ``[5, count]``: the fifth row the skipped pairs and the state's RMS)."""
+class Zaya1ForCausalLM(BiasBalanced, LlamaForCausalLM):
+    """The loss is the cross-entropy alone (``BiasBalanced``); ``moe_loads``
+    and ``router_biases`` are over every layer (a load is ``[5, count]``: the
+    fifth row the skipped pairs and the state's RMS)."""
     model_cls = Zaya1Model
 
     def __init__(self, config, name="zaya1", pipeline_stages=None):

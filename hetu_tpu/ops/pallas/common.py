@@ -13,6 +13,12 @@ a chunk's unit triangular system is inverted by substitution in blocks
 (``unit_lower_inverse``); ``gated_delta.py``'s docstring has the reasons and
 the measurements of both.  The stages here are jitted functions of values,
 as that file's are, so every kernel and call shares one trace of each.
+
+The host side of the walk is here once as well: how a ``[B, T, ..]`` array
+is cut into programs of chunks and padded (``cut``, ``rows``, ``by_chunk``),
+how a program's blocks and a window of a mixed array are addressed
+(``blocks``), and a chunk's L2 norm (``unit``, ``unit_bwd``).  A file's
+``_plan`` is the one place that names its operands.
 """
 
 from __future__ import annotations
@@ -235,6 +241,72 @@ def chunk_rows(j, n):
     """The ``n`` rows of chunk ``j`` (traced)."""
     import jax.experimental.pallas as pl
     return pl.ds(pl.multiple_of(j * n, n), n)
+
+
+def cut(T, chunk=C, chunks=CHUNKS):
+    """Chunks a program, programs along the sequence and the positions of
+    padding behind ``T``: those write nothing, decay nothing (the callers'
+    gates are 0 there) and their outputs are cut off."""
+    nc = min(chunks, -(-T // chunk))
+    groups = -(-T // (nc * chunk))
+    return nc, groups, groups * nc * chunk - T
+
+
+def rows(x, pad, **how):
+    """``[B, T, ..] -> [B, T', lanes]``: ``pad`` positions of 0 (or of
+    ``jnp.pad``'s ``constant_values``) behind ``T``."""
+    x = x.reshape(x.shape[:2] + (-1,))
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0)), **how) if pad else x
+
+
+def by_chunk(x, nc, groups, pad):
+    """``[B, T, H] -> [B, H, T' / (n C), n, C]`` f32, 0 at the padding: a
+    head's number a position of the delta rules (g, beta), ``cut`` before."""
+    B, _, H = x.shape
+    return jnp.moveaxis(rows(x.astype(_F32), pad), 2, 1).reshape(
+        B, H, groups, nc, C)
+
+
+def unit(t):
+    """The rows of ``t [C, d]`` over their norms, f32, and the norms' inverses
+    ``[C, 1]`` (the layers' ``l2norm``: 1e-6 under the root).  Not jitted:
+    ``kda.py`` differentiates through it inside its kernel."""
+    t = t.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(t * t, axis=1, keepdims=True) + 1e-6)
+    return t * r, r
+
+
+def unit_bwd(dt, t, r):
+    """``unit``'s cotangent from that of its rows ``dt``, the rows ``t`` and
+    the norms' inverses ``r``: one lane sum a row."""
+    return r * (dt - t * jnp.sum(dt * t, axis=1, keepdims=True))
+
+
+def blocks(nc, chunk, groups, reverse):
+    """The block specs of a plan whose grid is (batch, block of heads, block
+    of chunks), ``nc`` chunks of ``chunk`` rows a program and ``groups``
+    programs a sequence (``reverse``: from the last to the first), as three
+    functions: ``seq(lanes, first=0, head=..)`` a program's ``nc * chunk``
+    rows of a ``[B, T', ..]`` array, ``lanes`` wide at lane block ``first +
+    head(h)`` (``first``: where a window of a mixed array starts, in blocks);
+    ``kept(lead, *tail)`` what every chunk has of its own, ``[B, H', groups,
+    nc, *tail]``; ``state(lead, *tail)`` what a sequence has, ``[B, H',
+    *tail]``; ``lead`` of the heads' axis a program (None: one)."""
+    import jax.experimental.pallas as pl
+    at = (lambda i: groups - 1 - i) if reverse else (lambda i: i)
+
+    def seq(lanes, first=0, head=lambda h: h):
+        return pl.BlockSpec((None, nc * chunk, lanes),
+                            lambda b, h, i: (b, at(i), first + head(h)))
+
+    def kept(lead, *tail):
+        return pl.BlockSpec((None, lead, None, nc) + tail,
+                            lambda b, h, i: (b, h, at(i), 0) + (0,) * len(tail))
+
+    def state(lead, *tail):
+        return pl.BlockSpec((None, lead) + tail,
+                            lambda b, h, i: (b, h) + (0,) * len(tail))
+    return seq, kept, state
 
 
 def head_lanes(hb, dk, dv):

@@ -32,7 +32,7 @@ d_v]`` f32 in VMEM scratch.  A program reads its chunk's ``T`` as the forward
 kernel wrote it, rebuilds ``W, V', u, P`` from it, q, k, v, g, beta and the
 kept chunk-start state, and writes dq, dk, dv, dg and dbeta once; in place
 it sums a key head's dq and dk over its value heads in f32, takes them
-through the scale and the norm by hand (``_unit_bwd``: ``dt~ = r (dt^ - t^
+through the scale and the norm by hand (``common.unit_bwd``: ``dt~ = r (dt^ - t^
 sum(dt^ t^))``, one lane sum a row) and writes ``dq~, dk~ [B, T, key_dim]``:
 half the cotangent bytes, and ``d mixed`` is one concatenation with ``dv``.
 One kernel: the reverse walk and the gradients inside a chunk share every
@@ -105,10 +105,10 @@ import operator
 import jax
 import jax.numpy as jnp
 
-from . import dispatch
-from .common import (C, CHUNKS, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot,
-                     dot32, head_lanes, iotas, params, pick, put, to_col,
-                     to_row, together, unit_lower_inverse, walk)
+from . import common, dispatch
+from .common import (C, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot, dot32,
+                     head_lanes, iotas, params, pick, put, to_col, to_row,
+                     together, unit, unit_bwd, unit_lower_inverse, walk)
 
 #: heads a program runs in step (at two parts an f32 operand: 1: 7.2 + 9.2
 #: ms a layer forward + backward on a v5e, 2: 4.2 + 5.9, 4: 3.3 + 4.7, 8:
@@ -292,22 +292,6 @@ def _chunk_bwd(q, k, v, g_row, beta_row, S, T, do, dS):
     return _bwd_close(q, k, v, dP, dk_end, da, dQe, dRv, dRw, dL, c) + (dS0,)
 
 
-@jax.jit
-def _unit(t):
-    """The rows of ``t [C, d]`` over their norms, f32, and the norms' inverses
-    ``[C, 1]`` (the layer's ``l2norm``: 1e-6 under the root)."""
-    t = t.astype(_F32)
-    r = jax.lax.rsqrt(jnp.sum(t * t, axis=1, keepdims=True) + 1e-6)
-    return t * r, r
-
-
-@jax.jit
-def _unit_bwd(dt, t, r):
-    """``_unit``'s cotangent from that of its rows ``dt``, the rows ``t`` and
-    the norms' inverses ``r``: one lane sum a row."""
-    return r * (dt - t * jnp.sum(dt * t, axis=1, keepdims=True))
-
-
 def _operands(q_ref, k_ref, rows, hb, dk, rep):
     """``(q, k)`` in the compute type of each of a program's value heads at a
     chunk's ``rows``.  ``rep`` None: a head's lanes of both blocks as they
@@ -320,7 +304,7 @@ def _operands(q_ref, k_ref, rows, hb, dk, rep):
         return [(q_ref[rows, kl], k_ref[rows, kl])
                 for kl, _ in head_lanes(hb, dk, dk)], None
     ct = q_ref.dtype
-    norms = [_unit(q_ref[rows, kl]) + _unit(k_ref[rows, kl])
+    norms = [unit(q_ref[rows, kl]) + unit(k_ref[rows, kl])
              for kl, _ in head_lanes(hb // rep, dk, dk)]
     return [((qn * dk ** -0.5).astype(ct), kn.astype(ct))
             for qn, _, kn, _ in norms for _ in range(rep)], norms
@@ -391,9 +375,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, t_ref, do_ref,
             mine = outs[n_ * rep:(n_ + 1) * rep]
             dq, dk_ = (functools.reduce(operator.add, (o[n] for o in mine))
                        for n in range(2))
-            dq_ref[rows, kl] = _unit_bwd(dq * dk ** -0.5, qn,
-                                         rq).astype(dq_ref.dtype)
-            dk_ref[rows, kl] = _unit_bwd(dk_, kn, rk).astype(dk_ref.dtype)
+            dq_ref[rows, kl] = unit_bwd(dq * dk ** -0.5, qn,
+                                        rq).astype(dq_ref.dtype)
+            dk_ref[rows, kl] = unit_bwd(dk_, kn, rk).astype(dk_ref.dtype)
     walk(nc, body)
 
 
@@ -409,29 +393,19 @@ def _plan(g, widths, reverse):
     starts at the window's: a program's ``hb`` value heads read their ``hb /
     rep`` key heads' ``q~`` and ``k~`` once, and ``dq~``, ``dk~`` are ``[B,
     T, key_dim]``, blocked as ``q~`` is in its window."""
-    import jax.experimental.pallas as pl
     B, H, groups, nc, _ = g.shape
     dk, dv, rep = widths
     hb = math.gcd(H, HEADS)
     keys = hb * dk // (rep or 1)        # lanes of a program's q and k blocks
-    at = (lambda i: groups - 1 - i) if reverse else (lambda i: i)
-    seq = lambda lanes, first=0: pl.BlockSpec(
-        (None, nc * C, lanes), lambda b, h, i: (b, at(i), first + h))
-    kept = lambda rows, cols: pl.BlockSpec(
-        (None, hb, None, nc, rows, cols),
-        lambda b, h, i: (b, h, at(i), 0, 0, 0))
+    seq, kept, state = common.blocks(nc, C, groups, reverse)
     # the windows' first blocks: k~ behind the H / hb blocks of q~, v behind
     # both (``in_place_unsupported`` has refused a v that starts inside one)
     k_at, v_at = (0, 0) if rep is None else (
         H // hb, 2 * (H // hb) * keys // (hb * dv))
     return ((B, H // hb, groups), dict(nc=nc, hb=hb, dk=dk, dv=dv, rep=rep),
             dict(q=seq(keys), k=seq(keys, k_at), v=seq(hb * dv, v_at),
-                 o=seq(hb * dv),
-                 gate=pl.BlockSpec((None, hb, None, nc, C),
-                                   lambda b, h, i: (b, h, at(i), 0, 0)),
-                 kept=kept(dk, dv), inverse=kept(C, C),
-                 state=pl.BlockSpec((None, hb, dk, dv),
-                                    lambda b, h, i: (b, h, 0, 0))))
+                 o=seq(hb * dv), gate=kept(hb, C), kept=kept(hb, dk, dv),
+                 inverse=kept(hb, C, C), state=state(hb, dk, dv)))
 
 
 def _read(ops, widths):
@@ -568,28 +542,6 @@ def in_place_unsupported(key_heads, dk, dv, rep):
     return None
 
 
-def _cut(T):
-    """Chunks a program, programs along the sequence and the positions of
-    padding behind ``T``: those write nothing (beta 0), decay nothing (g 0)
-    and their outputs are cut off."""
-    nc = min(CHUNKS, -(-T // C))
-    groups = -(-T // (nc * C))
-    return nc, groups, groups * nc * C - T
-
-
-def _rows(x, pad):
-    """``[B, T, ..] -> [B, T', lanes]``."""
-    x = x.reshape(x.shape[:2] + (-1,))
-    return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
-
-
-def _gates(x, nc, groups, pad):
-    """``[B, T, H] -> [B, H, T' / (n C), n, C]`` f32."""
-    B, _, H = x.shape
-    return jnp.moveaxis(_rows(x.astype(_F32), pad), 2, 1).reshape(
-        B, H, groups, nc, C)
-
-
 def _kept(o, dk, dv):
     """Tell the group that recomputes this call (``dispatch.kept``) what
     ``_rule_fwd`` named of it: ``o [B, T', H dv]`` and, f32 a chunk and head,
@@ -603,11 +555,11 @@ def gated_delta_rule(q, k, v, g, beta):
     """``chunk_gated_delta_rule`` at chunk 64 through the kernel pair: ``q, k
     [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``g, beta [B, T, H]`` -> ``(o [B,
     T, H, d_v]`` in ``v``'s type, the last state ``[B, H, d_k, d_v]`` f32)``.
-    Any ``T`` (``_cut``)."""
+    Any ``T`` (``common.cut``: beta and g are 0 at the padding)."""
     B, T, H, dk = q.shape
-    cut = _cut(T)
-    o, last = _rule(None, *(_rows(x, cut[2]) for x in (q, k, v)),
-                    _gates(g, *cut), _gates(beta, *cut))
+    cut = common.cut(T)
+    o, last = _rule(None, *(common.rows(x, cut[2]) for x in (q, k, v)),
+                    common.by_chunk(g, *cut), common.by_chunk(beta, *cut))
     _kept(o, dk, v.shape[-1])
     return o[:, :T].reshape(B, T, H, v.shape[-1]), last
 
@@ -620,8 +572,8 @@ def gated_delta_rule_in_place(mixed, g, beta, *, dk, dv, rep):
     ``k = round(l2norm(k~))`` each repeated for its value heads, neither of
     which reaches HBM; the cotangent of ``mixed`` comes back whole."""
     T = mixed.shape[1]
-    cut = _cut(T)
-    o, _ = _rule((dk, dv, rep), _rows(mixed, cut[2]), _gates(g, *cut),
-                 _gates(beta, *cut))
+    cut = common.cut(T)
+    o, _ = _rule((dk, dv, rep), common.rows(mixed, cut[2]),
+                 common.by_chunk(g, *cut), common.by_chunk(beta, *cut))
     _kept(o, dk, dv)
     return o[:, :T]

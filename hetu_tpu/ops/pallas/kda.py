@@ -137,11 +137,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import dispatch
+from . import common, dispatch
 from ... import telemetry
-from .common import (C, CHUNKS, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot,
-                     dot32, dot32_stacked, head_lanes, iotas, params, pick,
-                     put, to_col, together, unit_lower_inverse, walk)
+from .common import (C, NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, dot, dot32,
+                     dot32_stacked, head_lanes, iotas, params, pick, put,
+                     to_col, together, unit, unit_lower_inverse, walk)
 from ..kda import SUB
 
 _F32 = jnp.float32
@@ -273,13 +273,6 @@ def _kept_inverses_bwd(Ts, dTs):
 _kept_inverses.defvjp(_kept_inverses_fwd, _kept_inverses_bwd)
 
 
-def _unit(x):
-    """The rows of ``x [C, d]`` over their norms, f32 (the layer's
-    ``l2norm``: 1e-6 under the root)."""
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6)
-
-
 def _open(q, k, g, beta_row, gate=None):
     """A chunk up to its triangle ``L``: what the state does not enter.  A
     generator, as ``_close``: it yields between stages that depend on each
@@ -291,8 +284,8 @@ def _open(q, k, g, beta_row, gate=None):
     ct = k.dtype
     if gate is not None:
         rate, bias, lower = gate
-        q = (_unit(q) * q.shape[1] ** -0.5).astype(ct)
-        k = _unit(k).astype(ct)
+        q = (unit(q)[0] * q.shape[1] ** -0.5).astype(ct)
+        k = unit(k)[0].astype(ct)
         g = lower * jax.nn.sigmoid(rate * (g.astype(_F32) + bias))
         yield
     row, col = iotas()
@@ -482,19 +475,13 @@ def _plan(ops, gate, reverse):
     else:
         dk = dv = ops[0].shape[2] // (3 * H)
     hb = math.gcd(H, HEADS)
-    at = (lambda i: groups - 1 - i) if reverse else (lambda i: i)
-    seq = lambda d, window=0: pl.BlockSpec(
-        (None, nc * C, hb * d),
-        lambda b, h, i: (b, at(i), window * (H // hb) + h))
-    rows = pl.BlockSpec((None, hb, None, nc, C),
-                        lambda b, h, i: (b, h, at(i), 0, 0))
-    kept = lambda rows, cols: pl.BlockSpec(
-        (None, hb, None, nc, rows, cols),
-        lambda b, h, i: (b, h, at(i), 0, 0, 0))
+    block, kept, state = common.blocks(nc, C, groups, reverse)
+    # a window of ``H d`` lanes is ``H / hb`` blocks of a program's
+    seq = lambda d, window=0: block(hb * d, window * (H // hb))
+    rows = kept(hb, C)
     names = dict(
-        qk=seq(dk), vo=seq(dv), rows=rows, kept=kept(dk, dv),
-        inverse=kept(C, C),
-        state=pl.BlockSpec((None, hb, dk, dv), lambda b, h, i: (b, h, 0, 0)),
+        qk=seq(dk), vo=seq(dv), rows=rows, kept=kept(hb, dk, dv),
+        inverse=kept(hb, C, C), state=state(hb, dk, dv),
         sums=pl.BlockSpec((None, 1, hb * dk), lambda b, h, i: (b, 0, h)))
     if gate is None:
         args, specs = ops, [seq(dk), seq(dk), seq(dv), seq(dk), rows]
@@ -700,23 +687,6 @@ def entries():
             for lab, n in dispatch.counted("hetu_kda_entry_total")}
 
 
-def _cut(T):
-    """Chunks a program, programs along the sequence and the positions of
-    padding behind ``T``."""
-    nc = min(CHUNKS, -(-T // C))
-    groups = -(-T // (nc * C))
-    return nc, groups, groups * nc * C - T
-
-
-def _beta(beta, nc, groups, pad):
-    """``[B, T, H] -> [B, H, T' / (n C), n, C]`` f32, 0 at the padding."""
-    B, _, H = beta.shape
-    beta = beta.astype(_F32)
-    if pad:
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    return jnp.moveaxis(beta, 2, 1).reshape(B, H, groups, nc, C)
-
-
 def kda(q, k, v, g, beta):
     """``chunk_kda`` at chunk 64 through the kernel pair: ``q, k [B, T, H,
     d_k]``, ``v [B, T, H, d_v]``, ``g [B, T, H, d_k]`` f32, ``beta [B, T, H]``
@@ -725,15 +695,10 @@ def kda(q, k, v, g, beta):
     decay nothing (g 0) and their outputs are cut off."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
-    nc, groups, pad = _cut(T)
+    cut = common.cut(T)
     _count_entry("plain")
-
-    def rows(x):                       # [B, T, H, d] -> [B, T', H d]
-        x = x.reshape(B, T, -1)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
-
-    o, last = _rule(None, rows(q), rows(k), rows(v), rows(g),
-                    _beta(beta, nc, groups, pad))
+    o, last = _rule(None, *(common.rows(x, cut[2]) for x in (q, k, v, g)),
+                    common.by_chunk(beta, *cut))
     return o[:, :T].reshape(B, T, H, dv), last
 
 
@@ -754,15 +719,11 @@ def kda_in_place(mixed, proj, beta, rate, bias, scale, *, lower_bound, eps):
     sigmoid(rate (f + bias))`` and ``y = o rsqrt(mean(o^2) + eps) scale
     sigmoid(z)`` a head, all on the chunk in VMEM.  Any ``T`` (a padded copy
     where 512 does not divide it)."""
-    B, T, H = beta.shape
-    nc, groups, pad = _cut(T)
+    T = beta.shape[1]
+    cut = common.cut(T)
     _count_entry("in_place")
-    if pad:
-        mixed = jnp.pad(mixed, ((0, 0), (0, pad), (0, 0)))
-        proj = jnp.pad(proj, ((0, 0), (0, pad), (0, 0)),
-                       constant_values=_SHUT)
     row = lambda x: x.astype(_F32).reshape(1, -1)
-    y, _ = _rule((float(lower_bound), float(eps)), mixed, proj,
-                 _beta(beta, nc, groups, pad), row(rate), row(bias),
-                 row(scale))
+    y, _ = _rule((float(lower_bound), float(eps)), common.rows(mixed, cut[2]),
+                 common.rows(proj, cut[2], constant_values=_SHUT),
+                 common.by_chunk(beta, *cut), row(rate), row(bias), row(scale))
     return y[:, :T]

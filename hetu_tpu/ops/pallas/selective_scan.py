@@ -36,7 +36,8 @@ channel tiles' rows up.  ``dA`` accumulates in its output block over the
 chunks of a sequence; XLA adds the batch up.
 
 ``delta``, ``A``, every decay, the state, ``y`` and every cotangent but
-``du`` are f32.
+``du`` are f32.  (Not ``common.walk``: the loops here carry the state, or its
+adjoint and ``dA``, in registers from one group of rows to the next.)
 """
 
 from __future__ import annotations

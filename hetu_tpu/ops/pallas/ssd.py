@@ -115,7 +115,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import telemetry
-from . import dispatch
+from . import common, dispatch
 from .common import (NN, NT, TN, VMEM_LIMIT, WALK, chunk_rows, params,
                      walk)
 
@@ -397,25 +397,16 @@ def _plan(dt, rp, N, reverse, wide, d=None):
     lanes is never indexed)."""
     import jax.experimental.pallas as pl
     b, G, blocks, nc, R, _ = dt.shape
-    at = (lambda i: blocks - 1 - i) if reverse else (lambda i: i)
-
-    def seq(lanes, first=0, shared=False):
-        return pl.BlockSpec(
-            (None, nc * L, lanes), lambda b, g, i: (
-                b, at(i), first + (g // wide if shared and wide > 1 else g)))
-
-    def six(rows, cols):
-        return pl.BlockSpec((None, None, None, nc, rows, cols),
-                            lambda b, g, i: (b, g, at(i), 0, 0, 0))
+    seq, kept, state = common.blocks(nc, L, blocks, reverse)
+    # a group's ``wide`` blocks of heads read its one block of ``B``, ``C``
+    group = (lambda g: g // wide) if wide > 1 else (lambda g: g)
     b_at, c_at = (0, 0) if d is None else (d // N, d // N + G // wide)
     return ((b, G, blocks), dict(nc=nc, heads=R, p=rp // R),
-            dict(x=seq(rp), B=seq(N, b_at, True), C=seq(N, c_at, True),
-                 part=seq(N), gate=six(R, L), kept=six(N, rp),
-                 state=pl.BlockSpec((None, None, N, rp),
-                                    lambda b, g, i: (b, g, 0, 0)),
+            dict(x=seq(rp), B=seq(N, b_at, group), C=seq(N, c_at, group),
+                 part=seq(N), gate=kept(None, R, L), kept=kept(None, N, rp),
+                 state=state(None, N, rp),
                  skip=pl.BlockSpec((None, 1, rp), lambda b, g, i: (g, 0, 0)),
-                 dskip=pl.BlockSpec((None, None, 1, rp),
-                                    lambda b, g, i: (b, g, 0, 0))))
+                 dskip=state(None, 1, rp)))
 
 
 def _read(ops, lanes):
@@ -573,15 +564,6 @@ def unsupported(x, Bm, Cm, chunk):
     return None
 
 
-def _cut(T):
-    """Chunks a program, programs along the sequence and the positions of
-    padding behind ``T``: those write nothing and decay nothing (dt 0) and
-    their outputs are cut off."""
-    nc = min(CHUNKS, -(-T // L))
-    blocks = -(-T // (nc * L))
-    return nc, blocks, blocks * nc * L - T
-
-
 def in_place_unsupported(T, d, N):
     """Why ``ssd_in_place`` does not read ``xBC [b, T, d + 2 G N]`` where the
     kernels take its heads (``unsupported``), or None when it does: the
@@ -592,7 +574,7 @@ def in_place_unsupported(T, d, N):
     entry is there to save."""
     if d % N:
         return "bc_window_not_block_aligned"
-    if _cut(T)[2]:
+    if common.cut(T, L, CHUNKS)[2]:
         return "positions_not_whole_blocks"
     return None
 
@@ -636,9 +618,7 @@ def _gates(dt, A, G, R, cut):
     """``dt [b, T, H]``, ``A [H]`` -> ``dt``, ``a = dt A`` as ``[b, G, T' / (n
     L), n, R, L]`` f32: a chunk along the lanes."""
     nc, blocks, pad = cut
-    dt = dt.astype(_F32)
-    if pad:
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+    dt = common.rows(dt.astype(_F32), pad)
     dt = dt.reshape(dt.shape[0], blocks, nc, L, G, R).transpose(
         0, 4, 1, 2, 5, 3)
     return dt, dt * A.astype(_F32).reshape(G, 1, 1, R, 1)
@@ -648,24 +628,21 @@ def ssd(x, dt, A, Bm, Cm):
     """``chunk_ssd`` at chunk 128 through the kernel pair: ``x [b, T, H,
     P]``, ``dt [b, T, H]``, ``A [H]``, ``B, C [b, T, G, N]`` -> ``(y [b, T, H,
     P]`` in ``x``'s type, the last state ``[b, H, P, N]`` f32)``.  Any ``T``
-    (``_cut``).  A group of more than ``HEADS`` heads runs as
-    ``wide`` blocks of ``R`` heads, each a program of its own on the grid's
-    second axis (below, ``G`` counts those blocks): a group's blocks read its
-    ``B`` and ``C`` rows in place, once a block, and the group's ``dB`` and
-    ``dC`` are the sum of what its blocks write."""
+    (``common.cut``: positions of padding write nothing and decay nothing, dt
+    0, and their outputs are cut off).  A group of more than ``HEADS`` heads
+    runs as ``wide`` blocks of ``R`` heads, each a program of its own on the
+    grid's second axis (below, ``G`` counts those blocks): a group's blocks
+    read its ``B`` and ``C`` rows in place, once a block, and the group's
+    ``dB`` and ``dC`` are the sum of what its blocks write."""
     b, T, H, P = x.shape
     N = Bm.shape[3]
     R = heads_a_program(H // Bm.shape[2], P)
     G, wide = H // R, H // Bm.shape[2] // R
     _count_entry(wide * R, R, "plain")
-    cut = _cut(T)
-
-    def rows(t):                       # [b, T, .., d] -> [b, T', .. d]
-        t = t.reshape(b, T, -1)
-        return jnp.pad(t, ((0, 0), (0, cut[2]), (0, 0))) if cut[2] else t
-
+    cut = common.cut(T, L, CHUNKS)
     dt, a = _gates(dt, A, G, R, cut)
-    y, last = _scan(wide, None, rows(x), dt, a, rows(Bm), rows(Cm))
+    x, Bm, Cm = (common.rows(t, cut[2]) for t in (x, Bm, Cm))
+    y, last = _scan(wide, None, x, dt, a, Bm, Cm)
     last = last.reshape(b, G, N, R, P).transpose(0, 1, 3, 4, 2)
     return y[:, :T].reshape(b, T, H, P), last.reshape(b, H, P, N)
 
@@ -680,5 +657,5 @@ def ssd_in_place(xbc, dt, A, D, *, heads, head_dim, groups, state):
     R = heads_a_program(heads // groups, head_dim)
     G, wide = heads // R, heads // groups // R
     _count_entry(wide * R, R, "in_place")
-    dt, a = _gates(dt, A, G, R, _cut(xbc.shape[1]))
+    dt, a = _gates(dt, A, G, R, common.cut(xbc.shape[1], L, CHUNKS))
     return _scan(wide, (head_dim, state), xbc, dt, a, D.astype(_F32))[0]

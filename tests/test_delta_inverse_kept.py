@@ -24,8 +24,8 @@ from hetu_tpu.ops.pallas import gated_delta as gdn_kernels
 from hetu_tpu.ops.pallas import kda as kda_kernels
 from hetu_tpu.ops.pallas.common import C
 from test_gated_delta_kernel import delta_inputs, mixed_inputs
-from test_kda import (D, draw, layer_arrays, mixer_in_place, mixer_jnp,
-                      rel)
+from test_kda import D, draw, rel
+from test_kda_in_place import layer_arrays, mixer_in_place, mixer_jnp
 
 T, H = 600, 2
 ENTRIES = ("gdn", "plain", "in_place")
@@ -155,7 +155,7 @@ def test_the_forward_kernel_writes_the_inverse_it_solved_for(entry, dtype,
                 # the one key head's k~ (the second window of ``mixed``)
                 # through the kernel's own norm, rounded as the kernel does
                 mixed, g, _ = ops
-                k = gdn_kernels._unit(mixed[0, rows, D:2 * D])[0]
+                k = common.unit(mixed[0, rows, D:2 * D])[0]
                 L, want = gdn_inverse(k.astype(mixed.dtype), row(g),
                                       row(beta))
             elif entry == "plain":
